@@ -37,7 +37,10 @@ def main():
     from llm_fine_tune_distributed_tpu.parallel.qlora import quantize_frozen
     from llm_fine_tune_distributed_tpu.utils.tree import flatten_dict, unflatten_dict
 
-    on_accelerator = jax.devices()[0].platform != "cpu"
+    from llm_fine_tune_distributed_tpu.runtime.device import on_accelerator as _on_acc
+
+    # raises on a CPU nobody asked for; JAX_PLATFORMS=cpu rehearses on tiny
+    on_accelerator = _on_acc(jax.devices()[0].platform)
     preset = os.environ.get(
         "DECODE_PRESET", "smollm3_3b" if on_accelerator else "tiny"
     )

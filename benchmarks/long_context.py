@@ -2,13 +2,14 @@
 """Long-context single-chip sweep: train-step throughput vs sequence length.
 
 Runs bench.py once per sequence length with the measured-best single-chip
-recipe for that cell (BASELINE.md "Long-context single-chip series") and
+recipe for that cell (found by earlier rounds; their record was deleted in
+PR 21 and the rates are not re-measured) and
 prints one JSON line per point plus a summary table. The recipes encode the
 HBM findings from the round-4 sweep on the 16G v5e chip (SmolLM3-3B):
 
   seq 1024  mb2 accum16  dots_no_batch remat, full-sequence unembed
-  seq 2048  mb1 accum16  dots_no_batch remat, seq-chunked CE 512, vmem 32M
-  seq 4096  mb1 accum8   mlp remat (dots_no_batch OOMs: 19.4G), CE 512, 48M
+  seq 2048  mb1 accum16  dots_no_batch remat, seq-chunked CE 512
+  seq 4096  mb1 accum8   mlp remat (dots_no_batch OOMs: 19.4G), CE 512
   seq 8192  mb1 accum4   QLoRA (NF4 base) — full-SFT does not fit a single
                          16G chip at 8k even under full remat (16.9G);
                          beyond that the supported path is the seq axis
@@ -40,7 +41,6 @@ RECIPES = {
         "BENCH_ACCUM": "8",
         "BENCH_LOSS_CHUNK": "512",
         "BENCH_REMAT_POLICY": "mlp",
-        "LIBTPU_INIT_ARGS": "--xla_tpu_scoped_vmem_limit_kib=49152",
     },
     8192: {
         "BENCH_BATCH": "1",
@@ -48,12 +48,13 @@ RECIPES = {
         "BENCH_LOSS_CHUNK": "512",
         "BENCH_REMAT_POLICY": "full",
         "BENCH_FREEZE": "qlora",
-        "LIBTPU_INIT_ARGS": "--xla_tpu_scoped_vmem_limit_kib=65536",
     },
 }
 
 
-def run_point(seq: int, steps: int) -> dict | None:
+def run_point(seq: int, steps: int) -> dict:
+    """One bench.py child per point (this parent never imports JAX, so each
+    child in turn owns the chip). A point that fails fails the sweep."""
     env = dict(os.environ)
     env.update(RECIPES[seq])
     env["BENCH_SEQ"] = str(seq)
@@ -65,15 +66,15 @@ def run_point(seq: int, steps: int) -> dict | None:
         text=True,
         timeout=900,
     )
-    for line in proc.stdout.splitlines():
-        line = line.strip()
-        if line.startswith("{"):
-            return json.loads(line)
-    print(f"seq {seq}: bench failed rc={proc.returncode}", file=sys.stderr)
-    tail = proc.stderr.strip().splitlines()[-3:]
-    for t in tail:
-        print(f"  {t}", file=sys.stderr)
-    return None
+    if proc.returncode == 0:
+        for line in proc.stdout.splitlines():
+            line = line.strip()
+            if line.startswith("{"):
+                return json.loads(line)
+    tail = "\n  ".join(proc.stderr.strip().splitlines()[-5:])
+    raise RuntimeError(
+        f"seq {seq}: bench.py failed rc={proc.returncode}\n  {tail}"
+    )
 
 
 def main() -> int:
@@ -82,28 +83,25 @@ def main() -> int:
     ap.add_argument("--steps", type=int, default=3)
     args = ap.parse_args()
 
+    seqs = [int(s) for s in args.seqs.split(",")]
+    unknown = [s for s in seqs if s not in RECIPES]
+    if unknown:
+        raise SystemExit(f"no recipe for seq {unknown} (known: {sorted(RECIPES)})")
     rows = []
-    for seq in (int(s) for s in args.seqs.split(",")):
-        if seq not in RECIPES:
-            print(f"seq {seq}: no recipe (known: {sorted(RECIPES)})", file=sys.stderr)
-            continue
+    for seq in seqs:
         res = run_point(seq, args.steps)
-        if res is not None:
-            res["recipe"] = {
-                k: v for k, v in RECIPES[seq].items() if k != "LIBTPU_INIT_ARGS"
-            }
-            rows.append(res)
-            print(json.dumps(res))
+        res["recipe"] = dict(RECIPES[seq])
+        rows.append(res)
+        print(json.dumps(res), flush=True)
 
-    if rows:
-        print(f"\n{'seq':>6} {'samples/s/chip':>15} {'tokens/s/chip':>14} {'step_s':>7}")
-        for r in rows:
-            print(
-                f"{r['seq_len']:>6} {r['value']:>15.3f} "
-                f"{r['tokens_per_sec_per_chip']:>14.1f} {r['step_seconds']:>7.2f}"
-            )
-    return 0 if rows else 1
+    print(f"\n{'seq':>6} {'samples/s/chip':>15} {'tokens/s/chip':>14} {'step_s':>7}")
+    for r in rows:
+        print(
+            f"{r['seq_len']:>6} {r['value']:>15.3f} "
+            f"{r['tokens_per_sec_per_chip']:>14.1f} {r['step_seconds']:>7.2f}"
+        )
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

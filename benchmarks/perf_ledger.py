@@ -1,7 +1,7 @@
 #!/usr/bin/env python
-"""Measured op-by-op ledger of the flagship train step (VERDICT r4 item 2).
+"""Measured op-by-op ledger of the flagship train step.
 
-BASELINE.md's "~18% non-matmul tax" claim was cost_analysis() arithmetic;
+An earlier "~18% non-matmul tax" claim was cost_analysis() arithmetic;
 this script replaces it with measurement: every constituent op of the
 SmolLM3-3B train step is timed ON THE CHIP at the exact step shapes
 (microbatch 2, seq 1024, bf16), fwd and fwd+bwd, then multiplied by its
@@ -12,8 +12,6 @@ sum of parts and the whole is XLA's fusion dividend (or overhead).
 Usage (real TPU):
     python benchmarks/perf_ledger.py            # full ledger, one JSON line
 Env: LEDGER_REPS (default 20), LEDGER_MB (microbatch, default 2).
-
-The same numbers feed the perf ledger section of BASELINE.md.
 """
 
 from __future__ import annotations
@@ -26,10 +24,6 @@ import os
 import time
 
 import numpy as np
-
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS", "--xla_tpu_scoped_vmem_limit_kib=32768"
-)
 
 import jax
 import jax.numpy as jnp
@@ -196,7 +190,7 @@ def main():
     # step is ledger-instrumented (observe/xla): AOT compile gives exact
     # compile seconds plus cost_analysis() FLOPs / bytes-accessed, which
     # the measured step time turns into roofline utilization gauges — the
-    # measured counterpart of BASELINE.md's cost_analysis() arithmetic.
+    # measured counterpart of cost_analysis() arithmetic.
     import bench
 
     from llm_fine_tune_distributed_tpu.observe.xla import (

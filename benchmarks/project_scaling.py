@@ -12,10 +12,12 @@ time on a real v5e-16 slice with the link model in observe/scaling.py:
 Run:  XLA_FLAGS=--xla_force_host_platform_device_count=16 JAX_PLATFORMS=cpu \
       python benchmarks/project_scaling.py
 
-Prints a markdown table (pasted into BASELINE.md's "Projected v5e-16
-scaling" section) plus one JSON line per mesh.
+Prints a markdown table plus one JSON line per mesh. The single-chip rate it
+scales from is an input, PROJ_MEASURED_SPS: samples/sec/chip from a chip run
+of bench.py (the 10.126 this script once defaulted to was taken over a
+device link that is gone; on the attached v5e: not measured).
 
-Honesty notes (also in BASELINE.md):
+Honesty notes:
 - the CPU backend's SPMD partitioner emits all-reduce+slice where TPU emits
   reduce-scatter, and lacks TPU's while-loop all-reduce sinking pass — so the
   accounted bytes are an UPPER bound on what the TPU program moves;
@@ -50,9 +52,12 @@ from llm_fine_tune_distributed_tpu.observe.scaling import (  # noqa: E402
 # The same rate is assumed for the larger-microbatch variants; validate on
 # the real chip with  BENCH_BATCH=8 BENCH_ACCUM=4 python bench.py  (larger
 # microbatches change HBM pressure, not per-sample matmul FLOPs).
-MEASURED_SAMPLES_PER_SEC_PER_CHIP = float(
-    os.environ.get("PROJ_MEASURED_SPS", "10.126")  # BENCH_r02.json
-)
+if "PROJ_MEASURED_SPS" not in os.environ:
+    raise SystemExit(
+        "project_scaling.py: set PROJ_MEASURED_SPS to the samples/sec/chip a "
+        "chip run of bench.py measured — there is no default to project from"
+    )
+MEASURED_SAMPLES_PER_SEC_PER_CHIP = float(os.environ["PROJ_MEASURED_SPS"])
 SEQ = 1024
 BASELINE_AGG_4GPU = 6.78 * 4                 # derived 4xL40S aggregate (bench.py)
 

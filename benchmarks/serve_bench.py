@@ -496,7 +496,10 @@ def main():
     from llm_fine_tune_distributed_tpu.models.configs import get_preset
     from llm_fine_tune_distributed_tpu.models.transformer import init_params
 
-    on_accelerator = jax.devices()[0].platform != "cpu"
+    from llm_fine_tune_distributed_tpu.runtime.device import on_accelerator as _on_acc
+
+    # raises on a CPU nobody asked for; JAX_PLATFORMS=cpu rehearses on tiny
+    on_accelerator = _on_acc(jax.devices()[0].platform)
     preset = os.environ.get(
         "SERVE_PRESET", "smollm3_3b" if on_accelerator else "tiny"
     )

@@ -20,6 +20,11 @@ def run_ask_cli(
     missing_dir_help: str,
     template_kwargs: Optional[dict] = None,
 ) -> int:
+    from llm_fine_tune_distributed_tpu.runtime.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=description)
     parser.add_argument(
         "question", nargs="*", help="question for the model (omit with --serve)"

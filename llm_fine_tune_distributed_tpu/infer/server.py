@@ -20,7 +20,10 @@ closes that gap with a dependency-free stdlib server exposing:
   POST /v1/fleet/scale               -> {"replicas": N} manual fleet
                                         resize within the autoscaler
                                         bounds (fleet servers only)
-  POST /v1/generate {"question": .., -> {"answer": ..}
+  POST /v1/generate {"question": .., -> {"answer": .., "token_ids": [..]}
+                                        (the generated ids: what a client
+                                        compares when the tokenizer cannot
+                                        decode them)
         optional: "max_new_tokens", "temperature", "top_p", "top_k",
                   "repetition_penalty", "greedy", "seed", "system_prompt",
                   "adapter" (tenant LoRA adapter name under --adapter-dir;
@@ -345,6 +348,14 @@ def serve(
             "boundary, which the window batcher does not have; drop "
             "--publish-watch-dir or pick --engine continuous|paged"
         )
+    import jax
+
+    devices = jax.devices()
+    print(
+        f"[serve] {len(devices)} devices ({devices[0].platform}, "
+        f"{devices[0].device_kind})",
+        flush=True,
+    )
     print(f"Loading model from {model_dir} ...")
     params, model_config = load_model_dir(model_dir)
     params = maybe_quantize(params, quantize)
@@ -368,8 +379,6 @@ def serve(
     slot_bridge = None
     engine_target = generator
     if getattr(generator, "_multihost", False):
-        import jax
-
         if engine_kind in ("continuous", "paged"):
             # sharded slot engines over the tick protocol: process 0 owns
             # HTTP, batching state, and settlement, and announces every
@@ -1118,9 +1127,12 @@ def serve(
                         chunk_out(
                             f"data: {json.dumps({'delta': delta})}\n\n".encode()
                         )
-                chunk_out(
-                    f"data: {json.dumps({'done': True, 'n_tokens': len(ids_all)})}\n\n".encode()
-                )
+                done = {
+                    "done": True,
+                    "n_tokens": len(ids_all),
+                    "token_ids": [int(t) for t in ids_all],
+                }
+                chunk_out(f"data: {json.dumps(done)}\n\n".encode())
             except Exception as e:
                 # the request died mid-stream (decode failure, shed, device
                 # error): emit a terminal error event with the structured
@@ -1398,7 +1410,10 @@ def serve(
             except Exception as e:  # surface generation errors as 500s
                 self._send(500, {"error": str(e)})
                 return
-            resp = {"answer": answer}
+            resp = {
+                "answer": answer,
+                "token_ids": [int(t) for t in pending.result],
+            }
             if gen.speculative_lookup > 0 and pending.spec_acceptance is not None:
                 # draft-acceptance telemetry so clients can see whether the
                 # speculation they asked for is actually paying off — THIS
@@ -1494,6 +1509,11 @@ def serve(
 
 
 def main(argv: Optional[list] = None) -> int:
+    from llm_fine_tune_distributed_tpu.runtime.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="Serve the tuned model over HTTP")
     parser.add_argument(
         "--model-dir", default=os.environ.get("MODEL_DIR", "outputs/best_model")

@@ -10,7 +10,7 @@ inside a sliding window open the circuit: the worker stops restarting,
 fails everything fast, and ``/healthz`` goes unhealthy so the orchestrator
 recycles the pod. That split — in-process recovery for blips, external
 restart for persistent faults — is the difference between a transient
-tunneled-link stall costing one batch of requests versus a full pod
+device stall costing one batch of requests versus a full pod
 bounce with cold HBM and a dropped prefix cache.
 
 ``FaultInjector`` is the deterministic chaos hook the tests and

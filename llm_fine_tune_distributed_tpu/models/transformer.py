@@ -727,8 +727,7 @@ def _lookup_table_constraint(table, mesh, vocab_dim: int = 0):
     embedding lookup and the unembed matmul — both places where FSDP's
     hidden-dim sharding collides with the batch-sharded activations and GSPMD
     would otherwise fall back to replicate-then-repartition
-    (spmd_partitioner.cc "Involuntary full rematerialization" warnings,
-    VERDICT r1 #1).
+    (spmd_partitioner.cc "Involuntary full rematerialization" warnings).
 
     The vocab dim shards over ``tensor`` when live (Megatron layout), else
     over ``fsdp`` — the table stays distributed either way (never fully

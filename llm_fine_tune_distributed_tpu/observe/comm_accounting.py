@@ -33,7 +33,7 @@ and returns every collective instruction with
 
 ``tests/test_comm_accounting.py`` pins these volumes against analytic
 expectations per target mesh; ``benchmarks/project_scaling.py`` feeds them
-into the v5e-16 throughput projection in BASELINE.md.
+into its v5e-16 throughput projection.
 
 Works on any backend whose compiled text is HLO (CPU, TPU). The parser
 understands sync collectives and the ``-start``/``-done`` async pairs.

@@ -19,7 +19,7 @@ baseline derivation):
 - remat adds one extra forward per backward for the rematerialized
   region (policy ``dots_no_batch`` saves matmul outputs, so the re-run
   is mostly non-matmul — counting a full extra forward is the
-  conservative upper bound BASELINE.md also uses);
+  conservative upper bound);
 - attention scores/values cost ``4*seq*heads*head_dim`` per token
   (QK^T + AV, un-causal — the flash kernel's causal skip would halve
   it; kept whole so the model stays an upper bound);

@@ -17,8 +17,7 @@ claims ride on *compiled-program* evidence instead of wall clocks:
    — see the function docstring).
 
 ``tests/test_comm_accounting.py`` pins (1)+(2) against analytic expectations;
-``benchmarks/project_scaling.py`` renders (3) into BASELINE.md's
-"projected v5e-16 scaling" section.
+``benchmarks/project_scaling.py`` renders (3) as a table.
 
 Hardware constants (stated assumptions, public v5e specs / scaling-book):
 
@@ -34,6 +33,7 @@ Hardware constants (stated assumptions, public v5e specs / scaling-book):
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, Optional, Sequence, Tuple
@@ -95,6 +95,7 @@ def abstract_train_setup(
     per_dp_batch: int = 1,
     param_dtype: str = "float32",
     train_kwargs: Optional[dict] = None,
+    model_overrides: Optional[dict] = None,
 ) -> AbstractSetup:
     """Build the trainer's sharded train step over ``mesh_shape`` with
     abstract (ShapeDtypeStruct) state — no parameter materialization, so the
@@ -102,9 +103,11 @@ def abstract_train_setup(
 
     Mirrors ``train/trainer.py:_prepare_state`` leaf-for-leaf: same freeze
     split, same master dtypes (trainable = ``param_dtype``, frozen =
-    compute dtype), same path-rule shardings, same optimizer-state sharding
-    propagation (via AOT ``output_shardings`` of ``optimizer.init``), and the
+    compute dtype), same path-rule shardings, same optimizer-state shardings
+    (``parallel/optimizer.opt_state_shardings``), and the
     pipe-mode stacked-layer representation when ``pipe > 1``.
+    ``model_overrides`` replaces fields of the preset (a compile test cuts
+    ``num_layers`` and keeps the widths).
     """
     from llm_fine_tune_distributed_tpu.config import (
         MeshConfig,
@@ -114,7 +117,10 @@ def abstract_train_setup(
     from llm_fine_tune_distributed_tpu.models.configs import get_preset
     from llm_fine_tune_distributed_tpu.models.transformer import init_params
     from llm_fine_tune_distributed_tpu.parallel.freeze import trainable_mask
-    from llm_fine_tune_distributed_tpu.parallel.optimizer import build_optimizer
+    from llm_fine_tune_distributed_tpu.parallel.optimizer import (
+        build_optimizer,
+        opt_state_shardings,
+    )
     from llm_fine_tune_distributed_tpu.parallel.sharding import (
         _validate_spec,
         param_spec,
@@ -130,7 +136,7 @@ def abstract_train_setup(
     )
     from llm_fine_tune_distributed_tpu.utils.tree import split_by_mask
 
-    mc = get_preset(preset)
+    mc = dataclasses.replace(get_preset(preset), **(model_overrides or {}))
     kwargs = dict(
         model_preset=preset,
         per_device_batch_size=per_dp_batch,
@@ -203,19 +209,11 @@ def abstract_train_setup(
     )
 
     optimizer = build_optimizer(tc, None, total_steps=4, data_parallel_size=dp)
-    init_compiled = jax.jit(optimizer.init).lower(trainable).compile()
-    opt_shardings = init_compiled.output_shardings
-    opt_shapes = jax.eval_shape(optimizer.init, trainable)
-    full_set = set(np.asarray(mesh.devices).flat)
-
-    def opt_leaf(struct, sh):
-        if getattr(sh, "device_set", None) and set(sh.device_set) == full_set:
-            return jax.ShapeDtypeStruct(struct.shape, struct.dtype, sharding=sh)
-        return jax.ShapeDtypeStruct(
-            struct.shape, struct.dtype, sharding=NamedSharding(mesh, P())
-        )
-
-    opt_state = jax.tree.map(opt_leaf, opt_shapes, opt_shardings)
+    opt_state = jax.tree.map(
+        lambda struct, sh: jax.ShapeDtypeStruct(struct.shape, struct.dtype, sharding=sh),
+        jax.eval_shape(optimizer.init, trainable),
+        opt_state_shardings(optimizer, trainable, mesh),
+    )
 
     state = TrainState(
         step=jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
@@ -240,12 +238,14 @@ def abstract_train_setup(
         )
 
         step = jit_train_step(
-            build_pipeline_train_step(mc, tc, optimizer, mesh, layer_vec)
+            build_pipeline_train_step(mc, tc, optimizer, mesh, layer_vec),
+            mesh=mesh,
         )
     else:
         act = NamedSharding(mesh, P(("data", "fsdp"), seq_ax, None))
         step = jit_train_step(
-            build_train_step(mc, tc, optimizer, activation_sharding=act)
+            build_train_step(mc, tc, optimizer, activation_sharding=act),
+            mesh=mesh,
         )
 
     return AbstractSetup(

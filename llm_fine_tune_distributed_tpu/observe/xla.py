@@ -320,39 +320,33 @@ def instrument(program, fn, ledger, shapes=None, aot=True):
 # -------------------------------------------------- utilization from cost
 
 
-# (peak dense bf16 FLOP/s, peak HBM bytes/s) per chip, matched by
-# substring against ``device_kind``. Marketing peaks — the gauges they
-# feed are roofline fractions, not absolute truth.
-_DEVICE_PEAKS: Tuple[Tuple[str, Tuple[float, float]], ...] = (
-    ("v6e", (918e12, 1.64e12)),
-    ("v5p", (459e12, 2.765e12)),
-    ("v5e", (197e12, 8.19e11)),
-    ("v5lite", (197e12, 8.19e11)),
-    ("v4", (275e12, 1.2288e12)),
-    ("v3", (123e12, 9.0e11)),
-    ("v2", (46e12, 7.0e11)),
-)
+# (peak dense bf16 FLOP/s, peak HBM bytes/s) per chip, keyed by the
+# ``device_kind`` string the installed runtime reports. Published peaks —
+# the gauges they feed are roofline fractions, not absolute truth.
+_DEVICE_PEAKS = {
+    # Google Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16 (393 TOP/s
+    # int8), 819 GB/s HBM. libtpu 0.0.34 reports a v5e as "TPU v5 lite".
+    "TPU v5 lite": (197e12, 8.19e11),
+}
 
 
 def device_peak_specs(device=None) -> Tuple[float, float]:
     """(peak FLOP/s, peak HBM bytes/s) for the given (default: first)
-    device. (0, 0) on CPU or unknown hardware — downstream gauges read
-    0.0 rather than invent a roofline. Overridable via SERVE_PEAK_FLOPS
-    and SERVE_PEAK_HBM_BPS for chips not in the table."""
-    env_f = os.environ.get("SERVE_PEAK_FLOPS")
-    env_b = os.environ.get("SERVE_PEAK_HBM_BPS")
-    if env_f or env_b:
-        return float(env_f or 0.0), float(env_b or 0.0)
+    device. A CPU is a known device with no peaks: (0, 0), and downstream
+    gauges read 0.0 rather than invent a roofline. An accelerator whose
+    kind is not in the table raises — it does not read as zero."""
     if device is None:
-        try:
-            device = jax.devices()[0]
-        except Exception:
-            return 0.0, 0.0
-    kind = str(getattr(device, "device_kind", "")).lower()
-    for sub, peaks in _DEVICE_PEAKS:
-        if sub in kind:
-            return peaks
-    return 0.0, 0.0
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return 0.0, 0.0
+    try:
+        return _DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); add it, with its source, to "
+            "observe/xla.py _DEVICE_PEAKS"
+        ) from None
 
 
 def utilization_from_cost(

@@ -32,15 +32,31 @@ _NEG_INF = -2.0e38  # large finite negative; avoids NaN from (-inf) - (-inf)
 # Trace-time dispatch ledger: which implementation each attention() call
 # actually resolved to (post-fallback). A sequence-parallel impl silently
 # degrading to flash/XLA is the difference between a live seq axis and dead
-# parallelism (VERDICT r4 weak #1: a "ulysses parity test" that really
+# parallelism (a "ulysses parity test" that really
 # exercised the fallback), so the resolution is recorded where it happens and
 # parallel/diagnostics.assert_seq_parallel() lets tests/users pin the path.
 _DISPATCH_COUNTS: collections.Counter = collections.Counter()
 
 
+# Why a requested "flash" resolved to "xla" instead (reason -> count). The
+# choice is made from what the call can observe (backend, shape, VMEM); it is
+# never a rescue from a kernel that failed to compile — that raises.
+_FLASH_FALLBACK_REASONS: collections.Counter = collections.Counter()
+
+
 def dispatch_count(impl: str) -> int:
     """How many attention() calls resolved to ``impl`` (trace-time count)."""
     return _DISPATCH_COUNTS[impl]
+
+
+def dispatch_summary() -> str:
+    """One line for entry points to print: the paths traced so far and, for
+    each flash request that took XLA attention, why."""
+    paths = ", ".join(f"{k}={v}" for k, v in sorted(_DISPATCH_COUNTS.items()))
+    why = "; ".join(f"{r} (x{n})" for r, n in _FLASH_FALLBACK_REASONS.items())
+    return f"attention paths traced: {paths or 'none'}" + (
+        f" | flash -> xla because: {why}" if why else ""
+    )
 
 
 def _causal_mask(q_len: int, kv_len: int, sliding_window: Optional[int] = None):
@@ -254,17 +270,53 @@ def attention(
             axis_name="seq", axis_size=mesh.shape["seq"], causal=causal,
         )
     if impl == "flash":
-        # Pallas kernel requires TPU, no sliding window (falls back otherwise).
+        # The Pallas kernel runs compiled or the run fails: it is skipped
+        # only for a reason visible here, at trace time, and the reason is
+        # kept for dispatch_summary().
         from llm_fine_tune_distributed_tpu.ops.flash_attention import (
-            flash_attention_supported,
+            flash_unsupported_reason,
             pallas_flash_attention,
         )
 
-        if flash_attention_supported(q, k, v, sliding_window=sliding_window, causal=causal):
-            _DISPATCH_COUNTS["flash"] += 1
-            return pallas_flash_attention(
-                q, k, v, padding_mask=padding_mask, segment_ids=segment_ids
+        # Mosaic kernels cannot be partitioned by GSPMD: on a mesh of more
+        # than one device the kernel runs per shard under a shard_map over
+        # the batch (data, fsdp) and head (tensor) axes, and eligibility is
+        # judged on the per-shard shape.
+        sharded = mesh is not None and mesh.size > 1
+        batch_n = mesh.shape["data"] * mesh.shape["fsdp"] if sharded else 1
+        head_n = mesh.shape["tensor"] if sharded else 1
+        if q.shape[0] % batch_n or k.shape[2] % head_n:
+            reason = (
+                f"batch {q.shape[0]} x kv heads {k.shape[2]} do not divide "
+                f"over mesh {dict(mesh.shape)}"
             )
+        else:
+            local = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+                (x.shape[0] // batch_n, x.shape[1], x.shape[2] // head_n, x.shape[3]),
+                x.dtype,
+            )
+            reason = flash_unsupported_reason(
+                local(q), local(k), local(v),
+                sliding_window=sliding_window, causal=causal,
+            )
+        if reason is None:
+            _DISPATCH_COUNTS["flash"] += 1
+            if not sharded:
+                return pallas_flash_attention(
+                    q, k, v, padding_mask=padding_mask, segment_ids=segment_ids
+                )
+            from llm_fine_tune_distributed_tpu.parallel.ring_attention import (
+                shard_map_seq_attention,
+            )
+
+            return shard_map_seq_attention(
+                lambda q_, k_, v_, p_, s_: pallas_flash_attention(
+                    q_, k_, v_, padding_mask=p_, segment_ids=s_
+                ),
+                mesh, None, q, k, v,
+                padding_mask=padding_mask, segment_ids=segment_ids,
+            )
+        _FLASH_FALLBACK_REASONS[reason] += 1
         impl = "xla"
     if impl == "xla":
         _DISPATCH_COUNTS["xla"] += 1

@@ -31,6 +31,7 @@ right-padded batches pass the 1/0 padding mask); softmax runs in float32.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,8 +40,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1.0e30
-_MAX_KERNEL_SEQ = 4096  # whole K/V/Q reside in VMEM per program; ring
-                        # attention (parallel/ring_attention.py) covers longer
+
+# Whole K/V/Q of a program reside in VMEM, so VMEM bounds the sequence (ring
+# attention, parallel/ring_attention.py, covers longer ones).
+# Scoped-VMEM budget, set per pallas_call so that every entry point compiles
+# the same kernel (the compiler's process-wide default is 16 MiB, and the
+# backward at seq 4096 needs more). A call asks for its pipelined blocks,
+# double-buffered at their tiled size, plus room for the body's [BQ, BK] f32
+# temporaries; nothing may ask for more than the cap, which leaves the rest
+# of a v5e core's 128 MiB to XLA's own fusions around the kernel.
+_VMEM_BODY_BYTES = 16 * 1024 * 1024
+_VMEM_CAP_BYTES = 100 * 1024 * 1024
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """Bytes of one VMEM buffer of ``shape``: the last dim pads to 128 lanes
+    and the one before to the dtype's sublane tile (8 rows of 32 bits)."""
+    itemsize = np.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sublanes = 8 * (4 // itemsize)
+    rows = -(-rows // sublanes) * sublanes
+    lanes = -(-lanes // 128) * 128
+    return int(np.prod(lead, dtype=np.int64)) * rows * lanes * itemsize
+
+
+def _vmem_budget(operands) -> int:
+    """``operands``: (block_shape, dtype, index_map) of every pipelined
+    operand of a kernel, inputs and outputs. Each kernel below lists its
+    operands once; its BlockSpecs and its budget are both built from that
+    list, so the two cannot drift apart."""
+    return 2 * sum(_tiled_bytes(s, d) for s, d, _ in operands) + _VMEM_BODY_BYTES
+
+
+def _compiler_params(operands):
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_budget(operands))
+
+
+def _block_specs(operands):
+    return [pl.BlockSpec(shape, index_map) for shape, _, index_map in operands]
 
 
 # ---------------------------------------------------------------------------
@@ -107,22 +144,69 @@ def _fwd(q, k, v, segments, *, scale, block_q, block_k, groups, interpret):
         jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
     )
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k, groups=groups)
+    ins, outs = _fwd_operands(q.dtype, sk, d, block_q, groups)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, sk, 1), lambda b_, h, i: (b_, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, sk, d), lambda b_, h, i: (b_, h // groups, 0, 0)),
-            pl.BlockSpec((1, 1, sk, d), lambda b_, h, i: (b_, h // groups, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i: (b_, h, i, 0)),
-        ),
+        in_specs=_block_specs(ins),
+        out_specs=tuple(_block_specs(outs)),
         out_shape=out_shape,
+        compiler_params=_compiler_params(ins + outs),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(segments[:, :, None], q, k, v)
+
+
+def _fwd_operands(dtype, sk, d, block_q, groups):
+    """Grid (batch, q head, q block): segments, q, k, v -> o, lse."""
+    q_blk = lambda b_, h, i: (b_, h, i, 0)  # noqa: E731
+    kv_head = lambda b_, h, i: (b_, h // groups, 0, 0)  # noqa: E731
+    ins = [
+        ((1, sk, 1), jnp.int32, lambda b_, h, i: (b_, 0, 0)),
+        ((1, 1, block_q, d), dtype, q_blk),
+        ((1, 1, sk, d), dtype, kv_head),
+        ((1, 1, sk, d), dtype, kv_head),
+    ]
+    outs = [
+        ((1, 1, block_q, d), dtype, q_blk),
+        ((1, 1, block_q, 1), jnp.float32, q_blk),
+    ]
+    return ins, outs
+
+
+def _dq_operands(dtype, sq, d, block_q, groups):
+    """Grid (batch, q head, q block): segments, q, k, v, do, lse, delta -> dq."""
+    q_blk = lambda b_, h, i: (b_, h, i, 0)  # noqa: E731
+    kv_head = lambda b_, h, i: (b_, h // groups, 0, 0)  # noqa: E731
+    ins = [
+        ((1, sq, 1), jnp.int32, lambda b_, h, i: (b_, 0, 0)),
+        ((1, 1, block_q, d), dtype, q_blk),
+        ((1, 1, sq, d), dtype, kv_head),
+        ((1, 1, sq, d), dtype, kv_head),
+        ((1, 1, block_q, d), dtype, q_blk),
+        ((1, 1, block_q, 1), jnp.float32, q_blk),
+        ((1, 1, block_q, 1), jnp.float32, q_blk),
+    ]
+    outs = [((1, 1, block_q, d), dtype, q_blk)]
+    return ins, outs
+
+
+def _dkv_operands(dtype, sq, d, block_k, groups):
+    """Grid (batch, KV head, k block): the q/do/lse/delta blocks span the
+    head's whole query group -> dk, dv at KV-head width."""
+    group = lambda b_, h, j: (b_, h, 0, 0)  # noqa: E731
+    k_blk = lambda b_, h, j: (b_, h, j, 0)  # noqa: E731
+    ins = [
+        ((1, sq, 1), jnp.int32, lambda b_, h, j: (b_, 0, 0)),
+        ((1, groups, sq, d), dtype, group),
+        ((1, 1, block_k, d), dtype, k_blk),
+        ((1, 1, block_k, d), dtype, k_blk),
+        ((1, groups, sq, d), dtype, group),
+        ((1, groups, sq, 1), jnp.float32, group),
+        ((1, groups, sq, 1), jnp.float32, group),
+    ]
+    outs = [((1, 1, block_k, d), dtype, k_blk), ((1, 1, block_k, d), dtype, k_blk)]
+    return ins, outs
 
 
 # ---------------------------------------------------------------------------
@@ -222,45 +306,31 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block_q, block_k, groups, inte
     hkv = k.shape[1]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # [b,hq,sq,1]
 
+    ins, outs = _dq_operands(q.dtype, sq, d, block_q, groups)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, block_k=block_k),
         grid=(b, hq, sq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, sq, 1), lambda b_, h, i: (b_, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, sq, d), lambda b_, h, i: (b_, h // groups, 0, 0)),
-            pl.BlockSpec((1, 1, sq, d), lambda b_, h, i: (b_, h // groups, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i: (b_, h, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
+        in_specs=_block_specs(ins),
+        out_specs=_block_specs(outs)[0],
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        compiler_params=_compiler_params(ins + outs),
         interpret=interpret,
+        name="flash_attention_dq",
     )(segments[:, :, None], q, k, v, do, lse, delta)
 
-    # grid over KV heads; q/do/lse/delta blocks span the head's query group
+    ins, outs = _dkv_operands(q.dtype, sq, d, block_k, groups)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, block_q=block_q, groups=groups),
         grid=(b, hkv, sq // block_k),
-        in_specs=[
-            pl.BlockSpec((1, sq, 1), lambda b_, h, j: (b_, 0, 0)),
-            pl.BlockSpec((1, groups, sq, d), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-            pl.BlockSpec((1, groups, sq, d), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, groups, sq, 1), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, groups, sq, 1), lambda b_, h, j: (b_, h, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-        ),
+        in_specs=_block_specs(ins),
+        out_specs=tuple(_block_specs(outs)),
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, sq, d), k.dtype),
             jax.ShapeDtypeStruct((b, hkv, sq, d), v.dtype),
         ),
+        compiler_params=_compiler_params(ins + outs),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(segments[:, :, None], q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -310,7 +380,7 @@ def _pick_block(s: int) -> int:
 
     override = os.environ.get("FLASH_BLOCK", "")
     if override:
-        blk = int(override)  # perf-sweep knob (BASELINE.md perf ledger)
+        blk = int(override)  # perf-sweep knob
         if blk % 128:
             raise ValueError(
                 f"FLASH_BLOCK={blk} violates the kernel's 128-lane alignment"
@@ -326,23 +396,37 @@ def _pick_block(s: int) -> int:
     return 0
 
 
-def flash_attention_supported(
+def flash_unsupported_reason(
     q, k, v, *, sliding_window=None, causal: bool = True
-) -> bool:
-    """Static eligibility check run at trace time by ops/attention.py."""
+) -> Optional[str]:
+    """Why the kernel cannot take this call (``None``: it can). Static, run
+    at trace time by ops/attention.py, which records the reason beside the
+    path it took instead."""
     b, sq, hq, d = q.shape
-    sk = k.shape[1]
+    sk, hkv = k.shape[1], k.shape[2]
     if jax.default_backend() != "tpu":
-        return False
+        return f"backend is {jax.default_backend()}, the kernel is compiled for TPU only"
     if not causal or sliding_window is not None:
-        return False
-    if sq != sk or sq > _MAX_KERNEL_SEQ:
-        return False  # decode/cache path and very long sequences use xla/ring
-    if _pick_block(sq) == 0:
-        return False
+        return "non-causal or sliding-window mask"
+    if sq != sk:
+        return f"q len {sq} != kv len {sk} (decode/cache path)"
+    block = _pick_block(sq)
+    if block == 0:
+        return f"seq {sq} is not a multiple of 128"
     if d % 128 != 0:
-        return False  # MXU lane alignment (all supported models have d=128)
-    return hq % k.shape[2] == 0
+        return f"head dim {d} is not a multiple of the 128 lanes"
+    if hq % hkv:
+        return f"q heads {hq} not a multiple of kv heads {hkv}"
+    # the dk/dv kernel holds a whole kv head's query group in VMEM and is the
+    # largest of the three; a shape it cannot hold takes xla/ring instead of
+    # dying inside the compiler (at SmolLM3 head shapes: seq <= 6144)
+    need = _vmem_budget(sum(_dkv_operands(q.dtype, sq, d, block, hq // hkv), []))
+    if need > _VMEM_CAP_BYTES:
+        return (
+            f"backward needs {need >> 20} MiB of VMEM at seq {sq}, over the "
+            f"{_VMEM_CAP_BYTES >> 20} MiB the kernel may ask for"
+        )
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -351,27 +435,36 @@ def flash_attention_supported(
 
 
 def _paged_decode_kernel(
-    tables_ref, lengths_ref,  # scalar-prefetch (SMEM)
-    q_ref, k_ref, v_ref, ks_ref, vs_ref,  # VMEM inputs
+    tables_ref, lengths_ref, ks_ref, vs_ref,  # scalar-prefetch (SMEM)
+    q_ref, k_ref, v_ref,  # VMEM inputs
     o_ref,  # VMEM output
     m_ref, l_ref, acc_ref,  # VMEM scratch, persistent across the block dim
-    *, scale,
+    *, scale, groups,
 ):
-    """One (batch row, kv head, table slot) step of online-softmax decode.
+    """One (batch row, table slot) step of online-softmax decode, all heads.
 
     The grid's innermost dim walks the row's block table; the BlockSpec
-    index maps have already gathered THIS slot's pool block (and its absmax
-    scales) into VMEM via the prefetched table, so the kernel never sees the
-    pool — no [b, nb*L] gather materializes anywhere. Dequantization folds
-    into the math: k codes scale the logits (``scale * k_absmax/127``), v
-    codes scale the accumulator update — two scalar multiplies per block
-    instead of casting L*d elements. The (m, l, acc) carry lives in scratch
-    that persists across the innermost grid dim; the output block flushes
-    once, on the last table slot.
+    index maps have already gathered THIS slot's pool block into VMEM via
+    the prefetched table, so the kernel never sees the pool — no [b, nb*L]
+    gather materializes anywhere. The block arrives whole, ``[L, hkv, d]``:
+    Mosaic only takes blocks whose last two dims are full or tile-aligned,
+    and a one-kv-head slice of the ``(hkv, d)`` minor dims is neither. It
+    is read as its flat ``[L*hkv, d]`` view (row ``l*hkv + h``; the same
+    bytes, int8 packs four sublane rows to a word either way) and every q
+    head is multiplied against every row; a column mask keeps only the
+    rows of the q head's own kv head. Decode is bandwidth-bound and the
+    block is read once for all heads, so the ``hkv``-fold extra MXU work is
+    free. Dequantization folds into the math: k absmax scales the logits'
+    columns, v absmax scales the probabilities' columns. The scales were
+    gathered per table slot outside (``[b*nb*hkv]`` f32 in SMEM — a
+    ``(1, 1)`` VMEM block of the ``[num_blocks, hkv]`` scale pool breaks
+    the same tiling rule). The (m, l, acc) carry lives in scratch that
+    persists across the innermost grid dim; the output block flushes once,
+    on the last table slot.
     """
     b_i = pl.program_id(0)
-    i = pl.program_id(2)
-    nb = pl.num_programs(2)
+    i = pl.program_id(1)
+    nb = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _init():
@@ -379,55 +472,70 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [G, d]
-    k_blk = k_ref[0, :, 0, :].astype(jnp.float32)  # [L, d] int8 codes
-    v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
-    g, _ = q.shape
-    block_len = k_blk.shape[0]
-    k_scale = ks_ref[0, 0] / 127.0
-    v_scale = vs_ref[0, 0] / 127.0
+    q = q_ref[0].astype(jnp.float32)  # [hq, d]
+    hq, d = q.shape
+    _, block_len, hkv, _ = k_ref.shape
+    cols = block_len * hkv
+    k_blk = k_ref[0].reshape(cols, d).astype(jnp.float32)  # int8 codes
+    v_blk = v_ref[0].reshape(cols, d).astype(jnp.float32)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1)
+    col_head = col % hkv
+    q_head = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0) // groups
+    base = (b_i * nb + i) * hkv
+    k_scale = jnp.zeros((hq, cols), jnp.float32)
+    v_scale = jnp.zeros((hq, cols), jnp.float32)
+    for h in range(hkv):  # static: hkv scalars spread over their columns
+        k_scale = jnp.where(col_head == h, ks_ref[base + h] / 127.0, k_scale)
+        v_scale = jnp.where(col_head == h, vs_ref[base + h] / 127.0, v_scale)
 
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (scale * k_scale)  # [G, L]
+    ) * (scale * k_scale)  # [hq, L*hkv]
     # gathered index IS logical position (models/transformer._block): slot i
     # of the table covers positions [i*L, (i+1)*L); visible iff < length.
     # Null-table slots gather block 0 (zero codes, zero scale) at positions
     # at/above length, so they are masked here exactly like the XLA path.
-    k_pos = i * block_len + jax.lax.broadcasted_iota(
-        jnp.int32, (g, block_len), 1
-    )
-    mask = k_pos < lengths_ref[b_i]
+    k_pos = i * block_len + col // hkv
+    mask = (k_pos < lengths_ref[b_i]) & (col_head == q_head)
     s = jnp.where(mask, s, _NEG_INF)
 
-    m_prev = m_ref[...]  # [G, 1]
+    m_prev = m_ref[...]  # [hq, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # [G, L]
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # [hq, L*hkv]
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * v_scale
+        p * v_scale, v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
     m_ref[...] = m_new
 
     @pl.when(i == nb - 1)
     def _flush():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+PAGED_DECODE_MODES = ("fused", "xla", "interpret")
 
 
 def paged_decode_mode() -> str:
-    """How ``models/transformer._block`` should read the int8 paged pool at
-    decode: ``"fused"`` (Pallas kernel), ``"interpret"`` (kernel under the
-    Pallas interpreter — CPU-runnable, tier-1 coverage of the kernel math)
-    or ``"xla"`` (dequantizing gather + masked attention — the default
-    everywhere off-TPU, so CPU CI never depends on Mosaic). The
-    ``PAGED_DECODE`` env var overrides the backend-based choice — the
-    serve_bench head-to-head sets it to pin each arm's path."""
+    """How ``models/transformer._block`` reads the int8 paged pool at
+    decode. On a TPU it is ``"fused"``: the Pallas kernel, compiled — if
+    Mosaic refuses it the run fails, nothing falls back. Elsewhere it is
+    ``"xla"`` (dequantizing gather + masked attention), so CPU tests never
+    depend on Mosaic. ``PAGED_DECODE`` set by hand overrides the choice;
+    ``interpret`` (the kernel under the Pallas interpreter, CPU-runnable
+    coverage of the kernel math) is reachable no other way."""
     import os
 
     override = os.environ.get("PAGED_DECODE", "").lower()
-    if override in ("fused", "xla", "interpret"):
+    if override:
+        if override not in PAGED_DECODE_MODES:
+            raise ValueError(
+                f"PAGED_DECODE={override!r}: expected one of {PAGED_DECODE_MODES}"
+            )
         return override
     return "fused" if jax.default_backend() == "tpu" else "xla"
 
@@ -446,29 +554,13 @@ def paged_decode_attention(
 
     Replaces the XLA sequence gather-pool -> dequantize -> mask -> softmax,
     whose gathered ``[b, nb*L, hkv, d]`` view round-trips through HBM every
-    decode tick — at batch 32 x 4k context that view is ~8x the bytes of
-    the int8 blocks it was gathered from. Here the block table is a scalar-
-    prefetch operand, so the BlockSpec index maps DMA exactly the table's
-    blocks into VMEM (the paged analog of the fwd kernel's GQA index maps)
-    and each is read once, in its 1-byte form.
-
-    Decode is HBM-bandwidth-bound — the opposite regime from the retired
-    NF4 matmul kernel (ops/nf4.py nf4_matmul), whose VPU nibble-decode lost
-    to the MXU it was feeding. The dequant here is two scalar multiplies
-    per block, so the kernel's byte traffic is the int8 pool itself;
-    serve_bench's SERVE_QUANT arm measures it head-to-head against the XLA
-    gather on the same pool and the bf16 baseline before it ships anywhere
-    (fallback policy: ``paged_decode_mode``).
-
-    Measured (serve_bench SERVE_QUANT, tiny preset, CPU via the XLA
-    fallback — the regime tier-1 actually runs; TPU numbers go here after
-    a device shootout, the nf4_matmul discipline): at an equal
-    bf16-equivalent pool budget of 208 KiB the int8 pool sustains 8 decode
-    slots vs bf16's 4 (slot ratio 2.0, gate >= 1.8) at 1394 vs 1466
-    tokens/sec — the ~5% CPU dequant overhead buys 2x the resident
-    batch, and every quantized request's greedy tokens matched the bf16
-    arm's. Interpret-mode kernel vs XLA reference: max |diff| 2.4e-7
-    (tests/test_quantized_serving.py pins it at 1e-5).
+    decode tick. Here the block table is a scalar-prefetch operand, so the
+    BlockSpec index maps DMA exactly the table's blocks into VMEM (the paged
+    analog of the fwd kernel's GQA index maps) and each is read once, in its
+    1-byte form. Speed on a chip: not measured. Lowering for v5e at SmolLM3
+    head shapes is pinned by tests/test_tpu_compile.py, agreement with the
+    XLA gather on the chip by chip_smoke.py, and the kernel math under the
+    interpreter by tests/test_quantized_serving.py.
     """
     b, s, hq, d = q.shape
     if s != 1:
@@ -480,39 +572,40 @@ def paged_decode_attention(
     nb = block_tables.shape[1]
     if scale is None:
         scale = float(1.0 / np.sqrt(d))
-    # head-major grouping: q head h serves kv head h // groups, so the
-    # [hkv, G] split is a plain reshape
-    qg = q[:, 0].reshape(b, hkv, groups, d)
+    tables = block_tables.astype(jnp.int32)
+    # per-slot scales, [b*nb*hkv]: a tiny XLA gather instead of a block spec
+    ks = k_scale.astype(jnp.float32)[tables].reshape(-1)
+    vs = v_scale.astype(jnp.float32)[tables].reshape(-1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, nb),
+        num_scalar_prefetch=4,
+        grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, groups, d), lambda bi, hi, i, t, ln: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_len, 1, d), lambda bi, hi, i, t, ln: (t[bi, i], 0, hi, 0)),
-            pl.BlockSpec((1, block_len, 1, d), lambda bi, hi, i, t, ln: (t[bi, i], 0, hi, 0)),
-            pl.BlockSpec((1, 1), lambda bi, hi, i, t, ln: (t[bi, i], hi)),
-            pl.BlockSpec((1, 1), lambda bi, hi, i, t, ln: (t[bi, i], hi)),
+            pl.BlockSpec((1, hq, d), lambda bi, i, t, ln, a, c: (bi, 0, 0)),
+            pl.BlockSpec(
+                (1, block_len, hkv, d), lambda bi, i, t, ln, a, c: (t[bi, i], 0, 0, 0)
+            ),
+            pl.BlockSpec(
+                (1, block_len, hkv, d), lambda bi, i, t, ln, a, c: (t[bi, i], 0, 0, 0)
+            ),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, groups, d), lambda bi, hi, i, t, ln: (bi, hi, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, hq, d), lambda bi, i, t, ln, a, c: (bi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((groups, 1), jnp.float32),  # m
-            pltpu.VMEM((groups, 1), jnp.float32),  # l
-            pltpu.VMEM((groups, d), jnp.float32),  # acc
+            pltpu.VMEM((hq, 1), jnp.float32),  # m
+            pltpu.VMEM((hq, 1), jnp.float32),  # l
+            pltpu.VMEM((hq, d), jnp.float32),  # acc
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=float(scale)),
+        functools.partial(_paged_decode_kernel, scale=float(scale), groups=groups),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, groups, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(
-        block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        qg, k_pool, v_pool,
-        k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-    )
+        name="paged_decode_attention",
+    )(tables, lengths.astype(jnp.int32), ks, vs, q[:, 0], k_pool, v_pool)
     return out.reshape(b, 1, hq, d)
 
 

@@ -38,13 +38,19 @@ TRUNK_MATMUL_MODES = ("xla", "pallas", "interpret")
 
 
 def trunk_matmul_mode() -> str:
-    """Implementation of the w8a8 trunk matmul. ``TRUNK_MATMUL`` overrides
-    the default (``xla``) — bench arms and the interpret/XLA parity tests
-    set it to pin each arm's path."""
+    """Implementation of the w8a8 trunk matmul: ``xla`` on every backend
+    unless ``TRUNK_MATMUL`` is set by hand — bench arms and the
+    interpret/XLA parity tests set it to pin each arm's path. ``pallas``
+    runs the kernel compiled or fails; ``interpret`` is reachable no other
+    way than by asking for it."""
     override = os.environ.get("TRUNK_MATMUL", "").lower()
-    if override in TRUNK_MATMUL_MODES:
-        return override
-    return "xla"
+    if not override:
+        return "xla"
+    if override not in TRUNK_MATMUL_MODES:
+        raise ValueError(
+            f"TRUNK_MATMUL={override!r}: expected one of {TRUNK_MATMUL_MODES}"
+        )
+    return override
 
 
 def quantize_rows_int8(x) -> Tuple[jax.Array, jax.Array]:
@@ -87,7 +93,13 @@ _BN = 512   # output-channel tile
 
 
 def _w8a8_kernel(xq_ref, wq_ref, xs_ref, ws_ref, out_ref):
-    acc = jnp.dot(xq_ref[:], wq_ref[:], preferred_element_type=jnp.int32)
+    # precision pinned: an integer contraction has none, and Mosaic refuses
+    # the int8 matmul ("Bad lhs type") if a process-wide
+    # jax_default_matmul_precision=highest leaks into it
+    acc = jnp.dot(
+        xq_ref[:], wq_ref[:], preferred_element_type=jnp.int32,
+        precision=jax.lax.Precision.DEFAULT,
+    )
     # scales arrive as 2-D tiles ([bm, 1] rows / [1, bn] cols) — Mosaic wants
     # >=2-D operands, and the broadcast shapes are already matmul-aligned
     out_ref[:] = (acc.astype(jnp.float32) * xs_ref[:] * ws_ref[:]).astype(

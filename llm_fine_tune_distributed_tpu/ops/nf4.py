@@ -204,8 +204,8 @@ def nf4_matmul(x, q: Dict, impl: str = "auto", compute_dtype=jnp.bfloat16):
     read where it can) or "auto" (resolves to "xla").
 
     A fused Pallas decode kernel was built and RETIRED after head-to-head
-    measurement on a v5e chip (round-2 shootout; BASELINE.md "NF4 matmul
-    implementations"): at the 3B train-microbatch shape (M=2048, K=2048,
+    measurement on a v5e chip (round-2 shootout, over a device link that
+    is gone; its record was deleted in PR 21): at the 3B train-microbatch shape (M=2048, K=2048,
     N=11008) fused-pallas ran 7.8ms vs 6.7ms XLA vs 5.6ms bf16, and at
     batch-1 decode both NF4 paths sat ~6.5ms vs 20us bf16. The bottleneck
     is not HBM (a fused kernel's win) but the exact nibble decode itself:

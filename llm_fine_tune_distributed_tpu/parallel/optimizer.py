@@ -18,6 +18,7 @@ from typing import Optional
 
 import jax
 import optax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from llm_fine_tune_distributed_tpu.config import TrainConfig
 
@@ -112,3 +113,35 @@ def build_optimizer(
     return optax.multi_transform(
         {"train": inner, "freeze": optax.set_to_zero()}, labels
     )
+
+
+def opt_state_shardings(optimizer: optax.GradientTransformation, trainable, mesh):
+    """A sharding for every leaf of ``optimizer.init(trainable)``: a leaf
+    that mirrors a parameter (Adam's mu and nu, keyed and shaped like it)
+    takes the parameter's sharding, everything else (step counts, factored
+    moments) is replicated over ``mesh``. Left to the compiler the moments
+    come out replicated — zeros depend on no input, so nothing propagates —
+    and every device of an fsdp mesh holds all of them (1.6 GB instead of
+    0.4 a device for the SmolLM3-3B flagship recipe on fsdp=4: PR 21).
+    ``trainable``: flat ``{path: array or ShapeDtypeStruct}`` with shardings."""
+    replicated = NamedSharding(mesh, P())
+
+    def for_leaf(path, leaf):
+        key = getattr(path[-1], "key", None) if path else None
+        param = trainable.get(key) if isinstance(key, str) else None
+        if param is not None and param.shape == leaf.shape:
+            return param.sharding
+        return replicated
+
+    return jax.tree_util.tree_map_with_path(
+        for_leaf, jax.eval_shape(optimizer.init, trainable)
+    )
+
+
+def init_opt_state(optimizer: optax.GradientTransformation, trainable, mesh):
+    """``optimizer.init`` with the state laid out by ``opt_state_shardings``:
+    the whole state lives on the full mesh (restore-from-checkpoint builds
+    its target shardings from it)."""
+    return jax.jit(
+        optimizer.init, out_shardings=opt_state_shardings(optimizer, trainable, mesh)
+    )(trainable)

@@ -64,7 +64,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from llm_fine_tune_distributed_tpu.utils.compat import shard_map
 
 import optax
 
@@ -311,7 +310,7 @@ def pipeline_forward(
     # partitions the dispatch/combine einsums over the expert axis exactly as
     # on a flat mesh (pipe x EP composition).
     manual_axes = {"pipe", *dp_axes} | ({"seq"} if seq_parallel else set())
-    outs, aux = shard_map(
+    outs, aux = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(

@@ -113,7 +113,9 @@ def shard_map_seq_attention(local, mesh: Mesh, axis_name: str, q, k, v,
     shard q/k/v (+ optional per-row operands) over the mesh and shard_map the
     local kernel. ``local(q, k, v, padding_mask, segment_ids)`` runs on one
     device's chunks. One source of truth so the optional-operand binding
-    cannot drift between ring and Ulysses entries."""
+    cannot drift between ring and Ulysses entries. ``axis_name=None`` leaves
+    the sequence whole: the flash kernel on a multi-device mesh
+    (ops/attention.py), sharded over batch and heads only."""
     qkv_spec = P(("data", "fsdp"), axis_name, "tensor", None)
     row_spec = P(("data", "fsdp"), axis_name)
 
@@ -126,9 +128,7 @@ def shard_map_seq_attention(local, mesh: Mesh, axis_name: str, q, k, v,
         s_ = rest.pop(0) if has_seg else None
         return local(q_, k_, v_, p_, s_)
 
-    from llm_fine_tune_distributed_tpu.utils.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(qkv_spec,) * 3
@@ -200,7 +200,7 @@ def ring_attention(q, k, v, *, mesh: Mesh, axis_name: str = "seq", padding_mask=
     q: [batch, seq, heads, dim]; k, v: [batch, seq, kv_heads, dim];
     padding_mask: optional [batch, seq], 1 = real token;
     segment_ids: optional [batch, seq] packing segments (packed long-context
-    runs keep their seq axis — VERDICT r3 #5).
+    runs keep their seq axis).
     Layout contract matches ops/attention.py; call sites go through
     ``ops.attention.attention(impl="ring", mesh=...)``.
     """

@@ -30,6 +30,7 @@ class RuntimeInfo:
     local_device_count: int
     global_device_count: int
     platform: str
+    device_kind: str
     hostname: str
 
     @property
@@ -74,6 +75,7 @@ def runtime_info() -> RuntimeInfo:
         local_device_count=jax.local_device_count(),
         global_device_count=len(devices),
         platform=devices[0].platform,
+        device_kind=devices[0].device_kind,
         hostname=socket.gethostname(),
     )
 
@@ -86,12 +88,16 @@ def is_primary_host() -> bool:
 
 def device_preflight(verbose: bool = True) -> dict:
     """Device/memory preflight report — the analog of the reference's CUDA
-    assert + VRAM print (C3, reference ``training.py:75-111``). Does NOT hard
-    fail off-TPU (CPU is a first-class simulation target here, unlike the
-    reference's CUDA-only RuntimeError at ``training.py:81-83``)."""
+    assert + VRAM print (C3, reference ``training.py:75-111``). The trainer
+    runs wherever JAX put it: the TPU where one is attached, a CPU for a
+    simulation run (``--platform cpu`` / ``JAX_PLATFORMS=cpu``). It does not
+    choose, so the report names the platform and the device kind, and a
+    caller that needs the chip (chip_smoke.py, the bench scripts) reads them
+    and fails on anything else."""
     info = runtime_info()
     report = {
         "platform": info.platform,
+        "device_kind": info.device_kind,
         "process": f"{info.process_index}/{info.process_count}",
         "local_devices": info.local_device_count,
         "global_devices": info.global_device_count,

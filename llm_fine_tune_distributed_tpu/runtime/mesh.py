@@ -23,13 +23,9 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from llm_fine_tune_distributed_tpu.config import MeshConfig
-from llm_fine_tune_distributed_tpu.utils.compat import (
-    mesh_auto_axis_types,
-    mesh_kwargs,
-)
 
 MESH_AXES = ("data", "pipe", "fsdp", "tensor", "seq", "expert")
 
@@ -40,9 +36,13 @@ def make_mesh(
 ) -> Mesh:
     """Build a Mesh with axes (data, fsdp, tensor, seq) from a MeshConfig.
 
-    Uses ``jax.make_mesh`` when laying out over real TPU devices so the mesh
-    follows the physical ICI topology; falls back to a reshape for explicit
-    device lists (tests).
+    ``jax.make_mesh`` lays the axes out along the physical ICI topology (a
+    failure there raises): on a 2x2 v5e host an axis of four is the ring of
+    neighbours 0, 1, 3, 2. The same holds for an explicit list of TPU
+    devices, attached or only described (a deviceless compile), so that a
+    program compiled without the chip is partitioned as it will be on it —
+    the compiler's choices depend on that order (PERF.md, PR 21). Devices of
+    any other platform (virtual CPU devices in tests) are taken as listed.
     """
     config = config or MeshConfig()
     if devices is None:
@@ -68,20 +68,12 @@ def make_mesh(
     # Auto axis types: sharding propagates GSPMD/Shardy-style from the
     # annotations on params/batch plus with_sharding_constraint points.
     # (jax.make_mesh defaults to Explicit axis types as of jax 0.9, which
-    # instead type-checks every intermediate — not what we want here. On
-    # jax 0.4.x AxisType does not exist and auto is the only semantics:
-    # mesh_auto_axis_types returns None and the kwarg is omitted.)
-    auto = mesh_auto_axis_types(len(MESH_AXES))
+    # instead type-checks every intermediate — not what we want here.)
+    auto = (AxisType.Auto,) * len(MESH_AXES)
     n_slices = len({getattr(d, "slice_index", 0) or 0 for d in devices})
     if n_slices > 1:
         return _make_hybrid_mesh(sizes, devices, n_slices, auto)
-    if devices is jax.devices() or list(devices) == list(jax.devices()):
-        try:
-            return jax.make_mesh(shape, MESH_AXES, **mesh_kwargs(auto))
-        except Exception:
-            pass
-    dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, MESH_AXES, **mesh_kwargs(auto))
+    return jax.make_mesh(shape, MESH_AXES, axis_types=auto, devices=list(devices))
 
 
 def _make_hybrid_mesh(sizes: dict, devices, n_slices: int, axis_types) -> Mesh:
@@ -90,7 +82,7 @@ def _make_hybrid_mesh(sizes: dict, devices, n_slices: int, axis_types) -> Mesh:
     Per-slice traffic (fsdp all-gathers, tensor psums, seq permutes, pipe
     boundaries, expert dispatch) must ride ICI; the pure data axis carries
     one gradient reduction per accumulation step — the only volume DCN can
-    afford (BASELINE.md "Multi-slice note"). Shapes that cannot place every
+    afford. Shapes that cannot place every
     non-data axis within a slice are rejected rather than silently built
     slow."""
     from jax.experimental import mesh_utils
@@ -117,7 +109,7 @@ def _make_hybrid_mesh(sizes: dict, devices, n_slices: int, axis_types) -> Mesh:
         tuple(dcn[a] for a in MESH_AXES),
         devices=list(devices),
     )
-    return Mesh(dev_array, MESH_AXES, **mesh_kwargs(axis_types))
+    return Mesh(dev_array, MESH_AXES, axis_types=axis_types)
 
 
 def data_parallel_size(mesh: Mesh) -> int:

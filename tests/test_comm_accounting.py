@@ -1,6 +1,6 @@
 """Collective-byte accounting of the compiled sharded train step, per mesh.
 
-The v5e-16 scaling claim (BASELINE.md) cannot be wall-clocked here (one real
+The v5e-16 scaling projection (benchmarks/project_scaling.py) cannot be wall-clocked here (one real
 chip), so its evidence is compiled-program facts: for each target mesh, the
 optimized HLO's per-step collective bytes must match the analytic cost of the
 parallelism strategy. ``observe/comm_accounting.py`` extracts the bytes (with
@@ -45,9 +45,6 @@ from llm_fine_tune_distributed_tpu.observe.comm_accounting import (
     account_text,
 )
 from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-from llm_fine_tune_distributed_tpu.utils.compat import (
-    make_mesh as compat_make_mesh,
-)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -80,7 +77,9 @@ def _ar(bytes_, g):
 def test_parser_exact_on_known_program(eight_devices):
     """A hand-built FSDP matmul step with a 3-trip scan: the parser must
     recover the exact collective set, axis attribution, and trip counts."""
-    mesh = compat_make_mesh((2, 4), ("data", "fsdp"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "fsdp"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     W = jax.ShapeDtypeStruct(
         (512, 512), jnp.float32, sharding=NamedSharding(mesh, P("fsdp", None))
     )
@@ -140,6 +139,27 @@ def test_trip_count_multiplier_scales_with_accum(eight_devices):
     w2 = abstract_train_setup({"data": 8}, accum=2).comm_report().total_wire_bytes()
     w4 = abstract_train_setup({"data": 8}, accum=4).comm_report().total_wire_bytes()
     assert 1.7 < w4 / w2 < 2.3
+
+
+def test_adam_moments_are_sharded_like_their_params(eight_devices):
+    """Left to the compiler ``optimizer.init``'s zeros come out replicated
+    (nothing propagates into a constant), so every device of an fsdp mesh held
+    all of Adam's moments. ``opt_state_shardings`` lays each moment out like
+    the parameter it mirrors and replicates only what mirrors none."""
+    s = abstract_train_setup({"data": 2, "fsdp": 4}, accum=2)
+    moments = [
+        (path[-1].key, leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(s.state.opt_state)
+        if leaf.ndim
+    ]
+    assert len(moments) == 2 * len(s.state.trainable)  # mu and nu
+    for key, leaf in moments:
+        assert leaf.sharding == s.state.trainable[key].sharding, key
+    assert _bytes_where({k: l for k, l in moments}, "fsdp") > 0
+    count = [
+        leaf for leaf in jax.tree.leaves(s.state.opt_state) if leaf.ndim == 0
+    ]
+    assert count and all(c.sharding.is_fully_replicated for c in count)
 
 
 # ------------------------------------------------------------- per-mesh volumes

@@ -1,4 +1,4 @@
-"""Distributed eval (VERDICT r1 #8): validation work is sharded over the
+"""Distributed eval: validation work is sharded over the
 data-parallel axes — per-device FLOPs shrink ~1/dp — while the token-weighted
 eval loss stays equal to the single-device result, and the whole sweep runs
 as one staged scan program."""

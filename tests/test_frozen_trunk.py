@@ -276,7 +276,7 @@ def test_backward_dce_compile_cost_guard():
 
 
 def test_bench_smoke_int8_interpret(tmp_path):
-    """bench.py end-to-end on the CPU fallback recipe with the int8 trunk
+    """bench.py end-to-end on the JAX_PLATFORMS=cpu rehearsal recipe with the int8 trunk
     on the INTERPRET path — tier-1 coverage of the Pallas kernel inside the
     real jitted train step, plus the bench JSON contract (mfu /
     trunk_flops_fraction / frozen_compute fields)."""

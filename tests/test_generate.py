@@ -222,7 +222,7 @@ def test_speculative_eos_stops(tiny_setup):
 
 @pytest.mark.slow
 def test_speculative_batched_per_row_equivalence(tiny_setup):
-    """Batched speculation (VERDICT r2 #6): every row of a speculative batch
+    """Batched speculation: every row of a speculative batch
     emits exactly the plain greedy sequence for ITS prompt — rows draft from
     their own contexts and desynchronize as acceptance diverges."""
     mc, params, tok = tiny_setup

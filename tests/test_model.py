@@ -139,8 +139,7 @@ def test_qk_norm_cache_decode_and_grad():
 
 
 def test_auto_remat_policy_by_size_and_seq():
-    """Auto remat resolution: measured-fastest per (model size, seq) cell —
-    BASELINE.md 'Long-context single-chip series'."""
+    """Auto remat resolution per (model size, seq) cell."""
     from llm_fine_tune_distributed_tpu.config import TrainConfig
 
     small, big = get_preset("smollm3_3b"), get_preset("llama3_8b")

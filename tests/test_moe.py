@@ -663,7 +663,7 @@ def test_mixtral_8x7b_qlora_traces():
 
 
 def test_moe_with_ring_attention_matches_unsharded(eight_devices):
-    """MoE x sequence parallelism on a FLAT mesh (VERDICT r3 missing #3):
+    """MoE x sequence parallelism on a FLAT mesh:
     a live seq axis with ring attention must not change MoE semantics —
     logits AND router aux (capacity/dispatch identical: the MoE runs in
     global view under GSPMD, only attention shard_maps over seq)."""
@@ -707,7 +707,7 @@ def test_moe_with_ulysses_attention_matches_unsharded(eight_devices):
 
     # mesh must SATISFY seq_parallel_preconditions (batch 2 % (data*fsdp) == 0,
     # kv heads 2 % seq 2 == 0) — the r4 version used data=2 x fsdp=2 with
-    # batch 2, which silently tested the fallback (VERDICT r4 weak #1); the
+    # batch 2, which silently tested the fallback; the
     # guard makes any such regression fail loudly instead of passing.
     mesh = Mesh(
         np.array(eight_devices).reshape(2, 1, 1, 2, 2),

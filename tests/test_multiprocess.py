@@ -124,7 +124,7 @@ def test_two_process_training(tmp_path):
 
 @pytest.mark.slow
 def test_two_process_cross_host_sequence_parallel(tmp_path):
-    """The seq axis SPANS process boundaries (VERDICT r1 #4): 2 processes x 1
+    """The seq axis SPANS process boundaries: 2 processes x 1
     device, mesh seq=2, ring attention — each host loads the same batch rows
     and its device holds a sequence slice; the ring's ppermute crosses the
     process gap every step."""
@@ -246,7 +246,7 @@ print("DECODE PROBE OK", jax.process_index())
 
 @pytest.mark.slow
 def test_two_process_tensor_parallel_decode_parity(tmp_path):
-    """Multi-host inference (VERDICT r2 #5): a tensor=2 mesh spanning TWO
+    """Multi-host inference: a tensor=2 mesh spanning TWO
     single-device processes decodes with greedy BIT-PARITY (f32) against the
     single-process meshless Generator — weights placed via global arrays,
     TP psums crossing a real process boundary every layer."""
@@ -534,7 +534,7 @@ def _assert_dumps_identical(a_path, b_path):
 
 @pytest.mark.slow
 def test_elastic_resume_four_to_two_processes(tmp_path):
-    """The JobSet restart reality (VERDICT r4 #6): a sharded Orbax save from
+    """The JobSet restart reality: a sharded Orbax save from
     FOUR processes restores into TWO — every leaf (params, frozen, Adam
     moments, step) bit-identical. Orbax stores global arrays; the fsdp axis
     resize is pure resharding."""

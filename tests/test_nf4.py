@@ -232,7 +232,7 @@ def test_pallas_impl_is_retired():
 
 def test_layered_stacked_roundtrip():
     """4-D [L, E, in, out] pipe-stacked expert quantization (qlora x pipe x
-    MoE, VERDICT r3 #4): per-layer slices are standalone stacked layouts and
+    MoE): per-layer slices are standalone stacked layouts and
     the roundtrip matches quantizing each layer independently."""
     from llm_fine_tune_distributed_tpu.ops.nf4 import (
         dequantize_nf4_layered_stacked,

@@ -142,7 +142,7 @@ def test_packed_arrays_loss_mask_never_crosses_segments(tok):
 
 @pytest.mark.slow
 def test_packed_training_with_seq_axis_matches_flat(tmp_path, eight_devices):
-    """packing x sequence parallelism (VERDICT r3 #5): a packed train step on
+    """packing x sequence parallelism: a packed train step on
     a live seq axis (ring and ulysses) computes the SAME loss as the flat-mesh
     XLA-attention step — same data, same seed, same init."""
     from llm_fine_tune_distributed_tpu.data.convert import convert_jsonl_to_parquet

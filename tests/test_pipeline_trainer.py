@@ -1,4 +1,4 @@
-"""Pipeline parallelism wired into SFTTrainer (VERDICT r1 #3): a `pipe` mesh
+"""Pipeline parallelism wired into SFTTrainer: a `pipe` mesh
 axis trains end-to-end with loss parity against the flat mesh, composes with
 data parallelism, honors the freezing policy via the per-layer gradient
 mask, and exports the identical per-layer artifact contract."""
@@ -221,7 +221,7 @@ def test_pipeline_state_split_lora():
 def test_pipe_lora_loss_parity(qa_parquet, tmp_path):  # noqa: F811
     """pipe=2 x LoRA trains with loss parity vs the flat LoRA run, keeps the
     optimizer state at adapter size, and exports the PEFT adapter +
-    merged model exactly like the flat path (VERDICT r2 #3)."""
+    merged model exactly like the flat path."""
     from llm_fine_tune_distributed_tpu.train.trainer import SFTTrainer
 
     data_dir, dataset_file = qa_parquet
@@ -293,7 +293,7 @@ def test_pipe_qlora_trains(qa_parquet, tmp_path):  # noqa: F811
 
 @pytest.mark.slow
 def test_pipe_qlora_moe_quantizes_experts(qa_parquet, tmp_path):  # noqa: F811
-    """qlora x pipe x MoE (VERDICT r3 #4): the pipe-stacked 4-D expert
+    """qlora x pipe x MoE: the pipe-stacked 4-D expert
     weights — the dominant bytes of an MoE model — are NF4 at rest, training
     learns through the dequantizing stage scan, and the export decodes back
     to plain safetensors."""
@@ -363,7 +363,7 @@ def test_pipe_trainer_moe(qa_parquet, tmp_path):  # noqa: F811
 
 @pytest.mark.slow
 def test_pipe_trainer_moe_expert_parallel(qa_parquet, tmp_path):  # noqa: F811
-    """pipe x EP (VERDICT r2 #4): on a pipe=2 x expert=2 x fsdp=2 mesh the
+    """pipe x EP: on a pipe=2 x expert=2 x fsdp=2 mesh the
     stacked expert weights shard over pipe AND expert (the memory win both
     axes exist for), the schedule keeps EP inside each stage, and training
     learns."""

@@ -1,4 +1,4 @@
-"""Pre-staged real-weights path (VERDICT r4 #5).
+"""Pre-staged real-weights path.
 
 No-egress environments cannot fetch HF Hub weights, so the reference's end
 oracle (real SmolLM3 answering the golden questions better after tuning)

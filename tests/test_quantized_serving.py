@@ -381,6 +381,10 @@ def test_paged_decode_mode_defaults_and_env(monkeypatch):
     assert paged_decode_mode() == "interpret"
     monkeypatch.setenv("PAGED_DECODE", "xla")
     assert paged_decode_mode() == "xla"
+    # a typo does not silently become the default
+    monkeypatch.setenv("PAGED_DECODE", "fuzed")
+    with pytest.raises(ValueError, match="fuzed"):
+        paged_decode_mode()
 
 
 def test_engine_parity_through_interpreted_fused_kernel(generator,
@@ -395,21 +399,9 @@ def test_engine_parity_through_interpreted_fused_kernel(generator,
     assert outs == solo
 
 
-@pytest.mark.slow
-def test_fused_kernel_compiled_tpu():
-    """The compiled Mosaic kernel (TPU only): same contract as interpret
-    mode, run head-to-head against the XLA reference on device."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("compiled Pallas path needs a TPU backend")
-    q, ck, cv, ks, vs, tables, lengths = _kernel_case()
-    got = paged_decode_attention(
-        q, ck, cv, ks, vs, tables, lengths=lengths, interpret=False,
-    )
-    ref = _xla_reference(q, ck, cv, ks, vs, tables, lengths)
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(ref, np.float32),
-        rtol=2e-2, atol=2e-2,  # bf16 MXU accumulation vs f32 reference
-    )
+# The COMPILED kernel: Mosaic lowering for v5e at SmolLM3 head shapes is
+# pinned without a chip by tests/test_tpu_compile.py, and its agreement with
+# the XLA gather (rtol = atol = 2e-2) is checked on the chip by chip_smoke.py.
 
 
 # --------------------------------------------------------- memory accounting

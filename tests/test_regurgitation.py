@@ -1,4 +1,4 @@
-"""Decode == train consistency (VERDICT r4 #2).
+"""Decode == train consistency.
 
 A model whose teacher-forced train loss is ~0 on a memorized dataset MUST
 greedily regurgitate the memorized answers through the production inference
@@ -130,6 +130,6 @@ def test_overfit_model_greedily_regurgitates_training_answers(memorize_setup):
     mean_overlap = float(np.mean(overlaps))
     # near-total byte overlap: loss ~0 must imply decode reproduces training
     # text; anything else is an inference-path (template/position/tokenizer)
-    # mismatch — the exact failure mode VERDICT r4 #2 demands be detectable
+    # mismatch — the failure mode this probe exists to make detectable
     assert mean_overlap > 0.9, (mean_overlap, overlaps)
     assert exact >= 7, (exact, overlaps)

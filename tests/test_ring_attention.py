@@ -98,7 +98,7 @@ def _segments(b, s, seed=0, n_seg=3, pad_tail=8):
 def test_ring_matches_xla_with_segments(eight_devices):
     """Packed rows (block-diagonal causal via segment ids) through the ring:
     the rotated key-side id chunk must reproduce xla_attention's segment
-    masking exactly (packing x sequence parallelism, VERDICT r3 #5)."""
+    masking exactly (packing x sequence parallelism)."""
     mesh = _mesh(eight_devices, seq=8)
     q, k, v = _qkv(s=64)
     seg = _segments(2, 64)

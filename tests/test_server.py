@@ -92,6 +92,25 @@ def test_generate(server):
     with urllib.request.urlopen(req, timeout=120) as r:
         payload = json.loads(r.read())
     assert isinstance(payload["answer"], str)
+    # the generated ids ride the response: what a client compares when the
+    # tokenizer cannot decode them (byte tokenizer, ids >= 256)
+    ids = payload["token_ids"]
+    assert 1 <= len(ids) <= 8 and all(isinstance(t, int) for t in ids)
+
+
+def test_generate_token_ids_identical_for_identical_greedy_requests(server):
+    body = json.dumps(
+        {"question": "Which way is north?", "max_new_tokens": 8, "greedy": True}
+    ).encode()
+    got = []
+    for _ in range(2):
+        req = urllib.request.Request(
+            f"{server}/v1/generate", data=body,
+            headers={"Content-Type": "application/json"},
+        )
+        with urllib.request.urlopen(req, timeout=120) as r:
+            got.append(json.loads(r.read())["token_ids"])
+    assert got[0] and got[0] == got[1]
 
 
 def test_bad_request(server):
@@ -249,7 +268,8 @@ def test_stream_sse(server):
         headers={"Content-Type": "application/json"},
     )
     with urllib.request.urlopen(req, timeout=120) as r:
-        answer = json.loads(r.read())["answer"]
+        generated = json.loads(r.read())
+    answer = generated["answer"]
 
     sreq = urllib.request.Request(
         f"{server}/v1/stream", data=json.dumps(body).encode(),
@@ -268,6 +288,10 @@ def test_stream_sse(server):
     # decode_reply strips; the streamed deltas carry the raw decode
     assert text.strip() == answer
     assert events[-1]["n_tokens"] >= 1
+    # the stream's closing event carries the ids it streamed: the same
+    # ids the non-streamed greedy request returned
+    assert events[-1]["token_ids"] == generated["token_ids"]
+    assert events[-1]["n_tokens"] == len(events[-1]["token_ids"])
 
 
 def test_stream_bad_request(server):

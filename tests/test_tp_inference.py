@@ -1,4 +1,4 @@
-"""Tensor-parallel inference (VERDICT r1 #5): a Generator given a tp mesh
+"""Tensor-parallel inference: a Generator given a tp mesh
 shards weights (and the KV cache by propagation) and produces the same
 greedy tokens as single-device decode; sampled decode stays seeded-
 deterministic; the weights are actually distributed (per-device shards)."""

@@ -1,0 +1,204 @@
+"""Deviceless compiles: the Pallas kernels of the main path, lowered by the
+TPU's own compiler for a described (not attached) v5e at SmolLM3-3B widths.
+
+Interpret mode cannot see what Mosaic refuses — a block whose last two dims
+are neither full nor tile-aligned, a kernel over the VMEM it may use, a
+kernel GSPMD cannot partition. These compiles can, at no chip time; what they
+cannot say is whether the results are right or how long they take
+(``chip_smoke.py`` checks the numbers on the chip).
+
+Rules that keep this file safe under pytest-xdist (only one process may load
+libtpu): the topology is described inside a module-scoped, non-autouse
+fixture, never at import, in a ``skipif`` or in ``parametrize`` arguments;
+every compile happens in the test's own process; all of them live in this
+one file, so one worker loads the library once.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from llm_fine_tune_distributed_tpu.ops import flash_attention as fa
+from llm_fine_tune_distributed_tpu.ops.int8_matmul import _w8a8_pallas
+
+# SmolLM3-3B attention geometry
+HQ, HKV, D = 16, 4, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a deviceless executable can be written to the persistent cache but not
+    # read back without a chip: keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def production_matmul_precision():
+    """conftest.py sets ``highest`` for CPU numerics; the entry points run at
+    the default, and that is the kernel these tests must compile."""
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel in the program"
+    return compiled
+
+
+def _qkv(b, s):
+    return (
+        ((b, s, HQ, D), jnp.bfloat16),
+        ((b, s, HKV, D), jnp.bfloat16),
+        ((b, s, HKV, D), jnp.bfloat16),
+    )
+
+
+# the largest sequence flash_unsupported_reason admits at these head shapes:
+# the dk/dv kernel's VMEM budget at 6144 is 95 MiB of the 100 MiB cap
+MAX_FLASH_SEQ = 6144
+
+
+@pytest.mark.parametrize("seq", [1024, MAX_FLASH_SEQ])
+def test_flash_forward_compiles_for_v5e(one_chip, seq):
+    _compile(lambda q, k, v: fa.pallas_flash_attention(q, k, v), one_chip, *_qkv(2, seq))
+
+
+@pytest.mark.parametrize("seq", [1024, MAX_FLASH_SEQ])
+def test_flash_forward_backward_compiles_for_v5e(one_chip, seq):
+    """The backward holds a kv head's whole query group in VMEM: this is the
+    compile the default 16 MiB scoped budget refused at seq 4096."""
+
+    def loss(q, k, v):
+        return fa.pallas_flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *_qkv(2, seq))
+
+
+def test_flash_says_no_past_its_vmem_cap(monkeypatch):
+    """One block past the largest admitted sequence the kernel is refused for
+    a stated reason, before the compiler is asked (so needs no topology)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def reason(seq):
+        q, k, v = (jax.ShapeDtypeStruct(s, d) for s, d in _qkv(2, seq))
+        return fa.flash_unsupported_reason(q, k, v)
+
+    assert reason(MAX_FLASH_SEQ) is None
+    assert "VMEM" in reason(MAX_FLASH_SEQ + 512)
+    assert reason(1000) == "seq 1000 is not a multiple of 128"
+
+
+def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
+    """Mosaic kernels cannot be partitioned by GSPMD: on the default fsdp=4
+    mesh the dispatcher must run the kernel per shard under a shard_map
+    (before PR 21 this lowering raised NotImplementedError)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llm_fine_tune_distributed_tpu.config import MeshConfig
+    from llm_fine_tune_distributed_tpu.ops.attention import attention
+    from llm_fine_tune_distributed_tpu.runtime.mesh import make_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh(MeshConfig(data=1, fsdp=4, tensor=1, seq=1), topo.devices)
+    rows = NamedSharding(mesh, P(("data", "fsdp")))
+
+    def loss(q, k, v, pad):
+        out = attention(q, k, v, impl="flash", padding_mask=pad, mesh=mesh)
+        return out.astype(jnp.float32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=rows) for s, d in _qkv(8, 1024)]
+    pad = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=rows)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args, pad).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+
+
+def test_fsdp4_step_keeps_plain_all_gathers_on_a_ring_ordered_mesh(topo, monkeypatch):
+    """``jax.make_mesh`` lays the fsdp axis of a 2x2 v5e host out as the ring
+    of neighbours 0, 1, 3, 2. In that order (and not in 0, 1, 2, 3, which is
+    what ``runtime/mesh.make_mesh`` used to build from a list of described
+    devices, so that no deviceless compile saw it) XLA rewrote every weight
+    all-gather + matmul of the step as a windowed einsum, kept 0.31 GiB of
+    weight shards a layer live, and refused the flagship recipe at full depth
+    (18.69 GB of a device's 15.75: PR 21). ``jit_train_step`` compiles a
+    partitioned step without that rewrite. Four layers at SmolLM3-3B width:
+    with the rewrite this program holds 225 collective-permutes and 3.27 GiB
+    of temporaries, without it 7 and 2.04."""
+    import re
+
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 4, "tensor": 1, "seq": 1}, "smollm3_3b",
+        devices=topo.devices, accum=4, seq=1024, per_dp_batch=2, param_dtype="bfloat16",
+        train_kwargs=dict(remat_policy="dots_no_batch", attention_impl="flash"),
+        model_overrides=dict(num_layers=4),
+    )
+    # make_mesh orders described devices as it orders attached ones
+    assert [d.id for d in setup.mesh.devices.flat] == [0, 1, 3, 2]
+    compiled = setup.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the flash kernel is not in the step"
+    gathers = len(re.findall(r"= .*\ball-gather(-start)?\(", text))
+    permutes = len(re.findall(r"= .*\bcollective-permute(-start)?\(", text))
+    assert gathers > 100 and permutes < 20, (gathers, permutes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.4 * 2**30
+
+
+@pytest.mark.parametrize("block_len", [256, 16])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, block_len):
+    """The int8 paged-decode kernel at SmolLM3 head shapes: the server's
+    default ``--kv-block-len 256`` and the small block of the unit tests.
+    (Until PR 21 its K/V blocks sliced the kv-head dim to 1 and Mosaic
+    refused it; agreement with the XLA gather is checked on the chip.)"""
+    num_blocks, b, nb = 64, 4, 4
+
+    def decode(q, kp, vp, ks, vs, tables, lengths):
+        return fa.paged_decode_attention(q, kp, vp, ks, vs, tables, lengths=lengths)
+
+    _compile(
+        decode, one_chip,
+        ((b, 1, HQ, D), jnp.bfloat16),
+        ((num_blocks, block_len, HKV, D), jnp.int8),
+        ((num_blocks, block_len, HKV, D), jnp.int8),
+        ((num_blocks, HKV), jnp.float32),
+        ((num_blocks, HKV), jnp.float32),
+        ((b, nb), jnp.int32),
+        ((b,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("m, k, n", [(2048, 2048, 11008), (2048, 11008, 2048)])
+def test_int8_trunk_matmul_compiles_for_v5e(one_chip, m, k, n):
+    """SmolLM3's MLP up- and down-projection at microbatch 2 x seq 1024."""
+    _compile(
+        lambda xq, xs, wq, ws: _w8a8_pallas(xq, xs, wq, ws, jnp.bfloat16),
+        one_chip,
+        ((m, k), jnp.int8), ((m,), jnp.float32),
+        ((k, n), jnp.int8), ((n,), jnp.float32),
+    )

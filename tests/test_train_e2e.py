@@ -172,11 +172,11 @@ def test_gemma2_family_sft_smoke(qa_parquet, tmp_path):
 
 
 def test_answer_only_eval_metric_and_eval_batch_size(qa_parquet, tmp_path):
-    """(a) eval_loss_answer (completion-span CE, VERDICT r4 #4) is computed
+    """(a) eval_loss_answer (completion-span CE) is computed
     from the same eval forward and logged beside the full-sequence eval_loss;
     with a long constant system prompt the two must differ. (b) eval_loss is
     a token-weighted sum, so a different eval_batch_size must reproduce it
-    bit-closely while cutting the number of eval dispatches (VERDICT r4 #7)."""
+    bit-closely while cutting the number of eval dispatches."""
     from llm_fine_tune_distributed_tpu.train.trainer import SFTTrainer
 
     data_dir, dataset_file = qa_parquet
@@ -257,3 +257,37 @@ def test_checkpoint_best_mode_warns_when_no_midrun_save_possible(
     capsys.readouterr()
     trainer2._resolve_best_mode()
     assert "final-weights-only" not in capsys.readouterr().out
+
+
+def test_trainer_states_a_compiler_refusal_before_it_raises(
+    qa_parquet, tmp_path, capsys
+):
+    """A step program the device cannot hold dies in the compiler with a page
+    of buffer listings. The trainer says first what it asked for (mesh,
+    microbatch, remat, loss chunk) and then lets the error through: the run
+    still fails."""
+    import jax
+
+    from llm_fine_tune_distributed_tpu.train.trainer import SFTTrainer
+
+    data_dir, dataset_file = qa_parquet
+    cfg = make_config(
+        tmp_path, data_dir, dataset_file, epochs=1, use_native_loader=False,
+        remat_policy="dots_no_batch",
+    )
+    trainer = SFTTrainer(cfg)
+
+    def refused(state, batch):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+            "memory in memory space hbm. Used 18.69G of 15.75G hbm.\nTotal hbm"
+        )
+
+    trainer.train_step = refused
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        trainer.train()
+    out = capsys.readouterr().out
+    line = next(ln for ln in out.splitlines() if ln.startswith("[train] REFUSED"))
+    assert "'fsdp': 2" in line and "per_device_batch_size=2" in line
+    assert "remat_policy='dots_no_batch'" in line
+    assert "Used 18.69G of 15.75G" in line and "Total hbm" not in line

@@ -44,7 +44,7 @@ def test_ulysses_matches_xla_causal(eight_devices):
 def test_ulysses_matches_xla_with_segments(eight_devices):
     """Packed rows through Ulysses: segment ids all-gather over the seq axis
     and the inner full-sequence kernel masks natively (packing x sequence
-    parallelism, VERDICT r3 #5)."""
+    parallelism)."""
     from tests.test_ring_attention import _segments
 
     mesh = _mesh(eight_devices, data=2, seq=4)

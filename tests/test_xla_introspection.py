@@ -11,7 +11,8 @@ What this file pins, layer by layer:
   cost analysis, or plain-jit wall timing), later calls don't re-record,
   and outputs are identical either way;
 - utilization math: ``utilization_from_cost`` clamps to [0, 1] and
-  returns 0.0 on unknowns; ``device_peak_specs`` honors env overrides;
+  returns 0.0 on unknowns; ``device_peak_specs`` knows the v5e by its real
+  ``device_kind``, gives a CPU (0, 0) and raises on an unknown accelerator;
 - the zero-recompile acceptance gate: both slot engines driven through
   mixed traffic (speculative K, two LoRA adapters, prefix hits AND
   misses, an injected crash + recovery), warm-marked, then the SAME
@@ -151,14 +152,31 @@ def test_utilization_from_cost_clamps_and_zeroes():
     assert utilization_from_cost(1e12, 1e12, 0.01, 0.0, 0.0) == (0.0, 0.0)
 
 
-def test_device_peak_specs_env_override(monkeypatch):
+class _StubDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize(
+    "device, expected",
+    [
+        # the kind libtpu really reports for a v5e; Google Cloud "TPU v5e"
+        (_StubDevice("tpu", "TPU v5 lite"), (197e12, 8.19e11)),
+        # a CPU is a known device with no roofline: (0, 0), never invented
+        (_StubDevice("cpu", "cpu"), (0.0, 0.0)),
+        (None, (0.0, 0.0)),  # the test process's own first device, a CPU
+    ],
+)
+def test_device_peak_specs_known_devices(device, expected, monkeypatch):
+    # the old environment override is gone: it must change nothing
     monkeypatch.setenv("SERVE_PEAK_FLOPS", "2e14")
-    monkeypatch.setenv("SERVE_PEAK_HBM_BPS", "8e11")
-    assert device_peak_specs() == (2e14, 8e11)
-    monkeypatch.delenv("SERVE_PEAK_FLOPS")
-    monkeypatch.delenv("SERVE_PEAK_HBM_BPS")
-    # CPU test runs have no TPU roofline: (0, 0), not an invented peak
-    assert device_peak_specs() == (0.0, 0.0)
+    assert device_peak_specs(device) == expected
+
+
+def test_device_peak_specs_unknown_accelerator_raises():
+    """An accelerator that is not in the table is an error, not a zero."""
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        device_peak_specs(_StubDevice("tpu", "TPU v9 imaginary"))
 
 
 # ------------------------------------------------------------- instrument
