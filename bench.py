@@ -167,10 +167,8 @@ def build(model_preset, per_device_batch_size, grad_accum, seq_len, attention_im
         "attention_mask": jax.device_put(np.ones((grad_accum, batch_size, seq_len), np.int32), batch_sharding),
     }
     info = {
-        "model_config": model_config,
         "frozen_compute": frozen_compute,
         "frozen_layers": frozen_layers,
-        "remat": train_config.gradient_checkpointing,
         "loss_vocab_chunk": vocab_chunk,
     }
     return mesh, state, step_fn, batch, batch_size * grad_accum, info
@@ -180,11 +178,10 @@ def measure_arm(preset, bs, accum, seq, attention_impl, loss_chunk, warmup, time
                 frozen_compute=None, vocab_chunk="env"):
     """Build + warm up + time one recipe. Returns the measured dict: the
     step is ledger-instrumented (observe/xla, AOT) so cost_analysis FLOPs
-    feed an MFU gauge, and the analytic phase split (observe/flops) turns
-    the trunk boundary into trunk_flops_fraction."""
+    feed an MFU gauge. Where the step's device time goes is measured, not
+    estimated: the step's scopes in a chip trace (PERF.md section 5)."""
     import jax
 
-    from llm_fine_tune_distributed_tpu.observe.flops import train_step_flop_split
     from llm_fine_tune_distributed_tpu.observe.xla import (
         CompileLedger,
         device_peak_specs,
@@ -220,9 +217,6 @@ def measure_arm(preset, bs, accum, seq, attention_impl, loss_chunk, warmup, time
     mfu, _bw = utilization_from_cost(
         flops, bytes_acc, step_s, peak_flops * n_chips, peak_bw * n_chips
     )
-    split = train_step_flop_split(
-        info["model_config"], seq, info["frozen_layers"], remat=info["remat"]
-    )
     return {
         "samples_per_sec_per_chip": samples_per_step * timed / elapsed / n_chips,
         "step_seconds": step_s,
@@ -230,7 +224,6 @@ def measure_arm(preset, bs, accum, seq, attention_impl, loss_chunk, warmup, time
         "effective_batch": samples_per_step,
         "n_chips": n_chips,
         "mfu": mfu,
-        "trunk_flops_fraction": split["fractions"]["trunk"],
         "frozen_compute": info["frozen_compute"],
         "frozen_layers": info["frozen_layers"],
         "loss_vocab_chunk": info["loss_vocab_chunk"],
@@ -305,7 +298,6 @@ def main():
             "samples_per_sec_per_chip_bf16": round(bf16["samples_per_sec_per_chip"], 3),
             "samples_per_sec_per_chip_int8": round(int8["samples_per_sec_per_chip"], 3),
             "frozen_layers": int8["frozen_layers"],
-            "trunk_flops_fraction": round(int8["trunk_flops_fraction"], 4),
             "trunk_matmul": os.environ.get("TRUNK_MATMUL", "xla"),
             "model": preset,
             "platform": platform,
@@ -364,7 +356,6 @@ def main():
         "loss": round(arm["loss"], 4),
         "tokens_per_sec_per_chip": round(sps_chip * seq, 1),
         "mfu": round(arm["mfu"], 6),
-        "trunk_flops_fraction": round(arm["trunk_flops_fraction"], 4),
         "frozen_compute": arm["frozen_compute"],
     }
     print(json.dumps(result))

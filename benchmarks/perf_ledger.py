@@ -223,20 +223,6 @@ def main():
         flops, bytes_acc, step_s, peak_flops, peak_bw
     )
 
-    # Analytic phase attribution (observe/flops): which share of the step's
-    # matmul FLOPs sits in the frozen trunk (forward-only under
-    # frozen_compute), the trainable tail (fwd+bwd+remat), and the loss
-    # head — the breakdown cost_analysis() totals cannot give.
-    from llm_fine_tune_distributed_tpu.observe.flops import train_step_flop_split
-
-    split = train_step_flop_split(
-        build_info["model_config"], S, build_info["frozen_layers"],
-        remat=build_info["remat"],
-    )
-    flop_shares = {
-        k: round(v, 4) for k, v in split["fractions"].items()
-    }
-
     result = {
         "metric": "perf_ledger",
         "microbatch": MB,
@@ -252,8 +238,6 @@ def main():
         "hbm_bandwidth_utilization": round(bw_util, 6),
         "frozen_compute": build_info["frozen_compute"],
         "frozen_layers": build_info["frozen_layers"],
-        "flop_shares": flop_shares,  # trunk / trainable / loss
-        "analytic_flops_per_token": round(split["total_per_token"], 1),
         "ledger": ledger,
     }
     print(json.dumps(result, indent=2))

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from llm_fine_tune_distributed_tpu.config import ModelConfig
+from llm_fine_tune_distributed_tpu.observe.xla import scope
 from llm_fine_tune_distributed_tpu.ops.attention import attention, softcap, xla_attention
 from llm_fine_tune_distributed_tpu.ops.int8 import (
     KV_QUANT_MODES,
@@ -238,225 +239,227 @@ def _block(
     zc = config.zero_centered_norm
     attn_p = lp["self_attn"]
 
-    hid = rms_norm(x, lp["input_layernorm"]["weight"], eps, zero_centered=zc)
-    q = _linear(hid, attn_p["q_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_heads, d)
-    k = _linear(hid, attn_p["k_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
-    v = _linear(hid, attn_p["v_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
+    with scope("attn"):
+        hid = rms_norm(x, lp["input_layernorm"]["weight"], eps, zero_centered=zc)
+        q = _linear(hid, attn_p["q_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_heads, d)
+        k = _linear(hid, attn_p["k_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
+        v = _linear(hid, attn_p["v_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
 
-    if config.qk_norm:
-        # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
-        q = rms_norm(q, attn_p["q_norm"]["weight"], eps)
-        k = rms_norm(k, attn_p["k_norm"]["weight"], eps)
+        if config.qk_norm:
+            # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
+            q = rms_norm(q, attn_p["q_norm"]["weight"], eps)
+            k = rms_norm(k, attn_p["k_norm"]["weight"], eps)
 
-    if rope_flag is not None:
-        qr, kr = apply_rope(q, k, cos, sin)
-        q = jnp.where(rope_flag, qr, q)
-        k = jnp.where(rope_flag, kr, k)
-    elif config.uses_rope(layer_idx):
-        q, k = apply_rope(q, k, cos, sin)
+        if rope_flag is not None:
+            qr, kr = apply_rope(q, k, cos, sin)
+            q = jnp.where(rope_flag, qr, q)
+            k = jnp.where(rope_flag, kr, k)
+        elif config.uses_rope(layer_idx):
+            q, k = apply_rope(q, k, cos, sin)
 
-    new_entry = None
-    paged_quant = None  # int8 paged pool: (ck, cv, k_scale, v_scale, pos)
-    if cache_entry is not None and block_tables is not None:
-        # Paged cache: the entry is the GLOBAL pool [num_blocks, L, kv_heads,
-        # d] and the row's block table maps logical position p to pool cell
-        # (table[p // L], p % L). Writes scatter each chunk token at its
-        # logical position through the table; reads gather the table's blocks
-        # back into one [b, nb*L] view whose index IS the logical position —
-        # so the caller's position mask applies to the view unchanged, and a
-        # row's decode cost tracks the blocks its table exposes (nb), not a
-        # global buffer ceiling. Unused table entries hold the null block
-        # (id 0): their view positions sit above every live query, hence
-        # always masked; dead rows get an all-null table from the engine so
-        # their (frozen-position) writes land in null-block garbage instead
-        # of a block since reassigned to a live row.
-        L = cache_entry["k"].shape[1]
-        nb = block_tables.shape[1]
-        offset = (
-            cache_pos[:, None] if getattr(cache_pos, "ndim", 0) == 1 else cache_pos
-        )
-        pos = jnp.broadcast_to(offset + jnp.arange(s)[None, :], (b, s))
-        # NOTE the clip: a position past the table view REDIRECTS its write
-        # into the view's LAST entry instead of dropping it (the dense branch
-        # below drops out-of-bounds scatters). Callers whose writes can run
-        # past a row's logical end — the speculative verify step writes K
-        # positions past the last accepted token — must size the table view
-        # to cover pos + K (engine-side block headroom), or live KV gets
-        # overwritten.
-        blk = jnp.take_along_axis(block_tables, jnp.clip(pos // L, 0, nb - 1), axis=1)
-        off = pos % L
-        if "k_scale" in cache_entry:
-            # Int8 pool (--quantize-kv int8): codes keep the bf16 layout's
-            # [nb, L, h, d] shape, per-(block, kv-head) absmax scales live in
-            # sibling pools indexed by the same block ids. Writes quantize at
-            # insert (growing a block's scale rescales its resident codes;
-            # untouched blocks are bit-stable — ops/int8.quantize_kv_write);
-            # reads either fuse gather+dequant+attention into the Pallas
-            # decode kernel (TPU, s == 1) or fall back to the dequantizing
-            # XLA gather below.
-            ck, k_sc = quantize_kv_write(
-                cache_entry["k"], cache_entry["k_scale"], blk, off, k
+        new_entry = None
+        paged_quant = None  # int8 paged pool: (ck, cv, k_scale, v_scale, pos)
+        if cache_entry is not None and block_tables is not None:
+            # Paged cache: the entry is the GLOBAL pool [num_blocks, L, kv_heads,
+            # d] and the row's block table maps logical position p to pool cell
+            # (table[p // L], p % L). Writes scatter each chunk token at its
+            # logical position through the table; reads gather the table's blocks
+            # back into one [b, nb*L] view whose index IS the logical position —
+            # so the caller's position mask applies to the view unchanged, and a
+            # row's decode cost tracks the blocks its table exposes (nb), not a
+            # global buffer ceiling. Unused table entries hold the null block
+            # (id 0): their view positions sit above every live query, hence
+            # always masked; dead rows get an all-null table from the engine so
+            # their (frozen-position) writes land in null-block garbage instead
+            # of a block since reassigned to a live row.
+            L = cache_entry["k"].shape[1]
+            nb = block_tables.shape[1]
+            offset = (
+                cache_pos[:, None] if getattr(cache_pos, "ndim", 0) == 1 else cache_pos
             )
-            cv, v_sc = quantize_kv_write(
-                cache_entry["v"], cache_entry["v_scale"], blk, off, v
-            )
-            new_entry = {"k": ck, "v": cv, "k_scale": k_sc, "v_scale": v_sc}
-            paged_quant = (ck, cv, k_sc, v_sc, pos)
-        else:
-            ck = cache_entry["k"].at[blk, off].set(k.astype(cache_entry["k"].dtype))
-            cv = cache_entry["v"].at[blk, off].set(v.astype(cache_entry["v"].dtype))
+            pos = jnp.broadcast_to(offset + jnp.arange(s)[None, :], (b, s))
+            # NOTE the clip: a position past the table view REDIRECTS its write
+            # into the view's LAST entry instead of dropping it (the dense branch
+            # below drops out-of-bounds scatters). Callers whose writes can run
+            # past a row's logical end — the speculative verify step writes K
+            # positions past the last accepted token — must size the table view
+            # to cover pos + K (engine-side block headroom), or live KV gets
+            # overwritten.
+            blk = jnp.take_along_axis(block_tables, jnp.clip(pos // L, 0, nb - 1), axis=1)
+            off = pos % L
+            if "k_scale" in cache_entry:
+                # Int8 pool (--quantize-kv int8): codes keep the bf16 layout's
+                # [nb, L, h, d] shape, per-(block, kv-head) absmax scales live in
+                # sibling pools indexed by the same block ids. Writes quantize at
+                # insert (growing a block's scale rescales its resident codes;
+                # untouched blocks are bit-stable — ops/int8.quantize_kv_write);
+                # reads either fuse gather+dequant+attention into the Pallas
+                # decode kernel (TPU, s == 1) or fall back to the dequantizing
+                # XLA gather below.
+                ck, k_sc = quantize_kv_write(
+                    cache_entry["k"], cache_entry["k_scale"], blk, off, k
+                )
+                cv, v_sc = quantize_kv_write(
+                    cache_entry["v"], cache_entry["v_scale"], blk, off, v
+                )
+                new_entry = {"k": ck, "v": cv, "k_scale": k_sc, "v_scale": v_sc}
+                paged_quant = (ck, cv, k_sc, v_sc, pos)
+            else:
+                ck = cache_entry["k"].at[blk, off].set(k.astype(cache_entry["k"].dtype))
+                cv = cache_entry["v"].at[blk, off].set(v.astype(cache_entry["v"].dtype))
+                new_entry = {"k": ck, "v": cv}
+                flat = block_tables.reshape(-1)
+                k = ck[flat].reshape(b, nb * L, ck.shape[2], ck.shape[3])
+                v = cv[flat].reshape(b, nb * L, cv.shape[2], cv.shape[3])
+        elif cache_entry is not None:
+            # Decode/prefill with a fixed-size KV buffer: write k,v at cache_pos.
+            # A scalar cache_pos writes the same slots for every row (single
+            # prompt / aligned batch); a [batch] vector writes per-row slots —
+            # ragged batched decode, where row i's token t lives at slot
+            # len_i + t so the slot == position invariant holds per row.
+            # Out-of-bounds slots DROP (jax scatter default): a speculative
+            # verify chunk overrunning the buffer on a slot's final tick
+            # cannot clobber other rows' live KV.
+            if getattr(cache_pos, "ndim", 0) == 1:
+                slots = cache_pos[:, None] + jnp.arange(s)[None, :]  # [b, s]
+                ck = cache_entry["k"].at[jnp.arange(b)[:, None], slots].set(
+                    k.astype(cache_entry["k"].dtype)
+                )
+                cv = cache_entry["v"].at[jnp.arange(b)[:, None], slots].set(
+                    v.astype(cache_entry["v"].dtype)
+                )
+            else:
+                ck = jax.lax.dynamic_update_slice(cache_entry["k"], k.astype(cache_entry["k"].dtype), (0, cache_pos, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cache_entry["v"], v.astype(cache_entry["v"].dtype), (0, cache_pos, 0, 0))
             new_entry = {"k": ck, "v": cv}
-            flat = block_tables.reshape(-1)
-            k = ck[flat].reshape(b, nb * L, ck.shape[2], ck.shape[3])
-            v = cv[flat].reshape(b, nb * L, cv.shape[2], cv.shape[3])
-    elif cache_entry is not None:
-        # Decode/prefill with a fixed-size KV buffer: write k,v at cache_pos.
-        # A scalar cache_pos writes the same slots for every row (single
-        # prompt / aligned batch); a [batch] vector writes per-row slots —
-        # ragged batched decode, where row i's token t lives at slot
-        # len_i + t so the slot == position invariant holds per row.
-        # Out-of-bounds slots DROP (jax scatter default): a speculative
-        # verify chunk overrunning the buffer on a slot's final tick
-        # cannot clobber other rows' live KV.
-        if getattr(cache_pos, "ndim", 0) == 1:
-            slots = cache_pos[:, None] + jnp.arange(s)[None, :]  # [b, s]
-            ck = cache_entry["k"].at[jnp.arange(b)[:, None], slots].set(
-                k.astype(cache_entry["k"].dtype)
-            )
-            cv = cache_entry["v"].at[jnp.arange(b)[:, None], slots].set(
-                v.astype(cache_entry["v"].dtype)
-            )
-        else:
-            ck = jax.lax.dynamic_update_slice(cache_entry["k"], k.astype(cache_entry["k"].dtype), (0, cache_pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache_entry["v"], v.astype(cache_entry["v"].dtype), (0, cache_pos, 0, 0))
-        new_entry = {"k": ck, "v": cv}
-        k, v = ck, cv
+            k, v = ck, cv
 
-    # Per-layer attention knobs (Gemma2: alternating local/global windows,
-    # query_pre_attn_scalar scale, logit softcap — all None for Llama-family)
-    layer_window = config.layer_sliding_window(layer_idx)
-    attn_scale = (
-        None
-        if config.query_pre_attn_scalar is None
-        else float(config.query_pre_attn_scalar) ** -0.5
-    )
-    out = None
-    if paged_quant is not None:
-        ck, cv, k_sc, v_sc, pos = paged_quant
-        from llm_fine_tune_distributed_tpu.ops.flash_attention import (
-            paged_decode_attention,
-            paged_decode_mode,
+        # Per-layer attention knobs (Gemma2: alternating local/global windows,
+        # query_pre_attn_scalar scale, logit softcap — all None for Llama-family)
+        layer_window = config.layer_sliding_window(layer_idx)
+        attn_scale = (
+            None
+            if config.query_pre_attn_scalar is None
+            else float(config.query_pre_attn_scalar) ** -0.5
         )
+        out = None
+        if paged_quant is not None:
+            ck, cv, k_sc, v_sc, pos = paged_quant
+            from llm_fine_tune_distributed_tpu.ops.flash_attention import (
+                paged_decode_attention,
+                paged_decode_mode,
+            )
 
-        mode = paged_decode_mode()
-        if (
-            mode != "xla"
-            and s == 1
-            and padding_mask is None
-            and layer_window is None
-            and config.attn_logit_softcap is None
-        ):
-            # fused Pallas kernel: block-table gather + per-block dequant +
-            # online softmax in one VMEM pass — the gathered [b, nb*L] view
-            # never materializes in HBM. Decode (s == 1) only; prefill
-            # chunks and speculative verify use the XLA gather below.
-            out = paged_decode_attention(
-                q, ck, cv, k_sc, v_sc, block_tables,
-                lengths=pos[:, 0] + 1,
-                scale=(
-                    float(attn_scale)
-                    if attn_scale is not None
-                    else float(d) ** -0.5
-                ),
-                interpret=(mode == "interpret"),
+            mode = paged_decode_mode()
+            if (
+                mode != "xla"
+                and s == 1
+                and padding_mask is None
+                and layer_window is None
+                and config.attn_logit_softcap is None
+            ):
+                # fused Pallas kernel: block-table gather + per-block dequant +
+                # online softmax in one VMEM pass — the gathered [b, nb*L] view
+                # never materializes in HBM. Decode (s == 1) only; prefill
+                # chunks and speculative verify use the XLA gather below.
+                out = paged_decode_attention(
+                    q, ck, cv, k_sc, v_sc, block_tables,
+                    lengths=pos[:, 0] + 1,
+                    scale=(
+                        float(attn_scale)
+                        if attn_scale is not None
+                        else float(d) ** -0.5
+                    ),
+                    interpret=(mode == "interpret"),
+                )
+            else:
+                k = dequantize_kv_gather(ck, k_sc, block_tables, compute_dtype)
+                v = dequantize_kv_gather(cv, v_sc, block_tables, compute_dtype)
+        if out is not None:
+            pass
+        elif explicit_mask is not None:
+            # windowed_mask carries the window restriction; a global layer (no
+            # window) uses the plain causal/padding mask
+            m = windowed_mask if (layer_window is not None and windowed_mask is not None) else explicit_mask
+            out = xla_attention(
+                q, k, v, mask=m, causal=False,
+                scale=attn_scale, logit_softcap=config.attn_logit_softcap,
             )
         else:
-            k = dequantize_kv_gather(ck, k_sc, block_tables, compute_dtype)
-            v = dequantize_kv_gather(cv, v_sc, block_tables, compute_dtype)
-    if out is not None:
-        pass
-    elif explicit_mask is not None:
-        # windowed_mask carries the window restriction; a global layer (no
-        # window) uses the plain causal/padding mask
-        m = windowed_mask if (layer_window is not None and windowed_mask is not None) else explicit_mask
-        out = xla_attention(
-            q, k, v, mask=m, causal=False,
-            scale=attn_scale, logit_softcap=config.attn_logit_softcap,
-        )
-    else:
-        out = attention(
-            q,
-            k,
-            v,
-            impl=attention_impl,
-            padding_mask=padding_mask,
-            segment_ids=segment_ids,
-            causal=True,
-            sliding_window=layer_window,
-            mesh=mesh,
-            scale=attn_scale,
-            logit_softcap=config.attn_logit_softcap,
-        )
+            out = attention(
+                q,
+                k,
+                v,
+                impl=attention_impl,
+                padding_mask=padding_mask,
+                segment_ids=segment_ids,
+                causal=True,
+                sliding_window=layer_window,
+                mesh=mesh,
+                scale=attn_scale,
+                logit_softcap=config.attn_logit_softcap,
+            )
 
-    out = out.reshape(b, s, config.num_heads * d)
-    attn_out = _linear(out, attn_p["o_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-    if config.sandwich_norms:
-        # Gemma2: post_attention_layernorm norms the attention OUTPUT
-        attn_out = rms_norm(
-            attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc
-        )
-    x = x + attn_out
-
-    pre_ffn = (
-        "pre_feedforward_layernorm" if config.sandwich_norms
-        else "post_attention_layernorm"
-    )
-    hid = rms_norm(x, lp[pre_ffn]["weight"], eps, zero_centered=zc)
-    aux = jnp.float32(0.0)
-    if config.num_experts > 0:
-        from llm_fine_tune_distributed_tpu.ops.moe import moe_mlp
-
-        # token-level real/pad mask for routing: packed batches encode pads
-        # as segment 0; the cache path's padding_mask covers the KV buffer
-        # (wrong length for the current chunk) and is skipped
-        token_mask = None
-        if segment_ids is not None:
-            token_mask = segment_ids > 0
-        elif padding_mask is not None and padding_mask.shape[-1] == s:
-            token_mask = padding_mask
-        moe_out, aux = moe_mlp(
-            lp["block_sparse_moe"], hid, config, compute_dtype, mesh=mesh,
-            token_mask=token_mask,
-            # decode/prefill (KV cache live) is dropless like HF Mixtral:
-            # capacity drops would make outputs depend on batch/chunk shape
-            dropless=cache_entry is not None,
-        )
+        out = out.reshape(b, s, config.num_heads * d)
+        attn_out = _linear(out, attn_p["o_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
         if config.sandwich_norms:
-            moe_out = rms_norm(
-                moe_out, lp["post_feedforward_layernorm"]["weight"], eps,
-                zero_centered=zc,
+            # Gemma2: post_attention_layernorm norms the attention OUTPUT
+            attn_out = rms_norm(
+                attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc
             )
-        x = x + moe_out
-    else:
-        gate = _linear(hid, lp["mlp"]["gate_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-        up = _linear(hid, lp["mlp"]["up_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-        # Named so remat_policy="mlp" can save JUST this [b, s, f] product: the
-        # gate/up matmuls are ~58% of a block's param FLOPs, so saving their
-        # fused output avoids most of full-remat's recompute at one tensor per
-        # layer of extra HBM (vs. two for saving gate and up separately).
-        if config.hidden_act == "gelu_tanh":
-            act = jax.nn.gelu(gate.astype(jnp.float32), approximate=True).astype(gate.dtype)
-        elif config.hidden_act == "gelu":
-            act = jax.nn.gelu(gate.astype(jnp.float32), approximate=False).astype(gate.dtype)
+        x = x + attn_out
+
+    with scope("mlp"):
+        pre_ffn = (
+            "pre_feedforward_layernorm" if config.sandwich_norms
+            else "post_attention_layernorm"
+        )
+        hid = rms_norm(x, lp[pre_ffn]["weight"], eps, zero_centered=zc)
+        aux = jnp.float32(0.0)
+        if config.num_experts > 0:
+            from llm_fine_tune_distributed_tpu.ops.moe import moe_mlp
+
+            # token-level real/pad mask for routing: packed batches encode pads
+            # as segment 0; the cache path's padding_mask covers the KV buffer
+            # (wrong length for the current chunk) and is skipped
+            token_mask = None
+            if segment_ids is not None:
+                token_mask = segment_ids > 0
+            elif padding_mask is not None and padding_mask.shape[-1] == s:
+                token_mask = padding_mask
+            moe_out, aux = moe_mlp(
+                lp["block_sparse_moe"], hid, config, compute_dtype, mesh=mesh,
+                token_mask=token_mask,
+                # decode/prefill (KV cache live) is dropless like HF Mixtral:
+                # capacity drops would make outputs depend on batch/chunk shape
+                dropless=cache_entry is not None,
+            )
+            if config.sandwich_norms:
+                moe_out = rms_norm(
+                    moe_out, lp["post_feedforward_layernorm"]["weight"], eps,
+                    zero_centered=zc,
+                )
+            x = x + moe_out
         else:
-            act = jax.nn.silu(gate)
-        prod = checkpoint_name(act * up, "mlp_act")
-        mlp_out = _linear(prod, lp["mlp"]["down_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-        if config.sandwich_norms:
-            mlp_out = rms_norm(
-                mlp_out, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc
-            )
-        x = x + mlp_out
+            gate = _linear(hid, lp["mlp"]["gate_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
+            up = _linear(hid, lp["mlp"]["up_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
+            # Named so remat_policy="mlp" can save JUST this [b, s, f] product: the
+            # gate/up matmuls are ~58% of a block's param FLOPs, so saving their
+            # fused output avoids most of full-remat's recompute at one tensor per
+            # layer of extra HBM (vs. two for saving gate and up separately).
+            if config.hidden_act == "gelu_tanh":
+                act = jax.nn.gelu(gate.astype(jnp.float32), approximate=True).astype(gate.dtype)
+            elif config.hidden_act == "gelu":
+                act = jax.nn.gelu(gate.astype(jnp.float32), approximate=False).astype(gate.dtype)
+            else:
+                act = jax.nn.silu(gate)
+            prod = checkpoint_name(act * up, "mlp_act")
+            mlp_out = _linear(prod, lp["mlp"]["down_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
+            if config.sandwich_norms:
+                mlp_out = rms_norm(
+                    mlp_out, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc
+                )
+            x = x + mlp_out
     return x, new_entry, aux
 
 
@@ -557,26 +560,27 @@ def forward(
     if activation_sharding is not None:
         mesh = getattr(activation_sharding, "mesh", None)
 
-    embed = params["model"]["embed_tokens"]["weight"].astype(compute_dtype)
-    if mesh is not None and (
-        dict(mesh.shape).get("tensor", 1) > 1 or dict(mesh.shape).get("data", 1) > 1
-    ):
-        # Embedding-lookup layout: shard the table by vocab (tensor, else
-        # fsdp) and gather the hidden dim. FSDP shards the table's hidden dim
-        # with the same mesh axis that shards the ids' batch dim; on tensor>1
-        # or data>1 meshes GSPMD resolves that conflict by replicating the
-        # gather output and repartitioning it ("involuntary full
-        # rematerialization", spmd_partitioner.cc warnings). With the table
-        # vocab-sharded, each device gathers from its vocab shard (masked +
-        # psum) and the output lands directly on the activation layout.
-        # (1, fsdp, 1, *) meshes reshard the (small) gather output cleanly
-        # without help, so they skip this.
-        embed = _lookup_table_constraint(embed, mesh)
-    x = constrain(embed[input_ids])
-    if config.embed_scale:
-        # Gemma normalizer: HF multiplies by a sqrt(hidden) scalar cast to
-        # the activation dtype first — mirror the cast for bf16 bit-parity
-        x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
+    with scope("embed"):
+        embed = params["model"]["embed_tokens"]["weight"].astype(compute_dtype)
+        if mesh is not None and (
+            dict(mesh.shape).get("tensor", 1) > 1 or dict(mesh.shape).get("data", 1) > 1
+        ):
+            # Embedding-lookup layout: shard the table by vocab (tensor, else
+            # fsdp) and gather the hidden dim. FSDP shards the table's hidden dim
+            # with the same mesh axis that shards the ids' batch dim; on tensor>1
+            # or data>1 meshes GSPMD resolves that conflict by replicating the
+            # gather output and repartitioning it ("involuntary full
+            # rematerialization", spmd_partitioner.cc warnings). With the table
+            # vocab-sharded, each device gathers from its vocab shard (masked +
+            # psum) and the output lands directly on the activation layout.
+            # (1, fsdp, 1, *) meshes reshard the (small) gather output cleanly
+            # without help, so they skip this.
+            embed = _lookup_table_constraint(embed, mesh)
+        x = constrain(embed[input_ids])
+        if config.embed_scale:
+            # Gemma normalizer: HF multiplies by a sqrt(hidden) scalar cast to
+            # the activation dtype first — mirror the cast for bf16 bit-parity
+            x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
     cos, sin = rope_cos_sin(
         positions, config.resolved_head_dim, config.rope_theta, config=config
     )
@@ -679,17 +683,18 @@ def forward(
                         f"'full', {sorted(policies)}"
                     )
                 block_fn = jax.checkpoint(block_fn, policy=policies[remat_policy])
-        x, new_entry, layer_aux = block_fn(
-            params["model"]["layers"][str(i)],
-            x,
-            cos,
-            sin,
-            padding_mask,
-            segment_ids,
-            explicit_mask,
-            entry,
-            cache_pos,
-        )
+        with scope("layer", i):
+            x, new_entry, layer_aux = block_fn(
+                params["model"]["layers"][str(i)],
+                x,
+                cos,
+                sin,
+                padding_mask,
+                segment_ids,
+                explicit_mask,
+                entry,
+                cache_pos,
+            )
         x = constrain(x)
         if in_trunk and i == trunk_layers - 1:
             # trunk/trainable boundary: the only gradient path through the
@@ -702,20 +707,22 @@ def forward(
         if new_entry is not None:
             new_layers[str(i)] = new_entry
 
-    x = rms_norm(
-        x,
-        params["model"]["norm"]["weight"],
-        config.rms_norm_eps,
-        zero_centered=config.zero_centered_norm,
-    )
+    with scope("final_norm"):
+        x = rms_norm(
+            x,
+            params["model"]["norm"]["weight"],
+            config.rms_norm_eps,
+            zero_centered=config.zero_centered_norm,
+        )
 
     new_cache = {"layers": new_layers} if cache is not None else None
     if output_hidden:
         out = x.astype(compute_dtype)
     else:
-        out = unembed(
-            params, x, config, compute_dtype=compute_dtype, logits_dtype=logits_dtype, mesh=mesh
-        )
+        with scope("loss_head"):  # the full-logits path: train/step.py scopes its cross-entropy alike
+            out = unembed(
+                params, x, config, compute_dtype=compute_dtype, logits_dtype=logits_dtype, mesh=mesh
+            )
     if return_aux:
         return out, new_cache, moe_aux
     return out, new_cache

@@ -32,6 +32,10 @@ This module makes that contract observable:
   the requested duration, a fresh subdirectory per capture.
 - ``annotate()`` yields ``jax.profiler.TraceAnnotation`` spans so tick
   phases (admit/prefill/verify/sample) line up with captured traces.
+- ``scope()`` names the train step's operations (``STEP_SCOPES``) with
+  ``jax.named_scope``: a device trace then carries, on every operation, the
+  path of scopes it was traced under, and a reader can say which part of
+  the step the device's time went to.
 """
 
 from __future__ import annotations
@@ -49,9 +53,11 @@ __all__ = [
     "CaptureBusyError",
     "CompileLedger",
     "ProfilerCapture",
+    "STEP_SCOPES",
     "annotate",
     "device_peak_specs",
     "instrument",
+    "scope",
     "utilization_from_cost",
 ]
 
@@ -451,3 +457,21 @@ def annotate(name: str):
         return jax.profiler.TraceAnnotation(name)
     except Exception:
         return contextlib.nullcontext()
+
+
+# The train step's scope vocabulary. ``layer`` takes the layer's index
+# (``layer0`` ... ``layer35``); ``attn`` and ``mlp`` sit inside a layer. A
+# scope is metadata on the traced operations (the ``op_name`` XLA keeps, the
+# ``tf_op`` of a device trace) and costs nothing when no trace is taken.
+# Forward, backward and recompute are not scopes: JAX writes them into the
+# path itself (``jvp(layer3)``, ``transpose(jvp(layer3))``,
+# ``rematted_computation``).
+STEP_SCOPES = (
+    "embed", "layer", "attn", "mlp", "final_norm", "loss_head", "grad_accum", "optimizer",
+)
+
+
+def scope(name: str, index: Optional[int] = None):
+    """``jax.named_scope`` for one name of ``STEP_SCOPES``."""
+    assert name in STEP_SCOPES, name
+    return jax.named_scope(name if index is None else f"{name}{index}")

@@ -22,6 +22,7 @@ import optax
 
 from llm_fine_tune_distributed_tpu.config import ModelConfig, TrainConfig, str_to_dtype
 from llm_fine_tune_distributed_tpu.models.transformer import forward, unembed
+from llm_fine_tune_distributed_tpu.observe.xla import scope
 from llm_fine_tune_distributed_tpu.train.state import TrainState
 from llm_fine_tune_distributed_tpu.utils.tree import merge_flat
 
@@ -263,26 +264,29 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
         if "completion_mask" in batch:
             amask = batch["completion_mask"][:, 1:].astype(jnp.float32)
         # ce_fn(mask) -> sum; ce_fn(mask, extra) -> (sum, extra_sum) from a
-        # SINGLE unembed on every path
-        if vocab_chunk is not None:
-            ce_fn = lambda m, e=None: vocab_chunked_ce_sum(
-                params, out[:, :-1], targets, m, model_config, vocab_chunk,
-                compute_dtype, mesh=_mesh_kw, extra_mask=e,
-            )
-        elif chunk is not None:
-            ce_fn = lambda m, e=None: chunked_ce_sum(
-                params, out[:, :-1], targets, m, model_config, chunk,
-                compute_dtype, mesh=_mesh_kw, extra_mask=e,
-            )
-        else:
-            ce = optax.softmax_cross_entropy_with_integer_labels(out[:, :-1], targets)
-            ce_fn = lambda m, e=None: (
-                (ce * m).sum() if e is None else ((ce * m).sum(), (ce * e).sum())
-            )
-        if amask is not None:
-            ce_sum, ans_sum = ce_fn(mask, amask)
-        else:
-            ce_sum = ce_fn(mask)
+        # SINGLE unembed on every path. One scope for the three of them (on
+        # the full-logits path the unembed itself ran inside forward, under
+        # the same name).
+        with scope("loss_head"):
+            if vocab_chunk is not None:
+                ce_fn = lambda m, e=None: vocab_chunked_ce_sum(
+                    params, out[:, :-1], targets, m, model_config, vocab_chunk,
+                    compute_dtype, mesh=_mesh_kw, extra_mask=e,
+                )
+            elif chunk is not None:
+                ce_fn = lambda m, e=None: chunked_ce_sum(
+                    params, out[:, :-1], targets, m, model_config, chunk,
+                    compute_dtype, mesh=_mesh_kw, extra_mask=e,
+                )
+            else:
+                ce = optax.softmax_cross_entropy_with_integer_labels(out[:, :-1], targets)
+                ce_fn = lambda m, e=None: (
+                    (ce * m).sum() if e is None else ((ce * m).sum(), (ce * e).sum())
+                )
+            if amask is not None:
+                ce_sum, ans_sum = ce_fn(mask, amask)
+            else:
+                ce_sum = ce_fn(mask)
         loss = ce_sum / tokens
         if want_aux:
             # layer-MEAN of the per-layer aux (forward returns the sum), so
@@ -325,19 +329,23 @@ def build_train_step(
         def micro_step(carry, micro):
             g_acc, loss_acc = carry
             (loss, _tokens), grads = grad_fn(state.trainable, state.frozen, micro)
-            g_acc = jax.tree.map(jnp.add, g_acc, grads)
+            with scope("grad_accum"):
+                g_acc = jax.tree.map(jnp.add, g_acc, grads)
             return (g_acc, loss_acc + loss), None
 
-        zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.trainable)
+        with scope("grad_accum"):
+            zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.trainable)
         (g_sum, loss_sum), _ = jax.lax.scan(micro_step, (zeros, jnp.float32(0.0)), batch)
 
         # Mean over accumulation steps (HF semantics: mean of microbatch means).
-        grads = jax.tree.map(lambda g: g / accum, g_sum)
+        with scope("grad_accum"):
+            grads = jax.tree.map(lambda g: g / accum, g_sum)
         loss = loss_sum / accum
 
-        grad_norm = optax.global_norm(grads)  # pre-clip, matches HF's logged grad_norm
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.trainable)
-        new_trainable = optax.apply_updates(state.trainable, updates)
+        with scope("optimizer"):
+            grad_norm = optax.global_norm(grads)  # pre-clip, matches HF's logged grad_norm
+            updates, new_opt_state = optimizer.update(grads, state.opt_state, state.trainable)
+            new_trainable = optax.apply_updates(state.trainable, updates)
 
         new_state = state.replace(
             step=state.step + 1,

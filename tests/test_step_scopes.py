@@ -1,0 +1,166 @@
+"""The train step names its device time from inside the program: every scope
+of ``observe/xla.STEP_SCOPES`` reaches the compiled step's ``op_name``s (what a
+device trace carries as ``tf_op``), JAX writes forward, backward and recompute
+into the same path, and ``benchmarks/chipbench/readers/scopes.py`` classifies
+what comes out. One tiny step is lowered and compiled once for the module; the
+three loss paths are compiled as the gradient of ``make_loss_fn`` alone.
+
+Scopes are debug information only: the lowering without it does not hold them,
+which is why a persistent compile cache keyed without debug information (JAX's
+default) can hand a scoped program the executable of an unscoped one (PERF.md
+section 6, PR 24).
+"""
+
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from llm_fine_tune_distributed_tpu.config import TrainConfig
+from llm_fine_tune_distributed_tpu.models.configs import get_preset
+from llm_fine_tune_distributed_tpu.models.transformer import init_params
+from llm_fine_tune_distributed_tpu.observe.xla import STEP_SCOPES, scope
+from llm_fine_tune_distributed_tpu.parallel.freeze import trainable_mask
+from llm_fine_tune_distributed_tpu.train.state import TrainState
+from llm_fine_tune_distributed_tpu.train.step import build_train_step, make_loss_fn
+from llm_fine_tune_distributed_tpu.utils.tree import flatten_dict
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmarks.chipbench.readers import scopes as reader  # noqa: E402
+
+MC = get_preset("tiny")  # 4 layers; last_n_and_head unfreezes 2: layers 0, 1 frozen, tied table trainable
+FIRST_TRAINABLE = MC.num_layers - 2
+ACCUM, BATCH, SEQ = 2, 2, 32
+
+
+def _config(**loss_path):
+    return TrainConfig(
+        model_preset="tiny", compute_dtype="float32", gradient_checkpointing=True,
+        remat_policy="dots_no_batch", per_device_batch_size=BATCH,
+        gradient_accumulation_steps=ACCUM, max_seq_length=SEQ, **loss_path,
+    )
+
+
+def _split(tc):
+    params = init_params(jax.random.PRNGKey(0), MC, dtype=jnp.float32)
+    mask = flatten_dict(trainable_mask(params, MC, tc))
+    flat = flatten_dict(params)
+    return ({k: v for k, v in flat.items() if mask[k]}, {k: v for k, v in flat.items() if not mask[k]})
+
+
+def _batch(lead=()):
+    shape = (*lead, BATCH, SEQ)
+    ids = np.random.RandomState(3).randint(0, MC.vocab_size, shape).astype(np.int32)
+    return {"input_ids": jnp.asarray(ids), "loss_mask": jnp.ones(shape, jnp.float32),
+            "attention_mask": jnp.ones(shape, jnp.int32)}
+
+
+def _op_names(hlo_text):
+    return re.findall(r'op_name="([^"]+)"', hlo_text)
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    tc = _config(loss_vocab_chunk=64)  # the flagship recipe's loss path
+    trainable, frozen = _split(tc)
+    optimizer = optax.adamw(1e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), trainable=trainable, frozen=frozen,
+                       opt_state=optimizer.init(trainable))
+    return jax.jit(build_train_step(MC, tc, optimizer)).lower(state, _batch((ACCUM,)))
+
+
+@pytest.fixture(scope="module")
+def paths(lowered):
+    """The ``op_name`` of every instruction of the compiled step: the whole
+    path, as the chip's trace carries it."""
+    return _op_names(lowered.compile().as_text())
+
+
+def _components(path):
+    return [reader.bare(c) for c in path.split("/")]
+
+
+@pytest.mark.parametrize("name", STEP_SCOPES)
+def test_every_scope_of_the_vocabulary_is_named(paths, name):
+    want = re.compile(r"^layer\d+$") if name == "layer" else re.compile(f"^{name}$")
+    assert any(want.match(c) for p in paths for c in _components(p)), name
+
+
+def test_every_layer_has_its_own_index_with_attn_and_mlp_inside(paths):
+    for i in range(MC.num_layers):
+        inside = set()
+        for components in map(_components, paths):
+            if f"layer{i}" in components:
+                inside.update(components[components.index(f"layer{i}") + 1:])
+        assert {"attn", "mlp"} <= inside, (i, inside)
+
+
+def test_scopes_are_debug_information_only(lowered):
+    """Without debug information the lowering names no scope: the scopes
+    change no operation (and a cache key that strips it cannot see them)."""
+    bare = lowered.as_text()
+    for name in ("loss_head", "final_norm", "grad_accum", "optimizer", "layer0"):
+        assert name not in bare
+    assert "loss_head" in lowered.as_text(debug_info=True)
+
+
+def test_scope_helper_takes_only_the_vocabulary():
+    with pytest.raises(AssertionError):
+        scope("trunk")
+
+
+def test_a_trainable_layer_occurs_under_transpose(paths):
+    last = f"layer{MC.num_layers - 1}"
+    assert any(p for p in paths if reader.BACKWARD in p and f"jvp({last})" in p)
+    # ...and forward: the same scope on a path with no transpose
+    assert any(p for p in paths if reader.BACKWARD not in p and f"jvp({last})" in p)
+
+
+def test_a_recomputed_operation_carries_the_readers_marker(paths):
+    # (a reducer's sub-computation keeps a relative name: whole paths only)
+    remat = [p for p in paths if reader.RECOMPUTED in p and p.startswith("jit(train_step)")]
+    assert remat, "jax.checkpoint no longer writes rematted_computation into the path"
+    assert all(reader.BACKWARD in p for p in remat)  # recompute runs in the backward pass
+    assert any("layer" in p for p in remat) and any("loss_head" in p for p in remat)
+
+
+@pytest.mark.parametrize("loss_path", [{"loss_vocab_chunk": 64}, {"loss_chunk_size": 16}, {}],
+                         ids=["vocab_chunked", "seq_chunked", "full_logits"])
+def test_loss_head_is_scoped_on_every_loss_path(loss_path):
+    tc = _config(**loss_path)
+    trainable, frozen = _split(tc)
+    grad = jax.jit(jax.grad(lambda t, f, b: make_loss_fn(MC, tc)(t, f, b)[0]))
+    names = _op_names(grad.lower(trainable, frozen, _batch()).compile().as_text())
+    head = [n for n in names if "loss_head" in n]
+    assert any(n.endswith("dot_general") for n in head), "the unembed matmul is outside loss_head"
+    assert any(reader.BACKWARD in n for n in head)
+    assert any("final_norm" in n for n in names)
+
+
+@pytest.mark.parametrize("cls,backward,recomputed", [
+    ("frozen", False, False), ("frozen", True, False), ("frozen", True, True),
+    ("tail", False, False), ("tail", True, False), ("tail", True, True),
+    ("loss_head", False, False), ("loss_head", True, False), ("loss_head", True, True),
+    ("optimizer", False, False), ("embed", False, False), ("embed", True, False),
+])
+def test_the_reader_finds_each_class_in_the_compiled_step(paths, cls, backward, recomputed):
+    """Tied table, two frozen layers: every class the SmolLM3 cell reads
+    exists here, the frozen layers' activation gradients included."""
+    found = {reader.classify(p, FIRST_TRAINABLE) for p in paths}
+    assert (cls, backward, recomputed) in found
+
+
+def test_most_of_the_compiled_step_is_scoped(paths):
+    """What stays unscoped is bookkeeping (loop counters, the batch's
+    slices, rope's tables, the causal mask); a scope that falls off the
+    step shows here before it shows on the chip."""
+    classes = [reader.classify(p, FIRST_TRAINABLE)[0] for p in paths if p.startswith("jit(train_step)")]
+    assert sum(c is not None for c in classes) / len(classes) > 0.75
