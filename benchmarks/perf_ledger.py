@@ -11,6 +11,8 @@ sum of parts and the whole is XLA's fusion dividend (or overhead).
 
 Usage (real TPU):
     python benchmarks/perf_ledger.py            # full ledger, one JSON line
+    python benchmarks/perf_ledger.py --flash-only [BLOCK ...]   # the three flash kernels apart at both
+        # cells' shapes, one JSON line for the kernel's own tile and one for each FLASH_BLOCK value given
 Env: LEDGER_REPS (default 20), LEDGER_MB (microbatch, default 2).
 """
 
@@ -71,7 +73,74 @@ def _grad_time(fn, *args, reps=None):
     return _time(fwd_bwd, cot, *args, reps=reps)
 
 
+# the attention call of one microbatch in each training cell of BENCHMARK.json:
+# (rows, seq, q heads, kv heads, head size)
+FLASH_SHAPES = {
+    "smollm3-3b.sft-1k-full": (2, 1024, 16, 4, 128),
+    "mistral-7b-d16.sft-2k-full": (1, 2048, 32, 8, 128),
+}
+FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv")
+
+
+def flash_kernels(reps=None):
+    """Device time of the forward, dq and dk/dv kernels apart, read from a
+    profiler trace of ``reps`` forward+backward calls by the kernels' own
+    names (the reduction the benchmark uses, ``chipbench/trace.py``), and each
+    kernel's share of the bfloat16 roofline by ``chipbench/flops.py``'s count:
+    causal attention at half the square, recomputation not counted, so the
+    backward's two halves (dO V^T and dS K in dq, P^T dO and dS^T q in dk/dv)
+    each owe what the forward owes."""
+    import tempfile
+
+    from benchmarks.chipbench import flops, trace
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import (
+        pallas_flash_attention,
+    )
+
+    reps = reps or int(os.environ.get("LEDGER_REPS", "20"))
+    kind = jax.devices()[0].device_kind
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "chipbench", "peaks.json")) as f:
+        peaks = json.load(f)[kind]  # a device without published peaks is an error
+    rng = np.random.RandomState(0)
+    out = {}
+    for cell, (b, s, hq, hkv, d) in FLASH_SHAPES.items():
+        q, k, v, cot = (
+            jnp.asarray(rng.randn(b, s, h, d), jnp.bfloat16) for h in (hq, hkv, hkv, hq)
+        )
+
+        @jax.jit
+        def fwd_bwd(q, k, v, cot):
+            _, vjp = jax.vjp(pallas_flash_attention, q, k, v)
+            return vjp(cot)
+
+        jax.block_until_ready(fwd_bwd(q, k, v, cot))
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for _ in range(reps):
+                    grads = fwd_bwd(q, k, v, cot)
+                jax.block_until_ready(grads)
+            reduced = trace.reduce_dir(tmp)
+        if reduced is None:
+            raise SystemExit("perf_ledger: the trace holds no device plane (not a TPU?)")
+        bound = flops.roofline_seconds(flops.flash_fwd_cost(b, s, hq, hkv, d), peaks)
+        entry = {"shape": [b, s, hq, hkv, d], "roofline_bound": bound["bound"]}
+        for name in FLASH_KERNELS:
+            secs, calls = trace.kernel_seconds(reduced, name)
+            entry[name] = {
+                "ms": round(1e3 * secs / calls, 4),
+                "calls": calls,
+                "roofline_pct": round(100.0 * bound["seconds"] * calls / secs, 2),
+            }
+        out[cell] = entry
+    return {"device_kind": kind, "flash_block": os.environ.get("FLASH_BLOCK", ""), "cells": out}
+
+
 def main():
+    if "--flash-only" in sys.argv[1:]:
+        for block in [""] + [a for a in sys.argv[1:] if a.isdigit()]:
+            os.environ["FLASH_BLOCK"] = block  # the kernel's sweep knob, read when it is traced
+            print(json.dumps({"metric": "flash_kernels", **flash_kernels()}), flush=True)
+        return
     from llm_fine_tune_distributed_tpu.ops.flash_attention import (
         pallas_flash_attention,
     )
@@ -141,6 +210,7 @@ def main():
     t = _time(lambda a, b_, c: pallas_flash_attention(a, b_, c), q, k, v)
     tb = _grad_time(lambda a, b_, c: pallas_flash_attention(a, b_, c), q, k, v)
     entry("flash_attention", t, tb, per_layer, per_layer, remat_refwd=True)
+    ledger["flash_attention"]["kernels"] = flash_kernels()["cells"]
 
     t = _time(lambda a, w: a @ w, x, w_qkv)
     tb = _grad_time(lambda a, w: a @ w, x, w_qkv)

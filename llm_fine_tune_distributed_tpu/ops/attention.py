@@ -43,6 +43,10 @@ _DISPATCH_COUNTS: collections.Counter = collections.Counter()
 # never a rescue from a kernel that failed to compile — that raises.
 _FLASH_FALLBACK_REASONS: collections.Counter = collections.Counter()
 
+# The dtypes q, k, v had where the flash kernel was traced (what its matmuls
+# are handed is the kernel's MXU_OPERAND_DTYPE, printed beside them).
+_FLASH_INPUT_DTYPES: set = set()
+
 
 def dispatch_count(impl: str) -> int:
     """How many attention() calls resolved to ``impl`` (trace-time count)."""
@@ -52,7 +56,15 @@ def dispatch_count(impl: str) -> int:
 def dispatch_summary() -> str:
     """One line for entry points to print: the paths traced so far and, for
     each flash request that took XLA attention, why."""
-    paths = ", ".join(f"{k}={v}" for k, v in sorted(_DISPATCH_COUNTS.items()))
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import MXU_OPERAND_DTYPE
+
+    dtypes = {
+        "flash": f" ({'/'.join(sorted(_FLASH_INPUT_DTYPES))} inputs, "
+        f"{jnp.dtype(MXU_OPERAND_DTYPE).name} MXU operands)"
+    }
+    paths = ", ".join(
+        f"{k}={v}{dtypes.get(k, '')}" for k, v in sorted(_DISPATCH_COUNTS.items())
+    )
     why = "; ".join(f"{r} (x{n})" for r, n in _FLASH_FALLBACK_REASONS.items())
     return f"attention paths traced: {paths or 'none'}" + (
         f" | flash -> xla because: {why}" if why else ""
@@ -301,6 +313,7 @@ def attention(
             )
         if reason is None:
             _DISPATCH_COUNTS["flash"] += 1
+            _FLASH_INPUT_DTYPES.add(jnp.dtype(q.dtype).name)
             if not sharded:
                 return pallas_flash_attention(
                     q, k, v, padding_mask=padding_mask, segment_ids=segment_ids

@@ -12,7 +12,27 @@ Formulation (FlashAttention-2 style):
   bwd   delta = rowsum(dO * O); then
         dq  per (batch, q_head, q_block):   ds = p * (dO V^T - delta); dq = ds K
         dk/dv per (batch, KV head, k_block): dv += p^T dO; dk += ds^T q,
-        accumulated over the KV head's query group inside the kernel.
+        accumulated over the KV head's query group inside the kernel, on
+        transposed scores [keys, queries] so that no tile is transposed.
+
+Operands and accumulators: every matmul of the three kernels takes both
+operands in ``MXU_OPERAND_DTYPE`` (float32: q, k, v, dO cast up from the dtype
+they arrive in, p and dS as computed) and accumulates in float32; scores,
+scale, softmax statistics, lse, delta and the dq/dk/dv accumulators are
+float32 throughout; only the stores round to the input dtype. Why float32
+operands are the fast ones on this chip is said at ``MXU_OPERAND_DTYPE``.
+
+Tiles: a sequence is cut into blocks (``_pick_block``: the whole sequence up
+to 2048, else at most 1024). A block pair wholly below the diagonal is one
+[block, block] tile; the pair on the diagonal is cut into strips of 256 rows
+(``_diagonal_pieces``), each as wide as the causal mask lets it see, so what
+lies above the diagonal is left out up to the strips' own corners.
+
+Masking: a tile does only the masking it needs. The causal test is made in
+the strips on the diagonal alone; the same-segment test in programs whose
+tiles hold more than one segment id (a per-program flag in SMEM, computed from
+the segment ids outside the kernel: one branch a program, none in the tile
+loop).
 
 GQA is handled by BlockSpec index maps (K/V indexed with ``head // groups``
 in fwd/dq; q/dO indexed per-group in dk/dv) — K/V are never repeated in HBM
@@ -81,117 +101,243 @@ def _block_specs(operands):
 
 
 # ---------------------------------------------------------------------------
+# shared by the three kernels: operand dtype, masks, the diagonal's strips
+# ---------------------------------------------------------------------------
+
+
+# What the ten dot_generals of the three kernels are handed: q, k, v and dO
+# cast up from the dtype they arrive in, p and dS as the float32 they are
+# computed in, accumulation in float32. At the default matmul precision the
+# MXU rounds a float32 operand to bfloat16 as it takes it in, one pass, at
+# the same rows a cycle as a bfloat16 operand: the cast up costs the MXU
+# nothing, while bfloat16 operands cost the vector unit, which sets the
+# kernels' pace, a pack of every p and dS tile and an unpack and repack of
+# every K and V tile (measured on a v5e, PR 25: forward 5%, dq 13% slower).
+MXU_OPERAND_DTYPE = jnp.float32
+
+
+def _operand(x):
+    return x.astype(MXU_OPERAND_DTYPE)
+
+
+def _tile_mask(q_seg, k_seg, q_start, k_start, shape, *, segments, on_diagonal, q_axis=0):
+    """Bool tile of ``shape``, True = attend, or None where the tile needs no
+    mask at all. Queries from ``q_start`` run along axis ``q_axis`` and keys
+    from ``k_start`` along the other; ``q_seg`` and ``k_seg`` are their
+    segment ids, one a column and one a row (read only under ``segments``).
+    Each test is made only where a tile can fail it (both flags are static):
+
+    ``segments``: the same-segment test, which packing needs and which
+    subsumes padding: pad queries (seg 0) attend only the pad tail (incl.
+    themselves at k == q, keeping softmax finite), real queries never see pad
+    keys or other segments. Tiles whose queries and keys all carry one id
+    pass it everywhere and skip it.
+    ``on_diagonal``: the causal test, needed only where the diagonal crosses
+    the tile; a tile wholly below it skips the two iotas and the compare."""
+    mask = (q_seg() == k_seg()) if segments else None
+    if on_diagonal:
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+        causal = k_pos <= q_pos
+        mask = causal if mask is None else causal & mask
+    return mask
+
+
+def _keep(mask, x, fill):
+    return x if mask is None else jnp.where(mask, x, fill)
+
+
+def _with_or_without_segments(one_segment, program):
+    """Run ``program(segments=...)``, the whole of a kernel program, without
+    the segment test where ``one_segment`` (a scalar read from SMEM) says
+    that every tile it visits holds a single segment id. One branch a
+    program: a branch inside the tile loop costs more than the test saves."""
+    pl.when(one_segment != 0)(functools.partial(program, segments=False))
+    pl.when(one_segment == 0)(functools.partial(program, segments=True))
+
+
+def _diagonal_pieces(block, *, own):
+    """The block x block tile on the diagonal, cut into strips so that most
+    of what lies above the diagonal is never computed: ``(own_at, own_n,
+    other_at, other_n)`` of each piece, offsets from the tile's corner.
+    ``own`` is the axis a kernel accumulates along, cut into strips of 256
+    (of 128 where 256 does not divide the block); each strip meets only the
+    part of the other axis the causal mask lets it see. By queries (forward,
+    dq): a strip of queries meets the keys up to its own end. By keys
+    (dk/dv): a strip of keys meets the queries from its own start. On a v5e
+    (PR 25) strips of 256 beat 128 and 512 in dq and dk/dv and tied with 512
+    in the forward."""
+    n = 256 if block % 256 == 0 else 128
+    if own == "queries":
+        return [(at, n, 0, at + n) for at in range(0, block, n)]
+    return [(at, n, at, block - at) for at in range(0, block, n)]
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(seg_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, groups):
-    iq = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)  # [BQ, d]
-    bq, d = q.shape
-    q_start = iq * bq
-    # 0 = padding, >0 = packed segment id; ref-indexed with pl.ds (Mosaic
-    # has no dynamic_slice on loaded arrays)
-    q_seg = seg_ref[0, pl.ds(q_start, bq), 0]
+def _fwd_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale):
+    batch, iq = pl.program_id(0), pl.program_id(2)
+    q = _operand(q_ref[0, 0])  # [B, d]
+    block, d = q.shape
+    q_start = iq * block
 
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    # causal upper bound: K blocks whose start exceeds the last q position of
-    # this block contribute nothing
-    n_blocks = (q_start + bq + block_k - 1) // block_k
+    def program(*, segments):
+        def update(carry, j, q_at, q_n, k_at, k_n, *, on_diagonal):
+            """One online-softmax step of this block's queries [q_at, q_at +
+            q_n) (static) against the keys [k_at, k_at + k_n) of K block
+            ``j``. Per-row statistics are [rows, 1] columns from start to
+            store: a 1-D value lives along the lanes and costs a relayout each
+            way each time it meets a tile."""
+            m, l, acc = carry
+            keys = pl.ds(j * block + k_at, k_n)
+            k_blk = _operand(k_ref[0, 0, keys, :])
+            v_blk = _operand(v_ref[0, 0, keys, :])
+            s = jax.lax.dot_general(
+                q[q_at : q_at + q_n], k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [q_n, k_n] float32
+            # 0 = padding, >0 = packed segment id; ref-indexed with pl.ds
+            # (Mosaic has no dynamic_slice on loaded arrays)
+            mask = _tile_mask(
+                lambda: segq_ref[0, pl.ds(q_start + q_at, q_n), :],
+                lambda: segk_ref[0, j][:, k_at : k_at + k_n],
+                q_start + q_at, j * block + k_at, (q_n, k_n),
+                segments=segments, on_diagonal=on_diagonal,
+            )
+            s = _keep(mask, s, _NEG_INF)
 
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [BQ, BK]
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        k_seg = seg_ref[0, pl.ds(j * block_k, block_k), 0]
-        # same-segment test subsumes padding: pad queries (seg 0) attend only
-        # the pad tail (incl. themselves at k==q, keeping softmax finite),
-        # real queries never see pad keys or other segments
-        mask = (k_pos <= q_pos) & (q_seg[:, None] == k_seg[None, :])
-        s = jnp.where(mask, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            if segments:
+                # a row whose every key so far is masked (its tiles held only
+                # another packed segment) still has m_new = -1e30, so
+                # exp(s - m_new) is 1 there, not 0: kept out of l and acc here,
+                # not left for the alpha of the row's first real tile
+                # (exp(-1e30 - m) = 0) to wipe. Under the causal mask alone a
+                # row's own key is never masked, m_new is finite and the
+                # masked keys' exp is exactly 0 already.
+                p = _keep(mask, p, 0.0)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l, acc
 
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)  # exp(-1e30 - m) underflows anyway; be exact
-        l = l * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        # the K blocks wholly below the diagonal, whole (a sequence that is one
+        # block has none, and no code for them); then the block on it in
+        # pieces; blocks past it contribute nothing and are not visited
+        carry = (
+            jnp.full((block, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((block, 1), jnp.float32),
+            jnp.zeros((block, d), jnp.float32),
         )
-        return m_new, l, acc
+        if k_ref.shape[2] > block:
+            carry = jax.lax.fori_loop(
+                0, iq, lambda j, c: update(c, j, 0, block, 0, block, on_diagonal=False), carry
+            )
+        for q_at, q_n, k_at, k_n in _diagonal_pieces(block, own="queries"):
+            rows = slice(q_at, q_at + q_n)
+            m, l, acc = update(
+                tuple(x[rows] for x in carry), iq, q_at, q_n, k_at, k_n, on_diagonal=True
+            )
+            l_safe = jnp.maximum(l, 1e-30)
+            o_ref[0, 0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
+            lse_ref[0, 0, rows, :] = m + jnp.log(l_safe)
 
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0, 0, :, 0] = m + jnp.log(l_safe)
+    _with_or_without_segments(one_segment_ref[batch * pl.num_programs(2) + iq], program)
 
 
-def _fwd(q, k, v, segments, *, scale, block_q, block_k, groups, interpret):
+def _one_segment(segments, block, *, from_start):
+    """[b * s / block] int32, 1 where every position from the row's start up
+    to and including the block (``from_start``), or from the block to the
+    row's end, carries one segment id: what the tiles of a program span."""
+    b, s = segments.shape
+    blocks = segments.reshape(b, s // block, block)
+    lo = jax.lax.cummin(blocks.min(-1), axis=1, reverse=not from_start)
+    hi = jax.lax.cummax(blocks.max(-1), axis=1, reverse=not from_start)
+    return (lo == hi).astype(jnp.int32).reshape(-1)
+
+
+def _key_rows(segments, block):
+    """[b, s] -> [b, s / B, 1, B]: one K block's segment ids along the lanes,
+    picked by a leading index (the column ``[b, s, 1]`` that the queries use
+    is sliced along sublanes)."""
+    b, s = segments.shape
+    return segments.reshape(b, s // block, 1, block)
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _fwd(q, k, v, segments, *, scale, block, groups, interpret):
     b, hq, sq, d = q.shape
     sk = k.shape[2]
-    grid = (b, hq, sq // block_q)
     out_shape = (
         jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         # trailing unit dim: TPU tiling wants the block's last dim equal to
-        # the array's (1) and the second-to-last divisible by 8 (block_q)
+        # the array's (1) and the second-to-last divisible by 8 (block)
         jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
     )
-    kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k, groups=groups)
-    ins, outs = _fwd_operands(q.dtype, sk, d, block_q, groups)
+    ins, outs = _fwd_operands(q.dtype, sk, d, block, groups)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_block_specs(ins),
+        functools.partial(_fwd_kernel, scale=scale),
+        grid=(b, hq, sq // block),
+        in_specs=[_SMEM] + _block_specs(ins),
         out_specs=tuple(_block_specs(outs)),
         out_shape=out_shape,
         compiler_params=_compiler_params(ins + outs),
         interpret=interpret,
         name="flash_attention_fwd",
-    )(segments[:, :, None], q, k, v)
+    )(
+        _one_segment(segments, block, from_start=True),
+        segments[:, :, None], _key_rows(segments, block), q, k, v,
+    )
 
 
-def _fwd_operands(dtype, sk, d, block_q, groups):
-    """Grid (batch, q head, q block): segments, q, k, v -> o, lse."""
+def _fwd_operands(dtype, sk, d, block, groups):
+    """Grid (batch, q head, q block): segments (column, key rows), q, k, v
+    -> o, lse. (The one-segment flags ride in SMEM, outside this list.)"""
     q_blk = lambda b_, h, i: (b_, h, i, 0)  # noqa: E731
     kv_head = lambda b_, h, i: (b_, h // groups, 0, 0)  # noqa: E731
     ins = [
         ((1, sk, 1), jnp.int32, lambda b_, h, i: (b_, 0, 0)),
-        ((1, 1, block_q, d), dtype, q_blk),
+        ((1, sk // block, 1, block), jnp.int32, lambda b_, h, i: (b_, 0, 0, 0)),
+        ((1, 1, block, d), dtype, q_blk),
         ((1, 1, sk, d), dtype, kv_head),
         ((1, 1, sk, d), dtype, kv_head),
     ]
     outs = [
-        ((1, 1, block_q, d), dtype, q_blk),
-        ((1, 1, block_q, 1), jnp.float32, q_blk),
+        ((1, 1, block, d), dtype, q_blk),
+        ((1, 1, block, 1), jnp.float32, q_blk),
     ]
     return ins, outs
 
 
-def _dq_operands(dtype, sq, d, block_q, groups):
-    """Grid (batch, q head, q block): segments, q, k, v, do, lse, delta -> dq."""
+def _dq_operands(dtype, sq, d, block, groups):
+    """Grid (batch, q head, q block): segments (column, key rows), q, k, v,
+    do, lse, delta -> dq."""
     q_blk = lambda b_, h, i: (b_, h, i, 0)  # noqa: E731
     kv_head = lambda b_, h, i: (b_, h // groups, 0, 0)  # noqa: E731
     ins = [
         ((1, sq, 1), jnp.int32, lambda b_, h, i: (b_, 0, 0)),
-        ((1, 1, block_q, d), dtype, q_blk),
+        ((1, sq // block, 1, block), jnp.int32, lambda b_, h, i: (b_, 0, 0, 0)),
+        ((1, 1, block, d), dtype, q_blk),
         ((1, 1, sq, d), dtype, kv_head),
         ((1, 1, sq, d), dtype, kv_head),
-        ((1, 1, block_q, d), dtype, q_blk),
-        ((1, 1, block_q, 1), jnp.float32, q_blk),
-        ((1, 1, block_q, 1), jnp.float32, q_blk),
+        ((1, 1, block, d), dtype, q_blk),
+        ((1, 1, block, 1), jnp.float32, q_blk),
+        ((1, 1, block, 1), jnp.float32, q_blk),
     ]
-    outs = [((1, 1, block_q, d), dtype, q_blk)]
+    outs = [((1, 1, block, d), dtype, q_blk)]
     return ins, outs
 
 
-def _dkv_operands(dtype, sq, d, block_k, groups):
+def _dkv_operands(dtype, sq, d, block, groups):
     """Grid (batch, KV head, k block): the q/do/lse/delta blocks span the
     head's whole query group -> dk, dv at KV-head width."""
     group = lambda b_, h, j: (b_, h, 0, 0)  # noqa: E731
@@ -199,13 +345,13 @@ def _dkv_operands(dtype, sq, d, block_k, groups):
     ins = [
         ((1, sq, 1), jnp.int32, lambda b_, h, j: (b_, 0, 0)),
         ((1, groups, sq, d), dtype, group),
-        ((1, 1, block_k, d), dtype, k_blk),
-        ((1, 1, block_k, d), dtype, k_blk),
+        ((1, 1, block, d), dtype, k_blk),
+        ((1, 1, block, d), dtype, k_blk),
         ((1, groups, sq, d), dtype, group),
         ((1, groups, sq, 1), jnp.float32, group),
         ((1, groups, sq, 1), jnp.float32, group),
     ]
-    outs = [((1, 1, block_k, d), dtype, k_blk), ((1, 1, block_k, d), dtype, k_blk)]
+    outs = [((1, 1, block, d), dtype, k_blk), ((1, 1, block, d), dtype, k_blk)]
     return ins, outs
 
 
@@ -214,115 +360,168 @@ def _dkv_operands(dtype, sq, d, block_k, groups):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_k):
-    iq = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, 0]
-    delta = delta_ref[0, 0, :, 0]
-    bq, d = q.shape
-    q_start = iq * bq
-    q_seg = seg_ref[0, pl.ds(q_start, bq), 0]
-    n_blocks = (q_start + bq + block_k - 1) // block_k
+def _dq_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale):
+    batch, iq = pl.program_id(0), pl.program_id(2)
+    q = _operand(q_ref[0, 0])
+    do = _operand(do_ref[0, 0])
+    lse = lse_ref[0, 0]  # [B, 1]
+    delta = delta_ref[0, 0]
+    block, d = q.shape
+    q_start = iq * block
 
-    def body(j, dq_acc):
-        k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        k_seg = seg_ref[0, pl.ds(j * block_k, block_k), 0]
-        mask = (k_pos <= q_pos) & (q_seg[:, None] == k_seg[None, :])
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[:, None])
-        return dq_acc + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    def program(*, segments):
+        def update(dq_acc, j, q_at, q_n, k_at, k_n, *, on_diagonal):
+            """This block's queries [q_at, q_at + q_n) (static) against the
+            keys [k_at, k_at + k_n) of K block ``j``."""
+            rows = slice(q_at, q_at + q_n)
+            keys = pl.ds(j * block + k_at, k_n)
+            k_blk = _operand(k_ref[0, 0, keys, :])
+            v_blk = _operand(v_ref[0, 0, keys, :])
+            s = jax.lax.dot_general(
+                q[rows], k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            mask = _tile_mask(
+                lambda: segq_ref[0, pl.ds(q_start + q_at, q_n), :],
+                lambda: segk_ref[0, j][:, k_at : k_at + k_n],
+                q_start + q_at, j * block + k_at, (q_n, k_n),
+                segments=segments, on_diagonal=on_diagonal,
+            )
+            p = _keep(mask, jnp.exp(s - lse[rows]), 0.0)
+            dp = jax.lax.dot_general(
+                do[rows], v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - delta[rows])
+            return dq_acc + jax.lax.dot_general(
+                ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
-    dq = jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+        # as in the forward kernel: whole blocks below the diagonal, then the
+        # block on it in pieces
+        dq = jnp.zeros((block, d), jnp.float32)
+        if k_ref.shape[2] > block:
+            dq = jax.lax.fori_loop(
+                0, iq, lambda j, acc: update(acc, j, 0, block, 0, block, on_diagonal=False), dq
+            )
+        for q_at, q_n, k_at, k_n in _diagonal_pieces(block, own="queries"):
+            rows = slice(q_at, q_at + q_n)
+            piece = update(dq[rows], iq, q_at, q_n, k_at, k_n, on_diagonal=True)
+            dq_ref[0, 0, rows, :] = (piece * scale).astype(dq_ref.dtype)
+
+    _with_or_without_segments(one_segment_ref[batch * pl.num_programs(2) + iq], program)
 
 
-def _dkv_kernel(seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, block_q, groups):
+def _as_row(column):
+    """[n, 1] -> [1, n] (n a multiple of 128) through the transpose unit: the
+    column spread over 128 lanes, transposed, one row kept."""
+    return jnp.transpose(jnp.broadcast_to(column, (column.shape[0], 128)))[:1, :]
+
+
+def _dkv_kernel(one_segment_ref, seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, groups):
     """Per (batch, KV head, k_block): accumulate dk/dv over this KV head's
     ``groups`` query heads and all causal q blocks — dk/dv stay at KV-head
-    width (no group-factor HBM inflation)."""
-    jk = pl.program_id(2)
-    k_blk = k_ref[0, 0].astype(jnp.float32)  # [BK, d]
-    v_blk = v_ref[0, 0].astype(jnp.float32)
-    bk, d = k_blk.shape
-    sq = q_ref.shape[2]
-    k_start = jk * bk
-    k_seg = seg_ref[0, pl.ds(k_start, bk), 0]
-    # causal: only q blocks at/after this k block contribute
-    start_block = k_start // block_q
-    n_blocks = sq // block_q
+    width (no group-factor HBM inflation). The scores are held transposed,
+    [keys, queries]: then all four matmuls run in the orientations the MXU
+    has (K Q^T, V dO^T, P^T dO, dS^T Q with P^T and dS^T as they stand) and no
+    score tile goes through the transpose unit; what does is a Q block's lse,
+    delta and segment ids, [B, 1] columns that such a tile needs as rows."""
+    batch, jk = pl.program_id(0), pl.program_id(2)
+    k_all = _operand(k_ref[0, 0])  # [B, d]
+    v_all = _operand(v_ref[0, 0])
+    block, d = k_all.shape
+    k_start = jk * block
+    pieces = _diagonal_pieces(block, own="keys")
 
-    def make_body(g):
-        def body(i, carry):
+    def program(*, segments):
+        def update(carry, g, i, k_at, k_n, q_at, q_n, *, on_diagonal):
+            """This block's keys [k_at, k_at + k_n) (static) against the
+            queries [q_at, q_at + q_n) of Q block ``i`` of head ``g``."""
             dk_acc, dv_acc = carry
-            q_blk = q_ref[0, g, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-            do_blk = do_ref[0, g, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-            lse_blk = lse_ref[0, g, pl.ds(i * block_q, block_q), 0]
-            delta_blk = delta_ref[0, g, pl.ds(i * block_q, block_q), 0]
-            s = jax.lax.dot_general(
-                q_blk, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale  # [BQ, BK]
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            q_seg = seg_ref[0, pl.ds(i * block_q, block_q), 0]
-            mask = (k_pos <= q_pos) & (q_seg[:, None] == k_seg[None, :])
-            p = jnp.where(mask, jnp.exp(s - lse_blk[:, None]), 0.0)
+            keys = slice(k_at, k_at + k_n)
+            rows = pl.ds(i * block + q_at, q_n)
+            q_blk = _operand(q_ref[0, g, rows, :])
+            do_blk = _operand(do_ref[0, g, rows, :])
+            s_t = jax.lax.dot_general(
+                k_all[keys], q_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [k_n, q_n]
+            mask = _tile_mask(
+                lambda: _as_row(seg_ref[0, rows, :]),
+                lambda: seg_ref[0, pl.ds(k_start + k_at, k_n), :],
+                i * block + q_at, k_start + k_at, (k_n, q_n),
+                segments=segments, on_diagonal=on_diagonal, q_axis=1,
+            )
+            p_t = _keep(mask, jnp.exp(s_t - _as_row(lse_ref[0, g, rows, :])), 0.0)
             dv_acc = dv_acc + jax.lax.dot_general(
-                p, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                p_t.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            dp = jax.lax.dot_general(
-                do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            dp_t = jax.lax.dot_general(
+                v_all[keys], do_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
-            ds = p * (dp - delta_blk[:, None])
+            ds_t = p_t * (dp_t - _as_row(delta_ref[0, g, rows, :]))
             dk_acc = dk_acc + jax.lax.dot_general(
-                ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                ds_t.astype(q_blk.dtype), q_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
             return dk_acc, dv_acc
 
-        return body
+        def head(g, carry):
+            """One query head of the KV head's group: the Q block on the
+            diagonal in pieces (each with accumulators of its own, there are
+            no rows to put back), the Q blocks after it whole; earlier ones
+            see none of these keys."""
+            whole, on_diagonal = carry
+            on_diagonal = tuple(
+                update(acc, g, jk, *piece, on_diagonal=True)
+                for acc, piece in zip(on_diagonal, pieces)
+            )
+            if q_ref.shape[2] > block:
+                whole = jax.lax.fori_loop(
+                    jk + 1, q_ref.shape[2] // block,
+                    lambda i, c: update(c, g, i, 0, block, 0, block, on_diagonal=False),
+                    whole,
+                )
+            return whole, on_diagonal
 
-    dk = jnp.zeros((bk, d), jnp.float32)
-    dv = jnp.zeros((bk, d), jnp.float32)
-    for g in range(groups):  # static unroll over the KV head's query group
-        dk, dv = jax.lax.fori_loop(start_block, n_blocks, make_body(g), (dk, dv))
-    dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        zeros = lambda n: (jnp.zeros((n, d), jnp.float32),) * 2  # noqa: E731
+        (dk, dv), on_diagonal = jax.lax.fori_loop(
+            0, groups, head, (zeros(block), tuple(zeros(k_n) for _, k_n, _, _ in pieces))
+        )
+        for (k_at, k_n, _, _), (dk_piece, dv_piece) in zip(pieces, on_diagonal):
+            keys = slice(k_at, k_at + k_n)
+            dk_ref[0, 0, keys, :] = ((dk[keys] + dk_piece) * scale).astype(dk_ref.dtype)
+            dv_ref[0, 0, keys, :] = (dv[keys] + dv_piece).astype(dv_ref.dtype)
+
+    _with_or_without_segments(one_segment_ref[batch * pl.num_programs(2) + jk], program)
 
 
-def _bwd(q, k, v, segments, o, lse, do, *, scale, block_q, block_k, groups, interpret):
+def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
     """Head-major inputs: q/o/do/lse [b, hq, ...], k/v [b, hkv, s, d]."""
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # [b,hq,sq,1]
+    seg_column = segments[:, :, None]
 
-    ins, outs = _dq_operands(q.dtype, sq, d, block_q, groups)
+    ins, outs = _dq_operands(q.dtype, sq, d, block, groups)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, block_k=block_k),
-        grid=(b, hq, sq // block_q),
-        in_specs=_block_specs(ins),
+        functools.partial(_dq_kernel, scale=scale),
+        grid=(b, hq, sq // block),
+        in_specs=[_SMEM] + _block_specs(ins),
         out_specs=_block_specs(outs)[0],
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         compiler_params=_compiler_params(ins + outs),
         interpret=interpret,
         name="flash_attention_dq",
-    )(segments[:, :, None], q, k, v, do, lse, delta)
+    )(
+        _one_segment(segments, block, from_start=True),
+        seg_column, _key_rows(segments, block), q, k, v, do, lse, delta,
+    )
 
-    ins, outs = _dkv_operands(q.dtype, sq, d, block_k, groups)
+    ins, outs = _dkv_operands(q.dtype, sq, d, block, groups)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, block_q=block_q, groups=groups),
-        grid=(b, hkv, sq // block_k),
-        in_specs=_block_specs(ins),
+        functools.partial(_dkv_kernel, scale=scale, groups=groups),
+        grid=(b, hkv, sq // block),
+        in_specs=[_SMEM] + _block_specs(ins),
         out_specs=tuple(_block_specs(outs)),
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, sq, d), k.dtype),
@@ -331,7 +530,7 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block_q, block_k, groups, inte
         compiler_params=_compiler_params(ins + outs),
         interpret=interpret,
         name="flash_attention_dkv",
-    )(segments[:, :, None], q, k, v, do, lse, delta)
+    )(_one_segment(segments, block, from_start=False), seg_column, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -341,38 +540,51 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block_q, block_k, groups, inte
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_fn(scale: float, block_q: int, block_k: int, groups: int, interpret: bool):
-    """One custom_vjp closure per static configuration."""
+def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
+    """One custom_vjp closure per static configuration. The forward and the
+    backward are jitted on their own: a model calls this once a layer (and
+    again under remat), and each call of a bare ``pallas_call`` would trace
+    its kernel body and lower it to Mosaic anew, in Python, in every process,
+    compile cache or not (PR 25: 0.6 to 1.0 s a layer for these kernels, 77 s
+    of a warm start at 36 layers). Behind a jit the callers share one traced
+    jaxpr and one lowered function."""
+    static = dict(scale=scale, block=block, groups=groups, interpret=interpret)
+
+    @jax.jit
+    def forward(q, k, v, segments):
+        return _fwd(q, k, v, segments, **static)
+
+    @jax.jit
+    def backward(q, k, v, segments, o, lse, do):
+        return _bwd(q, k, v, segments, o, lse, do, **static)
 
     @jax.custom_vjp
     def fn(q, k, v, segments):
-        o, _ = _fwd(
-            q, k, v, segments,
-            scale=scale, block_q=block_q, block_k=block_k, groups=groups,
-            interpret=interpret,
-        )
-        return o
+        return forward(q, k, v, segments)[0]
 
     def fn_fwd(q, k, v, segments):
-        o, lse = _fwd(
-            q, k, v, segments,
-            scale=scale, block_q=block_q, block_k=block_k, groups=groups,
-            interpret=interpret,
-        )
+        o, lse = forward(q, k, v, segments)
         return o, (q, k, v, segments, o, lse)
 
     def fn_bwd(res, do):
         q, k, v, segments, o, lse = res
-        dq, dk, dv = _bwd(
-            q, k, v, segments, o, lse, do,
-            scale=scale, block_q=block_q, block_k=block_k, groups=groups,
-            interpret=interpret,
-        )
+        dq, dk, dv = backward(q, k, v, segments, o, lse, do)
         dsegments = np.zeros(segments.shape, jax.dtypes.float0)
         return dq, dk, dv, dsegments
 
     fn.defvjp(fn_fwd, fn_bwd)
     return fn
+
+
+# Up to this length a sequence is one block: no tile lies below the diagonal,
+# the strips of the diagonal tile are all the work, each strip's softmax is
+# done in one step, and the widest score tile is [_PIECE_ROWS, s]. Longer
+# sequences take blocks of at most 1024: a whole [block, block] score tile
+# below the diagonal has to fit the kernels' VMEM (2048 x 2048 does not).
+# On a v5e (PR 25, the three kernels alone): seq 2048 as one block ran 1.22,
+# 1.07 and 1.14 times faster than as two of 1024, those 1.07, 1.10 and 1.10
+# times faster than four of 512.
+_ONE_BLOCK_SEQ = 2048
 
 
 def _pick_block(s: int) -> int:
@@ -390,10 +602,11 @@ def _pick_block(s: int) -> int:
                 f"FLASH_BLOCK={blk} does not divide seq length {s}"
             )
         return blk
-    for blk in (512, 256, 128):
-        if s % blk == 0:
-            return blk
-    return 0
+    if s % 128:
+        return 0
+    if s <= _ONE_BLOCK_SEQ:
+        return s
+    return next(blk for blk in (1024, 512, 256, 128) if s % blk == 0)
 
 
 def flash_unsupported_reason(
@@ -636,7 +849,7 @@ def pallas_flash_attention(
             f"flash attention requires seq length divisible by 128, got {sq} "
             f"(use ops.attention.attention() for automatic XLA fallback)"
         )
-    fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, block, groups, interpret)
+    fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, groups, interpret)
     # head-major layout for clean blocking
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -135,3 +135,209 @@ def test_backward_segments_match_xla():
             np.asarray(a) * real, np.asarray(b_) * real, atol=5e-5, rtol=5e-5,
             err_msg=f"d{name} mismatch",
         )
+
+
+# ---------------------------------------------------------------------------
+# bfloat16 inputs, as every caller on the chip has them
+# ---------------------------------------------------------------------------
+
+# Largest relative error (norm of the difference over the reference's norm)
+# measured over the cases below, kernels under the interpreter against
+# xla_attention in float32 on the same bfloat16 values: forward 1.59e-3, dq
+# 2.54e-3, dk 2.58e-3, dv 2.39e-3 (the kernels compute in float32; what is
+# left is the rounding of o, dq, dk, dv to bfloat16 as they are stored). The
+# tolerance is three times the largest; the chip smoke's bound for the same
+# comparison on the chip is 2e-2.
+BF16_REL_TOL = 7.8e-3
+
+
+def _bf16_case(name):
+    """(q, k, v, mask kwargs, [b, s] bool of the rows that count)."""
+    b, s, d = 2, 256, 32
+    hq, hkv = {"gqa4x4": (4, 4), "gqa4x2": (4, 2), "gqa8x2": (8, 2)}.get(name, (4, 2))
+    q, k, v = make_qkv(jax.random.PRNGKey(4), b, s, hq, hkv, d, jnp.bfloat16)
+    if name == "padding":
+        real = np.arange(s)[None, :] < np.asarray([[s], [100]])
+        return q, k, v, {"padding_mask": jnp.asarray(real.astype(np.int32))}, real
+    if name == "segments":
+        seg = _segments(b, s, np.random.RandomState(2))
+        return q, k, v, {"segment_ids": jnp.asarray(seg)}, seg > 0
+    return q, k, v, {}, np.ones((b, s), bool)
+
+
+def _rel(a, r):
+    a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+    return float(np.linalg.norm(a - r) / np.linalg.norm(r))
+
+
+BF16_CASES = ["gqa4x4", "gqa4x2", "gqa8x2", "padding", "segments"]
+
+
+@pytest.mark.parametrize("case", BF16_CASES)
+def test_bfloat16_forward_matches_float32_xla(case):
+    q, k, v, kw, real = _bf16_case(case)
+    out = pallas_flash_attention(q, k, v, interpret=True, **kw)
+    assert out.dtype == jnp.bfloat16
+    ref = xla_attention(*(x.astype(jnp.float32) for x in (q, k, v)), causal=True, **kw)
+    assert _rel(np.asarray(out, np.float32)[real], np.asarray(ref)[real]) < BF16_REL_TOL
+
+
+@pytest.mark.parametrize("case", BF16_CASES)
+def test_bfloat16_gradients_match_float32_xla(case):
+    q, k, v, kw, real = _bf16_case(case)
+    cot = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.float32)
+    cot = cot * jnp.asarray(real[:, :, None, None].astype(np.float32))  # like a loss mask
+
+    def f_flash(q, k, v):
+        out = pallas_flash_attention(q, k, v, interpret=True, **kw)
+        return jnp.sum(out.astype(jnp.float32) * cot)
+
+    def f_xla(q, k, v):
+        return jnp.sum(xla_attention(q, k, v, causal=True, **kw) * cot)
+
+    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    g_xla = jax.grad(f_xla, argnums=(0, 1, 2))(*(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, r, name in zip(g_flash, g_xla, "qkv"):
+        assert a.dtype == jnp.bfloat16
+        assert _rel(a, r) < BF16_REL_TOL, f"d{name}"
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_first_tile_holding_only_another_segment(monkeypatch, dtype):
+    """A packed row whose first K tile holds none of its own segment's keys:
+    its running max is still -1e30 after that tile, so exp(s - m) is 1 for
+    every masked key, and the second ``where`` (on p) is what keeps them out
+    of the normalizer and the accumulator at that point. Two 128-wide tiles,
+    the first all segment 1, the second all segment 2; the second segment's
+    outputs and all three gradients must not feel the first tile."""
+    monkeypatch.setenv("FLASH_BLOCK", "128")
+    q, k, v = make_qkv(jax.random.PRNGKey(6), 1, 256, 4, 2, 32, dtype)
+    seg = jnp.asarray(np.repeat([[1, 2]], 128, axis=1).astype(np.int32))
+    cot = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+
+    def f_flash(q, k, v):
+        out = pallas_flash_attention(q, k, v, segment_ids=seg, interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * cot), out
+
+    def f_xla(q, k, v):
+        out = xla_attention(q, k, v, segment_ids=seg, causal=True)
+        return jnp.sum(out * cot), out
+
+    (_, o_f), g_f = jax.value_and_grad(f_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    (_, o_x), g_x = jax.value_and_grad(f_xla, argnums=(0, 1, 2), has_aux=True)(*f32)
+    tol = 5e-5 if dtype == jnp.float32 else BF16_REL_TOL
+    # the second segment's rows are the ones whose first tile is all foreign
+    assert _rel(np.asarray(o_f, np.float32)[:, 128:], np.asarray(o_x)[:, 128:]) < tol
+    for a, r, name in zip(g_f, g_x, "qkv"):
+        assert _rel(a, r) < tol, f"d{name}"
+
+
+def _kernel_dots(jaxpr, kernel=None):
+    """(kernel name, operand dtypes, accumulator dtype) of every dot_general
+    inside the pallas_call bodies of ``jaxpr``, loops included."""
+    for eqn in jaxpr.eqns:
+        name = eqn.params["name"] if eqn.primitive.name == "pallas_call" else kernel
+        if eqn.primitive.name == "dot_general" and kernel is not None:
+            yield kernel, [x.aval.dtype for x in eqn.invars], eqn.outvars[0].aval.dtype
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_dots(sub, name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kernels_hand_the_mxu_one_operand_dtype_and_accumulate_in_float32(dtype):
+    """Whatever dtype q, k, v arrive in, every dot_general of the three kernel
+    bodies gets both operands in ``MXU_OPERAND_DTYPE`` (none is mixed, none is
+    left in the input dtype while its partner is cast) and accumulates in
+    float32. PR 25 measured on the chip that operands left in bfloat16 make
+    all three kernels slower (``ops/flash_attention.py``, ``PERF.md``), so
+    the dtype is float32; this pins that a change of it is made in one place
+    and reaches all ten products."""
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import (
+        MXU_OPERAND_DTYPE,
+        _diagonal_pieces,
+    )
+
+    q, k, v = make_qkv(jax.random.PRNGKey(8), 1, 256, 4, 2, 32, dtype)
+
+    def loss(q, k, v):
+        return pallas_flash_attention(q, k, v, interpret=True).astype(jnp.float32).sum()
+
+    dots = list(_kernel_dots(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr))
+    per_kernel = {}
+    for kernel, operands, acc in dots:
+        per_kernel[kernel] = per_kernel.get(kernel, 0) + 1
+        assert operands == [MXU_OPERAND_DTYPE, MXU_OPERAND_DTYPE], (kernel, operands)
+        assert acc == jnp.float32, (kernel, acc)
+    # QK^T, PV | QK^T, dO V^T, dS K | K Q^T, P^T dO, V dO^T, dS^T q: a body is
+    # traced for each strip of the block on the diagonal (seq 256 is one block:
+    # there is none below it and no code for one), with and without the segment
+    # test
+    bodies = 2 * len(_diagonal_pieces(256, own="queries"))
+    assert per_kernel == {
+        "flash_attention_fwd": 2 * bodies,
+        "flash_attention_dq": 3 * bodies,
+        "flash_attention_dkv": 4 * bodies,
+    }
+
+
+@pytest.mark.parametrize("own", ["queries", "keys"])
+@pytest.mark.parametrize("block", [128, 256, 512, 1024])
+def test_diagonal_pieces_cover_what_the_causal_mask_lets_through(block, own):
+    """The strips the diagonal tile is cut into hold every (key <= query)
+    pair of the tile, each position of the kernel's own axis in exactly one
+    strip, and leave out only pairs the causal mask removes anyway."""
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import _diagonal_pieces
+
+    covered = np.zeros((block, block), int)  # [key, query]
+    own_rows = np.zeros(block, int)
+    for own_at, own_n, other_at, other_n in _diagonal_pieces(block, own=own):
+        assert own_n % 128 == 0 and other_n % 128 == 0  # whole lanes
+        own_rows[own_at : own_at + own_n] += 1
+        keys, queries = (other_at, other_n), (own_at, own_n)
+        if own == "keys":
+            keys, queries = queries, keys
+        covered[keys[0] : keys[0] + keys[1], queries[0] : queries[0] + queries[1]] += 1
+    assert (own_rows == 1).all()
+    causal = np.arange(block)[:, None] <= np.arange(block)[None, :]
+    assert (covered[causal] == 1).all() and covered.max() == 1
+    if block >= 512:
+        assert covered.sum() <= 0.75 * block * block  # the cut saves a quarter or more
+
+
+@pytest.mark.parametrize("block", ["", "512"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_diagonal_in_strips(monkeypatch, dtype, block):
+    """seq 1024 as the kernel takes it, one block of four strips, and as two
+    512-wide blocks (one block below the diagonal taken whole, the two on it
+    in two strips each); a packed row (three segments and a pad tail, so both
+    the one-segment programs and the masked ones run), forward and all three
+    gradients."""
+    monkeypatch.setenv("FLASH_BLOCK", block)
+    b, s, hq, hkv, d = 2, 1024, 2, 1, 32
+    q, k, v = make_qkv(jax.random.PRNGKey(9), b, s, hq, hkv, d, dtype)
+    seg_np = np.ones((b, s), np.int32)
+    seg_np[1, 300:700], seg_np[1, 700:990], seg_np[1, 990:] = 2, 3, 0
+    seg = jnp.asarray(seg_np)
+    real = seg_np > 0
+    cot = jax.random.normal(jax.random.PRNGKey(10), q.shape, jnp.float32)
+    cot = cot * jnp.asarray(real[:, :, None, None].astype(np.float32))
+
+    def f_flash(q, k, v):
+        out = pallas_flash_attention(q, k, v, segment_ids=seg, interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * cot), out
+
+    def f_xla(q, k, v):
+        out = xla_attention(q, k, v, segment_ids=seg, causal=True)
+        return jnp.sum(out * cot), out
+
+    (_, o_f), g_f = jax.value_and_grad(f_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    (_, o_x), g_x = jax.value_and_grad(f_xla, argnums=(0, 1, 2), has_aux=True)(*f32)
+    tol = 5e-5 if dtype == jnp.float32 else BF16_REL_TOL
+    assert _rel(np.asarray(o_f, np.float32)[real], np.asarray(o_x)[real]) < tol
+    for a, r, name in zip(g_f, g_x, "qkv"):
+        assert _rel(a, r) < tol, f"d{name}"

@@ -70,11 +70,11 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
-def _qkv(b, s):
+def _qkv(b, s, hq=HQ, hkv=HKV):
     return (
-        ((b, s, HQ, D), jnp.bfloat16),
-        ((b, s, HKV, D), jnp.bfloat16),
-        ((b, s, HKV, D), jnp.bfloat16),
+        ((b, s, hq, D), jnp.bfloat16),
+        ((b, s, hkv, D), jnp.bfloat16),
+        ((b, s, hkv, D), jnp.bfloat16),
     )
 
 
@@ -82,21 +82,28 @@ def _qkv(b, s):
 # the dk/dv kernel's VMEM budget at 6144 is 95 MiB of the 100 MiB cap
 MAX_FLASH_SEQ = 6144
 
+# one microbatch of each training cell of BENCHMARK.json (SmolLM3-3B: 2 rows
+# of 1024; Mistral-7B: one row of 2048, 32 q heads on 8 kv heads) and the
+# largest admitted sequence: (rows, seq, q heads, kv heads)
+FLASH_SHAPES = [(2, 1024, HQ, HKV), (2, MAX_FLASH_SEQ, HQ, HKV), (1, 2048, 32, 8)]
 
-@pytest.mark.parametrize("seq", [1024, MAX_FLASH_SEQ])
-def test_flash_forward_compiles_for_v5e(one_chip, seq):
-    _compile(lambda q, k, v: fa.pallas_flash_attention(q, k, v), one_chip, *_qkv(2, seq))
+
+@pytest.mark.parametrize("rows, seq, hq, hkv", FLASH_SHAPES)
+def test_flash_forward_compiles_for_v5e(one_chip, rows, seq, hq, hkv):
+    _compile(
+        lambda q, k, v: fa.pallas_flash_attention(q, k, v), one_chip, *_qkv(rows, seq, hq, hkv)
+    )
 
 
-@pytest.mark.parametrize("seq", [1024, MAX_FLASH_SEQ])
-def test_flash_forward_backward_compiles_for_v5e(one_chip, seq):
+@pytest.mark.parametrize("rows, seq, hq, hkv", FLASH_SHAPES)
+def test_flash_forward_backward_compiles_for_v5e(one_chip, rows, seq, hq, hkv):
     """The backward holds a kv head's whole query group in VMEM: this is the
     compile the default 16 MiB scoped budget refused at seq 4096."""
 
     def loss(q, k, v):
         return fa.pallas_flash_attention(q, k, v).astype(jnp.float32).sum()
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *_qkv(2, seq))
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *_qkv(rows, seq, hq, hkv))
 
 
 def test_flash_says_no_past_its_vmem_cap(monkeypatch):
