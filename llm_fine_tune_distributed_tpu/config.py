@@ -94,9 +94,44 @@ class ModelConfig:
     # [b * s/chunk, chunk, E, C_chunk] instead of [b, s, E, C]. Tokens
     # compete for capacity within their chunk only.
     moe_dispatch_chunk: int = 1024
+    # --- Latent attention (MLA, HF DeepseekV3Attention; q_lora_rank null) ---
+    # kv_lora_rank > 0 switches every layer's attention: k and v come from one
+    # low-rank latent of this width (normed) plus ONE rope key of
+    # qk_rope_head_dim shared by all heads; q/k heads are qk_nope_head_dim +
+    # qk_rope_head_dim wide, v heads v_head_dim. head_dim is unused then.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- Routed experts with shared experts (HF DeepseekV3MoE, noaux_tc) ---
+    # n_routed_experts > 0 is the ROUTER's width: sigmoid scores, top
+    # num_experts_per_tok of scores + a bias buffer, weights normalised over
+    # the selected and scaled by routed_scaling_factor, no auxiliary loss, no
+    # dropped token (ops/moe.py grouped_moe_mlp). The first
+    # first_k_dense_replace layers keep the dense MLP of intermediate_size;
+    # the rest hold experts of moe_intermediate_size beside n_shared_experts
+    # shared ones (one SwiGLU of n_shared_experts * moe_intermediate_size).
+    n_routed_experts: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    # Global ids of the routed experts THIS program holds (expert
+    # parallelism's share; the stacked expert leaves have len(held_experts)
+    # rows). Empty = all n_routed_experts. The router stays n_routed_experts
+    # wide; what the absent experts would add is left out.
+    held_experts: tuple = ()
 
     def __post_init__(self):
-        if self.num_experts and self.hidden_act != "silu":
+        if self.n_routed_experts:
+            if self.num_experts:
+                raise ValueError("num_experts (Mixtral dispatch) and n_routed_experts (grouped dispatch) are exclusive")
+            held = self.held_expert_ids
+            if len(set(held)) != len(held) or not all(0 <= e < self.n_routed_experts for e in held):
+                raise ValueError(f"held_experts {held} must be distinct ids below n_routed_experts={self.n_routed_experts}")
+            if not 0 < self.num_experts_per_tok <= self.n_routed_experts:
+                raise ValueError("num_experts_per_tok must lie in 1..n_routed_experts")
+        if (self.num_experts or self.n_routed_experts) and self.hidden_act != "silu":
             # ops/moe.py's expert MLP hardcodes silu — reject at config
             # construction rather than silently training with the wrong
             # activation (same fail-fast contract as rope_scaling parsing)
@@ -108,6 +143,14 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.hidden_size // self.num_heads
+
+    @property
+    def held_expert_ids(self) -> tuple:
+        return tuple(self.held_experts) or tuple(range(self.n_routed_experts))
+
+    def layer_has_experts(self, layer_idx: int) -> bool:
+        """Routed + shared experts (n_routed_experts) instead of the dense MLP."""
+        return self.n_routed_experts > 0 and layer_idx >= self.first_k_dense_replace
 
     @property
     def num_params(self) -> int:
@@ -138,6 +181,24 @@ class ModelConfig:
         if self.mlp_bias:
             per_layer += 2 * f + h
         total = embed + L * per_layer + h  # + final norm
+        if self.kv_lora_rank:
+            # MLA: q, kv_a (latent + shared rope key), latent norm, kv_b, o
+            # instead of q, k, v, o (counted above at head_dim d)
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            r = self.kv_lora_rank
+            mla = (
+                h * self.num_heads * qk + h * (r + self.qk_rope_head_dim) + r
+                + r * self.num_heads * (self.qk_nope_head_dim + self.v_head_dim)
+                + self.num_heads * self.v_head_dim * h
+            )
+            total += L * (mla - 2 * h * (self.num_heads + self.num_kv_heads) * d)
+        if self.n_routed_experts:
+            # expert layers: router [h, E] + its bias buffer [E], the HELD
+            # experts and the shared expert, instead of the dense MLP
+            fe, e = self.moe_intermediate_size, self.n_routed_experts
+            held = len(self.held_expert_ids)
+            experts = h * e + e + 3 * h * fe * (held + self.n_shared_experts)
+            total += (L - min(L, self.first_k_dense_replace)) * (experts - 3 * h * f)
         if not self.tie_word_embeddings:
             total += v * h
         return total

@@ -105,6 +105,19 @@ def make_tp_mesh(tp: int, model_config: Optional[ModelConfig] = None):
     return make_mesh(MeshConfig(data=1, fsdp=1, tensor=tp, seq=1, expert=1, pipe=1))
 
 
+class LatentAttentionNotServed(NotImplementedError):
+    """A model with latent attention (``ModelConfig.kv_lora_rank``) was handed
+    to the serving path. It trains (``models/transformer.forward`` without a
+    cache); its cache, one latent and one rope key a token, is a third layout
+    that ``infer/`` does not have yet (ROADMAP.md, Reach C)."""
+
+    def __init__(self, name: str):
+        super().__init__(
+            f"model {name!r} has latent attention (kv_lora_rank): it is supported on the training "
+            "path only; serving it needs a latent KV cache layout and a decode path that infer/ lacks"
+        )
+
+
 class Generator:
     """Generation engine over a params pytree — single-chip by default, or
     sharded over a device mesh.
@@ -134,6 +147,9 @@ class Generator:
         the draft MODEL instead of prompt-lookup — the draft generalizes
         beyond repetition-heavy outputs (prompt-lookup's limit), at the cost
         of running the small model K steps per verify."""
+        for served in (model_config, draft_config):
+            if served is not None and served.kv_lora_rank:
+                raise LatentAttentionNotServed(served.name)
         self.mesh = mesh
         self._act_sharding = None
         self._multihost = False

@@ -10,6 +10,8 @@ the HF ``transformers`` config classes
 
 from __future__ import annotations
 
+import dataclasses
+
 from llm_fine_tune_distributed_tpu.config import ModelConfig
 
 
@@ -262,6 +264,61 @@ PRESETS = {
         final_logit_softcap=30.0,
         query_pre_attn_scalar=256.0,
     ),
+    "moonlight_16b_a3b": ModelConfig(
+        # HF moonshotai/Moonlight-16B-A3B (model_type deepseek_v3): latent
+        # attention with q/k heads of 128 + 64 rope dimensions and v heads of
+        # 128, one leading dense layer, then 64 routed experts (top 6, sigmoid
+        # scores, selection bias) beside 2 shared experts. Training path only
+        # (infer/ refuses latent attention). Set held_experts to one process's
+        # share of the experts for expert parallelism.
+        name="moonlight_16b_a3b",
+        vocab_size=163840,
+        hidden_size=2048,
+        intermediate_size=11264,
+        num_layers=27,
+        num_heads=16,
+        num_kv_heads=16,
+        rope_theta=50_000.0,
+        max_position_embeddings=8192,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        n_routed_experts=64,
+        num_experts_per_tok=6,
+        moe_intermediate_size=1408,
+        n_shared_experts=2,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.446,
+    ),
+    "tiny_mla_moe": ModelConfig(
+        # Moonlight's structure at toy widths (tests, the benchmark's CPU
+        # rehearsal): this process holds 4 of the 16 routed experts
+        name="tiny_mla_moe",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        rope_theta=50_000.0,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        n_routed_experts=16,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        n_shared_experts=2,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.446,
+        held_experts=(0, 1, 2, 3),
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -344,7 +401,68 @@ def to_hf_dict(mc: ModelConfig) -> dict:
         "num_local_experts": mc.num_experts,
         "num_experts_per_tok": mc.num_experts_per_tok,
         "router_aux_loss_coef": mc.router_aux_coef,
+        # latent attention and routed experts with shared experts (HF
+        # DeepseekV3Config naming; what this framework implements of it is
+        # fixed, and written out so that another loader reads it too)
+        **_deepseek_v3_keys(mc),
     }
+
+
+def _deepseek_v3_keys(mc: ModelConfig) -> dict:
+    if not (mc.kv_lora_rank or mc.n_routed_experts):
+        return {}
+    return {
+        "kv_lora_rank": mc.kv_lora_rank,
+        "q_lora_rank": None,
+        "qk_nope_head_dim": mc.qk_nope_head_dim,
+        "qk_rope_head_dim": mc.qk_rope_head_dim,
+        "v_head_dim": mc.v_head_dim,
+        "n_routed_experts": mc.n_routed_experts,
+        "n_shared_experts": mc.n_shared_experts,
+        "moe_intermediate_size": mc.moe_intermediate_size,
+        "first_k_dense_replace": mc.first_k_dense_replace,
+        "moe_layer_freq": 1,
+        "routed_scaling_factor": mc.routed_scaling_factor,
+        "scoring_func": "sigmoid",
+        "topk_method": "noaux_tc",
+        "norm_topk_prob": True,
+        "n_group": 1,
+        "topk_group": 1,
+        "held_experts": list(mc.held_experts),
+    }
+
+
+def _deepseek_v3_fields(g) -> dict:
+    """ModelConfig fields of a ``deepseek_v3`` config (HF DeepseekV3Config),
+    refusing by name whatever of it this framework does not implement, before
+    any weight loads."""
+    refused = {
+        "q_lora_rank": (None,), "n_group": (1, None), "topk_group": (1, None),
+        "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",), "norm_topk_prob": (True,),
+        "moe_layer_freq": (1, None), "num_nextn_predict_layers": (0, None), "rope_scaling": (None,),
+        "attention_bias": (False, None),
+    }
+    for key, allowed in refused.items():
+        if g(key) not in allowed:
+            raise ValueError(
+                f"deepseek_v3 config has {key}={g(key)!r}; implemented: {key} in {allowed} "
+                "(q as one matrix, one expert group, sigmoid scores with a selection bias, "
+                "normalised top-k weights, every layer past the leading dense ones with experts, "
+                "plain rope)"
+            )
+    return dict(
+        kv_lora_rank=g("kv_lora_rank"),
+        qk_nope_head_dim=g("qk_nope_head_dim"),
+        qk_rope_head_dim=g("qk_rope_head_dim"),
+        v_head_dim=g("v_head_dim"),
+        n_routed_experts=g("n_routed_experts") or 0,
+        n_shared_experts=g("n_shared_experts") or 0,
+        moe_intermediate_size=g("moe_intermediate_size") or 0,
+        first_k_dense_replace=g("first_k_dense_replace") or 0,
+        routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
+        held_experts=tuple(g("held_experts") or ()),
+        num_experts_per_tok=g("num_experts_per_tok"),
+    )
 
 
 def load_model_config(path: str) -> ModelConfig:
@@ -416,6 +534,11 @@ def from_hf_config(hf_config) -> ModelConfig:
             "and would silently produce wrong logits). Convert the config to "
             "explicit keys or add a validated preset."
         )
+    # deepseek_v3 (Moonlight, DeepSeek-V3): by model_type, or by this
+    # framework's own save, which carries kv_lora_rank under any name
+    deepseek = {}
+    if mt == "deepseek_v3" or g("kv_lora_rank") or g("n_routed_experts"):
+        deepseek = _deepseek_v3_fields(g)
     no_rope = g("no_rope_layers") or ()
     # HF rope_scaling dict: {"rope_type"|"type": "llama3"|"linear"|"default",
     # "factor", "low_freq_factor", "high_freq_factor",
@@ -433,7 +556,7 @@ def from_hf_config(hf_config) -> ModelConfig:
             f"unsupported rope_scaling type {rs_type!r}; supported: "
             "'llama3' (Llama-3.1 smoothed NTK), 'linear', 'default'"
         )
-    return ModelConfig(
+    mc = ModelConfig(
         name=g("model_type", "hf_model"),
         vocab_size=g("vocab_size"),
         hidden_size=g("hidden_size"),
@@ -521,3 +644,4 @@ def from_hf_config(hf_config) -> ModelConfig:
             0.01 if g("router_aux_loss_coef") is None else g("router_aux_loss_coef")
         ),
     )
+    return dataclasses.replace(mc, **deepseek) if deepseek else mc

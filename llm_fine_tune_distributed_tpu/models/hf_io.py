@@ -46,12 +46,52 @@ def _unflatten(flat: Dict[tuple, np.ndarray]):
     return tree
 
 
-def pytree_to_hf_state_dict(params) -> Dict[str, np.ndarray]:
-    """params pytree -> {hf_name: numpy array (torch layout)}."""
+# DeepSeek-V3 names of the grouped layer's stacked expert leaves (ops/moe.py)
+_DEEPSEEK_EXPERT = {"w1": "gate_proj", "w3": "up_proj", "w2": "down_proj"}
+
+
+def _rope_pairs(config: ModelConfig, path: tuple):
+    """For a latent-attention projection whose output holds rope dimensions:
+    the column permutation from DeepSeek's stored layout (each rotated pair
+    adjacent) to the layout the model rotates in (halves apart, what HF's
+    DeepseekV3 attention makes of q_pe and k_pe before it rotates), else
+    None. A dot product of q and k is the same under any permutation both
+    share, so only the two projections that produce rope dimensions move."""
+    if not config.kv_lora_rank or len(path) < 3 or path[-3] != "self_attn":
+        return None
+    dr = config.qk_rope_head_dim
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+    if path[-2] == "q_proj":
+        width = config.qk_nope_head_dim + dr
+        head = np.concatenate([np.arange(config.qk_nope_head_dim), config.qk_nope_head_dim + halves])
+        return (np.arange(config.num_heads)[:, None] * width + head[None, :]).reshape(-1)
+    if path[-2] == "kv_a_proj_with_mqa":
+        return np.concatenate([np.arange(config.kv_lora_rank), config.kv_lora_rank + halves])
+    return None
+
+
+def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dict[str, np.ndarray]:
+    """params pytree -> {hf_name: numpy array (torch layout)}. ``config`` is
+    needed for a model with latent attention or held experts (the rope
+    columns' stored order, the experts' global ids)."""
     state = {}
     for path, leaf in _flatten(params).items():
         arr = np.asarray(leaf)
         leaf_name = path[-1]
+        if len(path) >= 3 and path[-3] == "mlp" and path[-2] == "experts" and leaf_name in _DEEPSEEK_EXPERT:
+            # the grouped layer's [E_held, in, out] -> DeepSeek-V3's
+            # `mlp.experts.<global id>.{gate,up,down}_proj.weight [out, in]`
+            if config is None:
+                raise ValueError("exporting a model of routed experts with shared experts needs its config")
+            base = ".".join(path[:-1])
+            for row, expert in enumerate(config.held_expert_ids):
+                state[f"{base}.{expert}.{_DEEPSEEK_EXPERT[leaf_name]}.weight"] = np.ascontiguousarray(arr[row].T)
+            continue
+        if leaf_name == _KERNEL_LEAF and "kv_a_proj_with_mqa" in path and config is None:
+            raise ValueError("exporting a model with latent attention needs its config")
+        pairs = _rope_pairs(config, path) if config is not None and leaf_name == _KERNEL_LEAF else None
+        if pairs is not None:
+            arr = arr[:, np.argsort(pairs)]  # back to the stored order
         if len(path) >= 2 and path[-2] == "experts" and leaf_name in ("w1", "w2", "w3"):
             # Stacked MoE expert weights [E, in, out] (ops/moe.py) -> HF
             # Mixtral's per-expert Linears `...experts.<i>.w<n>.weight [out, in]`
@@ -85,10 +125,14 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
             for part in (
                 "q_proj", "k_proj", "v_proj", "o_proj",
                 "gate_proj", "up_proj", "down_proj", "lm_head",
-                "block_sparse_moe.gate",
+                "block_sparse_moe.gate", "kv_a_proj_with_mqa", "kv_b_proj", "mlp.gate.",
             )
         )
 
+    deepseek_re = re.compile(r"^(.*\.mlp\.experts)\.(\d+)\.(gate_proj|up_proj|down_proj)\.weight$")
+    stacked_name = {v: k for k, v in _DEEPSEEK_EXPERT.items()}
+    held_row = {expert: row for row, expert in enumerate(config.held_expert_ids)}
+    held: Dict[tuple, Dict[int, np.ndarray]] = {}
     expert_re = re.compile(r"^(.*\.experts)\.(\d+)\.(w[123])\.weight$")
     experts: Dict[tuple, Dict[int, np.ndarray]] = {}
     flat: Dict[tuple, np.ndarray] = {}
@@ -96,6 +140,14 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
         arr = np.asarray(arr)
         if dtype is not None:
             arr = arr.astype(dtype)
+        m = deepseek_re.match(name)
+        if m:
+            # DeepSeek-V3 per-expert Linear [out, in] -> row of the stacked
+            # [E_held, in, out] leaf; experts held elsewhere are passed over
+            if int(m.group(2)) in held_row:
+                key = tuple(m.group(1).split(".")) + (stacked_name[m.group(3)],)
+                held.setdefault(key, {})[held_row[int(m.group(2))]] = np.ascontiguousarray(arr.T)
+            continue
         m = expert_re.match(name)
         if m:
             # HF Mixtral per-expert Linear [out, in] -> row of the stacked
@@ -106,6 +158,9 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
         if needs_transpose(name):
             path = tuple(name[: -len(".weight")].split(".")) + (_KERNEL_LEAF,)
             arr = np.ascontiguousarray(arr.T)
+            pairs = _rope_pairs(config, path)
+            if pairs is not None:
+                arr = np.ascontiguousarray(arr[:, pairs])
         else:
             path = tuple(name.split("."))
         flat[path] = arr
@@ -123,6 +178,12 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
                 f"but config.num_experts={n}"
             )
         flat[key] = np.stack([rows[i] for i in range(n)])
+
+    for key, rows in held.items():
+        missing = [e for e, row in held_row.items() if row not in rows]
+        if missing:
+            raise ValueError(f"checkpoint is missing held experts {missing} for {'.'.join(key)}")
+        flat[key] = np.stack([rows[i] for i in range(len(held_row))])
 
     if config.tie_word_embeddings:
         flat.pop(("lm_head", _KERNEL_LEAF), None)
@@ -193,13 +254,14 @@ def save_hf_checkpoint(
     *,
     metadata: Optional[Dict[str, str]] = None,
     save_dtype=None,
+    config: Optional[ModelConfig] = None,
 ):
     """Write params as HF-layout safetensors under ``path`` (sharding files at
     4GB like HF does). Produces ``model.safetensors`` or shards + index."""
     from safetensors.numpy import save_file
 
     os.makedirs(path, exist_ok=True)
-    state = pytree_to_hf_state_dict(params)
+    state = pytree_to_hf_state_dict(params, config)
     if save_dtype is not None:
         state = {k: v.astype(save_dtype) for k, v in state.items()}
 

@@ -69,12 +69,24 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
 
     layers = {}
     for i in range(config.num_layers):
-        attn = {
-            "q_proj": {"kernel": dense(next(keys), (h, qd))},
-            "k_proj": {"kernel": dense(next(keys), (h, kvd))},
-            "v_proj": {"kernel": dense(next(keys), (h, kvd))},
-            "o_proj": {"kernel": dense(next(keys), (qd, h))},
-        }
+        if config.kv_lora_rank:
+            # latent attention (HF DeepseekV3Attention names, q_lora_rank null)
+            nh, r = config.num_heads, config.kv_lora_rank
+            dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+            attn = {
+                "q_proj": {"kernel": dense(next(keys), (h, nh * (dn + dr)))},
+                "kv_a_proj_with_mqa": {"kernel": dense(next(keys), (h, r + dr))},
+                "kv_a_layernorm": {"weight": jnp.ones((r,), dtype)},
+                "kv_b_proj": {"kernel": dense(next(keys), (r, nh * (dn + dv)))},
+                "o_proj": {"kernel": dense(next(keys), (nh * dv, h))},
+            }
+        else:
+            attn = {
+                "q_proj": {"kernel": dense(next(keys), (h, qd))},
+                "k_proj": {"kernel": dense(next(keys), (h, kvd))},
+                "v_proj": {"kernel": dense(next(keys), (h, kvd))},
+                "o_proj": {"kernel": dense(next(keys), (qd, h))},
+            }
         if config.attention_bias:
             # HF Llama applies attention_bias to q/k/v/o alike; Qwen2 skips
             # the o_proj bias (attention_out_bias=False).
@@ -102,6 +114,10 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
             # consumes one key (split internally); a model is uniformly MoE
             # or dense so per-layer key alignment needs no padding
             layer["block_sparse_moe"] = init_moe_params(next(keys), config, dtype)
+        elif config.layer_has_experts(i):
+            from llm_fine_tune_distributed_tpu.ops.moe import init_grouped_moe_params
+
+            layer["mlp"] = init_grouped_moe_params(next(keys), config, dtype)
         else:
             mlp = {
                 "gate_proj": {"kernel": dense(next(keys), (h, f))},
@@ -222,7 +238,10 @@ def _block(
     adapter_idx=None,
     w8a8: bool = False,
 ):
-    """One transformer block. Returns (x, new_cache_entry, moe_aux).
+    """One transformer block. Returns (x, new_cache_entry, moe_aux,
+    expert_load): ``expert_load`` is the (token, expert) pairs of each held
+    expert in a layer of routed experts with shared experts
+    (``config.layer_has_experts``), None elsewhere.
 
     ``rope_flag`` (traced bool scalar) overrides the static
     ``config.uses_rope(layer_idx)`` decision — used by the pipeline's
@@ -241,21 +260,32 @@ def _block(
 
     with scope("attn"):
         hid = rms_norm(x, lp["input_layernorm"]["weight"], eps, zero_centered=zc)
-        q = _linear(hid, attn_p["q_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_heads, d)
-        k = _linear(hid, attn_p["k_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
-        v = _linear(hid, attn_p["v_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
+        if config.kv_lora_rank:
+            if cache_entry is not None:
+                raise NotImplementedError(
+                    "latent attention (kv_lora_rank) has the training form only; its cache is a third layout"
+                )
+            q, k, v = _latent_qkv(
+                attn_p, hid, cos, sin, config,
+                lambda t, p: _linear(t, p, compute_dtype, quant_impl, adapter_idx, w8a8),
+            )
+            d = config.v_head_dim
+        else:
+            q = _linear(hid, attn_p["q_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_heads, d)
+            k = _linear(hid, attn_p["k_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
+            v = _linear(hid, attn_p["v_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
 
-        if config.qk_norm:
-            # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
-            q = rms_norm(q, attn_p["q_norm"]["weight"], eps)
-            k = rms_norm(k, attn_p["k_norm"]["weight"], eps)
+            if config.qk_norm:
+                # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
+                q = rms_norm(q, attn_p["q_norm"]["weight"], eps)
+                k = rms_norm(k, attn_p["k_norm"]["weight"], eps)
 
-        if rope_flag is not None:
-            qr, kr = apply_rope(q, k, cos, sin)
-            q = jnp.where(rope_flag, qr, q)
-            k = jnp.where(rope_flag, kr, k)
-        elif config.uses_rope(layer_idx):
-            q, k = apply_rope(q, k, cos, sin)
+            if rope_flag is not None:
+                qr, kr = apply_rope(q, k, cos, sin)
+                q = jnp.where(rope_flag, qr, q)
+                k = jnp.where(rope_flag, kr, k)
+            elif config.uses_rope(layer_idx):
+                q, k = apply_rope(q, k, cos, sin)
 
         new_entry = None
         paged_quant = None  # int8 paged pool: (ck, cv, k_scale, v_scale, pos)
@@ -416,7 +446,24 @@ def _block(
         )
         hid = rms_norm(x, lp[pre_ffn]["weight"], eps, zero_centered=zc)
         aux = jnp.float32(0.0)
-        if config.num_experts > 0:
+        expert_load = None
+        if config.layer_has_experts(layer_idx):
+            from llm_fine_tune_distributed_tpu.ops.moe import grouped_moe_mlp
+
+            if mesh is not None and dict(mesh.shape).get("expert", 1) > 1:
+                raise NotImplementedError(
+                    "grouped experts over a mesh's expert axis: the exchange is not written yet "
+                    "(ROADMAP.md, Reach A); name this process's share in ModelConfig.held_experts"
+                )
+            routed, expert_load = grouped_moe_mlp(lp["mlp"], hid, config, compute_dtype)
+            with scope("shared_expert"):
+                shared = lp["mlp"].get("shared_experts")
+                if shared is not None:
+                    lin = lambda t, name: _linear(t, shared[name], compute_dtype, quant_impl, adapter_idx, w8a8)  # noqa: E731
+                    prod = checkpoint_name(jax.nn.silu(lin(hid, "gate_proj")) * lin(hid, "up_proj"), "mlp_act")
+                    routed = routed + lin(prod, "down_proj")
+            x = x + routed
+        elif config.num_experts > 0:
             from llm_fine_tune_distributed_tpu.ops.moe import moe_mlp
 
             # token-level real/pad mask for routing: packed batches encode pads
@@ -460,7 +507,28 @@ def _block(
                     mlp_out, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc
                 )
             x = x + mlp_out
-    return x, new_entry, aux
+    return x, new_entry, aux, expert_load
+
+
+def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, linear):
+    """q, k, v of latent attention (MLA) in its training form, for the
+    ordinary attention paths: ``hid [b, s, h]`` -> q, k ``[b, s, heads,
+    qk_nope + qk_rope]`` and v ``[b, s, heads, v_head_dim]``. k and v come up
+    from one normed latent of ``kv_lora_rank``; the rope key (one per token)
+    is rotated once and shared by every head. ``cos``/``sin`` are tables of
+    ``qk_rope_head_dim``; halves are rotated (HF de-interleaves DeepSeek's
+    stored pairs first; ``models/hf_io.py`` does that to the weights)."""
+    b, s, _ = hid.shape
+    nh, r = config.num_heads, config.kv_lora_rank
+    dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    q = linear(hid, attn_p["q_proj"]).reshape(b, s, nh, dn + dr)
+    latent = linear(hid, attn_p["kv_a_proj_with_mqa"])
+    c_kv = rms_norm(latent[..., :r], attn_p["kv_a_layernorm"]["weight"], config.rms_norm_eps)
+    kv = linear(c_kv, attn_p["kv_b_proj"]).reshape(b, s, nh, dn + dv)
+    q_pe, k_pe = apply_rope(q[..., dn:], latent[..., r:].reshape(b, s, 1, dr), cos, sin)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, nh, dr))], axis=-1)
+    return q, k, kv[..., dn:]
 
 
 def forward(
@@ -483,6 +551,7 @@ def forward(
     output_hidden: bool = False,
     quant_impl: str = "auto",
     return_aux: bool = False,
+    return_expert_load: bool = False,
     adapter_idx=None,
     frozen_layers: int = 0,
     frozen_compute: str = "bf16",
@@ -527,6 +596,10 @@ def forward(
       return_aux: also return the summed MoE load-balancing loss as a third
         element ``(out, cache, aux)`` — 0.0 for dense models. The train step
         requests it when ``config.num_experts > 0``.
+      return_expert_load: also return, last, ``[expert layers, held experts]``
+        int32: the (token, expert) pairs each held expert of each layer of
+        routed experts (``config.n_routed_experts``) was given. The train
+        step's counters are made of it.
       activation_sharding: optional ``NamedSharding`` for the [batch, seq,
         hidden] activations (normally batch over (data, fsdp)). Constraining
         activations explicitly keeps XLA/Shardy propagation on the intended
@@ -582,7 +655,8 @@ def forward(
             # the activation dtype first — mirror the cast for bf16 bit-parity
             x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
     cos, sin = rope_cos_sin(
-        positions, config.resolved_head_dim, config.rope_theta, config=config
+        positions, config.qk_rope_head_dim if config.kv_lora_rank else config.resolved_head_dim,
+        config.rope_theta, config=config,
     )
 
     explicit_mask = None
@@ -632,6 +706,7 @@ def forward(
             windowed_mask = explicit_mask & (k_pos > q_pos - config.sliding_window)
 
     new_layers = {}
+    expert_loads = []
     moe_aux = jnp.float32(0.0)
     # Frozen-trunk fast path (TrainConfig.frozen_compute="int8"): layers
     # [0, frozen_layers) carry pre-quantized kernel_int8 siblings and run
@@ -684,7 +759,7 @@ def forward(
                     )
                 block_fn = jax.checkpoint(block_fn, policy=policies[remat_policy])
         with scope("layer", i):
-            x, new_entry, layer_aux = block_fn(
+            x, new_entry, layer_aux, layer_load = block_fn(
                 params["model"]["layers"][str(i)],
                 x,
                 cos,
@@ -704,6 +779,8 @@ def forward(
             # backward is dead code the compiler eliminates.
             x = jax.lax.stop_gradient(x)
         moe_aux = moe_aux + layer_aux
+        if layer_load is not None:
+            expert_loads.append(layer_load)
         if new_entry is not None:
             new_layers[str(i)] = new_entry
 
@@ -723,9 +800,11 @@ def forward(
             out = unembed(
                 params, x, config, compute_dtype=compute_dtype, logits_dtype=logits_dtype, mesh=mesh
             )
-    if return_aux:
-        return out, new_cache, moe_aux
-    return out, new_cache
+    result = (out, new_cache) + ((moe_aux,) if return_aux else ())
+    if return_expert_load:
+        no_layers = jnp.zeros((0, len(config.held_expert_ids)), jnp.int32)
+        result += (jnp.stack(expert_loads) if expert_loads else no_layers,)
+    return result
 
 
 def _lookup_table_constraint(table, mesh, vocab_dim: int = 0):

@@ -468,6 +468,8 @@ def annotate(name: str):
 # ``rematted_computation``).
 STEP_SCOPES = (
     "embed", "layer", "attn", "mlp", "final_norm", "loss_head", "grad_accum", "optimizer",
+    # inside "mlp", in a layer of routed experts with shared experts
+    "router", "experts", "shared_expert",
 )
 
 

@@ -115,6 +115,7 @@ def xla_attention(
     b, q_len, num_heads, head_dim = q.shape
     kv_len, num_kv = k.shape[1], k.shape[2]
     groups = num_heads // num_kv
+    v_dim = v.shape[3]  # latent attention: v heads narrower than q/k heads
 
     if scale is None:
         scale = 1.0 / jnp.sqrt(head_dim).astype(jnp.float32)
@@ -143,7 +144,7 @@ def xla_attention(
 
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, q_len, num_heads, head_dim).astype(q.dtype)
+    return out.reshape(b, q_len, num_heads, v_dim).astype(q.dtype)
 
 
 def _seq_parallel_fallback(impl: str, q, mesh) -> str:

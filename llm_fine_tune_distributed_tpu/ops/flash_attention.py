@@ -84,16 +84,19 @@ def _tiled_bytes(shape, dtype) -> int:
     return int(np.prod(lead, dtype=np.int64)) * rows * lanes * itemsize
 
 
-def _vmem_budget(operands) -> int:
+def _vmem_budget(operands, d: int = 128) -> int:
     """``operands``: (block_shape, dtype, index_map) of every pipelined
     operand of a kernel, inputs and outputs. Each kernel below lists its
     operands once; its BlockSpecs and its budget are both built from that
-    list, so the two cannot drift apart."""
-    return 2 * sum(_tiled_bytes(s, d) for s, d, _ in operands) + _VMEM_BODY_BYTES
+    list, so the two cannot drift apart. ``d``: the q/k head width; the body's
+    float32 copies of its blocks and its accumulators grow with it, so heads
+    of two lane registers (latent attention's 192, padded to 256) get twice
+    the body's room."""
+    return 2 * sum(_tiled_bytes(s, t) for s, t, _ in operands) + _VMEM_BODY_BYTES * max(1, _lanes(d) // 128)
 
 
-def _compiler_params(operands):
-    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_budget(operands))
+def _compiler_params(operands, d: int = 128):
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_budget(operands, d))
 
 
 def _block_specs(operands):
@@ -181,7 +184,8 @@ def _diagonal_pieces(block, *, own):
 def _fwd_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale):
     batch, iq = pl.program_id(0), pl.program_id(2)
     q = _operand(q_ref[0, 0])  # [B, d]
-    block, d = q.shape
+    block = q.shape[0]
+    d_v = v_ref.shape[3]  # v heads may be narrower than q/k heads (latent attention)
     q_start = iq * block
 
     def program(*, segments):
@@ -234,7 +238,7 @@ def _fwd_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, o_ref,
         carry = (
             jnp.full((block, 1), _NEG_INF, jnp.float32),
             jnp.zeros((block, 1), jnp.float32),
-            jnp.zeros((block, d), jnp.float32),
+            jnp.zeros((block, d_v), jnp.float32),
         )
         if k_ref.shape[2] > block:
             carry = jax.lax.fori_loop(
@@ -276,21 +280,21 @@ _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 def _fwd(q, k, v, segments, *, scale, block, groups, interpret):
     b, hq, sq, d = q.shape
-    sk = k.shape[2]
+    sk, d_v = k.shape[2], v.shape[3]
     out_shape = (
-        jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        jax.ShapeDtypeStruct((b, hq, sq, d_v), q.dtype),
         # trailing unit dim: TPU tiling wants the block's last dim equal to
         # the array's (1) and the second-to-last divisible by 8 (block)
         jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
     )
-    ins, outs = _fwd_operands(q.dtype, sk, d, block, groups)
+    ins, outs = _fwd_operands(q.dtype, sk, d, block, groups, d_v)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale),
         grid=(b, hq, sq // block),
         in_specs=[_SMEM] + _block_specs(ins),
         out_specs=tuple(_block_specs(outs)),
         out_shape=out_shape,
-        compiler_params=_compiler_params(ins + outs),
+        compiler_params=_compiler_params(ins + outs, d),
         interpret=interpret,
         name="flash_attention_fwd",
     )(
@@ -299,9 +303,12 @@ def _fwd(q, k, v, segments, *, scale, block, groups, interpret):
     )
 
 
-def _fwd_operands(dtype, sk, d, block, groups):
+def _fwd_operands(dtype, sk, d, block, groups, d_v=None):
     """Grid (batch, q head, q block): segments (column, key rows), q, k, v
-    -> o, lse. (The one-segment flags ride in SMEM, outside this list.)"""
+    -> o, lse. (The one-segment flags ride in SMEM, outside this list.)
+    ``d`` is the width of q and k heads, ``d_v`` that of v and o heads (``d``
+    where it is not given)."""
+    d_v = d_v or d
     q_blk = lambda b_, h, i: (b_, h, i, 0)  # noqa: E731
     kv_head = lambda b_, h, i: (b_, h // groups, 0, 0)  # noqa: E731
     ins = [
@@ -309,18 +316,19 @@ def _fwd_operands(dtype, sk, d, block, groups):
         ((1, sk // block, 1, block), jnp.int32, lambda b_, h, i: (b_, 0, 0, 0)),
         ((1, 1, block, d), dtype, q_blk),
         ((1, 1, sk, d), dtype, kv_head),
-        ((1, 1, sk, d), dtype, kv_head),
+        ((1, 1, sk, d_v), dtype, kv_head),
     ]
     outs = [
-        ((1, 1, block, d), dtype, q_blk),
+        ((1, 1, block, d_v), dtype, q_blk),
         ((1, 1, block, 1), jnp.float32, q_blk),
     ]
     return ins, outs
 
 
-def _dq_operands(dtype, sq, d, block, groups):
+def _dq_operands(dtype, sq, d, block, groups, d_v=None):
     """Grid (batch, q head, q block): segments (column, key rows), q, k, v,
     do, lse, delta -> dq."""
+    d_v = d_v or d
     q_blk = lambda b_, h, i: (b_, h, i, 0)  # noqa: E731
     kv_head = lambda b_, h, i: (b_, h // groups, 0, 0)  # noqa: E731
     ins = [
@@ -328,8 +336,8 @@ def _dq_operands(dtype, sq, d, block, groups):
         ((1, sq // block, 1, block), jnp.int32, lambda b_, h, i: (b_, 0, 0, 0)),
         ((1, 1, block, d), dtype, q_blk),
         ((1, 1, sq, d), dtype, kv_head),
-        ((1, 1, sq, d), dtype, kv_head),
-        ((1, 1, block, d), dtype, q_blk),
+        ((1, 1, sq, d_v), dtype, kv_head),
+        ((1, 1, block, d_v), dtype, q_blk),
         ((1, 1, block, 1), jnp.float32, q_blk),
         ((1, 1, block, 1), jnp.float32, q_blk),
     ]
@@ -337,21 +345,22 @@ def _dq_operands(dtype, sq, d, block, groups):
     return ins, outs
 
 
-def _dkv_operands(dtype, sq, d, block, groups):
+def _dkv_operands(dtype, sq, d, block, groups, d_v=None):
     """Grid (batch, KV head, k block): the q/do/lse/delta blocks span the
     head's whole query group -> dk, dv at KV-head width."""
+    d_v = d_v or d
     group = lambda b_, h, j: (b_, h, 0, 0)  # noqa: E731
     k_blk = lambda b_, h, j: (b_, h, j, 0)  # noqa: E731
     ins = [
         ((1, sq, 1), jnp.int32, lambda b_, h, j: (b_, 0, 0)),
         ((1, groups, sq, d), dtype, group),
         ((1, 1, block, d), dtype, k_blk),
-        ((1, 1, block, d), dtype, k_blk),
-        ((1, groups, sq, d), dtype, group),
+        ((1, 1, block, d_v), dtype, k_blk),
+        ((1, groups, sq, d_v), dtype, group),
         ((1, groups, sq, 1), jnp.float32, group),
         ((1, groups, sq, 1), jnp.float32, group),
     ]
-    outs = [((1, 1, block, d), dtype, k_blk), ((1, 1, block, d), dtype, k_blk)]
+    outs = [((1, 1, block, d), dtype, k_blk), ((1, 1, block, d_v), dtype, k_blk)]
     return ins, outs
 
 
@@ -429,6 +438,7 @@ def _dkv_kernel(one_segment_ref, seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
     k_all = _operand(k_ref[0, 0])  # [B, d]
     v_all = _operand(v_ref[0, 0])
     block, d = k_all.shape
+    d_v = v_all.shape[1]
     k_start = jk * block
     pieces = _diagonal_pieces(block, own="keys")
 
@@ -483,7 +493,7 @@ def _dkv_kernel(one_segment_ref, seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
                 )
             return whole, on_diagonal
 
-        zeros = lambda n: (jnp.zeros((n, d), jnp.float32),) * 2  # noqa: E731
+        zeros = lambda n: (jnp.zeros((n, d), jnp.float32), jnp.zeros((n, d_v), jnp.float32))  # noqa: E731
         (dk, dv), on_diagonal = jax.lax.fori_loop(
             0, groups, head, (zeros(block), tuple(zeros(k_n) for _, k_n, _, _ in pieces))
         )
@@ -498,18 +508,18 @@ def _dkv_kernel(one_segment_ref, seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
 def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
     """Head-major inputs: q/o/do/lse [b, hq, ...], k/v [b, hkv, s, d]."""
     b, hq, sq, d = q.shape
-    hkv = k.shape[1]
+    hkv, d_v = k.shape[1], v.shape[3]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # [b,hq,sq,1]
     seg_column = segments[:, :, None]
 
-    ins, outs = _dq_operands(q.dtype, sq, d, block, groups)
+    ins, outs = _dq_operands(q.dtype, sq, d, block, groups, d_v)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale),
         grid=(b, hq, sq // block),
         in_specs=[_SMEM] + _block_specs(ins),
         out_specs=_block_specs(outs)[0],
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        compiler_params=_compiler_params(ins + outs),
+        compiler_params=_compiler_params(ins + outs, d),
         interpret=interpret,
         name="flash_attention_dq",
     )(
@@ -517,7 +527,7 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
         seg_column, _key_rows(segments, block), q, k, v, do, lse, delta,
     )
 
-    ins, outs = _dkv_operands(q.dtype, sq, d, block, groups)
+    ins, outs = _dkv_operands(q.dtype, sq, d, block, groups, d_v)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, groups=groups),
         grid=(b, hkv, sq // block),
@@ -525,9 +535,9 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
         out_specs=tuple(_block_specs(outs)),
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, sq, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, sq, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, sq, d_v), v.dtype),
         ),
-        compiler_params=_compiler_params(ins + outs),
+        compiler_params=_compiler_params(ins + outs, d),
         interpret=interpret,
         name="flash_attention_dkv",
     )(_one_segment(segments, block, from_start=False), seg_column, q, k, v, do, lse, delta)
@@ -587,6 +597,11 @@ def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
 _ONE_BLOCK_SEQ = 2048
 
 
+def _lanes(d: int) -> int:
+    """``d`` rounded up to whole 128-lane registers."""
+    return -(-d // 128) * 128
+
+
 def _pick_block(s: int) -> int:
     import os
 
@@ -616,7 +631,7 @@ def flash_unsupported_reason(
     at trace time by ops/attention.py, which records the reason beside the
     path it took instead."""
     b, sq, hq, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, d_v = k.shape[1], k.shape[2], v.shape[3]
     if jax.default_backend() != "tpu":
         return f"backend is {jax.default_backend()}, the kernel is compiled for TPU only"
     if not causal or sliding_window is not None:
@@ -626,14 +641,17 @@ def flash_unsupported_reason(
     block = _pick_block(sq)
     if block == 0:
         return f"seq {sq} is not a multiple of 128"
-    if d % 128 != 0:
-        return f"head dim {d} is not a multiple of the 128 lanes"
+    if d_v % 128 != 0 or (d % 128 != 0 and d == d_v):
+        # v heads fill whole lane registers; q/k heads wider than them (latent
+        # attention: 192 against 128) are taken as they lie; one head size for
+        # all three has to be aligned
+        return f"head dim {d_v} is not a multiple of the 128 lanes"
     if hq % hkv:
         return f"q heads {hq} not a multiple of kv heads {hkv}"
     # the dk/dv kernel holds a whole kv head's query group in VMEM and is the
     # largest of the three; a shape it cannot hold takes xla/ring instead of
     # dying inside the compiler (at SmolLM3 head shapes: seq <= 6144)
-    need = _vmem_budget(sum(_dkv_operands(q.dtype, sq, d, block, hq // hkv), []))
+    need = _vmem_budget(sum(_dkv_operands(q.dtype, sq, d, block, hq // hkv, d_v), []), d)
     if need > _VMEM_CAP_BYTES:
         return (
             f"backward needs {need >> 20} MiB of VMEM at seq {sq}, over the "
@@ -825,7 +843,16 @@ def paged_decode_attention(
 def pallas_flash_attention(
     q, k, v, *, padding_mask=None, segment_ids=None, interpret: bool = False
 ):
-    """q [b, sq, hq, d], k/v [b, sk, hkv, d] -> [b, sq, hq, d] (q.dtype).
+    """q [b, sq, hq, d], k [b, sk, hkv, d], v [b, sk, hkv, d_v] ->
+    [b, sq, hq, d_v] (q.dtype). The softmax scale is ``d ** -0.5``.
+
+    q/k heads may be wider than v heads and need not fill whole lane
+    registers (latent attention: 128 + 64 rope dimensions against v heads of
+    128). Mosaic takes the 192 lanes as they lie: in VMEM a block pads to 256
+    and the MXU contracts 192 in two passes of 128 either way, so padding q
+    and k with zero lanes outside the kernel buys nothing; measured on a v5e it
+    cost 6% of the forward and 4% of forward + backward in the pad's copies
+    (PERF.md, PR 26).
 
     Masking is expressed as per-position segments [b, sk] int32: attention
     flows only within equal segment ids (plus causal). ``segment_ids`` comes

@@ -21,6 +21,12 @@ the Mixtral family with a TPU-first design:
   ``E * sum_e fraction_dispatched_e * mean_router_prob_e``, returned
   unscaled; the train step weights it by ``config.router_aux_coef``.
 
+A second routed-experts layer lives below (``grouped_moe_mlp``): many narrow
+experts behind a sigmoid router, shared experts beside them, no capacity and
+no dropped token, work that grows with the (token, expert) pairs held here.
+Folding the two dispatches into one is a later ``simplicity`` issue
+(ROADMAP.md, Design).
+
 Weight layout mirrors HF Mixtral names (models/hf_io.py stacks the
 per-expert torch Linears): ``block_sparse_moe/gate/kernel [h, E]``,
 ``block_sparse_moe/experts/{w1,w3} [E, h, f]`` (gate/up), ``w2 [E, f, h]``
@@ -33,6 +39,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -201,3 +208,252 @@ def init_moe_params(rng, config: ModelConfig, dtype):
             "w2": dense(k2, (e, f, h)),
         },
     }
+
+
+# ---------------------------------------------------------------------------
+# Routed experts with shared experts (HF DeepseekV3MoE): grouped dispatch
+# ---------------------------------------------------------------------------
+
+# What the router computes in: the scores' matrix product, the sigmoid, the
+# selection and the combine weights. HF multiplies hidden.float() by
+# weight.float(); near-ties among the top k flip on less. (The benchmark's
+# control sets float8_e4m3fn here and has to come out not correct.)
+ROUTER_DTYPE = jnp.float32
+
+
+def route(gate, x, config: ModelConfig):
+    """``x [T, h]`` -> (expert ids ``[T, k]`` int32, combine weights ``[T, k]``
+    float32). Sigmoid scores over all ``n_routed_experts``; the top k of
+    scores + ``e_score_correction_bias`` (a buffer: it selects, it does not
+    weigh, and no gradient reaches it); weights are the selected scores over
+    their sum, times ``routed_scaling_factor``."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(
+            x.astype(ROUTER_DTYPE), gate["kernel"].astype(ROUTER_DTYPE),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=ROUTER_DTYPE,
+        )
+    )
+    bias = jax.lax.stop_gradient(gate["e_score_correction_bias"]).astype(ROUTER_DTYPE)
+    _, top_i = jax.lax.top_k(scores + bias, config.num_experts_per_tok)
+    # the chosen scores by a one-hot product: exact; take_along_axis's gather
+    # of k of E values a token cost 1 ms a call on a v5e (PR 26)
+    top_s = (jax.nn.one_hot(top_i, scores.shape[-1], dtype=scores.dtype) * scores[:, None, :]).sum(-1)
+    weights = top_s / (top_s.sum(-1, keepdims=True) + 1e-20) * config.routed_scaling_factor
+    return top_i.astype(jnp.int32), weights.astype(jnp.float32)
+
+
+# (rows, contraction, columns) tile of the TPU's grouped product. On a v5e at
+# 8192 rows against 8 experts of 2048 x 1408 (PERF.md, PR 26): the kernel's
+# default of 128 each is 7 times slower, and the next larger tiles ask for
+# more scoped VMEM than a kernel gets unasked (megablox sets no limit).
+GMM_TILING = (512, 1024, 1024)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, impl=None):
+    """``lhs [m, k]`` whose rows lie grouped by expert, ``rhs [E, k, n]``,
+    ``group_sizes [E]`` (sum <= m) -> ``[m, n]``: each group's rows times its
+    expert's matrix. Rows past the groups' total are undefined.
+
+    On a TPU it is megablox ``gmm`` (a Pallas kernel that visits only the row
+    tiles the groups fill, so empty rows cost nothing); elsewhere
+    ``jax.lax.ragged_dot``, which XLA runs anywhere. ``impl`` overrides the
+    choice (``"gmm_interpret"``: the kernel under the Pallas interpreter)."""
+    impl = impl or ("gmm" if jax.default_backend() == "tpu" else "ragged_dot")
+    if impl == "ragged_dot":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    tiling = tuple(min(tile, size) for tile, size in zip(GMM_TILING, (m, k, rhs.shape[2])))
+    return gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype, tiling, interpret=impl == "gmm_interpret")
+
+
+# Moving rows between token order and sorted-pair order, both ways by GATHER.
+# A chunk holds C sorted pairs; ``tokens [C]`` is each pair's token and
+# ``rank [T, k]`` each (token, choice)'s row in the chunk (valid where
+# 0 <= rank < n_valid). Taking the pairs' rows out of the tokens and summing
+# the pairs' rows back into their tokens are each other's transposes, and each
+# is written as a gather with a mask; left to autodiff the transposes are row
+# scatters, which on a TPU cost several times the grouped products (PERF.md,
+# PR 26: 4.4 ms a scatter of 8192 rows against 1.7 ms for an expert's SwiGLU).
+
+
+def _rows_of_tokens(xf, tokens, n_valid):
+    valid = jnp.arange(tokens.shape[0]) < n_valid
+    return jnp.where(valid[:, None], xf[tokens], 0)
+
+
+def _sum_into_tokens(rows, rank, n_valid):
+    total = jnp.zeros((rank.shape[0], rows.shape[1]), jnp.float32)
+    for j in range(rank.shape[1]):  # k gathers of [T, h]: never [T, k, h] at once
+        r = rank[:, j]
+        hit = (r >= 0) & (r < n_valid)
+        total = total + jnp.where(hit[:, None], rows[jnp.clip(r, 0, rows.shape[0] - 1)], 0).astype(jnp.float32)
+    return total
+
+
+@jax.custom_vjp
+def take_rows(xf, tokens, rank, n_valid):
+    """``xf [T, h]`` -> the chunk's rows ``[C, h]`` (zeros past ``n_valid``)."""
+    return _rows_of_tokens(xf, tokens, n_valid)
+
+
+def _take_rows_fwd(xf, tokens, rank, n_valid):
+    return _rows_of_tokens(xf, tokens, n_valid), (tokens, rank, n_valid, jnp.zeros((0,), xf.dtype))
+
+
+def _take_rows_bwd(res, d):
+    tokens, rank, n_valid, like = res
+    return _sum_into_tokens(d, rank, n_valid).astype(like.dtype), None, None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def sum_rows(rows, tokens, rank, n_valid):
+    """The chunk's float32 rows ``[C, h]`` summed into their tokens, ``[T, h]``."""
+    return _sum_into_tokens(rows, rank, n_valid)
+
+
+def _sum_rows_fwd(rows, tokens, rank, n_valid):
+    return _sum_into_tokens(rows, rank, n_valid), (tokens, n_valid)
+
+
+def _sum_rows_bwd(res, d):
+    tokens, n_valid = res
+    return _rows_of_tokens(d, tokens, n_valid), None, None, None
+
+
+sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+@jax.custom_vjp
+def permute(x, order, rank):
+    """``x[order]`` for a permutation ``order`` with inverse ``rank``; the
+    transpose gathers by ``rank`` instead of scattering by ``order``."""
+    return x[order]
+
+
+permute.defvjp(lambda x, order, rank: (x[order], (order, rank)), lambda res, d: (d[res[1]], None, None))
+
+
+def _expert_rows(experts, xin, weights, sizes, n_valid, compute_dtype, impl):
+    """One chunk of sorted pairs through its experts: three grouped products
+    over the pairs' rows ``xin [C, h]``, each row scaled by its combine
+    weight. ``sizes [E]``: rows of each held expert in this chunk;
+    ``n_valid``: how many of the C rows are pairs at all (a grouped product
+    leaves the rows past them undefined). Returns ``[C, h]`` float32 with
+    zeros past ``n_valid``."""
+    w1, w3, w2 = (experts[n].astype(compute_dtype) for n in ("w1", "w3", "w2"))
+    act = jax.nn.silu(grouped_matmul(xin, w1, sizes, impl=impl)) * grouped_matmul(xin, w3, sizes, impl=impl)
+    out = grouped_matmul(act, w2, sizes, impl=impl).astype(jnp.float32)
+    # masked BEFORE the weights meet it: what lies past the pairs is whatever
+    # the kernel found there, and zero times that is not zero in a gradient
+    valid = jnp.arange(xin.shape[0]) < n_valid
+    return jnp.where(valid[:, None], out, 0.0) * weights[:, None]
+
+
+def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
+    """Routed part of a DeepSeek-V3-style expert layer, for the experts held
+    here. ``x [b, s, h]`` -> ``(y [b, s, h], load [E_held] int32)``.
+
+    ``lp``: ``gate/{kernel [h, n_routed_experts], e_score_correction_bias}``
+    and ``experts/{w1, w3 [E_held, h, f], w2 [E_held, f, h]}``, the rows in
+    the order of ``config.held_expert_ids``. The router is as wide as the
+    model's; a (token, expert) pair whose expert is held elsewhere adds
+    nothing here (on a mesh its owner adds it), but its score still stands
+    in the normaliser of the token's weights. No capacity, no dropped token:
+    the pairs held here are sorted by expert and taken ``b * s`` rows at a
+    time through grouped products; the first chunk covers up to one pair a
+    token (the expected load is k * E_held / n_routed_experts), further
+    chunks run only when the routing fills them, so the work follows the
+    pairs and not tokens x experts. ``load[e]`` counts the pairs of held
+    expert e (the step's counter)."""
+    from llm_fine_tune_distributed_tpu.observe.xla import scope
+
+    b, s, h = x.shape
+    t, k = b * s, config.num_experts_per_tok
+    held = config.held_expert_ids
+    n_held = len(held)
+    xf = x.reshape(t, h)
+
+    with scope("router"):
+        top_i, top_w = route(lp["gate"], xf, config)
+        # each pair's row among the held experts, n_held where held elsewhere
+        local_of = np.full((config.n_routed_experts,), n_held, np.int32)
+        local_of[list(held)] = np.arange(n_held)
+        local = jnp.asarray(local_of)[top_i].reshape(-1)          # [t * k]
+        order = jnp.argsort(local, stable=True)                   # held pairs first, by expert
+        rank = jnp.argsort(order).astype(jnp.int32)               # each pair's row in that order
+        # counted by compare and sum, the table above made on the host: as
+        # bincount and .at[].set they are scatters of a few integers, and the
+        # TPU compiler of this jaxlib aborts on them inside the step program
+        # (scatter_emitter.cc "operand_indices.size() == 1", PR 26)
+        load = (local[:, None] == jnp.arange(n_held)[None, :]).sum(0, dtype=jnp.int32)
+        ends = jnp.cumsum(load)
+        tokens = (order // k).astype(jnp.int32)
+        weights = permute(top_w.reshape(-1), order, rank)
+
+    def chunk(start):
+        """Rows [start, start + t) of the sorted pairs through the experts
+        and back into their tokens: ``[t, h]`` float32."""
+        in_chunk = lambda edge: jnp.clip(edge - start, 0, t)  # noqa: E731
+        sizes = in_chunk(ends) - in_chunk(ends - load)
+        n_valid = in_chunk(ends[-1])
+        chunk_tokens = jax.lax.dynamic_slice(tokens, (start,), (t,))
+        chunk_rank = rank.reshape(t, k) - start
+        rows = _expert_rows(
+            lp["experts"], take_rows(xf, chunk_tokens, chunk_rank, n_valid),
+            jax.lax.dynamic_slice(weights, (start,), (t,)), sizes, n_valid, compute_dtype, impl,
+        )
+        return sum_rows(rows, chunk_tokens, chunk_rank, n_valid)
+
+    with scope("experts"):
+        y = chunk(0)
+        overflow = min(k, n_held) - 1
+        if overflow > 0:
+            # Chunks past the first: a token's k choices can all be held here.
+            # The whole loop sits behind ONE branch on whether the routing
+            # fills more than the first chunk (inside it a chunk is skipped
+            # unless reached): a loop that only skips its chunks still adds
+            # k - 1 zero gradients the size of the experts in the backward
+            # pass (a tenth of the step on a v5e, PR 26). A chunk's
+            # activations are recomputed in the backward pass instead of
+            # saved: six chunks' would not fit beside the step's state.
+            def more(c, acc):
+                start = c * t
+                return acc + jax.lax.cond(
+                    ends[-1] > start, jax.checkpoint(chunk), lambda st: jnp.zeros((t, h), jnp.float32), start
+                )
+
+            y = y + jax.lax.cond(
+                ends[-1] > t,
+                lambda: jax.lax.fori_loop(1, overflow + 1, more, jnp.zeros((t, h), jnp.float32)),
+                lambda: jnp.zeros((t, h), jnp.float32),
+            )
+    return y.reshape(b, s, h).astype(x.dtype), load
+
+
+def init_grouped_moe_params(rng, config: ModelConfig, dtype):
+    """Random init of one expert layer's ``mlp`` subtree: router, the held
+    experts (rows in the order of ``config.held_expert_ids``), shared experts."""
+    h, f = config.hidden_size, config.moe_intermediate_size
+    e, n_held = config.n_routed_experts, len(config.held_expert_ids)
+    fs = f * config.n_shared_experts
+    kg, k1, k2, k3, ks1, ks2, ks3 = jax.random.split(rng, 7)
+
+    def dense(key, shape):
+        return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+
+    out = {
+        "gate": {"kernel": dense(kg, (h, e)), "e_score_correction_bias": jnp.zeros((e,), dtype)},
+        "experts": {"w1": dense(k1, (n_held, h, f)), "w3": dense(k3, (n_held, h, f)), "w2": dense(k2, (n_held, f, h))},
+    }
+    if fs:
+        out["shared_experts"] = {
+            "gate_proj": {"kernel": dense(ks1, (h, fs))},
+            "up_proj": {"kernel": dense(ks2, (h, fs))},
+            "down_proj": {"kernel": dense(ks3, (fs, h))},
+        }
+    return out

@@ -28,7 +28,18 @@ from llm_fine_tune_distributed_tpu.utils.tree import (
 _LAYER_RE = re.compile(r"model/layers/(\d+)/")
 
 
+# Leaves that are buffers under every strategy: the router's selection bias of
+# a DeepSeek-V3-style expert layer takes no gradient (ops/moe.route); its
+# owners move it by a rule of their own between steps, which is not here.
+_BUFFERS = ("e_score_correction_bias",)
+
+
 def trainable_predicate(config: ModelConfig, train: TrainConfig) -> Callable[[str], bool]:
+    pred = _strategy_predicate(config, train)
+    return lambda path: pred(path) and not path.endswith(_BUFFERS)
+
+
+def _strategy_predicate(config: ModelConfig, train: TrainConfig) -> Callable[[str], bool]:
     strategy = train.freeze_strategy
     if strategy == "none":
         return lambda path: True

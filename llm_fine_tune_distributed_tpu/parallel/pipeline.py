@@ -177,6 +177,11 @@ def pipeline_forward(
     seq_parallel = (
         attention_impl in ("ring", "ulysses") and mesh.shape.get("seq", 1) > 1
     )
+    if config.first_k_dense_replace:
+        raise ValueError(
+            "the pipeline's layer scan runs identical layers: leading dense "
+            "layers before the expert layers (first_k_dense_replace) are not supported"
+        )
     if seq_parallel and config.num_experts > 0:
         raise ValueError(
             f"pipe x {attention_impl} does not compose with MoE: inside the "
@@ -204,7 +209,7 @@ def pipeline_forward(
         def one_block(carry, args):
             h, aux = carry
             layer_params, flag = args
-            h, _, layer_aux = _block(
+            h, _, layer_aux, _ = _block(
                 layer_params, h, cos_l, sin_l, mask, None, None, None, 0,
                 config=config, layer_idx=0, attention_impl=stage_impl,
                 compute_dtype=compute_dtype,

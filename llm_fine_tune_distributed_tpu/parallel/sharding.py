@@ -42,9 +42,14 @@ _MATRIX_RULES = [
     # attention projections
     (re.compile(r".*self_attn/(q_proj|k_proj|v_proj)/" + _QK), ("fsdp", "tensor")),
     (re.compile(r".*self_attn/o_proj/" + _QK), ("tensor", "fsdp")),
-    # MLP
-    (re.compile(r".*mlp/(gate_proj|up_proj)/" + _QK), ("fsdp", "tensor")),
-    (re.compile(r".*mlp/down_proj/" + _QK), ("tensor", "fsdp")),
+    # latent attention: the latent (and the one rope key) is shared by all
+    # heads, so its down-projection keeps its output whole; the up-projection
+    # to the heads' k and v shards by head like q
+    (re.compile(r".*self_attn/kv_a_proj_with_mqa/" + _QK), ("fsdp", None)),
+    (re.compile(r".*self_attn/kv_b_proj/" + _QK), ("fsdp", "tensor")),
+    # MLP (and the shared experts beside routed ones: the same SwiGLU)
+    (re.compile(r".*mlp/(shared_experts/)?(gate_proj|up_proj)/" + _QK), ("fsdp", "tensor")),
+    (re.compile(r".*mlp/(shared_experts/)?down_proj/" + _QK), ("tensor", "fsdp")),
     # embeddings: [vocab, hidden] — shard vocab over tensor, hidden over fsdp
     (re.compile(r".*embed_tokens/weight$"), ("tensor", "fsdp")),
     (re.compile(r".*lm_head/kernel$"), ("fsdp", "tensor")),
@@ -62,11 +67,12 @@ _MATRIX_RULES = [
     # NF4-quantized experts ([E, in/8, out] packed + [E, in/block, out]
     # absmax) keep the same orientation; _validate_spec drops any dim the
     # packed shapes no longer divide.
-    (re.compile(r".*block_sparse_moe/experts/(w1|w3)(_nf4|_absmax|_absmax_q)?$"),
+    # The grouped layer (mlp/experts, mlp/gate) lays its leaves out alike.
+    (re.compile(r".*(block_sparse_moe|mlp)/experts/(w1|w3)(_nf4|_absmax|_absmax_q)?$"),
      ("expert", "fsdp", "tensor")),
-    (re.compile(r".*block_sparse_moe/experts/w2(_nf4|_absmax|_absmax_q)?$"),
+    (re.compile(r".*(block_sparse_moe|mlp)/experts/w2(_nf4|_absmax|_absmax_q)?$"),
      ("expert", "tensor", "fsdp")),
-    (re.compile(r".*block_sparse_moe/gate/kernel$"), ("fsdp", None)),
+    (re.compile(r".*(block_sparse_moe|mlp)/gate/kernel$"), ("fsdp", None)),
 ]
 
 

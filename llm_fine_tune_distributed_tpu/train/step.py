@@ -189,7 +189,10 @@ def static_seq_parallel_size(
 
 def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activation_sharding=None,
                  quant_impl: Optional[str] = None, include_router_aux: bool = True,
-                 frozen_layers: int = 0):
+                 frozen_layers: int = 0, with_expert_load: bool = False):
+    """``with_expert_load`` (the train step's, for a model of routed experts
+    with shared experts): the loss function's second result becomes
+    ``(tokens, expert_load [expert layers, held experts])``."""
     compute_dtype = str_to_dtype(train_config.compute_dtype)
     _mesh = getattr(activation_sharding, "mesh", None)
     seq_parallel = static_seq_parallel_size(model_config, train_config, _mesh)
@@ -252,6 +255,7 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             output_hidden=chunk is not None or vocab_chunk is not None,
             quant_impl=quant_impl,
             return_aux=want_aux,
+            return_expert_load=with_expert_load,
             frozen_layers=frozen_layers,
             frozen_compute=frozen_compute,
         )
@@ -297,6 +301,8 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             loss = loss + model_config.router_aux_coef * aux
         if amask is not None:
             return loss, tokens, ans_sum, amask.sum()
+        if with_expert_load:
+            return loss, (tokens, result[-1])
         return loss, tokens
 
     return loss_fn
@@ -317,9 +323,12 @@ def build_train_step(
     the accumulation factor (reference ``gradient_accumulation_steps=4``,
     ``training.py:262``).
     """
+    # routed experts with shared experts: the step counts the (token, expert)
+    # pairs its held experts were given
+    experts = model_config.layer_has_experts(model_config.num_layers - 1)
     loss_fn = make_loss_fn(
         model_config, train_config, activation_sharding, quant_impl,
-        frozen_layers=frozen_layers,
+        frozen_layers=frozen_layers, with_expert_load=experts,
     )
     accum = train_config.gradient_accumulation_steps
 
@@ -327,15 +336,25 @@ def build_train_step(
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
         def micro_step(carry, micro):
-            g_acc, loss_acc = carry
-            (loss, _tokens), grads = grad_fn(state.trainable, state.frozen, micro)
+            g_acc, loss_acc, counters = carry
+            (loss, aux), grads = grad_fn(state.trainable, state.frozen, micro)
             with scope("grad_accum"):
                 g_acc = jax.tree.map(jnp.add, g_acc, grads)
-            return (g_acc, loss_acc + loss), None
+            if experts:
+                load_acc, skew = counters
+                load = aux[1].astype(jnp.float32)  # [expert layers, held experts]
+                worst = (load.max(-1) / jnp.maximum(load.mean(-1), 1.0)).max()
+                counters = (load_acc + load.sum(0), jnp.maximum(skew, worst))
+            return (g_acc, loss_acc + loss, counters), None
 
         with scope("grad_accum"):
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.trainable)
-        (g_sum, loss_sum), _ = jax.lax.scan(micro_step, (zeros, jnp.float32(0.0)), batch)
+        counters = ()  # dense models carry none: their step program is what it was
+        if experts:
+            counters = (jnp.zeros((len(model_config.held_expert_ids),), jnp.float32), jnp.float32(0.0))
+        (g_sum, loss_sum, counters), _ = jax.lax.scan(
+            micro_step, (zeros, jnp.float32(0.0), counters), batch
+        )
 
         # Mean over accumulation steps (HF semantics: mean of microbatch means).
         with scope("grad_accum"):
@@ -356,6 +375,19 @@ def build_train_step(
             "loss": loss,
             "grad_norm": grad_norm,
         }
+        if experts:
+            load_sum, skew = counters
+            expert_layers = model_config.num_layers - model_config.first_k_dense_replace
+            positions = batch["input_ids"].size * expert_layers
+            metrics.update(
+                # pairs held here a token and expert layer (the expectation
+                # under even routing is k * held / n_routed_experts); each held
+                # expert's pairs by that same measure; and the fullest held
+                # expert over the mean, in the step's worst layer and microbatch
+                expert_pairs_per_token=load_sum.sum() / positions,
+                expert_load=load_sum / positions,
+                expert_load_max_over_mean=skew,
+            )
         return new_state, metrics
 
     return train_step

@@ -567,6 +567,11 @@ class SFTTrainer:
                 )
         if cfg.objective not in ("sft", "dpo"):
             problems.append(f"objective={cfg.objective!r}")
+        if mc.first_k_dense_replace:
+            problems.append(
+                "first_k_dense_replace (leading dense layers before the expert "
+                "layers) — the pipeline layer-scan runs identical layers"
+            )
         if mc.alternating_sliding_window:
             # the schedule's layer-scan treats every layer identically
             # (layer_idx is data, not Python); the local/global window
@@ -1271,6 +1276,8 @@ class SFTTrainer:
                         for k, v in metrics.items():
                             if k != "loss" and getattr(v, "ndim", 0) == 0:
                                 logs[k] = float(v)
+                            elif getattr(v, "ndim", 0) == 1:  # expert_load: one entry a held expert
+                                logs.update({f"{k}_{i}": float(x) for i, x in enumerate(v)})
                         if do_eval:
                             logs["eval_loss"] = last_eval
                             if getattr(self, "_last_eval_answer", None) is not None:
@@ -1708,6 +1715,7 @@ class SFTTrainer:
             best_dir,
             save_dtype=ml_dtypes.bfloat16,
             metadata={"framework": "llm_fine_tune_distributed_tpu"},
+            config=self.model_config,
         )
         if hasattr(self.tokenizer, "save_pretrained"):
             self.tokenizer.save_pretrained(best_dir)

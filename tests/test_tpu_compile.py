@@ -106,6 +106,42 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, rows, seq, hq, hkv):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *_qkv(rows, seq, hq, hkv))
 
 
+def test_flash_with_wider_query_and_key_heads_compiles_for_v5e(one_chip):
+    """Latent attention's shapes, one microbatch of the Moonlight cell: q/k
+    heads of 192 (taken as they lie; a block pads to 256 lanes in VMEM) against v heads of 128,
+    16 heads, 2 rows of 4096. At two lane registers a head the kernels' bodies
+    need twice the room (``_vmem_budget``): with the one-register budget the
+    chip's compiler refused dk/dv (38.3 MiB of scoped VMEM against 31)."""
+
+    def loss(q, k, v):
+        return fa.pallas_flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    shapes = (((2, 4096, 16, 192), jnp.bfloat16), ((2, 4096, 16, 192), jnp.bfloat16), ((2, 4096, 16, 128), jnp.bfloat16))
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *shapes)
+    assert compiled.as_text().count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+
+
+def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, monkeypatch):
+    """The dense layer and one expert layer of Moonlight-16B-A3B at its
+    published widths (this chip's share: 8 of 64 experts, an eighth of the
+    vocabulary), every parameter trained, one chip: the flash kernels at
+    192/128 and the grouped products are in the step, and the step is what the
+    chip's compiler accepts. (With the held experts' load counted by
+    ``bincount`` this program aborted the compiler: ops/moe.py.)"""
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "moonlight_16b_a3b",
+        devices=topo.devices[:1], accum=2, seq=4096, per_dp_batch=1, param_dtype="bfloat16",
+        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
+        model_overrides=dict(num_layers=2, vocab_size=20480, held_experts=tuple(range(8))),
+    )
+    text = setup.compile().as_text()
+    assert text.count("flash_attention_fwd") >= 2, "the flash kernel is not in the step"
+    assert "jit(gmm)" in text, "no grouped product kernel in the step"
+
+
 def test_flash_says_no_past_its_vmem_cap(monkeypatch):
     """One block past the largest admitted sequence the kernel is refused for
     a stated reason, before the compiler is asked (so needs no topology)."""
