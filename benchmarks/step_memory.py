@@ -1,0 +1,96 @@
+#!/usr/bin/env python
+"""What the TPU's compiler says of a train step's memory, with no chip: the
+step of a benchmark cell (or a neighbour of one) is compiled for a described
+v5e through ``observe/scaling.abstract_train_setup`` and one JSON line a step
+gives ``peak_memory_in_bytes`` (what has to fit; arguments + temporaries
+counts the donated state twice), the Mosaic calls by flash kernel, or the
+compiler's refusal. Counts and the compiler's word, never a rate.
+
+The state is the cells': bfloat16 masters, Adam's moments float32 (optax
+keeps them so from the first update on, PERF.md section 6).
+
+Usage: JAX_PLATFORMS=cpu python benchmarks/step_memory.py [STEP ...]
+(all of ``STEPS`` when none is named; about a minute each, one after another:
+only one process at a time may load the TPU's library).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+_DENSE = dict(freeze_strategy="last_n_and_head", unfreeze_last_n_layers=2, attention_impl="flash")
+# name -> (preset, model overrides, rows, accumulation, sequence, recipe)
+STEPS = {
+    # the three cells of BENCHMARK.json, as their traffic files state them
+    "smollm3-3b.sft-1k-full": ("smollm3_3b", {}, 2, 16, 1024, dict(_DENSE, remat_policy="dots_no_batch")),
+    "mistral-7b-d16.sft-2k-full": ("mistral_7b", dict(num_layers=16), 1, 16, 2048, dict(_DENSE, remat_policy="dots_no_batch")),
+    "moonlight-16b-a3b-ep8-d6.sft-4k-allparams": (
+        "moonlight_16b_a3b", dict(num_layers=6, vocab_size=20480, held_experts=tuple(range(8))), 4, 2, 4096,
+        dict(freeze_strategy="none", attention_impl="flash", remat_policy="full", loss_chunk_size=1024),
+    ),
+    # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
+    "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
+    "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),
+}
+
+
+def main(names) -> int:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    # a deviceless executable cannot be read back from the persistent cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    jax.default_backend = lambda: "tpu"  # or the flash dispatch takes its CPU branch
+
+    def float32(leaf):
+        if not jnp.issubdtype(leaf.dtype, jnp.floating):
+            return leaf
+        return jax.ShapeDtypeStruct(leaf.shape, jnp.float32, sharding=leaf.sharding)
+
+    for name in names:
+        preset, overrides, rows, accum, seq, recipe = STEPS[name]
+        started = time.time()
+        setup = abstract_train_setup(
+            {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, preset, devices=topo.devices[:1],
+            accum=accum, seq=seq, per_dp_batch=rows, param_dtype="bfloat16",
+            train_kwargs=recipe, model_overrides=overrides,
+        )
+        state = setup.state.replace(opt_state=jax.tree.map(float32, setup.state.opt_state))
+        line = {"step": name}
+        try:
+            compiled = dataclasses.replace(setup, state=state).compile()
+        except jax.errors.JaxRuntimeError as e:  # the refusal is the reading
+            line["refused"] = str(e).split("\n\n")[0]
+        else:
+            memory, text = compiled.memory_analysis(), compiled.as_text().splitlines()
+            gib = lambda n: round(n / 2**30, 3)  # noqa: E731
+            line.update(
+                peak_gib=gib(memory.peak_memory_in_bytes),
+                arguments_gib=gib(memory.argument_size_in_bytes),
+                temporaries_gib=gib(memory.temp_size_in_bytes),
+                mosaic_calls=sum("tpu_custom_call" in ln for ln in text),
+                **{
+                    kernel: sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text)
+                    for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv")
+                },
+            )
+        line["compile_s"] = round(time.time() - started, 1)
+        print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:] or list(STEPS)))

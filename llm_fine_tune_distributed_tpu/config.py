@@ -325,6 +325,12 @@ class TrainConfig:
     # remat granularity: "full" (recompute whole block — min memory),
     # "dots" / "dots_no_batch" (save matmul outputs — least recompute, most
     # HBM), "mlp" (save only the [s,f] SwiGLU product — the middle ground).
+    # Under each of them, "full" included, a block on long rows also keeps
+    # the flash forward kernel's output and row statistics (o and lse, one
+    # [b, s, heads * d_v] tensor and one float32 a row and head), so that the
+    # kernel is not run a second time in the backward pass: decided from the
+    # shapes, where seq * (d_qk + d_v) / (2 * d_v) > hidden_size
+    # (models/transformer._remat_policy; no setting switches it).
     # None = auto (resolved_remat_policy): picked by model size and PER-CHIP
     # sequence length, from earlier rounds' single-chip sweep (its record
     # was deleted in PR 21; not re-measured on the attached v5e).
@@ -484,6 +490,21 @@ class TrainConfig:
         only the [s,f] SwiGLU product) fits and runs 2.4x faster than
         full-block remat; at 8k even the mlp saves OOM (17.1G). Big models
         always take minimum-HBM "full".
+
+        Whatever this resolves to, on rows longer than ``hidden_size * 2 *
+        d_v / (d_qk + d_v)`` tokens (2048 for this model) a block also
+        keeps the flash forward kernel's ``o`` and ``lse``
+        (models/transformer._remat_policy): at 4096 that is 16 MiB of ``o``
+        and 32 MiB of ``lse`` (in the padded layout the kernels read) a row
+        and layer on top of what the policy keeps. The TPU compiler's count
+        for the 4096 recipe of benchmarks/long_context.py (1 row x 8, "mlp",
+        loss in chunks of 512; bfloat16 masters, float32 moments, last 2
+        layers + tied head trained; a described v5e, PR 27,
+        benchmarks/step_memory.py): 12.49 GiB before, 14.15 GiB with them
+        kept, of 15.49 a program may use: it fits, and "mlp" stays the
+        choice. The same step with the whole-sequence unembed was at the
+        brim before (15.49 GiB) and is refused with them kept (by 1.53 GB):
+        chunk the loss there.
 
         ``seq_parallel_size``: the mesh's seq-axis size. A ring/ulysses run
         at global seq 8192 over 4 chips holds 2048 tokens per chip — the

@@ -531,6 +531,57 @@ def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, linear):
     return q, k, kv[..., dn:]
 
 
+def keeps_flash_outputs(config: ModelConfig, seq: int) -> bool:
+    """Whether a rematerialized block of this model keeps the flash forward
+    kernel's output and row statistics at rows of ``seq`` tokens: the rule of
+    ``ops/flash_attention.worth_keeping_across_remat`` at this model's head
+    widths. What the kernel sees is the whole row on every mesh that calls it
+    (batch and heads are sharded around it, Ulysses hands it the whole
+    sequence of a head subset; ring attention does not call it)."""
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import worth_keeping_across_remat
+
+    if config.kv_lora_rank:
+        d_qk, d_v = config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim
+    else:
+        d_qk = d_v = config.resolved_head_dim
+    return worth_keeping_across_remat(seq, d_qk, d_v, config.hidden_size)
+
+
+def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
+    """What ``jax.checkpoint`` keeps of a block besides its input. ``full``
+    (and None): nothing, the whole block is recomputed, least memory. The
+    selective policies save the expensive tensors and recompute the cheap
+    elementwise operations, trading HBM for fewer recomputed FLOPs (a v5e is
+    compute-bound here): ``dots`` / ``dots_no_batch`` the matmuls' outputs,
+    ``mlp`` only the [b, s, f] SwiGLU product (``mlp_act``).
+
+    Under every one of them alike, ``full`` included, a block on long rows
+    (``keeps_flash_outputs``: from the shapes, no switch) also keeps the flash
+    forward kernel's ``o`` and ``lse``, so that the kernel runs once a layer
+    and not a second time in the backward pass. Where the kernel is not in
+    the block (XLA attention) the two names are in no program and the policy
+    is the plain one."""
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import KEPT_ACROSS_REMAT
+
+    saveable = jax.checkpoint_policies
+    policies = {
+        "full": None,
+        "dots": saveable.checkpoint_dots,
+        "dots_no_batch": saveable.dots_with_no_batch_dims_saveable,
+        "mlp": saveable.save_only_these_names("mlp_act"),
+    }
+    remat_policy = remat_policy or "full"
+    if remat_policy not in policies:
+        raise ValueError(
+            f"unknown remat_policy {remat_policy!r}; expected one of {sorted(policies)}"
+        )
+    policy = policies[remat_policy]
+    if not keeps_flash_outputs(config, seq):
+        return policy
+    kept = saveable.save_only_these_names(*KEPT_ACROSS_REMAT)
+    return kept if policy is None else saveable.save_from_both_policies(policy, kept)
+
+
 def forward(
     params: Params,
     input_ids,
@@ -715,6 +766,8 @@ def forward(
     # stop_gradient at the boundary so no cotangent enters it — the
     # compile-cost guard (tests/test_frozen_trunk.py) pins both.
     trunk_layers = frozen_layers if (frozen_compute == "int8" and cache is None) else 0
+    remat = remat and cache is None
+    kept_of_a_block = _remat_policy(remat_policy, config, s) if remat else None
     for i in range(config.num_layers):
         entry = cache["layers"][str(i)] if cache is not None else None
         in_trunk = i < trunk_layers
@@ -740,24 +793,8 @@ def forward(
             adapter_idx=adapter_idx,
             w8a8=in_trunk,
         )
-        if remat and cache is None and not in_trunk:
-            if remat_policy in (None, "full"):
-                block_fn = jax.checkpoint(block_fn)
-            else:
-                # Selective remat: save the expensive tensors, recompute the
-                # cheap elementwise ops — trades HBM for less recompute FLOPs
-                # than full-block remat (v5e is compute-bound here).
-                policies = {
-                    "dots": jax.checkpoint_policies.checkpoint_dots,
-                    "dots_no_batch": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                    "mlp": jax.checkpoint_policies.save_only_these_names("mlp_act"),
-                }
-                if remat_policy not in policies:
-                    raise ValueError(
-                        f"unknown remat_policy {remat_policy!r}; expected one of "
-                        f"'full', {sorted(policies)}"
-                    )
-                block_fn = jax.checkpoint(block_fn, policy=policies[remat_policy])
+        if remat and not in_trunk:
+            block_fn = jax.checkpoint(block_fn, policy=kept_of_a_block)
         with scope("layer", i):
             x, new_entry, layer_aux, layer_load = block_fn(
                 params["model"]["layers"][str(i)],

@@ -56,6 +56,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -549,6 +550,31 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
 # ---------------------------------------------------------------------------
 
 
+# What the forward rule of the custom_vjp below calls its two outputs
+# (``jax.ad_checkpoint.checkpoint_name``). A ``jax.checkpoint`` whose policy
+# saves these names keeps them for the backward kernels; under any other policy
+# the names are inert.
+KEPT_ACROSS_REMAT = ("flash_o", "flash_lse")
+
+
+def worth_keeping_across_remat(seq: int, d_qk: int, d_v: int, hidden_size: int) -> bool:
+    """Whether a rematerialized block should keep the forward kernel's ``o``
+    and ``lse`` instead of running the kernel a second time in the backward
+    pass. From shapes alone: per byte of ``o`` a causal forward costs
+    ``seq * (d_qk + d_v) / (2 * d_v)`` FLOPs (``seq^2 * (d_qk + d_v)`` a head
+    for ``2 * seq * d_v`` bytes), the kernel's work growing with the square of
+    the row and what is kept with the row; per byte of its output a
+    projection from ``hidden_size`` costs ``hidden_size``, which is what a
+    kept byte buys anywhere else in a block. Keep where the first exceeds the
+    second. At the benchmark's cells: SmolLM3-3B 1024 against 2048 and
+    Mistral-7B 2048 against 4096, recompute; Moonlight 4096 x 320 / 256 = 5120
+    against 2048, keep. With the kernel's measured share of its roofline
+    against the matmuls' (48 to 62% against about 80%, PERF.md section 5) the
+    crossing lies at 0.6 to 0.8 of ``hidden_size`` and the three fall on the
+    same sides, so the plain form stands (PERF.md, PR 27)."""
+    return seq * (d_qk + d_v) > 2 * d_v * hidden_size
+
+
 @functools.lru_cache(maxsize=None)
 def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
     """One custom_vjp closure per static configuration. The forward and the
@@ -557,7 +583,27 @@ def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
     its kernel body and lower it to Mosaic anew, in Python, in every process,
     compile cache or not (PR 25: 0.6 to 1.0 s a layer for these kernels, 77 s
     of a warm start at 36 layers). Behind a jit the callers share one traced
-    jaxpr and one lowered function."""
+    jaxpr and one lowered function.
+
+    The backward kernels need the forward's ``o`` and row statistics ``lse``.
+    Under ``jax.checkpoint`` a block keeps its input only, and the forward
+    kernel runs a second time in the backward pass to get the two back. The
+    forward rule therefore names them (``KEPT_ACROSS_REMAT``), and the block's
+    remat policy (models/transformer.py) saves the names on rows long enough
+    that the second run costs more than holding them
+    (``worth_keeping_across_remat``): q, k and v are still rebuilt from the
+    block's input, the second forward is dead code and XLA drops it. The
+    primal output is the named ``o`` too (the output projection's backward
+    reads it). ``lse`` is kept as the kernel writes it, ``[b, hq, sq, 1]``
+    float32, and goes from the forward kernel into the backward kernels
+    untouched. In HBM that layout pads the unit dimension to a tile's 128
+    lanes (128 MiB a layer at 4 x 16 x 4096 for 1 MiB of numbers), and the
+    compact ``[b, hq, sq]`` was tried (PERF.md, PR 27): the TPU compiler's
+    peak for the Moonlight step is 12.91 GiB compact and 13.05 GiB as
+    written, but the two relayouts a layer (a reduce over the padded
+    dimension, a copy back) and what they did to XLA's schedule around them
+    took back a third of the gain on the chip (device busy time of 7 steps
+    7.30 s before, 7.13 s compact, 7.04 s as written)."""
     static = dict(scale=scale, block=block, groups=groups, interpret=interpret)
 
     @jax.jit
@@ -574,6 +620,8 @@ def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
 
     def fn_fwd(q, k, v, segments):
         o, lse = forward(q, k, v, segments)
+        o = checkpoint_name(o, KEPT_ACROSS_REMAT[0])
+        lse = checkpoint_name(lse, KEPT_ACROSS_REMAT[1])
         return o, (q, k, v, segments, o, lse)
 
     def fn_bwd(res, do):

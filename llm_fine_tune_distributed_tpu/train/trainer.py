@@ -40,7 +40,7 @@ from llm_fine_tune_distributed_tpu.data.loader import SFTBatchLoader
 from llm_fine_tune_distributed_tpu.data.tokenizer import load_tokenizer
 from llm_fine_tune_distributed_tpu.models.configs import get_preset
 from llm_fine_tune_distributed_tpu.models.hf_io import load_hf_checkpoint, save_hf_checkpoint
-from llm_fine_tune_distributed_tpu.models.transformer import init_params
+from llm_fine_tune_distributed_tpu.models.transformer import init_params, keeps_flash_outputs
 from llm_fine_tune_distributed_tpu.observe.metrics import MetricLogger
 from llm_fine_tune_distributed_tpu.observe.throughput import ThroughputMeter
 from llm_fine_tune_distributed_tpu.observe.tracing import Histogram
@@ -645,6 +645,21 @@ class SFTTrainer:
         ops/nf4.nf4_matmul docstring; 4-bit at rest in HBM either way)."""
         return self.config.quant_matmul_impl
 
+    def _layers_keeping_flash_outputs(self) -> int:
+        """How many blocks of the step keep the flash forward kernel's output
+        and row statistics across their remat boundary
+        (models/transformer._remat_policy): a static fact of the step, from its
+        shapes. The pipeline schedule wraps its blocks without a policy and
+        the int8 trunk is not rematerialized: none there."""
+        cfg, mc = self.config, self.model_config
+        if (
+            not cfg.gradient_checkpointing
+            or self._pipe_size > 1
+            or not keeps_flash_outputs(mc, cfg.max_seq_length)
+        ):
+            return 0
+        return mc.num_layers - self._frozen_boundary
+
     def _prepare_steps(self) -> None:
         act = self._make_shardings()
         # Every jitted entry point registers with the compile ledger so a
@@ -1160,7 +1175,10 @@ class SFTTrainer:
                                 f"[train] REFUSED: the step program does not fit "
                                 f"the device on mesh {dict(self.mesh.shape)} with "
                                 f"per_device_batch_size={cfg.per_device_batch_size}, "
-                                f"remat_policy={cfg.remat_policy!r}, "
+                                f"remat_policy={cfg.remat_policy!r} "
+                                f"({self._layers_keeping_flash_outputs()} of "
+                                f"{self.model_config.num_layers} layers also keep "
+                                f"the flash kernel's outputs), "
                                 f"loss_chunk_size={cfg.loss_chunk_size}: "
                                 f"{str(e).splitlines()[0][:300]}",
                                 flush=True,

@@ -127,7 +127,8 @@ def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, mo
     vocabulary), every parameter trained, one chip: the flash kernels at
     192/128 and the grouped products are in the step, and the step is what the
     chip's compiler accepts. (With the held experts' load counted by
-    ``bincount`` this program aborted the compiler: ops/moe.py.)"""
+    ``bincount`` this program aborted the compiler: ops/moe.py.) The forward
+    kernel is in it once a layer (tests/test_flash_remat.py)."""
     from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -138,7 +139,15 @@ def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, mo
         model_overrides=dict(num_layers=2, vocab_size=20480, held_experts=tuple(range(8))),
     )
     text = setup.compile().as_text()
-    assert text.count("flash_attention_fwd") >= 2, "the flash kernel is not in the step"
+    mosaic_calls = lambda kernel: sum(  # noqa: E731
+        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
+    )
+    # rows of 4096 at heads of 192/128 over a hidden size of 2048: each block
+    # keeps the forward kernel's o and lse across its remat boundary, so the
+    # forward kernel is in the step once a layer and not a second time in the
+    # backward pass (under ``full`` too)
+    assert mosaic_calls("flash_attention_fwd") == setup.model_config.num_layers
+    assert mosaic_calls("flash_attention_dq") == mosaic_calls("flash_attention_dkv") == setup.model_config.num_layers
     assert "jit(gmm)" in text, "no grouped product kernel in the step"
 
 

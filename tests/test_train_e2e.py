@@ -290,4 +290,8 @@ def test_trainer_states_a_compiler_refusal_before_it_raises(
     line = next(ln for ln in out.splitlines() if ln.startswith("[train] REFUSED"))
     assert "'fsdp': 2" in line and "per_device_batch_size=2" in line
     assert "remat_policy='dots_no_batch'" in line
+    # rows of 128 tokens against a hidden size of 64: every block also keeps
+    # the flash forward kernel's outputs (models/transformer._remat_policy)
+    layers = trainer.model_config.num_layers
+    assert f"({layers} of {layers} layers also keep the flash kernel's outputs)" in line
     assert "Used 18.69G of 15.75G" in line and "Total hbm" not in line
