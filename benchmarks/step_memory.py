@@ -78,6 +78,7 @@ def main(names) -> int:
             memory, text = compiled.memory_analysis(), compiled.as_text().splitlines()
             gib = lambda n: round(n / 2**30, 3)  # noqa: E731
             line.update(
+                peak_bytes=memory.peak_memory_in_bytes,
                 peak_gib=gib(memory.peak_memory_in_bytes),
                 arguments_gib=gib(memory.argument_size_in_bytes),
                 temporaries_gib=gib(memory.temp_size_in_bytes),

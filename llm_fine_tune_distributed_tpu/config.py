@@ -19,6 +19,17 @@ from typing import Any, Optional, Sequence
 
 
 @dataclass(frozen=True)
+class LayerPlan:
+    """One layer's parts, as ``ModelConfig.layer(i)`` reads them from the
+    config: exactly what the model code branches on."""
+
+    attention: str  # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent)
+    rope: bool  # rotary embedding on this layer's q and k (SmolLM3's NoPE layers: False)
+    window: Optional[int]  # sliding-window width, None = global attention
+    feed_forward: str  # "dense" | "capacity_experts" (ops/moe.moe_mlp) | "grouped_experts" (grouped_moe_mlp + shared)
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture of a dense decoder-only transformer.
 
@@ -148,10 +159,6 @@ class ModelConfig:
     def held_expert_ids(self) -> tuple:
         return tuple(self.held_experts) or tuple(range(self.n_routed_experts))
 
-    def layer_has_experts(self, layer_idx: int) -> bool:
-        """Routed + shared experts (n_routed_experts) instead of the dense MLP."""
-        return self.n_routed_experts > 0 and layer_idx >= self.first_k_dense_replace
-
     @property
     def num_params(self) -> int:
         """Exact parameter count (matches HF model.num_parameters())."""
@@ -203,19 +210,27 @@ class ModelConfig:
             total += v * h
         return total
 
-    def uses_rope(self, layer_idx: int) -> bool:
-        if not self.no_rope_layers:
-            return True
-        return bool(self.no_rope_layers[layer_idx])
-
-    def layer_sliding_window(self, layer_idx: int) -> Optional[int]:
-        """Per-layer sliding window: Gemma2 alternates local (even layers) /
-        global (odd); Mistral applies the window everywhere."""
-        if self.sliding_window is None:
-            return None
-        if self.alternating_sliding_window and layer_idx % 2 != 0:
-            return None
-        return self.sliding_window
+    def layer(self, i: int) -> "LayerPlan":
+        """What layer ``i`` is made of: the one place the family fields above
+        become a layer's parts. ``models/transformer.py`` builds the block from
+        it, and nothing outside ``models/`` and ``ops/`` asks the fields."""
+        if self.num_experts:
+            feed_forward = "capacity_experts"
+        elif self.n_routed_experts and i >= self.first_k_dense_replace:
+            feed_forward = "grouped_experts"
+        else:
+            feed_forward = "dense"
+        # Gemma2 alternates local (even layers) / global (odd); Mistral
+        # applies the window everywhere
+        window = self.sliding_window
+        if self.alternating_sliding_window and i % 2 != 0:
+            window = None
+        return LayerPlan(
+            attention="latent" if self.kv_lora_rank else "heads",
+            rope=bool(self.no_rope_layers[i]) if self.no_rope_layers else True,
+            window=window,
+            feed_forward=feed_forward,
+        )
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

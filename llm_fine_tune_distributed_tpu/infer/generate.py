@@ -106,14 +106,14 @@ def make_tp_mesh(tp: int, model_config: Optional[ModelConfig] = None):
 
 
 class LatentAttentionNotServed(NotImplementedError):
-    """A model with latent attention (``ModelConfig.kv_lora_rank``) was handed
+    """A model with latent attention (``ModelConfig.layer(i).attention``) was handed
     to the serving path. It trains (``models/transformer.forward`` without a
     cache); its cache, one latent and one rope key a token, is a third layout
     that ``infer/`` does not have yet (ROADMAP.md, Reach C)."""
 
     def __init__(self, name: str):
         super().__init__(
-            f"model {name!r} has latent attention (kv_lora_rank): it is supported on the training "
+            f"model {name!r} has latent attention: it is supported on the training "
             "path only; serving it needs a latent KV cache layout and a decode path that infer/ lacks"
         )
 
@@ -148,7 +148,7 @@ class Generator:
         beyond repetition-heavy outputs (prompt-lookup's limit), at the cost
         of running the small model K steps per verify."""
         for served in (model_config, draft_config):
-            if served is not None and served.kv_lora_rank:
+            if served is not None and served.layer(0).attention == "latent":
                 raise LatentAttentionNotServed(served.name)
         self.mesh = mesh
         self._act_sharding = None

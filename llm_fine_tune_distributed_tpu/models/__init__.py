@@ -4,6 +4,5 @@ from llm_fine_tune_distributed_tpu.models.configs import (  # noqa: F401
     from_hf_config,
 )
 from llm_fine_tune_distributed_tpu.models.transformer import (  # noqa: F401
-    TransformerLM,
     init_params,
 )

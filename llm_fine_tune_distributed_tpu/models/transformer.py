@@ -29,8 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from llm_fine_tune_distributed_tpu.config import ModelConfig
+from llm_fine_tune_distributed_tpu.config import LayerPlan, ModelConfig
 from llm_fine_tune_distributed_tpu.observe.xla import scope
+from llm_fine_tune_distributed_tpu.ops import moe
 from llm_fine_tune_distributed_tpu.ops.attention import attention, softcap, xla_attention
 from llm_fine_tune_distributed_tpu.ops.int8 import (
     KV_QUANT_MODES,
@@ -41,111 +42,6 @@ from llm_fine_tune_distributed_tpu.ops.norms import rms_norm
 from llm_fine_tune_distributed_tpu.ops.rope import apply_rope, rope_cos_sin
 
 Params = Dict[str, Any]
-
-
-# ---------------------------------------------------------------------------
-# init
-# ---------------------------------------------------------------------------
-
-
-def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
-    """Random init (normal 0.02, HF convention). Returns the params pytree."""
-    h = config.hidden_size
-    d = config.resolved_head_dim
-    qd, kvd = config.num_heads * d, config.num_kv_heads * d
-    f, v = config.intermediate_size, config.vocab_size
-
-    keys = iter(jax.random.split(rng, 2 + config.num_layers * 7))
-
-    def dense(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
-
-    # Gemma zero-centered RMSNorm stores the weight as an offset from 1
-    # (init 0); Llama-style stores the multiplier itself (init 1).
-    def norm_init():
-        if config.zero_centered_norm:
-            return {"weight": jnp.zeros((h,), dtype)}
-        return {"weight": jnp.ones((h,), dtype)}
-
-    layers = {}
-    for i in range(config.num_layers):
-        if config.kv_lora_rank:
-            # latent attention (HF DeepseekV3Attention names, q_lora_rank null)
-            nh, r = config.num_heads, config.kv_lora_rank
-            dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
-            attn = {
-                "q_proj": {"kernel": dense(next(keys), (h, nh * (dn + dr)))},
-                "kv_a_proj_with_mqa": {"kernel": dense(next(keys), (h, r + dr))},
-                "kv_a_layernorm": {"weight": jnp.ones((r,), dtype)},
-                "kv_b_proj": {"kernel": dense(next(keys), (r, nh * (dn + dv)))},
-                "o_proj": {"kernel": dense(next(keys), (nh * dv, h))},
-            }
-        else:
-            attn = {
-                "q_proj": {"kernel": dense(next(keys), (h, qd))},
-                "k_proj": {"kernel": dense(next(keys), (h, kvd))},
-                "v_proj": {"kernel": dense(next(keys), (h, kvd))},
-                "o_proj": {"kernel": dense(next(keys), (qd, h))},
-            }
-        if config.attention_bias:
-            # HF Llama applies attention_bias to q/k/v/o alike; Qwen2 skips
-            # the o_proj bias (attention_out_bias=False).
-            attn["q_proj"]["bias"] = jnp.zeros((qd,), dtype)
-            attn["k_proj"]["bias"] = jnp.zeros((kvd,), dtype)
-            attn["v_proj"]["bias"] = jnp.zeros((kvd,), dtype)
-            if config.attention_out_bias:
-                attn["o_proj"]["bias"] = jnp.zeros((h,), dtype)
-        if config.qk_norm:
-            attn["q_norm"] = {"weight": jnp.ones((d,), dtype)}
-            attn["k_norm"] = {"weight": jnp.ones((d,), dtype)}
-        layer = {
-            "input_layernorm": norm_init(),
-            "self_attn": attn,
-            "post_attention_layernorm": norm_init(),
-        }
-        if config.sandwich_norms:
-            # Gemma2: post_attention_layernorm norms the attention OUTPUT;
-            # pre_feedforward replaces Llama's post_attention pre-MLP role
-            layer["pre_feedforward_layernorm"] = norm_init()
-            layer["post_feedforward_layernorm"] = norm_init()
-        if config.num_experts > 0:
-            from llm_fine_tune_distributed_tpu.ops.moe import init_moe_params
-
-            # consumes one key (split internally); a model is uniformly MoE
-            # or dense so per-layer key alignment needs no padding
-            layer["block_sparse_moe"] = init_moe_params(next(keys), config, dtype)
-        elif config.layer_has_experts(i):
-            from llm_fine_tune_distributed_tpu.ops.moe import init_grouped_moe_params
-
-            layer["mlp"] = init_grouped_moe_params(next(keys), config, dtype)
-        else:
-            mlp = {
-                "gate_proj": {"kernel": dense(next(keys), (h, f))},
-                "up_proj": {"kernel": dense(next(keys), (h, f))},
-                "down_proj": {"kernel": dense(next(keys), (f, h))},
-            }
-            if config.mlp_bias:
-                mlp["gate_proj"]["bias"] = jnp.zeros((f,), dtype)
-                mlp["up_proj"]["bias"] = jnp.zeros((f,), dtype)
-                mlp["down_proj"]["bias"] = jnp.zeros((h,), dtype)
-            layer["mlp"] = mlp
-        layers[str(i)] = layer
-
-    params: Params = {
-        "model": {
-            "embed_tokens": {"weight": dense(next(keys), (v, h))},
-            "layers": layers,
-            "norm": norm_init(),
-        }
-    }
-    if not config.tie_word_embeddings:
-        params["lm_head"] = {"kernel": dense(next(keys), (h, v))}
-    return params
-
-
-# ---------------------------------------------------------------------------
-# forward
-# ---------------------------------------------------------------------------
 
 
 def _linear(x, p, compute_dtype, quant_impl: str = "auto", adapter_idx=None,
@@ -215,6 +111,365 @@ def _linear(x, p, compute_dtype, quant_impl: str = "auto", adapter_idx=None,
     return y
 
 
+# ---------------------------------------------------------------------------
+# The parts of a block. ``ModelConfig.layer(i)`` names layer i's attention and
+# feed-forward; each kind is its half of ``init_params``, its function, and one
+# row of the table after its group. ``lin`` is ``_linear`` bound to the block.
+# ---------------------------------------------------------------------------
+
+
+def _init_heads_attention(keys, config: ModelConfig, dense, dtype):
+    h, d = config.hidden_size, config.resolved_head_dim
+    qd, kvd = config.num_heads * d, config.num_kv_heads * d
+    attn = {
+        "q_proj": {"kernel": dense(next(keys), (h, qd))},
+        "k_proj": {"kernel": dense(next(keys), (h, kvd))},
+        "v_proj": {"kernel": dense(next(keys), (h, kvd))},
+        "o_proj": {"kernel": dense(next(keys), (qd, h))},
+    }
+    if config.attention_bias:
+        # HF Llama applies attention_bias to q/k/v/o alike; Qwen2 skips
+        # the o_proj bias (attention_out_bias=False).
+        attn["q_proj"]["bias"] = jnp.zeros((qd,), dtype)
+        attn["k_proj"]["bias"] = jnp.zeros((kvd,), dtype)
+        attn["v_proj"]["bias"] = jnp.zeros((kvd,), dtype)
+        if config.attention_out_bias:
+            attn["o_proj"]["bias"] = jnp.zeros((h,), dtype)
+    if config.qk_norm:
+        attn["q_norm"] = {"weight": jnp.ones((d,), dtype)}
+        attn["k_norm"] = {"weight": jnp.ones((d,), dtype)}
+    return attn
+
+
+def _heads_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
+    """q ``[b, s, heads, d]``, k and v ``[b, s, kv_heads, d]`` from their own
+    projections. ``rope``: the plan's bool, or a traced bool scalar where the
+    layer index is data (the pipeline's layer scan over NoPE-interleaved
+    layers): then both are computed and one selected."""
+    b, s, _ = hid.shape
+    d = config.resolved_head_dim
+    q = lin(hid, attn_p["q_proj"]).reshape(b, s, config.num_heads, d)
+    k = lin(hid, attn_p["k_proj"]).reshape(b, s, config.num_kv_heads, d)
+    v = lin(hid, attn_p["v_proj"]).reshape(b, s, config.num_kv_heads, d)
+    if config.qk_norm:
+        # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
+        q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps)
+        k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps)
+    if not isinstance(rope, bool):
+        qr, kr = apply_rope(q, k, cos, sin)
+        q = jnp.where(rope, qr, q)
+        k = jnp.where(rope, kr, k)
+    elif rope:
+        q, k = apply_rope(q, k, cos, sin)
+    return q, k, v
+
+
+def _init_latent_attention(keys, config: ModelConfig, dense, dtype):
+    # HF DeepseekV3Attention names, q_lora_rank null
+    h, nh, r = config.hidden_size, config.num_heads, config.kv_lora_rank
+    dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    return {
+        "q_proj": {"kernel": dense(next(keys), (h, nh * (dn + dr)))},
+        "kv_a_proj_with_mqa": {"kernel": dense(next(keys), (h, r + dr))},
+        "kv_a_layernorm": {"weight": jnp.ones((r,), dtype)},
+        "kv_b_proj": {"kernel": dense(next(keys), (r, nh * (dn + dv)))},
+        "o_proj": {"kernel": dense(next(keys), (nh * dv, h))},
+    }
+
+
+def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope=True):
+    """q, k, v of latent attention (MLA) in its training form, for the
+    ordinary attention paths: ``hid [b, s, h]`` -> q, k ``[b, s, heads,
+    qk_nope + qk_rope]`` and v ``[b, s, heads, v_head_dim]``. k and v come up
+    from one normed latent of ``kv_lora_rank``; the rope key (one per token)
+    is rotated once and shared by every head, on every layer (``rope`` is not
+    asked). ``cos``/``sin`` are tables of ``qk_rope_head_dim``; halves are
+    rotated (HF de-interleaves DeepSeek's stored pairs first;
+    ``models/hf_io.py`` does that to the weights)."""
+    b, s, _ = hid.shape
+    nh, r = config.num_heads, config.kv_lora_rank
+    dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    q = lin(hid, attn_p["q_proj"]).reshape(b, s, nh, dn + dr)
+    latent = lin(hid, attn_p["kv_a_proj_with_mqa"])
+    c_kv = rms_norm(latent[..., :r], attn_p["kv_a_layernorm"]["weight"], config.rms_norm_eps)
+    kv = lin(c_kv, attn_p["kv_b_proj"]).reshape(b, s, nh, dn + dv)
+    q_pe, k_pe = apply_rope(q[..., dn:], latent[..., r:].reshape(b, s, 1, dr), cos, sin)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, nh, dr))], axis=-1)
+    return q, k, kv[..., dn:]
+
+
+# LayerPlan.attention -> (its half of init_params, q/k/v of a normed input)
+_ATTENTION = {
+    "heads": (_init_heads_attention, _heads_qkv),
+    "latent": (_init_latent_attention, _latent_qkv),
+}
+
+
+def _init_dense_mlp(keys, config: ModelConfig, dense, dtype):
+    h, f = config.hidden_size, config.intermediate_size
+    mlp = {
+        "gate_proj": {"kernel": dense(next(keys), (h, f))},
+        "up_proj": {"kernel": dense(next(keys), (h, f))},
+        "down_proj": {"kernel": dense(next(keys), (f, h))},
+    }
+    if config.mlp_bias:
+        mlp["gate_proj"]["bias"] = jnp.zeros((f,), dtype)
+        mlp["up_proj"]["bias"] = jnp.zeros((f,), dtype)
+        mlp["down_proj"]["bias"] = jnp.zeros((h,), dtype)
+    return mlp
+
+
+def _dense_mlp(p, hid, lin, config: ModelConfig, **_):
+    gate = lin(hid, p["gate_proj"])
+    up = lin(hid, p["up_proj"])
+    # Named so remat_policy="mlp" can save JUST this [b, s, f] product: the
+    # gate/up matmuls are ~58% of a block's param FLOPs, so saving their
+    # fused output avoids most of full-remat's recompute at one tensor per
+    # layer of extra HBM (vs. two for saving gate and up separately).
+    if config.hidden_act == "gelu_tanh":
+        act = jax.nn.gelu(gate.astype(jnp.float32), approximate=True).astype(gate.dtype)
+    elif config.hidden_act == "gelu":
+        act = jax.nn.gelu(gate.astype(jnp.float32), approximate=False).astype(gate.dtype)
+    else:
+        act = jax.nn.silu(gate)
+    prod = checkpoint_name(act * up, "mlp_act")
+    return lin(prod, p["down_proj"]), {}
+
+
+def _capacity_experts(p, hid, lin, config: ModelConfig, *, compute_dtype, mesh, padding_mask, segment_ids, serving):
+    """Mixtral's experts through the one-hot capacity dispatch. Counts the
+    layer's load-balancing loss (float32 scalar)."""
+    # token-level real/pad mask for routing: packed batches encode pads
+    # as segment 0; the cache path's padding_mask covers the KV buffer
+    # (wrong length for the current chunk) and is skipped
+    token_mask = None
+    if segment_ids is not None:
+        token_mask = segment_ids > 0
+    elif padding_mask is not None and padding_mask.shape[-1] == hid.shape[1]:
+        token_mask = padding_mask
+    y, aux = moe.moe_mlp(
+        p, hid, config, compute_dtype, mesh=mesh, token_mask=token_mask,
+        # decode/prefill (KV cache live) is dropless like HF Mixtral:
+        # capacity drops would make outputs depend on batch/chunk shape
+        dropless=serving,
+    )
+    return y, {"router_aux": aux}
+
+
+def _grouped_experts(p, hid, lin, config: ModelConfig, *, compute_dtype, mesh, **_):
+    """DeepSeek-V3's routed experts held here, beside the shared experts.
+    Counts the (token, expert) pairs of each held expert (``[held]`` int32)."""
+    if mesh is not None and dict(mesh.shape).get("expert", 1) > 1:
+        raise NotImplementedError(
+            "grouped experts over a mesh's expert axis: the exchange is not written yet "
+            "(ROADMAP.md, Reach A); name this process's share in ModelConfig.held_experts"
+        )
+    y, load = moe.grouped_moe_mlp(p, hid, config, compute_dtype)
+    with scope("shared_expert"):
+        shared = p.get("shared_experts")
+        if shared is not None:
+            prod = checkpoint_name(
+                jax.nn.silu(lin(hid, shared["gate_proj"])) * lin(hid, shared["up_proj"]), "mlp_act"
+            )
+            y = y + lin(prod, shared["down_proj"])
+    return y, {"expert_load": load}
+
+
+def _one_key(init):
+    """ops/moe's inits take one key and split it themselves."""
+    return lambda keys, config, dense, dtype: init(next(keys), config, dtype)
+
+
+# LayerPlan.feed_forward -> (the layer's subtree of that kind, its half of
+# init_params, normed input -> (output, what the layer counted))
+_FEED_FORWARD = {
+    "dense": ("mlp", _init_dense_mlp, _dense_mlp),
+    "capacity_experts": ("block_sparse_moe", _one_key(moe.init_moe_params), _capacity_experts),
+    "grouped_experts": ("mlp", _one_key(moe.init_grouped_moe_params), _grouped_experts),
+}
+
+
+def report_shapes(config: ModelConfig) -> Dict[str, jax.ShapeDtypeStruct]:
+    """The structure of ``forward_with_report``'s report for this model, from
+    the plan alone: what a caller needs to carry the report through a scan."""
+    kinds = [config.layer(i).feed_forward for i in range(config.num_layers)]
+    shapes = {}
+    if "capacity_experts" in kinds:
+        shapes["router_aux"] = jax.ShapeDtypeStruct((), jnp.float32)
+    if "grouped_experts" in kinds:
+        shapes["expert_load"] = jax.ShapeDtypeStruct(
+            (kinds.count("grouped_experts"), len(config.held_expert_ids)), jnp.int32
+        )
+    return shapes
+
+
+# how what layers counted becomes the report's: summed, or stacked [layers, ...]
+_OVER_LAYERS = {"router_aux": lambda each: sum(each, jnp.float32(0.0)), "expert_load": jnp.stack}
+
+
+def _report(counted_by_layer) -> Dict[str, jax.Array]:
+    """What the layers counted, as one pytree (``report_shapes``)."""
+    keys = dict.fromkeys(k for counted in counted_by_layer for k in counted)
+    return {k: _OVER_LAYERS[k]([c[k] for c in counted_by_layer if k in c]) for k in keys}
+
+
+# ---------------------------------------------------------------------------
+# init
+# ---------------------------------------------------------------------------
+
+
+def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
+    """Random init (normal 0.02, HF convention). Returns the params pytree."""
+    h, v = config.hidden_size, config.vocab_size
+    keys = iter(jax.random.split(rng, 2 + config.num_layers * 7))
+
+    def dense(key, shape):
+        return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+
+    # Gemma zero-centered RMSNorm stores the weight as an offset from 1
+    # (init 0); Llama-style stores the multiplier itself (init 1).
+    def norm_init():
+        if config.zero_centered_norm:
+            return {"weight": jnp.zeros((h,), dtype)}
+        return {"weight": jnp.ones((h,), dtype)}
+
+    layers = {}
+    for i in range(config.num_layers):
+        plan = config.layer(i)
+        layer = {
+            "input_layernorm": norm_init(),
+            "self_attn": _ATTENTION[plan.attention][0](keys, config, dense, dtype),
+            "post_attention_layernorm": norm_init(),
+        }
+        if config.sandwich_norms:
+            # Gemma2: post_attention_layernorm norms the attention OUTPUT;
+            # pre_feedforward replaces Llama's post_attention pre-MLP role
+            layer["pre_feedforward_layernorm"] = norm_init()
+            layer["post_feedforward_layernorm"] = norm_init()
+        subtree, init, _ = _FEED_FORWARD[plan.feed_forward]
+        layer[subtree] = init(keys, config, dense, dtype)
+        layers[str(i)] = layer
+
+    params: Params = {
+        "model": {
+            "embed_tokens": {"weight": dense(next(keys), (v, h))},
+            "layers": layers,
+            "norm": norm_init(),
+        }
+    }
+    if not config.tie_word_embeddings:
+        params["lm_head"] = {"kernel": dense(next(keys), (h, v))}
+    return params
+
+
+def _cache_write_and_view(entry, q, k, v, cache_pos, block_tables, *, plan, fusable: bool, scale, compute_dtype):
+    """Write this chunk's ``k``/``v`` into a layer's cache entry and hand back
+    what to attend over: ``(k, v, new_entry, out)``. ``out`` is None, or the
+    attention output already made (the int8 pool's fused decode kernel; k and
+    v are then None). No entry: ``k``, ``v`` as they came. The three layouts
+    are told apart here and nowhere else in the model: the dense buffer
+    (``init_cache``; ``block_tables`` None), the paged pool
+    (``init_paged_cache``) in bf16, and in int8 with ``k_scale``/``v_scale``.
+    ``fusable``: the scores are plain causal ones (no padding mask, window or
+    softcap), all the fused kernel computes; ``scale`` None = ``d ** -0.5``."""
+    if entry is None:
+        return k, v, None, None
+    if plan.attention == "latent":
+        raise NotImplementedError(
+            "latent attention (kv_lora_rank) has the training form only; its cache is a third layout"
+        )
+    b, s = k.shape[:2]
+    if block_tables is None:
+        # Decode/prefill with a fixed-size KV buffer: write k,v at cache_pos.
+        # A scalar cache_pos writes the same slots for every row (single
+        # prompt / aligned batch); a [batch] vector writes per-row slots —
+        # ragged batched decode, where row i's token t lives at slot
+        # len_i + t so the slot == position invariant holds per row.
+        # Out-of-bounds slots DROP (jax scatter default): a speculative
+        # verify chunk overrunning the buffer on a slot's final tick
+        # cannot clobber other rows' live KV.
+        if getattr(cache_pos, "ndim", 0) == 1:
+            slots = cache_pos[:, None] + jnp.arange(s)[None, :]  # [b, s]
+            ck = entry["k"].at[jnp.arange(b)[:, None], slots].set(k.astype(entry["k"].dtype))
+            cv = entry["v"].at[jnp.arange(b)[:, None], slots].set(v.astype(entry["v"].dtype))
+        else:
+            ck = jax.lax.dynamic_update_slice(entry["k"], k.astype(entry["k"].dtype), (0, cache_pos, 0, 0))
+            cv = jax.lax.dynamic_update_slice(entry["v"], v.astype(entry["v"].dtype), (0, cache_pos, 0, 0))
+        return ck, cv, {"k": ck, "v": cv}, None
+
+    # Paged cache: the entry is the GLOBAL pool [num_blocks, L, kv_heads,
+    # d] and the row's block table maps logical position p to pool cell
+    # (table[p // L], p % L). Writes scatter each chunk token at its
+    # logical position through the table; reads gather the table's blocks
+    # back into one [b, nb*L] view whose index IS the logical position —
+    # so the caller's position mask applies to the view unchanged, and a
+    # row's decode cost tracks the blocks its table exposes (nb), not a
+    # global buffer ceiling. Unused table entries hold the null block
+    # (id 0): their view positions sit above every live query, hence
+    # always masked; dead rows get an all-null table from the engine so
+    # their (frozen-position) writes land in null-block garbage instead
+    # of a block since reassigned to a live row.
+    L = entry["k"].shape[1]
+    nb = block_tables.shape[1]
+    offset = cache_pos[:, None] if getattr(cache_pos, "ndim", 0) == 1 else cache_pos
+    pos = jnp.broadcast_to(offset + jnp.arange(s)[None, :], (b, s))
+    # NOTE the clip: a position past the table view REDIRECTS its write
+    # into the view's LAST entry instead of dropping it (the dense buffer
+    # above drops out-of-bounds scatters). Callers whose writes can run
+    # past a row's logical end — the speculative verify step writes K
+    # positions past the last accepted token — must size the table view
+    # to cover pos + K (engine-side block headroom), or live KV gets
+    # overwritten.
+    blk = jnp.take_along_axis(block_tables, jnp.clip(pos // L, 0, nb - 1), axis=1)
+    off = pos % L
+    if "k_scale" not in entry:
+        ck = entry["k"].at[blk, off].set(k.astype(entry["k"].dtype))
+        cv = entry["v"].at[blk, off].set(v.astype(entry["v"].dtype))
+        flat = block_tables.reshape(-1)
+        k = ck[flat].reshape(b, nb * L, ck.shape[2], ck.shape[3])
+        v = cv[flat].reshape(b, nb * L, cv.shape[2], cv.shape[3])
+        return k, v, {"k": ck, "v": cv}, None
+
+    # Int8 pool (--quantize-kv int8): codes keep the bf16 layout's
+    # [nb, L, h, d] shape, per-(block, kv-head) absmax scales live in
+    # sibling pools indexed by the same block ids. Writes quantize at
+    # insert (growing a block's scale rescales its resident codes;
+    # untouched blocks are bit-stable — ops/int8.quantize_kv_write);
+    # reads either fuse gather+dequant+attention into the Pallas
+    # decode kernel (TPU, s == 1) or fall back to the dequantizing
+    # XLA gather.
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import (
+        paged_decode_attention,
+        paged_decode_mode,
+    )
+
+    ck, k_sc = quantize_kv_write(entry["k"], entry["k_scale"], blk, off, k)
+    cv, v_sc = quantize_kv_write(entry["v"], entry["v_scale"], blk, off, v)
+    new_entry = {"k": ck, "v": cv, "k_scale": k_sc, "v_scale": v_sc}
+    mode = paged_decode_mode()
+    if mode != "xla" and s == 1 and fusable:
+        # fused Pallas kernel: block-table gather + per-block dequant +
+        # online softmax in one VMEM pass — the gathered [b, nb*L] view
+        # never materializes in HBM. Decode (s == 1) only; prefill
+        # chunks and speculative verify use the XLA gather below.
+        out = paged_decode_attention(
+            q, ck, cv, k_sc, v_sc, block_tables,
+            lengths=pos[:, 0] + 1,
+            scale=float(scale) if scale is not None else float(q.shape[-1]) ** -0.5,
+            interpret=(mode == "interpret"),
+        )
+        return None, None, new_entry, out
+    k = dequantize_kv_gather(ck, k_sc, block_tables, compute_dtype)
+    v = dequantize_kv_gather(cv, v_sc, block_tables, compute_dtype)
+    return k, v, new_entry, None
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+
 def _block(
     lp: Params,
     x,
@@ -222,313 +477,84 @@ def _block(
     sin,
     padding_mask,
     segment_ids,
-    explicit_mask,
+    mask,
     cache_entry,
     cache_pos,
     *,
     config: ModelConfig,
-    layer_idx: int,
+    plan: LayerPlan,
     attention_impl: str,
     compute_dtype,
     mesh=None,
     quant_impl: str = "auto",
     rope_flag=None,
-    windowed_mask=None,
     block_tables=None,
     adapter_idx=None,
     w8a8: bool = False,
 ):
-    """One transformer block. Returns (x, new_cache_entry, moe_aux,
-    expert_load): ``expert_load`` is the (token, expert) pairs of each held
-    expert in a layer of routed experts with shared experts
-    (``config.layer_has_experts``), None elsewhere.
+    """One transformer block, composed from its layer's ``plan``
+    (``ModelConfig.layer``). Returns ``(x, new_cache_entry, counted)``:
+    ``counted`` is what the feed-forward counted (``_FEED_FORWARD``).
 
-    ``rope_flag`` (traced bool scalar) overrides the static
-    ``config.uses_rope(layer_idx)`` decision — used by the pipeline's
-    layer-scan, where the absolute layer index is data, not Python.
-    ``moe_aux`` is the layer's load-balancing loss (f32 scalar; 0.0 for
-    dense models — ``config.num_experts == 0``).
-    ``block_tables`` ([batch, nb] int32) switches the cache entry to the
-    PAGED layout: a global block pool instead of per-row buffers (see the
-    cache-write branch below and ``init_paged_cache``).
+    ``mask`` ([batch, q, kv] bool): the explicit attention mask where the
+    caller made one (packing with a window, the KV cache), the windowed
+    variant on a layer with a window; None = causal attention from
+    ``padding_mask`` / ``segment_ids`` through the attention dispatch.
+    ``rope_flag`` (traced bool scalar) overrides the static ``plan.rope`` —
+    used by the pipeline's layer scan, where the layer index is data.
+    ``block_tables`` ([batch, nb] int32): the cache entry is the PAGED pool.
     """
-    b, s, h = x.shape
-    d = config.resolved_head_dim
-    eps = config.rms_norm_eps
-    zc = config.zero_centered_norm
-    attn_p = lp["self_attn"]
+    b, s, _ = x.shape
+    eps, zc = config.rms_norm_eps, config.zero_centered_norm
+    lin = partial(_linear, compute_dtype=compute_dtype, quant_impl=quant_impl, adapter_idx=adapter_idx, w8a8=w8a8)
 
     with scope("attn"):
         hid = rms_norm(x, lp["input_layernorm"]["weight"], eps, zero_centered=zc)
-        if config.kv_lora_rank:
-            if cache_entry is not None:
-                raise NotImplementedError(
-                    "latent attention (kv_lora_rank) has the training form only; its cache is a third layout"
-                )
-            q, k, v = _latent_qkv(
-                attn_p, hid, cos, sin, config,
-                lambda t, p: _linear(t, p, compute_dtype, quant_impl, adapter_idx, w8a8),
-            )
-            d = config.v_head_dim
-        else:
-            q = _linear(hid, attn_p["q_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_heads, d)
-            k = _linear(hid, attn_p["k_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
-            v = _linear(hid, attn_p["v_proj"], compute_dtype, quant_impl, adapter_idx, w8a8).reshape(b, s, config.num_kv_heads, d)
-
-            if config.qk_norm:
-                # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
-                q = rms_norm(q, attn_p["q_norm"]["weight"], eps)
-                k = rms_norm(k, attn_p["k_norm"]["weight"], eps)
-
-            if rope_flag is not None:
-                qr, kr = apply_rope(q, k, cos, sin)
-                q = jnp.where(rope_flag, qr, q)
-                k = jnp.where(rope_flag, kr, k)
-            elif config.uses_rope(layer_idx):
-                q, k = apply_rope(q, k, cos, sin)
-
-        new_entry = None
-        paged_quant = None  # int8 paged pool: (ck, cv, k_scale, v_scale, pos)
-        if cache_entry is not None and block_tables is not None:
-            # Paged cache: the entry is the GLOBAL pool [num_blocks, L, kv_heads,
-            # d] and the row's block table maps logical position p to pool cell
-            # (table[p // L], p % L). Writes scatter each chunk token at its
-            # logical position through the table; reads gather the table's blocks
-            # back into one [b, nb*L] view whose index IS the logical position —
-            # so the caller's position mask applies to the view unchanged, and a
-            # row's decode cost tracks the blocks its table exposes (nb), not a
-            # global buffer ceiling. Unused table entries hold the null block
-            # (id 0): their view positions sit above every live query, hence
-            # always masked; dead rows get an all-null table from the engine so
-            # their (frozen-position) writes land in null-block garbage instead
-            # of a block since reassigned to a live row.
-            L = cache_entry["k"].shape[1]
-            nb = block_tables.shape[1]
-            offset = (
-                cache_pos[:, None] if getattr(cache_pos, "ndim", 0) == 1 else cache_pos
-            )
-            pos = jnp.broadcast_to(offset + jnp.arange(s)[None, :], (b, s))
-            # NOTE the clip: a position past the table view REDIRECTS its write
-            # into the view's LAST entry instead of dropping it (the dense branch
-            # below drops out-of-bounds scatters). Callers whose writes can run
-            # past a row's logical end — the speculative verify step writes K
-            # positions past the last accepted token — must size the table view
-            # to cover pos + K (engine-side block headroom), or live KV gets
-            # overwritten.
-            blk = jnp.take_along_axis(block_tables, jnp.clip(pos // L, 0, nb - 1), axis=1)
-            off = pos % L
-            if "k_scale" in cache_entry:
-                # Int8 pool (--quantize-kv int8): codes keep the bf16 layout's
-                # [nb, L, h, d] shape, per-(block, kv-head) absmax scales live in
-                # sibling pools indexed by the same block ids. Writes quantize at
-                # insert (growing a block's scale rescales its resident codes;
-                # untouched blocks are bit-stable — ops/int8.quantize_kv_write);
-                # reads either fuse gather+dequant+attention into the Pallas
-                # decode kernel (TPU, s == 1) or fall back to the dequantizing
-                # XLA gather below.
-                ck, k_sc = quantize_kv_write(
-                    cache_entry["k"], cache_entry["k_scale"], blk, off, k
-                )
-                cv, v_sc = quantize_kv_write(
-                    cache_entry["v"], cache_entry["v_scale"], blk, off, v
-                )
-                new_entry = {"k": ck, "v": cv, "k_scale": k_sc, "v_scale": v_sc}
-                paged_quant = (ck, cv, k_sc, v_sc, pos)
-            else:
-                ck = cache_entry["k"].at[blk, off].set(k.astype(cache_entry["k"].dtype))
-                cv = cache_entry["v"].at[blk, off].set(v.astype(cache_entry["v"].dtype))
-                new_entry = {"k": ck, "v": cv}
-                flat = block_tables.reshape(-1)
-                k = ck[flat].reshape(b, nb * L, ck.shape[2], ck.shape[3])
-                v = cv[flat].reshape(b, nb * L, cv.shape[2], cv.shape[3])
-        elif cache_entry is not None:
-            # Decode/prefill with a fixed-size KV buffer: write k,v at cache_pos.
-            # A scalar cache_pos writes the same slots for every row (single
-            # prompt / aligned batch); a [batch] vector writes per-row slots —
-            # ragged batched decode, where row i's token t lives at slot
-            # len_i + t so the slot == position invariant holds per row.
-            # Out-of-bounds slots DROP (jax scatter default): a speculative
-            # verify chunk overrunning the buffer on a slot's final tick
-            # cannot clobber other rows' live KV.
-            if getattr(cache_pos, "ndim", 0) == 1:
-                slots = cache_pos[:, None] + jnp.arange(s)[None, :]  # [b, s]
-                ck = cache_entry["k"].at[jnp.arange(b)[:, None], slots].set(
-                    k.astype(cache_entry["k"].dtype)
-                )
-                cv = cache_entry["v"].at[jnp.arange(b)[:, None], slots].set(
-                    v.astype(cache_entry["v"].dtype)
-                )
-            else:
-                ck = jax.lax.dynamic_update_slice(cache_entry["k"], k.astype(cache_entry["k"].dtype), (0, cache_pos, 0, 0))
-                cv = jax.lax.dynamic_update_slice(cache_entry["v"], v.astype(cache_entry["v"].dtype), (0, cache_pos, 0, 0))
-            new_entry = {"k": ck, "v": cv}
-            k, v = ck, cv
-
-        # Per-layer attention knobs (Gemma2: alternating local/global windows,
-        # query_pre_attn_scalar scale, logit softcap — all None for Llama-family)
-        layer_window = config.layer_sliding_window(layer_idx)
-        attn_scale = (
-            None
-            if config.query_pre_attn_scalar is None
-            else float(config.query_pre_attn_scalar) ** -0.5
+        q, k, v = _ATTENTION[plan.attention][1](
+            lp["self_attn"], hid, cos, sin, config, lin, plan.rope if rope_flag is None else rope_flag
         )
-        out = None
-        if paged_quant is not None:
-            ck, cv, k_sc, v_sc, pos = paged_quant
-            from llm_fine_tune_distributed_tpu.ops.flash_attention import (
-                paged_decode_attention,
-                paged_decode_mode,
-            )
-
-            mode = paged_decode_mode()
-            if (
-                mode != "xla"
-                and s == 1
-                and padding_mask is None
-                and layer_window is None
-                and config.attn_logit_softcap is None
-            ):
-                # fused Pallas kernel: block-table gather + per-block dequant +
-                # online softmax in one VMEM pass — the gathered [b, nb*L] view
-                # never materializes in HBM. Decode (s == 1) only; prefill
-                # chunks and speculative verify use the XLA gather below.
-                out = paged_decode_attention(
-                    q, ck, cv, k_sc, v_sc, block_tables,
-                    lengths=pos[:, 0] + 1,
-                    scale=(
-                        float(attn_scale)
-                        if attn_scale is not None
-                        else float(d) ** -0.5
-                    ),
-                    interpret=(mode == "interpret"),
-                )
-            else:
-                k = dequantize_kv_gather(ck, k_sc, block_tables, compute_dtype)
-                v = dequantize_kv_gather(cv, v_sc, block_tables, compute_dtype)
-        if out is not None:
-            pass
-        elif explicit_mask is not None:
-            # windowed_mask carries the window restriction; a global layer (no
-            # window) uses the plain causal/padding mask
-            m = windowed_mask if (layer_window is not None and windowed_mask is not None) else explicit_mask
+        # Gemma2: query_pre_attn_scalar scale, logit softcap (None for Llama-family)
+        scale = None if config.query_pre_attn_scalar is None else float(config.query_pre_attn_scalar) ** -0.5
+        heads_width = config.num_heads * v.shape[-1]
+        k, v, new_entry, out = _cache_write_and_view(
+            cache_entry, q, k, v, cache_pos, block_tables, plan=plan, compute_dtype=compute_dtype, scale=scale,
+            fusable=padding_mask is None and plan.window is None and config.attn_logit_softcap is None,
+        )
+        if out is None and mask is not None:
             out = xla_attention(
-                q, k, v, mask=m, causal=False,
-                scale=attn_scale, logit_softcap=config.attn_logit_softcap,
+                q, k, v, mask=mask, causal=False, scale=scale, logit_softcap=config.attn_logit_softcap
             )
-        else:
+        elif out is None:
             out = attention(
-                q,
-                k,
-                v,
+                q, k, v,
                 impl=attention_impl,
                 padding_mask=padding_mask,
                 segment_ids=segment_ids,
                 causal=True,
-                sliding_window=layer_window,
+                sliding_window=plan.window,
                 mesh=mesh,
-                scale=attn_scale,
+                scale=scale,
                 logit_softcap=config.attn_logit_softcap,
             )
-
-        out = out.reshape(b, s, config.num_heads * d)
-        attn_out = _linear(out, attn_p["o_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
+        attn_out = lin(out.reshape(b, s, heads_width), lp["self_attn"]["o_proj"])
         if config.sandwich_norms:
             # Gemma2: post_attention_layernorm norms the attention OUTPUT
-            attn_out = rms_norm(
-                attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc
-            )
+            attn_out = rms_norm(attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc)
         x = x + attn_out
 
     with scope("mlp"):
-        pre_ffn = (
-            "pre_feedforward_layernorm" if config.sandwich_norms
-            else "post_attention_layernorm"
-        )
+        pre_ffn = "pre_feedforward_layernorm" if config.sandwich_norms else "post_attention_layernorm"
         hid = rms_norm(x, lp[pre_ffn]["weight"], eps, zero_centered=zc)
-        aux = jnp.float32(0.0)
-        expert_load = None
-        if config.layer_has_experts(layer_idx):
-            from llm_fine_tune_distributed_tpu.ops.moe import grouped_moe_mlp
-
-            if mesh is not None and dict(mesh.shape).get("expert", 1) > 1:
-                raise NotImplementedError(
-                    "grouped experts over a mesh's expert axis: the exchange is not written yet "
-                    "(ROADMAP.md, Reach A); name this process's share in ModelConfig.held_experts"
-                )
-            routed, expert_load = grouped_moe_mlp(lp["mlp"], hid, config, compute_dtype)
-            with scope("shared_expert"):
-                shared = lp["mlp"].get("shared_experts")
-                if shared is not None:
-                    lin = lambda t, name: _linear(t, shared[name], compute_dtype, quant_impl, adapter_idx, w8a8)  # noqa: E731
-                    prod = checkpoint_name(jax.nn.silu(lin(hid, "gate_proj")) * lin(hid, "up_proj"), "mlp_act")
-                    routed = routed + lin(prod, "down_proj")
-            x = x + routed
-        elif config.num_experts > 0:
-            from llm_fine_tune_distributed_tpu.ops.moe import moe_mlp
-
-            # token-level real/pad mask for routing: packed batches encode pads
-            # as segment 0; the cache path's padding_mask covers the KV buffer
-            # (wrong length for the current chunk) and is skipped
-            token_mask = None
-            if segment_ids is not None:
-                token_mask = segment_ids > 0
-            elif padding_mask is not None and padding_mask.shape[-1] == s:
-                token_mask = padding_mask
-            moe_out, aux = moe_mlp(
-                lp["block_sparse_moe"], hid, config, compute_dtype, mesh=mesh,
-                token_mask=token_mask,
-                # decode/prefill (KV cache live) is dropless like HF Mixtral:
-                # capacity drops would make outputs depend on batch/chunk shape
-                dropless=cache_entry is not None,
-            )
-            if config.sandwich_norms:
-                moe_out = rms_norm(
-                    moe_out, lp["post_feedforward_layernorm"]["weight"], eps,
-                    zero_centered=zc,
-                )
-            x = x + moe_out
-        else:
-            gate = _linear(hid, lp["mlp"]["gate_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-            up = _linear(hid, lp["mlp"]["up_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-            # Named so remat_policy="mlp" can save JUST this [b, s, f] product: the
-            # gate/up matmuls are ~58% of a block's param FLOPs, so saving their
-            # fused output avoids most of full-remat's recompute at one tensor per
-            # layer of extra HBM (vs. two for saving gate and up separately).
-            if config.hidden_act == "gelu_tanh":
-                act = jax.nn.gelu(gate.astype(jnp.float32), approximate=True).astype(gate.dtype)
-            elif config.hidden_act == "gelu":
-                act = jax.nn.gelu(gate.astype(jnp.float32), approximate=False).astype(gate.dtype)
-            else:
-                act = jax.nn.silu(gate)
-            prod = checkpoint_name(act * up, "mlp_act")
-            mlp_out = _linear(prod, lp["mlp"]["down_proj"], compute_dtype, quant_impl, adapter_idx, w8a8)
-            if config.sandwich_norms:
-                mlp_out = rms_norm(
-                    mlp_out, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc
-                )
-            x = x + mlp_out
-    return x, new_entry, aux, expert_load
-
-
-def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, linear):
-    """q, k, v of latent attention (MLA) in its training form, for the
-    ordinary attention paths: ``hid [b, s, h]`` -> q, k ``[b, s, heads,
-    qk_nope + qk_rope]`` and v ``[b, s, heads, v_head_dim]``. k and v come up
-    from one normed latent of ``kv_lora_rank``; the rope key (one per token)
-    is rotated once and shared by every head. ``cos``/``sin`` are tables of
-    ``qk_rope_head_dim``; halves are rotated (HF de-interleaves DeepSeek's
-    stored pairs first; ``models/hf_io.py`` does that to the weights)."""
-    b, s, _ = hid.shape
-    nh, r = config.num_heads, config.kv_lora_rank
-    dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
-    q = linear(hid, attn_p["q_proj"]).reshape(b, s, nh, dn + dr)
-    latent = linear(hid, attn_p["kv_a_proj_with_mqa"])
-    c_kv = rms_norm(latent[..., :r], attn_p["kv_a_layernorm"]["weight"], config.rms_norm_eps)
-    kv = linear(c_kv, attn_p["kv_b_proj"]).reshape(b, s, nh, dn + dv)
-    q_pe, k_pe = apply_rope(q[..., dn:], latent[..., r:].reshape(b, s, 1, dr), cos, sin)
-    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, nh, dr))], axis=-1)
-    return q, k, kv[..., dn:]
+        subtree, _, feed_forward = _FEED_FORWARD[plan.feed_forward]
+        y, counted = feed_forward(
+            lp[subtree], hid, lin, config, compute_dtype=compute_dtype, mesh=mesh,
+            padding_mask=padding_mask, segment_ids=segment_ids, serving=cache_entry is not None,
+        )
+        if config.sandwich_norms and plan.feed_forward != "grouped_experts":
+            # (HF DeepseekV3's layer has no output norms; no model asks for both)
+            y = rms_norm(y, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc)
+        x = x + y
+    return x, new_entry, counted
 
 
 def keeps_flash_outputs(config: ModelConfig, seq: int) -> bool:
@@ -540,7 +566,7 @@ def keeps_flash_outputs(config: ModelConfig, seq: int) -> bool:
     sequence of a head subset; ring attention does not call it)."""
     from llm_fine_tune_distributed_tpu.ops.flash_attention import worth_keeping_across_remat
 
-    if config.kv_lora_rank:
+    if config.layer(0).attention == "latent":
         d_qk, d_v = config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim
     else:
         d_qk = d_v = config.resolved_head_dim
@@ -582,7 +608,15 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
     return kept if policy is None else saveable.save_from_both_policies(policy, kept)
 
 
-def forward(
+def rope_tables(config: ModelConfig, positions):
+    """cos, sin for ``positions``, at the width this model's attention
+    rotates: the whole head, or latent attention's rope part of it."""
+    latent = config.layer(0).attention == "latent"
+    width = config.qk_rope_head_dim if latent else config.resolved_head_dim
+    return rope_cos_sin(positions, width, config.rope_theta, config=config)
+
+
+def forward_with_report(
     params: Params,
     input_ids,
     config: ModelConfig,
@@ -601,16 +635,11 @@ def forward(
     activation_sharding=None,
     output_hidden: bool = False,
     quant_impl: str = "auto",
-    return_aux: bool = False,
-    return_expert_load: bool = False,
     adapter_idx=None,
     frozen_layers: int = 0,
     frozen_compute: str = "bf16",
-) -> (
-    Tuple[jax.Array, Optional[Dict[str, Any]]]
-    | Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]
-):
-    """Run the model.
+) -> Tuple[jax.Array, Optional[Dict[str, Any]], Dict[str, jax.Array]]:
+    """Run the model. ``forward`` is this without the report.
 
     Args:
       input_ids: int32 [batch, seq].
@@ -644,13 +673,6 @@ def forward(
         (in ``compute_dtype``) instead of logits — the chunked-loss path
         (train/step.py) unembeds chunk-by-chunk so the [batch, seq, vocab]
         float32 logits tensor never materializes in HBM.
-      return_aux: also return the summed MoE load-balancing loss as a third
-        element ``(out, cache, aux)`` — 0.0 for dense models. The train step
-        requests it when ``config.num_experts > 0``.
-      return_expert_load: also return, last, ``[expert layers, held experts]``
-        int32: the (token, expert) pairs each held expert of each layer of
-        routed experts (``config.n_routed_experts``) was given. The train
-        step's counters are made of it.
       activation_sharding: optional ``NamedSharding`` for the [batch, seq,
         hidden] activations (normally batch over (data, fsdp)). Constraining
         activations explicitly keeps XLA/Shardy propagation on the intended
@@ -659,7 +681,13 @@ def forward(
         layout). Set by the trainer whenever a mesh is in use.
 
     Returns:
-      (logits [batch, seq, vocab] in ``logits_dtype``, updated cache or None).
+      (logits [batch, seq, vocab] in ``logits_dtype``, updated cache or None,
+      report). The report is what the layers counted, one pytree whose
+      structure is fixed for a ``ModelConfig`` (``report_shapes``):
+      ``router_aux``, the capacity experts' load-balancing loss summed over
+      layers (float32 scalar); ``expert_load`` ``[expert layers, held
+      experts]`` int32, the (token, expert) pairs each held expert of each
+      layer of grouped experts was given. ``{}`` for a model with neither.
     """
     b, s = input_ids.shape
     if positions is None:
@@ -680,9 +708,7 @@ def forward(
     # MoE dispatch constrains its expert blocks to it; recover the mesh from
     # the activation sharding so call sites stay unchanged. (The attention
     # dispatch ignores it for non-sequence-parallel impls.)
-    mesh = None
-    if activation_sharding is not None:
-        mesh = getattr(activation_sharding, "mesh", None)
+    mesh = getattr(activation_sharding, "mesh", None)
 
     with scope("embed"):
         embed = params["model"]["embed_tokens"]["weight"].astype(compute_dtype)
@@ -705,10 +731,7 @@ def forward(
             # Gemma normalizer: HF multiplies by a sqrt(hidden) scalar cast to
             # the activation dtype first — mirror the cast for bf16 bit-parity
             x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
-    cos, sin = rope_cos_sin(
-        positions, config.qk_rope_head_dim if config.kv_lora_rank else config.resolved_head_dim,
-        config.rope_theta, config=config,
-    )
+    cos, sin = rope_tables(config, positions)
 
     explicit_mask = None
     windowed_mask = None
@@ -726,19 +749,15 @@ def forward(
             same_seg = segment_ids[:, :, None] == segment_ids[:, None, :]
             explicit_mask = causal & same_seg
             q_pos, k_pos = positions[:, :, None], positions[:, None, :]
-            # windowed variant for the layers the window applies to; global
-            # layers (Gemma2 odd layers) keep the plain block-causal mask
-            windowed_mask = explicit_mask & (k_pos > q_pos - config.sliding_window)
             segment_ids = None  # consumed into the explicit mask
     elif cache is not None:
         # Mask over the fixed-size buffer: key j visible to query i iff
         # j <= position(i), and within the sliding window if configured.
         # Paged caches mask the gathered [nb * block_len] view — gathered
         # index IS logical position, so the same rule applies verbatim.
+        kv_len = cache["layers"]["0"]["k"].shape[1]  # the buffer's length, or a block's
         if block_tables is not None:
-            kv_len = block_tables.shape[1] * cache["layers"]["0"]["k"].shape[1]
-        else:
-            kv_len = cache["layers"]["0"]["k"].shape[1]
+            kv_len *= block_tables.shape[1]
         k_pos = jnp.arange(kv_len, dtype=jnp.int32)[None, None, :]
         q_pos = positions[:, :, None]
         explicit_mask = k_pos <= q_pos
@@ -752,13 +771,14 @@ def forward(
                     f"(full buffer), got {padding_mask.shape}"
                 )
             explicit_mask &= padding_mask.astype(bool)[:, None, :]
-        if config.sliding_window is not None:
-            # after padding so the windowed variant carries the pad bits too
-            windowed_mask = explicit_mask & (k_pos > q_pos - config.sliding_window)
+    if explicit_mask is not None and config.sliding_window is not None:
+        # the windowed variant for the layers the window applies to (global
+        # layers, Gemma2's odd ones, keep the plain mask), made after the
+        # padding so that it carries the pad bits too
+        windowed_mask = explicit_mask & (k_pos > q_pos - config.sliding_window)
 
     new_layers = {}
-    expert_loads = []
-    moe_aux = jnp.float32(0.0)
+    counted_by_layer = []
     # Frozen-trunk fast path (TrainConfig.frozen_compute="int8"): layers
     # [0, frozen_layers) carry pre-quantized kernel_int8 siblings and run
     # their projections w8a8 (ops/int8_matmul). The trunk is a pure
@@ -780,15 +800,15 @@ def forward(
             # drops the same embedding-through-trunk gradient the exit
             # boundary drops anyway (documented approximation).
             x = jax.lax.stop_gradient(x)
+        plan = config.layer(i)
         block_fn = partial(
             _block,
             config=config,
-            layer_idx=i,
+            plan=plan,
             attention_impl=attention_impl,
             compute_dtype=compute_dtype,
             mesh=mesh,
             quant_impl=quant_impl,
-            windowed_mask=windowed_mask,
             block_tables=block_tables,
             adapter_idx=adapter_idx,
             w8a8=in_trunk,
@@ -796,14 +816,14 @@ def forward(
         if remat and not in_trunk:
             block_fn = jax.checkpoint(block_fn, policy=kept_of_a_block)
         with scope("layer", i):
-            x, new_entry, layer_aux, layer_load = block_fn(
+            x, new_entry, counted = block_fn(
                 params["model"]["layers"][str(i)],
                 x,
                 cos,
                 sin,
                 padding_mask,
                 segment_ids,
-                explicit_mask,
+                explicit_mask if plan.window is None else windowed_mask,  # (made whenever an explicit one is)
                 entry,
                 cache_pos,
             )
@@ -815,11 +835,8 @@ def forward(
             # docs/architecture.md "Training fast path") so the trunk
             # backward is dead code the compiler eliminates.
             x = jax.lax.stop_gradient(x)
-        moe_aux = moe_aux + layer_aux
-        if layer_load is not None:
-            expert_loads.append(layer_load)
-        if new_entry is not None:
-            new_layers[str(i)] = new_entry
+        counted_by_layer.append(counted)
+        new_layers[str(i)] = new_entry
 
     with scope("final_norm"):
         x = rms_norm(
@@ -837,11 +854,12 @@ def forward(
             out = unembed(
                 params, x, config, compute_dtype=compute_dtype, logits_dtype=logits_dtype, mesh=mesh
             )
-    result = (out, new_cache) + ((moe_aux,) if return_aux else ())
-    if return_expert_load:
-        no_layers = jnp.zeros((0, len(config.held_expert_ids)), jnp.int32)
-        result += (jnp.stack(expert_loads) if expert_loads else no_layers,)
-    return result
+    return out, new_cache, _report(counted_by_layer)
+
+
+def forward(params: Params, input_ids, config: ModelConfig, **kwargs) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
+    """``forward_with_report``, by its keywords, without the report: ``(out, cache)``."""
+    return forward_with_report(params, input_ids, config, **kwargs)[:2]
 
 
 def _lookup_table_constraint(table, mesh, vocab_dim: int = 0):
@@ -961,7 +979,7 @@ def init_paged_cache(
     ``kv_quant="int8"`` keeps the same per-layer ``k``/``v`` shape in int8
     and adds sibling ``k_scale``/``v_scale`` pools — f32 per-(block, kv-head)
     absmax, indexed by the same block ids — halving HBM per cached token.
-    ``_block`` detects the layout by the ``k_scale`` key; the allocator and
+    ``_cache_write_and_view`` tells the layout by the ``k_scale`` key; the allocator and
     prefix cache (infer/paged.py) deal only in block ids and are untouched.
     Scales start at 0 ("never written"), so every block — the null block
     forever — dequantizes to exact zeros until its first real write.
@@ -1019,16 +1037,3 @@ def insert_cache_row(cache, row_cache, slot):
             for n in ("k", "v")
         }
     return {"layers": new_layers}
-
-
-class TransformerLM:
-    """Thin OO facade over the functional API (convenience for scripts)."""
-
-    def __init__(self, config: ModelConfig):
-        self.config = config
-
-    def init(self, rng, dtype=jnp.float32) -> Params:
-        return init_params(rng, self.config, dtype)
-
-    def apply(self, params, input_ids, **kw):
-        return forward(params, input_ids, self.config, **kw)

@@ -771,7 +771,7 @@ def _paged_decode_kernel(
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * (scale * k_scale)  # [hq, L*hkv]
-    # gathered index IS logical position (models/transformer._block): slot i
+    # gathered index IS logical position (models/transformer._cache_write_and_view): slot i
     # of the table covers positions [i*L, (i+1)*L); visible iff < length.
     # Null-table slots gather block 0 (zero codes, zero scale) at positions
     # at/above length, so they are masked here exactly like the XLA path.
@@ -800,7 +800,7 @@ PAGED_DECODE_MODES = ("fused", "xla", "interpret")
 
 
 def paged_decode_mode() -> str:
-    """How ``models/transformer._block`` reads the int8 paged pool at
+    """How ``models/transformer._cache_write_and_view`` reads the int8 paged pool at
     decode. On a TPU it is ``"fused"``: the Pallas kernel, compiled — if
     Mosaic refuses it the run fails, nothing falls back. Elsewhere it is
     ``"xla"`` (dequantizing gather + masked attention), so CPU tests never

@@ -210,7 +210,7 @@ def quantize_kv_write(codes, scales, blk, off, x):
 
 def dequantize_kv_gather(codes, scales, block_tables, dtype=jnp.bfloat16):
     """Gather a row's table blocks out of an int8 paged pool into the dense
-    [b, nb * block_len, kv_heads, d] view ``models/transformer._block``
+    [b, nb * block_len, kv_heads, d] view ``models/transformer._cache_write_and_view``
     attends over (the XLA fallback for the fused Pallas decode kernel —
     ops/flash_attention.paged_decode_attention). The gathered index IS the
     logical position, exactly like the bf16 layout, so the caller's position

@@ -52,14 +52,14 @@ shard over pipe AND expert), and and with sequence parallelism — BOTH impls (`
 ``"ulysses"`` + a live seq axis: the schedule goes manual over seq and
 stages call the local kernels — long-context pipe runs). Scope bounds
 (raised loudly by the trainer): packing (no segment support in the
-schedule) and seq-parallel x MoE (per-chunk routing would change capacity
-semantics).
+schedule) and what the layer scan asks of a model (``layer_scan_problems``).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import re
-from typing import Dict
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -67,10 +67,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import optax
 
-from llm_fine_tune_distributed_tpu.config import ModelConfig
-from llm_fine_tune_distributed_tpu.models.transformer import _block, unembed
+from llm_fine_tune_distributed_tpu.config import ModelConfig, str_to_dtype
+from llm_fine_tune_distributed_tpu.models.transformer import _block, report_shapes, rope_tables, unembed
 from llm_fine_tune_distributed_tpu.ops.norms import rms_norm
-from llm_fine_tune_distributed_tpu.ops.rope import rope_cos_sin
 
 
 def stack_stage_params(params: Dict, config: ModelConfig, num_stages: int) -> Dict:
@@ -95,6 +94,30 @@ def stage_sharding(mesh: Mesh):
     return NamedSharding(mesh, P("pipe"))
 
 
+def layer_scan_problems(config: ModelConfig, seq_parallel: bool) -> List[str]:
+    """Why ``config`` cannot run under the schedule's layer scan (empty: it
+    can). The scan compiles ONE block body over stacked leaves, so every
+    layer's plan (``ModelConfig.layer``) must be the same but for ``rope``,
+    which the scan carries as data; and the capacity experts' router must see
+    whole rows, which a manual ``seq`` axis (``seq_parallel``) takes away.
+    Said here once, for ``pipeline_forward`` and the trainer alike."""
+    problems = []
+    plans = [{**dataclasses.asdict(config.layer(i)), "rope": True} for i in range(config.num_layers)]
+    odd = next((i for i, plan in enumerate(plans) if plan != plans[0]), None)
+    if odd is not None:
+        differ = ", ".join(f"{k} ({v!r} vs {plans[odd][k]!r})" for k, v in plans[0].items() if v != plans[odd][k])
+        problems.append(
+            f"layers that differ: the pipeline's layer scan runs identical layers, and layers 0 and {odd} "
+            f"of {config.name!r} differ in {differ}"
+        )
+    if seq_parallel and plans[0]["feed_forward"] == "capacity_experts":
+        problems.append(
+            "a sequence-parallel attention_impl with capacity experts (MoE): inside the manual-seq "
+            "schedule the router would see per-chunk token populations, changing capacity semantics"
+        )
+    return problems
+
+
 def pipeline_forward(
     params: Dict,
     stacked_layers: Dict,
@@ -107,10 +130,9 @@ def pipeline_forward(
     compute_dtype=jnp.bfloat16,
     remat_blocks: bool = True,
     output_hidden: bool = False,
-    return_aux: bool = False,
     attention_impl: str = "xla",
 ):
-    """Pipelined forward: logits for ``input_ids [M * mb, seq]``.
+    """Pipelined forward: ``(logits for input_ids [M * mb, seq], report)``.
 
     ``params`` holds the non-pipelined leaves (embedding, final norm, lm_head
     if untied), replicated; ``stacked_layers`` are the transformer blocks
@@ -119,9 +141,11 @@ def pipeline_forward(
 
     MoE models work too: each stage accumulates its layers' router aux loss
     in the scan carry, bubble ticks are masked out, and the psum over the
-    pipe axis yields the total. With ``return_aux=True`` the result is
-    ``(out, aux)`` where aux is the layer-SUM averaged over microbatches —
-    the same scale ``models/transformer.forward`` returns per microbatch.
+    pipe axis yields the total. The report holds it as ``router_aux`` where
+    the model's own report does (``models/transformer.report_shapes``): the
+    layer-SUM averaged over microbatches — the same scale
+    ``forward_with_report`` gives per microbatch. It is all the schedule
+    carries of what layers count (no ``expert_load``).
     Expert parallelism composes: the schedule's shard_map is manual only
     over pipe + dp axes, so expert-sharded stacked leaves
     ([L, E, in, out] -> P("pipe", "expert", ...)) keep EP inside each stage
@@ -159,14 +183,12 @@ def pipeline_forward(
     # [1, seq]: broadcasts over however many microbatch rows a device holds
     # (the mb dim shards over data/fsdp inside the shard_map)
     positions = jnp.arange(seq, dtype=jnp.int32)[None]
-    cos, sin = rope_cos_sin(
-        positions, config.resolved_head_dim, config.rope_theta, config=config
-    )
+    cos, sin = rope_tables(config, positions)
     # Per-layer RoPE flags as DATA: the layer scan compiles one block body,
     # and NoPE-interleaved models (SmolLM3) select rope/no-rope per layer.
     # Uniform patterns (every preset except NoPE ones) skip the
     # rotate-then-select and keep the static branch.
-    flags_list = [config.uses_rope(i) for i in range(config.num_layers)]
+    flags_list = [config.layer(i).rope for i in range(config.num_layers)]
     uniform_rope = all(flags_list) or not any(flags_list)
     rope_flags = jnp.asarray(flags_list, jnp.bool_)
 
@@ -177,17 +199,9 @@ def pipeline_forward(
     seq_parallel = (
         attention_impl in ("ring", "ulysses") and mesh.shape.get("seq", 1) > 1
     )
-    if config.first_k_dense_replace:
-        raise ValueError(
-            "the pipeline's layer scan runs identical layers: leading dense "
-            "layers before the expert layers (first_k_dense_replace) are not supported"
-        )
-    if seq_parallel and config.num_experts > 0:
-        raise ValueError(
-            f"pipe x {attention_impl} does not compose with MoE: inside the "
-            "manual-seq schedule the router would see per-chunk token "
-            "populations, changing capacity semantics"
-        )
+    problems = layer_scan_problems(config, seq_parallel)
+    if problems:
+        raise ValueError("the pipeline does not compose with: " + "; ".join(problems))
     if seq_parallel and seq % mesh.shape["seq"]:
         raise ValueError(
             f"seq {seq} not divisible by the seq axis ({mesh.shape['seq']})"
@@ -209,14 +223,14 @@ def pipeline_forward(
         def one_block(carry, args):
             h, aux = carry
             layer_params, flag = args
-            h, _, layer_aux, _ = _block(
+            h, _, counted = _block(
                 layer_params, h, cos_l, sin_l, mask, None, None, None, 0,
-                config=config, layer_idx=0, attention_impl=stage_impl,
+                config=config, plan=config.layer(0), attention_impl=stage_impl,
                 compute_dtype=compute_dtype,
                 mesh=mesh if seq_parallel else None,
                 rope_flag=None if uniform_rope else flag,
             )
-            return (h, aux + layer_aux), None
+            return (h, aux + counted.get("router_aux", 0.0)), None
 
         body = jax.checkpoint(one_block) if remat_blocks else one_block
         (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), (stage_layers, stage_flags))
@@ -342,7 +356,7 @@ def pipeline_forward(
         out = h.astype(compute_dtype)
     else:
         out = unembed(params, h, config, compute_dtype=compute_dtype, logits_dtype=jnp.float32)
-    return (out, aux) if return_aux else out
+    return out, ({"router_aux": aux} if "router_aux" in report_shapes(config) else {})
 
 
 def pipeline_loss_fn(
@@ -371,48 +385,34 @@ def pipeline_loss_fn(
     targets = ids[..., 1:]
     mask = batch["loss_mask"][..., 1:].astype(jnp.float32)
     tokens = jnp.maximum(mask.sum(), 1.0)
-    want_aux = include_router_aux and config.num_experts > 0
-
-    def add_aux(loss, aux):
-        if not want_aux:
-            return loss
-        return loss + config.router_aux_coef * aux / config.num_layers
-
-    if loss_chunk_size is not None:
-        # never materialize [B, seq, vocab] logits (128k-vocab models):
-        # unembed chunk-by-chunk exactly like train/step.py
+    # one schedule either way: hidden states where the loss is chunked
+    # (never materialize [B, seq, vocab] logits of a 128k vocabulary: unembed
+    # chunk by chunk exactly like train/step.py), logits where it is not
+    out, report = pipeline_forward(
+        params, stacked_layers, ids, config, mesh,
+        num_microbatches, padding_mask=batch.get("attention_mask"),
+        compute_dtype=compute_dtype, output_hidden=loss_chunk_size is not None,
+        attention_impl=attention_impl,
+    )
+    if loss_chunk_size is None:
+        ce = optax.softmax_cross_entropy_with_integer_labels(out[..., :-1, :], targets)
+        ce_sum = (ce * mask).sum()
+    else:
         from llm_fine_tune_distributed_tpu.train.step import chunked_ce_sum
 
-        hidden, aux = pipeline_forward(
-            params, stacked_layers, ids, config, mesh,
-            num_microbatches, padding_mask=batch.get("attention_mask"),
-            compute_dtype=compute_dtype, output_hidden=True, return_aux=True,
-            attention_impl=attention_impl,
-        )
+        def chunked(hidden, targets, mask):
+            return chunked_ce_sum(params, hidden[:, :-1], targets, mask, config, loss_chunk_size, compute_dtype)
+
         if micro_dims:
             # one chunked-CE pass per microbatch (lax.map keeps a single
             # compiled body and one [mb, chunk, vocab] tile live at a time)
-            ce_sum = jax.lax.map(
-                lambda args: chunked_ce_sum(
-                    params, args[0][:, :-1], args[1], args[2], config,
-                    loss_chunk_size, compute_dtype,
-                ),
-                (hidden, targets, mask),
-            ).sum()
+            ce_sum = jax.lax.map(lambda args: chunked(*args), (out, targets, mask)).sum()
         else:
-            ce_sum = chunked_ce_sum(
-                params, hidden[:, :-1], targets, mask, config, loss_chunk_size,
-                compute_dtype,
-            )
-        return add_aux(ce_sum / tokens, aux)
-    logits, aux = pipeline_forward(
-        params, stacked_layers, ids, config, mesh,
-        num_microbatches, padding_mask=batch.get("attention_mask"),
-        compute_dtype=compute_dtype, return_aux=True,
-        attention_impl=attention_impl,
-    )
-    ce = optax.softmax_cross_entropy_with_integer_labels(logits[..., :-1, :], targets)
-    return add_aux((ce * mask).sum() / tokens, aux)
+            ce_sum = chunked(out, targets, mask)
+    loss = ce_sum / tokens
+    if include_router_aux and "router_aux" in report:
+        loss = loss + config.router_aux_coef * report["router_aux"] / config.num_layers
+    return loss
 
 
 # ---------------------------------------------------------------------------
@@ -588,8 +588,6 @@ def build_pipeline_train_step(model_config, train_config, optimizer, mesh, layer
     Freezing: grads AND updates on stacked leaves are masked by
     ``layer_vec`` — masking updates too keeps AdamW's decoupled weight decay
     off frozen layers."""
-    from llm_fine_tune_distributed_tpu.config import str_to_dtype
-
     compute_dtype = str_to_dtype(train_config.compute_dtype)
     M = train_config.gradient_accumulation_steps
     chunk = train_config.loss_chunk_size
@@ -644,8 +642,6 @@ def eval_microbatches(mesh: Mesh, batch_rows: int) -> int:
 def build_pipeline_eval_step(model_config, train_config, mesh):
     """eval_step(state, batch[b, s]) -> (ce_sum, token_count), matching
     train/step.build_eval_step's contract (pure CE, no router aux)."""
-    from llm_fine_tune_distributed_tpu.config import str_to_dtype
-
     compute_dtype = str_to_dtype(train_config.compute_dtype)
     chunk = train_config.loss_chunk_size
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import optax
 
 from llm_fine_tune_distributed_tpu.config import ModelConfig, TrainConfig, str_to_dtype
-from llm_fine_tune_distributed_tpu.models.transformer import forward, unembed
+from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, unembed
 from llm_fine_tune_distributed_tpu.train.state import TrainState
 from llm_fine_tune_distributed_tpu.train.step import jit_train_step
 from llm_fine_tune_distributed_tpu.utils.tree import merge_flat
@@ -104,6 +104,15 @@ def _dpo_pair_loss(pi_c, pi_r, ref_c, ref_r, beta: float, eps: float):
     return per_pair_loss.mean(), aux
 
 
+def _with_router_aux(loss, report, model_config: ModelConfig):
+    """Where the POLICY forward's report has a router load-balancing loss, it
+    joins the train objective (layer-mean scale, same as SFT); the reference
+    model is stop-gradient so its routers need no balancing pressure."""
+    if "router_aux" not in report:
+        return loss
+    return loss + model_config.router_aux_coef * report["router_aux"] / model_config.num_layers
+
+
 def make_dpo_loss_fn(
     model_config: ModelConfig,
     train_config: TrainConfig,
@@ -133,13 +142,9 @@ def make_dpo_loss_fn(
     quant_impl = quant_impl or train_config.quant_matmul_impl
     beta = train_config.dpo_beta
     eps = train_config.dpo_label_smoothing
-    # MoE: the POLICY forward contributes the router load-balancing loss to
-    # the train objective (layer-mean scale, same as SFT); the reference
-    # model is stop-gradient so its routers need no balancing pressure.
-    want_moe_aux = model_config.num_experts > 0
 
-    def batch_logprobs(params, input_ids, attention_mask, loss_mask, with_aux=False):
-        result = forward(
+    def batch_logprobs(params, input_ids, attention_mask, loss_mask):
+        hidden, _, report = forward_with_report(
             params,
             input_ids,
             model_config,
@@ -151,15 +156,12 @@ def make_dpo_loss_fn(
             activation_sharding=activation_sharding,
             output_hidden=True,
             quant_impl=quant_impl,
-            return_aux=with_aux,
         )
-        hidden = result[0]
         per_token = _target_logprobs(
             params, hidden[:, :-1], input_ids[:, 1:], model_config, chunk, compute_dtype,
             mesh=getattr(activation_sharding, "mesh", None),
         )
-        lp = masked_sequence_logprob(per_token, loss_mask)
-        return (lp, result[2]) if with_aux else lp
+        return masked_sequence_logprob(per_token, loss_mask), report
 
     def loss_fn(trainable, ref_trainable, frozen, batch):
         # one [2B, S] forward per model: rows 0..B-1 chosen, B..2B-1 rejected
@@ -170,23 +172,16 @@ def make_dpo_loss_fn(
         mask = jnp.concatenate([batch["chosen_loss_mask"], batch["rejected_loss_mask"]])
         b = batch["chosen_input_ids"].shape[0]
 
-        if want_moe_aux:
-            policy_lp, moe_aux = batch_logprobs(
-                merge_flat(trainable, frozen), ids, attn, mask, with_aux=True
-            )
-        else:
-            policy_lp = batch_logprobs(merge_flat(trainable, frozen), ids, attn, mask)
+        policy_lp, report = batch_logprobs(merge_flat(trainable, frozen), ids, attn, mask)
         ref_params = merge_flat(
             {k: jax.lax.stop_gradient(v) for k, v in ref_trainable.items()}, frozen
         )
-        ref_lp = jax.lax.stop_gradient(batch_logprobs(ref_params, ids, attn, mask))
+        ref_lp = jax.lax.stop_gradient(batch_logprobs(ref_params, ids, attn, mask)[0])
 
         pi_c, pi_r = policy_lp[:b], policy_lp[b:]
         ref_c, ref_r = ref_lp[:b], ref_lp[b:]
         loss, aux = _dpo_pair_loss(pi_c, pi_r, ref_c, ref_r, beta, eps)
-        if want_moe_aux:
-            loss = loss + model_config.router_aux_coef * moe_aux / model_config.num_layers
-        return loss, aux
+        return _with_router_aux(loss, report, model_config), aux
 
     return loss_fn
 
@@ -262,14 +257,13 @@ def make_pipeline_dpo_loss_fn(model_config: ModelConfig, train_config: TrainConf
     chunk = train_config.loss_chunk_size
     beta = train_config.dpo_beta
     eps = train_config.dpo_label_smoothing
-    want_moe_aux = model_config.num_experts > 0
 
     def batch_logprobs(flat_params, ids, attn, mask, M):
         params, stacked = split_stacked_flat(flat_params)
-        hidden, moe_aux = pipeline_forward(
+        hidden, report = pipeline_forward(
             params, stacked, ids, model_config, mesh, M,
             padding_mask=attn, compute_dtype=compute_dtype,
-            output_hidden=True, return_aux=True,
+            output_hidden=True,
         )
 
         def lp_one(args):
@@ -280,7 +274,7 @@ def make_pipeline_dpo_loss_fn(model_config: ModelConfig, train_config: TrainConf
 
         per_token = jax.lax.map(lp_one, (hidden, ids[..., 1:]))  # [M, 2B, S-1]
         lp = (per_token * mask[..., 1:]).sum(axis=-1)  # [M, 2B]
-        return lp, moe_aux
+        return lp, report
 
     def loss_fn(trainable, ref_trainable, frozen, batch):
         ids = jnp.concatenate(
@@ -294,7 +288,7 @@ def make_pipeline_dpo_loss_fn(model_config: ModelConfig, train_config: TrainConf
         ).astype(jnp.float32)
         M, b = batch["chosen_input_ids"].shape[:2]
 
-        policy_lp, moe_aux = batch_logprobs({**trainable, **frozen}, ids, attn, mask, M)
+        policy_lp, report = batch_logprobs({**trainable, **frozen}, ids, attn, mask, M)
         ref_flat = {
             **{k: jax.lax.stop_gradient(v) for k, v in ref_trainable.items()},
             **frozen,
@@ -305,9 +299,7 @@ def make_pipeline_dpo_loss_fn(model_config: ModelConfig, train_config: TrainConf
         pi_c, pi_r = policy_lp[:, :b], policy_lp[:, b:]
         ref_c, ref_r = ref_lp[:, :b], ref_lp[:, b:]
         loss, aux = _dpo_pair_loss(pi_c, pi_r, ref_c, ref_r, beta, eps)
-        if want_moe_aux:
-            loss = loss + model_config.router_aux_coef * moe_aux / model_config.num_layers
-        return loss, aux
+        return _with_router_aux(loss, report, model_config), aux
 
     return loss_fn
 

@@ -13,7 +13,6 @@ of DDP's bucketed NCCL all-reduce (reference ``docs/architecture-diagram.md:119-
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -21,7 +20,7 @@ import jax.numpy as jnp
 import optax
 
 from llm_fine_tune_distributed_tpu.config import ModelConfig, TrainConfig, str_to_dtype
-from llm_fine_tune_distributed_tpu.models.transformer import forward, unembed
+from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, report_shapes, unembed
 from llm_fine_tune_distributed_tpu.observe.xla import scope
 from llm_fine_tune_distributed_tpu.train.state import TrainState
 from llm_fine_tune_distributed_tpu.utils.tree import merge_flat
@@ -189,10 +188,13 @@ def static_seq_parallel_size(
 
 def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activation_sharding=None,
                  quant_impl: Optional[str] = None, include_router_aux: bool = True,
-                 frozen_layers: int = 0, with_expert_load: bool = False):
-    """``with_expert_load`` (the train step's, for a model of routed experts
-    with shared experts): the loss function's second result becomes
-    ``(tokens, expert_load [expert layers, held experts])``."""
+                 frozen_layers: int = 0):
+    """``loss_fn(trainable, frozen, batch) -> (loss, stats)``. ``stats`` is a
+    dict: ``tokens`` always; ``answer_ce_sum`` and ``answer_tokens`` when the
+    batch carries a ``completion_mask``; ``expert_load`` where the model's
+    report has one (``models/transformer.forward_with_report``).
+    ``include_router_aux``: add the report's ``router_aux``, where it has
+    one, to the loss (eval leaves the balancing loss out)."""
     compute_dtype = str_to_dtype(train_config.compute_dtype)
     _mesh = getattr(activation_sharding, "mesh", None)
     seq_parallel = static_seq_parallel_size(model_config, train_config, _mesh)
@@ -215,22 +217,17 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
         raise ValueError(
             f"unknown frozen_compute {frozen_compute!r} (expected 'bf16' or 'int8')"
         )
-    # MoE: add the load-balancing aux loss to the TRAIN objective only (eval
-    # loss stays pure CE so perplexity/best-model tracking is comparable with
-    # dense runs). Dense models skip the plumbing entirely.
-    want_aux = include_router_aux and model_config.num_experts > 0
 
     def loss_fn(trainable, frozen, batch):
         """Masked next-token cross-entropy (token-mean within the batch) —
         the SFT objective TRL computes for packing=False full-sequence LM
-        loss (reference ``training.py:282-283``). Returns (loss, token_count).
+        loss (reference ``training.py:282-283``).
 
         When the batch additionally carries a ``completion_mask`` (eval
-        batches only — trainer._prepare_data), returns
-        (loss, tokens, answer_ce_sum, answer_tokens): the completion-span CE
-        computed from the SAME forward pass, so the answer-only eval metric
-        (the full-sequence eval_loss is dominated by the
-        constant system prompt) costs one extra masked reduction on the
+        batches only — trainer._prepare_data), ``stats`` holds the
+        completion-span CE, computed from the SAME forward pass, so the
+        answer-only eval metric (the full-sequence eval_loss is dominated by
+        the constant system prompt) costs one extra masked reduction on the
         full-logits path (and one extra streamed unembed on the chunked
         paths, which rematerialize per-mask)."""
         params = merge_flat(trainable, frozen)
@@ -240,7 +237,7 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
                 "segment_ids": batch["segment_ids"],
                 "positions": batch["positions"],
             }
-        result = forward(
+        out, _, report = forward_with_report(
             params,
             batch["input_ids"],
             model_config,
@@ -254,16 +251,12 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             logits_dtype=jnp.float32,
             output_hidden=chunk is not None or vocab_chunk is not None,
             quant_impl=quant_impl,
-            return_aux=want_aux,
-            return_expert_load=with_expert_load,
             frozen_layers=frozen_layers,
             frozen_compute=frozen_compute,
         )
-        out = result[0]
         targets = batch["input_ids"][:, 1:]
         mask = batch["loss_mask"][:, 1:].astype(jnp.float32)
         tokens = jnp.maximum(mask.sum(), 1.0)
-        _mesh_kw = getattr(activation_sharding, "mesh", None)
         amask = None
         if "completion_mask" in batch:
             amask = batch["completion_mask"][:, 1:].astype(jnp.float32)
@@ -275,12 +268,12 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             if vocab_chunk is not None:
                 ce_fn = lambda m, e=None: vocab_chunked_ce_sum(
                     params, out[:, :-1], targets, m, model_config, vocab_chunk,
-                    compute_dtype, mesh=_mesh_kw, extra_mask=e,
+                    compute_dtype, mesh=_mesh, extra_mask=e,
                 )
             elif chunk is not None:
                 ce_fn = lambda m, e=None: chunked_ce_sum(
                     params, out[:, :-1], targets, m, model_config, chunk,
-                    compute_dtype, mesh=_mesh_kw, extra_mask=e,
+                    compute_dtype, mesh=_mesh, extra_mask=e,
                 )
             else:
                 ce = optax.softmax_cross_entropy_with_integer_labels(out[:, :-1], targets)
@@ -292,20 +285,54 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             else:
                 ce_sum = ce_fn(mask)
         loss = ce_sum / tokens
-        if want_aux:
-            # layer-MEAN of the per-layer aux (forward returns the sum), so
-            # router_aux_coef is depth-independent — matching the effective
-            # scale of HF Mixtral's router_aux_loss_coef rather than growing
-            # the balancing pressure 32x on a 32-layer model
-            aux = result[2] / model_config.num_layers
+        if include_router_aux and "router_aux" in report:
+            # The load-balancing loss joins the TRAIN objective only (eval
+            # loss stays pure CE so perplexity/best-model tracking is
+            # comparable with dense runs). Layer-MEAN of the per-layer aux
+            # (the report has the sum), so router_aux_coef is
+            # depth-independent — matching the effective scale of HF
+            # Mixtral's router_aux_loss_coef rather than growing the
+            # balancing pressure 32x on a 32-layer model
+            aux = report["router_aux"] / model_config.num_layers
             loss = loss + model_config.router_aux_coef * aux
+        stats = {"tokens": tokens}
         if amask is not None:
-            return loss, tokens, ans_sum, amask.sum()
-        if with_expert_load:
-            return loss, (tokens, result[-1])
-        return loss, tokens
+            stats.update(answer_ce_sum=ans_sum, answer_tokens=amask.sum())
+        if "expert_load" in report:
+            stats["expert_load"] = report["expert_load"]
+        return loss, stats
 
     return loss_fn
+
+
+# What the train step keeps of the loss function's ``stats``, by the
+# statistic's key: what one microbatch adds to the accumulation scan's carry,
+# how the carry combines it, and the metrics made of the step's totals.
+def _kept_of_a_microbatch(stats):
+    kept = {}
+    if "expert_load" in stats:
+        load = stats["expert_load"].astype(jnp.float32)  # [expert layers, held experts]
+        worst = (load.max(-1) / jnp.maximum(load.mean(-1), 1.0)).max()
+        kept.update(expert_load=load.sum(0), expert_load_max_over_mean=worst)
+    return kept
+
+
+_COMBINE_KEPT = {"expert_load": jnp.add, "expert_load_max_over_mean": jnp.maximum}
+
+
+def _metrics_of_kept(kept, report, batch_positions: int):
+    if "expert_load" not in kept:
+        return {}
+    positions = batch_positions * report["expert_load"].shape[0]  # tokens x expert layers
+    return dict(
+        # pairs held here a token and expert layer (the expectation under
+        # even routing is k * held / routed experts); each held expert's
+        # pairs by that same measure; and the fullest held expert over the
+        # mean, in the step's worst layer and microbatch
+        expert_pairs_per_token=kept["expert_load"].sum() / positions,
+        expert_load=kept["expert_load"] / positions,
+        expert_load_max_over_mean=kept["expert_load_max_over_mean"],
+    )
 
 
 def build_train_step(
@@ -323,37 +350,29 @@ def build_train_step(
     the accumulation factor (reference ``gradient_accumulation_steps=4``,
     ``training.py:262``).
     """
-    # routed experts with shared experts: the step counts the (token, expert)
-    # pairs its held experts were given
-    experts = model_config.layer_has_experts(model_config.num_layers - 1)
     loss_fn = make_loss_fn(
-        model_config, train_config, activation_sharding, quant_impl,
-        frozen_layers=frozen_layers, with_expert_load=experts,
+        model_config, train_config, activation_sharding, quant_impl, frozen_layers=frozen_layers,
     )
     accum = train_config.gradient_accumulation_steps
+    report = report_shapes(model_config)
+    kept_shapes = jax.eval_shape(_kept_of_a_microbatch, report)
 
     def train_step(state: TrainState, batch):
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
         def micro_step(carry, micro):
-            g_acc, loss_acc, counters = carry
-            (loss, aux), grads = grad_fn(state.trainable, state.frozen, micro)
+            g_acc, loss_acc, kept = carry
+            (loss, stats), grads = grad_fn(state.trainable, state.frozen, micro)
             with scope("grad_accum"):
                 g_acc = jax.tree.map(jnp.add, g_acc, grads)
-            if experts:
-                load_acc, skew = counters
-                load = aux[1].astype(jnp.float32)  # [expert layers, held experts]
-                worst = (load.max(-1) / jnp.maximum(load.mean(-1), 1.0)).max()
-                counters = (load_acc + load.sum(0), jnp.maximum(skew, worst))
-            return (g_acc, loss_acc + loss, counters), None
+            kept = {k: _COMBINE_KEPT[k](kept[k], v) for k, v in _kept_of_a_microbatch(stats).items()}
+            return (g_acc, loss_acc + loss, kept), None
 
         with scope("grad_accum"):
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.trainable)
-        counters = ()  # dense models carry none: their step program is what it was
-        if experts:
-            counters = (jnp.zeros((len(model_config.held_expert_ids),), jnp.float32), jnp.float32(0.0))
-        (g_sum, loss_sum, counters), _ = jax.lax.scan(
-            micro_step, (zeros, jnp.float32(0.0), counters), batch
+        kept = jax.tree.map(lambda k: jnp.zeros(k.shape, k.dtype), kept_shapes)
+        (g_sum, loss_sum, kept), _ = jax.lax.scan(
+            micro_step, (zeros, jnp.float32(0.0), kept), batch
         )
 
         # Mean over accumulation steps (HF semantics: mean of microbatch means).
@@ -374,20 +393,8 @@ def build_train_step(
         metrics = {
             "loss": loss,
             "grad_norm": grad_norm,
+            **_metrics_of_kept(kept, report, batch["input_ids"].size),
         }
-        if experts:
-            load_sum, skew = counters
-            expert_layers = model_config.num_layers - model_config.first_k_dense_replace
-            positions = batch["input_ids"].size * expert_layers
-            metrics.update(
-                # pairs held here a token and expert layer (the expectation
-                # under even routing is k * held / n_routed_experts); each held
-                # expert's pairs by that same measure; and the fullest held
-                # expert over the mean, in the step's worst layer and microbatch
-                expert_pairs_per_token=load_sum.sum() / positions,
-                expert_load=load_sum / positions,
-                expert_load_max_over_mean=skew,
-            )
         return new_state, metrics
 
     return train_step
@@ -413,11 +420,10 @@ def build_eval_step(
     )
 
     def eval_step(state: TrainState, batch):
-        out = loss_fn(state.trainable, state.frozen, batch)
-        if len(out) == 4:
-            loss, tokens, ans_ce, ans_tokens = out
-            return loss * tokens, tokens, ans_ce, ans_tokens
-        loss, tokens = out
+        loss, stats = loss_fn(state.trainable, state.frozen, batch)
+        tokens = stats["tokens"]
+        if "answer_ce_sum" in stats:
+            return loss * tokens, tokens, stats["answer_ce_sum"], stats["answer_tokens"]
         return loss * tokens, tokens
 
     return eval_step

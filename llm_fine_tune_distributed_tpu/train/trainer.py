@@ -540,6 +540,8 @@ class SFTTrainer:
         return _validate_spec(param_spec(path, leaf.ndim), leaf.shape, self.mesh)
 
     def _validate_pipeline_config(self) -> None:
+        from llm_fine_tune_distributed_tpu.parallel.pipeline import bubble_fraction, layer_scan_problems
+
         cfg, mc = self.config, self.model_config
         problems = []
         if cfg.packing:
@@ -547,14 +549,8 @@ class SFTTrainer:
         if cfg.attention_impl in ("ring", "ulysses"):
             # both sequence-parallel impls compose: the schedule goes manual
             # over seq and stages call the LOCAL kernel (ring_manual /
-            # ulysses_manual) — except with MoE, where per-chunk routing
-            # would change capacity semantics (pipeline_forward raises the
-            # same constraints)
+            # ulysses_manual)
             seq_size = max(self.mesh.shape.get("seq", 1), 1)
-            if mc.num_experts > 0:
-                problems.append(
-                    f"attention_impl={cfg.attention_impl!r} with an MoE preset"
-                )
             if cfg.max_seq_length % seq_size:
                 problems.append(
                     f"max_seq_length={cfg.max_seq_length} not divisible by "
@@ -567,19 +563,8 @@ class SFTTrainer:
                 )
         if cfg.objective not in ("sft", "dpo"):
             problems.append(f"objective={cfg.objective!r}")
-        if mc.first_k_dense_replace:
-            problems.append(
-                "first_k_dense_replace (leading dense layers before the expert "
-                "layers) — the pipeline layer-scan runs identical layers"
-            )
-        if mc.alternating_sliding_window:
-            # the schedule's layer-scan treats every layer identically
-            # (layer_idx is data, not Python); the local/global window
-            # alternation needs per-layer static masks
-            problems.append(
-                "alternating_sliding_window (Gemma2) — the pipeline "
-                "layer-scan has no per-layer window support"
-            )
+        # what the layer scan asks of the MODEL is said once; the checks here are about the run
+        problems.extend(layer_scan_problems(mc, cfg.attention_impl in ("ring", "ulysses")))
         if cfg.loss_vocab_chunk is not None:
             # the schedule's last stage computes CE via loss_chunk_size only
             # (parallel/pipeline.py) — rejecting beats silently materializing
@@ -601,7 +586,7 @@ class SFTTrainer:
             # legal but mostly bubble: (S-1)/(M+S-1) of every step idle
             print(
                 f"[pipeline] grad_accum={accum} < pipe={self._pipe_size}: "
-                f"bubble fraction {(self._pipe_size - 1) / (accum + self._pipe_size - 1):.0%}"
+                f"bubble fraction {bubble_fraction(accum, self._pipe_size):.0%}"
                 " — raise gradient_accumulation_steps for efficiency"
             )
         if problems:

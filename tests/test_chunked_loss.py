@@ -33,9 +33,10 @@ def test_chunked_ce_matches_full(chunk):
         "attention_mask": np.ones((2, 96), np.int32),
     }
 
-    loss_full, tok_full = make_loss_fn(mc, tc_full)(trainable, frozen, batch)
-    loss_chunk, tok_chunk = make_loss_fn(mc, tc_chunk)(trainable, frozen, batch)
-    assert float(tok_full) == float(tok_chunk)
+    loss_full, stats_full = make_loss_fn(mc, tc_full)(trainable, frozen, batch)
+    loss_chunk, stats_chunk = make_loss_fn(mc, tc_chunk)(trainable, frozen, batch)
+    assert set(stats_full) == set(stats_chunk) == {"tokens"}  # a dense model, no completion_mask
+    assert float(stats_full["tokens"]) == float(stats_chunk["tokens"])
     assert abs(float(loss_full) - float(loss_chunk)) < 1e-5
 
     g_full = jax.grad(lambda t: make_loss_fn(mc, tc_full)(t, frozen, batch)[0])(trainable)
@@ -67,9 +68,9 @@ def test_vocab_streamed_ce_matches_full(vchunk):
         "attention_mask": np.ones((2, 96), np.int32),
     }
 
-    loss_full, tok_full = make_loss_fn(mc, tc_full)(trainable, frozen, batch)
-    loss_v, tok_v = make_loss_fn(mc, tc_v)(trainable, frozen, batch)
-    assert float(tok_full) == float(tok_v)
+    loss_full, stats_full = make_loss_fn(mc, tc_full)(trainable, frozen, batch)
+    loss_v, stats_v = make_loss_fn(mc, tc_v)(trainable, frozen, batch)
+    assert float(stats_full["tokens"]) == float(stats_v["tokens"])
     assert abs(float(loss_full) - float(loss_v)) < 1e-5
 
     g_full = jax.grad(lambda t: make_loss_fn(mc, tc_full)(t, frozen, batch)[0])(trainable)
@@ -145,14 +146,16 @@ def test_dual_mask_eval_metrics_agree_across_ce_paths(kind):
         "attention_mask": np.ones((2, 96), np.int32),
         "completion_mask": cm,
     }
-    loss, tokens, ans_ce, ans_tok = make_loss_fn(mc, tc)(trainable, frozen, batch)
+    loss, stats = make_loss_fn(mc, tc)(trainable, frozen, batch)
+    assert set(stats) == {"tokens", "answer_ce_sum", "answer_tokens"}
+    ans_ce, ans_tok = stats["answer_ce_sum"], stats["answer_tokens"]
     # reference: full-logits path with the completion mask AS the loss mask
     ref_batch = dict(batch, loss_mask=cm)
     ref_batch.pop("completion_mask")
-    ref_loss, ref_tok = make_loss_fn(mc, TrainConfig(
+    ref_loss, ref_stats = make_loss_fn(mc, TrainConfig(
         model_preset="tiny", max_seq_length=96, compute_dtype="float32"
     ))(trainable, frozen, ref_batch)
-    assert float(ans_tok) == float(ref_tok)
+    assert float(ans_tok) == float(ref_stats["tokens"])
     np.testing.assert_allclose(
         float(ans_ce) / float(ans_tok), float(ref_loss), rtol=2e-5
     )

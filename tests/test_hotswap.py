@@ -40,7 +40,7 @@ from llm_fine_tune_distributed_tpu.infer.engine import (
 from llm_fine_tune_distributed_tpu.infer.fleet import EngineFleet
 from llm_fine_tune_distributed_tpu.infer.generate import Generator
 from llm_fine_tune_distributed_tpu.models.configs import get_preset
-from llm_fine_tune_distributed_tpu.models.transformer import init_params
+from llm_fine_tune_distributed_tpu.models.transformer import forward, init_params
 from llm_fine_tune_distributed_tpu.train.checkpoints import frozen_fingerprint
 from llm_fine_tune_distributed_tpu.train.publish import (
     CheckpointPublisher,
@@ -53,7 +53,7 @@ from llm_fine_tune_distributed_tpu.train.publish import (
     step_dir_name,
     weights_digest,
 )
-from llm_fine_tune_distributed_tpu.utils.tree import flatten_dict
+from llm_fine_tune_distributed_tpu.utils.tree import flatten_dict, unflatten_dict
 
 GREEDY = GenerationConfig(max_new_tokens=6, do_sample=False)
 LONG = GenerationConfig(max_new_tokens=32, do_sample=False)
@@ -293,7 +293,13 @@ def test_rollback_restores_prior_outputs(generator, tmp_path):
     prompt = _prompt()
     base = fleet.submit(prompt, GREEDY)
 
-    trainable, frozen_fp = _split(generator)
+    # the fine-tuned set here is the tied table (lookup and output head): a
+    # uniform shift of a projection is normed away and leaves the tiny
+    # model's greedy output where it was
+    flat = flatten_dict(generator.params)
+    head = "model/embed_tokens/weight"
+    trainable = {head: np.asarray(flat[head])}
+    frozen_fp = frozen_fingerprint({k: v for k, v in flat.items() if k != head})
     pub = CheckpointPublisher(str(tmp_path))
     pub.publish(1, trainable, frozen_fp=frozen_fp)
     watcher = CheckpointWatcher(str(tmp_path), base_params=generator.params)
@@ -301,8 +307,24 @@ def test_rollback_restores_prior_outputs(generator, tmp_path):
     assert mgr.poll_once()["step"] == 1
     assert fleet.submit(prompt, GREEDY) == base  # same values
 
-    pub.publish(2, {k: v + 0.25 for k, v in trainable.items()},
-                frozen_fp=frozen_fp)
+    # step 2: a seeded random change of the table's rows, shown ON THE LOGITS
+    # to move the first greedy token (by a margin no summation order closes)
+    # before the fleet is asked. With a tied table the tiny model echoes its
+    # last token (a row's largest product is with itself), and noise alone
+    # keeps it echoing: one randomly chosen other row is made that row's
+    # multiple, so that it wins
+    rng = np.random.RandomState(2)
+    table = trainable[head] + rng.normal(0.0, 0.02, trainable[head].shape).astype(np.float32)
+    winner = int(rng.choice([t for t in range(256) if t != base[0]]))
+    table[winner] = 4.0 * table[base[0]]
+    moved = {head: table}
+    logits, _ = forward(
+        unflatten_dict({**flat, **moved}), jnp.asarray([prompt], jnp.int32),
+        generator.config, compute_dtype=jnp.float32,
+    )
+    last = np.asarray(logits[0, -1])
+    assert int(last.argmax()) != base[0] and last.max() - last[base[0]] > 0.1
+    pub.publish(2, moved, frozen_fp=frozen_fp)
     res = mgr.poll_once()
     assert res["step"] == 2 and res["cache_invalidated"]
     changed = fleet.submit(prompt, GREEDY)

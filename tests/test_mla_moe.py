@@ -29,7 +29,7 @@ import pytest
 from llm_fine_tune_distributed_tpu.config import TrainConfig
 from llm_fine_tune_distributed_tpu.models import hf_io
 from llm_fine_tune_distributed_tpu.models.configs import from_hf_config, get_preset, to_hf_dict
-from llm_fine_tune_distributed_tpu.models.transformer import forward
+from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report
 from llm_fine_tune_distributed_tpu.ops import moe
 from llm_fine_tune_distributed_tpu.ops.attention import xla_attention
 from llm_fine_tune_distributed_tpu.ops.flash_attention import pallas_flash_attention
@@ -88,8 +88,9 @@ def test_leaves_and_parameter_count_agree_with_the_benchmarks_weights(flat):
 
 
 def test_forward_logits_agree_with_the_reference(flat, ids):
-    got, _, load = forward(_params(flat), jnp.asarray(ids[0]), MC, compute_dtype=jnp.float32,
-                           return_expert_load=True)
+    got, _, report = forward_with_report(_params(flat), jnp.asarray(ids[0]), MC, compute_dtype=jnp.float32)
+    assert set(report) == {"expert_load"}
+    load = report["expert_load"]
     want = ref.logits(flat, bench_cfg(), ids[0])
     assert _rel(got, want) < RTOL
     # the program's counter against the reference's selection, layer by layer
@@ -164,10 +165,17 @@ def test_one_optimizer_steps_parameter_change(one_step):
         assert np.linalg.norm(got - update) <= 1e-3 * np.linalg.norm(update) + 1e-9, k
 
 
-def test_the_step_reports_its_expert_counters(one_step):
+def test_the_step_reports_its_expert_counters(one_step, ids):
     m = one_step["metrics"]
     held = len(MC.held_expert_ids)
     assert m["expert_load"].shape == (held,)
+    # the step's counters are the report's expert_load, microbatch by microbatch
+    params = weights.nest({**one_step["state"].trainable, **one_step["frozen"]})
+    loads = [forward_with_report(params, jnp.asarray(micro), MC, compute_dtype=jnp.float32)[2]["expert_load"]
+             for micro in ids]
+    assert loads[0].shape == (MC.num_layers - 1, held)  # [expert layers, held]
+    pairs = sum(int(load.sum()) for load in loads)
+    np.testing.assert_allclose(float(m["expert_pairs_per_token"]), pairs / (ids.size * (MC.num_layers - 1)), rtol=1e-6)
     np.testing.assert_allclose(float(m["expert_load"].sum()), float(m["expert_pairs_per_token"]), rtol=1e-6)
     # 3 of 16 chosen, 4 held: 0.75 pairs a token expected; the seed's draw is near it
     assert 0.4 < float(m["expert_pairs_per_token"]) < 1.1
@@ -341,6 +349,11 @@ def test_serving_refuses_latent_attention_by_name(flat):
 
     with pytest.raises(LatentAttentionNotServed, match="training path only"):
         Generator(_params(flat), MC, tokenizer=None)
+    # ...and the model's own cache function refuses a cache handed to it directly
+    from llm_fine_tune_distributed_tpu.models.transformer import init_cache
+
+    with pytest.raises(NotImplementedError, match="training form only"):
+        forward_with_report(_params(flat), jnp.zeros((1, 4), jnp.int32), MC, cache=init_cache(MC, 1, 8))
 
 
 def test_the_new_scopes_reach_the_lowered_step(one_step, ids):

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from llm_fine_tune_distributed_tpu.models import get_preset, init_params
+from llm_fine_tune_distributed_tpu.config import LayerPlan
+from llm_fine_tune_distributed_tpu.models import PRESETS, get_preset, init_params
 from llm_fine_tune_distributed_tpu.models.transformer import forward, init_cache
 from llm_fine_tune_distributed_tpu.utils.tree import count_params
 
@@ -102,11 +103,99 @@ def test_untied_and_sliding_window_preset():
     assert logits.shape == (1, 8, cfg.vocab_size)
 
 
+# What ModelConfig.layer says of every preset's layers, where it is not the
+# Llama default (q/k/v heads, rope, no window, dense MLP on every layer).
+_EVERY_4TH = lambda n: tuple(range(3, n, 4))  # noqa: E731
+_EVEN_ONLY = lambda w: (lambda i: w if i % 2 == 0 else None)  # noqa: E731
+_LEADING_DENSE = lambda i: "dense" if i == 0 else "grouped_experts"  # noqa: E731
+PLAN_OF_PRESET = {
+    "tiny": dict(nope=_EVERY_4TH(4)),
+    "smollm3_3b": dict(nope=_EVERY_4TH(36)),  # 3, 7, ..., 35
+    "tiny_mistral": dict(window=lambda i: 64),  # Mistral: on all layers
+    "tiny_gemma2": dict(window=_EVEN_ONLY(8)),  # Gemma2: local on even layers, global on odd
+    "gemma2_9b": dict(window=_EVEN_ONLY(4096)),
+    "tiny_moe": dict(feed_forward=lambda i: "capacity_experts"),
+    "mixtral_8x7b": dict(feed_forward=lambda i: "capacity_experts"),
+    "moonlight_16b_a3b": dict(attention="latent", feed_forward=_LEADING_DENSE),
+    "tiny_mla_moe": dict(attention="latent", feed_forward=_LEADING_DENSE),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PRESETS))
+def test_layer_plan_of_every_preset(name):
+    cfg = get_preset(name)
+    want = {"attention": "heads", "nope": (), "window": lambda i: None, "feed_forward": lambda i: "dense",
+            **PLAN_OF_PRESET.get(name, {})}
+    for i in range(cfg.num_layers):
+        assert cfg.layer(i) == LayerPlan(
+            attention=want["attention"], rope=i not in want["nope"],
+            window=want["window"](i), feed_forward=want["feed_forward"](i),
+        ), (name, i)
+    assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= 2  # hashable; no preset has more than two kinds
+
+
+@pytest.mark.parametrize("name, counted, step_counters", [
+    ("tiny", set(), set()),
+    ("tiny_moe", {"router_aux"}, set()),
+    ("tiny_mla_moe", {"expert_load"}, {"expert_pairs_per_token", "expert_load", "expert_load_max_over_mean"}),
+])
+def test_report_of_what_the_layers_counted(name, counted, step_counters):
+    """One pytree, its structure fixed by the plan (``report_shapes``);
+    ``forward`` is the first two results; the loss function and the step
+    read it by key."""
+    import optax
+
+    from llm_fine_tune_distributed_tpu.config import TrainConfig
+    from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, report_shapes
+    from llm_fine_tune_distributed_tpu.train.state import TrainState
+    from llm_fine_tune_distributed_tpu.train.step import build_train_step, make_loss_fn
+    from llm_fine_tune_distributed_tpu.utils.tree import flatten_dict
+
+    cfg = get_preset(name)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 2, 16)), jnp.int32)
+    tc = TrainConfig(model_preset=name, compute_dtype="float32", freeze_strategy="none",
+                     per_device_batch_size=2, gradient_accumulation_steps=2, max_seq_length=16)
+    batch = {"input_ids": ids, "loss_mask": jnp.ones(ids.shape, jnp.float32),
+             "attention_mask": jnp.ones(ids.shape, jnp.int32)}
+    micro = jax.tree.map(lambda a: a[0], batch)
+    flat = flatten_dict(params)
+    run = lambda p, i: forward_with_report(p, i, cfg, compute_dtype=jnp.float32)  # noqa: E731
+    shape_of = lambda tree: jax.tree.map(lambda a: (a.shape, jnp.dtype(a.dtype)), tree)  # noqa: E731
+
+    # structure, by shapes alone (no compile): the report, the loss function's stats, the step's metrics
+    _, _, report = jax.eval_shape(run, params, ids[0])
+    assert set(report) == counted
+    assert shape_of(report) == shape_of(report_shapes(cfg))
+    _, stats = jax.eval_shape(make_loss_fn(cfg, tc), flat, {}, micro)
+    assert set(stats) == {"tokens"} | (counted & {"expert_load"})
+    optimizer = optax.sgd(1e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), trainable=flat, frozen={}, opt_state=optimizer.init(flat))
+    _, metrics = jax.eval_shape(build_train_step(cfg, tc, optimizer), state, batch)
+    assert set(metrics) == {"loss", "grad_norm"} | step_counters
+    if "expert_load" in report:
+        held = len(cfg.held_expert_ids)
+        assert report["expert_load"].shape == (cfg.num_layers - 1, held)  # one leading dense layer
+        assert metrics["expert_load"].shape == (held,)
+        return  # its numbers against the reference: tests/test_mla_moe.py
+
+    # numbers: forward is the first two results, and the balancing loss joins the train objective only
+    both = jax.jit(lambda p, i: (run(p, i), forward(p, i, cfg, compute_dtype=jnp.float32)))
+    (out, cache, report), (plain, plain_cache) = both(params, ids[0])
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(out))
+    assert plain_cache is None and cache is None
+    if "router_aux" in report:
+        loss, _ = make_loss_fn(cfg, tc)(flat, {}, micro)
+        ce, _ = make_loss_fn(cfg, tc, include_router_aux=False)(flat, {}, micro)
+        want = cfg.router_aux_coef * float(report["router_aux"]) / cfg.num_layers  # the layer-mean
+        np.testing.assert_allclose(float(loss) - float(ce), want, rtol=1e-4)
+
+
 def test_smollm3_nope_pattern():
     cfg = get_preset("smollm3_3b")
     # every 4th layer (1-indexed) has NO rope — HF SmolLM3Config convention.
-    assert not cfg.uses_rope(3) and not cfg.uses_rope(7) and not cfg.uses_rope(35)
-    assert cfg.uses_rope(0) and cfg.uses_rope(34)
+    assert not cfg.layer(3).rope and not cfg.layer(7).rope and not cfg.layer(35).rope
+    assert cfg.layer(0).rope and cfg.layer(34).rope
     assert sum(cfg.no_rope_layers) == 27
 
 
@@ -208,8 +297,8 @@ def test_gemma2_preset_param_count_and_decode():
     cfg9 = get_preset("gemma2_9b")
     assert 9.0e9 < cfg9.num_params < 9.5e9
     # local/global alternation
-    assert cfg9.layer_sliding_window(0) == 4096
-    assert cfg9.layer_sliding_window(1) is None
+    assert cfg9.layer(0).window == 4096
+    assert cfg9.layer(1).window is None
 
     tiny = cfg9.replace(
         vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=4,

@@ -122,17 +122,16 @@ def test_expert_parallel_matches_unsharded(eight_devices):
 
 
 def test_forward_tiny_moe_and_aux():
-    from llm_fine_tune_distributed_tpu.models.transformer import forward, init_params
+    from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, init_params
 
     config = get_preset("tiny_moe")
     params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 512, (2, 16)), jnp.int32)
-    logits, _, aux = forward(
-        params, ids, config, compute_dtype=jnp.float32, return_aux=True
-    )
+    logits, _, report = forward_with_report(params, ids, config, compute_dtype=jnp.float32)
     assert logits.shape == (2, 16, 512)
     assert np.isfinite(np.asarray(logits)).all()
-    assert float(aux) > 0  # 2 MoE layers contribute
+    assert set(report) == {"router_aux"}
+    assert float(report["router_aux"]) > 0  # 2 MoE layers contribute
 
 
 @pytest.mark.slow
@@ -268,7 +267,7 @@ def test_pipeline_moe_matches_plain(eight_devices):
     )
 
     config = get_preset("tiny_moe")
-    from llm_fine_tune_distributed_tpu.models.transformer import forward, init_params
+    from llm_fine_tune_distributed_tpu.models.transformer import forward, forward_with_report, init_params
 
     params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
     ids = jnp.asarray(
@@ -278,10 +277,11 @@ def test_pipeline_moe_matches_plain(eight_devices):
     stacked = jax.device_put(
         stack_stage_params(params, config, 2), stage_sharding(mesh)
     )
-    logits_pipe, aux_pipe = pipeline_forward(
+    logits_pipe, report_pipe = pipeline_forward(
         params, stacked, ids, config, mesh, 2,
-        compute_dtype=jnp.float32, remat_blocks=False, return_aux=True,
+        compute_dtype=jnp.float32, remat_blocks=False,
     )
+    assert set(report_pipe) == {"router_aux"}
     logits_plain, _ = forward(
         params, ids, config, compute_dtype=jnp.float32, logits_dtype=jnp.float32
     )
@@ -293,12 +293,11 @@ def test_pipeline_moe_matches_plain(eight_devices):
     # gives the plain path) must equal forward() run per microbatch
     per_mb = []
     for m in range(2):
-        _, _, a = forward(
-            params, ids[m * 2 : (m + 1) * 2], config,
-            compute_dtype=jnp.float32, return_aux=True,
+        _, _, report = forward_with_report(
+            params, ids[m * 2 : (m + 1) * 2], config, compute_dtype=jnp.float32,
         )
-        per_mb.append(float(a))
-    np.testing.assert_allclose(float(aux_pipe), np.mean(per_mb), rtol=1e-5)
+        per_mb.append(float(report["router_aux"]))
+    np.testing.assert_allclose(float(report_pipe["router_aux"]), np.mean(per_mb), rtol=1e-5)
 
 
 @pytest.mark.slow
@@ -667,15 +666,15 @@ def test_moe_with_ring_attention_matches_unsharded(eight_devices):
     a live seq axis with ring attention must not change MoE semantics —
     logits AND router aux (capacity/dispatch identical: the MoE runs in
     global view under GSPMD, only attention shard_maps over seq)."""
-    from llm_fine_tune_distributed_tpu.models.transformer import forward, init_params
+    from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, init_params
 
     config = get_preset("tiny_moe")
     params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 512, (2, 64)), jnp.int32)
-    ref, _, aux_ref = forward(
+    ref, _, report_ref = forward_with_report(
         params, ids, config, attention_impl="xla", compute_dtype=jnp.float32,
-        return_aux=True,
     )
+    aux_ref = report_ref["router_aux"]
 
     mesh = Mesh(
         np.array(eight_devices).reshape(2, 1, 1, 4, 1),
@@ -683,27 +682,27 @@ def test_moe_with_ring_attention_matches_unsharded(eight_devices):
     )
     act = NamedSharding(mesh, P(("data", "fsdp"), "seq", None))
     with assert_seq_parallel("ring"):
-        out, _, aux = jax.jit(
-            lambda p, i: forward(
+        out, _, report = jax.jit(
+            lambda p, i: forward_with_report(
                 p, i, config, attention_impl="ring", compute_dtype=jnp.float32,
-                activation_sharding=act, return_aux=True,
+                activation_sharding=act,
             )
         )(params, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-4)
-    np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
+    np.testing.assert_allclose(float(report["router_aux"]), float(aux_ref), rtol=1e-5)
 
 
 def test_moe_with_ulysses_attention_matches_unsharded(eight_devices):
     """Companion to the ring case: Ulysses all-to-all over seq with MoE."""
-    from llm_fine_tune_distributed_tpu.models.transformer import forward, init_params
+    from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, init_params
 
     config = get_preset("tiny_moe")
     params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
     ids = jnp.asarray(np.random.RandomState(1).randint(0, 512, (2, 64)), jnp.int32)
-    ref, _, aux_ref = forward(
+    ref, _, report_ref = forward_with_report(
         params, ids, config, attention_impl="xla", compute_dtype=jnp.float32,
-        return_aux=True,
     )
+    aux_ref = report_ref["router_aux"]
 
     # mesh must SATISFY seq_parallel_preconditions (batch 2 % (data*fsdp) == 0,
     # kv heads 2 % seq 2 == 0) — the r4 version used data=2 x fsdp=2 with
@@ -715,28 +714,28 @@ def test_moe_with_ulysses_attention_matches_unsharded(eight_devices):
     )
     act = NamedSharding(mesh, P(("data", "fsdp"), "seq", None))
     with assert_seq_parallel("ulysses"):
-        out, _, aux = jax.jit(
-            lambda p, i: forward(
+        out, _, report = jax.jit(
+            lambda p, i: forward_with_report(
                 p, i, config, attention_impl="ulysses", compute_dtype=jnp.float32,
-                activation_sharding=act, return_aux=True,
+                activation_sharding=act,
             )
         )(params, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-4)
-    np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
+    np.testing.assert_allclose(float(report["router_aux"]), float(aux_ref), rtol=1e-5)
 
 
 def test_moe_seq_axis_with_expert_axis_matches_unsharded(eight_devices):
     """seq x expert together: ring attention over seq while expert weights
     shard over the expert axis — the full long-context MoE mesh family."""
-    from llm_fine_tune_distributed_tpu.models.transformer import forward, init_params
+    from llm_fine_tune_distributed_tpu.models.transformer import forward_with_report, init_params
 
     config = get_preset("tiny_moe")
     params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
     ids = jnp.asarray(np.random.RandomState(2).randint(0, 512, (2, 64)), jnp.int32)
-    ref, _, aux_ref = forward(
+    ref, _, report_ref = forward_with_report(
         params, ids, config, attention_impl="xla", compute_dtype=jnp.float32,
-        return_aux=True,
     )
+    aux_ref = report_ref["router_aux"]
 
     mesh = Mesh(
         np.array(eight_devices).reshape(2, 1, 1, 2, 2),
@@ -747,11 +746,11 @@ def test_moe_seq_axis_with_expert_axis_matches_unsharded(eight_devices):
     params_sharded = shard_params(params, mesh)
     act = NamedSharding(mesh, P(("data", "fsdp"), "seq", None))
     with assert_seq_parallel("ring"):
-        out, _, aux = jax.jit(
-            lambda p, i: forward(
+        out, _, report = jax.jit(
+            lambda p, i: forward_with_report(
                 p, i, config, attention_impl="ring", compute_dtype=jnp.float32,
-                activation_sharding=act, return_aux=True,
+                activation_sharding=act,
             )
         )(params_sharded, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-4)
-    np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
+    np.testing.assert_allclose(float(report["router_aux"]), float(aux_ref), rtol=1e-5)

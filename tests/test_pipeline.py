@@ -42,10 +42,11 @@ def test_pipeline_forward_matches_plain(setup, n_stages, n_micro):
     stacked = jax.device_put(
         stack_stage_params(params, config, n_stages), stage_sharding(mesh)
     )
-    logits_pipe = pipeline_forward(
+    logits_pipe, report = pipeline_forward(
         params, stacked, ids, config, mesh, n_micro,
         compute_dtype=jnp.float32, remat_blocks=False,
     )
+    assert report == {}  # a dense model: its layers count nothing
     logits_plain, _ = forward(
         params, ids, config, compute_dtype=jnp.float32, logits_dtype=jnp.float32
     )
@@ -117,7 +118,7 @@ def test_pipeline_nope_interleaved_matches_plain(setup):
     stacked = jax.device_put(
         stack_stage_params(params, nope, 2), stage_sharding(mesh)
     )
-    logits_pipe = pipeline_forward(
+    logits_pipe, _ = pipeline_forward(
         params, stacked, ids, nope, mesh, 2,
         compute_dtype=jnp.float32, remat_blocks=False,
     )
@@ -151,7 +152,7 @@ def test_pipeline_padded_batch_matches_plain(setup):
     )
     lengths = np.array([64, 50, 33, 64, 12, 64, 40, 64])
     pm = jnp.asarray((np.arange(SEQ)[None, :] < lengths[:, None]).astype(np.float32))
-    logits_pipe = pipeline_forward(
+    logits_pipe, _ = pipeline_forward(
         params, stacked, ids, config, mesh, 2,
         padding_mask=pm, compute_dtype=jnp.float32, remat_blocks=False,
     )

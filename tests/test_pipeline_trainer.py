@@ -189,6 +189,44 @@ def test_pipe_rejects_unsupported_combos(qa_parquet, tmp_path):  # noqa: F811
             SFTTrainer(cfg)
 
 
+@pytest.mark.parametrize("preset, impl, differ", [
+    ("tiny_mla_moe", "xla", "feed_forward ('dense' vs 'grouped_experts')"),  # a leading dense layer
+    ("tiny_gemma2", "xla", "window (8 vs None)"),                            # alternating windows
+    ("tiny_moe", "ring", "capacity experts"),                               # MoE under a sequence axis
+])
+def test_layer_scan_rule_is_one_and_raised_alike(preset, impl, differ, qa_parquet, tmp_path):  # noqa: F811
+    """What the schedule's layer scan asks of a model is said once
+    (``layer_scan_problems``, from ``ModelConfig.layer``) and raised by
+    ``pipeline_forward`` and by the trainer in the same words."""
+    from jax.sharding import Mesh
+
+    from llm_fine_tune_distributed_tpu.models.configs import get_preset
+    from llm_fine_tune_distributed_tpu.models.transformer import init_params
+    from llm_fine_tune_distributed_tpu.parallel.pipeline import layer_scan_problems, pipeline_forward
+    from llm_fine_tune_distributed_tpu.train.trainer import SFTTrainer
+
+    mc = get_preset(preset)
+    seq_parallel = impl != "xla"
+    (problem,) = layer_scan_problems(mc, seq_parallel)
+    assert differ in problem
+    assert layer_scan_problems(get_preset("tiny"), True) == []  # NoPE layers differ in rope alone: data
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("pipe", "seq"))
+    params = init_params(jax.random.PRNGKey(0), mc, dtype=jnp.float32)
+    with pytest.raises(ValueError) as from_schedule:
+        pipeline_forward(params, None, jnp.zeros((2, 16), jnp.int32), mc, mesh, 2, attention_impl=impl)
+    assert problem in str(from_schedule.value)
+
+    data_dir, dataset_file = qa_parquet
+    cfg = make_config(
+        tmp_path / "bad", data_dir, dataset_file, model_preset=preset, freeze_strategy="none",
+        attention_impl=impl, mesh=MeshConfig(data=1, fsdp=1, tensor=1, seq=1, pipe=2),
+    )
+    with pytest.raises(ValueError, match="pipe mesh axis") as from_trainer:
+        SFTTrainer(cfg)
+    assert problem in str(from_trainer.value)
+
+
 def test_pipeline_state_split_lora():
     """Under LoRA, only adapters are trainable in pipe mode: stacked base
     kernels land in `frozen` (no optimizer state, like the flat path) and the

@@ -272,6 +272,13 @@ def test_circuit_opens_after_repeated_failures(generator):
 # ------------------------------------------------------------- admission
 
 
+def _wait_until(probe, timeout_s: float = 60.0):
+    deadline = time.monotonic() + timeout_s
+    while not probe() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert probe(), "the engine never got there"
+
+
 def test_queue_overflow_sheds_429_with_finite_retry_after(generator):
     prompts = _prompts()
     engine = ContinuousBatchingEngine(
@@ -282,12 +289,15 @@ def test_queue_overflow_sheds_429_with_finite_retry_after(generator):
         target=lambda: engine.submit(prompts[0], long_cfg, timeout=240)
     )
     occupier.start()
-    time.sleep(0.1)  # occupant takes the only slot
+    # polls on the engine's own probes, not fixed sleeps: while the occupier
+    # is still QUEUED (its prefill compiles first) the depth-1 queue is full
+    # and the waiter itself would be the one shed
+    _wait_until(lambda: engine.live_slots == 1)  # occupant holds the only slot
     waiter = threading.Thread(
         target=lambda: engine.submit(prompts[1], long_cfg, timeout=240)
     )
     waiter.start()
-    time.sleep(0.1)  # waiter fills the depth-1 queue
+    _wait_until(lambda: engine.queue_depth == 1)  # waiter fills the depth-1 queue
     with pytest.raises(QueueOverflowError) as exc:
         engine.submit(prompts[2], GREEDY, timeout=30)
     assert exc.value.status == 429
@@ -316,12 +326,7 @@ def test_queue_deadline_sheds_before_prefill(generator):
     # full-suite load a slow pickup would shed the occupier on its own
     # deadline and hand the waiter the free slot); its fresh compile + 64
     # greedy tokens then hold the slot far past the waiter's 0.3s deadline
-    deadline = time.monotonic() + 30
-    while (
-        engine.stats_snapshot()["requests_admitted"] < 1
-        and time.monotonic() < deadline
-    ):
-        time.sleep(0.005)
+    _wait_until(lambda: engine.stats_snapshot()["requests_admitted"] >= 1)
     with pytest.raises(QueueDeadlineError):
         engine.submit(prompts[1], GREEDY, timeout=240)
     occupier.join(timeout=240)
