@@ -345,7 +345,10 @@ class TrainConfig:
     # [b, s, heads * d_v] tensor and one float32 a row and head), so that the
     # kernel is not run a second time in the backward pass: decided from the
     # shapes, where seq * (d_qk + d_v) / (2 * d_v) > hidden_size
-    # (models/transformer._remat_policy; no setting switches it).
+    # (models/transformer._remat_policy; no setting switches it); and a block
+    # whose feed-forward is grouped experts keeps its routing (scores,
+    # selection, sorted tables) and the first chunk's gathered rows, about as
+    # large as its input, so that router, sorts and gather run once a layer.
     # None = auto (resolved_remat_policy): picked by model size and PER-CHIP
     # sequence length, from earlier rounds' single-chip sweep (its record
     # was deleted in PR 21; not re-measured on the attached v5e).

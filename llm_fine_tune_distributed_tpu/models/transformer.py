@@ -573,6 +573,12 @@ def keeps_flash_outputs(config: ModelConfig, seq: int) -> bool:
     return worth_keeping_across_remat(seq, d_qk, d_v, config.hidden_size)
 
 
+def keeps_routing(config: ModelConfig) -> bool:
+    """Whether a rematerialized block of this model can hold an expert layer
+    that keeps its routing and gathered rows (``ops/moe.grouped_moe_mlp``)."""
+    return any(config.layer(i).feed_forward == "grouped_experts" for i in range(config.num_layers))
+
+
 def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
     """What ``jax.checkpoint`` keeps of a block besides its input. ``full``
     (and None): nothing, the whole block is recomputed, least memory. The
@@ -586,8 +592,19 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
     forward kernel's ``o`` and ``lse``, so that the kernel runs once a layer
     and not a second time in the backward pass. Where the kernel is not in
     the block (XLA attention) the two names are in no program and the policy
-    is the plain one."""
-    from llm_fine_tune_distributed_tpu.ops.flash_attention import KEPT_ACROSS_REMAT
+    is the plain one.
+
+    And a model with a ``grouped_experts`` layer (``keeps_routing``: the
+    layer's kind, no switch) keeps what that layer names
+    (``ops/moe.KEPT_ACROSS_REMAT``), so that the router's product, the top k,
+    the two sorts and the row gather run once a layer. No rule of shapes: the
+    rows are as large as the block's input, which every policy keeps anyway,
+    and at 16,384 tokens of 2048 the about 85 MiB a layer took 16 ms of a
+    1,006 ms step to make again (0.04 ms a MiB; ISSUE 29 had counted on 0.1),
+    as much as the best of what else such a block could keep and above the
+    0.011 of a byte of ``o`` at 1024 tokens (PERF.md, PRs 27 and 29). A model
+    without such a layer gets the policy object it got before."""
+    from llm_fine_tune_distributed_tpu.ops import flash_attention, moe
 
     saveable = jax.checkpoint_policies
     policies = {
@@ -602,9 +619,12 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
             f"unknown remat_policy {remat_policy!r}; expected one of {sorted(policies)}"
         )
     policy = policies[remat_policy]
-    if not keeps_flash_outputs(config, seq):
+    names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq) else ()
+    if keeps_routing(config):
+        names += moe.KEPT_ACROSS_REMAT
+    if not names:
         return policy
-    kept = saveable.save_only_these_names(*KEPT_ACROSS_REMAT)
+    kept = saveable.save_only_these_names(*names)
     return kept if policy is None else saveable.save_from_both_policies(policy, kept)
 
 

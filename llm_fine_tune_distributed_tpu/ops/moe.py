@@ -220,6 +220,33 @@ def init_moe_params(rng, config: ModelConfig, dtype):
 # control sets float8_e4m3fn here and has to come out not correct.)
 ROUTER_DTYPE = jnp.float32
 
+# What ``grouped_moe_mlp`` names (``checkpoint_name``) for a rematerialized
+# block to keep (models/transformer._remat_policy, as the flash kernel's
+# outputs): the backward pass follows from them by slices and subtractions,
+# with no second router product, selection, sort or row gather. The router's
+# sigmoid scores ``[T, E]`` and selection ``[T, k]``; each sorted pair's token,
+# each pair's sorted row and the sorted weights, flat ``[T * k]`` (a ``[T, 6]``
+# pads to 128 lanes); the held experts' counts and their running sum; the
+# FIRST chunk's gathered rows ``[T, h]``, three quarters of the bytes.
+KEPT_ACROSS_REMAT = (
+    "moe_scores", "moe_top_i", "moe_tokens", "moe_rank", "moe_load", "moe_ends", "moe_weights", "moe_rows",
+)
+
+
+@jax.custom_vjp
+def _sigmoid(z):
+    """``jax.nn.sigmoid`` whose backward pass reads the NAMED scores: autodiff's
+    own rule reads the unnamed output, and the router's product runs again for it."""
+    return jax.nn.sigmoid(z)
+
+
+def _sigmoid_fwd(z):
+    scores = checkpoint_name(jax.nn.sigmoid(z), "moe_scores")
+    return scores, scores
+
+
+_sigmoid.defvjp(_sigmoid_fwd, lambda scores, d: (d * scores * (1 - scores),))
+
 
 def route(gate, x, config: ModelConfig):
     """``x [T, h]`` -> (expert ids ``[T, k]`` int32, combine weights ``[T, k]``
@@ -227,14 +254,14 @@ def route(gate, x, config: ModelConfig):
     scores + ``e_score_correction_bias`` (a buffer: it selects, it does not
     weigh, and no gradient reaches it); weights are the selected scores over
     their sum, times ``routed_scaling_factor``."""
-    scores = jax.nn.sigmoid(
+    scores = _sigmoid(
         jnp.dot(
             x.astype(ROUTER_DTYPE), gate["kernel"].astype(ROUTER_DTYPE),
             precision=jax.lax.Precision.HIGHEST, preferred_element_type=ROUTER_DTYPE,
         )
     )
     bias = jax.lax.stop_gradient(gate["e_score_correction_bias"]).astype(ROUTER_DTYPE)
-    _, top_i = jax.lax.top_k(scores + bias, config.num_experts_per_tok)
+    top_i = checkpoint_name(jax.lax.top_k(scores + bias, config.num_experts_per_tok)[1], "moe_top_i")
     # the chosen scores by a one-hot product: exact; take_along_axis's gather
     # of k of E values a token cost 1 ms a call on a v5e (PR 26)
     top_s = (jax.nn.one_hot(top_i, scores.shape[-1], dtype=scores.dtype) * scores[:, None, :]).sum(-1)
@@ -335,7 +362,8 @@ def permute(x, order, rank):
     return x[order]
 
 
-permute.defvjp(lambda x, order, rank: (x[order], (order, rank)), lambda res, d: (d[res[1]], None, None))
+# (the residual is ``rank`` alone: a block that keeps it need not sort for ``order`` again)
+permute.defvjp(lambda x, order, rank: (x[order], rank), lambda rank, d: (d[rank], None, None))
 
 
 def _expert_rows(experts, xin, weights, sizes, n_valid, compute_dtype, impl):
@@ -385,32 +413,37 @@ def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
         local_of[list(held)] = np.arange(n_held)
         local = jnp.asarray(local_of)[top_i].reshape(-1)          # [t * k]
         order = jnp.argsort(local, stable=True)                   # held pairs first, by expert
-        rank = jnp.argsort(order).astype(jnp.int32)               # each pair's row in that order
+        rank = checkpoint_name(jnp.argsort(order).astype(jnp.int32), "moe_rank")  # each pair's row in that order
         # counted by compare and sum, the table above made on the host: as
         # bincount and .at[].set they are scatters of a few integers, and the
         # TPU compiler of this jaxlib aborts on them inside the step program
         # (scatter_emitter.cc "operand_indices.size() == 1", PR 26)
-        load = (local[:, None] == jnp.arange(n_held)[None, :]).sum(0, dtype=jnp.int32)
-        ends = jnp.cumsum(load)
-        tokens = (order // k).astype(jnp.int32)
-        weights = permute(top_w.reshape(-1), order, rank)
+        load = checkpoint_name((local[:, None] == jnp.arange(n_held)[None, :]).sum(0, dtype=jnp.int32), "moe_load")
+        ends = checkpoint_name(jnp.cumsum(load), "moe_ends")
+        tokens = checkpoint_name((order // k).astype(jnp.int32), "moe_tokens")
+        weights = checkpoint_name(permute(top_w.reshape(-1), order, rank), "moe_weights")
 
-    def chunk(start):
+    def chunk(start, first=False):
         """Rows [start, start + t) of the sorted pairs through the experts
-        and back into their tokens: ``[t, h]`` float32."""
+        and back into their tokens: ``[t, h]`` float32. Only the ``first``
+        chunk names its rows: a block's policy reaches a name through the
+        ``cond`` and the loop below, and k - 1 more chunks' rows do not fit."""
         in_chunk = lambda edge: jnp.clip(edge - start, 0, t)  # noqa: E731
         sizes = in_chunk(ends) - in_chunk(ends - load)
         n_valid = in_chunk(ends[-1])
         chunk_tokens = jax.lax.dynamic_slice(tokens, (start,), (t,))
         chunk_rank = rank.reshape(t, k) - start
+        xin = take_rows(xf, chunk_tokens, chunk_rank, n_valid)
+        if first:
+            xin = checkpoint_name(xin, "moe_rows")
         rows = _expert_rows(
-            lp["experts"], take_rows(xf, chunk_tokens, chunk_rank, n_valid),
+            lp["experts"], xin,
             jax.lax.dynamic_slice(weights, (start,), (t,)), sizes, n_valid, compute_dtype, impl,
         )
         return sum_rows(rows, chunk_tokens, chunk_rank, n_valid)
 
     with scope("experts"):
-        y = chunk(0)
+        y = chunk(0, first=True)
         overflow = min(k, n_held) - 1
         if overflow > 0:
             # Chunks past the first: a token's k choices can all be held here.

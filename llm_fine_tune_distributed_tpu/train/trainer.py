@@ -630,20 +630,25 @@ class SFTTrainer:
         ops/nf4.nf4_matmul docstring; 4-bit at rest in HBM either way)."""
         return self.config.quant_matmul_impl
 
+    def _rematted_layers(self) -> range:
+        """The blocks whose ``jax.checkpoint`` has a policy
+        (models/transformer._remat_policy). The pipeline schedule wraps its
+        blocks without one and the int8 trunk is not rematerialized."""
+        if not self.config.gradient_checkpointing or self._pipe_size > 1:
+            return range(0)
+        return range(self._frozen_boundary, self.model_config.num_layers)
+
     def _layers_keeping_flash_outputs(self) -> int:
         """How many blocks of the step keep the flash forward kernel's output
-        and row statistics across their remat boundary
-        (models/transformer._remat_policy): a static fact of the step, from its
-        shapes. The pipeline schedule wraps its blocks without a policy and
-        the int8 trunk is not rematerialized: none there."""
-        cfg, mc = self.config, self.model_config
-        if (
-            not cfg.gradient_checkpointing
-            or self._pipe_size > 1
-            or not keeps_flash_outputs(mc, cfg.max_seq_length)
-        ):
-            return 0
-        return mc.num_layers - self._frozen_boundary
+        and row statistics across their remat boundary: a static fact of the
+        step, from its shapes."""
+        keeps = keeps_flash_outputs(self.model_config, self.config.max_seq_length)
+        return len(self._rematted_layers()) if keeps else 0
+
+    def _layers_keeping_routing(self) -> int:
+        """How many keep an expert layer's routing and gathered rows
+        (ops/moe.KEPT_ACROSS_REMAT): from the layers' kinds."""
+        return sum(self.model_config.layer(i).feed_forward == "grouped_experts" for i in self._rematted_layers())
 
     def _prepare_steps(self) -> None:
         act = self._make_shardings()
@@ -1163,7 +1168,9 @@ class SFTTrainer:
                                 f"remat_policy={cfg.remat_policy!r} "
                                 f"({self._layers_keeping_flash_outputs()} of "
                                 f"{self.model_config.num_layers} layers also keep "
-                                f"the flash kernel's outputs), "
+                                f"the flash kernel's outputs, "
+                                f"{self._layers_keeping_routing()} their experts' "
+                                f"routing and gathered rows), "
                                 f"loss_chunk_size={cfg.loss_chunk_size}: "
                                 f"{str(e).splitlines()[0][:300]}",
                                 flush=True,

@@ -17,6 +17,7 @@ one file, so one worker loads the library once.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +150,10 @@ def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, mo
     assert mosaic_calls("flash_attention_fwd") == setup.model_config.num_layers
     assert mosaic_calls("flash_attention_dq") == mosaic_calls("flash_attention_dkv") == setup.model_config.num_layers
     assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    # and each expert layer keeps its routing and gathered rows: the backward
+    # pass holds no second router product, selection or sort (tests/test_moe_remat.py)
+    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
+    assert not again, again
 
 
 def test_flash_says_no_past_its_vmem_cap(monkeypatch):

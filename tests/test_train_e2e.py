@@ -293,5 +293,6 @@ def test_trainer_states_a_compiler_refusal_before_it_raises(
     # rows of 128 tokens against a hidden size of 64: every block also keeps
     # the flash forward kernel's outputs (models/transformer._remat_policy)
     layers = trainer.model_config.num_layers
-    assert f"({layers} of {layers} layers also keep the flash kernel's outputs)" in line
+    # and no block of a dense model keeps an expert layer's routing (ops/moe.KEPT_ACROSS_REMAT)
+    assert f"({layers} of {layers} layers also keep the flash kernel's outputs, 0 their experts' routing" in line
     assert "Used 18.69G of 15.75G" in line and "Total hbm" not in line
