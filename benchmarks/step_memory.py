@@ -3,7 +3,9 @@
 step of a benchmark cell (or a neighbour of one) is compiled for a described
 v5e through ``observe/scaling.abstract_train_setup`` and one JSON line a step
 gives ``peak_memory_in_bytes`` (what has to fit; arguments + temporaries
-counts the donated state twice), the Mosaic calls by flash kernel, or the
+counts the donated state twice), the Mosaic calls by flash kernel (the
+resident ones and, by their own names, the streamed ones of window and global
+layers), whether any ``[seq, seq]`` scores are in the program, or the
 compiler's refusal. Counts and the compiler's word, never a rate.
 
 The state is the cells': bfloat16 masters, Adam's moments float32 (optax
@@ -27,15 +29,20 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 _DENSE = dict(freeze_strategy="last_n_and_head", unfreeze_last_n_layers=2, attention_impl="flash")
+_ALL = dict(freeze_strategy="none", attention_impl="flash", remat_policy="full", loss_chunk_size=1024)
+_MELLUM = ("mellum2_12b_a2_5b", dict(num_layers=4, vocab_size=24576, held_experts=tuple(range(16))))
 # name -> (preset, model overrides, rows, accumulation, sequence, recipe)
 STEPS = {
     # the three cells of BENCHMARK.json, as their traffic files state them
     "smollm3-3b.sft-1k-full": ("smollm3_3b", {}, 2, 16, 1024, dict(_DENSE, remat_policy="dots_no_batch")),
     "mistral-7b-d16.sft-2k-full": ("mistral_7b", dict(num_layers=16), 1, 16, 2048, dict(_DENSE, remat_policy="dots_no_batch")),
     "moonlight-16b-a3b-ep8-d6.sft-4k-allparams": (
-        "moonlight_16b_a3b", dict(num_layers=6, vocab_size=20480, held_experts=tuple(range(8))), 4, 2, 4096,
-        dict(freeze_strategy="none", attention_impl="flash", remat_policy="full", loss_chunk_size=1024),
+        "moonlight_16b_a3b", dict(num_layers=6, vocab_size=20480, held_experts=tuple(range(8))), 4, 2, 4096, _ALL,
     ),
+    "mellum2-12b-a2.5b-ep4-d4.sft-8k-allparams": (*_MELLUM, 4, 1, 8192, _ALL),
+    # the same at 2 rows and at 1 (of 4, 2, 1 rows the cell takes the most that fits: 4)
+    "mellum2-12b-a2.5b-ep4-d4.8k-2rows": (*_MELLUM, 2, 2, 8192, _ALL),
+    "mellum2-12b-a2.5b-ep4-d4.8k-1row": (*_MELLUM, 1, 4, 8192, _ALL),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),
@@ -87,6 +94,12 @@ def main(names) -> int:
                     kernel: sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text)
                     for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv")
                 },
+                streamed={
+                    kernel: n
+                    for kernel in (f"flash_attention_{kind}_{k}" for kind in ("window", "causal") for k in ("fwd", "dq", "dkv"))
+                    if (n := sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text))
+                },
+                seq_by_seq_buffers=sum(f",{seq},{seq}]" in ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in text),
             )
         line["compile_s"] = round(time.time() - started, 1)
         print(json.dumps(line), flush=True)

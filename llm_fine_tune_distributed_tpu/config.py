@@ -25,6 +25,7 @@ class LayerPlan:
 
     attention: str  # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent)
     rope: bool  # rotary embedding on this layer's q and k (SmolLM3's NoPE layers: False)
+    rope_kind: str  # which of the forward's cos/sin tables: "plain" | "scaled" (the config's context extension)
     window: Optional[int]  # sliding-window width, None = global attention
     feed_forward: str  # "dense" | "capacity_experts" (ops/moe.moe_mlp) | "grouped_experts" (grouped_moe_mlp + shared)
 
@@ -85,11 +86,27 @@ class ModelConfig:
     rope_low_freq_factor: float = 1.0
     rope_high_freq_factor: float = 4.0
     rope_original_max_position: int = 8192
+    # "yarn" (HF _compute_yarn_parameters): wavelengths beyond the original
+    # length are interpolated by ``factor``, short ones kept, a linear ramp
+    # between the dimensions that turn beta_fast and beta_slow times over the
+    # original length; cos and sin are multiplied by the attention factor
+    # (None: HF's default 0.1 ln(factor) + 1).
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: Optional[float] = None
+    # The one kind of ``layer_types`` whose layers rotate with the scaled
+    # table (HF ``rope_parameters`` keyed by layer type: Mellum extends its
+    # global layers only); None = the scaling is every layer's (Llama-3.1).
+    rope_scaling_layer_type: Optional[str] = None
     mlp_bias: bool = False
     # SmolLM3 NoPE: 1 = RoPE on this layer, 0 = no positional embedding.
     # Empty tuple = RoPE everywhere (Llama/Mistral).
     no_rope_layers: tuple = ()
     sliding_window: Optional[int] = None  # Mistral-style local attention
+    # HF ``layer_types``, one entry a layer: "sliding_attention" (the window
+    # applies) | "full_attention" (global). Empty = the window, if any, on
+    # every layer (or on even ones, ``alternating_sliding_window``).
+    layer_types: tuple = ()
     dtype: str = "bfloat16"
     # Mixture-of-experts (Mixtral-style). 0 = dense MLP. When > 0 every
     # layer's MLP becomes num_experts SwiGLU experts with top-k routing
@@ -114,11 +131,11 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # --- Routed experts with shared experts (HF DeepseekV3MoE, noaux_tc) ---
-    # n_routed_experts > 0 is the ROUTER's width: sigmoid scores, top
-    # num_experts_per_tok of scores + a bias buffer, weights normalised over
-    # the selected and scaled by routed_scaling_factor, no auxiliary loss, no
-    # dropped token (ops/moe.py grouped_moe_mlp). The first
+    # --- Routed experts without capacity (HF DeepseekV3MoE noaux_tc, Mellum) ---
+    # n_routed_experts > 0 is the ROUTER's width: scores by router_scoring, top
+    # num_experts_per_tok of them (plus a bias buffer where sigmoid), weights
+    # normalised over the selected and scaled by routed_scaling_factor, no
+    # auxiliary loss, no dropped token (ops/moe.py grouped_moe_mlp). The first
     # first_k_dense_replace layers keep the dense MLP of intermediate_size;
     # the rest hold experts of moe_intermediate_size beside n_shared_experts
     # shared ones (one SwiGLU of n_shared_experts * moe_intermediate_size).
@@ -127,6 +144,11 @@ class ModelConfig:
     n_shared_experts: int = 0
     first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
+    # How the router scores: "sigmoid" (DeepSeek-V3: independent scores, top k
+    # of scores + the ``e_score_correction_bias`` buffer) | "softmax" (Mellum,
+    # Qwen3-MoE: probabilities over all experts, top k of them, no bias leaf).
+    # Either way the weights are the selected scores over their sum.
+    router_scoring: str = "sigmoid"
     # Global ids of the routed experts THIS program holds (expert
     # parallelism's share; the stacked expert leaves have len(held_experts)
     # rows). Empty = all n_routed_experts. The router stays n_routed_experts
@@ -142,6 +164,17 @@ class ModelConfig:
                 raise ValueError(f"held_experts {held} must be distinct ids below n_routed_experts={self.n_routed_experts}")
             if not 0 < self.num_experts_per_tok <= self.n_routed_experts:
                 raise ValueError("num_experts_per_tok must lie in 1..n_routed_experts")
+            if self.router_scoring not in ("sigmoid", "softmax"):
+                raise ValueError(f"router_scoring {self.router_scoring!r}: expected 'sigmoid' or 'softmax'")
+        if self.layer_types:
+            kinds = set(self.layer_types) - {"sliding_attention", "full_attention"}
+            if kinds or len(self.layer_types) < self.num_layers:
+                raise ValueError(
+                    f"layer_types must name each of the {self.num_layers} layers 'sliding_attention' or "
+                    f"'full_attention' (got {len(self.layer_types)} entries, unknown kinds {sorted(kinds)})"
+                )
+            if "sliding_attention" in self.layer_types[: self.num_layers] and self.sliding_window is None:
+                raise ValueError("layer_types has sliding_attention layers but sliding_window is None")
         if (self.num_experts or self.n_routed_experts) and self.hidden_act != "silu":
             # ops/moe.py's expert MLP hardcodes silu — reject at config
             # construction rather than silently training with the wrong
@@ -200,11 +233,12 @@ class ModelConfig:
             )
             total += L * (mla - 2 * h * (self.num_heads + self.num_kv_heads) * d)
         if self.n_routed_experts:
-            # expert layers: router [h, E] + its bias buffer [E], the HELD
+            # expert layers: router [h, E] + (sigmoid) its bias buffer [E], the HELD
             # experts and the shared expert, instead of the dense MLP
             fe, e = self.moe_intermediate_size, self.n_routed_experts
             held = len(self.held_expert_ids)
-            experts = h * e + e + 3 * h * fe * (held + self.n_shared_experts)
+            bias = e if self.router_scoring == "sigmoid" else 0
+            experts = h * e + bias + 3 * h * fe * (held + self.n_shared_experts)
             total += (L - min(L, self.first_k_dense_replace)) * (experts - 3 * h * f)
         if not self.tie_word_embeddings:
             total += v * h
@@ -220,14 +254,22 @@ class ModelConfig:
             feed_forward = "grouped_experts"
         else:
             feed_forward = "dense"
-        # Gemma2 alternates local (even layers) / global (odd); Mistral
-        # applies the window everywhere
+        # layer_types names each layer's kind (Mellum: three window layers to
+        # one global); else Gemma2 alternates local (even layers) / global
+        # (odd) and Mistral applies the window everywhere
         window = self.sliding_window
-        if self.alternating_sliding_window and i % 2 != 0:
+        if self.layer_types:
+            if self.layer_types[i] == "full_attention":
+                window = None
+        elif self.alternating_sliding_window and i % 2 != 0:
             window = None
+        scaled = bool(self.rope_scaling_type) and self.rope_scaling_layer_type in (
+            None, self.layer_types[i] if self.layer_types else None
+        )
         return LayerPlan(
             attention="latent" if self.kv_lora_rank else "heads",
             rope=bool(self.no_rope_layers[i]) if self.no_rope_layers else True,
+            rope_kind="scaled" if scaled else "plain",
             window=window,
             feed_forward=feed_forward,
         )

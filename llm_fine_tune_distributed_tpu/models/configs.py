@@ -319,6 +319,67 @@ PRESETS = {
         routed_scaling_factor=2.446,
         held_experts=(0, 1, 2, 3),
     ),
+    "mellum2_12b_a2_5b": ModelConfig(
+        # HF JetBrains/Mellum2-12B-A2.5B-Instruct (model_type mellum): three
+        # window layers (1024, plain rope) to one global layer (YaRN rope, its
+        # factor on cos and sin), GQA 32/4 at 128; every layer's feed-forward
+        # is 64 experts of 896 behind a softmax router, 8 a token, weights
+        # normalised over the 8, no shared expert, no selection bias. Set
+        # held_experts to one process's share for expert parallelism.
+        name="mellum2_12b_a2_5b",
+        vocab_size=98304,
+        hidden_size=2304,
+        intermediate_size=7168,  # the config's dense width; no layer is dense
+        num_layers=28,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        rope_theta=500_000.0,
+        max_position_embeddings=131072,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        sliding_window=1024,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 7,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=16.0,
+        rope_original_max_position=8192,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_attention_factor=1.2772588722239782,
+        rope_scaling_layer_type="full_attention",
+        n_routed_experts=64,
+        num_experts_per_tok=8,
+        moe_intermediate_size=896,
+        router_scoring="softmax",
+    ),
+    "tiny_mellum": ModelConfig(
+        # Mellum's structure at toy widths (tests, the benchmark's CPU
+        # rehearsal): one period of the 3:1 pattern, a window shorter than the
+        # rows, this process holding 4 of the 16 routed experts
+        name="tiny_mellum",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=500_000.0,
+        max_position_embeddings=2048,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        sliding_window=32,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        rope_scaling_type="yarn",
+        rope_scaling_factor=16.0,
+        rope_original_max_position=128,
+        rope_scaling_layer_type="full_attention",
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=32,
+        router_scoring="softmax",
+        held_experts=(0, 1, 2, 3),
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -389,6 +450,10 @@ def to_hf_dict(mc: ModelConfig) -> dict:
                 "low_freq_factor": mc.rope_low_freq_factor,
                 "high_freq_factor": mc.rope_high_freq_factor,
                 "original_max_position_embeddings": mc.rope_original_max_position,
+                "beta_fast": mc.rope_beta_fast,
+                "beta_slow": mc.rope_beta_slow,
+                "attention_factor": mc.rope_attention_factor,
+                "layer_type": mc.rope_scaling_layer_type,
             }
             if mc.rope_scaling_type
             else None
@@ -396,6 +461,7 @@ def to_hf_dict(mc: ModelConfig) -> dict:
         "mlp_bias": mc.mlp_bias,
         "no_rope_layers": list(mc.no_rope_layers),
         "sliding_window": mc.sliding_window,
+        "layer_types": list(mc.layer_types),
         # MoE round trip (HF MixtralConfig naming — consumed by
         # models/configs.from_hf_config at inference load time)
         "num_local_experts": mc.num_experts,
@@ -423,7 +489,7 @@ def _deepseek_v3_keys(mc: ModelConfig) -> dict:
         "first_k_dense_replace": mc.first_k_dense_replace,
         "moe_layer_freq": 1,
         "routed_scaling_factor": mc.routed_scaling_factor,
-        "scoring_func": "sigmoid",
+        "scoring_func": mc.router_scoring,
         "topk_method": "noaux_tc",
         "norm_topk_prob": True,
         "n_group": 1,
@@ -438,17 +504,19 @@ def _deepseek_v3_fields(g) -> dict:
     any weight loads."""
     refused = {
         "q_lora_rank": (None,), "n_group": (1, None), "topk_group": (1, None),
-        "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",), "norm_topk_prob": (True,),
-        "moe_layer_freq": (1, None), "num_nextn_predict_layers": (0, None), "rope_scaling": (None,),
+        "scoring_func": ("sigmoid", "softmax"), "topk_method": ("noaux_tc",), "norm_topk_prob": (True,),
+        "moe_layer_freq": (1, None), "num_nextn_predict_layers": (0, None),
         "attention_bias": (False, None),
     }
+    if g("kv_lora_rank"):  # (DeepSeek's YaRN also rescales the softmax: not written for latent attention)
+        refused["rope_scaling"] = (None,)
     for key, allowed in refused.items():
         if g(key) not in allowed:
             raise ValueError(
                 f"deepseek_v3 config has {key}={g(key)!r}; implemented: {key} in {allowed} "
-                "(q as one matrix, one expert group, sigmoid scores with a selection bias, "
-                "normalised top-k weights, every layer past the leading dense ones with experts, "
-                "plain rope)"
+                "(q as one matrix, one expert group, sigmoid scores with a selection bias or softmax "
+                "scores without, normalised top-k weights, every layer past the leading dense ones "
+                "with experts, plain rope under latent attention)"
             )
     return dict(
         kv_lora_rank=g("kv_lora_rank"),
@@ -462,6 +530,53 @@ def _deepseek_v3_fields(g) -> dict:
         routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
         held_experts=tuple(g("held_experts") or ()),
         num_experts_per_tok=g("num_experts_per_tok"),
+        router_scoring=g("scoring_func"),
+    )
+
+
+def _mellum_fields(g) -> dict:
+    """ModelConfig fields of a ``mellum`` config (JetBrains Mellum 2): window
+    and global layers by ``layer_types``, ``rope_parameters`` keyed by layer
+    type (plain rope for the window layers, YaRN for the global ones), every
+    layer's feed-forward ``num_experts`` experts behind a softmax router.
+    Whatever of it this framework does not implement is refused by name,
+    before any weight loads."""
+    n = g("num_hidden_layers")
+    layer_types = tuple(g("layer_types") or ())
+    ropes = dict(g("rope_parameters") or {})
+    window, full = dict(ropes.get("sliding_attention") or {}), dict(ropes.get("full_attention") or {})
+    problems = []
+    if set(g("mlp_layer_types") or ("sparse",)) != {"sparse"}:
+        problems.append("mlp_layer_types other than 'sparse' on every layer")
+    if not g("norm_topk_prob", True):
+        problems.append("norm_topk_prob false")
+    if window.get("rope_type", "default") != "default":
+        problems.append(f"rope_parameters.sliding_attention.rope_type {window.get('rope_type')!r} (implemented: default)")
+    if full.get("rope_type", "default") not in ("default", "yarn"):
+        problems.append(f"rope_parameters.full_attention.rope_type {full.get('rope_type')!r} (implemented: default, yarn)")
+    if window.get("rope_theta", full.get("rope_theta")) != full.get("rope_theta"):
+        problems.append("a rope_theta for the window layers other than the global layers'")
+    if len(layer_types) < n:
+        problems.append(f"layer_types with {len(layer_types)} entries for {n} layers")
+    if problems:
+        raise ValueError("mellum config has " + "; ".join(problems))
+    yarn = full.get("rope_type") == "yarn"
+    return dict(
+        layer_types=layer_types,
+        sliding_window=g("sliding_window") if g("use_sliding_window", True) else None,
+        rope_theta=float(full.get("rope_theta", 10_000.0)),
+        rope_scaling_type="yarn" if yarn else None,
+        rope_scaling_factor=float(full.get("factor", 1.0)),
+        rope_original_max_position=int(full.get("original_max_position_embeddings", 8192)),
+        rope_beta_fast=float(full.get("beta_fast") or 32.0),
+        rope_beta_slow=float(full.get("beta_slow") or 1.0),
+        rope_attention_factor=full.get("attention_factor"),
+        rope_scaling_layer_type="full_attention" if yarn else None,
+        n_routed_experts=g("num_experts"),
+        num_experts_per_tok=g("num_experts_per_tok"),
+        moe_intermediate_size=g("moe_intermediate_size"),
+        router_scoring="softmax",
+        held_experts=tuple(g("held_experts") or ()),
     )
 
 
@@ -549,12 +664,12 @@ def from_hf_config(hf_config) -> ModelConfig:
     rs_type = rs.get("rope_type", rs.get("type"))
     if rs_type in ("default", None):
         rs_type = None
-    elif rs_type not in ("linear", "llama3"):
+    elif rs_type not in ("linear", "llama3", "yarn"):
         # reject at config-load time, not minutes later inside the first
         # forward's jit trace (after multi-GB weight loading)
         raise ValueError(
             f"unsupported rope_scaling type {rs_type!r}; supported: "
-            "'llama3' (Llama-3.1 smoothed NTK), 'linear', 'default'"
+            "'llama3' (Llama-3.1 smoothed NTK), 'yarn', 'linear', 'default'"
         )
     mc = ModelConfig(
         name=g("model_type", "hf_model"),
@@ -633,9 +748,14 @@ def from_hf_config(hf_config) -> ModelConfig:
         rope_original_max_position=int(
             rs.get("original_max_position_embeddings", 8192)
         ),
+        rope_beta_fast=float(rs.get("beta_fast") or 32.0),
+        rope_beta_slow=float(rs.get("beta_slow") or 1.0),
+        rope_attention_factor=rs.get("attention_factor"),
+        rope_scaling_layer_type=rs.get("layer_type"),
         mlp_bias=bool(g("mlp_bias", False)),
         no_rope_layers=tuple(no_rope),
         sliding_window=g("sliding_window") if g("use_sliding_window", True) else None,
+        layer_types=tuple(g("layer_types") or ()),
         # MoE (HF MixtralConfig naming). router_aux_loss_coef=0.0 is a
         # legitimate explicit choice (aux disabled) — only None falls back.
         num_experts=g("num_local_experts", 0) or 0,
@@ -644,4 +764,6 @@ def from_hf_config(hf_config) -> ModelConfig:
             0.01 if g("router_aux_loss_coef") is None else g("router_aux_loss_coef")
         ),
     )
+    if mt == "mellum":
+        return dataclasses.replace(mc, **_mellum_fields(g))
     return dataclasses.replace(mc, **deepseek) if deepseek else mc

@@ -258,8 +258,9 @@ def _capacity_experts(p, hid, lin, config: ModelConfig, *, compute_dtype, mesh, 
 
 
 def _grouped_experts(p, hid, lin, config: ModelConfig, *, compute_dtype, mesh, **_):
-    """DeepSeek-V3's routed experts held here, beside the shared experts.
-    Counts the (token, expert) pairs of each held expert (``[held]`` int32)."""
+    """The routed experts held here (no capacity, no dropped token), beside
+    the shared experts where the model has them (DeepSeek-V3 does, Mellum does
+    not). Counts the (token, expert) pairs of each held expert (``[held]`` int32)."""
     if mesh is not None and dict(mesh.shape).get("expert", 1) > 1:
         raise NotImplementedError(
             "grouped experts over a mesh's expert axis: the exchange is not written yet "
@@ -557,9 +558,10 @@ def _block(
     return x, new_entry, counted
 
 
-def keeps_flash_outputs(config: ModelConfig, seq: int) -> bool:
-    """Whether a rematerialized block of this model keeps the flash forward
-    kernel's output and row statistics at rows of ``seq`` tokens: the rule of
+def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = None) -> bool:
+    """Whether a rematerialized block of this model, with this ``window``
+    (None: global attention), keeps the flash forward kernel's output and row
+    statistics at rows of ``seq`` tokens: the rule of
     ``ops/flash_attention.worth_keeping_across_remat`` at this model's head
     widths. What the kernel sees is the whole row on every mesh that calls it
     (batch and heads are sharded around it, Ulysses hands it the whole
@@ -570,7 +572,7 @@ def keeps_flash_outputs(config: ModelConfig, seq: int) -> bool:
         d_qk, d_v = config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim
     else:
         d_qk = d_v = config.resolved_head_dim
-    return worth_keeping_across_remat(seq, d_qk, d_v, config.hidden_size)
+    return worth_keeping_across_remat(seq, d_qk, d_v, config.hidden_size, window=window)
 
 
 def keeps_routing(config: ModelConfig) -> bool:
@@ -579,8 +581,9 @@ def keeps_routing(config: ModelConfig) -> bool:
     return any(config.layer(i).feed_forward == "grouped_experts" for i in range(config.num_layers))
 
 
-def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
-    """What ``jax.checkpoint`` keeps of a block besides its input. ``full``
+def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int, window: Optional[int] = None):
+    """What ``jax.checkpoint`` keeps of a block (of this ``window``, None for
+    global attention) besides its input. ``full``
     (and None): nothing, the whole block is recomputed, least memory. The
     selective policies save the expensive tensors and recompute the cheap
     elementwise operations, trading HBM for fewer recomputed FLOPs (a v5e is
@@ -588,7 +591,9 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
     ``mlp`` only the [b, s, f] SwiGLU product (``mlp_act``).
 
     Under every one of them alike, ``full`` included, a block on long rows
-    (``keeps_flash_outputs``: from the shapes, no switch) also keeps the flash
+    (``keeps_flash_outputs``: from the shapes and the keys a query of this
+    layer sees, no switch; at 8192 tokens a layer with a window of 1024 does
+    not, its global neighbour does) also keeps the flash
     forward kernel's ``o`` and ``lse``, so that the kernel runs once a layer
     and not a second time in the backward pass. Where the kernel is not in
     the block (XLA attention) the two names are in no program and the policy
@@ -619,7 +624,7 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
             f"unknown remat_policy {remat_policy!r}; expected one of {sorted(policies)}"
         )
     policy = policies[remat_policy]
-    names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq) else ()
+    names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq, window) else ()
     if keeps_routing(config):
         names += moe.KEPT_ACROSS_REMAT
     if not names:
@@ -629,11 +634,19 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int):
 
 
 def rope_tables(config: ModelConfig, positions):
-    """cos, sin for ``positions``, at the width this model's attention
-    rotates: the whole head, or latent attention's rope part of it."""
+    """``{rope_kind: (cos, sin)}`` for ``positions``, one pair for each kind
+    some layer's plan names (``LayerPlan.rope_kind``: "plain", or "scaled" by
+    the config's context extension; a model whose window layers keep plain
+    rope while its global layers extend theirs has both), at the width this
+    model's attention rotates: the whole head, or latent attention's rope
+    part of it."""
     latent = config.layer(0).attention == "latent"
     width = config.qk_rope_head_dim if latent else config.resolved_head_dim
-    return rope_cos_sin(positions, width, config.rope_theta, config=config)
+    kinds = {config.layer(i).rope_kind for i in range(config.num_layers)}
+    return {
+        kind: rope_cos_sin(positions, width, config.rope_theta, config=config if kind == "scaled" else None)
+        for kind in sorted(kinds)
+    }
 
 
 def forward_with_report(
@@ -751,7 +764,7 @@ def forward_with_report(
             # Gemma normalizer: HF multiplies by a sqrt(hidden) scalar cast to
             # the activation dtype first — mirror the cast for bf16 bit-parity
             x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
-    cos, sin = rope_tables(config, positions)
+    tables = rope_tables(config, positions)
 
     explicit_mask = None
     windowed_mask = None
@@ -807,7 +820,9 @@ def forward_with_report(
     # compile-cost guard (tests/test_frozen_trunk.py) pins both.
     trunk_layers = frozen_layers if (frozen_compute == "int8" and cache is None) else 0
     remat = remat and cache is None
-    kept_of_a_block = _remat_policy(remat_policy, config, s) if remat else None
+    # one policy for each kind of layer (by its window), not one a layer
+    windows = {config.layer(i).window for i in range(config.num_layers)} if remat else ()
+    kept_of_a_block = {w: _remat_policy(remat_policy, config, s, w) for w in windows}
     for i in range(config.num_layers):
         entry = cache["layers"][str(i)] if cache is not None else None
         in_trunk = i < trunk_layers
@@ -834,13 +849,12 @@ def forward_with_report(
             w8a8=in_trunk,
         )
         if remat and not in_trunk:
-            block_fn = jax.checkpoint(block_fn, policy=kept_of_a_block)
+            block_fn = jax.checkpoint(block_fn, policy=kept_of_a_block[plan.window])
         with scope("layer", i):
             x, new_entry, counted = block_fn(
                 params["model"]["layers"][str(i)],
                 x,
-                cos,
-                sin,
+                *tables[plan.rope_kind],
                 padding_mask,
                 segment_ids,
                 explicit_mask if plan.window is None else windowed_mask,  # (made whenever an explicit one is)

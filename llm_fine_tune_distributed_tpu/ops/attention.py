@@ -43,6 +43,11 @@ _DISPATCH_COUNTS: collections.Counter = collections.Counter()
 # never a rescue from a kernel that failed to compile — that raises.
 _FLASH_FALLBACK_REASONS: collections.Counter = collections.Counter()
 
+# Which of the flash kernel's programs each traced call took, by the kind of
+# layer that called it (flash_attention.program_label: "resident causal",
+# "streamed causal", "streamed window 1024").
+_FLASH_PROGRAMS: collections.Counter = collections.Counter()
+
 # The dtypes q, k, v had where the flash kernel was traced (what its matmuls
 # are handed is the kernel's MXU_OPERAND_DTYPE, printed beside them).
 _FLASH_INPUT_DTYPES: set = set()
@@ -58,9 +63,10 @@ def dispatch_summary() -> str:
     each flash request that took XLA attention, why."""
     from llm_fine_tune_distributed_tpu.ops.flash_attention import MXU_OPERAND_DTYPE
 
+    programs = ", ".join(f"{label} x{n}" for label, n in sorted(_FLASH_PROGRAMS.items()))
     dtypes = {
         "flash": f" ({'/'.join(sorted(_FLASH_INPUT_DTYPES))} inputs, "
-        f"{jnp.dtype(MXU_OPERAND_DTYPE).name} MXU operands)"
+        f"{jnp.dtype(MXU_OPERAND_DTYPE).name} MXU operands; {programs})"
     }
     paths = ", ".join(
         f"{k}={v}{dtypes.get(k, '')}" for k, v in sorted(_DISPATCH_COUNTS.items())
@@ -289,6 +295,7 @@ def attention(
         from llm_fine_tune_distributed_tpu.ops.flash_attention import (
             flash_unsupported_reason,
             pallas_flash_attention,
+            program_label,
         )
 
         # Mosaic kernels cannot be partitioned by GSPMD: on a mesh of more
@@ -315,9 +322,10 @@ def attention(
         if reason is None:
             _DISPATCH_COUNTS["flash"] += 1
             _FLASH_INPUT_DTYPES.add(jnp.dtype(q.dtype).name)
+            _FLASH_PROGRAMS[program_label(local(q), local(k), local(v), sliding_window=sliding_window)] += 1
             if not sharded:
                 return pallas_flash_attention(
-                    q, k, v, padding_mask=padding_mask, segment_ids=segment_ids
+                    q, k, v, padding_mask=padding_mask, segment_ids=segment_ids, sliding_window=sliding_window
                 )
             from llm_fine_tune_distributed_tpu.parallel.ring_attention import (
                 shard_map_seq_attention,
@@ -325,7 +333,7 @@ def attention(
 
             return shard_map_seq_attention(
                 lambda q_, k_, v_, p_, s_: pallas_flash_attention(
-                    q_, k_, v_, padding_mask=p_, segment_ids=s_
+                    q_, k_, v_, padding_mask=p_, segment_ids=s_, sliding_window=sliding_window
                 ),
                 mesh, None, q, k, v,
                 padding_mask=padding_mask, segment_ids=segment_ids,

@@ -28,6 +28,17 @@ to 2048, else at most 1024). A block pair wholly below the diagonal is one
 (``_diagonal_pieces``), each as wide as the causal mask lets it see, so what
 lies above the diagonal is left out up to the strips' own corners.
 
+Two sets of the three kernels. The RESIDENT ones (a causal row without a
+window whose operands fit: the whole K/V of a kv head beside a Q block in
+forward and dq, a kv head's whole query group beside a K block in dk/dv) loop
+over the other axis inside the kernel. The STREAMED ones (a sliding window, or
+a row whose group does not fit: 8 queries a kv head at 8192 would ask dk/dv
+for 218 MiB) put the other axis on the grid: one [block, block] tile a grid
+step, the running statistics and the dq, dk, dv accumulators in VMEM scratch,
+and with a window only the blocks inside the band on the grid at all
+(``_band``: at 8192 with a window of 1024, two blocks of 1024 a block, not
+eight), the band's edge tiles cut into strips as the diagonal is.
+
 Masking: a tile does only the masking it needs. The causal test is made in
 the strips on the diagonal alone; the same-segment test in programs whose
 tiles hold more than one segment id (a per-program flag in SMEM, computed from
@@ -51,7 +62,7 @@ right-padded batches pass the 1/0 padding mask); softmax runs in float32.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +73,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1.0e30
 
-# Whole K/V/Q of a program reside in VMEM, so VMEM bounds the sequence (ring
-# attention, parallel/ring_attention.py, covers longer ones).
-# Scoped-VMEM budget, set per pallas_call so that every entry point compiles
+# In the resident kernels the whole K/V (or a kv head's whole query group)
+# of a program resides in VMEM, so VMEM bounds the sequence they take; past
+# it the streamed kernels hold one tile of each operand, whatever the row's
+# length. Scoped-VMEM budget, set per pallas_call so that every entry point compiles
 # the same kernel (the compiler's process-wide default is 16 MiB, and the
 # backward at seq 4096 needs more). A call asks for its pipelined blocks,
 # double-buffered at their tiled size, plus room for the body's [BQ, BK] f32
@@ -124,7 +136,7 @@ def _operand(x):
     return x.astype(MXU_OPERAND_DTYPE)
 
 
-def _tile_mask(q_seg, k_seg, q_start, k_start, shape, *, segments, on_diagonal, q_axis=0):
+def _tile_mask(q_seg, k_seg, q_start, k_start, shape, *, segments, on_diagonal, q_axis=0, window=None):
     """Bool tile of ``shape``, True = attend, or None where the tile needs no
     mask at all. Queries from ``q_start`` run along axis ``q_axis`` and keys
     from ``k_start`` along the other; ``q_seg`` and ``k_seg`` are their
@@ -137,13 +149,17 @@ def _tile_mask(q_seg, k_seg, q_start, k_start, shape, *, segments, on_diagonal, 
     keys or other segments. Tiles whose queries and keys all carry one id
     pass it everywhere and skip it.
     ``on_diagonal``: the causal test, needed only where the diagonal crosses
-    the tile; a tile wholly below it skips the two iotas and the compare."""
+    the tile; a tile wholly below it skips the two iotas and the compare.
+    ``window``: the sliding-window test (a key more than ``window - 1`` behind
+    its query is out), handed over only where the band's lower edge crosses
+    the tile."""
     mask = (q_seg() == k_seg()) if segments else None
-    if on_diagonal:
+    if on_diagonal or window is not None:
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-        causal = k_pos <= q_pos
-        mask = causal if mask is None else causal & mask
+        tests = ([k_pos <= q_pos] if on_diagonal else []) + ([k_pos > q_pos - window] if window is not None else [])
+        for test in tests:
+            mask = test if mask is None else test & mask
     return mask
 
 
@@ -170,11 +186,9 @@ def _diagonal_pieces(block, *, own):
     dq): a strip of queries meets the keys up to its own end. By keys
     (dk/dv): a strip of keys meets the queries from its own start. On a v5e
     (PR 25) strips of 256 beat 128 and 512 in dq and dk/dv and tied with 512
-    in the forward."""
-    n = 256 if block % 256 == 0 else 128
-    if own == "queries":
-        return [(at, n, 0, at + n) for at in range(0, block, n)]
-    return [(at, n, at, block - at) for at in range(0, block, n)]
+    in the forward. (``_tile_pieces``, which also knows a window's edge, on
+    the one tile of a row that is one block.)"""
+    return [piece[:4] for piece in _tile_pieces(_band(block, block, None), 0, own=own)]
 
 
 # ---------------------------------------------------------------------------
@@ -546,6 +560,350 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
 
 
 # ---------------------------------------------------------------------------
+# streamed kernels: the other axis on the grid, a window's band and no more
+# ---------------------------------------------------------------------------
+
+
+class _Band(NamedTuple):
+    """Which [block, block] tiles of a row's score matrix a layer needs.
+    Query ``i`` sees key ``j`` iff ``i - window < j <= i`` (``window`` None:
+    every ``j <= i``). A block of queries (keys) then meets ``steps`` blocks
+    of keys (queries): its own and the ``steps - 1`` before (after) it. The
+    grids of the three streamed kernels are built from this and visit no tile
+    outside it; ``tiles`` is what they visit."""
+
+    block: int
+    blocks: int  # a row's blocks
+    window: Optional[int]
+    steps: int
+
+    @property
+    def tiles(self) -> int:
+        return sum(min(i + 1, self.steps) for i in range(self.blocks))
+
+
+def _band(seq: int, block: int, window: Optional[int]) -> _Band:
+    blocks = seq // block
+    reach = seq if window is None else min(window, seq)
+    return _Band(block, blocks, None if window is None or window >= seq else window, min(blocks, -(-(reach - 1) // block) + 1))
+
+
+def _is_cut(band: _Band, delta: int) -> bool:
+    """Whether the diagonal or the band's lower edge crosses the tile
+    ``delta`` blocks below the diagonal (some pair in it is masked)."""
+    return delta == 0 or (band.window is not None and delta * band.block + band.block - 1 >= band.window)
+
+
+def _tile_pieces(band: _Band, delta: int, *, own):
+    """The tile ``delta`` blocks below the diagonal (queries of block ``i``
+    against keys of block ``i - delta``), as ``(own_at, own_n, other_at,
+    other_n, causal, window)`` pieces, offsets from the tile's corner. A tile
+    wholly inside the band is one piece with no test. A tile that the
+    diagonal or the band's lower edge crosses is cut along ``own`` (the axis
+    the kernel accumulates along: "queries" in forward and dq, "keys" in
+    dk/dv) into strips of 256 as ``_diagonal_pieces`` cuts the diagonal; each
+    strip meets the part of the other axis it can see, rounded out to whole
+    lane registers, and makes only the tests (``causal`` a bool, ``window``
+    the width or None) that some pair in it can fail."""
+    block, w = band.block, band.window
+    if not _is_cut(band, delta):
+        return [(0, block, 0, block, False, None)]
+    shift = delta * block  # query position - key position = row - column + shift
+    n = 256 if block % 256 == 0 else 128
+    pieces = []
+    for at in range(0, block, n):
+        last = at + n - 1
+        if own == "queries":  # rows at..last see columns in (row + shift - w, row + shift]
+            lo, hi = (0 if w is None else at + shift - w + 1), last + shift + 1
+        else:  # columns at..last are seen by rows in [column - shift, column - shift + w)
+            lo, hi = at - shift, (block if w is None else last - shift + w)
+        lo, hi = max(0, lo // 128 * 128), min(block, -(-hi // 128) * 128)
+        if hi <= lo:
+            continue
+        if own == "queries":
+            causal, edge = hi - 1 > at + shift, w is not None and lo <= last + shift - w
+        else:
+            causal, edge = last > lo + shift, w is not None and at <= hi - 1 + shift - w
+        pieces.append((at, n, lo, hi - lo, causal, w if edge else None))
+    return pieces
+
+
+def _by_offset(band: _Band, delta, run):
+    """``run(offset)`` for the tile ``delta`` (traced) blocks below the
+    diagonal: a branch of its own for each offset whose tile is cut into
+    pieces (the diagonal, the band's edge: two or three in all), one for all
+    the whole tiles between them."""
+    cut = [d for d in range(band.steps) if _is_cut(band, d)]
+    for d in cut:
+        pl.when(delta == d)(functools.partial(run, d))
+    whole = [d for d in range(band.steps) if d not in cut]
+    if whole:
+        pl.when(jnp.logical_and(delta >= whole[0], delta <= whole[-1]))(functools.partial(run, whole[0]))
+
+
+def _fwd_stream_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, band):
+    """Grid (batch, q head, q block, step): step ``t`` brings in the K/V
+    block ``steps - 1 - t`` before the Q block's own (the band's far edge
+    first, the diagonal last); the online softmax's m, l and accumulator live
+    in scratch across the steps and the block's output leaves on the last."""
+    batch, iq, t = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    delta = band.steps - 1 - t
+
+    @pl.when(t == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def program(*, segments):
+        def tile(offset):
+            q = _operand(q_ref[0, 0])
+            for q_at, q_n, k_at, k_n, causal, window in _tile_pieces(band, offset, own="queries"):
+                rows, keys = slice(q_at, q_at + q_n), slice(k_at, k_at + k_n)
+                v_blk = _operand(v_ref[0, 0, keys, :])
+                s = jax.lax.dot_general(
+                    q[rows], _operand(k_ref[0, 0, keys, :]), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale
+                mask = _tile_mask(
+                    lambda: segq_ref[0, rows, :], lambda: segk_ref[0, 0, :, keys],
+                    q_at + offset * band.block, k_at, (q_n, k_n),
+                    segments=segments, on_diagonal=causal, window=window,
+                )
+                s = _keep(mask, s, _NEG_INF)
+                m = m_ref[rows]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                if segments or window is not None:
+                    # a row with no key in this piece (behind the band's edge, or
+                    # another segment) still has m_new = -1e30 and exp(0) = 1
+                    # there: kept out of l and acc here (the resident kernel's note)
+                    p = _keep(mask, p, 0.0)
+                l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+                acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+                    p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                m_ref[rows] = m_new
+
+        _by_offset(band, delta, tile)
+
+    # (a block nearer the row's start than the band is wide has no K/V block that far back)
+    pl.when(delta <= iq)(lambda: _with_or_without_segments(one_segment_ref[batch], program))
+
+    @pl.when(t == band.steps - 1)
+    def _finish():
+        l_safe = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l_safe)
+
+
+def _dq_stream_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *, scale, band):
+    """Grid and steps as the streamed forward; dq accumulates in scratch."""
+    batch, iq, t = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    delta = band.steps - 1 - t
+
+    @pl.when(t == 0)
+    def _start():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def program(*, segments):
+        def tile(offset):
+            q, do = _operand(q_ref[0, 0]), _operand(do_ref[0, 0])
+            for q_at, q_n, k_at, k_n, causal, window in _tile_pieces(band, offset, own="queries"):
+                rows, keys = slice(q_at, q_at + q_n), slice(k_at, k_at + k_n)
+                k_blk, v_blk = _operand(k_ref[0, 0, keys, :]), _operand(v_ref[0, 0, keys, :])
+                s = jax.lax.dot_general(
+                    q[rows], k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                ) * scale
+                mask = _tile_mask(
+                    lambda: segq_ref[0, rows, :], lambda: segk_ref[0, 0, :, keys],
+                    q_at + offset * band.block, k_at, (q_n, k_n),
+                    segments=segments, on_diagonal=causal, window=window,
+                )
+                p = _keep(mask, jnp.exp(s - lse_ref[0, 0, rows, :]), 0.0)
+                dp = jax.lax.dot_general(
+                    do[rows], v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                ds = p * (dp - delta_ref[0, 0, rows, :])
+                acc_ref[rows] = acc_ref[rows] + jax.lax.dot_general(
+                    ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+
+        _by_offset(band, delta, tile)
+
+    pl.when(delta <= iq)(lambda: _with_or_without_segments(one_segment_ref[batch], program))
+
+    @pl.when(t == band.steps - 1)
+    def _finish():
+        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _dkv_stream_kernel(one_segment_ref, segq_ref, segk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, band):
+    """Grid (batch, KV head, k block, query head of the group, step): step
+    ``t`` brings in the Q block ``t`` after the K block's own (q, dO, lse and
+    delta of ONE head: the group is walked by the grid, not held), on
+    transposed scores as the resident kernel; dk and dv accumulate in scratch
+    over the group and the steps and leave on the last of both."""
+    batch, jk, g, t = pl.program_id(0), pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    first = jnp.logical_and(g == 0, t == 0)
+    last = jnp.logical_and(g == pl.num_programs(3) - 1, t == band.steps - 1)
+
+    @pl.when(first)
+    def _start():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    def program(*, segments):
+        def tile(offset):
+            for k_at, k_n, q_at, q_n, causal, window in _tile_pieces(band, offset, own="keys"):
+                keys, rows = slice(k_at, k_at + k_n), slice(q_at, q_at + q_n)
+                q_blk, do_blk = _operand(q_ref[0, 0, rows, :]), _operand(do_ref[0, 0, rows, :])
+                s_t = jax.lax.dot_general(
+                    _operand(k_ref[0, 0, keys, :]), q_blk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [k_n, q_n]
+                mask = _tile_mask(
+                    lambda: _as_row(segq_ref[0, rows, :]), lambda: segk_ref[0, keys, :],
+                    q_at + offset * band.block, k_at, (k_n, q_n),
+                    segments=segments, on_diagonal=causal, window=window, q_axis=1,
+                )
+                p_t = _keep(mask, jnp.exp(s_t - _as_row(lse_ref[0, 0, rows, :])), 0.0)
+                dv_acc[keys] = dv_acc[keys] + jax.lax.dot_general(
+                    p_t.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                dp_t = jax.lax.dot_general(
+                    _operand(v_ref[0, 0, keys, :]), do_blk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                ds_t = p_t * (dp_t - _as_row(delta_ref[0, 0, rows, :]))
+                dk_acc[keys] = dk_acc[keys] + jax.lax.dot_general(
+                    ds_t.astype(q_blk.dtype), q_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+
+        _by_offset(band, t, tile)
+
+    # (a block nearer the row's end than the band is wide has no Q block that far on)
+    pl.when(jk + t < band.blocks)(lambda: _with_or_without_segments(one_segment_ref[batch], program))
+
+    @pl.when(last)
+    def _finish():
+        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _stream_operands(kernel, dtype, d, d_v, band: _Band, groups):
+    """(ins, outs, scratch) of a streamed kernel: as the resident kernels'
+    lists, every block one ``[block, width]`` tile. The block of the OTHER
+    axis is picked by the step; a step that would reach past the row's start
+    (end) names the nearest block there is, which is already in VMEM, and the
+    kernel skips it."""
+    block, steps = band.block, band.steps
+    f32 = jnp.float32
+    if kernel == "dkv":
+        own = lambda b_, h, j, g, t: (b_, h, j, 0)  # noqa: E731
+        other = lambda b_, h, j, g, t: (b_, h * groups + g, jnp.minimum(j + t, band.blocks - 1), 0)  # noqa: E731
+        ins = [
+            ((1, block, 1), jnp.int32, lambda b_, h, j, g, t: (b_, jnp.minimum(j + t, band.blocks - 1), 0)),
+            ((1, block, 1), jnp.int32, lambda b_, h, j, g, t: (b_, j, 0)),
+            ((1, 1, block, d), dtype, other),
+            ((1, 1, block, d), dtype, own),
+            ((1, 1, block, d_v), dtype, own),
+            ((1, 1, block, d_v), dtype, other),
+            ((1, 1, block, 1), f32, other),
+            ((1, 1, block, 1), f32, other),
+        ]
+        outs = [((1, 1, block, d), dtype, own), ((1, 1, block, d_v), dtype, own)]
+        return ins, outs, [((block, d), f32), ((block, d_v), f32)]
+    behind = lambda i, t: jnp.maximum(i - (steps - 1) + t, 0)  # noqa: E731
+    own = lambda b_, h, i, t: (b_, h, i, 0)  # noqa: E731
+    other = lambda b_, h, i, t: (b_, h // groups, behind(i, t), 0)  # noqa: E731
+    ins = [
+        ((1, block, 1), jnp.int32, lambda b_, h, i, t: (b_, i, 0)),
+        ((1, 1, 1, block), jnp.int32, lambda b_, h, i, t: (b_, behind(i, t), 0, 0)),
+        ((1, 1, block, d), dtype, own),
+        ((1, 1, block, d), dtype, other),
+        ((1, 1, block, d_v), dtype, other),
+    ]
+    if kernel == "fwd":
+        outs = [((1, 1, block, d_v), dtype, own), ((1, 1, block, 1), f32, own)]
+        return ins, outs, [((block, 1), f32), ((block, 1), f32), ((block, d_v), f32)]
+    ins += [((1, 1, block, d_v), dtype, own), ((1, 1, block, 1), f32, own), ((1, 1, block, 1), f32, own)]
+    return ins, [((1, 1, block, d), dtype, own)], [((block, d), f32)]
+
+
+# name of each streamed kernel in the program (and in a device trace): the
+# window's calls and the causal ones are told apart by it
+def _stream_name(kernel: str, band: _Band) -> str:
+    return f"flash_attention_{'causal' if band.window is None else 'window'}_{kernel}"
+
+
+# {(kernel name, band): (tiles its grid visits in one head's row, tiles of the
+# row's causal triangle)} of every streamed kernel built (traced) in this
+# process: what a grid was built to visit, for whoever wants to count it. The
+# band carries the row's blocks, their size and the window, so two shapes
+# traced in one process do not overwrite each other
+GRID_TILES: dict = {}
+
+
+def _stream_call(kernel, body, band: _Band, q, k, v, groups, out_shape, interpret, **static):
+    b, hq, _, d = q.shape
+    hkv, d_v = k.shape[1], v.shape[3]
+    ins, outs, scratch = _stream_operands(kernel, q.dtype, d, d_v, band, groups)
+    grid = (b, hkv, band.blocks, groups, band.steps) if kernel == "dkv" else (b, hq, band.blocks, band.steps)
+    name = _stream_name(kernel, band)
+    GRID_TILES[name, band] = (band.tiles, band.blocks * (band.blocks + 1) // 2)
+    budget = _vmem_budget(ins + outs, d) + sum(_tiled_bytes(shape, t) for shape, t in scratch)
+    return pl.pallas_call(
+        functools.partial(body, band=band, **static),
+        grid=grid,
+        in_specs=[_SMEM] + _block_specs(ins),
+        out_specs=tuple(_block_specs(outs)) if len(outs) > 1 else _block_specs(outs)[0],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(shape, t) for shape, t in scratch],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=budget,
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",) * (len(grid) - 3),
+        ),
+        interpret=interpret,
+        name=name,
+    )
+
+
+def _whole_row_one_segment(segments):
+    """[b] int32, 1 where a row carries one segment id from end to end (a
+    full row): its streamed programs make no segment test at all."""
+    return (segments.min(axis=1) == segments.max(axis=1)).astype(jnp.int32)
+
+
+def _fwd_stream(q, k, v, segments, *, scale, block, groups, window, interpret):
+    b, hq, sq, _ = q.shape
+    band = _band(sq, block, window)
+    out_shape = (
+        jax.ShapeDtypeStruct((b, hq, sq, v.shape[3]), q.dtype),
+        jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
+    )
+    return _stream_call("fwd", _fwd_stream_kernel, band, q, k, v, groups, out_shape, interpret, scale=scale)(
+        _whole_row_one_segment(segments), segments[:, :, None], _key_rows(segments, block), q, k, v
+    )
+
+
+def _bwd_stream(q, k, v, segments, o, lse, do, *, scale, block, groups, window, interpret):
+    b, hq, sq, d = q.shape
+    band = _band(sq, block, window)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]
+    one_segment, seg_column = _whole_row_one_segment(segments), segments[:, :, None]
+    dq = _stream_call(
+        "dq", _dq_stream_kernel, band, q, k, v, groups, jax.ShapeDtypeStruct(q.shape, q.dtype), interpret, scale=scale
+    )(one_segment, seg_column, _key_rows(segments, block), q, k, v, do, lse, delta)
+    dk, dv = _stream_call(
+        "dkv", _dkv_stream_kernel, band, q, k, v, groups,
+        (jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)), interpret, scale=scale,
+    )(one_segment, seg_column, seg_column, q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
 # custom-vjp wrapper (public entry)
 # ---------------------------------------------------------------------------
 
@@ -557,7 +915,7 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
 KEPT_ACROSS_REMAT = ("flash_o", "flash_lse")
 
 
-def worth_keeping_across_remat(seq: int, d_qk: int, d_v: int, hidden_size: int) -> bool:
+def worth_keeping_across_remat(seq: int, d_qk: int, d_v: int, hidden_size: int, window: Optional[int] = None) -> bool:
     """Whether a rematerialized block should keep the forward kernel's ``o``
     and ``lse`` instead of running the kernel a second time in the backward
     pass. From shapes alone: per byte of ``o`` a causal forward costs
@@ -571,13 +929,22 @@ def worth_keeping_across_remat(seq: int, d_qk: int, d_v: int, hidden_size: int) 
     against 2048, keep. With the kernel's measured share of its roofline
     against the matmuls' (48 to 62% against about 80%, PERF.md section 5) the
     crossing lies at 0.6 to 0.8 of ``hidden_size`` and the three fall on the
-    same sides, so the plain form stands (PERF.md, PR 27)."""
+    same sides, so the plain form stands (PERF.md, PR 27).
+
+    With a ``window`` the kernel's work grows with the keys a query sees and
+    not with the row: ``seq`` above stands for twice the mean number of keys a
+    query sees, which under the causal mask alone is the row's length and
+    inside a band ``window * (2 - window / seq)``. Mellum at 8192: its window
+    layers 1920 against 2304, recompute; its global layers 8192, keep."""
+    if window is not None and window < seq:
+        seq = window * (2 - window / seq)
     return seq * (d_qk + d_v) > 2 * d_v * hidden_size
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
-    """One custom_vjp closure per static configuration. The forward and the
+def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool, window: Optional[int] = None, streamed: bool = False):
+    """One custom_vjp closure per static configuration (``streamed``: the
+    kernels with the other axis on the grid; ``window`` goes with them). The forward and the
     backward are jitted on their own: a model calls this once a layer (and
     again under remat), and each call of a bare ``pallas_call`` would trace
     its kernel body and lower it to Mosaic anew, in Python, in every process,
@@ -605,14 +972,17 @@ def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool):
     took back a third of the gain on the chip (device busy time of 7 steps
     7.30 s before, 7.13 s compact, 7.04 s as written)."""
     static = dict(scale=scale, block=block, groups=groups, interpret=interpret)
+    fwd, bwd = _fwd, _bwd
+    if streamed:
+        fwd, bwd = functools.partial(_fwd_stream, window=window), functools.partial(_bwd_stream, window=window)
 
     @jax.jit
     def forward(q, k, v, segments):
-        return _fwd(q, k, v, segments, **static)
+        return fwd(q, k, v, segments, **static)
 
     @jax.jit
     def backward(q, k, v, segments, o, lse, do):
-        return _bwd(q, k, v, segments, o, lse, do, **static)
+        return bwd(q, k, v, segments, o, lse, do, **static)
 
     @jax.custom_vjp
     def fn(q, k, v, segments):
@@ -672,6 +1042,29 @@ def _pick_block(s: int) -> int:
     return next(blk for blk in (1024, 512, 256, 128) if s % blk == 0)
 
 
+def _resident_need(dtype, seq, d, d_v, block, groups) -> int:
+    """VMEM the largest resident kernel asks for: dk/dv, which holds a kv
+    head's whole query group."""
+    return _vmem_budget(sum(_dkv_operands(dtype, seq, d, block, groups, d_v), []), d)
+
+
+def _streamed(dtype, seq, d, d_v, block, groups, window) -> bool:
+    """Which set of kernels takes a call: the resident ones wherever they can
+    (no window, and the query group fits the cap), else the streamed ones.
+    From the call's shapes alone."""
+    return (window is not None and window < seq) or _resident_need(dtype, seq, d, d_v, block, groups) > _VMEM_CAP_BYTES
+
+
+def program_label(q, k, v, *, sliding_window=None) -> str:
+    """What ``flash_unsupported_reason`` accepted, in words, for the record
+    of the paths a model took (ops/attention.py): which set of kernels, and
+    the window."""
+    d, d_v, seq = q.shape[3], v.shape[3], q.shape[1]
+    streamed = _streamed(q.dtype, seq, d, d_v, _pick_block(seq), q.shape[2] // k.shape[2], sliding_window)
+    kind = "causal" if sliding_window is None or sliding_window >= seq else f"window {sliding_window}"
+    return f"{'streamed' if streamed else 'resident'} {kind}"
+
+
 def flash_unsupported_reason(
     q, k, v, *, sliding_window=None, causal: bool = True
 ) -> Optional[str]:
@@ -682,8 +1075,10 @@ def flash_unsupported_reason(
     sk, hkv, d_v = k.shape[1], k.shape[2], v.shape[3]
     if jax.default_backend() != "tpu":
         return f"backend is {jax.default_backend()}, the kernel is compiled for TPU only"
-    if not causal or sliding_window is not None:
-        return "non-causal or sliding-window mask"
+    if not causal:
+        return "non-causal mask"
+    if sliding_window is not None and sliding_window < 1:
+        return f"sliding window {sliding_window} sees no key"
     if sq != sk:
         return f"q len {sq} != kv len {sk} (decode/cache path)"
     block = _pick_block(sq)
@@ -696,15 +1091,18 @@ def flash_unsupported_reason(
         return f"head dim {d_v} is not a multiple of the 128 lanes"
     if hq % hkv:
         return f"q heads {hq} not a multiple of kv heads {hkv}"
-    # the dk/dv kernel holds a whole kv head's query group in VMEM and is the
-    # largest of the three; a shape it cannot hold takes xla/ring instead of
-    # dying inside the compiler (at SmolLM3 head shapes: seq <= 6144)
-    need = _vmem_budget(sum(_dkv_operands(q.dtype, sq, d, block, hq // hkv, d_v), []), d)
-    if need > _VMEM_CAP_BYTES:
-        return (
-            f"backward needs {need >> 20} MiB of VMEM at seq {sq}, over the "
-            f"{_VMEM_CAP_BYTES >> 20} MiB the kernel may ask for"
-        )
+    # A row whose query group the resident dk/dv kernel cannot hold (at
+    # SmolLM3's head shapes past 6144) and a layer with a window take the
+    # streamed kernels, whose blocks are one tile each whatever the row's
+    # length: their need grows with the block and the head widths alone.
+    if _streamed(q.dtype, sq, d, d_v, block, hq // hkv, sliding_window):
+        ins, outs, scratch = _stream_operands("dkv", q.dtype, d, d_v, _band(sq, block, sliding_window), hq // hkv)
+        need = _vmem_budget(ins + outs, d) + sum(_tiled_bytes(shape, t) for shape, t in scratch)
+        if need > _VMEM_CAP_BYTES:
+            return (
+                f"streamed backward needs {need >> 20} MiB of VMEM at blocks of {block} x {d}, over the "
+                f"{_VMEM_CAP_BYTES >> 20} MiB the kernel may ask for"
+            )
     return None
 
 
@@ -889,7 +1287,7 @@ def paged_decode_attention(
 
 
 def pallas_flash_attention(
-    q, k, v, *, padding_mask=None, segment_ids=None, interpret: bool = False
+    q, k, v, *, padding_mask=None, segment_ids=None, sliding_window=None, interpret: bool = False
 ):
     """q [b, sq, hq, d], k [b, sk, hkv, d], v [b, sk, hkv, d_v] ->
     [b, sq, hq, d_v] (q.dtype). The softmax scale is ``d ** -0.5``.
@@ -906,7 +1304,8 @@ def pallas_flash_attention(
     flows only within equal segment ids (plus causal). ``segment_ids`` comes
     from the packing pipeline (data/packing.py, 0 = pad tail); without it,
     ``padding_mask`` (1 = real) degenerates to the two-segment real/pad case.
-    Softmax in f32; causal.
+    Softmax in f32; causal; with ``sliding_window`` a query sees the
+    ``sliding_window`` keys up to and including its own.
     """
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
@@ -924,7 +1323,10 @@ def pallas_flash_attention(
             f"flash attention requires seq length divisible by 128, got {sq} "
             f"(use ops.attention.attention() for automatic XLA fallback)"
         )
-    fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, groups, interpret)
+    if _streamed(q.dtype, sq, d, v.shape[3], block, groups, sliding_window):
+        fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, groups, interpret, sliding_window, True)
+    else:
+        fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, groups, interpret)
     # head-major layout for clean blocking
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -248,20 +248,47 @@ def _sigmoid_fwd(z):
 _sigmoid.defvjp(_sigmoid_fwd, lambda scores, d: (d * scores * (1 - scores),))
 
 
+def _softmax_of(z):
+    # the row maximum and sum in float32 whatever the scores are kept in
+    # (float8_e4m3fn has no infinity for the reductions to start from)
+    return jax.nn.softmax(z.astype(jnp.float32), axis=-1).astype(z.dtype)
+
+
+@jax.custom_vjp
+def _softmax(z):
+    """Softmax over the experts, for ``_sigmoid``'s reason: the backward pass
+    reads the NAMED probabilities and nothing upstream of them."""
+    return _softmax_of(z)
+
+
+def _softmax_fwd(z):
+    probs = checkpoint_name(_softmax_of(z), "moe_scores")
+    return probs, probs
+
+
+_softmax.defvjp(_softmax_fwd, lambda probs, d: (probs * (d - (d * probs).sum(-1, keepdims=True)),))
+
+# ModelConfig.router_scoring -> the scores of the router's product
+_SCORING = {"sigmoid": _sigmoid, "softmax": _softmax}
+
+
 def route(gate, x, config: ModelConfig):
     """``x [T, h]`` -> (expert ids ``[T, k]`` int32, combine weights ``[T, k]``
-    float32). Sigmoid scores over all ``n_routed_experts``; the top k of
-    scores + ``e_score_correction_bias`` (a buffer: it selects, it does not
-    weigh, and no gradient reaches it); weights are the selected scores over
-    their sum, times ``routed_scaling_factor``."""
-    scores = _sigmoid(
+    float32). Scores over all ``n_routed_experts`` (``router_scoring``:
+    independent sigmoids, or one softmax); the top k of the scores, plus
+    ``e_score_correction_bias`` where the gate has one (DeepSeek-V3's buffer:
+    it selects, it does not weigh, and no gradient reaches it); weights are
+    the selected scores over their sum, times ``routed_scaling_factor``."""
+    scores = _SCORING[config.router_scoring](
         jnp.dot(
             x.astype(ROUTER_DTYPE), gate["kernel"].astype(ROUTER_DTYPE),
             precision=jax.lax.Precision.HIGHEST, preferred_element_type=ROUTER_DTYPE,
         )
     )
-    bias = jax.lax.stop_gradient(gate["e_score_correction_bias"]).astype(ROUTER_DTYPE)
-    top_i = checkpoint_name(jax.lax.top_k(scores + bias, config.num_experts_per_tok)[1], "moe_top_i")
+    select_by = scores
+    if "e_score_correction_bias" in gate:
+        select_by = scores + jax.lax.stop_gradient(gate["e_score_correction_bias"]).astype(ROUTER_DTYPE)
+    top_i = checkpoint_name(jax.lax.top_k(select_by, config.num_experts_per_tok)[1], "moe_top_i")
     # the chosen scores by a one-hot product: exact; take_along_axis's gather
     # of k of E values a token cost 1 ms a call on a v5e (PR 26)
     top_s = (jax.nn.one_hot(top_i, scores.shape[-1], dtype=scores.dtype) * scores[:, None, :]).sum(-1)
@@ -382,21 +409,34 @@ def _expert_rows(experts, xin, weights, sizes, n_valid, compute_dtype, impl):
     return jnp.where(valid[:, None], out, 0.0) * weights[:, None]
 
 
-def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
-    """Routed part of a DeepSeek-V3-style expert layer, for the experts held
-    here. ``x [b, s, h]`` -> ``(y [b, s, h], load [E_held] int32)``.
+def pairs_a_chunk(config: ModelConfig) -> int:
+    """How many sorted pairs a token one chunk of ``grouped_moe_mlp`` takes:
+    the load expected under even routing, ``k * held / routed`` pairs a token,
+    with a quarter of room above it, in whole pairs a token. The first chunk
+    then holds what the routing gives in all but a freak step, and the
+    ``lax.cond`` behind it is not taken (0.75 expected where 8 of 64 experts
+    are held and 6 chosen: 1; 2.0 where 16 of 64 are held and 8 chosen: 3;
+    with every expert held, k)."""
+    k, held = config.num_experts_per_tok, len(config.held_expert_ids)
+    return min(min(k, held), max(1, math.ceil(1.25 * k * held / config.n_routed_experts)))
 
-    ``lp``: ``gate/{kernel [h, n_routed_experts], e_score_correction_bias}``
-    and ``experts/{w1, w3 [E_held, h, f], w2 [E_held, f, h]}``, the rows in
-    the order of ``config.held_expert_ids``. The router is as wide as the
-    model's; a (token, expert) pair whose expert is held elsewhere adds
-    nothing here (on a mesh its owner adds it), but its score still stands
-    in the normaliser of the token's weights. No capacity, no dropped token:
-    the pairs held here are sorted by expert and taken ``b * s`` rows at a
-    time through grouped products; the first chunk covers up to one pair a
-    token (the expected load is k * E_held / n_routed_experts), further
-    chunks run only when the routing fills them, so the work follows the
-    pairs and not tokens x experts. ``load[e]`` counts the pairs of held
+
+def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
+    """Routed part of an expert layer without capacity (DeepSeek-V3, Mellum),
+    for the experts held here. ``x [b, s, h]`` -> ``(y [b, s, h], load
+    [E_held] int32)``.
+
+    ``lp``: ``gate/{kernel [h, n_routed_experts]}`` (and DeepSeek-V3's
+    ``e_score_correction_bias``) and ``experts/{w1, w3 [E_held, h, f], w2
+    [E_held, f, h]}``, the rows in the order of ``config.held_expert_ids``.
+    The router is as wide as the model's; a (token, expert) pair whose expert
+    is held elsewhere adds nothing here (on a mesh its owner adds it), but its
+    score still stands in the normaliser of the token's weights. No capacity,
+    no dropped token: the pairs held here are sorted by expert and taken a
+    chunk of ``pairs_a_chunk(config) * b * s`` rows at a time through grouped
+    products; the first chunk covers the expected load with room above it,
+    further chunks run only when the routing fills them, so the work follows
+    the pairs and not tokens x experts. ``load[e]`` counts the pairs of held
     expert e (the step's counter)."""
     from llm_fine_tune_distributed_tpu.observe.xla import scope
 
@@ -404,6 +444,7 @@ def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
     t, k = b * s, config.num_experts_per_tok
     held = config.held_expert_ids
     n_held = len(held)
+    rows_a_chunk = pairs_a_chunk(config) * t
     xf = x.reshape(t, h)
 
     with scope("router"):
@@ -424,27 +465,31 @@ def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
         weights = checkpoint_name(permute(top_w.reshape(-1), order, rank), "moe_weights")
 
     def chunk(start, first=False):
-        """Rows [start, start + t) of the sorted pairs through the experts
-        and back into their tokens: ``[t, h]`` float32. Only the ``first``
-        chunk names its rows: a block's policy reaches a name through the
-        ``cond`` and the loop below, and k - 1 more chunks' rows do not fit."""
-        in_chunk = lambda edge: jnp.clip(edge - start, 0, t)  # noqa: E731
+        """Rows [start, start + rows_a_chunk) of the sorted pairs through the
+        experts and back into their tokens: ``[t, h]`` float32. Only the
+        ``first`` chunk names its rows: a block's policy reaches a name through
+        the ``cond`` and the loop below, and the further chunks' rows do not fit."""
+        in_chunk = lambda edge: jnp.clip(edge - start, 0, rows_a_chunk)  # noqa: E731
         sizes = in_chunk(ends) - in_chunk(ends - load)
         n_valid = in_chunk(ends[-1])
-        chunk_tokens = jax.lax.dynamic_slice(tokens, (start,), (t,))
+        chunk_tokens = jax.lax.dynamic_slice(tokens, (start,), (rows_a_chunk,))
         chunk_rank = rank.reshape(t, k) - start
         xin = take_rows(xf, chunk_tokens, chunk_rank, n_valid)
         if first:
             xin = checkpoint_name(xin, "moe_rows")
         rows = _expert_rows(
             lp["experts"], xin,
-            jax.lax.dynamic_slice(weights, (start,), (t,)), sizes, n_valid, compute_dtype, impl,
+            jax.lax.dynamic_slice(weights, (start,), (rows_a_chunk,)), sizes, n_valid, compute_dtype, impl,
         )
         return sum_rows(rows, chunk_tokens, chunk_rank, n_valid)
 
     with scope("experts"):
+        # every pair held here lies in the first min(k, n_held) * t sorted rows
+        overflow = -(-min(k, n_held) * t // rows_a_chunk) - 1
+        past_the_tables = (overflow + 1) * rows_a_chunk - t * k
+        if past_the_tables > 0:  # the last chunk's slice must not slide back
+            tokens, weights = (jnp.pad(a, (0, past_the_tables)) for a in (tokens, weights))
         y = chunk(0, first=True)
-        overflow = min(k, n_held) - 1
         if overflow > 0:
             # Chunks past the first: a token's k choices can all be held here.
             # The whole loop sits behind ONE branch on whether the routing
@@ -455,13 +500,13 @@ def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
             # activations are recomputed in the backward pass instead of
             # saved: six chunks' would not fit beside the step's state.
             def more(c, acc):
-                start = c * t
+                start = c * rows_a_chunk
                 return acc + jax.lax.cond(
                     ends[-1] > start, jax.checkpoint(chunk), lambda st: jnp.zeros((t, h), jnp.float32), start
                 )
 
             y = y + jax.lax.cond(
-                ends[-1] > t,
+                ends[-1] > rows_a_chunk,
                 lambda: jax.lax.fori_loop(1, overflow + 1, more, jnp.zeros((t, h), jnp.float32)),
                 lambda: jnp.zeros((t, h), jnp.float32),
             )
@@ -480,9 +525,11 @@ def init_grouped_moe_params(rng, config: ModelConfig, dtype):
         return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
 
     out = {
-        "gate": {"kernel": dense(kg, (h, e)), "e_score_correction_bias": jnp.zeros((e,), dtype)},
+        "gate": {"kernel": dense(kg, (h, e))},
         "experts": {"w1": dense(k1, (n_held, h, f)), "w3": dense(k3, (n_held, h, f)), "w2": dense(k2, (n_held, f, h))},
     }
+    if config.router_scoring == "sigmoid":  # DeepSeek-V3's selection bias, a buffer
+        out["gate"]["e_score_correction_bias"] = jnp.zeros((e,), dtype)
     if fs:
         out["shared_experts"] = {
             "gate_proj": {"kernel": dense(ks1, (h, fs))},

@@ -13,6 +13,8 @@ with ``cos/sin = f(outer(positions, inv_freq))`` tiled twice along the last dim.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -25,6 +27,8 @@ def rope_inv_freq(
     low_freq_factor: float = 1.0,
     high_freq_factor: float = 4.0,
     original_max_position: int = 8192,
+    beta_fast: float = 32.0,
+    beta_slow: float = 1.0,
 ):
     """Per-frequency inverse wavelengths, with optional context extension.
 
@@ -37,6 +41,12 @@ def rope_inv_freq(
         (< original_max/high_freq_factor) untouched, with linear interpolation
         in between. Matching HF exactly is required for imported Llama-3.1+
         checkpoints to reproduce reference logits.
+      - "yarn": HF ``_compute_yarn_parameters`` (truncate true): dimension i
+        keeps its frequency below ``low``, is divided by ``factor`` above
+        ``high``, a linear ramp between; ``low``/``high`` are the (floored /
+        ceiled, clamped) dimensions whose wavelength turns ``beta_fast`` /
+        ``beta_slow`` times over the original length. The attention factor on
+        cos and sin is ``yarn_attention_factor``, applied by ``rope_cos_sin``.
     """
     half = head_dim // 2
     inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
@@ -56,7 +66,23 @@ def rope_inv_freq(
         out = jnp.where(wavelen > low_freq_wavelen, scaled, inv_freq)
         is_medium = (wavelen <= low_freq_wavelen) & (wavelen >= high_freq_wavelen)
         return jnp.where(is_medium, smoothed, out)
+    if scaling_type == "yarn":
+        def turns_at(rotations):  # the dimension that turns this often over the original length
+            return head_dim * math.log(original_max_position / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(turns_at(beta_fast)), 0)
+        high = min(math.ceil(turns_at(beta_slow)), head_dim - 1)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+        return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
     raise ValueError(f"unsupported rope scaling type: {scaling_type!r}")
+
+
+def yarn_attention_factor(factor: float, attention_factor=None) -> float:
+    """What YaRN multiplies cos and sin by (so the scores carry its square):
+    the config's own number, else HF's default ``0.1 ln(factor) + 1``."""
+    if attention_factor is not None:
+        return float(attention_factor)
+    return 1.0 if factor <= 1 else 0.1 * math.log(factor) + 1.0
 
 
 def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32, *, config=None):
@@ -67,13 +93,18 @@ def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32, *, c
       head_dim: per-head dimension (must be even).
       theta: RoPE base frequency.
       config: optional ModelConfig; when given, its rope_scaling_* fields
-        select the context-extension scheme (Llama-3.1 "llama3", "linear").
+        select the context-extension scheme (Llama-3.1 "llama3", "linear",
+        "yarn" with its attention factor on both tables). Without it the
+        tables are plain rope's.
 
     Returns:
       (cos, sin) arrays of shape positions.shape + (head_dim,).
     """
     # f32 throughout: bf16 position phases destroy long-context accuracy.
+    scale = 1.0
     if config is not None and config.rope_scaling_type:
+        if config.rope_scaling_type == "yarn":
+            scale = yarn_attention_factor(config.rope_scaling_factor, config.rope_attention_factor)
         inv_freq = rope_inv_freq(
             head_dim,
             theta,
@@ -82,12 +113,14 @@ def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32, *, c
             low_freq_factor=config.rope_low_freq_factor,
             high_freq_factor=config.rope_high_freq_factor,
             original_max_position=config.rope_original_max_position,
+            beta_fast=config.rope_beta_fast,
+            beta_slow=config.rope_beta_slow,
         )
     else:
         inv_freq = rope_inv_freq(head_dim, theta)
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., half]
     emb = jnp.concatenate([freqs, freqs], axis=-1)  # [..., head_dim]
-    return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
+    return (jnp.cos(emb) * scale).astype(dtype), (jnp.sin(emb) * scale).astype(dtype)
 
 
 def _rotate_half(x):

@@ -183,7 +183,7 @@ def pipeline_forward(
     # [1, seq]: broadcasts over however many microbatch rows a device holds
     # (the mb dim shards over data/fsdp inside the shard_map)
     positions = jnp.arange(seq, dtype=jnp.int32)[None]
-    cos, sin = rope_tables(config, positions)
+    cos, sin = rope_tables(config, positions)[config.layer(0).rope_kind]  # (one kind: layer_scan_problems)
     # Per-layer RoPE flags as DATA: the layer scan compiles one block body,
     # and NoPE-interleaved models (SmolLM3) select rope/no-rope per layer.
     # Uniform patterns (every preset except NoPE ones) skip the

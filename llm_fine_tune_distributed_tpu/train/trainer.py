@@ -642,8 +642,8 @@ class SFTTrainer:
         """How many blocks of the step keep the flash forward kernel's output
         and row statistics across their remat boundary: a static fact of the
         step, from its shapes."""
-        keeps = keeps_flash_outputs(self.model_config, self.config.max_seq_length)
-        return len(self._rematted_layers()) if keeps else 0
+        mc, seq = self.model_config, self.config.max_seq_length
+        return sum(keeps_flash_outputs(mc, seq, mc.layer(i).window) for i in self._rematted_layers())
 
     def _layers_keeping_routing(self) -> int:
         """How many keep an expert layer's routing and gathered rows

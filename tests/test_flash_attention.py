@@ -341,3 +341,104 @@ def test_the_diagonal_in_strips(monkeypatch, dtype, block):
     assert _rel(np.asarray(o_f, np.float32)[real], np.asarray(o_x)[real]) < tol
     for a, r, name in zip(g_f, g_x, "qkv"):
         assert _rel(a, r) < tol, f"d{name}"
+
+
+# ---------------------------------------------------------------------------
+# streamed kernels: a sliding window, and rows whose query group is not held
+# ---------------------------------------------------------------------------
+
+from llm_fine_tune_distributed_tpu.ops import flash_attention as fa  # noqa: E402
+
+
+def _forward_and_grads(fn, q, k, v, cot):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(cot)
+
+
+# (window, q heads, kv heads, q/k head, v head): a window that is and is not a
+# multiple of the block (128 here), 8 and 1 queries a kv head, heads of 128 and
+# of 192 against 128; None = the causal row through the streamed kernels
+STREAMED_CASES = [
+    (128, 8, 1, 128, 128), (200, 8, 1, 128, 128), (128, 2, 2, 128, 128), (200, 2, 2, 192, 128),
+    (384, 2, 1, 128, 128), (None, 8, 1, 128, 128),
+]
+
+
+@pytest.mark.parametrize("window, hq, hkv, d, d_v", STREAMED_CASES)
+def test_streamed_kernels_match_masked_xla_attention(monkeypatch, window, hq, hkv, d, d_v):
+    """Forward and all three gradients of the streamed kernels (interpret
+    mode, blocks of 128 on rows of 512: four blocks, band of two to four)
+    against ``xla_attention``'s masked scores. float32 inputs: what differs is
+    the order of the sums (observed 6e-7 forward, 6e-6 gradients); a window
+    off by one key, or a tile left out, is 1e-2 and more."""
+    monkeypatch.setenv("FLASH_BLOCK", "128")
+    monkeypatch.setattr(fa, "_VMEM_CAP_BYTES", 0 if window is None else fa._VMEM_CAP_BYTES)  # None: force streaming
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    b, s = 2, 512
+    q = jax.random.normal(ks[0], (b, s, hq, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, s, hkv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, s, hkv, d_v), jnp.float32)
+    cot = jax.random.normal(ks[3], (b, s, hq, d_v), jnp.float32)
+    assert fa.program_label(q, k, v, sliding_window=window).startswith("streamed")
+    got = _forward_and_grads(lambda *a: pallas_flash_attention(*a, sliding_window=window, interpret=True), q, k, v, cot)
+    want = _forward_and_grads(lambda *a: xla_attention(*a, causal=True, sliding_window=window), q, k, v, cot)
+    for a, b_, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=5e-5, rtol=5e-5, err_msg=name)
+    names = {"fwd", "dq", "dkv"}
+    kind = "causal" if window is None else "window"
+    assert {f"flash_attention_{kind}_{n}" for n in names} <= {name for name, _ in fa.GRID_TILES}
+
+
+def test_streamed_window_with_padding_and_a_row_as_one_block(monkeypatch):
+    """Right-padded rows through the window's kernels (the padding rides the
+    segment test: one flag a row says whether any tile makes it), on a row
+    that is one block of 256 with a window of 100 inside it."""
+    b, s, window = 3, 256, 100
+    q, k, v = make_qkv(jax.random.PRNGKey(4), b, s, 4, 2, 128)
+    lengths = np.asarray([256, 190, 40], np.int32)
+    row_ok = (np.arange(s)[None, :] < lengths[:, None]).astype(np.float32)
+    padding_mask = jnp.asarray(row_ok)
+    cot = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.float32) * padding_mask[:, :, None, None]
+    got = _forward_and_grads(
+        lambda *a: pallas_flash_attention(*a, padding_mask=padding_mask, sliding_window=window, interpret=True), q, k, v, cot)
+    want = _forward_and_grads(
+        lambda *a: xla_attention(*a, padding_mask=padding_mask, causal=True, sliding_window=window), q, k, v, cot)
+    np.testing.assert_allclose(np.asarray(got[0]) * row_ok[:, :, None, None], np.asarray(want[0]) * row_ok[:, :, None, None],
+                               atol=5e-5, rtol=5e-5)
+    for a, b_, name in zip(got[1:], want[1:], ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("own", ["queries", "keys"])
+@pytest.mark.parametrize("window", [None, 100, 128, 256, 300, 1024, 1500])
+@pytest.mark.parametrize("block", [128, 256, 1024])
+def test_tile_pieces_cover_the_band_and_test_only_where_a_pair_can_fail(block, window, own):
+    """Over every tile of a row of 4 blocks: the pieces of the tiles the grid
+    visits cover each pair the mask keeps exactly once, a pair outside a piece
+    is never kept, a piece that makes no causal (window) test holds no pair
+    that fails it, and no tile outside ``band.steps`` holds a kept pair."""
+    seq = 4 * block
+    band = fa._band(seq, block, window)
+    i, j = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    keep = (j <= i) & ((i - j < window) if band.window is not None else True)
+    covered = np.zeros((seq, seq), np.int32)
+    for qb in range(band.blocks):
+        for delta in range(min(band.steps, qb + 1)):
+            kb = qb - delta
+            for own_at, own_n, other_at, other_n, causal, edge in fa._tile_pieces(band, delta, own=own):
+                (q_at, q_n, k_at, k_n) = (own_at, own_n, other_at, other_n) if own == "queries" else (other_at, other_n, own_at, own_n)
+                rows, cols = slice(qb * block + q_at, qb * block + q_at + q_n), slice(kb * block + k_at, kb * block + k_at + k_n)
+                covered[rows, cols] += 1
+                assert causal or (j[:, cols] <= i[rows]).all()
+                assert edge is not None or band.window is None or (i[rows] - j[:, cols] < window).all()
+                assert other_at % 128 == 0 and other_n % 128 == 0
+    assert covered.max() <= 1 and (covered[keep] == 1).all()
+    assert band.tiles == sum(min(qb + 1, band.steps) for qb in range(band.blocks))
+
+
+def test_the_band_at_the_cells_shapes():
+    """8192 tokens in blocks of 1024 with a window of 1024: a block meets two
+    blocks, 15 tiles of the causal triangle's 36; without a window all 36."""
+    assert fa._band(8192, 1024, 1024)[2:] == (1024, 2) and fa._band(8192, 1024, 1024).tiles == 15
+    assert fa._band(8192, 1024, None)[2:] == (None, 8) and fa._band(8192, 1024, None).tiles == 36
+    assert fa._band(8192, 1024, 8192).window is None  # a window as long as the row is no window

@@ -140,7 +140,7 @@ def test_gradients_with_the_outputs_kept_equal_those_recomputed(interpreted_kern
     params = init_params(jax.random.PRNGKey(1), config)
     grad = lambda: jax.jit(jax.grad(partial(_loss, config=config, remat_policy="full")))(params)  # noqa: E731
     kept = grad()
-    monkeypatch.setattr(fa, "worth_keeping_across_remat", lambda *shapes: False)
+    monkeypatch.setattr(fa, "worth_keeping_across_remat", lambda *shapes, **window: False)
     recomputed = grad()
     leaves = jax.tree.leaves(kept)
     assert leaves and all(float(jnp.abs(leaf).max()) > 0 for leaf in leaves)

@@ -302,13 +302,33 @@ def test_linear_rope_scaling_logit_parity():
     _compare(model, hf_cfg, seq=40)
 
 
+def test_yarn_rope_scaling_logit_parity():
+    """YaRN: rope_inv_freq's ramp between interpolated and kept frequencies and
+    the attention factor on cos and sin, against HF _compute_yarn_parameters
+    (default factor 0.1 ln(8) + 1, and one given in the config)."""
+    for extra in ({}, {"attention_factor": 1.3}):
+        hf_cfg = transformers.LlamaConfig(
+            vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=256,
+            tie_word_embeddings=False, rope_theta=10000.0,
+            rope_scaling={"rope_type": "yarn", "factor": 8.0, "original_max_position_embeddings": 32,
+                          "beta_fast": 4.0, "beta_slow": 1.0, **extra},
+            pad_token_id=0, bos_token_id=1, eos_token_id=2,
+        )
+        torch.manual_seed(0)
+        model = transformers.LlamaForCausalLM(hf_cfg).eval()
+        cfg = from_hf_config(hf_cfg)
+        assert (cfg.rope_scaling_type, cfg.rope_beta_fast, cfg.rope_attention_factor) == ("yarn", 4.0, extra.get("attention_factor"))
+        _compare(model, hf_cfg, seq=48)
+
+
 def test_unsupported_rope_scaling_rejected_at_load():
-    """yarn/longrope/dynamic must fail at config load, not inside the first
+    """longrope/dynamic must fail at config load, not inside the first
     forward's jit trace after weights are already in HBM."""
     hf_cfg = transformers.LlamaConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64,
         num_hidden_layers=1, num_attention_heads=2, num_key_value_heads=2,
-        rope_scaling={"rope_type": "yarn", "factor": 4.0},
+        rope_scaling={"rope_type": "dynamic", "factor": 4.0},
     )
     with pytest.raises(ValueError, match="unsupported rope_scaling"):
         from_hf_config(hf_cfg)

@@ -305,7 +305,7 @@ def test_published_config_builds_and_round_trips():
 
 @pytest.mark.parametrize("key,value", [("q_lora_rank", 1536), ("n_group", 8), ("topk_group", 4),
                                        ("rope_scaling", {"type": "yarn", "factor": 40}),
-                                       ("scoring_func", "softmax"), ("norm_topk_prob", False)])
+                                       ("scoring_func", "tanh"), ("norm_topk_prob", False)])
 def test_what_is_not_implemented_is_refused_by_name(key, value):
     cfg = dict(to_hf_dict(get_preset("moonlight_16b_a3b")), model_type="deepseek_v3", **{key: value})
     with pytest.raises(ValueError, match=key):

@@ -108,7 +108,15 @@ def test_untied_and_sliding_window_preset():
 _EVERY_4TH = lambda n: tuple(range(3, n, 4))  # noqa: E731
 _EVEN_ONLY = lambda w: (lambda i: w if i % 2 == 0 else None)  # noqa: E731
 _LEADING_DENSE = lambda i: "dense" if i == 0 else "grouped_experts"  # noqa: E731
+_SCALED = dict(rope_kind=lambda i: "scaled")  # Llama-3.1's context extension: every layer's rope
+# Mellum: three window layers with plain rope to one global layer with YaRN's; experts on every layer
+_MELLUM = lambda w: dict(  # noqa: E731
+    window=lambda i: None if i % 4 == 3 else w, rope_kind=lambda i: "scaled" if i % 4 == 3 else "plain",
+    feed_forward=lambda i: "grouped_experts",
+)
 PLAN_OF_PRESET = {
+    "llama3_1_8b": _SCALED, "llama3_2_1b": _SCALED, "llama3_2_3b": _SCALED,
+    "mellum2_12b_a2_5b": _MELLUM(1024), "tiny_mellum": _MELLUM(32),
     "tiny": dict(nope=_EVERY_4TH(4)),
     "smollm3_3b": dict(nope=_EVERY_4TH(36)),  # 3, 7, ..., 35
     "tiny_mistral": dict(window=lambda i: 64),  # Mistral: on all layers
@@ -124,11 +132,11 @@ PLAN_OF_PRESET = {
 @pytest.mark.parametrize("name", sorted(PRESETS))
 def test_layer_plan_of_every_preset(name):
     cfg = get_preset(name)
-    want = {"attention": "heads", "nope": (), "window": lambda i: None, "feed_forward": lambda i: "dense",
-            **PLAN_OF_PRESET.get(name, {})}
+    want = {"attention": "heads", "nope": (), "rope_kind": lambda i: "plain", "window": lambda i: None,
+            "feed_forward": lambda i: "dense", **PLAN_OF_PRESET.get(name, {})}
     for i in range(cfg.num_layers):
         assert cfg.layer(i) == LayerPlan(
-            attention=want["attention"], rope=i not in want["nope"],
+            attention=want["attention"], rope=i not in want["nope"], rope_kind=want["rope_kind"](i),
             window=want["window"](i), feed_forward=want["feed_forward"](i),
         ), (name, i)
     assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= 2  # hashable; no preset has more than two kinds

@@ -157,17 +157,81 @@ def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, mo
 
 
 def test_flash_says_no_past_its_vmem_cap(monkeypatch):
-    """One block past the largest admitted sequence the kernel is refused for
-    a stated reason, before the compiler is asked (so needs no topology)."""
+    """One block past the largest sequence whose query group the resident
+    dk/dv kernel holds, the streamed kernels take the call; what they cannot
+    hold either is refused for a stated reason, before the compiler is asked
+    (so needs no topology)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    def reason(seq):
+    def call(seq, window=None):
         q, k, v = (jax.ShapeDtypeStruct(s, d) for s, d in _qkv(2, seq))
-        return fa.flash_unsupported_reason(q, k, v)
+        return fa.flash_unsupported_reason(q, k, v, sliding_window=window), fa.program_label(q, k, v, sliding_window=window)
 
-    assert reason(MAX_FLASH_SEQ) is None
-    assert "VMEM" in reason(MAX_FLASH_SEQ + 512)
-    assert reason(1000) == "seq 1000 is not a multiple of 128"
+    assert call(MAX_FLASH_SEQ) == (None, "resident causal")
+    assert call(MAX_FLASH_SEQ + 512) == (None, "streamed causal")
+    assert call(1024, window=512) == (None, "streamed window 512")
+    assert call(1024, window=4096) == (None, "resident causal")  # a window as long as the row is none
+    assert call(1000)[0] == "seq 1000 is not a multiple of 128"
+    assert "sees no key" in call(1024, window=0)[0]
+    q, k, v = (jax.ShapeDtypeStruct(s, d) for s, d in _qkv(2, 1024))
+    assert fa.flash_unsupported_reason(q, k, v, causal=False) == "non-causal mask"
+    monkeypatch.setattr(fa, "_VMEM_CAP_BYTES", 8 * 1024 * 1024)
+    assert "streamed backward needs" in call(MAX_FLASH_SEQ)[0] and "VMEM" in call(1024, window=512)[0]
+
+
+# Mellum2-12B-A2.5B's attention, one row of the new cell: 32 query heads on 4
+# kv heads (8 queries a kv head) at 8192 positions, where the resident dk/dv
+# kernel would ask for 218 MiB
+MELLUM_QKV = (((1, 8192, 32, D), jnp.bfloat16), ((1, 8192, 4, D), jnp.bfloat16), ((1, 8192, 4, D), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window-1024", "global"])
+def test_streamed_flash_forward_backward_compiles_for_v5e(one_chip, window):
+    """The window layers' and the global layers' kernels, forward, dq and
+    dk/dv, inside the 100 MiB a kernel may ask for, each under its own name."""
+
+    def loss(q, k, v):
+        return fa.pallas_flash_attention(q, k, v, sliding_window=window).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *MELLUM_QKV).as_text()
+    kind = "window" if window else "causal"
+    for kernel in ("fwd", "dq", "dkv"):
+        assert f"flash_attention_{kind}_{kernel}" in text
+    assert text.count("tpu_custom_call") >= 3
+    band = fa._band(8192, 1024, window)  # the key says which shape was counted: another shape is another entry
+    assert fa.GRID_TILES[f"flash_attention_{kind}_dkv", band] == ((15, 36) if window else (36, 36))
+
+
+def test_step_with_window_and_global_layers_and_softmax_experts_compiles_for_v5e(topo, monkeypatch):
+    """One window layer and one global layer of Mellum2-12B-A2.5B at its
+    published widths (this chip's share: 16 of 64 experts, a quarter of the
+    vocabulary), every parameter trained, one row of 8192 a microbatch: both
+    kinds of layer run the streamed flash kernels (no ``[8192, 8192]`` scores
+    in the program), the window layer's forward kernel twice (recomputed: 1920
+    against the hidden 2304) and the global layer's once (kept), and the
+    grouped products and the kept routing are in the step."""
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "mellum2_12b_a2_5b",
+        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=1, param_dtype="bfloat16",
+        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
+        model_overrides=dict(num_layers=2, vocab_size=24576, held_experts=tuple(range(16)),
+                             layer_types=("sliding_attention", "full_attention")),
+    )
+    text = setup.compile().as_text()
+    mosaic_calls = lambda kernel: sum(  # noqa: E731
+        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
+    )
+    assert mosaic_calls("flash_attention_window_fwd") == 2 and mosaic_calls("flash_attention_causal_fwd") == 1
+    for kernel in ("window_dq", "window_dkv", "causal_dq", "causal_dkv"):
+        assert mosaic_calls(f"flash_attention_{kernel}") == 1
+    assert mosaic_calls("flash_attention_fwd") == 0  # no resident kernel at 8 queries a kv head and 8192
+    assert not re.search(r"\[[0-9,]*8192,8192\]", text), "a [seq, seq] buffer in the step"
+    assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
+    assert not again, again
 
 
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
