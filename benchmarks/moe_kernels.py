@@ -5,10 +5,15 @@ product (``jax.lax.ragged_dot`` or megablox ``gmm`` at several tilings) and
 which flash layout (q/k padded to 256 lanes, or 192 as it lies) is faster.
 Wall time of forward and forward+backward over ``--iters`` calls that end in
 ``block_until_ready``; a builder's tool, the benchmark never runs it.
+``--sum`` times what sums a chunk's rows back into their tokens instead
+(``ops/moe._sum_into_tokens``: the masked-gather loop against the kernel, at
+both expert cells' shapes, float32 and bfloat16 rows) and holds the kernel to
+the loop's bits on the chip.
 
-    chiprun -- python benchmarks/moe_kernels.py
+    chiprun -- python benchmarks/moe_kernels.py [--sum]
 """
 import argparse
+import functools
 import json
 import os
 import sys
@@ -106,16 +111,56 @@ def check_flash(seq=1024):
     print(json.dumps({"flash_vs_xla_rel_err": errs, "seq": seq}), flush=True)
 
 
+# (rows of a chunk, hidden, tokens a microbatch, choices, routed, held): the first chunk of each expert cell
+SUM_SHAPES = {"mellum2-12b-a2.5b-ep4-d4": (98304, 2304, 32768, 8, 64, 16), "moonlight-16b-a3b-ep8-d6": (16384, 2048, 16384, 6, 64, 8)}
+
+
+def sum_into_tokens(iters, seed=0):
+    """The loop and the kernel over one routing drawn as ``grouped_moe_mlp``
+    sorts it (k of the routed experts a token, uniformly), ms a call; the
+    kernel's least time is its bytes over the chip's 819 GB/s: the hits' rows
+    read once, the tokens' sums written once."""
+    rng = np.random.default_rng(seed)
+    for cell, (c, h, t, k, routed, held) in SUM_SHAPES.items():
+        top_i = np.argsort(rng.random((t, routed)), axis=1)[:, :k]
+        local = np.where(top_i < held, top_i, held).reshape(-1)
+        rank = np.argsort(np.argsort(local, kind="stable")).astype(np.int32).reshape(t, k)
+        ends = np.clip(np.cumsum((local[:, None] == np.arange(held)).sum(0)), 0, c).astype(np.int32)
+        rank, ends = jnp.asarray(rank), jnp.asarray(ends)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            rows = jax.random.normal(jax.random.key(seed), (c, h), dtype)
+            row = {"sum_into_tokens": cell, "rows": jnp.dtype(dtype).name, "pairs_a_token": round(int(ends[-1]) / t, 4),
+                   "refused": moe.sum_kernel_refused(rows, rank, ends)}
+            loop = jax.jit(functools.partial(moe._sum_into_tokens, impl="loop"))
+            row["loop_ms"] = timed(loop, (rows, rank, ends), iters)
+            if row["refused"] is None:
+                kernel = functools.partial(moe._sum_into_tokens, impl="kernel")
+                row["kernel_ms"] = timed(kernel, (rows, rank, ends), iters)
+                plan = jax.jit(functools.partial(moe._sum_plan, tile=moe.SUM_TILE, block=moe._block_rows(dtype)))
+                row["plan_ms"] = timed(plan, (rank, ends), iters)
+                first, blocks, _ = plan(rank, ends)
+                row["rows_fetched_a_row_used"] = round(int(blocks.sum()) * moe._block_rows(dtype) / int(ends[-1]), 3)
+                least = (int(ends[-1]) * h * jnp.dtype(dtype).itemsize + t * h * 4) / 819e9 * 1e3
+                row["least_ms"], row["share_of_least_pct"] = round(least, 3), round(100 * least / row["kernel_ms"], 1)
+                same = jnp.array_equal(*(jax.lax.bitcast_convert_type(f(rows, rank, ends), jnp.int32) for f in (loop, kernel)))
+                row["same_bits"] = bool(same)
+            print(json.dumps(row), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--rows", type=int, default=8192)
     ap.add_argument("--only-check", action="store_true", help="the flash kernels against XLA attention, nothing timed")
+    ap.add_argument("--sum", action="store_true", help="the expert layer's sum into tokens alone: loop against kernel")
     args = ap.parse_args()
     if not on_accelerator(jax.devices()[0].platform):
         print("moe_kernels: no accelerator; timings of a CPU are not rates", file=sys.stderr)
         return 2
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.sum:
+        sum_into_tokens(args.iters)
+        return 0
     check_flash()
     if args.only_check:
         return 0

@@ -5,7 +5,7 @@ v5e through ``observe/scaling.abstract_train_setup`` and one JSON line a step
 gives ``peak_memory_in_bytes`` (what has to fit; arguments + temporaries
 counts the donated state twice), the Mosaic calls by flash kernel (the
 resident ones and, by their own names, the streamed ones of window and global
-layers), whether any ``[seq, seq]`` scores are in the program, or the
+layers) and those of the expert layer's sum of rows into tokens, whether any ``[seq, seq]`` scores are in the program, or the
 compiler's refusal. Counts and the compiler's word, never a rate.
 
 The state is the cells': bfloat16 masters, Adam's moments float32 (optax
@@ -99,6 +99,8 @@ def main(names) -> int:
                     for kernel in (f"flash_attention_{kind}_{k}" for kind in ("window", "causal") for k in ("fwd", "dq", "dkv"))
                     if (n := sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text))
                 },
+                # the expert layer's sum of rows into tokens (ops/moe._sum_held_rows): 4 an expert layer, 2 of them behind the overflow cond
+                sum_held_rows=sum("tpu_custom_call" in ln and "/sum_held_rows/" in ln for ln in text),
                 seq_by_seq_buffers=sum(f",{seq},{seq}]" in ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in text),
             )
         line["compile_s"] = round(time.time() - started, 1)

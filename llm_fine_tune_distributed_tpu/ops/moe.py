@@ -35,12 +35,15 @@ per-expert torch Linears): ``block_sparse_moe/gate/kernel [h, E]``,
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from llm_fine_tune_distributed_tpu.config import ModelConfig
@@ -322,14 +325,23 @@ def grouped_matmul(lhs, rhs, group_sizes, *, impl=None):
     return gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype, tiling, interpret=impl == "gmm_interpret")
 
 
-# Moving rows between token order and sorted-pair order, both ways by GATHER.
-# A chunk holds C sorted pairs; ``tokens [C]`` is each pair's token and
-# ``rank [T, k]`` each (token, choice)'s row in the chunk (valid where
-# 0 <= rank < n_valid). Taking the pairs' rows out of the tokens and summing
-# the pairs' rows back into their tokens are each other's transposes, and each
-# is written as a gather with a mask; left to autodiff the transposes are row
-# scatters, which on a TPU cost several times the grouped products (PERF.md,
-# PR 26: 4.4 ms a scatter of 8192 rows against 1.7 ms for an expert's SwiGLU).
+# Moving rows between token order and sorted-pair order. A chunk holds C sorted
+# pairs; ``tokens [C]`` is each pair's token, ``rank [T, k]`` each (token,
+# choice)'s row in the chunk and ``ends [E]`` where each held expert's rows end
+# in it (a pair is in the chunk where 0 <= rank < ends[-1]). Taking the pairs'
+# rows out of the tokens and summing the pairs' rows back into their tokens are
+# each other's transposes; left to autodiff the transposes are row scatters,
+# which on a TPU cost several times the grouped products (PERF.md, PR 26: 4.4 ms
+# a scatter of 8192 rows against 1.7 ms for an expert's SwiGLU), so each is
+# written out:
+# - rows OUT of tokens (``take_rows`` forward, ``sum_rows`` backward): a GATHER
+#   with a mask, one row a sorted pair (``_rows_of_tokens``), everywhere.
+# - rows INTO tokens (``sum_rows`` forward, ``take_rows`` backward,
+#   ``_sum_into_tokens``): on a TPU ONE KERNEL that fetches only the rows of the
+#   pairs held here and writes each token's float32 sum once
+#   (``_sum_held_rows``); elsewhere, and for a shape the kernel refuses, k
+#   masked gathers of ``[T, h]``, one a choice, held here or not (``_sum_loop``,
+#   which is also what the tests hold the kernel to, to the bit).
 
 
 def _rows_of_tokens(xf, tokens, n_valid):
@@ -337,7 +349,7 @@ def _rows_of_tokens(xf, tokens, n_valid):
     return jnp.where(valid[:, None], xf[tokens], 0)
 
 
-def _sum_into_tokens(rows, rank, n_valid):
+def _sum_loop(rows, rank, n_valid):
     total = jnp.zeros((rank.shape[0], rows.shape[1]), jnp.float32)
     for j in range(rank.shape[1]):  # k gathers of [T, h]: never [T, k, h] at once
         r = rank[:, j]
@@ -346,37 +358,262 @@ def _sum_into_tokens(rows, rank, n_valid):
     return total
 
 
-@jax.custom_vjp
-def take_rows(xf, tokens, rank, n_valid):
-    """``xf [T, h]`` -> the chunk's rows ``[C, h]`` (zeros past ``n_valid``)."""
-    return _rows_of_tokens(xf, tokens, n_valid)
+# The kernel. Mosaic copies no single row of an array tiled (8, 128) ("slice
+# shape must be aligned to tiling", in HBM and in VMEM alike), and XLA's
+# relayout to rows that lie alone is a pass over the chunk of its own. But the
+# sort is stable, so the rows of ONE expert lie in the order of their tokens,
+# and what a tile of consecutive tokens holds of an expert is ONE RUN of
+# consecutive rows: a tile's rows are E runs, fetched whole in blocks of
+# ``_block_rows`` rows, each block once, 1.2 to 1.5 rows read a row used.
+# ``_sum_plan`` works the runs out in XLA from ``rank`` and ``ends`` alone (a
+# few passes over ``[E, T * k]`` integers); the kernel copies a tile's blocks
+# HBM -> VMEM (the next tile's under this tile's adds), lays each block out
+# again so that a row fills whole registers (a strided store a 128 columns),
+# adds a token's rows in ascending choice as the loop does (a choice held
+# elsewhere is skipped: the total is never -0.0, so the loop's + 0.0 changes
+# no bit), and lays 8 tokens' sums out as a block of the output.
+SUM_TILE = 128  # tokens a grid step
+_LANES, _SUBLANES = 128, 8
+
+# {(rows' shape, rows' dtype name, rank's shape, E): "kernel" or "loop: <why>"}
+# of every ``_sum_into_tokens`` traced in this process: which program the
+# expert layer's sum took (as ``flash_attention.GRID_TILES`` says what a grid
+# was built to visit). ``sum_programs_summary()`` is the line entry points print.
+SUM_PROGRAMS: dict = {}
 
 
-def _take_rows_fwd(xf, tokens, rank, n_valid):
-    return _rows_of_tokens(xf, tokens, n_valid), (tokens, rank, n_valid, jnp.zeros((0,), xf.dtype))
+def _block_rows(dtype) -> int:
+    """Rows one copy moves: a tile of the rows' dtype (8 float32, 16 bfloat16)."""
+    return _SUBLANES * 4 // jnp.dtype(dtype).itemsize
 
 
-def _take_rows_bwd(res, d):
-    tokens, rank, n_valid, like = res
-    return _sum_into_tokens(d, rank, n_valid).astype(like.dtype), None, None, None
+def _row_stride(h) -> int:
+    """Registers' rows (of 128 lanes) a row of ``h`` takes once it lies alone: whole registers of 8."""
+    return -(-(h // _LANES) // _SUBLANES) * _SUBLANES
+
+
+def _sum_room(tile, k, runs, block):
+    """Rows a tile's fetched blocks can fill: a run of L rows touches at most
+    L / block + 2 blocks (cut at both ends), and the runs' rows are at most
+    the tile's pairs."""
+    return (-(-tile * k // block) + 2 * runs) * block
+
+
+def _sum_vmem_bytes(h, k, runs, dtype, tile=SUM_TILE):
+    """What ``_sum_held_rows`` asks for: two tiles' fetched blocks as they land, one tile's rows and
+    sums lying alone, the output's two blocks, and 2 MiB of room."""
+    room = _sum_room(tile, k, runs, _block_rows(dtype))
+    landed = 2 * room * h * jnp.dtype(dtype).itemsize
+    alone = (room + tile) * _row_stride(h) * _LANES * 4
+    return landed + alone + 2 * tile * h * 4 + 2 * 2**20
+
+
+def sum_kernel_refused(rows, rank, ends):
+    """Why the kernel does not take these shapes (None: it does); the choice
+    is made from what the call can observe, never a rescue from a kernel that
+    failed to compile."""
+    (c, h), (_, k) = rows.shape, rank.shape
+    if jnp.dtype(rows.dtype).itemsize not in (2, 4) or not jnp.issubdtype(rows.dtype, jnp.floating):
+        return f"rows of {jnp.dtype(rows.dtype).name}"
+    if h % _LANES:
+        return f"hidden size {h} is not a multiple of {_LANES}"
+    if c % _block_rows(rows.dtype):
+        return f"a chunk of {c} rows is not whole blocks of {_block_rows(rows.dtype)}"
+    from llm_fine_tune_distributed_tpu.ops.flash_attention import _VMEM_CAP_BYTES  # one cap for every kernel of the chip
+
+    need = _sum_vmem_bytes(h, k, ends.shape[0], rows.dtype)
+    if need > _VMEM_CAP_BYTES:
+        return f"needs {need >> 20} MiB of VMEM, a kernel may ask for {_VMEM_CAP_BYTES >> 20}"
+    return None
+
+
+def sum_programs_summary() -> str:
+    """One line for entry points to print beside ``dispatch_summary()``."""
+    said = "; ".join(
+        f"rows {dtype}{list(rows)} into tokens {list(rank)[:1]} of {rank[1]} choices, {runs} experts held: {program}"
+        for (rows, dtype, rank, runs), program in sorted(SUM_PROGRAMS.items())
+    )
+    return f"expert rows summed into tokens by: {said or 'nothing traced'}"
+
+
+def _sum_plan(rank, ends, tile, block):
+    """Which blocks each tile of tokens fetches and where each hit's row then
+    lies: ``(first [E, tiles], blocks [E, tiles], pos [tiles * tile, k])``.
+    Run e of a tile is its hits in ``[ends[e - 1], ends[e])``, consecutive
+    rows; it is fetched as ``blocks`` blocks from row ``first`` (a multiple of
+    ``block``) into the tile's buffer behind the runs before it, and ``pos``
+    is a hit's row in that buffer, -1 for a choice not held in this chunk.
+    (The runs lead every array, so that a tile's pairs lie along the lanes.)"""
+    t, k = rank.shape
+    runs, tiles = ends.shape[0], -(-t // tile)
+    flat = jnp.pad(rank, ((0, tiles * tile - t), (0, 0)), constant_values=-1).reshape(tiles, tile * k)
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    of_run = (flat >= starts[:, None, None]) & (flat < ends[:, None, None])              # [E, tiles, tile * k]; a miss is of none
+    lo = jnp.where(of_run, flat, jnp.iinfo(jnp.int32).max).min(-1)                       # [E, tiles]
+    hi = jnp.where(of_run, flat + 1, 0).max(-1)
+    first = jnp.where(hi > lo, lo // block, 0)
+    blocks = jnp.where(hi > lo, (hi - 1) // block - first + 1, 0)
+    before = jnp.cumsum(blocks, axis=0) - blocks
+    # (a rank that no stable sort by expert made could ask for more than the buffer holds: no copy past its end)
+    blocks = jnp.minimum(blocks, jnp.maximum(_sum_room(tile, k, runs, block) // block - before, 0))
+    shift = (before - first) * block                                                     # a hit's row in the buffer - its rank
+    pos = jnp.where(of_run.any(0), flat + jnp.where(of_run, shift[:, :, None], 0).sum(0), -1)
+    return (first * block).astype(jnp.int32), blocks.astype(jnp.int32), pos.reshape(tiles * tile, k).astype(jnp.int32)
+
+
+def _sum_held_rows_body(first_ref, blocks_ref, pos_ref, rows_ref, out_ref, landed, as_rows, sums, count, sems,
+                        *, tile, k, k_pad, runs, block):
+    i, steps = pl.program_id(0), pl.num_programs(0)
+    chunks, stride = out_ref.shape[1] // _LANES, _row_stride(out_ref.shape[1])
+    whole = pl.multiple_of
+
+    def fetch(step, slot):  # start the copies of a tile's blocks, run behind run, and count them
+        def run(e, filled):
+            first, n = whole(first_ref[e * steps + step], block), blocks_ref[e * steps + step]
+
+            def copy(b, carry):
+                src = rows_ref.at[pl.ds(whole(first + b * block, block), block)]
+                pltpu.make_async_copy(src, landed.at[slot, pl.ds(whole((filled + b) * block, block), block)], sems.at[slot]).start()
+                return carry
+
+            jax.lax.fori_loop(0, n, copy, 0)
+            return filled + n
+
+        count[slot] = jax.lax.fori_loop(0, runs, run, jnp.int32(0))
+
+    @pl.when(i == 0)
+    def _():
+        fetch(0, 0)
+
+    @pl.when(i + 1 < steps)
+    def _():
+        fetch(i + 1, (i + 1) % 2)
+
+    slot = i % 2
+
+    def wait(b, carry):  # every copy moves one block: any block's descriptor waits for one of them
+        pltpu.make_async_copy(rows_ref.at[pl.ds(0, block)], landed.at[slot, pl.ds(0, block)], sems.at[slot]).wait()
+        return carry
+
+    jax.lax.fori_loop(0, count[slot], wait, 0)
+
+    def rows_alone(b, carry):  # a landed block holds its rows on the sublanes; row r, columns 128 ch.. -> as_rows[r * stride + ch]
+        at, to = whole(b * block, block), whole(b * block * stride, _SUBLANES)
+        for ch in range(chunks):
+            part = landed[slot, pl.ds(at, block), ch * _LANES:(ch + 1) * _LANES].astype(jnp.float32)
+            as_rows[pl.ds(to + ch, block, stride=stride), :] = part
+        return carry
+
+    jax.lax.fori_loop(0, count[slot], rows_alone, 0)
+
+    def token(t, carry):  # a token's held rows, whole registers each, added in ascending choice
+        total = jnp.zeros((stride, _LANES), jnp.float32)
+        for j in range(k):
+            p = pos_ref[t * k_pad + j]
+            total = jax.lax.cond(
+                p >= 0, lambda p=p, total=total: total + as_rows[pl.ds(whole(p * stride, _SUBLANES), stride), :],
+                lambda total=total: total,
+            )
+        sums[pl.ds(whole(t * stride, _SUBLANES), stride), :] = total
+        return carry
+
+    jax.lax.fori_loop(0, tile, token, 0)
+
+    def tokens_as_block(g, carry):  # 8 tokens' sums back onto the sublanes of one block of the output
+        at, to = whole(g * _SUBLANES * stride, _SUBLANES), whole(g * _SUBLANES, _SUBLANES)
+        for ch in range(chunks):
+            out_ref[pl.ds(to, _SUBLANES), ch * _LANES:(ch + 1) * _LANES] = sums[pl.ds(at + ch, _SUBLANES, stride=stride), :]
+        return carry
+
+    jax.lax.fori_loop(0, tile // _SUBLANES, tokens_as_block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _sum_held_rows(rows, rank, ends, *, tile=SUM_TILE, interpret=False):
+    h, (t, k), runs = rows.shape[1], rank.shape, ends.shape[0]
+    block = _block_rows(rows.dtype)
+    first, blocks, pos = _sum_plan(rank, ends, tile, block)
+    k_pad = -(-k // 8) * 8  # an SMEM block of tile * k_pad integers is whole tiles of 1024
+    pos = jnp.pad(pos, ((0, 0), (0, k_pad - k)), constant_values=-1).reshape(-1)
+    room, stride = _sum_room(tile, k, runs, block), _row_stride(h)
+    return pl.pallas_call(
+        functools.partial(_sum_held_rows_body, tile=tile, k=k, k_pad=k_pad, runs=runs, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(first.shape[1],),
+            in_specs=[
+                pl.BlockSpec((tile * k_pad,), lambda i, *_: (i,), memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((tile, h), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, room, h), rows.dtype),
+                pltpu.VMEM((room * stride, _LANES), jnp.float32),
+                pltpu.VMEM((tile * stride, _LANES), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t, h), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_sum_vmem_bytes(h, k, runs, rows.dtype, tile),
+            dimension_semantics=("arbitrary",),  # a step fetches for the next one
+        ),
+        interpret=interpret,
+        name="sum_held_rows",
+    )(first.reshape(-1), blocks.reshape(-1), pos, rows)
+
+
+def _sum_into_tokens(rows, rank, ends, impl=None):
+    """``rows [C, h]`` summed into their tokens, ``[T, h]`` float32: token t
+    gets ``sum_j rows[rank[t, j]]`` over the choices j with ``0 <= rank[t, j]
+    < ends[-1]``, added in ascending j. ``impl``: ``"loop"``, ``"kernel"`` or
+    ``"kernel_interpret"`` (the kernel under the Pallas interpreter); None
+    chooses as ``grouped_matmul`` does, the kernel on a TPU where the shapes
+    allow it, and records the choice in ``SUM_PROGRAMS``."""
+    refused = sum_kernel_refused(rows, rank, ends)
+    if impl is None:
+        why = "backend is " + jax.default_backend() if jax.default_backend() != "tpu" else refused
+        impl = "loop" if why else "kernel"
+        SUM_PROGRAMS[rows.shape, jnp.dtype(rows.dtype).name, rank.shape, ends.shape[0]] = impl + (f": {why}" if why else "")
+    if impl == "loop":
+        return _sum_loop(rows, rank, ends[-1])
+    if refused:
+        raise ValueError(f"the kernel that sums rows into tokens was asked for by name and refuses: {refused}")
+    return _sum_held_rows(rows, rank, ends, interpret=impl == "kernel_interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def take_rows(xf, tokens, rank, ends, sum_impl=None):
+    """``xf [T, h]`` -> the chunk's rows ``[C, h]`` (zeros past ``ends[-1]``)."""
+    return _rows_of_tokens(xf, tokens, ends[-1])
+
+
+def _take_rows_fwd(xf, tokens, rank, ends, sum_impl):
+    return _rows_of_tokens(xf, tokens, ends[-1]), (rank, ends, jnp.zeros((0,), xf.dtype))
+
+
+def _take_rows_bwd(sum_impl, res, d):
+    rank, ends, like = res
+    return _sum_into_tokens(d, rank, ends, sum_impl).astype(like.dtype), None, None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-@jax.custom_vjp
-def sum_rows(rows, tokens, rank, n_valid):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def sum_rows(rows, tokens, rank, ends, sum_impl=None):
     """The chunk's float32 rows ``[C, h]`` summed into their tokens, ``[T, h]``."""
-    return _sum_into_tokens(rows, rank, n_valid)
+    return _sum_into_tokens(rows, rank, ends, sum_impl)
 
 
-def _sum_rows_fwd(rows, tokens, rank, n_valid):
-    return _sum_into_tokens(rows, rank, n_valid), (tokens, n_valid)
+def _sum_rows_fwd(rows, tokens, rank, ends, sum_impl):
+    return _sum_into_tokens(rows, rank, ends, sum_impl), (tokens, ends)
 
 
-def _sum_rows_bwd(res, d):
-    tokens, n_valid = res
-    return _rows_of_tokens(d, tokens, n_valid), None, None, None
+def _sum_rows_bwd(sum_impl, res, d):
+    tokens, ends = res
+    return _rows_of_tokens(d, tokens, ends[-1]), None, None, None
 
 
 sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
@@ -421,7 +658,7 @@ def pairs_a_chunk(config: ModelConfig) -> int:
     return min(min(k, held), max(1, math.ceil(1.25 * k * held / config.n_routed_experts)))
 
 
-def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
+def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None, sum_impl=None):
     """Routed part of an expert layer without capacity (DeepSeek-V3, Mellum),
     for the experts held here. ``x [b, s, h]`` -> ``(y [b, s, h], load
     [E_held] int32)``.
@@ -437,7 +674,9 @@ def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
     products; the first chunk covers the expected load with room above it,
     further chunks run only when the routing fills them, so the work follows
     the pairs and not tokens x experts. ``load[e]`` counts the pairs of held
-    expert e (the step's counter)."""
+    expert e (the step's counter). ``impl`` chooses the grouped product
+    (``grouped_matmul``), ``sum_impl`` what sums a chunk's rows back into
+    their tokens (``_sum_into_tokens``); both are for the tests."""
     from llm_fine_tune_distributed_tpu.observe.xla import scope
 
     b, s, h = x.shape
@@ -470,18 +709,19 @@ def grouped_moe_mlp(lp, x, config: ModelConfig, compute_dtype, *, impl=None):
         ``first`` chunk names its rows: a block's policy reaches a name through
         the ``cond`` and the loop below, and the further chunks' rows do not fit."""
         in_chunk = lambda edge: jnp.clip(edge - start, 0, rows_a_chunk)  # noqa: E731
-        sizes = in_chunk(ends) - in_chunk(ends - load)
-        n_valid = in_chunk(ends[-1])
+        chunk_ends = in_chunk(ends)  # where each held expert's rows end in this chunk
+        sizes = chunk_ends - in_chunk(ends - load)
+        n_valid = chunk_ends[-1]
         chunk_tokens = jax.lax.dynamic_slice(tokens, (start,), (rows_a_chunk,))
         chunk_rank = rank.reshape(t, k) - start
-        xin = take_rows(xf, chunk_tokens, chunk_rank, n_valid)
+        xin = take_rows(xf, chunk_tokens, chunk_rank, chunk_ends, sum_impl)
         if first:
             xin = checkpoint_name(xin, "moe_rows")
         rows = _expert_rows(
             lp["experts"], xin,
             jax.lax.dynamic_slice(weights, (start,), (rows_a_chunk,)), sizes, n_valid, compute_dtype, impl,
         )
-        return sum_rows(rows, chunk_tokens, chunk_rank, n_valid)
+        return sum_rows(rows, chunk_tokens, chunk_rank, chunk_ends, sum_impl)
 
     with scope("experts"):
         # every pair held here lies in the first min(k, n_held) * t sorted rows

@@ -1181,6 +1181,7 @@ class SFTTrainer:
                         # the step program is traced now: say which attention
                         # path it holds (a flash request that took XLA
                         # attention names its reason) and on what it runs
+                        from llm_fine_tune_distributed_tpu.ops import moe
                         from llm_fine_tune_distributed_tpu.ops.attention import (
                             dispatch_summary,
                         )
@@ -1190,6 +1191,8 @@ class SFTTrainer:
                             f"{jax.default_backend()}; {dispatch_summary()}",
                             flush=True,
                         )
+                        if moe.SUM_PROGRAMS:  # routed experts: kernel or loop, and why
+                            print(f"[train] {moe.sum_programs_summary()}", flush=True)
                     pending_samples += samples_per_step
                     # real-token accounting for the throughput meter: a host
                     # numpy mean over the loader's (pre-device) mask — cheap

@@ -754,3 +754,132 @@ def test_moe_seq_axis_with_expert_axis_matches_unsharded(eight_devices):
         )(params_sharded, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-4)
     np.testing.assert_allclose(float(report["router_aux"]), float(aux_ref), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The routed experts' sum of rows back into tokens (ops/moe._sum_into_tokens):
+# the kernel, under the Pallas interpreter, against the masked-gather loop
+# ---------------------------------------------------------------------------
+
+
+def _sorted_routing(rng, tokens, k, routed, held, rows_a_chunk, start):
+    """``rank [T, k]`` and ``ends [held]`` of one chunk as ``grouped_moe_mlp``
+    makes them (a stable sort of the pairs by held expert, pairs held elsewhere
+    last; the chunk is sorted rows [start, start + rows_a_chunk)). A third of the
+    tokens choose held experts only (all k where ``held >= k``), a third none,
+    the rest as drawn."""
+    top_i = np.stack([rng.permutation(routed)[:k] for _ in range(tokens)])
+    third = tokens // 3
+    top_i[:third] = np.stack([rng.permutation(held)[:k] if held >= k else top_i[i] for i in range(third)])
+    if routed - held >= k:
+        top_i[third:2 * third] = held + np.stack([rng.permutation(routed - held)[:k] for _ in range(third)])
+    local = np.where(top_i < held, top_i, held).reshape(-1)
+    rank = np.argsort(np.argsort(local, kind="stable")).astype(np.int32).reshape(tokens, k)
+    ends = np.cumsum((local[:, None] == np.arange(held)).sum(0))
+    return jnp.asarray(rank - start), jnp.asarray(np.clip(ends - start, 0, rows_a_chunk).astype(np.int32))
+
+
+@pytest.mark.parametrize(
+    "dtype, tokens, k, routed, held, rows_a_chunk, start",
+    [
+        (jnp.float32, 48, 8, 16, 8, 192, 0),     # some tokens hold all 8 of their choices, some none
+        (jnp.bfloat16, 48, 8, 16, 8, 192, 0),    # the backward's rows: blocks of 16, cast in the kernel
+        (jnp.float32, 48, 6, 16, 4, 96, 0),      # k 6; no token can hold more than 4
+        (jnp.bfloat16, 48, 6, 16, 8, 96, 0),     # k 6, the chunk cuts the held pairs (n_valid = its 96 rows)
+        (jnp.float32, 48, 8, 16, 8, 64, 64),     # an overflow chunk: ranks before it are negative, ranks past it miss
+        (jnp.bfloat16, 48, 8, 16, 8, 64, 128),   # a later one, cut at both ends
+        (jnp.float32, 37, 8, 16, 8, 296, 0),     # T no multiple of the tile, every held pair in one chunk
+        (jnp.float32, 48, 8, 16, 16, 384, 0),    # every expert held: k rows a token
+        (jnp.float32, 48, 4, 16, 4, 48, 10_000), # a chunk no pair reaches: zeros
+    ],
+    ids=["f32-k8", "bf16-k8", "f32-k6", "bf16-k6-cut", "f32-overflow", "bf16-overflow-cut", "f32-ragged-T", "f32-all-held", "f32-empty"],
+)
+def test_sum_kernel_equals_the_masked_gather_loop_to_the_bit(dtype, tokens, k, routed, held, rows_a_chunk, start):
+    """Same pairs, same float32 adds in the same order: the same bits (a
+    choice the kernel skips adds + 0.0 in the loop, and the total is never
+    -0.0, so not even a zero's sign differs)."""
+    from llm_fine_tune_distributed_tpu.ops import moe
+
+    rng = np.random.default_rng(tokens * k + held + start)
+    rank, ends = _sorted_routing(rng, tokens, k, routed, held, rows_a_chunk, start)
+    rows = jnp.asarray(rng.normal(size=(rows_a_chunk, 256)), dtype)
+    want = moe._sum_into_tokens(rows, rank, ends, impl="loop")
+    hits = np.asarray((rank >= 0) & (rank < ends[-1])).sum(1)
+    if start == 0 and held >= k and int(ends[-1]) < rows_a_chunk:  # (a chunk filled to its end cuts the pairs)
+        assert hits.min() == 0 and hits.max() == k  # tokens with none and with all k of their choices held
+    for tile in (16, moe.SUM_TILE):  # three tiles (the last ragged), and the tile the step runs (one, ragged)
+        got = moe._sum_held_rows(rows, rank, ends, tile=tile, interpret=True)
+        assert got.dtype == jnp.float32 and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got).view(np.int32), np.asarray(want).view(np.int32))
+    assert float(jnp.abs(want).max()) > 0 or start == 10_000
+
+
+def test_sum_into_tokens_records_which_program_it_took(monkeypatch):
+    """One entry a traced shape (as ``flash_attention.GRID_TILES``): the loop
+    off a TPU and why, the kernel on one where the shapes allow, the loop and
+    the kernel's refusal where they do not; a kernel asked for by name on a
+    shape it refuses raises. ``sum_programs_summary()`` is the line the
+    trainer prints beside ``dispatch_summary()``."""
+    from llm_fine_tune_distributed_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "SUM_PROGRAMS", {})
+    shapes = lambda c, h, dtype=jnp.float32: (  # noqa: E731
+        jax.ShapeDtypeStruct((c, h), dtype), jax.ShapeDtypeStruct((64, 8), jnp.int32), jax.ShapeDtypeStruct((16,), jnp.int32))
+    jax.eval_shape(moe._sum_into_tokens, *shapes(192, 256))
+    assert moe.SUM_PROGRAMS == {((192, 256), "float32", (64, 8), 16): "loop: backend is cpu"}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.sum_kernel_refused(*shapes(98304, 2304)) is None and moe.sum_kernel_refused(*shapes(16384, 2048, jnp.bfloat16)) is None
+    jax.eval_shape(moe._sum_into_tokens, *shapes(192, 192))
+    jax.eval_shape(moe._sum_into_tokens, *shapes(200, 256, jnp.bfloat16))
+    jax.eval_shape(moe._sum_into_tokens, *shapes(192, 256, jnp.int32))
+    jax.eval_shape(moe._sum_into_tokens, *shapes(192, 65536))
+    took = {key[:2]: program for key, program in moe.SUM_PROGRAMS.items()}
+    assert took[(192, 192), "float32"] == "loop: hidden size 192 is not a multiple of 128"
+    assert took[(200, 256), "bfloat16"] == "loop: a chunk of 200 rows is not whole blocks of 16"
+    assert took[(192, 256), "int32"] == "loop: rows of int32"
+    assert took[(192, 65536), "float32"].startswith("loop: needs ") and "MiB of VMEM" in took[(192, 65536), "float32"]
+    with pytest.raises(ValueError, match="refuses: hidden size 192"):
+        jax.eval_shape(lambda *a: moe._sum_into_tokens(*a, impl="kernel"), *shapes(192, 192))
+    said = moe.sum_programs_summary()
+    assert said.startswith("expert rows summed into tokens by: ") and "loop: backend is cpu" in said and said.count(";") == 4
+    monkeypatch.setattr(moe, "SUM_PROGRAMS", {})
+    assert moe.sum_programs_summary().endswith("nothing traced")
+
+
+@pytest.mark.parametrize("preset, pulled", [("tiny_mla_moe", ()), ("tiny_mla_moe", (0, 1, 2)), ("tiny_mellum", ()), ("tiny_mellum", (0, 1, 2, 3))],
+                         ids=["moonlight-as-drawn", "moonlight-all-k-held", "mellum-as-drawn", "mellum-all-k-held"])
+def test_grouped_experts_with_the_sum_kernel_equal_the_loop(preset, pulled):
+    """The routed experts' output and gradients (input, router, experts) with
+    both sums by the kernel (interpreter: ``sum_rows`` forward on float32
+    rows, ``take_rows`` backward) against the ``ragged_dot`` and loop path,
+    at the tiny Moonlight and Mellum presets the suite uses, the hidden size
+    one register's 128 lanes (the kernel refuses their 64); as drawn, and
+    with a router that sends every token to k held experts, so that the
+    overflow chunks behind the ``lax.cond`` run the kernel too (``rank -
+    start`` negative there). Same bits: run operation by operation, as one
+    program XLA may fuse the two differently."""
+    from llm_fine_tune_distributed_tpu.ops import moe
+
+    config = get_preset(preset).replace(hidden_size=128)
+    lp = moe.init_grouped_moe_params(jax.random.PRNGKey(0), config, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, config.hidden_size), jnp.float32)
+    if pulled:
+        push = np.zeros((config.hidden_size, config.n_routed_experts), np.float32)
+        push[:, list(pulled)] = 1.0
+        lp["gate"]["kernel"] = lp["gate"]["kernel"] + jnp.asarray(push)
+        x = jnp.abs(x)
+
+    def run(sum_impl):
+        loss = lambda lp, x: (moe.grouped_moe_mlp(lp, x, config, jnp.float32, sum_impl=sum_impl)[0] ** 2).sum()  # noqa: E731
+        (y, load), grads = moe.grouped_moe_mlp(lp, x, config, jnp.float32, sum_impl=sum_impl), jax.grad(loss, argnums=(0, 1))(lp, x)
+        return y, load, grads
+
+    y, load, grads = run("kernel_interpret")
+    want_y, want_load, want_grads = run("loop")
+    if pulled:  # every pair of every token is held here: more chunks than the first
+        assert int(load.sum()) == x.shape[0] * x.shape[1] * config.num_experts_per_tok > moe.pairs_a_chunk(config) * 64
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want_y))
+    assert float(jnp.abs(want_y).max()) > 0
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

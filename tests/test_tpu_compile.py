@@ -122,6 +122,48 @@ def test_flash_with_wider_query_and_key_heads_compiles_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
 
 
+# the first chunk of each expert cell, one microbatch: (rows of the chunk, hidden, rows' dtype, tokens, choices, experts held)
+SUM_SHAPES = [
+    (98304, 2304, jnp.float32, 32768, 8, 16), (98304, 2304, jnp.bfloat16, 32768, 8, 16),  # Mellum: sum_rows forward, take_rows backward
+    (16384, 2048, jnp.float32, 16384, 6, 8), (16384, 2048, jnp.bfloat16, 16384, 6, 8),     # Moonlight
+]
+
+
+@pytest.mark.parametrize("rows, hidden, dtype, tokens, k, held", SUM_SHAPES,
+                         ids=["mellum-f32", "mellum-bf16", "moonlight-f32", "moonlight-bf16"])
+def test_sum_kernel_compiles_for_v5e(one_chip, rows, hidden, dtype, tokens, k, held):
+    """The kernel that sums a chunk's rows into their tokens, at both expert
+    cells' shapes, inside the VMEM it asks for (its copies move whole blocks of
+    8 float32 or 16 bfloat16 rows: Mosaic refuses a copy of one row of a tiled
+    array; the plan around the call is XLA's)."""
+    from llm_fine_tune_distributed_tpu.ops import moe
+
+    shapes = (((rows, hidden), dtype), ((tokens, k), jnp.int32), ((held,), jnp.int32))
+    assert moe.sum_kernel_refused(*(jax.ShapeDtypeStruct(s, d) for s, d in shapes)) is None
+    assert moe._sum_vmem_bytes(hidden, k, held, dtype) <= 48 * 2**20
+    text = _compile(lambda *a: moe._sum_into_tokens(*a, impl="kernel"), one_chip, *shapes).as_text()
+    assert "sum_held_rows" in text and text.count("tpu_custom_call") >= 1
+
+
+def _sum_kernel_calls(text):
+    """The paths (``op_name``) of the Mosaic calls that sum rows into tokens."""
+    found = (re.search(r'op_name="([^"]*/sum_held_rows/pallas_call)"', ln) for ln in text.splitlines() if "tpu_custom_call" in ln)
+    return [m.group(1) for m in found if m]
+
+
+def _assert_two_sums_an_expert_layer(text, expert_layers):
+    """One call forward (``sum_rows``) and one backward (``take_rows``'
+    transpose) an expert layer for the first chunk, the same two again inside
+    the overflow chunks' ``cond``, and none recomputed: the block's last
+    operation is dead in the recompute."""
+    calls = _sum_kernel_calls(text)
+    assert len(calls) == 4 * expert_layers, calls
+    first_chunk = [c for c in calls if "/cond/" not in c]
+    assert len(first_chunk) == 2 * expert_layers and sum("transpose(" in c for c in first_chunk) == expert_layers
+    assert not [c for c in calls if "rematted_computation" in c]
+    assert all("/mlp/experts/" in c and "gmm" not in c and "flash_attention" not in c for c in calls)
+
+
 def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, monkeypatch):
     """The dense layer and one expert layer of Moonlight-16B-A3B at its
     published widths (this chip's share: 8 of 64 experts, an eighth of the
@@ -150,6 +192,7 @@ def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, mo
     assert mosaic_calls("flash_attention_fwd") == setup.model_config.num_layers
     assert mosaic_calls("flash_attention_dq") == mosaic_calls("flash_attention_dkv") == setup.model_config.num_layers
     assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers - 1)
     # and each expert layer keeps its routing and gathered rows: the backward
     # pass holds no second router product, selection or sort (tests/test_moe_remat.py)
     again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
@@ -230,6 +273,7 @@ def test_step_with_window_and_global_layers_and_softmax_experts_compiles_for_v5e
     assert mosaic_calls("flash_attention_fwd") == 0  # no resident kernel at 8 queries a kv head and 8192
     assert not re.search(r"\[[0-9,]*8192,8192\]", text), "a [seq, seq] buffer in the step"
     assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers)
     again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
     assert not again, again
 
