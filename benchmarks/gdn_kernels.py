@@ -1,0 +1,190 @@
+#!/usr/bin/env python3
+"""A linear-attention mixer's own operations alone, on the chip, at the shapes
+of ``qwen3-next-80b-a3b-ep16-d4.sft-8k-linear-allparams`` (2 rows of 8192,
+hidden 2048, 16 key and 32 value heads of 128, bfloat16); a builder's tool,
+the benchmark never runs it. Wall time over ``--iters`` calls that end in
+``block_until_ready``.
+
+``--only rule``: the gated delta rule (``ops/gated_delta.gated_delta_rule``),
+forward and forward+backward, by chunk (64 or 128) and by the way to the
+triangular inverse: ``solve`` (the library's ``unit_lower_inverse``: XLA's
+triangular solve against the identity) or doublings ``(I - A)(I + A^2)(I +
+A^4)...`` at ``HIGHEST``, ``HIGH`` or default precision (defined here: the
+library keeps ONE way, the one this tool found ahead; PERF.md, PR 32), with
+each variant's distance from the token-by-token recurrence in float32 at 512
+tokens.
+
+``--only mixer``: the whole mixer (``models/transformer._linear_mixer``:
+projections, convolution, silu, l2 norms, the rule, the gated norm),
+forward+backward to its input and every leaf, by the convolution: ``shifts``
+(the library's ``causal_conv``: taps as shifted multiply-adds, each slice cast
+on its own, the backward pass written out), ``shifts_by_autodiff`` (the same
+over a float32 padded copy, differentiated by JAX) or ``lax_conv``
+(``lax.conv_general_dilated`` with one group a channel), both defined here.
+
+    chiprun -- python benchmarks/gdn_kernels.py [--only rule|mixer]
+"""
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.chipbench import reference_gdn_moe
+from llm_fine_tune_distributed_tpu.ops import gated_delta as gd
+from llm_fine_tune_distributed_tpu.runtime.device import on_accelerator
+
+
+def timed(fn, args, iters):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3
+
+
+def inputs(rows, seq, hk, hv, d, dtype):
+    ks = jax.random.split(jax.random.key(0), 6)
+    q = (gd.l2_norm(jax.random.normal(ks[0], (rows, seq, hk, d))) * d ** -0.5).astype(dtype)
+    k = gd.l2_norm(jax.random.normal(ks[1], (rows, seq, hk, d))).astype(dtype)
+    v = jax.random.normal(ks[2], (rows, seq, hv, d)).astype(dtype)
+    a = jax.random.uniform(ks[3], (hv,), minval=0.02, maxval=2.0)
+    g = -a * jax.nn.softplus(jax.random.normal(ks[4], (rows, seq, hv)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (rows, seq, hv)))
+    return q, k, v, g, beta
+
+
+def by_doublings(precision):
+    """``(I + a)^-1`` as ``(I - a)(I + a^2)(I + a^4)...`` up to the power C / 2 (a is nilpotent), its
+    derivative written out (``-T^T dT T^T``: autodiff of the doublings would hold every power of a)."""
+    mm = functools.partial(jnp.matmul, precision=precision)
+
+    @jax.custom_vjp
+    def inverse(a):
+        n = a.shape[-1]
+        out = jnp.eye(n, dtype=a.dtype) - a
+        power, reach = mm(a, a), 2  # a^reach; out covers the powers below reach
+        while reach < n:
+            out = out + mm(out, power)
+            reach *= 2
+            if reach < n:
+                power = mm(power, power)
+        return out
+
+    def fwd(a):
+        t = inverse(a)
+        return t, t
+
+    def bwd(t, dt):
+        tt = jnp.swapaxes(t, -1, -2)
+        return (-mm(mm(tt, dt), tt),)
+
+    inverse.defvjp(fwd, bwd)
+    return inverse
+
+
+def lax_conv(x, weight):
+    """``causal_conv`` as one grouped convolution, float32 inside."""
+    taps, channels = weight.shape
+    y = jax.lax.conv_general_dilated(
+        x.astype(jnp.float32), weight.astype(jnp.float32)[:, None, :], window_strides=(1,), padding=[(taps - 1, 0)],
+        dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=channels,
+    )
+    return y.astype(x.dtype)
+
+
+def by_autodiff(x, weight):
+    """The convolution as shifted multiply-adds over a float32 padded copy, its backward pass left to
+    autodiff (what ``ops/gated_delta.causal_conv`` was before it cast each tap's slice on its own and wrote
+    its backward pass out)."""
+    taps, s = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    return sum(padded[:, j:j + s] * w[j] for j in range(taps)).astype(x.dtype)
+
+
+def rule_variants(args, small):
+    rows, seq, hk, hv, d = (1, 256, 2, 4, 32) if small else (args.rows, args.seq, 16, 32, 128)
+    solve = gd.unit_lower_inverse
+    check = inputs(1, 256 if small else 512, hk, hv, d, jnp.float32)
+    r = hv // hk
+    want = reference_gdn_moe._highest(reference_gdn_moe.delta_rule)(
+        jnp.repeat(check[0], r, axis=2), jnp.repeat(check[1], r, axis=2), *check[2:])
+    x = inputs(rows, seq, hk, hv, d, jnp.bfloat16)
+    for chunk in (64, 128):
+        for name in ("solve", "HIGHEST", "HIGH", "DEFAULT"):
+            gd.unit_lower_inverse = solve if name == "solve" else by_doublings(getattr(jax.lax.Precision, name))
+            rule = lambda *a: gd.gated_delta_rule(*a, chunk=chunk)  # noqa: E731
+            fwd = jax.jit(rule)
+            both = jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4)))
+            line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "chunk": chunk, "inverse": name}
+            try:
+                got = fwd(*check)
+                line["rel_err_f32_512"] = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+                line["fwd_ms"] = round(timed(fwd, x, args.iters), 3)
+                line["fwd_bwd_ms"] = round(timed(both, x, args.iters), 3)
+            except Exception as e:  # a refusal (memory, a shape) is the reading
+                line["refused"] = str(e).split("\n")[0][:300]
+            print(json.dumps(line), flush=True)
+            jax.clear_caches()
+    gd.unit_lower_inverse = solve
+
+
+def mixer_variants(args, small):
+    from llm_fine_tune_distributed_tpu.models import transformer
+    from llm_fine_tune_distributed_tpu.models.configs import get_preset
+
+    mc = get_preset("tiny_qwen3_next" if small else "qwen3_next_80b_a3b")
+    rows, seq = (1, 256) if small else (args.rows, args.seq)
+    keys = iter(jax.random.split(jax.random.key(1), 8))
+    dense = lambda key, shape: (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(jnp.bfloat16)  # noqa: E731
+    leaves = transformer._init_linear_attention(keys, mc, dense, jnp.bfloat16)
+    hid = jax.random.normal(jax.random.key(2), (rows, seq, mc.hidden_size), jnp.bfloat16)
+    lin = lambda x, p: x @ p["kernel"]  # noqa: E731
+
+    def loss(leaves, hid):
+        out, _ = transformer._linear_mixer(leaves, hid, None, None, config=mc, lin=lin, segment_ids=None, cache_entry=None)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    shifts = gd.causal_conv
+    for name, conv in (("shifts", shifts), ("shifts_by_autodiff", by_autodiff), ("lax_conv", lax_conv)):
+        gd.causal_conv = conv
+        both = jax.jit(jax.grad(loss, argnums=(0, 1)))
+        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "mixer": "fwd+bwd", "conv": name}
+        try:
+            line["fwd_bwd_ms"] = round(timed(both, (leaves, hid), args.iters), 3)
+            conv_alone = jax.jit(jax.grad(lambda x, w: jnp.sum(jax.nn.silu(conv(x, w)).astype(jnp.float32) ** 2), argnums=(0, 1)))
+            qkv = jax.random.normal(jax.random.key(3), (rows, seq, leaves["conv1d"]["weight"].shape[1]), jnp.bfloat16)
+            line["conv_silu_fwd_bwd_ms"] = round(timed(conv_alone, (qkv, leaves["conv1d"]["weight"]), args.iters), 3)
+        except Exception as e:
+            line["refused"] = str(e).split("\n")[0][:300]
+        print(json.dumps(line), flush=True)
+        jax.clear_caches()
+    gd.causal_conv = shifts
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--rows", type=int, default=2)
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--only", choices=("rule", "mixer"))
+    args = ap.parse_args(argv)
+    small = not on_accelerator(jax.devices()[0].platform)  # a CPU rehearsal of the control flow: its numbers are not rates
+    if args.only != "mixer":
+        rule_variants(args, small)
+    if args.only != "rule":
+        mixer_variants(args, small)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -31,9 +31,10 @@ if REPO not in sys.path:
 _DENSE = dict(freeze_strategy="last_n_and_head", unfreeze_last_n_layers=2, attention_impl="flash")
 _ALL = dict(freeze_strategy="none", attention_impl="flash", remat_policy="full", loss_chunk_size=1024)
 _MELLUM = ("mellum2_12b_a2_5b", dict(num_layers=4, vocab_size=24576, held_experts=tuple(range(16))))
+_QWEN3_NEXT = ("qwen3_next_80b_a3b", dict(num_layers=4, vocab_size=18992, held_experts=tuple(range(32))))
 # name -> (preset, model overrides, rows, accumulation, sequence, recipe)
 STEPS = {
-    # the three cells of BENCHMARK.json, as their traffic files state them
+    # the cells of BENCHMARK.json, as their traffic files state them
     "smollm3-3b.sft-1k-full": ("smollm3_3b", {}, 2, 16, 1024, dict(_DENSE, remat_policy="dots_no_batch")),
     "mistral-7b-d16.sft-2k-full": ("mistral_7b", dict(num_layers=16), 1, 16, 2048, dict(_DENSE, remat_policy="dots_no_batch")),
     "moonlight-16b-a3b-ep8-d6.sft-4k-allparams": (
@@ -43,6 +44,10 @@ STEPS = {
     # the same at 2 rows and at 1 (of 4, 2, 1 rows the cell takes the most that fits: 4)
     "mellum2-12b-a2.5b-ep4-d4.8k-2rows": (*_MELLUM, 2, 2, 8192, _ALL),
     "mellum2-12b-a2.5b-ep4-d4.8k-1row": (*_MELLUM, 1, 4, 8192, _ALL),
+    "qwen3-next-80b-a3b-ep16-d4.sft-8k-linear-allparams": (*_QWEN3_NEXT, 2, 2, 8192, _ALL),
+    # the same at 4 rows and at 1 (of 4, 2, 1 rows the cell takes the most that fits: 2; 4 are refused)
+    "qwen3-next-80b-a3b-ep16-d4.8k-4rows": (*_QWEN3_NEXT, 4, 1, 8192, _ALL),
+    "qwen3-next-80b-a3b-ep16-d4.8k-1row": (*_QWEN3_NEXT, 1, 4, 8192, _ALL),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),

@@ -23,7 +23,9 @@ class LayerPlan:
     """One layer's parts, as ``ModelConfig.layer(i)`` reads them from the
     config: exactly what the model code branches on."""
 
-    attention: str  # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent)
+    # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent) |
+    # "linear" (Gated DeltaNet: a recurrence over time, no softmax, no rope, no window)
+    attention: str
     rope: bool  # rotary embedding on this layer's q and k (SmolLM3's NoPE layers: False)
     rope_kind: str  # which of the forward's cos/sin tables: "plain" | "scaled" (the config's context extension)
     window: Optional[int]  # sliding-window width, None = global attention
@@ -104,9 +106,27 @@ class ModelConfig:
     no_rope_layers: tuple = ()
     sliding_window: Optional[int] = None  # Mistral-style local attention
     # HF ``layer_types``, one entry a layer: "sliding_attention" (the window
-    # applies) | "full_attention" (global). Empty = the window, if any, on
-    # every layer (or on even ones, ``alternating_sliding_window``).
+    # applies) | "full_attention" (global) | "linear_attention" (a Gated
+    # DeltaNet mixer, the ``linear_*`` fields below). Empty = the window, if
+    # any, on every layer (or on even ones, ``alternating_sliding_window``).
     layer_types: tuple = ()
+    # --- Qwen3-Next (HF Qwen3NextConfig) ---
+    # A "linear_attention" layer's mixer (ops/gated_delta.py): key heads and
+    # value heads of their own widths (each key head serves value/key value
+    # heads), a causal depthwise convolution of this many taps over q, k, v.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # The share of a head's dimensions that rope rotates, from dimension 0
+    # (rotate-half inside them); the rest pass unrotated. 1.0 = the whole head.
+    partial_rotary_factor: float = 1.0
+    # q_proj is twice as wide, query and gate of each head side by side, and
+    # the attention output is multiplied by sigmoid(gate) before o_proj.
+    attention_output_gate: bool = False
+    # The shared expert's output is multiplied by sigmoid(x w_s), one column.
+    shared_expert_gate: bool = False
     dtype: str = "bfloat16"
     # Mixture-of-experts (Mixtral-style). 0 = dense MLP. When > 0 every
     # layer's MLP becomes num_experts SwiGLU experts with top-k routing
@@ -167,14 +187,27 @@ class ModelConfig:
             if self.router_scoring not in ("sigmoid", "softmax"):
                 raise ValueError(f"router_scoring {self.router_scoring!r}: expected 'sigmoid' or 'softmax'")
         if self.layer_types:
-            kinds = set(self.layer_types) - {"sliding_attention", "full_attention"}
+            kinds = set(self.layer_types) - {"sliding_attention", "full_attention", "linear_attention"}
             if kinds or len(self.layer_types) < self.num_layers:
                 raise ValueError(
-                    f"layer_types must name each of the {self.num_layers} layers 'sliding_attention' or "
-                    f"'full_attention' (got {len(self.layer_types)} entries, unknown kinds {sorted(kinds)})"
+                    f"layer_types must name each of the {self.num_layers} layers 'sliding_attention', "
+                    f"'full_attention' or 'linear_attention' (got {len(self.layer_types)} entries, unknown "
+                    f"kinds {sorted(kinds)})"
                 )
             if "sliding_attention" in self.layer_types[: self.num_layers] and self.sliding_window is None:
                 raise ValueError("layer_types has sliding_attention layers but sliding_window is None")
+            if "linear_attention" in self.layer_types[: self.num_layers]:
+                hk, hv = self.linear_num_key_heads, self.linear_num_value_heads
+                if min(hk, hv, self.linear_key_head_dim, self.linear_value_head_dim, self.linear_conv_kernel_dim) < 1:
+                    raise ValueError("layer_types has linear_attention layers: set the linear_* fields")
+                if hv % hk:
+                    raise ValueError(f"linear_num_value_heads={hv} must be a multiple of linear_num_key_heads={hk}")
+        rotary = self.resolved_head_dim * self.partial_rotary_factor
+        if not 0 < self.partial_rotary_factor <= 1 or rotary != int(rotary) or int(rotary) % 2:
+            raise ValueError(
+                f"partial_rotary_factor={self.partial_rotary_factor} of head_dim={self.resolved_head_dim} "
+                "must give an even number of rotated dimensions"
+            )
         if (self.num_experts or self.n_routed_experts) and self.hidden_act != "silu":
             # ops/moe.py's expert MLP hardcodes silu — reject at config
             # construction rather than silently training with the wrong
@@ -191,6 +224,16 @@ class ModelConfig:
     @property
     def held_expert_ids(self) -> tuple:
         return tuple(self.held_experts) or tuple(range(self.n_routed_experts))
+
+    @property
+    def rotary_dim(self) -> int:
+        """Dimensions of a head that rope rotates (``partial_rotary_factor``)."""
+        return int(self.resolved_head_dim * self.partial_rotary_factor)
+
+    @property
+    def linear_layers(self) -> tuple:
+        """Indices of the layers whose mixer is the linear recurrence."""
+        return tuple(i for i, kind in enumerate(self.layer_types[: self.num_layers]) if kind == "linear_attention")
 
     @property
     def num_params(self) -> int:
@@ -239,7 +282,24 @@ class ModelConfig:
             held = len(self.held_expert_ids)
             bias = e if self.router_scoring == "sigmoid" else 0
             experts = h * e + bias + 3 * h * fe * (held + self.n_shared_experts)
+            if self.shared_expert_gate:
+                experts += h  # one column
             total += (L - min(L, self.first_k_dense_replace)) * (experts - 3 * h * f)
+        if self.attention_output_gate:
+            total += (L - len(self.linear_layers)) * h * self.num_heads * d  # q_proj carries the gate
+        if self.linear_layers:
+            # in_proj_qkvz, in_proj_ba, the convolution over q, k, v, A_log and
+            # dt_bias, the gated norm, out_proj, instead of q, k, v, o (and
+            # their norms and the gate's half of q_proj, counted above)
+            kd = self.linear_num_key_heads * self.linear_key_head_dim
+            vd = self.linear_num_value_heads * self.linear_value_head_dim
+            mixer = (
+                h * (2 * kd + 2 * vd) + h * 2 * self.linear_num_value_heads
+                + (2 * kd + vd) * self.linear_conv_kernel_dim + 2 * self.linear_num_value_heads
+                + self.linear_value_head_dim + vd * h
+            )
+            heads = 2 * h * (self.num_heads + self.num_kv_heads) * d + (2 * d if self.qk_norm else 0)
+            total += len(self.linear_layers) * (mixer - heads)
         if not self.tie_word_embeddings:
             total += v * h
         return total
@@ -258,8 +318,11 @@ class ModelConfig:
         # one global); else Gemma2 alternates local (even layers) / global
         # (odd) and Mistral applies the window everywhere
         window = self.sliding_window
+        kind = self.layer_types[i] if self.layer_types else None
+        if kind == "linear_attention":
+            return LayerPlan(attention="linear", rope=False, rope_kind="plain", window=None, feed_forward=feed_forward)
         if self.layer_types:
-            if self.layer_types[i] == "full_attention":
+            if kind == "full_attention":
                 window = None
         elif self.alternating_sliding_window and i % 2 != 0:
             window = None

@@ -106,16 +106,29 @@ def make_tp_mesh(tp: int, model_config: Optional[ModelConfig] = None):
 
 
 class LatentAttentionNotServed(NotImplementedError):
-    """A model with latent attention (``ModelConfig.layer(i).attention``) was handed
-    to the serving path. It trains (``models/transformer.forward`` without a
-    cache); its cache, one latent and one rope key a token, is a third layout
-    that ``infer/`` does not have yet (ROADMAP.md, Reach C)."""
+    """A model with a kind of layer whose cache ``infer/`` does not have
+    (``ModelConfig.layer(i).attention`` other than ``"heads"``) was handed to
+    the serving path. It trains (``models/transformer.forward`` without a
+    cache). Latent attention's cache, one latent and one rope key a token, is
+    a third layout (ROADMAP.md, Reach C); a linear-attention layer's is a
+    state and the convolution's last inputs, not keys and values (Reach D).
+    (The name is the first kind's; both are refused here.)"""
 
-    def __init__(self, name: str):
-        super().__init__(
-            f"model {name!r} has latent attention: it is supported on the training "
-            "path only; serving it needs a latent KV cache layout and a decode path that infer/ lacks"
-        )
+    _LACKS = {
+        "latent": "latent attention: it is supported on the training path only; serving it needs a latent KV "
+                  "cache layout and a decode path that infer/ lacks",
+        "linear": "linear-attention layers: it is supported on the training path only; serving it needs a "
+                  "recurrent state (and the convolution's last inputs) as a cache entry, which infer/ lacks",
+    }
+
+    def __init__(self, name: str, kind: str = "latent"):
+        super().__init__(f"model {name!r} has {self._LACKS[kind]}")
+
+
+def unserved_layer_kind(config: ModelConfig):
+    """The first kind of layer of ``config`` that has no cache here, or None."""
+    kinds = {config.layer(i).attention for i in range(config.num_layers)}
+    return next((kind for kind in ("latent", "linear") if kind in kinds), None)
 
 
 class Generator:
@@ -148,8 +161,8 @@ class Generator:
         beyond repetition-heavy outputs (prompt-lookup's limit), at the cost
         of running the small model K steps per verify."""
         for served in (model_config, draft_config):
-            if served is not None and served.layer(0).attention == "latent":
-                raise LatentAttentionNotServed(served.name)
+            if served is not None and (kind := unserved_layer_kind(served)) is not None:
+                raise LatentAttentionNotServed(served.name, kind)
         self.mesh = mesh
         self._act_sharding = None
         self._multihost = False

@@ -380,6 +380,79 @@ PRESETS = {
         router_scoring="softmax",
         held_experts=(0, 1, 2, 3),
     ),
+    "qwen3_next_80b_a3b": ModelConfig(
+        # HF Qwen/Qwen3-Next-80B-A3B-Instruct (model_type qwen3_next): three
+        # Gated DeltaNet layers (16 key heads, 32 value heads of 128, a causal
+        # convolution of 4 taps) to one gated softmax-attention layer (16
+        # heads of 256 on 2, a quarter of each head rotated, zero-centred q/k
+        # norms, a sigmoid gate on the output); every layer's feed-forward is
+        # 512 experts of 512 behind a softmax router, 10 a token, beside one
+        # shared expert behind a sigmoid gate; every norm zero-centred. Set
+        # held_experts to one process's share for expert parallelism.
+        name="qwen3_next_80b_a3b",
+        vocab_size=151936,
+        hidden_size=2048,
+        intermediate_size=5120,  # the config's dense width; no layer is dense
+        num_layers=48,
+        num_heads=16,
+        num_kv_heads=2,
+        head_dim=256,
+        rope_theta=10_000_000.0,
+        max_position_embeddings=262144,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        qk_norm=True,
+        zero_centered_norm=True,
+        partial_rotary_factor=0.25,
+        attention_output_gate=True,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 12,
+        linear_num_key_heads=16,
+        linear_num_value_heads=32,
+        linear_key_head_dim=128,
+        linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+        n_routed_experts=512,
+        num_experts_per_tok=10,
+        moe_intermediate_size=512,
+        n_shared_experts=1,
+        shared_expert_gate=True,
+        router_scoring="softmax",
+    ),
+    "tiny_qwen3_next": ModelConfig(
+        # Qwen3-Next's structure at toy widths (tests, the benchmark's CPU
+        # rehearsal): one period of the 3:1 pattern, two value heads a key
+        # head, a quarter of a head rotated, this process holding 4 of the 16
+        # routed experts
+        name="tiny_qwen3_next",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=32,
+        rope_theta=10_000_000.0,
+        max_position_embeddings=2048,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        qk_norm=True,
+        zero_centered_norm=True,
+        partial_rotary_factor=0.25,
+        attention_output_gate=True,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_key_heads=2,
+        linear_num_value_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        shared_expert_gate=True,
+        router_scoring="softmax",
+        held_experts=(0, 1, 2, 3),
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -462,6 +535,16 @@ def to_hf_dict(mc: ModelConfig) -> dict:
         "no_rope_layers": list(mc.no_rope_layers),
         "sliding_window": mc.sliding_window,
         "layer_types": list(mc.layer_types),
+        "partial_rotary_factor": mc.partial_rotary_factor,
+        "attention_output_gate": mc.attention_output_gate,
+        "shared_expert_gate": mc.shared_expert_gate,
+        **({
+            "linear_num_key_heads": mc.linear_num_key_heads,
+            "linear_num_value_heads": mc.linear_num_value_heads,
+            "linear_key_head_dim": mc.linear_key_head_dim,
+            "linear_value_head_dim": mc.linear_value_head_dim,
+            "linear_conv_kernel_dim": mc.linear_conv_kernel_dim,
+        } if mc.linear_layers else {}),
         # MoE round trip (HF MixtralConfig naming — consumed by
         # models/configs.from_hf_config at inference load time)
         "num_local_experts": mc.num_experts,
@@ -580,6 +663,58 @@ def _mellum_fields(g) -> dict:
     )
 
 
+def _qwen3_next_fields(g) -> dict:
+    """ModelConfig fields of a ``qwen3_next`` config (HF Qwen3NextConfig):
+    Gated DeltaNet layers and gated softmax-attention layers by
+    ``layer_types`` (or ``full_attention_interval``), a quarter of each head
+    rotated, every norm zero-centred, every layer's feed-forward
+    ``num_experts`` experts behind a softmax router beside one gated shared
+    expert. Whatever of it this framework does not implement is refused by
+    name, before any weight loads."""
+    n = g("num_hidden_layers")
+    interval = g("full_attention_interval") or 4
+    layer_types = tuple(g("layer_types") or (
+        "full_attention" if (i + 1) % interval == 0 else "linear_attention" for i in range(n)
+    ))
+    moe_width, shared_width = g("moe_intermediate_size"), g("shared_expert_intermediate_size") or 0
+    problems = []
+    if (g("decoder_sparse_step") or 1) != 1 or list(g("mlp_only_layers") or ()):
+        problems.append("layers with a dense feed-forward (decoder_sparse_step other than 1, mlp_only_layers)")
+    if not g("norm_topk_prob", True):
+        problems.append("norm_topk_prob false")
+    if g("rope_scaling"):
+        problems.append(f"rope_scaling {g('rope_scaling')!r} (implemented: none)")
+    if g("attention_bias"):
+        problems.append("attention_bias")
+    if shared_width % moe_width:
+        problems.append(f"a shared expert of {shared_width}, no multiple of the routed experts' {moe_width}")
+    if len(layer_types) < n:
+        problems.append(f"layer_types with {len(layer_types)} entries for {n} layers")
+    if problems:
+        raise ValueError("qwen3_next config has " + "; ".join(problems))
+    return dict(
+        layer_types=layer_types,
+        qk_norm=True,
+        zero_centered_norm=True,
+        attention_bias=False,
+        sliding_window=None,
+        partial_rotary_factor=float(g("partial_rotary_factor", 1.0)),
+        attention_output_gate=True,
+        linear_num_key_heads=g("linear_num_key_heads"),
+        linear_num_value_heads=g("linear_num_value_heads"),
+        linear_key_head_dim=g("linear_key_head_dim"),
+        linear_value_head_dim=g("linear_value_head_dim"),
+        linear_conv_kernel_dim=g("linear_conv_kernel_dim"),
+        n_routed_experts=g("num_experts"),
+        num_experts_per_tok=g("num_experts_per_tok"),
+        moe_intermediate_size=moe_width,
+        n_shared_experts=shared_width // moe_width,
+        shared_expert_gate=bool(shared_width),
+        router_scoring="softmax",
+        held_experts=tuple(g("held_experts") or ()),
+    )
+
+
 def load_model_config(path: str) -> ModelConfig:
     """Read ``path/config.json`` (HF layout) into a ModelConfig — the ONE
     place train-time (trainer._resolve_model_config) and inference-time
@@ -635,7 +770,7 @@ def from_hf_config(hf_config) -> ModelConfig:
     # always writes sandwich_norms AND qk_norm), so they bypass the
     # heuristics and are accepted under any model_type name.
     mt = str(g("model_type") or "")
-    _VALIDATED_HEURISTIC_TYPES = {"qwen2", "qwen3", "gemma", "gemma2"}
+    _VALIDATED_HEURISTIC_TYPES = {"qwen2", "qwen3", "qwen3_next", "gemma", "gemma2"}  # (qwen3_next: its own fields below)
     framework_save = g("sandwich_norms") is not None and g("qk_norm") is not None
     if (
         mt.startswith(("qwen", "gemma"))
@@ -756,6 +891,15 @@ def from_hf_config(hf_config) -> ModelConfig:
         no_rope_layers=tuple(no_rope),
         sliding_window=g("sliding_window") if g("use_sliding_window", True) else None,
         layer_types=tuple(g("layer_types") or ()),
+        # (explicit keys of this framework's own save; a qwen3_next config's come from _qwen3_next_fields)
+        partial_rotary_factor=float(g("partial_rotary_factor") or 1.0),
+        attention_output_gate=bool(g("attention_output_gate", False)),
+        shared_expert_gate=bool(g("shared_expert_gate", False)),
+        linear_num_key_heads=g("linear_num_key_heads") or 0,
+        linear_num_value_heads=g("linear_num_value_heads") or 0,
+        linear_key_head_dim=g("linear_key_head_dim") or 0,
+        linear_value_head_dim=g("linear_value_head_dim") or 0,
+        linear_conv_kernel_dim=g("linear_conv_kernel_dim") or 4,
         # MoE (HF MixtralConfig naming). router_aux_loss_coef=0.0 is a
         # legitimate explicit choice (aux disabled) — only None falls back.
         num_experts=g("num_local_experts", 0) or 0,
@@ -766,4 +910,6 @@ def from_hf_config(hf_config) -> ModelConfig:
     )
     if mt == "mellum":
         return dataclasses.replace(mc, **_mellum_fields(g))
+    if mt == "qwen3_next":
+        return dataclasses.replace(mc, **_qwen3_next_fields(g))
     return dataclasses.replace(mc, **deepseek) if deepseek else mc

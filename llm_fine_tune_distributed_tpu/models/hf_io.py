@@ -70,6 +70,32 @@ def _rope_pairs(config: ModelConfig, path: tuple):
     return None
 
 
+def _linear_columns(config: ModelConfig, path: tuple):
+    """For the two input projections of a linear-attention mixer: the column
+    permutation from HF's stored layout to the one the model slices. HF's
+    ``Qwen3NextGatedDeltaNet`` interleaves by KEY head: ``in_proj_qkvz`` holds,
+    for key head g, ``[q_g | k_g | v of its r value heads | z of its r value
+    heads]`` and ``in_proj_ba`` ``[b of its r value heads | a of them]``; the
+    model keeps ``[q | k | v | z]`` and ``[b | a]``, each part by head, so that
+    every part is one slice. Else None."""
+    if len(path) < 3 or path[-3] != "linear_attn" or path[-2] not in ("in_proj_qkvz", "in_proj_ba"):
+        return None
+    hk, hv = config.linear_num_key_heads, config.linear_num_value_heads
+    dk, dv, r = config.linear_key_head_dim, config.linear_value_head_dim, hv // hk
+    g = np.arange(hk)[:, None]
+    if path[-2] == "in_proj_ba":
+        parts = [g * 2 * r + off + np.arange(r)[None, :] for off in (0, r)]
+    else:
+        width = 2 * dk + 2 * r * dv
+        parts = [g * width + off + np.arange(n)[None, :]
+                 for off, n in ((0, dk), (dk, dk), (2 * dk, r * dv), (2 * dk + r * dv, r * dv))]
+    return np.concatenate([part.reshape(-1) for part in parts])
+
+
+# the shared expert beside routed ones: DeepSeek-V3's name is the tree's; Qwen3-Next's differs
+_QWEN_SHARED = ("shared_experts", "shared_expert")
+
+
 def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dict[str, np.ndarray]:
     """params pytree -> {hf_name: numpy array (torch layout)}. ``config`` is
     needed for a model with latent attention or held experts (the rope
@@ -89,9 +115,21 @@ def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dic
             continue
         if leaf_name == _KERNEL_LEAF and "kv_a_proj_with_mqa" in path and config is None:
             raise ValueError("exporting a model with latent attention needs its config")
-        pairs = _rope_pairs(config, path) if config is not None and leaf_name == _KERNEL_LEAF else None
+        pairs = None
+        if config is not None and leaf_name == _KERNEL_LEAF:
+            pairs = _rope_pairs(config, path)
+            if pairs is None:
+                pairs = _linear_columns(config, path)
         if pairs is not None:
             arr = arr[:, np.argsort(pairs)]  # back to the stored order
+        if "linear_attn" in path and config is None:
+            raise ValueError("exporting a model with linear-attention layers needs its config")
+        if config is not None and config.shared_expert_gate and _QWEN_SHARED[0] in path:
+            path = tuple(_QWEN_SHARED[1] if part == _QWEN_SHARED[0] else part for part in path)
+        if path[-2:] == ("conv1d", "weight"):
+            # [taps, channels] -> torch Conv1d's [channels, 1, taps]
+            state[".".join(path)] = np.ascontiguousarray(arr.T[:, None, :])
+            continue
         if len(path) >= 2 and path[-2] == "experts" and leaf_name in ("w1", "w2", "w3"):
             # Stacked MoE expert weights [E, in, out] (ops/moe.py) -> HF
             # Mixtral's per-expert Linears `...experts.<i>.w<n>.weight [out, in]`
@@ -126,6 +164,7 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
                 "q_proj", "k_proj", "v_proj", "o_proj",
                 "gate_proj", "up_proj", "down_proj", "lm_head",
                 "block_sparse_moe.gate", "kv_a_proj_with_mqa", "kv_b_proj", "mlp.gate.",
+                "in_proj_qkvz", "in_proj_ba", "linear_attn.out_proj", "shared_expert_gate",
             )
         )
 
@@ -155,14 +194,19 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
             key = tuple(m.group(1).split(".")) + (m.group(3),)
             experts.setdefault(key, {})[int(m.group(2))] = np.ascontiguousarray(arr.T)
             continue
+        name = name.replace(f".mlp.{_QWEN_SHARED[1]}.", f".mlp.{_QWEN_SHARED[0]}.")
         if needs_transpose(name):
             path = tuple(name[: -len(".weight")].split(".")) + (_KERNEL_LEAF,)
             arr = np.ascontiguousarray(arr.T)
             pairs = _rope_pairs(config, path)
+            if pairs is None:
+                pairs = _linear_columns(config, path)
             if pairs is not None:
                 arr = np.ascontiguousarray(arr[:, pairs])
         else:
             path = tuple(name.split("."))
+            if path[-2:] == ("conv1d", "weight"):  # torch Conv1d's [channels, 1, taps] -> [taps, channels]
+                arr = np.ascontiguousarray(arr[:, 0, :].T)
         flat[path] = arr
     for key, rows in experts.items():
         n = config.num_experts or (max(rows) + 1)

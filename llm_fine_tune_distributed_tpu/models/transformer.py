@@ -31,7 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from llm_fine_tune_distributed_tpu.config import LayerPlan, ModelConfig
 from llm_fine_tune_distributed_tpu.observe.xla import scope
-from llm_fine_tune_distributed_tpu.ops import moe
+from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
 from llm_fine_tune_distributed_tpu.ops.attention import attention, softcap, xla_attention
 from llm_fine_tune_distributed_tpu.ops.int8 import (
     KV_QUANT_MODES,
@@ -122,7 +122,8 @@ def _init_heads_attention(keys, config: ModelConfig, dense, dtype):
     h, d = config.hidden_size, config.resolved_head_dim
     qd, kvd = config.num_heads * d, config.num_kv_heads * d
     attn = {
-        "q_proj": {"kernel": dense(next(keys), (h, qd))},
+        # with an output gate each head's query and gate lie side by side (HF Qwen3NextAttention)
+        "q_proj": {"kernel": dense(next(keys), (h, qd * (2 if config.attention_output_gate else 1)))},
         "k_proj": {"kernel": dense(next(keys), (h, kvd))},
         "v_proj": {"kernel": dense(next(keys), (h, kvd))},
         "o_proj": {"kernel": dense(next(keys), (qd, h))},
@@ -136,32 +137,42 @@ def _init_heads_attention(keys, config: ModelConfig, dense, dtype):
         if config.attention_out_bias:
             attn["o_proj"]["bias"] = jnp.zeros((h,), dtype)
     if config.qk_norm:
-        attn["q_norm"] = {"weight": jnp.ones((d,), dtype)}
-        attn["k_norm"] = {"weight": jnp.ones((d,), dtype)}
+        one = jnp.zeros if config.zero_centered_norm else jnp.ones
+        attn["q_norm"] = {"weight": one((d,), dtype)}
+        attn["k_norm"] = {"weight": one((d,), dtype)}
     return attn
 
 
 def _heads_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
     """q ``[b, s, heads, d]``, k and v ``[b, s, kv_heads, d]`` from their own
-    projections. ``rope``: the plan's bool, or a traced bool scalar where the
-    layer index is data (the pipeline's layer scan over NoPE-interleaved
-    layers): then both are computed and one selected."""
+    projections, and the output gate ``[b, s, heads * d]`` (None without
+    ``attention_output_gate``). ``rope``: the plan's bool, or a traced bool
+    scalar where the layer index is data (the pipeline's layer scan over
+    NoPE-interleaved layers): then both are computed and one selected. Tables
+    narrower than the head rotate its first dimensions (``ops/rope.apply_rope``)."""
     b, s, _ = hid.shape
     d = config.resolved_head_dim
-    q = lin(hid, attn_p["q_proj"]).reshape(b, s, config.num_heads, d)
+    gate = None
+    if config.attention_output_gate:
+        q = lin(hid, attn_p["q_proj"]).reshape(b, s, config.num_heads, 2 * d)
+        q, gate = q[..., :d], q[..., d:].reshape(b, s, config.num_heads * d)
+    else:
+        q = lin(hid, attn_p["q_proj"]).reshape(b, s, config.num_heads, d)
     k = lin(hid, attn_p["k_proj"]).reshape(b, s, config.num_kv_heads, d)
     v = lin(hid, attn_p["v_proj"]).reshape(b, s, config.num_kv_heads, d)
     if config.qk_norm:
-        # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention)
-        q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps)
-        k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps)
+        # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention);
+        # zero-centred where the model's norms are (Qwen3-Next)
+        zc = config.zero_centered_norm
+        q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
+        k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
     if not isinstance(rope, bool):
         qr, kr = apply_rope(q, k, cos, sin)
         q = jnp.where(rope, qr, q)
         k = jnp.where(rope, kr, k)
     elif rope:
         q, k = apply_rope(q, k, cos, sin)
-    return q, k, v
+    return q, k, v, gate
 
 
 def _init_latent_attention(keys, config: ModelConfig, dense, dtype):
@@ -196,13 +207,122 @@ def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope=True):
     q_pe, k_pe = apply_rope(q[..., dn:], latent[..., r:].reshape(b, s, 1, dr), cos, sin)
     q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
     k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, nh, dr))], axis=-1)
-    return q, k, kv[..., dn:]
+    return q, k, kv[..., dn:], None
 
 
-# LayerPlan.attention -> (its half of init_params, q/k/v of a normed input)
+def _softmax_mixer(qkv):
+    """The mixer of a layer of softmax attention, over ``qkv`` (``_heads_qkv``
+    or ``_latent_qkv``): q, k, v, the cache where there is one, the attention
+    dispatch, the output gate where the model has one, ``o_proj``."""
+
+    def mixer(
+        attn_p, hid, cos, sin, *, config, plan, lin, rope, compute_dtype, attention_impl, mesh,
+        padding_mask, segment_ids, mask, cache_entry, cache_pos, block_tables,
+    ):
+        b, s, _ = hid.shape
+        q, k, v, gate = qkv(attn_p, hid, cos, sin, config, lin, rope)
+        # Gemma2: query_pre_attn_scalar scale, logit softcap (None for Llama-family)
+        scale = None if config.query_pre_attn_scalar is None else float(config.query_pre_attn_scalar) ** -0.5
+        heads_width = config.num_heads * v.shape[-1]
+        k, v, new_entry, out = _cache_write_and_view(
+            cache_entry, q, k, v, cache_pos, block_tables, plan=plan, compute_dtype=compute_dtype, scale=scale,
+            fusable=padding_mask is None and plan.window is None and config.attn_logit_softcap is None,
+        )
+        if out is None and mask is not None:
+            out = xla_attention(
+                q, k, v, mask=mask, causal=False, scale=scale, logit_softcap=config.attn_logit_softcap
+            )
+        elif out is None:
+            out = attention(
+                q, k, v,
+                impl=attention_impl,
+                padding_mask=padding_mask,
+                segment_ids=segment_ids,
+                causal=True,
+                sliding_window=plan.window,
+                mesh=mesh,
+                scale=scale,
+                logit_softcap=config.attn_logit_softcap,
+            )
+        out = out.reshape(b, s, heads_width)
+        if gate is not None:
+            with scope("attn_gate"):  # outside the kernel: it multiplies what the kernel wrote
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+        return lin(out, attn_p["o_proj"]), new_entry
+
+    return mixer
+
+
+def _init_linear_attention(keys, config: ModelConfig, dense, dtype):
+    """HF ``Qwen3NextGatedDeltaNet``'s leaves. The columns of ``in_proj_qkvz``
+    lie ``[q | k | v | z]`` and of ``in_proj_ba`` ``[b | a]``, each part by
+    head (HF interleaves both by key head: ``models/hf_io.py`` moves the
+    columns); ``conv1d/weight`` is ``[taps, q | k | v channels]`` (torch's
+    ``[channels, 1, taps]`` transposed). ``A_log = log U(0, 16)`` and
+    ``dt_bias = 1`` as HF draws them; the gated norm's weight is plain (1)."""
+    h, taps = config.hidden_size, config.linear_conv_kernel_dim
+    hv = config.linear_num_value_heads
+    kd = config.linear_num_key_heads * config.linear_key_head_dim
+    vd = hv * config.linear_value_head_dim
+    return {
+        "in_proj_qkvz": {"kernel": dense(next(keys), (h, 2 * kd + 2 * vd))},
+        "in_proj_ba": {"kernel": dense(next(keys), (h, 2 * hv))},
+        "conv1d": {"weight": dense(next(keys), (taps, 2 * kd + vd))},
+        "A_log": jnp.log(jax.random.uniform(next(keys), (hv,), jnp.float32, 1e-3, 16.0)).astype(dtype),
+        "dt_bias": jnp.ones((hv,), dtype),
+        "norm": {"weight": jnp.ones((config.linear_value_head_dim,), dtype)},
+        "out_proj": {"kernel": dense(next(keys), (vd, h))},
+    }
+
+
+def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, **_):
+    """A Gated DeltaNet mixer (``ops/gated_delta.py``): q, k, v through a
+    causal convolution and silu, the gated delta rule per value head in place
+    of softmax attention, a norm gated by ``silu(z)``, ``out_proj``. No rope
+    (``cos``/``sin`` unused), no mask: the rule is causal, and what a
+    right-padded row computes at its pads reaches no real token."""
+    if segment_ids is not None:
+        raise NotImplementedError(
+            f"model {config.name!r} has linear-attention layers and the batch is packed (segment_ids): the "
+            "recurrent state and the convolution would have to restart at every segment boundary, which "
+            "ops/gated_delta.py does not do yet (ROADMAP.md, Reach D); train it with packing off"
+        )
+    if cache_entry is not None:
+        raise NotImplementedError(
+            "a linear-attention layer has the training form only; its cache is a state, not keys and values"
+        )
+    b, s, _ = hid.shape
+    hk, hv = config.linear_num_key_heads, config.linear_num_value_heads
+    dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
+    kd, vd = hk * dk, hv * dv
+    qkvz = lin(hid, attn_p["in_proj_qkvz"])
+    z = qkvz[..., 2 * kd + vd:]
+    ba = lin(hid, attn_p["in_proj_ba"]).astype(jnp.float32)
+    with scope("gdn_conv"):
+        qkv = jax.nn.silu(gated_delta.causal_conv(qkvz[..., : 2 * kd + vd], attn_p["conv1d"]["weight"]))
+    q = gated_delta.l2_norm(qkv[..., :kd].reshape(b, s, hk, dk)) * jnp.asarray(dk ** -0.5, qkv.dtype)
+    k = gated_delta.l2_norm(qkv[..., kd: 2 * kd].reshape(b, s, hk, dk))
+    v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(attn_p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., hv:] + attn_p["dt_bias"].astype(jnp.float32)
+    )  # a log decay, <= 0
+    with scope("gdn_scan"):
+        o = checkpoint_name(gated_delta.gated_delta_rule(q, k, v, g, beta), "gdn_o")
+    with scope("gdn_gate_norm"):
+        o = rms_norm(o, attn_p["norm"]["weight"], config.rms_norm_eps).astype(jnp.float32) * jax.nn.silu(
+            z.reshape(b, s, hv, dv).astype(jnp.float32)
+        )
+    return lin(o.astype(hid.dtype).reshape(b, s, vd), attn_p["out_proj"]), None
+
+
+# LayerPlan.attention -> (the layer's subtree of that kind, the scope its device
+# time is read under, its half of init_params, the mixer: normed input ->
+# (output [b, s, hidden], new cache entry))
 _ATTENTION = {
-    "heads": (_init_heads_attention, _heads_qkv),
-    "latent": (_init_latent_attention, _latent_qkv),
+    "heads": ("self_attn", "attn", _init_heads_attention, _softmax_mixer(_heads_qkv)),
+    "latent": ("self_attn", "attn", _init_latent_attention, _softmax_mixer(_latent_qkv)),
+    "linear": ("linear_attn", "linear_attn", _init_linear_attention, _linear_mixer),
 }
 
 
@@ -273,7 +393,10 @@ def _grouped_experts(p, hid, lin, config: ModelConfig, *, compute_dtype, mesh, *
             prod = checkpoint_name(
                 jax.nn.silu(lin(hid, shared["gate_proj"])) * lin(hid, shared["up_proj"]), "mlp_act"
             )
-            y = y + lin(prod, shared["down_proj"])
+            out = lin(prod, shared["down_proj"])
+            if "shared_expert_gate" in p:  # Qwen3-Next: one column, a sigmoid on the whole shared expert
+                out = out * jax.nn.sigmoid(lin(hid, p["shared_expert_gate"]).astype(jnp.float32)).astype(out.dtype)
+            y = y + out
     return y, {"expert_load": load}
 
 
@@ -323,7 +446,7 @@ def _report(counted_by_layer) -> Dict[str, jax.Array]:
 def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
     """Random init (normal 0.02, HF convention). Returns the params pytree."""
     h, v = config.hidden_size, config.vocab_size
-    keys = iter(jax.random.split(rng, 2 + config.num_layers * 7))
+    keys = iter(jax.random.split(rng, 2 + config.num_layers * 7))  # (a layer draws at most 7)
 
     def dense(key, shape):
         return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
@@ -338,9 +461,10 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
     layers = {}
     for i in range(config.num_layers):
         plan = config.layer(i)
+        mixer_subtree, _, mixer_init, _ = _ATTENTION[plan.attention]
         layer = {
             "input_layernorm": norm_init(),
-            "self_attn": _ATTENTION[plan.attention][0](keys, config, dense, dtype),
+            mixer_subtree: mixer_init(keys, config, dense, dtype),
             "post_attention_layernorm": norm_init(),
         }
         if config.sandwich_norms:
@@ -505,39 +629,18 @@ def _block(
     used by the pipeline's layer scan, where the layer index is data.
     ``block_tables`` ([batch, nb] int32): the cache entry is the PAGED pool.
     """
-    b, s, _ = x.shape
     eps, zc = config.rms_norm_eps, config.zero_centered_norm
     lin = partial(_linear, compute_dtype=compute_dtype, quant_impl=quant_impl, adapter_idx=adapter_idx, w8a8=w8a8)
 
-    with scope("attn"):
+    subtree, mixer_scope, _, mixer = _ATTENTION[plan.attention]
+    with scope(mixer_scope):
         hid = rms_norm(x, lp["input_layernorm"]["weight"], eps, zero_centered=zc)
-        q, k, v = _ATTENTION[plan.attention][1](
-            lp["self_attn"], hid, cos, sin, config, lin, plan.rope if rope_flag is None else rope_flag
+        attn_out, new_entry = mixer(
+            lp[subtree], hid, cos, sin, config=config, plan=plan, lin=lin,
+            rope=plan.rope if rope_flag is None else rope_flag, compute_dtype=compute_dtype,
+            attention_impl=attention_impl, mesh=mesh, padding_mask=padding_mask, segment_ids=segment_ids,
+            mask=mask, cache_entry=cache_entry, cache_pos=cache_pos, block_tables=block_tables,
         )
-        # Gemma2: query_pre_attn_scalar scale, logit softcap (None for Llama-family)
-        scale = None if config.query_pre_attn_scalar is None else float(config.query_pre_attn_scalar) ** -0.5
-        heads_width = config.num_heads * v.shape[-1]
-        k, v, new_entry, out = _cache_write_and_view(
-            cache_entry, q, k, v, cache_pos, block_tables, plan=plan, compute_dtype=compute_dtype, scale=scale,
-            fusable=padding_mask is None and plan.window is None and config.attn_logit_softcap is None,
-        )
-        if out is None and mask is not None:
-            out = xla_attention(
-                q, k, v, mask=mask, causal=False, scale=scale, logit_softcap=config.attn_logit_softcap
-            )
-        elif out is None:
-            out = attention(
-                q, k, v,
-                impl=attention_impl,
-                padding_mask=padding_mask,
-                segment_ids=segment_ids,
-                causal=True,
-                sliding_window=plan.window,
-                mesh=mesh,
-                scale=scale,
-                logit_softcap=config.attn_logit_softcap,
-            )
-        attn_out = lin(out.reshape(b, s, heads_width), lp["self_attn"]["o_proj"])
         if config.sandwich_norms:
             # Gemma2: post_attention_layernorm norms the attention OUTPUT
             attn_out = rms_norm(attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc)
@@ -559,8 +662,8 @@ def _block(
 
 
 def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = None) -> bool:
-    """Whether a rematerialized block of this model, with this ``window``
-    (None: global attention), keeps the flash forward kernel's output and row
+    """Whether a rematerialized block of softmax attention of this model, with this
+    ``window`` (None: global attention), keeps the flash forward kernel's output and row
     statistics at rows of ``seq`` tokens: the rule of
     ``ops/flash_attention.worth_keeping_across_remat`` at this model's head
     widths. What the kernel sees is the whole row on every mesh that calls it
@@ -568,11 +671,29 @@ def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = N
     sequence of a head subset; ring attention does not call it)."""
     from llm_fine_tune_distributed_tpu.ops.flash_attention import worth_keeping_across_remat
 
-    if config.layer(0).attention == "latent":
+    if config.kv_lora_rank:
         d_qk, d_v = config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim
     else:
         d_qk = d_v = config.resolved_head_dim
     return worth_keeping_across_remat(seq, d_qk, d_v, config.hidden_size, window=window)
+
+
+def keeps_scan_output(config: ModelConfig) -> bool:
+    """Whether a rematerialized block with a linear-attention mixer keeps the
+    gated delta rule's output (``gdn_o``, ``[b, s, value heads * d_v]``), so
+    that the recomputed scan only has to carry its state forward and the
+    output half of each step (``Q S`` and ``(decay * Q K^T) D``) is dead code.
+    The rule of ``worth_keeping_across_remat``, from shapes alone: per byte of
+    ``o`` that half costs ``d_k + chunk * (1 + d_k / (r d_v))`` FLOPs (``2 d_k
+    d_v`` for ``Q S``, ``2 chunk d_v`` for the product with D and ``2 chunk
+    d_k / r`` for ``Q K^T`` a token and value head, for ``2 d_v`` bytes),
+    against ``hidden_size`` a byte a projection's output buys. The row's
+    length is not in it: the rule's work grows with the row as what is kept
+    does. Qwen3-Next: 128 + 64 x 1.5 = 224 against 2048, recompute (and on
+    the chip: PERF.md, PR 32)."""
+    r = config.linear_num_value_heads // config.linear_num_key_heads
+    d_k, d_v = config.linear_key_head_dim, config.linear_value_head_dim
+    return d_k + gated_delta.CHUNK * (1 + d_k / (r * d_v)) > config.hidden_size
 
 
 def keeps_routing(config: ModelConfig) -> bool:
@@ -581,9 +702,11 @@ def keeps_routing(config: ModelConfig) -> bool:
     return any(config.layer(i).feed_forward == "grouped_experts" for i in range(config.num_layers))
 
 
-def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int, window: Optional[int] = None):
-    """What ``jax.checkpoint`` keeps of a block (of this ``window``, None for
-    global attention) besides its input. ``full``
+def _remat_policy(
+    remat_policy: Optional[str], config: ModelConfig, seq: int, window: Optional[int] = None, attention: str = "heads"
+):
+    """What ``jax.checkpoint`` keeps of a block (of this kind of ``attention``
+    and this ``window``, None for global attention) besides its input. ``full``
     (and None): nothing, the whole block is recomputed, least memory. The
     selective policies save the expensive tensors and recompute the cheap
     elementwise operations, trading HBM for fewer recomputed FLOPs (a v5e is
@@ -597,7 +720,9 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int, wi
     forward kernel's ``o`` and ``lse``, so that the kernel runs once a layer
     and not a second time in the backward pass. Where the kernel is not in
     the block (XLA attention) the two names are in no program and the policy
-    is the plain one.
+    is the plain one. A block whose mixer is the linear recurrence has no
+    such kernel; it keeps the recurrence's output where ``keeps_scan_output``
+    says so (from the mixer's widths).
 
     And a model with a ``grouped_experts`` layer (``keeps_routing``: the
     layer's kind, no switch) keeps what that layer names
@@ -624,7 +749,10 @@ def _remat_policy(remat_policy: Optional[str], config: ModelConfig, seq: int, wi
             f"unknown remat_policy {remat_policy!r}; expected one of {sorted(policies)}"
         )
     policy = policies[remat_policy]
-    names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq, window) else ()
+    if attention == "linear":
+        names = ("gdn_o",) if keeps_scan_output(config) else ()
+    else:
+        names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq, window) else ()
     if keeps_routing(config):
         names += moe.KEPT_ACROSS_REMAT
     if not names:
@@ -638,10 +766,9 @@ def rope_tables(config: ModelConfig, positions):
     some layer's plan names (``LayerPlan.rope_kind``: "plain", or "scaled" by
     the config's context extension; a model whose window layers keep plain
     rope while its global layers extend theirs has both), at the width this
-    model's attention rotates: the whole head, or latent attention's rope
-    part of it."""
-    latent = config.layer(0).attention == "latent"
-    width = config.qk_rope_head_dim if latent else config.resolved_head_dim
+    model's attention rotates: the whole head, its first
+    ``partial_rotary_factor`` of it, or latent attention's rope part."""
+    width = config.qk_rope_head_dim if config.kv_lora_rank else config.rotary_dim
     kinds = {config.layer(i).rope_kind for i in range(config.num_layers)}
     return {
         kind: rope_cos_sin(positions, width, config.rope_theta, config=config if kind == "scaled" else None)
@@ -820,9 +947,10 @@ def forward_with_report(
     # compile-cost guard (tests/test_frozen_trunk.py) pins both.
     trunk_layers = frozen_layers if (frozen_compute == "int8" and cache is None) else 0
     remat = remat and cache is None
-    # one policy for each kind of layer (by its window), not one a layer
-    windows = {config.layer(i).window for i in range(config.num_layers)} if remat else ()
-    kept_of_a_block = {w: _remat_policy(remat_policy, config, s, w) for w in windows}
+    # one policy for each kind of layer (by its mixer and window), not one a layer
+    plans = [config.layer(i) for i in range(config.num_layers)]
+    kinds = {(plan.attention, plan.window) for plan in plans} if remat else ()
+    kept_of_a_block = {(a, w): _remat_policy(remat_policy, config, s, w, a) for a, w in kinds}
     for i in range(config.num_layers):
         entry = cache["layers"][str(i)] if cache is not None else None
         in_trunk = i < trunk_layers
@@ -835,7 +963,7 @@ def forward_with_report(
             # drops the same embedding-through-trunk gradient the exit
             # boundary drops anyway (documented approximation).
             x = jax.lax.stop_gradient(x)
-        plan = config.layer(i)
+        plan = plans[i]
         block_fn = partial(
             _block,
             config=config,
@@ -849,7 +977,7 @@ def forward_with_report(
             w8a8=in_trunk,
         )
         if remat and not in_trunk:
-            block_fn = jax.checkpoint(block_fn, policy=kept_of_a_block[plan.window])
+            block_fn = jax.checkpoint(block_fn, policy=kept_of_a_block[plan.attention, plan.window])
         with scope("layer", i):
             x, new_entry, counted = block_fn(
                 params["model"]["layers"][str(i)],

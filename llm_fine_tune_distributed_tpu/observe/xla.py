@@ -470,6 +470,9 @@ STEP_SCOPES = (
     "embed", "layer", "attn", "mlp", "final_norm", "loss_head", "grad_accum", "optimizer",
     # inside "mlp", in a layer of routed experts with shared experts
     "router", "experts", "shared_expert",
+    # a linear-attention layer's mixer (in place of "attn") and its parts; the
+    # output gate of a gated softmax-attention layer, inside "attn"
+    "linear_attn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "attn_gate",
 )
 
 

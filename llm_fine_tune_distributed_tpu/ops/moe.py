@@ -776,4 +776,6 @@ def init_grouped_moe_params(rng, config: ModelConfig, dtype):
             "up_proj": {"kernel": dense(ks2, (h, fs))},
             "down_proj": {"kernel": dense(ks3, (fs, h))},
         }
+        if config.shared_expert_gate:
+            out["shared_expert_gate"] = {"kernel": dense(jax.random.fold_in(kg, 1), (h, 1))}
     return out

@@ -135,10 +135,17 @@ def apply_rope(q, k, cos, sin):
     Args:
       q: [batch, seq, num_heads, head_dim]
       k: [batch, seq, num_kv_heads, head_dim]
-      cos/sin: [batch, seq, head_dim] (or broadcastable)
+      cos/sin: [batch, seq, head_dim] (or broadcastable); narrower tables
+        rotate the first ``cos.shape[-1]`` dimensions of a head (rotate-half
+        inside them) and the rest pass (HF ``partial_rotary_factor``)
 
     Returns rotated (q, k), same dtypes as inputs.
     """
+    width = cos.shape[-1]
+    if width < q.shape[-1]:
+        q_rot, k_rot = apply_rope(q[..., :width], k[..., :width], cos, sin)
+        return (jnp.concatenate([q_rot, q[..., width:]], axis=-1),
+                jnp.concatenate([k_rot, k[..., width:]], axis=-1))
     # Broadcast over the heads axis.
     c = cos[..., None, :]
     s = sin[..., None, :]

@@ -47,6 +47,13 @@ _MATRIX_RULES = [
     # to the heads' k and v shards by head like q
     (re.compile(r".*self_attn/kv_a_proj_with_mqa/" + _QK), ("fsdp", None)),
     (re.compile(r".*self_attn/kv_b_proj/" + _QK), ("fsdp", "tensor")),
+    # a linear-attention mixer: its input projections' columns hold parts of
+    # different kinds side by side ([q | k | v | z], [b | a]), so only the
+    # input dim shards; out_proj like o_proj without the tensor axis; the
+    # convolution's [taps, channels] and the per-head vectors stay whole
+    (re.compile(r".*linear_attn/(in_proj_qkvz|in_proj_ba)/" + _QK), ("fsdp", None)),
+    (re.compile(r".*linear_attn/out_proj/" + _QK), (None, "fsdp")),
+    (re.compile(r".*mlp/shared_expert_gate/" + _QK), ("fsdp", None)),
     # MLP (and the shared experts beside routed ones: the same SwiGLU)
     (re.compile(r".*mlp/(shared_experts/)?(gate_proj|up_proj)/" + _QK), ("fsdp", "tensor")),
     (re.compile(r".*mlp/(shared_experts/)?down_proj/" + _QK), ("tensor", "fsdp")),

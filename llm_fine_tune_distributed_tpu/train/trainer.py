@@ -1181,7 +1181,7 @@ class SFTTrainer:
                         # the step program is traced now: say which attention
                         # path it holds (a flash request that took XLA
                         # attention names its reason) and on what it runs
-                        from llm_fine_tune_distributed_tpu.ops import moe
+                        from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
                         from llm_fine_tune_distributed_tpu.ops.attention import (
                             dispatch_summary,
                         )
@@ -1193,6 +1193,8 @@ class SFTTrainer:
                         )
                         if moe.SUM_PROGRAMS:  # routed experts: kernel or loop, and why
                             print(f"[train] {moe.sum_programs_summary()}", flush=True)
+                        if gated_delta.CALLS:  # linear-attention layers: the form their rule took
+                            print(f"[train] {gated_delta.calls_summary()}", flush=True)
                     pending_samples += samples_per_step
                     # real-token accounting for the throughput meter: a host
                     # numpy mean over the loader's (pre-device) mask — cheap

@@ -114,6 +114,11 @@ _MELLUM = lambda w: dict(  # noqa: E731
     window=lambda i: None if i % 4 == 3 else w, rope_kind=lambda i: "scaled" if i % 4 == 3 else "plain",
     feed_forward=lambda i: "grouped_experts",
 )
+# Qwen3-Next: three linear-recurrence layers (no rope, no window) to one softmax layer; experts on every layer
+_QWEN3_NEXT = dict(
+    attention=lambda i: "heads" if i % 4 == 3 else "linear", nope=lambda i: i % 4 != 3,
+    feed_forward=lambda i: "grouped_experts",
+)
 PLAN_OF_PRESET = {
     "llama3_1_8b": _SCALED, "llama3_2_1b": _SCALED, "llama3_2_3b": _SCALED,
     "mellum2_12b_a2_5b": _MELLUM(1024), "tiny_mellum": _MELLUM(32),
@@ -126,6 +131,7 @@ PLAN_OF_PRESET = {
     "mixtral_8x7b": dict(feed_forward=lambda i: "capacity_experts"),
     "moonlight_16b_a3b": dict(attention="latent", feed_forward=_LEADING_DENSE),
     "tiny_mla_moe": dict(attention="latent", feed_forward=_LEADING_DENSE),
+    "qwen3_next_80b_a3b": _QWEN3_NEXT, "tiny_qwen3_next": _QWEN3_NEXT,
 }
 
 
@@ -134,9 +140,12 @@ def test_layer_plan_of_every_preset(name):
     cfg = get_preset(name)
     want = {"attention": "heads", "nope": (), "rope_kind": lambda i: "plain", "window": lambda i: None,
             "feed_forward": lambda i: "dense", **PLAN_OF_PRESET.get(name, {})}
+    # (a preset whose layers differ in kind gives a function of the layer; the others a name, a tuple)
+    attention = want["attention"] if callable(want["attention"]) else (lambda i: want["attention"])
+    nope = want["nope"] if callable(want["nope"]) else (lambda i: i in want["nope"])
     for i in range(cfg.num_layers):
         assert cfg.layer(i) == LayerPlan(
-            attention=want["attention"], rope=i not in want["nope"], rope_kind=want["rope_kind"](i),
+            attention=attention(i), rope=not nope(i), rope_kind=want["rope_kind"](i),
             window=want["window"](i), feed_forward=want["feed_forward"](i),
         ), (name, i)
     assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= 2  # hashable; no preset has more than two kinds

@@ -90,9 +90,11 @@ def _components(path):
 
 # the scopes of an expert layer are checked where a model has one: tests/test_mla_moe.py
 EXPERT_LAYER_SCOPES = ("router", "experts", "shared_expert")
+# those of a linear-attention layer and of a gated softmax-attention layer likewise: tests/test_gdn_moe.py
+LINEAR_LAYER_SCOPES = ("linear_attn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "attn_gate")
 
 
-@pytest.mark.parametrize("name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES])
+@pytest.mark.parametrize("name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES])
 def test_every_scope_of_the_vocabulary_is_named(paths, name):
     want = re.compile(r"^layer\d+$") if name == "layer" else re.compile(f"^{name}$")
     assert any(want.match(c) for p in paths for c in _components(p)), name

@@ -16,6 +16,7 @@ one file, so one worker loads the library once.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 
@@ -276,6 +277,49 @@ def test_step_with_window_and_global_layers_and_softmax_experts_compiles_for_v5e
     _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers)
     again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
     assert not again, again
+
+
+def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
+    """The step of ``qwen3-next-80b-a3b-ep16-d4.sft-8k-linear-allparams`` as
+    its traffic file states it: one period of Qwen3-Next-80B-A3B at its
+    published widths (three Gated DeltaNet layers, one gated full-attention
+    layer; this chip's share: 32 of 512 experts, an eighth of the vocabulary),
+    every parameter trained, 2 rows of 8192 a microbatch, two microbatches.
+    The compiler's own count has to fit beside the state (15.49 GiB a program
+    may use; at 4 rows a microbatch it refuses the step, 17.89 G of 15.75 G);
+    the full layer runs the streamed flash kernels at heads of 256, 8 queries
+    a kv head (no resident kernel: its dk/dv would ask 300 MiB), its forward
+    kernel once (``o`` and ``lse`` kept: 8192 against the hidden 2048); each
+    linear layer's rule is a scan of its own, forward, recomputed and
+    backward; grouped products, the sums of rows into tokens and the kept
+    routing are in the step."""
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "qwen3_next_80b_a3b",
+        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=2, param_dtype="bfloat16",
+        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
+        model_overrides=dict(num_layers=4, vocab_size=18992, held_experts=tuple(range(32))),
+    )
+    state = setup.state.replace(opt_state=jax.tree.map(  # Adam's moments float32, as the cell holds them
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+        setup.state.opt_state))
+    compiled = dataclasses.replace(setup, state=state).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.49 * 2**30
+    text = compiled.as_text()
+    mosaic_calls = lambda kernel: sum(  # noqa: E731
+        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
+    )
+    for kernel in ("causal_fwd", "causal_dq", "causal_dkv"):
+        assert mosaic_calls(f"flash_attention_{kernel}") == 1, kernel
+    assert mosaic_calls("flash_attention_fwd") == 0 and mosaic_calls("flash_attention_window_fwd") == 0
+    assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers)
+    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
+    assert not again, again
+    scans = set(re.findall(r'op_name="[^"]*?((?:transpose\()?jvp\(layer\d\)\)?)/(?:[^"]*/)?linear_attn/gdn_scan/(?:closed_call/)?while"', text))
+    assert {s for s in scans if "layer3" in s} == set() and len({re.sub(r"\D", "", s) for s in scans}) == 3, scans
 
 
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
