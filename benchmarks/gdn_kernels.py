@@ -12,7 +12,12 @@ triangular solve against the identity) or doublings ``(I - A)(I + A^2)(I +
 A^4)...`` at ``HIGHEST``, ``HIGH`` or default precision (defined here: the
 library keeps ONE way, the one this tool found ahead; PERF.md, PR 32), with
 each variant's distance from the token-by-token recurrence in float32 at 512
-tokens.
+tokens; last the library's Pallas kernels (``inverse`` reads ``kernels``; chunks of 64, heads of 128), with the
+seconds their forward + backward takes to trace and lower, each kernel's serialized Mosaic module in bytes
+(``observe/xla.mosaic_programs``: what a warm start loads) and their distance from the XLA form on the timed inputs,
+output and every gradient. ``--inverse NAME ...`` keeps those variants.
+
+``--only inverse``: the kernels' triangular inverse alone against float64, by the precision of its products.
 
 ``--only mixer``: the whole mixer (``models/transformer._linear_mixer``:
 projections, convolution, silu, l2 norms, the rule, the gated norm),
@@ -22,7 +27,7 @@ on its own, the backward pass written out), ``shifts_by_autodiff`` (the same
 over a float32 padded copy, differentiated by JAX) or ``lax_conv``
 (``lax.conv_general_dilated`` with one group a channel), both defined here.
 
-    chiprun -- python benchmarks/gdn_kernels.py [--only rule|mixer]
+    chiprun -- python benchmarks/gdn_kernels.py [--only rule|mixer|inverse] [--inverse solve kernels]
 """
 import argparse
 import functools
@@ -37,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.chipbench import reference_gdn_moe
+from llm_fine_tune_distributed_tpu.observe.xla import mosaic_programs
 from llm_fine_tune_distributed_tpu.ops import gated_delta as gd
 from llm_fine_tune_distributed_tpu.runtime.device import on_accelerator
 
@@ -119,23 +125,70 @@ def rule_variants(args, small):
     want = reference_gdn_moe._highest(reference_gdn_moe.delta_rule)(
         jnp.repeat(check[0], r, axis=2), jnp.repeat(check[1], r, axis=2), *check[2:])
     x = inputs(rows, seq, hk, hv, d, jnp.bfloat16)
-    for chunk in (64, 128):
-        for name in ("solve", "HIGHEST", "HIGH", "DEFAULT"):
-            gd.unit_lower_inverse = solve if name == "solve" else by_doublings(getattr(jax.lax.Precision, name))
-            rule = lambda *a: gd.gated_delta_rule(*a, chunk=chunk)  # noqa: E731
-            fwd = jax.jit(rule)
-            both = jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4)))
-            line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "chunk": chunk, "inverse": name}
-            try:
-                got = fwd(*check)
-                line["rel_err_f32_512"] = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
-                line["fwd_ms"] = round(timed(fwd, x, args.iters), 3)
-                line["fwd_bwd_ms"] = round(timed(both, x, args.iters), 3)
-            except Exception as e:  # a refusal (memory, a shape) is the reading
-                line["refused"] = str(e).split("\n")[0][:300]
-            print(json.dumps(line), flush=True)
-            jax.clear_caches()
+    # the XLA form by chunk and by the way to the inverse, then the library's kernels (chunks of 64, d = 128 only)
+    variants = [(chunk, name, "xla") for chunk in (64, 128) for name in ("solve", "HIGHEST", "HIGH", "DEFAULT")]
+    if not small:
+        variants.append((64, "kernels", "kernels"))
+    for chunk, name, impl in variants:
+        if args.inverse and name not in args.inverse:
+            continue
+        gd.unit_lower_inverse = solve if name in ("solve", "kernels") else by_doublings(getattr(jax.lax.Precision, name))
+        rule = lambda *a: gd.gated_delta_rule(*a, chunk=chunk, impl=impl)  # noqa: E731,B023
+        fwd = jax.jit(rule)
+        both = jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4)))  # noqa: B023
+        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "chunk": chunk, "inverse": name}
+        try:
+            got = fwd(*check)
+            line["rel_err_f32_512"] = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+            line["fwd_ms"] = round(timed(fwd, x, args.iters), 3)
+            line["fwd_bwd_ms"] = round(timed(both, x, args.iters), 3)
+            if impl == "kernels":  # what a warm start loads of them (PERF.md, PR 37), then held to the XLA form on the timed inputs
+                jax.clear_caches()
+                t0 = time.perf_counter()
+                lowered = both.lower(*x)
+                line["trace_lower_s"] = round(time.perf_counter() - t0, 2)
+                line["programs"] = mosaic_programs(lowered.as_text())
+                xla = lambda *a: gd.gated_delta_rule(*a, impl="xla")  # noqa: E731
+                line["rel_to_xla_fwd"] = rel(fwd(*x), jax.jit(xla)(*x))
+                grads = jax.jit(jax.grad(lambda *a: jnp.sum(xla(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4)))(*x)
+                line["rel_to_xla_grads"] = {n: rel(a, b) for n, a, b in zip("q k v g beta".split(), both(*x), grads)}
+        except Exception as e:  # a refusal (memory, a shape) is the reading
+            line["refused"] = str(e).split("\n")[0][:300]
+        print(json.dumps(line), flush=True)
+        jax.clear_caches()
     gd.unit_lower_inverse = solve
+
+
+def rel(got, want):
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def inverse_alone(args, small):
+    """The kernels' triangular inverse alone (``gd._inverse_in_vmem`` on one ``[128, 128]`` matrix of two diagonal
+    blocks), against numpy's inverse in float64: what the products' precision leaves, as the library does it (levels
+    at one bfloat16 pass, ``gd.NEWTON_STEPS`` Newton steps with the residual at HIGHEST), with fewer and more steps,
+    and with every level at HIGHEST and no step. Entries of A are ``beta decay k.k'``, so within (-1, 1): all of one
+    sign up to 1.0 is every key alike; both signs let T's entries grow."""
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    def body(a_ref, t_ref):
+        t_ref[...] = gd._in_step([gd._inverse_in_vmem(a_ref[...], *gd._iotas(128))])[0]
+
+    ri, ci = np.indices((128, 128))
+    library = gd.NEWTON_STEPS, gd._one_pass
+    for name, steps, product in [(f"one pass, {n} Newton steps", n, gd._one_pass) for n in (0, 1, 2, 3)] + [("HIGHEST, no step", 0, gd._mm32)]:
+        gd.NEWTON_STEPS, gd._one_pass = steps, product
+        for low, high in ((0, 0.1), (0, 0.5), (0, 1.0), (-0.3, 0.3), (-0.6, 0.6)):
+            a = np.where((ri // 64 == ci // 64) & (ri > ci), np.random.RandomState(0).uniform(low, high, (128, 128)), 0).astype(np.float32)
+            t = jax.jit(pl.pallas_call(body, out_shape=jax.ShapeDtypeStruct((128, 128), jnp.float32), interpret=small))(jnp.asarray(a))
+            want = np.linalg.inv(np.eye(128) + a.astype(np.float64))
+            print(json.dumps({"device": jax.devices()[0].device_kind, "inverse": name + (" (the library)" if (steps, product) == library else ""),
+                              "entries": [low, high], "rel_err": float(np.linalg.norm(np.asarray(t, np.float64) - want) / np.linalg.norm(want)),
+                              "largest_entry": float(np.abs(want).max())}), flush=True)
+        jax.clear_caches()
+    gd.NEWTON_STEPS, gd._one_pass = library
 
 
 def mixer_variants(args, small):
@@ -176,12 +229,15 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--rows", type=int, default=2)
     ap.add_argument("--seq", type=int, default=8192)
-    ap.add_argument("--only", choices=("rule", "mixer"))
+    ap.add_argument("--only", choices=("rule", "mixer", "inverse"))
+    ap.add_argument("--inverse", nargs="*", help="of the rule's variants, only these (solve HIGHEST HIGH DEFAULT kernels)")
     args = ap.parse_args(argv)
     small = not on_accelerator(jax.devices()[0].platform)  # a CPU rehearsal of the control flow: its numbers are not rates
-    if args.only != "mixer":
+    if args.only == "inverse":
+        inverse_alone(args, small)
+    if args.only in (None, "rule"):
         rule_variants(args, small)
-    if args.only != "rule":
+    if args.only in (None, "mixer"):
         mixer_variants(args, small)
     return 0
 

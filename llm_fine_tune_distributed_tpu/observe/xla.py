@@ -43,6 +43,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
@@ -57,6 +58,7 @@ __all__ = [
     "annotate",
     "device_peak_specs",
     "instrument",
+    "mosaic_programs",
     "scope",
     "utilization_from_cost",
 ]
@@ -321,6 +323,22 @@ class _InstrumentedProgram:
 def instrument(program, fn, ledger, shapes=None, aot=True):
     """Ledger-wrap a jitted callable (see ``_InstrumentedProgram``)."""
     return _InstrumentedProgram(program, fn, ledger, shapes=shapes, aot=aot)
+
+
+_MOSAIC_CALL = re.compile(r'\\22body\\22: \\22([^\\]*)\\22.*?kernel_name = "([^"]*)"')
+
+
+def mosaic_programs(stablehlo_text: str) -> Dict[str, Dict[str, int]]:
+    """The Pallas TPU kernels of a lowered program (``jitted.lower(...).as_text()``):
+    ``{kernel name: {"programs": distinct serialized Mosaic modules, "bytes": their
+    lengths added up as the text carries them, "call_sites": calls}}``. What a warm
+    start pays for a kernel follows these (PERF.md, PR 37), and a CPU can count them."""
+    bodies: Dict[str, Dict[str, int]] = {}
+    for body, name in _MOSAIC_CALL.findall(stablehlo_text):
+        sites = bodies.setdefault(name, {})
+        sites[body] = sites.get(body, 0) + 1
+    return {name: {"programs": len(sites), "bytes": sum(map(len, sites)), "call_sites": sum(sites.values())}
+            for name, sites in bodies.items()}
 
 
 # -------------------------------------------------- utilization from cost

@@ -11,7 +11,19 @@ zero and for t = 0, 1, ...:
 (``g_t <= 0`` a log decay, ``beta_t`` in (0, 1), q and k l2-normed by the
 caller). Token by token that is a chain of ``seq`` dependent steps of rank-one
 updates; it stays in the reference (``benchmarks/chipbench/reference_gdn_moe.py``).
-Here the rule runs in CHUNKED form, the one implementation on every backend:
+Here the rule runs in CHUNKED form, as one of two programs that compute the
+same thing in the same dtypes. Which one takes a call is read from the input
+and the backend (``gated_delta_rule``, no knob; ``CALLS`` says which, and on a
+TPU why not the kernels):
+
+- on a TPU where a head is whole lanes (``d_k`` and ``d_v`` multiples of 128)
+  and the chunk is 64: two Pallas kernels (``gdn_rule_fwd``, ``gdn_rule_bwd``,
+  behind a ``custom_vjp``) that keep a chunk's matrices and the state in VMEM;
+- everywhere else (a CPU, a GPU, the ``tiny_*`` presets' heads of 16 on any
+  backend, another chunk): XLA operations (``_rule_xla``), which is also what
+  the tests hold the kernels to.
+
+The chunked form:
 
 - a row is cut into chunks of ``CHUNK`` tokens; inside a chunk, with ``G_i``
   the running sum of g from the chunk's start, the updates ``d_i`` of all its
@@ -19,9 +31,11 @@ Here the rule runs in CHUNKED form, the one implementation on every backend:
   ``(I + A) D = beta (V - exp(G) K S_0)`` with
   ``A_ij = beta_i exp(G_i - G_j) k_i.k_j`` for j < i, so with
   ``T = (I + A)^-1``, ``U = T (beta V)`` and ``W = T (beta exp(G) K)``:
-  ``D = U - W S_0``. T, U, W and the decayed ``q k^T`` are made for all chunks
-  at once, as batched matrix products;
-- across chunks a ``lax.scan`` carries ``S`` in float32 (``STATE_DTYPE``):
+  ``D = U - W S_0``. The XLA form makes T, U, W and the decayed ``q k^T`` for
+  all chunks at once, as batched matrix products (through HBM); the kernels
+  make them a few chunks at a time and write none of them;
+- across chunks the state ``S`` is carried in float32 (``STATE_DTYPE``), by a
+  ``lax.scan`` or in the kernels' VMEM scratch along a sequential grid axis:
   ``D = U - W S``, ``O = exp(G) (Q S) + (decay * Q K^T) D``,
   ``S = exp(G_C) S + K^T (exp(G_C - G) D)``: ``seq / CHUNK`` dependent steps of
   full matrix products instead of ``seq`` rank-one ones.
@@ -30,19 +44,27 @@ A decay is only ever formed as ``exp(G_i - G_j)`` with ``i >= j`` (at most 1),
 masked BEFORE the ``exp``: ``exp(-G_j)`` alone overflows float32 at the decays
 ``A_log`` allows (a head with ``A = 16`` loses ``exp(-16 softplus(.))`` a token).
 
-``T``: a unit lower-triangular ``C x C`` matrix, inverted by XLA's triangular
-solve against the identity (``unit_lower_inverse``), in float32. Measured on a
-v5e at the Qwen3-Next cell's shapes (``benchmarks/gdn_kernels.py``, PERF.md, PR
-32: 2 rows of 8192, forward / forward + backward of the rule): the solve 13.9 /
-34.1 ms at chunks of 64, against 19.3 / 48.9 for doublings ``(I - A)(I +
-A^2)(I + A^4)...`` at HIGHEST precision (15.1 / 41.0 at the default one); at
-chunks of 128 the solve is the slowest (50.7 / 69.1) and doublings read 23.7 /
-41.0. So: chunks of 64 and the solve; the doublings live on in that tool.
+``T``: a unit lower-triangular ``C x C`` matrix in float32. The XLA form
+inverts it by XLA's triangular solve against the identity
+(``unit_lower_inverse``). Measured on a v5e at the Qwen3-Next cell's shapes
+(``benchmarks/gdn_kernels.py``, PERF.md, PR 32: 2 rows of 8192, forward /
+forward + backward of the rule): the solve 13.9 / 34.1 ms at chunks of 64,
+against 19.3 / 48.9 for doublings ``(I - A)(I + A^2)(I + A^4)...`` at HIGHEST
+precision (15.1 / 41.0 at the default one); at chunks of 128 the solve is the
+slowest (50.7 / 69.1) and doublings read 23.7 / 41.0. So: chunks of 64 and the
+solve; the doublings live on in that tool. The kernels invert by levels of
+diagonal blocks (``_inverse_in_vmem``: why, and in which precision).
 
-The backward pass is autodiff of the scan with its body rematerialized: what
-it holds of a layer is the state at every chunk boundary (``rows x seq / CHUNK
-x value heads x d_k x d_v`` float32) and the per-chunk inputs, never a state a
-token.
+The backward pass. Of the XLA form: autodiff of the scan with its body
+rematerialized: what it holds of a layer is the state at every chunk boundary
+(``rows x seq / CHUNK x value heads x d_k x d_v`` float32), T, U, W and the
+per-chunk inputs, never a state a token. Of the kernels: the ``custom_vjp``
+keeps the inputs and the state at every EIGHTH chunk boundary (the forward
+sweep always writes it, 67 MB a call of the Qwen3-Next cell, so that the sweep
+is ONE program whether a gradient follows or not); the backward sweep walks a
+row's steps last to first, makes a step's ``T``, ``U``, ``W``, ``D`` and
+states again in VMEM, then the chunks against time with the state's cotangent
+carried in VMEM.
 
 Rows whose length is no multiple of the chunk are padded with tokens that
 change nothing (k = 0, beta = 0, g = 0) and the padding's outputs dropped.
@@ -53,8 +75,12 @@ restart at a boundary (the model refuses them, ROADMAP.md).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Tokens a chunk. HF's torch fallback and the flash-linear-attention kernels
 # use 64. (What 128 costs on a v5e: PERF.md, PR 32.)
@@ -133,8 +159,8 @@ def unit_lower_inverse(a):
     return jax.scipy.linalg.solve_triangular(eye + a, eye, lower=True, unit_diagonal=True)
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
-    """The gated delta rule over whole rows, chunked (module docstring).
+def _rule_xla(q, k, v, g, beta, *, chunk: int = CHUNK):
+    """The chunked rule as XLA operations (module docstring): it runs on any backend, and the kernels are held to it.
 
     ``q``, ``k`` ``[b, s, key heads, d_k]`` (l2-normed, q scaled by the
     caller), ``v`` ``[b, s, value heads, d_v]``, ``g`` and ``beta`` ``[b, s,
@@ -147,8 +173,6 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
     hv, dv = v.shape[2], v.shape[3]
     r = hv // hk
     cd, f32 = v.dtype, jnp.float32
-    entry = CALLS.setdefault((b, s, hk, hv, dk, dv), [0, f"chunked {chunk}"])
-    entry[0] += 1
 
     pad = -s % chunk
     if pad:  # tokens that change nothing: k = 0, beta = 0, g = 0
@@ -199,3 +223,513 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
     _, o = jax.lax.scan(step, jnp.zeros((b, hk, r, dk, dv), STATE_DTYPE), xs)
     o = jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(b, n * chunk, hv, dv)     # [n, b, hk, r, C, dv] -> rows
     return o[:, :s]
+
+
+# -- the same chunked rule as Pallas kernels (TPU) ---------------------------------
+#
+# One grid step holds one row's ``STEP_CHUNKS`` chunks of ONE key head and ``rs``
+# (two, or one where r is odd) of its value heads; the grid walks a row's steps in
+# order with the state ``[d_k, rs d_v]`` (head j in lanes ``j d_v ..``) in VMEM
+# scratch. The ``rs`` heads of a chunk are STACKED: their ``rs C`` tokens are the
+# rows of one matrix, so decay, ``A``, ``T`` and ``q k^T`` are ``[rs C, rs C]``
+# matrices of diagonal ``C x C`` blocks (128 x 128 at r = 2: whole vregs, one MXU
+# tile) and ``k k^T``, ``q k^T`` and the q/k loads are shared. q, k, v and o are read
+# and written where they lie, as blocks of ``[b, s, heads x d]``; g's running sum and
+# beta come as rows ``[b, groups, chunks, rs C]`` (a chunk's tokens along lanes) and
+# are turned into columns in VMEM (``_col``).
+#
+# Mosaic issues every product to one MXU in program order, and a product that the
+# next one waits for costs its whole latency (about 90 cycles beside 16 to 32 of
+# pushes; my chip runs, PR 36). A chunk's work until the state reaches it is a chain
+# of 14 such products, so the chunks of a GROUP are written as GENERATORS that yield
+# after each product something waits for, and ``_in_step`` runs them a stage at a
+# time: in program order the group's chunks stand side by side.
+#
+# What that text costs is paid at every start of a process, warm cache or not: Python
+# traces it and lowers it to a Mosaic module before the compile cache is even asked.
+# PR 36 wrote all 8 chunks of a grid step side by side (126 KB of serialized modules in
+# the Qwen3-Next cell's step) and every warm ``setup_s`` of the cell grew by 10.9 s, for
+# which the driver refused it; the step's ``lower()`` follows that text and its
+# ``.compile()`` on a cache hit does not move (PERF.md, PR 37, step 0). So a grid step is
+# a loop over groups that is NOT unrolled (a group is traced, lowered and emitted once),
+# and the forward sweep is one program whether a gradient follows or not.
+# ``tests/test_tpu_compile.py`` holds the programs' count and bytes.
+
+STEP_CHUNKS = 8
+# Chunks whose state-free work stands side by side in the text: a grid step is a ``lax.fori_loop`` over
+# ``STEP_CHUNKS / GROUP`` such groups, NOT unrolled, so a group is traced, lowered and emitted once (section's end).
+GROUP = 4
+# Newton steps that repair the triangular inverse after its one-pass levels (``_inverse_in_vmem``).
+NEWTON_STEPS = 2
+_F32 = jnp.float32
+_SHIFT = CHUNK.bit_length() - 1
+
+
+def _dot(x, y):
+    return jnp.dot(x, y, preferred_element_type=_F32)
+
+
+def _dot_nt(x, y):
+    """``x y^T``: the MXU takes its right operand transposed as it is loaded."""
+    return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())), preferred_element_type=_F32)
+
+
+def _mm32(x, y):
+    """A product of float32 matrices in float32: Mosaic rounds float32 operands to ONE
+    bfloat16 pass unless the product states its precision (PERF.md, PR 25)."""
+    return jnp.dot(x, y, preferred_element_type=_F32, precision=jax.lax.Precision.HIGHEST)
+
+
+def _one_pass(x, y):
+    """``_dot`` of float32 matrices, ONE bfloat16 pass: what the inverse's levels take, see there (a name of
+    its own so that ``benchmarks/gdn_kernels.py`` can price the choice)."""
+    return _dot(x, y)
+
+
+def _iotas(n):
+    return jax.lax.broadcasted_iota(jnp.int32, (n, n), 0), jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+
+
+def _col(row, eye):
+    """``[1, n]`` -> ``[n, 1]`` without a transpose: the diagonal of the row's broadcast."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _row(col, eye):
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _last_of(g_row, ci, rs):
+    """A chunk's last running sum, a head: ``[1, 1]`` each, as a sum over lanes (Mosaic refuses a value's slice
+    at a lane offset, and a ``[1, 1]`` read at one spreads over neither sublanes nor lanes)."""
+    return [jnp.sum(jnp.where(ci[:1] == j * CHUNK + CHUNK - 1, g_row, 0.0), axis=1, keepdims=True) for j in range(rs)]
+
+
+def _in_step(chunks):
+    """Run the generators a stage at a time (the section's comment); their return values."""
+    chunks = list(chunks)
+    out, live = [None] * len(chunks), list(range(len(chunks)))
+    while live:
+        for i in list(live):
+            try:
+                next(chunks[i])
+            except StopIteration as done:
+                out[i] = done.value
+                live.remove(i)
+    return out
+
+
+def _inverse_in_vmem(a, ri, ci):
+    """``(I + a)^-1`` of ``a [n, n]`` float32, strictly lower triangular inside diagonal
+    blocks of ``CHUNK``, in float32 (a generator, ``_in_step``).
+
+    By levels: with ``T`` the inverse of the diagonal blocks of size s, the blocks of
+    size 2 s are ``[[T11, 0], [-T22 A21 T11, T22]] = T - T (A's lower-left s x s blocks)
+    T``. Every entry on the way is an entry of a true inverse or one product of three of
+    them (the doublings ``(I - A)(I + A^2)...`` pass through powers of A whose entries
+    grow like binomials and cancel); 2 products a level, 10 in all, as the doublings.
+
+    Precision. At HIGHEST (six bfloat16 passes) those ten products are 60 of a chunk's 72
+    passes through the one MXU Mosaic uses, 5.4 of the forward sweep's 9.2 ms (my chip
+    runs, PR 36). So the levels multiply at ONE pass, which leaves ``T0`` right to about
+    2^-8, and ``NEWTON_STEPS`` steps ``T <- T + T R`` with ``R = I - (I + A) T`` taken at
+    HIGHEST repair it: ``(I + A) T`` stays unit lower triangular whatever the rounding, so
+    R is strictly lower triangular and each step squares it (3e-3 -> 7e-5 -> 4e-8 at
+    entries of A like the cell's); 24 passes for 60, the same float32 inverse
+    (``benchmarks/gdn_kernels.py --only inverse``: against float64, beside HIGHEST at
+    every level)."""
+    lower_left = lambda s: ((ri ^ ci) < 2 * s) & ((ri & s) != 0) & ((ci & s) == 0)  # noqa: E731
+    eye = jnp.where(ri == ci, 1.0, 0.0)
+    t = eye - jnp.where(lower_left(1), a, 0.0)
+    s = 2
+    while s < CHUNK:
+        y = _one_pass(t, jnp.where(lower_left(s), a, 0.0))
+        yield
+        t = t - _one_pass(y, t)
+        yield
+        s *= 2
+    for _ in range(NEWTON_STEPS):
+        r = eye - t - _mm32(a, t)
+        yield
+        t = t + _one_pass(t, r)
+        yield
+    return t
+
+
+def _transposed(x):
+    """``x^T`` of ``x [rows, d]`` in a matmul dtype, by the MXU: ``I x^T``, exact."""
+    ri, ci = _iotas(x.shape[1])
+    return _dot_nt(jnp.where(ri == ci, 1.0, 0.0).astype(x.dtype), x).astype(x.dtype)
+
+
+def _stack(x, rs, width):
+    """``[C, rs width]`` (heads side by side) -> ``[rs C, width]`` (heads one under the other)."""
+    return x if rs == 1 else jnp.concatenate([x[:, j * width:(j + 1) * width] for j in range(rs)], axis=0)
+
+
+def _beside(x, rs):
+    """``[rs C, width]`` -> ``[C, rs width]``."""
+    return x if rs == 1 else jnp.concatenate([x[j * CHUNK:(j + 1) * CHUNK] for j in range(rs)], axis=1)
+
+
+def _decays(g_ref, b_ref, c, rs, dv):
+    """A chunk's g and beta as rows and columns, and what hangs on them alone."""
+    n = rs * CHUNK
+    ri, ci = _iotas(n)
+    eye, same = ri == ci, (ri >> _SHIFT) == (ci >> _SHIFT)
+    g_row, b_row = g_ref[0, 0, pl.ds(c, 1), :], b_ref[0, 0, pl.ds(c, 1), :]
+    gc, bc = _col(g_row, eye), _col(b_row, eye)
+    last = _last_of(g_row, ci, rs)
+    return dict(
+        ri=ri, ci=ci, eye=eye, same=same, g_row=g_row, b_row=b_row, gc=gc, bc=bc, e=jnp.exp(gc), last=last,
+        decay=jnp.exp(jnp.where(same & (ri >= ci), gc - g_row, -jnp.inf)),   # exp(G_i - G_j); 0 above and between heads
+        rest=jnp.exp(jnp.concatenate([jnp.broadcast_to(x, (CHUNK, 1)) for x in last], axis=0) - gc),   # exp(G_C - G_i)
+        whole=jnp.concatenate([jnp.broadcast_to(jnp.exp(x), (1, dv)) for x in last], axis=1),           # exp(G_C), a head's lanes
+    )
+
+
+def _chunk_alone(q, k, v2, g_ref, b_ref, c, rs, with_output=True):
+    """What a chunk of ``rs`` stacked heads is before any state reaches it (a generator, ``_in_step``): ``T``,
+    ``U``, ``W``, the decayed ``q k^T``, ``k^T`` and the decays the state's walk needs. Line by line what
+    ``_rule_xla`` computes for all chunks at once, in its dtypes."""
+    cd = k.dtype
+    x = _decays(g_ref, b_ref, c, rs, v2.shape[1])
+    k2 = jnp.concatenate([k] * rs, axis=0)
+    kk = _dot_nt(k2, k2)
+    p = (x["decay"] * _dot_nt(jnp.concatenate([q] * rs, axis=0), k2)).astype(cd) if with_output else None
+    k_t = _transposed(k)
+    yield
+    t = yield from _inverse_in_vmem(jnp.where(x["same"] & (x["ri"] > x["ci"]), x["bc"] * x["decay"] * kk, 0.0), x["ri"], x["ci"])
+    tc = t.astype(cd)
+    return dict(t=t, u=_dot(tc, v2 * x["bc"].astype(cd)).astype(cd), w=_dot(tc, k2 * (x["bc"] * x["e"]).astype(cd)).astype(cd),
+                p=p, k_t=k_t, e=x["e"], rest=x["rest"], whole=x["whole"])
+
+
+def _chunk_through(alone, s, rs, state_dtype):
+    """The chunk from the state ``s [d_k, rs d_v]`` it starts from: ``(the next state, D)``: two dependent
+    products a chunk are all that the walk over chunks waits for."""
+    cd, dv = alone["u"].dtype, alone["u"].shape[1]
+    sc = s.astype(cd)
+    d = jnp.concatenate([alone["u"][j * CHUNK:(j + 1) * CHUNK].astype(_F32)
+                         - _dot(alone["w"][j * CHUNK:(j + 1) * CHUNK], sc[:, j * dv:(j + 1) * dv]) for j in range(rs)], axis=0)
+    grown = _dot(alone["k_t"], _beside((alone["rest"] * d).astype(cd), rs))
+    return (alone["whole"] * s.astype(_F32) + grown).astype(state_dtype), d
+
+
+def _rows(c):
+    """The tokens of chunk ``c`` of a grid step's block (``c`` may be a loop's index)."""
+    return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+
+
+def _over_groups(body, carry, against_time=False):
+    """``body(first chunk, carry) -> carry`` over a grid step's groups of ``GROUP`` chunks, in a loop that is not
+    unrolled (one group alone is plain code: its indices are static)."""
+    trips = STEP_CHUNKS // GROUP
+    if trips == 1:
+        return body(0, carry)
+    return jax.lax.fori_loop(0, trips, lambda i, x: body((trips - 1 - i if against_time else i) * GROUP, x), carry)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, state, *, rs):
+    """A grid step of the forward sweep. It always writes the state the step starts from (the backward sweep's
+    only residual besides the inputs; a call that no gradient follows drops it): ONE program for both."""
+    dv, cd = v_ref.shape[2] // rs, v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    s0_ref[0, 0, 0] = state[...]
+
+    def group(first, s):
+        chunks = [first + j for j in range(GROUP)]
+        q = [q_ref[0, _rows(c), :] for c in chunks]
+        alone = _in_step(_chunk_alone(q[j], k_ref[0, _rows(c), :], _stack(v_ref[0, _rows(c), :], rs, dv), g_ref, b_ref, c, rs)
+                         for j, c in enumerate(chunks))
+        walked = []
+        for j in range(GROUP):
+            of_state = _dot(q[j], s.astype(cd))                      # q S, beside the walk
+            s, d = _chunk_through(alone[j], s, rs, state.dtype)
+            walked.append((of_state, d))
+        for c, x, (of_state, d) in zip(chunks, alone, walked):       # o = exp(G) (q S) + P D
+            o = x["e"] * _stack(of_state, rs, dv) + _dot(x["p"], d.astype(cd))
+            o_ref[0, _rows(c), :] = _beside(o, rs).astype(o_ref.dtype)
+        return s
+
+    state[...] = _over_groups(group, state[...])
+
+
+def _chunk_backward_alone(q, k, v2, do2, g_ref, b_ref, c, s, d, w, rs):
+    """The backward sweep's chunk before the state's cotangent reaches it (a generator, ``_in_step``): what
+    hangs on the chunk's inputs, its output's cotangent ``do2 [rs C, d_v]``, the state ``s`` it started from
+    and its ``D`` and ``W`` alone. What stands transposed in the algebra is MADE transposed where that is a product
+    or an ``exp`` (``exp(G_j - G_i)``, ``k q^T``, ``D dO^T``)."""
+    cd, dv = k.dtype, v2.shape[1]
+    x = _decays(g_ref, b_ref, c, rs, dv)
+    ri, ci, same, e = x["ri"], x["ci"], x["same"], x["e"]
+    decay_t = jnp.exp(jnp.where(same & (ri <= ci), x["g_row"] - x["gc"], -jnp.inf))      # the decay's transpose
+    k2, q2 = jnp.concatenate([k] * rs, axis=0), jnp.concatenate([q] * rs, axis=0)
+    sc, dc, do32 = s.astype(cd), d.astype(cd), do2.astype(_F32)
+    do_e = (e * do32).astype(cd)
+    kk, qk, kq = _dot_nt(k2, k2), _dot_nt(q2, k2), _dot_nt(k2, q2)
+    q_t, w_t = _transposed(q), _transposed(w)
+    of_state = _dot(q, sc)
+    dp = jnp.where(same & (ri >= ci), _dot_nt(do2, dc), 0.0)
+    dp_t = jnp.where(same & (ri <= ci), _dot_nt(dc, do2), 0.0)
+    dq = _dot_nt(_beside(do_e, rs), sc)                                                   # through o = exp(G) (q S)
+    yield
+    return dict(
+        x, decay_t=decay_t, k2=k2, q2=q2, sc=sc, kk=kk, w_t=w_t, p=x["decay"] * qk, dp=dp, dp_t=dp_t, dq=dq,
+        dd=_dot((decay_t * kq).astype(cd), do2),                                          # P^T dO
+        ds=_dot(q_t, _beside(do_e, rs)),
+        dg=_row_sum(do32 * e * _stack(of_state, rs, dv)),
+        to_state=_beside((x["rest"] * d).astype(cd), rs),
+    )
+
+
+def _chunk_backward_through(x, k, s, d, ds_next, rs):
+    """The state's cotangent through the chunk: ``(its cotangent before the chunk, and what the rest of the
+    chunk's backward pass needs of it)``. Two dependent products."""
+    cd, dv = k.dtype, d.shape[1]
+    dsc = ds_next.astype(cd)
+    # S' = exp(G_C) S + k^T (exp(G_C - G) D)
+    d_grown = _stack(_dot(k, dsc), rs, dv)
+    dd = x["dd"] + x["rest"] * d_grown
+    ddc = dd.astype(cd)
+    by_head = jnp.concatenate([jnp.where((x["ri"][:, :1] >> _SHIFT) == j, ddc, 0) for j in range(rs)], axis=1)  # head j's rows in its lanes
+    ds = x["whole"] * ds_next + x["ds"] - _dot(x["w_t"], by_head)                              # D = U - W S
+    d_whole = [jnp.sum(ds_next[:, j * dv:(j + 1) * dv] * s[:, j * dv:(j + 1) * dv].astype(_F32), axis=(0, 1), keepdims=True)
+               for j in range(rs)]
+    return ds, dict(ddc=ddc, d_rest=_row_sum(d_grown * d), d_whole=d_whole, dk=_dot_nt(x["to_state"], dsc))
+
+
+def _chunk_backward_rest(x, y, v2, t, rs):
+    """The rest of the chunk's backward pass (a generator, ``_in_step``): ``(dq, dk [C, d_k] summed over the
+    stacked heads, dv [rs C, d_v], the cotangents of g's running sum and of beta as rows [1, rs C])``, float32.
+    Two ``[rs C, rs C]`` float32 matrices are transposed as such (``T``, ``dA``)."""
+    cd, dv = v2.dtype, v2.shape[1]
+    ri, ci, same, e, bc, k2, sc, ddc = x["ri"], x["ci"], x["same"], x["e"], x["bc"], x["k2"], x["sc"], y["ddc"]
+    heads = [(slice(j * CHUNK, (j + 1) * CHUNK), slice(j * dv, (j + 1) * dv)) for j in range(rs)]
+    summed = lambda z: sum(z[rows] for rows, _ in heads)  # noqa: E731  [rs C, .] -> [C, .] over the heads
+    # D = U - W S;  U = T (beta v), W = T (beta exp(G) k)
+    dwc = (-jnp.concatenate([_dot_nt(ddc[rows], sc[:, lanes]) for rows, lanes in heads], axis=0)).astype(cd)
+    tt = t.T
+    ttc = tt.astype(cd)
+    v_beta, k_beta = v2 * bc.astype(cd), k2 * (bc * e).astype(cd)
+    dt_t = _dot_nt(v_beta, ddc)
+    d_vb = _dot(ttc, ddc)
+    yield
+    dt_t = dt_t + _dot_nt(k_beta, dwc)                                                    # dT^T
+    d_kb = _dot(ttc, dwc)
+    of_k = _row_sum(d_kb * k2.astype(_F32))
+    db = _row_sum(d_vb * v2.astype(_F32)) + e * of_k
+    dg = x["dg"] + bc * e * of_k
+    dk = y["dk"] + summed(bc * e * d_kb)
+    dq = x["dq"] + summed(_dot((x["dp"] * x["decay"]).astype(cd), k2))
+    yield
+    # T = (I + A)^-1:  dA = -T^T dT T^T, made transposed and turned
+    da_t = _mm32(t, dt_t)
+    yield
+    da_t = jnp.where(same & (ri < ci), -_mm32(da_t, t), 0.0)
+    yield
+    da = da_t.T
+    # A = beta decay k k^T below the diagonal, P = decay q k^T
+    of_decay = da * x["decay"]
+    db = db + _row_sum(of_decay * x["kk"])
+    through_decay = of_decay * bc * x["kk"] + x["dp"] * x["p"]                            # d decay * decay
+    dg = dg + _row_sum(through_decay) - x["rest"] * y["d_rest"]
+    at_last = jnp.sum(jnp.where(same, x["rest"] * y["d_rest"], 0.0), axis=0, keepdims=True)   # a head's sum, in each of its lanes
+    at_last = at_last + jnp.concatenate(
+        [jnp.broadcast_to(jnp.exp(g) * z, (1, CHUNK)) for g, z in zip(x["last"], y["d_whole"])], axis=1)
+    dg_row = _row(dg, x["eye"]) - jnp.sum(through_decay, axis=0, keepdims=True) + jnp.where(
+        (ci[:1] & (CHUNK - 1)) == CHUNK - 1, at_last, 0.0)
+    dkk_both = bc * of_decay + x["b_row"] * da_t * x["decay_t"]                           # d(k k^T) and its transpose
+    dk = dk + summed(_dot((x["dp_t"] * x["decay_t"]).astype(cd), x["q2"]) + _dot(dkk_both.astype(cd), k2))
+    return dq, dk, bc * d_vb, dg_row, _row(db, x["eye"])
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                ds_ref, s_at, d_at, t_at, w_at, *, rs):
+    """A grid step of the backward sweep (the steps of a row come last first): the step's chunks forward once
+    more from the state the forward sweep kept, group by group, each chunk's starting state, ``D``, ``T`` and ``W``
+    left in VMEM scratch (``*_at``); then the groups against time with the state's cotangent carried
+    (``ds_ref`` from step to step); in both directions a group's state-free work first, the walk, then what
+    hangs on the walk."""
+    dv = v_ref.shape[2] // rs
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    def inputs(chunks):
+        return ([q_ref[0, _rows(c), :] for c in chunks], [k_ref[0, _rows(c), :] for c in chunks],
+                [_stack(v_ref[0, _rows(c), :], rs, dv) for c in chunks])
+
+    def forward(first, s):
+        chunks = [first + j for j in range(GROUP)]
+        q, k, v2 = inputs(chunks)
+        alone = _in_step(_chunk_alone(q[j], k[j], v2[j], g_ref, b_ref, c, rs, with_output=False) for j, c in enumerate(chunks))
+        for c, x in zip(chunks, alone):
+            s_at[c] = s
+            s, d = _chunk_through(x, s, rs, s.dtype)
+            d_at[c], t_at[c], w_at[c] = d, x["t"], x["w"]
+        return s
+
+    _over_groups(forward, s0_ref[0, 0, 0])
+
+    def backward(first, ds):
+        chunks = [first + j for j in range(GROUP)]
+        q, k, v2 = inputs(chunks)
+        back = _in_step(_chunk_backward_alone(q[j], k[j], v2[j], _stack(do_ref[0, _rows(c), :], rs, dv), g_ref, b_ref, c,
+                                              s_at[c], d_at[c], w_at[c], rs) for j, c in enumerate(chunks))
+        through = [None] * GROUP
+        for j in reversed(range(GROUP)):
+            ds, through[j] = _chunk_backward_through(back[j], k[j], s_at[chunks[j]], d_at[chunks[j]], ds, rs)
+        out = _in_step(_chunk_backward_rest(back[j], through[j], v2[j], t_at[c], rs) for j, c in enumerate(chunks))
+        for c, (dq, dk, dv2, dg, db) in zip(chunks, out):
+            dq_ref[0, _rows(c), :] = dq.astype(dq_ref.dtype)
+            dk_ref[0, _rows(c), :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, _rows(c), :] = _beside(dv2, rs).astype(dv_ref.dtype)
+            dg_ref[0, 0, pl.ds(c, 1), :] = dg
+            db_ref[0, 0, pl.ds(c, 1), :] = db
+        return ds
+
+    ds_ref[...] = _over_groups(backward, ds_ref[...], against_time=True)
+
+
+def _specs(rs, r, dk, dv):
+    """Block specs over ``(row, group of rs value heads, step)`` for q or k, v or o, and g or beta."""
+    tokens = STEP_CHUNKS * CHUNK
+    return (
+        lambda at: pl.BlockSpec((1, tokens, dk), lambda i, j, t: (i, at(t), (j * rs) // r)),
+        lambda at: pl.BlockSpec((1, tokens, rs * dv), lambda i, j, t: (i, at(t), j)),
+        lambda at: pl.BlockSpec((1, 1, STEP_CHUNKS, rs * CHUNK), lambda i, j, t: (i, j, at(t), 0)),
+    )
+
+
+def _params(interpret):
+    return {} if interpret else dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=64 * 2**20))
+
+
+@functools.partial(jax.jit, static_argnames=("hk", "rs", "state_dtype", "interpret"))
+def gdn_rule_fwd(q, k, v, cum, beta, *, hk, rs, state_dtype, interpret):
+    """The forward sweep. ``q``, ``k`` ``[b, s, hk d_k]``, ``v`` ``[b, s, hv d_v]``, ``cum`` (g's running sum
+    from each chunk's start) and ``beta`` ``[b, hv / rs, s / C, rs C]`` -> ``o`` like v and the state each step
+    starts from ``[b, hv / rs, steps, d_k, rs d_v]``."""
+    b, s, _ = q.shape
+    groups, dk = cum.shape[1], q.shape[2] // hk
+    dv, r, steps = v.shape[2] // (groups * rs), groups * rs // hk, s // (STEP_CHUNKS * CHUNK)
+    qk, vo, gb = (spec(lambda t: t) for spec in _specs(rs, r, dk, dv))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, rs=rs),
+        grid=(b, groups, steps), in_specs=[qk, qk, vo, gb, gb],
+        out_specs=[vo, pl.BlockSpec((1, 1, 1, dk, rs * dv), lambda i, j, t: (i, j, t, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype), jax.ShapeDtypeStruct((b, groups, steps, dk, rs * dv), state_dtype)],
+        scratch_shapes=[pltpu.VMEM((dk, rs * dv), state_dtype)], name="gdn_rule_fwd", interpret=interpret, **_params(interpret),
+    )(q, k, v, cum, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("hk", "rs", "interpret"))
+def gdn_rule_bwd(q, k, v, cum, beta, do, states, *, hk, rs, interpret):
+    """The backward sweep: ``gdn_rule_fwd``'s inputs, ``do`` like v and the kept states -> cotangents of q and k
+    ``[b, s, (hv / rs) d_k]`` (a group of value heads each: the caller adds a key head's groups), of v, and of
+    ``cum`` and ``beta`` in their row form."""
+    b, s, _ = q.shape
+    groups, dk = cum.shape[1], q.shape[2] // hk
+    dv, r, steps = v.shape[2] // (groups * rs), groups * rs // hk, s // (STEP_CHUNKS * CHUNK)
+    qk, vo, gb = (spec(lambda t: steps - 1 - t) for spec in _specs(rs, r, dk, dv))
+    per_group = pl.BlockSpec((1, STEP_CHUNKS * CHUNK, dk), lambda i, j, t: (i, steps - 1 - t, j))
+    like = jax.ShapeDtypeStruct
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, rs=rs),
+        grid=(b, groups, steps),
+        in_specs=[qk, qk, vo, gb, gb, vo, pl.BlockSpec((1, 1, 1, dk, rs * dv), lambda i, j, t: (i, j, steps - 1 - t, 0, 0))],
+        out_specs=[per_group, per_group, vo, gb, gb],
+        out_shape=[like((b, s, groups * dk), q.dtype), like((b, s, groups * dk), k.dtype), like(v.shape, v.dtype),
+                   like(cum.shape, _F32), like(beta.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((dk, rs * dv), _F32), pltpu.VMEM((STEP_CHUNKS, dk, rs * dv), states.dtype),
+                        pltpu.VMEM((STEP_CHUNKS, rs * CHUNK, dv), _F32), pltpu.VMEM((STEP_CHUNKS, rs * CHUNK, rs * CHUNK), _F32),
+                        pltpu.VMEM((STEP_CHUNKS, rs * CHUNK, dk), k.dtype)],
+        name="gdn_rule_bwd", interpret=interpret, **_params(interpret),
+    )(q, k, v, cum, beta, do, states)
+
+
+def _rows_of(x, b, n, groups, rs):
+    """``[b, n C, hv]`` -> ``[b, groups, n, rs C]``: a chunk's tokens along lanes, a group's heads side by side."""
+    return jnp.transpose(x.reshape(b, n, CHUNK, groups, rs), (0, 3, 1, 4, 2)).reshape(b, groups, n, rs * CHUNK)
+
+
+@functools.lru_cache(maxsize=None)
+def _flat_rule(hk, rs, state_dtype, interpret):
+    """The two sweeps as one differentiable function of the kernels' own layouts. What the backward sweep
+    keeps besides the inputs is the state each step starts from (``rows x seq / (8 C) x value heads x d_k x
+    d_v``, an eighth of what the scan's autodiff held), which the forward sweep always writes (under a
+    block's remat the recomputed sweep's is the one kept)."""
+    static = dict(hk=hk, rs=rs, interpret=interpret)
+
+    def fwd(q, k, v, cum, beta):
+        o, states = gdn_rule_fwd(q, k, v, cum, beta, state_dtype=state_dtype, **static)
+        return o, (q, k, v, cum, beta, states)
+
+    @jax.custom_vjp
+    def rule(q, k, v, cum, beta):
+        return fwd(q, k, v, cum, beta)[0]
+
+    def bwd(kept, do):
+        dq, dk, dv, dcum, dbeta = gdn_rule_bwd(*kept[:5], do, kept[5], **static)
+        b, s, _ = dq.shape
+        of_key_head = lambda x: x.reshape(b, s, hk, -1, kept[0].shape[2] // hk).sum(axis=3).reshape(kept[0].shape)  # noqa: E731
+        return of_key_head(dq), of_key_head(dk), dv, dcum, dbeta
+
+    rule.defvjp(fwd, bwd)
+    return rule
+
+
+def _rule_kernels(q, k, v, g, beta, *, interpret=False):
+    """The chunked rule through the kernels (``_rule_xla``'s signature at ``chunk=CHUNK``): rows padded to whole
+    steps with tokens that change nothing, heads flattened into lanes, g summed from each chunk's start and laid
+    out with beta as rows; JAX differentiates these layouts, the kernels' ``custom_vjp`` the rule."""
+    b, s, hk, _ = q.shape
+    hv = v.shape[2]
+    rs = 2 if (hv // hk) % 2 == 0 else 1
+    pad = -s % (STEP_CHUNKS * CHUNK)
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
+    n = (s + pad) // CHUNK
+    cum = jnp.cumsum(g.astype(_F32).reshape(b, n, CHUNK, hv), axis=2).reshape(b, n * CHUNK, hv)
+    flat = lambda x: x.reshape(b, s + pad, -1)  # noqa: E731
+    o = _flat_rule(hk, rs, jnp.dtype(STATE_DTYPE), interpret)(
+        flat(q), flat(k), flat(v), _rows_of(cum, b, n, hv // rs, rs), _rows_of(beta.astype(_F32), b, n, hv // rs, rs))
+    return o.reshape(b, s + pad, hv, -1)[:, :s]
+
+
+def _program(dk, dv, chunk):
+    """Which program takes a call of these shapes, as ``CALLS`` words it: ``kernels``, or ``xla`` and, on a TPU, why."""
+    if jax.default_backend() != "tpu":
+        return "xla"
+    for name, d in (("d_k", dk), ("d_v", dv)):
+        if d % 128:
+            return f"xla ({name} {d} is no multiple of 128)"
+    return "kernels" if chunk == CHUNK else f"xla (chunk {chunk} is not {CHUNK})"
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, impl=None):
+    """The gated delta rule over whole rows, chunked (module docstring; the
+    arguments and the dtypes: ``_rule_xla``). Which program runs is read from
+    the input: the kernels on a TPU where a head is whole lanes and the chunk
+    is ``CHUNK``, the XLA form elsewhere. ``impl`` is the tests' and the
+    tools' handle: ``"xla"``, ``"kernels"``, ``"kernels_interpret"`` (the
+    kernels under the Pallas interpreter)."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    program = _program(dk, dv, chunk) if impl is None else impl.split("_")[0]
+    entry = CALLS.setdefault((b, s, hk, hv, dk, dv), [0, f"chunked {chunk}: {program}"])
+    entry[0] += 1
+    if program == "kernels":
+        return _rule_kernels(q, k, v, g, beta, interpret=impl == "kernels_interpret")
+    return _rule_xla(q, k, v, g, beta, chunk=chunk)

@@ -129,23 +129,63 @@ def _token_by_token(q, k, v, g, beta):
     return ref.delta_rule(jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g, beta, segment=16)
 
 
+def _at_a_kernel_head(seed, seq, r, a_max):
+    """The rule's inputs at heads of 128 (whole lanes: what the kernels take), one key head serving r value heads."""
+    return _rule_inputs(seed, 2, seq, 1, r, 128, 128, a_max)
+
+
+_LOSS = lambda fn: (lambda *a: jnp.sum(jnp.sin(fn(*a))))  # noqa: E731
+_KERNELS = lambda *a: gated_delta.gated_delta_rule(*a, impl="kernels_interpret")  # noqa: E731
+_XLA = lambda *a: gated_delta.gated_delta_rule(*a, impl="xla")  # noqa: E731
+
+
 @pytest.mark.parametrize("seq", [64, 128, 100, 37, 200], ids=lambda s: f"seq{s}")
 @pytest.mark.parametrize("a_max", [1.0, 16.0], ids=["slow-decays", "strongest-decay"])
-def test_chunked_rule_equals_token_by_token(seq, a_max):
-    """Rows that are and are not whole chunks (of 32 here), output and every
+@pytest.mark.parametrize("program", ["xla", "kernels-r1", "kernels-r2"])
+def test_chunked_rule_equals_token_by_token(seq, a_max, program):
+    """Rows that are and are not whole chunks (of 32 for the XLA form here; the
+    kernels pad a row to whole steps of 8 chunks of 64), output and every
     input's gradient. ``a_max`` 16 with every head AT 16 is the strongest
     decay ``A_log`` can give (``exp(-16 softplus(.))`` a token: ``exp(-G)``
     alone would overflow float32 within a chunk; the chunked form never forms
-    it) and must stay finite."""
-    args = _rule_inputs(seq, 2, seq, 2, 4, 16, 8, a_max)
-    chunked = lambda *a: gated_delta.gated_delta_rule(*a, chunk=32)  # noqa: E731
+    it) and must stay finite. The XLA form is held to the recurrence in both;
+    the kernels (under the Pallas interpreter, heads of 128, one and two value
+    heads a key head) to the recurrence in the output and to ``jax.vjp`` of
+    the XLA form in the gradients."""
+    if program == "xla":
+        args = _rule_inputs(seq, 2, seq, 2, 4, 16, 8, a_max)
+        chunked, held_to = (lambda *a: gated_delta.gated_delta_rule(*a, chunk=32)), _token_by_token
+    else:
+        args = _at_a_kernel_head(seq, seq, int(program[-1]), a_max)
+        chunked, held_to = _KERNELS, _XLA
     got, want = chunked(*args), _token_by_token(*args)
     assert bool(jnp.isfinite(got).all()) and _rel(got, want) < 1e-5
-    loss = lambda fn: (lambda *a: jnp.sum(jnp.sin(fn(*a))))  # noqa: E731
-    g_got = jax.grad(loss(chunked), argnums=(0, 1, 2, 3, 4))(*args)
-    g_want = jax.grad(loss(_token_by_token), argnums=(0, 1, 2, 3, 4))(*args)
+    g_got = jax.grad(_LOSS(chunked), argnums=(0, 1, 2, 3, 4))(*args)
+    g_want = jax.grad(_LOSS(held_to), argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("q k v g beta".split(), g_got, g_want):
         assert bool(jnp.isfinite(a).all()) and _rel(a, b) < 1e-4, name
+
+
+@pytest.mark.parametrize("backend, shape, chunk, form", [
+    ("tpu", (2, 128, 16, 32, 128, 128), 64, "chunked 64: kernels"),
+    ("tpu", (2, 100, 2, 2, 256, 128), 64, "chunked 64: kernels"),
+    ("tpu", (2, 128, 2, 4, 16, 16), 64, "chunked 64: xla (d_k 16 is no multiple of 128)"),
+    ("tpu", (2, 128, 2, 4, 128, 64), 64, "chunked 64: xla (d_v 64 is no multiple of 128)"),
+    ("tpu", (2, 128, 2, 4, 128, 128), 32, "chunked 32: xla (chunk 32 is not 64)"),
+    ("cpu", (2, 128, 2, 4, 128, 128), 64, "chunked 64: xla"),
+], ids=["cell", "wide-keys", "narrow-keys", "narrow-values", "other-chunk", "cpu"])
+def test_which_program_takes_the_rule_is_read_from_the_input(monkeypatch, backend, shape, chunk, form):
+    """No knob: the kernels on a TPU where a head is whole lanes and the chunk is
+    64, the XLA form elsewhere, and ``CALLS`` says which and, on a TPU, why not
+    (every form starts ``chunked``, what ``gdn_chunked_calls_pct`` reads)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(gated_delta, "CALLS", {})
+    rows, seq, hk, hv, dk, dv = shape
+    like = lambda *x: jax.ShapeDtypeStruct(x, jnp.bfloat16)  # noqa: E731
+    out = jax.eval_shape(lambda *a: gated_delta.gated_delta_rule(*a, chunk=chunk), like(rows, seq, hk, dk), like(rows, seq, hk, dk),
+                         like(rows, seq, hv, dv), like(rows, seq, hv), like(rows, seq, hv))
+    assert out.shape == (rows, seq, hv, dv) and out.dtype == jnp.bfloat16
+    assert gated_delta.CALLS == {shape: [1, form]} and form in gated_delta.calls_summary()
 
 
 def test_unit_lower_inverse_and_its_derivative():
@@ -281,7 +321,7 @@ def test_the_step_reports_its_expert_counters(two_steps):
     assert 0.6 < float(m["expert_pairs_per_token"]) < 1.4  # 4 of 16 chosen, 4 held: 1 pair a token expected
     # every linear layer's rule was traced in the chunked form, whole rows of the microbatch
     calls, form = gated_delta.CALLS[ROWS, SEQ, 2, 4, 16, 16]
-    assert form == f"chunked {gated_delta.CHUNK}" and calls >= 6 and "chunked" in gated_delta.calls_summary()
+    assert form == f"chunked {gated_delta.CHUNK}: xla" and calls >= 6 and "chunked" in gated_delta.calls_summary()
 
 
 def test_right_padded_rows_train_and_match_the_reference_on_the_unpadded_part(flat, ids):
@@ -484,15 +524,20 @@ def test_a_bfloat16_router_fails_the_tolerance(flat, ids, monkeypatch):
     assert _logit_gap(flat, ids) > 10 * RTOL
 
 
-def test_a_bfloat16_state_in_the_scan_fails_the_tolerance(monkeypatch):
+@pytest.mark.parametrize("program", ["xla", "kernels"])
+def test_a_bfloat16_state_in_the_scan_fails_the_tolerance(monkeypatch, program):
     """Held at the rule itself, where chunked is held to token by token at
-    1e-5: the state carried in bfloat16 reads 2e-3 there. (On this tiny
-    model's logits it reads 4e-5: three mixers' outputs through ``out_proj``
-    at 0.02 move a logit little.)"""
-    args = _rule_inputs(3, 2, 128, 2, 4, 16, 8, 1.0)
-    assert _rel(gated_delta.gated_delta_rule(*args, chunk=32), _token_by_token(*args)) < 1e-5
+    1e-5: the state carried in bfloat16 reads 2e-3 there, in the XLA form's
+    scan and in the kernels' VMEM scratch alike (``STATE_DTYPE`` is read when
+    the rule is traced). (On this tiny model's logits it reads 4e-5: three
+    mixers' outputs through ``out_proj`` at 0.02 move a logit little.)"""
+    if program == "xla":
+        args, rule = _rule_inputs(3, 2, 128, 2, 4, 16, 8, 1.0), lambda *a: gated_delta.gated_delta_rule(*a, chunk=32)
+    else:
+        args, rule = _at_a_kernel_head(3, 128, 2, 1.0), _KERNELS
+    assert _rel(rule(*args), _token_by_token(*args)) < 1e-5
     monkeypatch.setattr(gated_delta, "STATE_DTYPE", jnp.bfloat16)
-    assert _rel(gated_delta.gated_delta_rule(*args, chunk=32), _token_by_token(*args)) > 10 * 1e-5
+    assert _rel(rule(*args), _token_by_token(*args)) > 10 * 1e-5
 
 
 @pytest.mark.parametrize("field, value", [
