@@ -12,7 +12,10 @@ the step hot path:
   MFU / HBM-BW gauges, the preemption flag, and
   ``training_anomalies_total{kind=...}``.
 - ``GET /v1/train/status`` — step/epoch/ETA, last + best eval, checkpoint
-  and publish history, anomaly summary.
+  and publish history, anomaly summary, and ``startup``: where the time
+  from process start to the first optimizer step went (seconds by phase,
+  compile-cache hits and misses), which a preempted job pays at every
+  restart.
 - ``GET /v1/train/flight`` — the trainer-owned FlightRecorder ring: step
   milestones, evals, checkpoint save/restore, publishes, watchdog events,
   SIGTERM/preemption.
@@ -305,6 +308,13 @@ class TrainTelemetry:
             "epoch": 0.0,
             "epochs": 0,
             "preempted": False,
+            # where start-up went, once the first step has finished: seconds
+            # by phase (observe/xla.py spans: startup/data, startup/weights,
+            # startup/optimizer, startup/restore, startup/first_step with the
+            # step program's train_step/load inside it), the seconds since the
+            # process started, the persistent compile cache's hits and misses
+            # (CompileLedger.setup_phases(); a restarted job pays all of it)
+            "startup": None,
         }
         self._checkpoints: deque = deque(maxlen=64)
         self._publishes: deque = deque(maxlen=64)

@@ -1,5 +1,5 @@
-"""XLA runtime introspection: compile ledger, cost-analysis utilization
-gauges, and on-demand profiler capture.
+"""XLA runtime introspection: compile ledger, the process's spans,
+cost-analysis utilization gauges, and on-demand profiler capture.
 
 The serving engines and the trainer dispatch a small set of jitted
 programs (decode tick, prefill chunk, speculative verify, draft step,
@@ -9,19 +9,41 @@ steady state silently costs seconds per occurrence and is always a bug
 (a stray shape bucket, a weak-type flip, a donated-buffer mismatch).
 This module makes that contract observable:
 
+- ``annotate()`` is the one span primitive: ``name``, ``start_ns``,
+  ``end_ns`` on the profiler's clock (``time.time_ns()``), the ``parent``
+  that was open on the same thread, small attributes. A span is a
+  ``jax.profiler.TraceAnnotation`` too, so a captured trace shows it,
+  and while set-up lasts it lands in the process's ``SpanRecorder``: a
+  bounded list plus counters that go on when the list is full. JAX's own
+  monitoring events feed it too (``install_compile_listeners``): every
+  jitted function's trace, lowering and backend compile as ``jit/trace``,
+  ``jit/lower``, ``jit/compile`` with its ``fun_name``, and the
+  persistent cache's hits and misses as counters.
 - ``CompileLedger`` records every compilation (program name, abstract
-  arg shapes, wall compile seconds, engine generation), deduplicates by
-  (program, shapes), and exposes ``recompiles_after_warmup`` — the
-  number that must stay zero once ``mark_warm()`` has been called.
-  Listeners (the engines' flight recorders) are notified of post-warmup
-  recompiles as they happen.
+  arg shapes, wall compile seconds split into trace, lowering and
+  backend compile, cache hit or miss), deduplicates by (program,
+  shapes), and exposes ``recompiles_after_warmup`` — the number that must
+  stay zero once ``mark_warm()`` has been called. Listeners (the engines'
+  flight recorders) are notified of post-warmup recompiles as they
+  happen. It owns the recorder's export: ``mark_warm()`` ends the root
+  span ``setup`` that began with the process, from then on a span is its
+  ``TraceAnnotation`` alone and nothing more is recorded, and
+  ``CompileLedger.setup()`` is what set-up recorded (spans, counters, the
+  dearest functions): an accessor of its own, so that ``snapshot()``, which
+  every scrape and logging step calls, stays at its totals.
 - ``instrument()`` wraps a jitted callable so its first call registers
   with the ledger. For engine hot-path programs (``aot=True``) the
-  first call goes through ``fn.lower(...).compile()`` — exact compile
-  wall time plus ``cost_analysis()`` FLOPs / bytes-accessed — and the
-  AOT executable becomes the dispatch target (one compile, not two).
-  Any AOT failure falls back permanently to the plain jit callable with
-  first-call wall timing (an upper bound on compile time).
+  first call goes through ``fn.lower(...)`` and ``.compile()`` under
+  ``<program>/load``: the one ``lower`` call's two stages are JAX's own
+  ``jit/trace`` and ``jit/lower`` spans of the function, directly under
+  that span (held apart as two calls the same module cost seconds more to
+  lower on the chip), ``<program>/compile`` and ``/first_dispatch`` are
+  spans of their own — exact seconds by stage plus ``cost_analysis()``
+  FLOPs / bytes-accessed — and the AOT executable becomes the dispatch
+  target (one compile, not two). Any AOT failure falls back permanently
+  to the plain jit callable with first-call wall timing (an upper bound
+  on compile time), and the ledger's entry says so (``aot: false``,
+  ``aot_error``).
 - ``device_peak_specs()`` + ``utilization_from_cost()`` turn the cost
   analysis and the ``decode_tick_s`` histogram into
   ``model_flops_utilization`` and ``hbm_bandwidth_utilization`` gauges
@@ -30,8 +52,6 @@ This module makes that contract observable:
 - ``ProfilerCapture`` guards ``jax.profiler`` traces for the serving
   ``POST /v1/profile`` endpoint: one capture at a time, auto-stop after
   the requested duration, a fresh subdirectory per capture.
-- ``annotate()`` yields ``jax.profiler.TraceAnnotation`` spans so tick
-  phases (admit/prefill/verify/sample) line up with captured traces.
 - ``scope()`` names the train step's operations (``STEP_SCOPES``) with
   ``jax.named_scope``: a device trace then carries, on every operation, the
   path of scopes it was traced under, and a reader can say which part of
@@ -40,7 +60,6 @@ This module makes that contract observable:
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import os
 import re
@@ -55,8 +74,10 @@ __all__ = [
     "CompileLedger",
     "ProfilerCapture",
     "STEP_SCOPES",
+    "SpanRecorder",
     "annotate",
     "device_peak_specs",
+    "install_compile_listeners",
     "instrument",
     "mosaic_programs",
     "scope",
@@ -64,7 +85,306 @@ __all__ = [
 ]
 
 
+# ------------------------------------------------------------------- spans
+
+MAX_SPANS = 8192  # kept spans
+# A jitted function's trace, lowering or compile shorter than this is counted (the
+# counters, by_function) and not kept: tracing the Qwen3-Next cell's step reports
+# 7,959 such stages, 7,741 of them under a millisecond and 0.47 s in all (my chip
+# run, PR 38), and it is the list's first spans that would crowd out the last.
+BRIEF_NS = 1_000_000
+_IMPORTED_NS = time.time_ns()
+
+# JAX's monitoring events (jax/_src/dispatch.py, compiler.py, compilation_cache.py)
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit/lower",
+    "/jax/core/compile/backend_compile_duration": "jit/compile",
+}
+_CACHE_COUNTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "compile_requests_use_cache",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_time_sec",
+    "/jax/compilation_cache/compile_time_saved_sec": "compile_time_saved_sec",
+}
+
+
+def _process_start_ns() -> int:
+    """When this process started, on the epoch clock: from ``/proc`` (the
+    interpreter's start-up and the imports count as set-up), else the import
+    of this module."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age_s = uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+        return time.time_ns() - int(age_s * 1e9)
+    except (OSError, ValueError, IndexError):
+        return _IMPORTED_NS
+
+
+class _Span:
+    """One open span: a context manager that is a ``TraceAnnotation`` as well,
+    so a profiler capture shows it under the same name. Once set-up is over
+    (``SpanRecorder.freeze()``) it is the annotation alone: ``record`` is None,
+    no clock is read and the recorder is not touched."""
+
+    __slots__ = ("_recorder", "_annotation", "record")
+
+    def __init__(self, recorder: "SpanRecorder", name: str, attrs: Dict[str, Any]):
+        self._recorder = recorder
+        self._annotation = jax.profiler.TraceAnnotation(name)
+        self.record: Optional[Dict[str, Any]] = None if recorder.frozen else {
+            "id": 0, "name": name, "start_ns": 0, "end_ns": 0, "parent": None, "thread": 0, **attrs}
+
+    def set(self, **attrs: Any) -> None:
+        if self.record is not None:
+            self.record.update(attrs)
+
+    def __enter__(self) -> "_Span":
+        if self.record is None:
+            self._annotation.__enter__()
+            return self
+        self._recorder._open_span(self.record)
+        self._annotation.__enter__()
+        self.record["start_ns"] = time.time_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.record is None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            return
+        self.record["end_ns"] = time.time_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            self.record["error"] = exc_type.__name__
+        self._recorder._close_span(self.record)
+
+
+class SpanRecorder:
+    """The spans and counters of the process's SET-UP. A span is a dict:
+    ``id``, ``name``, ``start_ns`` and ``end_ns`` from ``time.time_ns()``
+    (the clock of the profiler's host events, so a captured trace and these
+    lines need no conversion beyond the capture's own start, which an xplane
+    counts from), ``parent`` (the id of the span that was open on the same
+    thread when it started, else the root span ``setup``, id 0, which began
+    with the process), ``thread``, and small attributes (``program``,
+    ``fun_name``, ``cache``). Spans are kept until the list holds
+    ``max_spans`` (JAX's own stages only from a millisecond up); the
+    counters go on. ``freeze()`` ends ``setup``: what was recorded until
+    then is the set-up section, and nothing is recorded after it (the
+    engine's tick phases and a late compile are ``TraceAnnotation``s and
+    ledger entries, which have their own readers)."""
+
+    def __init__(self, max_spans: int = MAX_SPANS, start_ns: Optional[int] = None):
+        self._lock = threading.Lock()
+        # per thread: .open, the ids of its open spans; .cache, what it has asked of the
+        # persistent cache; .compiled_at, that tally at its last jit/compile
+        self._thread = threading.local()
+        self._ids = itertools.count(1)
+        self._max_spans = max_spans
+        self._root = {"id": 0, "name": "setup", "start_ns": _process_start_ns() if start_ns is None else start_ns,
+                      "end_ns": None, "parent": None, "thread": None}
+        self._spans: List[Dict[str, Any]] = []
+        self._by_function: Dict[str, List[float]] = {}  # fun_name -> [seconds, spans]
+        self.frozen = False
+        self.counters: Dict[str, float] = {"spans": 0, "spans_brief": 0, "spans_dropped": 0, "jit_seconds": 0.0}
+        self.counters.update(dict.fromkeys(_CACHE_COUNTS.values(), 0))
+        self.counters.update(dict.fromkeys(_CACHE_SECONDS.values(), 0.0))
+
+    def span(self, name: str, **attrs: Any) -> _Span:
+        return _Span(self, name, attrs)
+
+    def count(self, counter: str, by: float = 1) -> None:
+        if self.frozen:
+            return
+        with self._lock:
+            self.counters[counter] = self.counters.get(counter, 0) + by
+
+    def cache_tally(self) -> Tuple[int, int]:
+        """(requests, hits) this THREAD has made of the persistent cache:
+        JAX reports both from the thread that compiles."""
+        return getattr(self._thread, "cache", (0, 0))
+
+    def cache_verdict(self, since: Tuple[int, int]) -> str:
+        """What this thread's compiles asked of the persistent cache since
+        the tally ``since``: ``hit``, ``miss``, or ``off`` (nothing asked:
+        no cache directory, or a backend JAX does not cache for)."""
+        requests, hits = self.cache_tally()
+        if hits > since[1]:
+            return "hit"
+        return "miss" if requests > since[0] else "off"
+
+    def add_jit_stage(self, name: str, start_s: float, end_s: float, fun_name: str) -> None:
+        """One of JAX's three compile events, as it reports them when they
+        END: seconds of ``time.time()`` and the function's name (``sin`` where
+        it traces, ``jit(sin)`` where it lowers and compiles: one name here).
+        Its parent is the span open on this thread now; it is no
+        ``TraceAnnotation`` (a capture cannot be told afterwards). One under
+        ``BRIEF_NS`` is counted and not kept."""
+        if self.frozen:
+            return
+        if fun_name.startswith("jit(") and fun_name.endswith(")"):
+            fun_name = fun_name[4:-1]
+        start_ns, end_ns = int(start_s * 1e9), int(end_s * 1e9)
+        record = {"id": 0, "name": name, "start_ns": start_ns, "end_ns": end_ns, "parent": None, "thread": 0,
+                  "fun_name": fun_name}
+        if name == "jit/compile":
+            record["cache"] = self.cache_verdict(getattr(self._thread, "compiled_at", (0, 0)))
+            self._thread.compiled_at = self.cache_tally()
+        self._open_span(record)
+        self._close_span(record, keep=end_ns - start_ns >= BRIEF_NS)
+
+    def seconds_under(self, parent: _Span, name: str) -> Optional[float]:
+        """Seconds of the kept spans ``name`` directly under ``parent``; None
+        where there is none (under a millisecond, a full list, set-up over)."""
+        if parent.record is None:
+            return None
+        with self._lock:
+            found = [s["end_ns"] - s["start_ns"] for s in self._spans
+                     if s["parent"] == parent.record["id"] and s["name"] == name]
+        return sum(found) / 1e9 if found else None
+
+    def _cache_event(self, counter: str) -> None:
+        requests, hits = self.cache_tally()
+        if counter == "compile_requests_use_cache":
+            self._thread.cache = (requests + 1, hits)
+        elif counter == "cache_hits":
+            self._thread.cache = (requests, hits + 1)
+        self.count(counter)
+
+    def _open_span(self, record: Dict[str, Any]) -> None:
+        stack = getattr(self._thread, "open", None)
+        if stack is None:
+            stack = self._thread.open = []
+        record["id"] = next(self._ids)
+        record["thread"] = threading.get_ident()
+        record["parent"] = stack[-1] if stack else 0
+        stack.append(record["id"])
+
+    def _close_span(self, record: Dict[str, Any], keep: bool = True) -> None:
+        self._thread.open.pop()
+        seconds = (record["end_ns"] - record["start_ns"]) / 1e9
+        with self._lock:
+            if self.frozen:  # it was open when set-up ended
+                return
+            self.counters["spans"] += 1
+            if not keep:
+                self.counters["spans_brief"] += 1
+            elif len(self._spans) < self._max_spans:
+                self._spans.append(record)
+            else:
+                self.counters["spans_dropped"] += 1
+            if "fun_name" in record:
+                self.counters["jit_seconds"] += seconds
+                if record["fun_name"] in self._by_function or len(self._by_function) < self._max_spans:
+                    entry = self._by_function.setdefault(record["fun_name"], [0.0, 0])
+                    entry[0] += seconds
+                    entry[1] += 1
+
+    def freeze(self) -> None:
+        """End ``setup`` (once: the first call of a process decides)."""
+        with self._lock:
+            if not self.frozen:
+                self._root["end_ns"] = time.time_ns()
+                self.frozen = True
+
+    def section(self) -> Dict[str, Any]:
+        """What set-up recorded, the caller's own copy: the whole of it once
+        ``freeze()`` has ended it, else what there is so far (``setup`` still
+        open, its ``end_ns`` None). ``by_function`` adds a function's
+        ``jit/*`` spans: a function that calls jitted functions counts their
+        tracing too."""
+        with self._lock:
+            dearest = sorted(self._by_function.items(), key=lambda kv: -kv[1][0])[:20]
+            return {
+                "spans": [dict(self._root)] + [dict(s) for s in self._spans],
+                "counters": dict(self.counters),
+                "by_function": [{"fun_name": k, "seconds": round(v[0], 6), "spans": v[1]} for k, v in dearest],
+            }
+
+
+_RECORDER = SpanRecorder()
+_LISTENING = threading.Lock()
+_listeners_installed = False
+
+
+def _on_compile_span(event: str, start_s: float, end_s: float, **kw: Any) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is not None:
+        _RECORDER.add_jit_stage(name, start_s, end_s, str(kw.get("fun_name", "?")))
+
+
+def _on_cache_event(event: str, **kw: Any) -> None:
+    counter = _CACHE_COUNTS.get(event)
+    if counter is not None:
+        _RECORDER._cache_event(counter)
+
+
+def _on_cache_seconds(event: str, seconds: float, **kw: Any) -> None:
+    counter = _CACHE_SECONDS.get(event)
+    if counter is not None:
+        _RECORDER.count(counter, seconds)
+
+
+def install_compile_listeners() -> None:
+    """Feed the recorder from JAX's monitoring events: once a process,
+    however often and from whichever thread it is called (the first
+    ``CompileLedger()``; ``runtime/compile_cache.enable_compile_cache()``,
+    which every entry point calls before anything compiles)."""
+    global _listeners_installed
+    with _LISTENING:
+        if _listeners_installed:
+            return
+        jax.monitoring.register_event_time_span_listener(_on_compile_span)
+        jax.monitoring.register_event_listener(_on_cache_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+        _listeners_installed = True
+
+
+def annotate(name: str, **attrs: Any) -> _Span:
+    """The span primitive: ``with annotate("prefill"):`` is a
+    ``jax.profiler.TraceAnnotation`` (a captured trace lines up with the
+    request timeline) and, while set-up lasts, a record in the process's
+    ``SpanRecorder``; after ``mark_warm()`` it is the annotation alone."""
+    return _RECORDER.span(name, **attrs)
+
+
 # ------------------------------------------------------------------ ledger
+
+
+_STAGE_SECONDS = ("trace_s", "lower_s", "backend_compile_s")
+
+
+def _fold_program(into: Dict[str, Any], part: Dict[str, Any]) -> None:
+    """Add one entry (or one snapshot's program) to a program's totals:
+    counts and seconds add up, ``cache`` and ``aot`` are the newest's."""
+    into["compiles"] += part["compiles"]
+    into["compile_s"] += part["compile_s"]
+    for key in _STAGE_SECONDS:
+        if key in part:
+            into[key] = into.get(key, 0.0) + part[key]
+    for key in ("cache", "aot", "aot_error"):
+        if key in part:
+            into[key] = part[key]
+
+
+def _programs_snapshot(programs: Dict[str, Dict[str, Any]], recompiles: int, warmed: bool) -> Dict[str, Any]:
+    for p in programs.values():
+        for key in ("compile_s", *_STAGE_SECONDS):
+            if key in p:
+                p[key] = round(p[key], 6)
+    return {
+        "programs": programs,
+        "total_compiles": sum(p["compiles"] for p in programs.values()),
+        "total_compile_s": round(sum(p["compile_s"] for p in programs.values()), 6),
+        "recompiles_after_warmup": recompiles,
+        "warmed": warmed,
+    }
 
 
 class CompileLedger:
@@ -73,18 +393,21 @@ class CompileLedger:
     bumps its compile count (a cache rebuild), and any record after
     ``mark_warm()`` increments ``recompiles_after_warmup`` and notifies
     listeners — steady-state recompile is a bug, and this is the counter
-    that proves its absence.
+    that proves its absence. The ledger also exports the process's
+    ``SpanRecorder`` (``setup()``, ``setup_phases()``): the first
+    ``mark_warm()`` of a process ends set-up.
     """
 
     def __init__(self) -> None:
+        install_compile_listeners()
         self._lock = threading.Lock()
         self._entries: Dict[Tuple[str, str], Dict[str, Any]] = {}
         self._seq = 0
         self._warmed = False
         self.recompiles_after_warmup = 0
-        # engines stamp their supervisor generation here so ledger entries
-        # attribute to the engine incarnation that compiled them (replicas
-        # sharing one Generator share one ledger; the stamp is best-effort)
+        # engines stamp their supervisor generation here so a post-warmup
+        # recompile is reported with the engine incarnation that made it
+        # (replicas sharing one Generator share one ledger; best-effort)
         self.current_generation = 0
         self._listeners: List[Callable[..., None]] = []
 
@@ -95,24 +418,22 @@ class CompileLedger:
         compile_s: float,
         flops: Optional[float] = None,
         bytes_accessed: Optional[float] = None,
+        parts: Optional[Dict[str, Any]] = None,
     ) -> None:
+        """``parts``: what ``instrument()`` knows of this compilation beyond
+        its seconds: ``backend_compile_s`` and, where the recorder kept JAX's
+        lowering of the function, ``trace_s`` and ``lower_s`` (the three add
+        up to ``compile_s``), ``cache`` (hit, miss, off), ``aot`` and, where
+        the AOT path was given up, ``aot_error``."""
         sig = shapes if isinstance(shapes, str) else str(tuple(shapes)) if isinstance(shapes, (list, tuple)) else str(shapes)
         with self._lock:
             self._seq += 1
             entry = self._entries.get((program, sig))
             if entry is None:
-                entry = {
-                    "compiles": 0,
-                    "compile_s": 0.0,
-                    "flops": None,
-                    "bytes_accessed": None,
-                    "generation": self.current_generation,
-                }
+                entry = {"compiles": 0, "compile_s": 0.0, "flops": None, "bytes_accessed": None}
                 self._entries[(program, sig)] = entry
-            entry["compiles"] += 1
-            entry["compile_s"] += float(compile_s)
+            _fold_program(entry, dict(parts or {}, compiles=1, compile_s=float(compile_s)))
             entry["seq"] = self._seq
-            entry["generation"] = self.current_generation
             if flops is not None:
                 entry["flops"] = float(flops)
             if bytes_accessed is not None:
@@ -129,9 +450,11 @@ class CompileLedger:
                     pass  # a broken listener must never fail a dispatch
 
     def mark_warm(self) -> None:
-        """Declare warmup over: every record from here on is a recompile."""
+        """Declare warmup over: every record from here on is a recompile,
+        and the process's set-up has ended (``setup()`` stands)."""
         with self._lock:
             self._warmed = True
+        _RECORDER.freeze()
 
     @property
     def warmed(self) -> bool:
@@ -159,22 +482,36 @@ class CompileLedger:
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            programs: Dict[str, Dict[str, float]] = {}
-            for (name, _), e in self._entries.items():
-                p = programs.setdefault(name, {"compiles": 0, "compile_s": 0.0})
-                p["compiles"] += e["compiles"]
-                p["compile_s"] += e["compile_s"]
-            for p in programs.values():
-                p["compile_s"] = round(p["compile_s"], 6)
-            return {
-                "programs": programs,
-                "total_compiles": sum(p["compiles"] for p in programs.values()),
-                "total_compile_s": round(
-                    sum(p["compile_s"] for p in programs.values()), 6
-                ),
-                "recompiles_after_warmup": self.recompiles_after_warmup,
-                "warmed": self._warmed,
-            }
+            programs: Dict[str, Dict[str, Any]] = {}
+            for (name, _), e in sorted(self._entries.items(), key=lambda kv: kv[1]["seq"]):
+                _fold_program(programs.setdefault(name, {"compiles": 0, "compile_s": 0.0}), e)
+            return _programs_snapshot(programs, self.recompiles_after_warmup, self._warmed)
+
+    @staticmethod
+    def setup() -> Dict[str, Any]:
+        """What the PROCESS's set-up recorded, the same under every ledger:
+        ``{"spans": [the root span setup, then every kept span], "counters":
+        {...}, "by_function": the 20 dearest functions}``; the caller's own
+        copy. Not in ``snapshot()``: that is read at every scrape and logging
+        step, this once a run."""
+        return _RECORDER.section()
+
+    @staticmethod
+    def setup_phases() -> Dict[str, Any]:
+        """The operator's reading of set-up so far: seconds by span name in
+        order of first start (``startup/weights``, ``train_step/load``; JAX's
+        own ``jit/*`` spans are their children and stay out), the seconds
+        since the process started, and the persistent cache's counters."""
+        section = _RECORDER.section()
+        root, phases = section["spans"][0], {}
+        for span in sorted(section["spans"][1:], key=lambda sp: sp["start_ns"]):
+            if "fun_name" not in span:
+                phases[span["name"]] = phases.get(span["name"], 0.0) + (span["end_ns"] - span["start_ns"]) / 1e9
+        return {
+            "phases_s": {name: round(secs, 3) for name, secs in phases.items()},
+            "since_process_start_s": round(((root["end_ns"] or time.time_ns()) - root["start_ns"]) / 1e9, 3),
+            **{name: section["counters"][name] for name in _CACHE_COUNTS.values()},
+        }
 
     @staticmethod
     def merge(ledgers: Iterable["CompileLedger"]) -> Dict[str, Any]:
@@ -185,28 +522,16 @@ class CompileLedger:
         for led in ledgers:
             if led is not None:
                 seen.setdefault(id(led), led)
-        programs: Dict[str, Dict[str, float]] = {}
+        programs: Dict[str, Dict[str, Any]] = {}
         recompiles = 0
         warmed = bool(seen)
         for led in seen.values():
             snap = led.snapshot()
             for name, p in snap["programs"].items():
-                agg = programs.setdefault(name, {"compiles": 0, "compile_s": 0.0})
-                agg["compiles"] += p["compiles"]
-                agg["compile_s"] += p["compile_s"]
+                _fold_program(programs.setdefault(name, {"compiles": 0, "compile_s": 0.0}), p)
             recompiles += snap["recompiles_after_warmup"]
             warmed = warmed and snap["warmed"]
-        for p in programs.values():
-            p["compile_s"] = round(p["compile_s"], 6)
-        return {
-            "programs": programs,
-            "total_compiles": sum(p["compiles"] for p in programs.values()),
-            "total_compile_s": round(
-                sum(p["compile_s"] for p in programs.values()), 6
-            ),
-            "recompiles_after_warmup": recompiles,
-            "warmed": warmed,
-        }
+        return _programs_snapshot(programs, recompiles, warmed)
 
 
 # ----------------------------------------------------- program instrumenting
@@ -259,7 +584,8 @@ class _InstrumentedProgram:
     calls straight to that executable; an AOT failure (python-scalar
     args, donation quirks, old JAX) falls back to the plain jit callable
     for that signature, timing its first call as an upper bound on
-    compile time. Dispatch is keyed by the abstract shapes of the actual
+    compile time (the ledger's entry then says ``aot: false`` and, under
+    ``aot_error``, what the AOT path raised). Dispatch is keyed by the abstract shapes of the actual
     call, NOT the owner's cache key: a Generator's jit-cache key doesn't
     fully determine shapes (two engines with different slot counts share
     one Generator, so one ``slot_prefill`` bucket entry sees two cache
@@ -297,25 +623,46 @@ class _InstrumentedProgram:
 
     def _first_call(self, sig, args, kwargs):
         shapes = sig if self._shapes is None else f"{self._shapes}{sig}"
+        name, recorder = self._program, _RECORDER
+        parts: Dict[str, Any] = {"aot": False}
         if self._aot:
             try:
-                t0 = time.perf_counter()
-                compiled = self._fn.lower(*args, **kwargs).compile()
-                dt = time.perf_counter() - t0
-                flops, nbytes = _extract_cost(compiled)
-                out = compiled(*args, **kwargs)
+                with recorder.span(f"{name}/load", program=name) as loading:
+                    # ONE call, as ever: its two stages are JAX's own jit/trace and
+                    # jit/lower events, which land under this span. Held apart
+                    # (fn.trace(...), then traced.lower()) the same module cost the
+                    # benchmark's cells 1.6 to 5.4 s more to lower on the chip
+                    # (PERF.md, PR 38).
+                    t0 = time.perf_counter()
+                    lowered = self._fn.lower(*args, **kwargs)
+                    t1 = time.perf_counter()
+                    asked = recorder.cache_tally()
+                    with recorder.span(f"{name}/compile", program=name) as compiling:
+                        compiled = lowered.compile()
+                        cache = recorder.cache_verdict(asked)
+                        compiling.set(cache=cache)
+                    t2 = time.perf_counter()
+                    flops, nbytes = _extract_cost(compiled)
+                    with recorder.span(f"{name}/first_dispatch", program=name):
+                        out = compiled(*args, **kwargs)
                 # record only after a successful execute: if the AOT
                 # artifact can't even run, the plain-jit retry below must
                 # own the ledger entry
-                self._ledger.record(self._program, shapes, dt, flops, nbytes)
+                stages = {"backend_compile_s": t2 - t1, "cache": cache, "aot": True}
+                lower_s = recorder.seconds_under(loading, "jit/lower")
+                if lower_s is not None:  # the rest of the call is tracing (and the call's own handling of its arguments)
+                    stages.update(trace_s=t1 - t0 - lower_s, lower_s=lower_s)
+                self._ledger.record(name, shapes, t2 - t0, flops, nbytes, stages)
                 self._calls[sig] = compiled
                 return out
-            except Exception:
-                pass
+            except Exception as e:
+                parts["aot_error"] = f"{type(e).__name__}: {e}"[:300]
+        asked = recorder.cache_tally()
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        dt = time.perf_counter() - t0
-        self._ledger.record(self._program, shapes, dt)
+        with recorder.span(f"{name}/load", program=name, aot=False):
+            out = self._fn(*args, **kwargs)
+        parts["cache"] = recorder.cache_verdict(asked)
+        self._ledger.record(name, shapes, time.perf_counter() - t0, parts=parts)
         self._calls[sig] = self._fn
         return out
 
@@ -465,16 +812,6 @@ class ProfilerCapture:
                 self._on_event(kind, **fields)
             except Exception:
                 pass
-
-
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation`` span (nullcontext when the
-    profiler lacks it) — wraps tick phases so captures line up with the
-    request timeline."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
 
 
 # The train step's scope vocabulary. ``layer`` takes the layer's index

@@ -24,7 +24,13 @@ _CHECKOUT = os.path.dirname(
 def enable_compile_cache() -> str | None:
     """Call first thing in an entry point, before anything compiles. Returns
     the directory the process caches in, or None when it set none (an
-    installed package without ``JAX_COMPILATION_CACHE_DIR``)."""
+    installed package without ``JAX_COMPILATION_CACHE_DIR``). From here on the
+    process's span recorder hears JAX's compile and cache events
+    (``observe/xla.install_compile_listeners``): what set-up compiles, and
+    what it finds in the cache, is counted from the first program."""
+    from llm_fine_tune_distributed_tpu.observe.xla import install_compile_listeners
+
+    install_compile_listeners()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -48,7 +48,7 @@ from llm_fine_tune_distributed_tpu.observe.trainplane import (
     TrainControlPlane,
     TrainTelemetry,
 )
-from llm_fine_tune_distributed_tpu.observe.xla import CompileLedger, instrument
+from llm_fine_tune_distributed_tpu.observe.xla import CompileLedger, annotate, instrument
 from llm_fine_tune_distributed_tpu.parallel.freeze import describe_trainable, trainable_mask
 from llm_fine_tune_distributed_tpu.parallel.optimizer import (
     build_lr_schedule,
@@ -124,7 +124,10 @@ class SFTTrainer:
             os.makedirs(os.path.join(config.output_dir, "best_model"), exist_ok=True)
         device_preflight()
 
-        self._prepare_data()
+        # start-up by phase (observe/xla.py spans; train() adds the restore and
+        # the first step, prints them and hands them to /v1/train/status)
+        with annotate("startup/data"):
+            self._prepare_data()
         self._prepare_state()
         self._prepare_steps()
 
@@ -381,6 +384,30 @@ class SFTTrainer:
         return init_params(self.rng, mc, dtype=init_dtype)
 
     def _prepare_state(self) -> None:
+        cfg = self.config
+        with annotate("startup/weights"):  # load or init, freeze split, casts, shard
+            trainable, frozen = self._sharded_params()
+        with annotate("startup/optimizer"):
+            self.optimizer = build_optimizer(
+                cfg, None, total_steps=self.total_steps, data_parallel_size=self.dp_size
+            )
+            # Adam moments on their params' shardings, scalar leaves (the step
+            # count) replicated: the whole state shares the mesh's device set
+            opt_state = init_opt_state(self.optimizer, trainable, self.mesh)
+            self.state = TrainState(
+                # replicated over the mesh so restore() places it consistently
+                step=jax.device_put(
+                    jnp.zeros((), jnp.int32), NamedSharding(self.mesh, P())
+                ),
+                trainable=trainable,
+                frozen=frozen,
+                opt_state=opt_state,
+            )
+        self.lr_schedule = build_lr_schedule(cfg, self.total_steps, self.dp_size)
+
+    def _sharded_params(self):
+        """(trainable, frozen): the weights loaded or initialised, split by
+        the freeze policy, cast to their master dtypes and put on the mesh."""
         cfg, mc = self.config, self.model_config
         params = self._load_or_init_params()
         if cfg.freeze_strategy in ("lora", "qlora"):
@@ -507,25 +534,7 @@ class SFTTrainer:
                 for k, v in flat.items()
             }
 
-        trainable = put(trainable)
-        frozen = put(frozen)
-
-        self.optimizer = build_optimizer(
-            cfg, None, total_steps=self.total_steps, data_parallel_size=self.dp_size
-        )
-        # Adam moments on their params' shardings, scalar leaves (the step
-        # count) replicated: the whole state shares the mesh's device set
-        opt_state = init_opt_state(self.optimizer, trainable, self.mesh)
-        self.state = TrainState(
-            # replicated over the mesh so restore() places it consistently
-            step=jax.device_put(
-                jnp.zeros((), jnp.int32), NamedSharding(self.mesh, P())
-            ),
-            trainable=trainable,
-            frozen=frozen,
-            opt_state=opt_state,
-        )
-        self.lr_schedule = build_lr_schedule(cfg, self.total_steps, self.dp_size)
+        return put(trainable), put(frozen)
 
     def _validated_spec(self, path: str, leaf) -> P:
         from llm_fine_tune_distributed_tpu.parallel.sharding import _validate_spec
@@ -1004,7 +1013,8 @@ class SFTTrainer:
 
         resumed_step = 0
         if cfg.resume_from_checkpoint:
-            resumed_step = self._resume(ckpt)
+            with annotate("startup/restore"):
+                resumed_step = self._resume(ckpt)
         start_epoch = resumed_step // self.steps_per_epoch
         # Mid-epoch resume: skip the batches this epoch already consumed
         # (loader epochs are deterministic) so no sample trains twice and the
@@ -1143,6 +1153,11 @@ class SFTTrainer:
                 phase_hist["data_wait"].observe(time.perf_counter() - t0)
                 yield batch
 
+        # open until the first step's call has returned (first batch, the step
+        # program's load, its first dispatch: the device's time is not waited
+        # for); the steps after it get no span
+        first_step = annotate("startup/first_step")
+        first_step.__enter__()
         try:
             for epoch in range(start_epoch, cfg.epochs):
                 batches = self.loader.epoch(epoch)
@@ -1177,24 +1192,38 @@ class SFTTrainer:
                             )
                         raise
                     step += 1
-                    if step == resumed_step + 1 and is_primary_host():
-                        # the step program is traced now: say which attention
-                        # path it holds (a flash request that took XLA
-                        # attention names its reason) and on what it runs
-                        from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
-                        from llm_fine_tune_distributed_tpu.ops.attention import (
-                            dispatch_summary,
-                        )
+                    if step == resumed_step + 1:
+                        # the first step is on its way: start-up has its phases
+                        first_step.__exit__(None, None, None)
+                        first_step = None
+                        startup = self.compile_ledger.setup_phases()
+                        self.telemetry.update(startup=startup)
+                        if is_primary_host():
+                            print(
+                                "[train] start-up: "
+                                + ", ".join(f"{k} {v:.1f} s" for k, v in startup["phases_s"].items())
+                                + f"; {startup['since_process_start_s']:.1f} s since the process started; "
+                                f"compile cache: {startup['cache_hits']} hits, {startup['cache_misses']} "
+                                f"misses (JAX counts the entries it writes), {startup['compile_requests_use_cache']} requests",
+                                flush=True,
+                            )
+                            # the step program is traced now: say which attention
+                            # path it holds (a flash request that took XLA
+                            # attention names its reason) and on what it runs
+                            from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
+                            from llm_fine_tune_distributed_tpu.ops.attention import (
+                                dispatch_summary,
+                            )
 
-                        print(
-                            f"[train] step program traced on "
-                            f"{jax.default_backend()}; {dispatch_summary()}",
-                            flush=True,
-                        )
-                        if moe.SUM_PROGRAMS:  # routed experts: kernel or loop, and why
-                            print(f"[train] {moe.sum_programs_summary()}", flush=True)
-                        if gated_delta.CALLS:  # linear-attention layers: the form their rule took
-                            print(f"[train] {gated_delta.calls_summary()}", flush=True)
+                            print(
+                                f"[train] step program traced on "
+                                f"{jax.default_backend()}; {dispatch_summary()}",
+                                flush=True,
+                            )
+                            if moe.SUM_PROGRAMS:  # routed experts: kernel or loop, and why
+                                print(f"[train] {moe.sum_programs_summary()}", flush=True)
+                            if gated_delta.CALLS:  # linear-attention layers: the form their rule took
+                                print(f"[train] {gated_delta.calls_summary()}", flush=True)
                     pending_samples += samples_per_step
                     # real-token accounting for the throughput meter: a host
                     # numpy mean over the loader's (pre-device) mask — cheap
@@ -1372,6 +1401,8 @@ class SFTTrainer:
                 if preempted:
                     break
         finally:
+            if first_step is not None:  # no step ran, or the first one raised
+                first_step.__exit__(None, None, None)
             profiler.close()
             if detector is not None:
                 detector.stop()

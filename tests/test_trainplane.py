@@ -503,6 +503,41 @@ def _wait_plane(trainer, timeout=120.0):
     raise AssertionError("control plane never came up")
 
 
+def test_startup_phases_ride_status_and_the_log(qa_parquet, tmp_path, capsys, monkeypatch):  # noqa: F811
+    """Where the time from process start to the first optimizer step went:
+    the trainer's start-up under spans (observe/xla.py), printed once the
+    first step has finished and served as ``startup`` of /v1/train/status."""
+    from llm_fine_tune_distributed_tpu.observe import xla
+    from llm_fine_tune_distributed_tpu.train.trainer import SFTTrainer
+
+    # a recorder of this test's own: the test run's first mark_warm() froze the process's
+    monkeypatch.setattr(xla, "_RECORDER", xla.SpanRecorder())
+    data_dir, dataset_file = qa_parquet
+    config = make_config(
+        tmp_path / "out", data_dir, dataset_file, epochs=1, eval_steps=2, save_steps=100,
+        system_prompt="Be brief.", use_native_loader=False,
+    )
+    trainer = SFTTrainer(config)
+    assert trainer.telemetry.status()["startup"] is None  # no step yet
+    trainer.train()
+    startup = trainer.telemetry.status()["startup"]
+    phases = startup["phases_s"]
+    assert list(phases) == ["startup/data", "startup/weights", "startup/optimizer", "startup/first_step",
+                            "train_step/load"]  # in order of start; no restore: nothing was resumed
+    assert all(v >= 0.0 for v in phases.values())
+    assert phases["train_step/load"] <= phases["startup/first_step"]  # the step's load is inside its first step
+    assert startup["since_process_start_s"] >= sum(phases.values()) - phases["train_step/load"]
+    assert {"cache_hits", "cache_misses", "compile_requests_use_cache"} <= set(startup)
+    line = [ln for ln in capsys.readouterr().out.splitlines() if ln.startswith("[train] start-up: ")]
+    assert len(line) == 1 and "startup/weights" in line[0] and "train_step/load" in line[0]
+    # set-up ended at the warm boundary, with the eval programs' loads in it; the step's load says it was no AOT compile
+    snap = trainer.compile_ledger.snapshot()
+    assert snap["warmed"] and "setup" not in snap  # a scrape's snapshot stays at its totals
+    assert trainer.compile_ledger.setup()["spans"][0]["end_ns"] is not None
+    assert snap["programs"]["train_step"]["aot"] is False and "aot_error" not in snap["programs"]["train_step"]
+    json.dumps(trainer.telemetry.status())  # the status stays JSON-ready
+
+
 @pytest.mark.slow
 def test_train_serves_live_plane_and_clean_lineage(qa_parquet, tmp_path):  # noqa: F811
     from llm_fine_tune_distributed_tpu.train.publish import (

@@ -9,7 +9,15 @@ What this file pins, layer by layer:
   Generator share one ledger object — identity dedup);
 - ``instrument()``: first call registers with the ledger (AOT path with
   cost analysis, or plain-jit wall timing), later calls don't re-record,
-  and outputs are identical either way;
+  and outputs are identical either way; the AOT path's seconds by stage
+  add up to ``compile_s``, say hit or miss of the persistent cache, and a
+  fallback says why;
+- the span recorder: parent links (JAX's own compile events land under
+  the span open on their thread), the bounded list whose counters go on,
+  the set-up section frozen by the first ``mark_warm()`` (nothing is
+  recorded after it) while later compiles still count as recompiles,
+  listeners installed once, and the section the process's own, in no
+  ledger's snapshot;
 - utilization math: ``utilization_from_cost`` clamps to [0, 1] and
   returns 0.0 on unknowns; ``device_peak_specs`` knows the v5e by its real
   ``device_kind``, gives a CPU (0, 0) and raises on an unknown accelerator;
@@ -25,6 +33,9 @@ What this file pins, layer by layer:
 
 import json
 import os
+import subprocess
+import sys
+import threading
 import time
 
 import jax
@@ -44,11 +55,13 @@ from llm_fine_tune_distributed_tpu.infer.engine import (
 from llm_fine_tune_distributed_tpu.infer.errors import RetryableEngineError
 from llm_fine_tune_distributed_tpu.models.configs import get_preset
 from llm_fine_tune_distributed_tpu.models.transformer import init_params
+from llm_fine_tune_distributed_tpu.observe import xla
 from llm_fine_tune_distributed_tpu.observe.tracing import RequestTrace
 from llm_fine_tune_distributed_tpu.observe.xla import (
     CaptureBusyError,
     CompileLedger,
     ProfilerCapture,
+    SpanRecorder,
     annotate,
     device_peak_specs,
     instrument,
@@ -210,7 +223,231 @@ def test_instrument_aot_falls_back_on_unlowerable_fn():
 
 def test_annotate_is_a_usable_context():
     with annotate("admit"):
-        pass  # TraceAnnotation or nullcontext — either must just work
+        pass  # a TraceAnnotation and a record in the recorder; it must just work
+
+
+def test_instrument_aot_fallback_is_recorded_with_its_reason():
+    led = CompileLedger()
+    wrapped = instrument("plain", lambda x: x + 1, led, aot=True)  # no .lower
+    assert wrapped(41) == 42
+    program = led.snapshot()["programs"]["plain"]
+    assert program["aot"] is False and program["aot_error"].startswith("AttributeError")
+    assert "trace_s" not in program  # a first call's wall time, and it says so
+    ok = CompileLedger()
+    instrument("double", jax.jit(lambda x: x * 2), ok)(jnp.arange(4.0))
+    assert ok.snapshot()["programs"]["double"]["aot"] is True
+    assert "aot_error" not in ok.snapshot()["programs"]["double"]
+
+
+# ------------------------------------------------------------ span recorder
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    """A recorder of this test's own in place of the process's (which the
+    first ``mark_warm()`` of the test run has long frozen)."""
+    fresh = SpanRecorder()
+    monkeypatch.setattr(xla, "_RECORDER", fresh)
+    return fresh
+
+
+def test_annotate_is_a_span_with_parent_links(recorder):
+    with annotate("tick") as tick:
+        with annotate("prefill", program="paged_step") as prefill:
+            pass
+        with annotate("sample"):
+            pass
+    spans = {s["name"]: s for s in recorder.section()["spans"]}
+    assert spans["setup"]["id"] == 0 and spans["setup"]["end_ns"] is None  # set-up still lasts
+    assert spans["tick"]["parent"] == 0  # nothing open on the thread: under the root
+    assert spans["prefill"]["parent"] == spans["sample"]["parent"] == spans["tick"]["id"]
+    assert spans["prefill"]["program"] == "paged_step"
+    for s in (spans["tick"], spans["prefill"], spans["sample"]):
+        assert s["thread"] == threading.get_ident()
+        assert spans["setup"]["start_ns"] < s["start_ns"] <= s["end_ns"] <= time.time_ns()  # the epoch clock
+    assert spans["tick"]["start_ns"] <= spans["prefill"]["start_ns"] <= spans["sample"]["start_ns"]
+    assert tick.record is spans["tick"] or tick.record == spans["tick"]  # the span's own record is what is kept
+    # another thread's span does not take this thread's open span as its parent
+    with annotate("tick"):
+        t = threading.Thread(target=lambda: annotate("elsewhere").__enter__().__exit__(None, None, None))
+        t.start()
+        t.join()
+    assert [s for s in recorder.section()["spans"] if s["name"] == "elsewhere"][0]["parent"] == 0
+
+
+def test_a_span_that_raises_is_closed_and_says_so(recorder):
+    with pytest.raises(ValueError):
+        with annotate("prefill"):
+            raise ValueError("x")
+    with annotate("sample"):
+        pass
+    spans = {s["name"]: s for s in recorder.section()["spans"]}
+    assert spans["prefill"]["error"] == "ValueError" and spans["sample"]["parent"] == 0
+
+
+def _busy():
+    """A function of this call's own (JAX finds nothing of it in its caches) with enough
+    operations that its lowering lasts a millisecond, and is kept."""
+    def busy(x):
+        for i in range(60):
+            x = jnp.sin(x) * (i + 1.0)
+        return x
+    return jax.jit(busy)
+
+
+def test_jax_compile_events_land_under_the_open_program_span(recorder):
+    led = CompileLedger()
+    wrapped = instrument("busy", _busy(), led)
+    wrapped(jnp.arange(8, dtype=jnp.float32))
+    spans = recorder.section()["spans"]
+    by_name = {s["name"]: s for s in spans if s["name"].startswith("busy/")}
+    assert set(by_name) == {"busy/load", "busy/compile", "busy/first_dispatch"}
+    load = by_name["busy/load"]
+    assert by_name["busy/compile"]["parent"] == by_name["busy/first_dispatch"]["parent"] == load["id"]
+    # tracing and lowering are ONE call (fn.lower): its stages are JAX's own spans of the function, under the load
+    trace, = [s for s in spans if s["name"] == "jit/trace" and s["fun_name"] == "busy"]
+    lower, = [s for s in spans if s["name"] == "jit/lower" and s["fun_name"] == "busy"]
+    assert trace["parent"] == lower["parent"] == load["id"]
+    assert load["start_ns"] <= trace["start_ns"] < trace["end_ns"] <= lower["start_ns"] < lower["end_ns"]
+    assert lower["end_ns"] <= by_name["busy/compile"]["start_ns"] + 1000
+    compile_span = [s for s in spans if s["name"] == "jit/compile" and s["parent"] == by_name["busy/compile"]["id"]][0]
+    assert compile_span["cache"] == by_name["busy/compile"]["cache"] in ("hit", "miss", "off")
+    section = recorder.section()
+    names = {f["fun_name"]: f["spans"] for f in section["by_function"]}
+    assert names["busy"] == 3 and len(names) <= 20  # every stage counts for its function, kept or not
+    assert section["counters"]["spans"] == len(section["spans"]) - 1 + section["counters"]["spans_brief"]
+    section["spans"].clear()  # the caller's own copy
+    assert len(recorder.section()["spans"]) == len(spans)
+
+
+def test_stage_seconds_add_up_to_compile_s(recorder):
+    led = CompileLedger()
+    wrapped = instrument("busy", _busy(), led)
+    wrapped(jnp.arange(8, dtype=jnp.float32))
+    wrapped(jnp.arange(16, dtype=jnp.float32))  # a second signature: the stages add up over both
+    p = led.snapshot()["programs"]["busy"]
+    assert p["compiles"] == 2
+    assert p["trace_s"] + p["lower_s"] + p["backend_compile_s"] == pytest.approx(p["compile_s"], abs=1e-5)
+    spans = recorder.section()["spans"]
+    lowering = [s for s in spans if s["name"] == "jit/lower" and s["fun_name"] == "busy"]
+    assert sum(s["end_ns"] - s["start_ns"] for s in lowering) / 1e9 == pytest.approx(p["lower_s"], abs=1e-5)
+    compiling = [s for s in spans if s["name"] == "busy/compile"]
+    assert sum(s["end_ns"] - s["start_ns"] for s in compiling) / 1e9 == pytest.approx(p["backend_compile_s"], abs=1e-3)
+    # once set-up is over a new signature's entry has its seconds, and no split: nothing is recorded to split by
+    led.mark_warm()
+    late = CompileLedger()
+    instrument("late", _busy(), late)(jnp.arange(32, dtype=jnp.float32))
+    q = late.snapshot()["programs"]["late"]
+    assert q["compile_s"] >= q["backend_compile_s"] > 0.0 and "lower_s" not in q and "trace_s" not in q
+
+
+_CACHE_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from llm_fine_tune_distributed_tpu.observe.xla import CompileLedger, instrument
+led = CompileLedger()
+def probe(x):
+    for _ in range(60):
+        x = jnp.tanh(x) @ x.T
+    return x
+instrument("probe", jax.jit(probe), led)(jnp.ones((32, 32)))
+led.mark_warm()
+setup = led.setup()
+spans = [s for s in setup["spans"] if s["name"] == "probe/compile"]
+print(json.dumps({"program": led.snapshot()["programs"]["probe"], "span_cache": spans[0]["cache"],
+                  "counters": setup["counters"]}))
+"""
+
+
+def test_cache_miss_in_a_fresh_directory_then_hit_in_a_second_process(tmp_path):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    reads = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _CACHE_PROBE, str(tmp_path / "cache")], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        reads.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    cold, warm = reads
+    assert cold["program"]["cache"] == cold["span_cache"] == "miss"
+    assert cold["counters"]["cache_misses"] >= 1 and cold["counters"]["cache_hits"] == 0
+    assert warm["program"]["cache"] == warm["span_cache"] == "hit"
+    assert warm["counters"]["cache_misses"] == 0 and warm["counters"]["cache_hits"] >= 1
+    assert warm["counters"]["cache_retrieval_time_sec"] > 0.0
+    for read in reads:
+        p = read["program"]
+        assert p["trace_s"] + p["lower_s"] + p["backend_compile_s"] == pytest.approx(p["compile_s"], abs=1e-5)
+
+
+def test_the_list_is_bounded_and_the_counters_go_on(monkeypatch):
+    small = SpanRecorder(max_spans=3)
+    monkeypatch.setattr(xla, "_RECORDER", small)
+    for _ in range(5):
+        with annotate("tick"):
+            pass
+    small.add_jit_stage("jit/compile", 1.0, 3.5, "jit(late)")  # JAX's seconds; its name for a compile
+    small.add_jit_stage("jit/trace", 1.0, 1.0005, "late")  # under a millisecond: counted, never kept
+    section = small.section()
+    assert len(section["spans"]) == 1 + 3  # the root and what the list holds
+    assert section["counters"]["spans"] == 7 and section["counters"]["spans_dropped"] == 3
+    assert section["counters"]["spans_brief"] == 1
+    assert section["counters"]["jit_seconds"] == pytest.approx(2.5005)
+    assert section["by_function"] == [{"fun_name": "late", "seconds": 2.5005, "spans": 2}]
+    roomy = SpanRecorder()
+    roomy.add_jit_stage("jit/trace", 1.0, 1.0005, "brief")
+    roomy.add_jit_stage("jit/trace", 1.0, 1.002, "long_enough")
+    assert [s["fun_name"] for s in roomy.section()["spans"][1:]] == ["long_enough"]
+
+
+def test_mark_warm_freezes_the_setup_section_and_later_compiles_still_count(recorder):
+    led = CompileLedger()
+    wrapped = instrument("double", jax.jit(lambda x: x * 2), led)
+    wrapped(jnp.arange(4.0))
+    with annotate("startup/weights"):
+        pass
+    assert led.setup()["spans"][0]["end_ns"] is None
+    with annotate("startup/first_step") as still_open:
+        led.mark_warm()
+    frozen = led.setup()
+    root = frozen["spans"][0]
+    assert root["name"] == "setup" and root["end_ns"] >= max(s["end_ns"] for s in frozen["spans"][1:])
+    assert still_open.record["name"] not in {s["name"] for s in frozen["spans"]}  # it ended after set-up did
+    wrapped(jnp.arange(32.0))  # a new shape after warm-up: a recompile, and no part of set-up
+    with annotate("tick", slot=3) as tick:
+        tick.set(tokens=1)
+    assert tick.record is None  # set-up is over: a span is its TraceAnnotation alone
+    assert led.snapshot()["recompiles_after_warmup"] == 1
+    assert led.snapshot()["programs"]["double"]["compiles"] == 2
+    assert led.setup() == frozen and recorder.counters == frozen["counters"]  # nothing is recorded any more
+    assert len(recorder._spans) == len(frozen["spans"]) - 1
+    CompileLedger().mark_warm()  # a second ledger's warm-up does not move the end of set-up
+    assert led.setup()["spans"][0]["end_ns"] == root["end_ns"]
+    phases = led.setup_phases()
+    assert list(phases["phases_s"])[:2] == ["double/load", "double/compile"] and "startup/weights" in phases["phases_s"]
+    assert "jit/trace" not in phases["phases_s"]
+    assert phases["since_process_start_s"] == pytest.approx((root["end_ns"] - root["start_ns"]) / 1e9, abs=1e-3)
+
+
+def test_listeners_are_installed_once_and_the_section_is_the_processes_own(recorder):
+    from jax._src import monitoring
+
+    ledgers = [CompileLedger() for _ in range(3)]
+    xla.install_compile_listeners()
+    assert monitoring.get_event_time_span_listeners().count(xla._on_compile_span) == 1
+    assert monitoring.get_event_listeners().count(xla._on_cache_event) == 1
+    assert monitoring.get_event_duration_listeners().count(xla._on_cache_seconds) == 1
+    _busy()(jnp.arange(5.0))  # no ledger's program: still one set of spans, not three
+    mine = [s for s in recorder.section()["spans"] if s["name"] == "jit/compile" and s["fun_name"] == "busy"]
+    assert len(mine) == 1
+    # the section is the process's: the same under every ledger, and in no snapshot (a scrape reads those)
+    assert ledgers[0].setup() == ledgers[2].setup() == CompileLedger.setup() == recorder.section()
+    merged = CompileLedger.merge(ledgers)
+    assert "setup" not in merged and "setup" not in ledgers[0].snapshot()
+    assert set(merged) == set(ledgers[0].snapshot())
 
 
 # ------------------------------------------- zero-recompile acceptance gate
