@@ -19,13 +19,12 @@ output and every gradient. ``--inverse NAME ...`` keeps those variants.
 
 ``--only inverse``: the kernels' triangular inverse alone against float64, by the precision of its products.
 
-``--only mixer``: the whole mixer (``models/transformer._linear_mixer``:
-projections, convolution, silu, l2 norms, the rule, the gated norm),
-forward+backward to its input and every leaf, by the convolution: ``shifts``
-(the library's ``causal_conv``: taps as shifted multiply-adds, each slice cast
-on its own, the backward pass written out), ``shifts_by_autodiff`` (the same
-over a float32 padded copy, differentiated by JAX) or ``lax_conv``
-(``lax.conv_general_dilated`` with one group a channel), both defined here.
+``--only mixer``: the mixer's two elementwise passes alone (``ops/gated_delta.mixer_in``: convolution, silu, l2
+norms, scale; ``gated_norm``: the norm gated by ``silu(z)``), forward and forward+backward, as XLA operations and as
+the Pallas kernels, each beside the time its bytes take at the HBM peak (every array read once and written once) and
+the kernels' distance from the XLA form; then the whole mixer (``models/transformer._linear_mixer``: projections, both
+passes, the rule), forward+backward to its input and every leaf, with the passes in either form (the rule in the form
+the backend gives it).
 
     chiprun -- python benchmarks/gdn_kernels.py [--only rule|mixer|inverse] [--inverse solve kernels]
 """
@@ -95,26 +94,6 @@ def by_doublings(precision):
 
     inverse.defvjp(fwd, bwd)
     return inverse
-
-
-def lax_conv(x, weight):
-    """``causal_conv`` as one grouped convolution, float32 inside."""
-    taps, channels = weight.shape
-    y = jax.lax.conv_general_dilated(
-        x.astype(jnp.float32), weight.astype(jnp.float32)[:, None, :], window_strides=(1,), padding=[(taps - 1, 0)],
-        dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=channels,
-    )
-    return y.astype(x.dtype)
-
-
-def by_autodiff(x, weight):
-    """The convolution as shifted multiply-adds over a float32 padded copy, its backward pass left to
-    autodiff (what ``ops/gated_delta.causal_conv`` was before it cast each tap's slice on its own and wrote
-    its backward pass out)."""
-    taps, s = weight.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = weight.astype(jnp.float32)
-    return sum(padded[:, j:j + s] * w[j] for j in range(taps)).astype(x.dtype)
 
 
 def rule_variants(args, small):
@@ -191,12 +170,56 @@ def inverse_alone(args, small):
     gd.NEWTON_STEPS, gd._one_pass = library
 
 
+HBM_BYTES_PER_S = 819e9  # benchmarks/chipbench/peaks.json, "TPU v5 lite"
+
+
+def _timed_pass(line, name, fn, args, iters, arrays_moved, both_arrays_moved, nbytes):
+    """``fn`` forward and forward + backward (to every argument) beside the time its bytes take at the HBM peak."""
+    # (sin, not a square: q and k are l2-normed, the square's gradient would be rounding alone)
+    both = jax.jit(jax.grad(lambda *a: sum(jnp.sum(jnp.sin(y.astype(jnp.float32))) for y in jax.tree.leaves(fn(*a))),
+                            argnums=tuple(range(len(args)))))
+    line[f"{name}_fwd_ms"] = round(timed(jax.jit(fn), args, iters), 3)
+    line[f"{name}_fwd_bwd_ms"] = round(timed(both, args, iters), 3)
+    line[f"{name}_fwd_bound_ms"] = round(1e3 * arrays_moved * nbytes / HBM_BYTES_PER_S, 3)
+    line[f"{name}_fwd_bwd_bound_ms"] = round(1e3 * (arrays_moved + both_arrays_moved) * nbytes / HBM_BYTES_PER_S, 3)
+    return jax.jit(fn)(*args), both(*args)
+
+
 def mixer_variants(args, small):
     from llm_fine_tune_distributed_tpu.models import transformer
     from llm_fine_tune_distributed_tpu.models.configs import get_preset
 
-    mc = get_preset("tiny_qwen3_next" if small else "qwen3_next_80b_a3b")
-    rows, seq = (1, 256) if small else (args.rows, args.seq)
+    mc = get_preset("qwen3_next_80b_a3b")
+    rows, seq, hk, hv = (1, 512, 2, 4) if small else (args.rows, args.seq, mc.linear_num_key_heads, mc.linear_num_value_heads)
+    d = mc.linear_key_head_dim
+    kernels = "kernels_interpret" if small else "kernels"
+    ks = jax.random.split(jax.random.key(3), 8)
+    act = lambda key, width: jax.random.normal(key, (rows, seq, width), jnp.float32).astype(jnp.bfloat16)  # noqa: E731
+    x_in = (act(ks[0], hk * d), act(ks[1], hk * d), act(ks[2], hv * d),
+            (jax.random.normal(ks[3], (mc.linear_conv_kernel_dim, (2 * hk + hv) * d)) * 0.3).astype(jnp.bfloat16))
+    x_out = (act(ks[4], hv * d), act(ks[5], hv * d), (1 + 0.1 * jax.random.normal(ks[6], (d,))).astype(jnp.bfloat16))
+    lines, kept = [], {}
+    for impl in ("xla", kernels):
+        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "passes": impl}
+        try:
+            # in: the q | k | v columns read and q, k, v written (2 arrays of [rows, seq, channels]); backward: the columns and
+            # dq, dk, dv read, the columns' cotangent written (3). out: o, z read, y written (3 of [rows, seq, hv d]); 5 back.
+            kept[impl] = (
+                _timed_pass(line, "in", lambda *a: gd.mixer_in(*a, hk, impl=impl), x_in, args.iters, 2, 3, 2 * rows * seq * (2 * hk + hv) * d),  # noqa: B023
+                _timed_pass(line, "out", lambda *a: gd.gated_norm(*a, mc.rms_norm_eps, impl=impl), x_out, args.iters, 3, 5, 2 * rows * seq * hv * d),  # noqa: B023
+            )
+            if impl != "xla":
+                line["programs"] = mosaic_programs(jax.jit(jax.grad(lambda *a: sum(  # noqa: B023
+                    jnp.sum(jnp.sin(y.astype(jnp.float32))) for y in gd.mixer_in(*a, hk, impl=impl)), argnums=(0, 1, 2, 3))).lower(*x_in).as_text())  # noqa: B023
+                for name, got, want in zip(("in", "out"), kept[impl], kept["xla"]):
+                    line[f"{name}_rel_to_xla"] = [rel(a, b) for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+        except Exception as e:  # a refusal is the reading
+            line["refused"] = str(e).split("\n")[0][:300]
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+        jax.clear_caches()
+
+    mc = mc if not small else mc.replace(linear_num_key_heads=hk, linear_num_value_heads=hv, hidden_size=256)
     keys = iter(jax.random.split(jax.random.key(1), 8))
     dense = lambda key, shape: (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(jnp.bfloat16)  # noqa: E731
     leaves = transformer._init_linear_attention(keys, mc, dense, jnp.bfloat16)
@@ -207,21 +230,17 @@ def mixer_variants(args, small):
         out, _ = transformer._linear_mixer(leaves, hid, None, None, config=mc, lin=lin, segment_ids=None, cache_entry=None)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    shifts = gd.causal_conv
-    for name, conv in (("shifts", shifts), ("shifts_by_autodiff", by_autodiff), ("lax_conv", lax_conv)):
-        gd.causal_conv = conv
-        both = jax.jit(jax.grad(loss, argnums=(0, 1)))
-        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "mixer": "fwd+bwd", "conv": name}
+    library = gd.mixer_in, gd.gated_norm
+    for impl in ("xla", kernels):
+        gd.mixer_in, gd.gated_norm = (functools.partial(f, impl=impl) for f in library)
+        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "mixer": "fwd+bwd", "passes": impl}
         try:
-            line["fwd_bwd_ms"] = round(timed(both, (leaves, hid), args.iters), 3)
-            conv_alone = jax.jit(jax.grad(lambda x, w: jnp.sum(jax.nn.silu(conv(x, w)).astype(jnp.float32) ** 2), argnums=(0, 1)))
-            qkv = jax.random.normal(jax.random.key(3), (rows, seq, leaves["conv1d"]["weight"].shape[1]), jnp.bfloat16)
-            line["conv_silu_fwd_bwd_ms"] = round(timed(conv_alone, (qkv, leaves["conv1d"]["weight"]), args.iters), 3)
+            line["fwd_bwd_ms"] = round(timed(jax.jit(jax.grad(loss, argnums=(0, 1))), (leaves, hid), args.iters), 3)
         except Exception as e:
             line["refused"] = str(e).split("\n")[0][:300]
         print(json.dumps(line), flush=True)
         jax.clear_caches()
-    gd.causal_conv = shifts
+    gd.mixer_in, gd.gated_norm = library
 
 
 def main(argv=None) -> int:

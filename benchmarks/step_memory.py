@@ -45,7 +45,7 @@ STEPS = {
     "mellum2-12b-a2.5b-ep4-d4.8k-2rows": (*_MELLUM, 2, 2, 8192, _ALL),
     "mellum2-12b-a2.5b-ep4-d4.8k-1row": (*_MELLUM, 1, 4, 8192, _ALL),
     "qwen3-next-80b-a3b-ep16-d4.sft-8k-linear-allparams": (*_QWEN3_NEXT, 2, 2, 8192, _ALL),
-    # the same at 4 rows and at 1 (of 4, 2, 1 rows the cell takes the most that fits: 2; 4 are refused)
+    # the same at 4 rows and at 1 (the cell was sized at 2 when 4 were refused, 17.89 G of 15.75 G; since PR 39 4 fit, 14.13 GiB)
     "qwen3-next-80b-a3b-ep16-d4.8k-4rows": (*_QWEN3_NEXT, 4, 1, 8192, _ALL),
     "qwen3-next-80b-a3b-ep16-d4.8k-1row": (*_QWEN3_NEXT, 1, 4, 8192, _ALL),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096

@@ -275,10 +275,28 @@ def _init_linear_attention(keys, config: ModelConfig, dense, dtype):
     }
 
 
+_CUT_BY_COLUMN = ("kernel", "lora_b", "bias")
+
+
+def _by_columns(hid, p, cuts, lin):
+    """``lin(hid, p)`` as one array for each run of output columns ``cuts[i] .. cuts[i + 1]``. Where the leaf can be
+    cut (a plain kernel, with or without LoRA and a bias) it is the LEAF that is cut, a few MB once a call, and each run
+    is a product of its own: the runs and their cotangents are separate arrays from birth, and no ``[b, s, .]``
+    activation is sliced, nor its cotangent padded and added (PERF.md, PR 39). Any other leaf (a quantized kernel, a
+    pool of adapters) makes one product, whose output is cut."""
+    runs = list(zip(cuts, cuts[1:]))
+    if set(p) - {*_CUT_BY_COLUMN, "lora_a", "lora_scale"}:
+        y = lin(hid, p)
+        return [y[..., lo:hi] for lo, hi in runs]
+    return [lin(hid, {name: x[..., lo:hi] if name in _CUT_BY_COLUMN else x for name, x in p.items()}) for lo, hi in runs]
+
+
 def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, **_):
     """A Gated DeltaNet mixer (``ops/gated_delta.py``): q, k, v through a
-    causal convolution and silu, the gated delta rule per value head in place
-    of softmax attention, a norm gated by ``silu(z)``, ``out_proj``. No rope
+    causal convolution and silu, q and k l2-normed a head (``mixer_in``, one
+    pass), the gated delta rule per value head in place of softmax attention,
+    a norm gated by ``silu(z)`` (``gated_norm``, one pass), ``out_proj``;
+    everything between the projections flat, ``[b, s, heads x d]``. No rope
     (``cos``/``sin`` unused), no mask: the rule is causal, and what a
     right-padded row computes at its pads reaches no real token."""
     if segment_ids is not None:
@@ -295,25 +313,20 @@ def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entr
     hk, hv = config.linear_num_key_heads, config.linear_num_value_heads
     dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
     kd, vd = hk * dk, hv * dv
-    qkvz = lin(hid, attn_p["in_proj_qkvz"])
-    z = qkvz[..., 2 * kd + vd:]
+    xq, xk, xv, z = _by_columns(hid, attn_p["in_proj_qkvz"], (0, kd, 2 * kd, 2 * kd + vd, 2 * kd + 2 * vd), lin)
     ba = lin(hid, attn_p["in_proj_ba"]).astype(jnp.float32)
-    with scope("gdn_conv"):
-        qkv = jax.nn.silu(gated_delta.causal_conv(qkvz[..., : 2 * kd + vd], attn_p["conv1d"]["weight"]))
-    q = gated_delta.l2_norm(qkv[..., :kd].reshape(b, s, hk, dk)) * jnp.asarray(dk ** -0.5, qkv.dtype)
-    k = gated_delta.l2_norm(qkv[..., kd: 2 * kd].reshape(b, s, hk, dk))
-    v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
+    with scope("gdn_conv"):  # convolution, silu, l2 norms and q's scale: one pass, flat
+        q, k, v = gated_delta.mixer_in(xq, xk, xv, attn_p["conv1d"]["weight"], hk)
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(attn_p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         ba[..., hv:] + attn_p["dt_bias"].astype(jnp.float32)
     )  # a log decay, <= 0
     with scope("gdn_scan"):
-        o = checkpoint_name(gated_delta.gated_delta_rule(q, k, v, g, beta), "gdn_o")
+        o = checkpoint_name(gated_delta.gated_delta_rule(
+            q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk), v.reshape(b, s, hv, dv), g, beta), "gdn_o")
     with scope("gdn_gate_norm"):
-        o = rms_norm(o, attn_p["norm"]["weight"], config.rms_norm_eps).astype(jnp.float32) * jax.nn.silu(
-            z.reshape(b, s, hv, dv).astype(jnp.float32)
-        )
-    return lin(o.astype(hid.dtype).reshape(b, s, vd), attn_p["out_proj"]), None
+        o = gated_delta.gated_norm(o.reshape(b, s, vd), z, attn_p["norm"]["weight"], config.rms_norm_eps)
+    return lin(o.astype(hid.dtype), attn_p["out_proj"]), None
 
 
 # LayerPlan.attention -> (the layer's subtree of that kind, the scope its device

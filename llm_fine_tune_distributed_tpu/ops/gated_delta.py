@@ -1,7 +1,36 @@
-"""The two operations of a Gated DeltaNet mixer that are not projections
-(Qwen3-Next's ``linear_attention`` layers, HF ``Qwen3NextGatedDeltaNet``): a
-causal depthwise convolution of a few taps, and the gated delta rule, a
-recurrence over time that stands where softmax attention stands elsewhere.
+"""The operations of a Gated DeltaNet mixer that are not projections
+(Qwen3-Next's ``linear_attention`` layers, HF ``Qwen3NextGatedDeltaNet``): the
+gated delta rule, a recurrence over time that stands where softmax attention
+stands elsewhere, and the elementwise work on either side of it: on the way IN
+a causal depthwise convolution of a few taps, silu, the l2 norms of q and k
+and q's scale (``mixer_in``), on the way OUT a norm gated by ``silu(z)``
+(``gated_norm``).
+
+Each of the three runs as one of two programs that compute the same thing.
+Which one takes a call is read from the input and the backend
+(``gated_delta_rule``, ``mixer_in``, ``gated_norm``; ``_program``; no knob):
+Pallas kernels on a TPU where a head is whole lanes (``d_k``, ``d_v``
+multiples of 128; the rule also wants chunks of 64), XLA operations everywhere
+else (a CPU, a GPU, the ``tiny_*`` presets' heads of 16 on any backend), which
+is also what the tests hold the kernels to. ``CALLS`` says which the rule
+took and ``PASSES`` which the two passes took, and on a TPU why not the
+kernels; ``calls_summary()`` is the line entry points print.
+
+The two passes (the section at the end of the file). As XLA operations:
+``causal_conv`` (a ``custom_vjp``: shifted multiply-adds over a padded copy),
+``jax.nn.silu``, ``l2_norm``, ``ops/norms.rms_norm``; float32 inside each,
+rounded to the activation's dtype between them. As kernels
+(``gdn_in_fwd``/``gdn_in_bwd``, ``gdn_out_fwd``/``gdn_out_bwd``, each pair
+behind a ``custom_vjp``): every activation is read once where it lies, flat
+``[b, s, heads x d]``, and written once in the layout the next consumer reads
+(the rule's kernels, ``out_proj``); float32 inside and ONE rounding, at the
+output, so never fewer bits than the XLA form (which rounds after the
+convolution, after the norm, after the scale, and after ``rms_norm``); the
+same ``eps``, the same zeros left of a row, the weight broadcast the same way.
+Each ``custom_vjp`` keeps its inputs and nothing else (the in pass: the
+projection's q, k, v columns and the taps; the out pass: ``o``, ``z`` and the
+norm's weight): the backward kernels make the convolution's output, the norms
+and the gate again in VMEM.
 
 The rule, for one row and one value head, with a state ``S [d_k, d_v]`` from
 zero and for t = 0, 1, ...:
@@ -12,9 +41,7 @@ zero and for t = 0, 1, ...:
 caller). Token by token that is a chain of ``seq`` dependent steps of rank-one
 updates; it stays in the reference (``benchmarks/chipbench/reference_gdn_moe.py``).
 Here the rule runs in CHUNKED form, as one of two programs that compute the
-same thing in the same dtypes. Which one takes a call is read from the input
-and the backend (``gated_delta_rule``, no knob; ``CALLS`` says which, and on a
-TPU why not the kernels):
+same thing in the same dtypes:
 
 - on a TPU where a head is whole lanes (``d_k`` and ``d_v`` multiples of 128)
   and the chunk is 64: two Pallas kernels (``gdn_rule_fwd``, ``gdn_rule_bwd``,
@@ -93,12 +120,16 @@ STATE_DTYPE = jnp.float32
 # ``gated_delta_rule`` traced in this process (as ``flash_attention.GRID_TILES``
 # says what a grid was built to visit): which form the linear layers' rule took.
 CALLS: dict = {}
+# {(pass, rows, seq, channels): [calls traced, form]} of every ``mixer_in`` ("in") and ``gated_norm`` ("out") traced in
+# this process; a dict of its own because ``CALLS`` is the rule's (``gdn_chunked_calls_pct`` reads all of it).
+PASSES: dict = {}
 
 
 def calls_summary() -> str:
     """One line for entry points to print beside ``dispatch_summary()``."""
     said = "; ".join(f"{list(shape)}: {form} x {n}" for shape, (n, form) in sorted(CALLS.items()))
-    return f"gated delta rule traced as: {said or 'nothing traced'}"
+    passes = "; ".join(f"{which} {list(shape)}: {form} x {n}" for (which, *shape), (n, form) in sorted(PASSES.items()))
+    return f"gated delta rule traced as: {said or 'nothing traced'}" + (f"; mixer passes: {passes}" if passes else "")
 
 
 def _shifted_sum(padded, w, offsets, length, dtype):
@@ -146,7 +177,10 @@ def _causal_conv_bwd(kept, dy):
 causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)
 
 
-def l2_norm(x, eps: float = 1e-6):
+L2_EPS = 1e-6
+
+
+def l2_norm(x, eps: float = L2_EPS):
     """``x * rsqrt(sum x^2 + eps)`` over the last axis, float32 inside."""
     x32 = x.astype(jnp.float32)
     return (x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + eps)).astype(x.dtype)
@@ -157,6 +191,15 @@ def unit_lower_inverse(a):
     triangular solve against the identity (module docstring: measured)."""
     eye = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=a.dtype), a.shape)
     return jax.scipy.linalg.solve_triangular(eye + a, eye, lower=True, unit_diagonal=True)
+
+
+def _padded_rows(arrays, multiple):
+    """``arrays [b, s, ...]`` padded with zeros along the row to whole multiples, and the rows' own length. What a pad
+    computes reaches no real token (rule and passes are causal), and to the rule a zero token changes nothing (k = 0,
+    beta = 0, g = 0)."""
+    s = arrays[0].shape[1]
+    pad = -s % multiple
+    return [jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) if pad else x for x in arrays], s
 
 
 def _rule_xla(q, k, v, g, beta, *, chunk: int = CHUNK):
@@ -174,10 +217,8 @@ def _rule_xla(q, k, v, g, beta, *, chunk: int = CHUNK):
     r = hv // hk
     cd, f32 = v.dtype, jnp.float32
 
-    pad = -s % chunk
-    if pad:  # tokens that change nothing: k = 0, beta = 0, g = 0
-        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
-    n = (s + pad) // chunk
+    (q, k, v, g, beta), s = _padded_rows((q, k, v, g, beta), chunk)
+    n = q.shape[1] // chunk
     qc = q.reshape(b, n, chunk, hk, dk)
     kc = k.reshape(b, n, chunk, hk, dk)
     vc = v.reshape(b, n, chunk, hk, r, dv)
@@ -611,9 +652,9 @@ def _specs(rs, r, dk, dv):
     )
 
 
-def _params(interpret):
+def _params(interpret, semantics=("parallel", "parallel", "arbitrary")):
     return {} if interpret else dict(compiler_params=pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=64 * 2**20))
+        dimension_semantics=semantics, vmem_limit_bytes=64 * 2**20))
 
 
 @functools.partial(jax.jit, static_argnames=("hk", "rs", "state_dtype", "interpret"))
@@ -697,22 +738,21 @@ def _rule_kernels(q, k, v, g, beta, *, interpret=False):
     b, s, hk, _ = q.shape
     hv = v.shape[2]
     rs = 2 if (hv // hk) % 2 == 0 else 1
-    pad = -s % (STEP_CHUNKS * CHUNK)
-    if pad:
-        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
-    n = (s + pad) // CHUNK
+    (q, k, v, g, beta), s = _padded_rows((q, k, v, g, beta), STEP_CHUNKS * CHUNK)
+    n = q.shape[1] // CHUNK
     cum = jnp.cumsum(g.astype(_F32).reshape(b, n, CHUNK, hv), axis=2).reshape(b, n * CHUNK, hv)
-    flat = lambda x: x.reshape(b, s + pad, -1)  # noqa: E731
+    flat = lambda x: x.reshape(b, n * CHUNK, -1)  # noqa: E731
     o = _flat_rule(hk, rs, jnp.dtype(STATE_DTYPE), interpret)(
         flat(q), flat(k), flat(v), _rows_of(cum, b, n, hv // rs, rs), _rows_of(beta.astype(_F32), b, n, hv // rs, rs))
-    return o.reshape(b, s + pad, hv, -1)[:, :s]
+    return o.reshape(b, n * CHUNK, hv, -1)[:, :s]
 
 
-def _program(dk, dv, chunk):
-    """Which program takes a call of these shapes, as ``CALLS`` words it: ``kernels``, or ``xla`` and, on a TPU, why."""
+def _program(chunk=CHUNK, **head_sizes):
+    """Which program takes a call of these head sizes (``d_k=``, ``d_v=``), as ``CALLS`` and ``PASSES`` word it:
+    ``kernels``, or ``xla`` and, on a TPU, why."""
     if jax.default_backend() != "tpu":
         return "xla"
-    for name, d in (("d_k", dk), ("d_v", dv)):
+    for name, d in head_sizes.items():
         if d % 128:
             return f"xla ({name} {d} is no multiple of 128)"
     return "kernels" if chunk == CHUNK else f"xla (chunk {chunk} is not {CHUNK})"
@@ -727,9 +767,355 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, impl=None):
     kernels under the Pallas interpreter)."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    program = _program(dk, dv, chunk) if impl is None else impl.split("_")[0]
+    program = _program(chunk, d_k=dk, d_v=dv) if impl is None else impl.split("_")[0]
     entry = CALLS.setdefault((b, s, hk, hv, dk, dv), [0, f"chunked {chunk}: {program}"])
     entry[0] += 1
     if program == "kernels":
         return _rule_kernels(q, k, v, g, beta, interpret=impl == "kernels_interpret")
     return _rule_xla(q, k, v, g, beta, chunk=chunk)
+
+
+# -- the mixer's elementwise work as two fused passes (TPU) -----------------------
+#
+# Between ``in_proj_qkvz`` and the rule stand a convolution of a few taps, silu, two l2 norms and a scale; between the
+# rule and ``out_proj`` a norm gated by ``silu(z)``. As XLA operations each is a pass or two over a ``[b, s, .]``
+# activation, with the pads, reshapes to heads and float32 copies that autodiff adds (PERF.md, PR 39, step 0). Here each
+# way is ONE kernel forward and one backward over the flat ``[b, s, heads x d]`` layout the rule's kernels read: an
+# activation is read once where it lies and written once. A grid step holds a block of ``_token_block`` tokens of one
+# key head's columns (its q and k columns and its value heads' columns: three arrays, cut apart where the projection is
+# made, ``models/transformer._linear_mixer``) or of one value head (the gated norm); inside it a loop over ``ROWS``
+# tokens that is NOT unrolled, so that the text is one trip's (``setup_s``: the rule's section) and a trip's values stay
+# in vector registers. The taps are sublane rotations of the trip's rows with the 8 rows before them (after them, in the
+# backward pass, which walks a row against time).
+
+ROWS = 256         # tokens a trip of a pass's inner loop (32: every kernel twice as slow, a trip waits out its own latencies)
+HALO = 16          # rows fetched before a token block: one bfloat16 tile (the taps look back taps - 1 <= 8 tokens)
+
+
+def _token_block(s):
+    """Tokens a grid step of a pass holds, of a row of ``s`` (whole multiples of 512, as ``_rule_kernels`` pads)."""
+    return next(t for t in (2048, 1024, 512) if s % t == 0)
+
+
+def _shifted(ext, first, rows):
+    """Rows ``first .. first + rows - 1`` of ``ext [rows + 8, width]`` float32: a rotation along sublanes where
+    ``first`` is no whole tile."""
+    n = ext.shape[0]
+    return ext[first:first + rows] if first % 8 == 0 else pltpu.roll(ext, n - first, 0)[:rows]
+
+
+def _sigmoid(x):
+    """``1 / (1 + exp(-x))`` with a true division, as the XLA form's (a name of its own so that
+    ``benchmarks/calls/pr39_sweep.py`` can price ``tanh`` and an approximate reciprocal: 0.2 ms of the in pass's 1.25)."""
+    return jax.nn.sigmoid(x)
+
+
+def _by_eights(x):
+    """``[rows, width]`` -> ``[8, width]``: rows 8 apart added (whole tiles; the caller adds the 8 sublanes up)."""
+    return x.reshape(x.shape[0] // 8, 8, x.shape[1]).sum(axis=0)
+
+
+def _conv_silu(ext, w_ref):
+    """A trip's convolution: ``ext`` its rows under the 8 before them. ``(the shifted rows a tap, c, sigmoid(c))``."""
+    taps = w_ref.shape[0]
+    shifted = [_shifted(ext, 8 - (taps - 1) + j, ROWS) for j in range(taps)]
+    c = sum(w_ref[pl.ds(j, 1), :].astype(_F32) * x for j, x in enumerate(shifted))
+    return shifted, c, _sigmoid(c)
+
+
+def _in_part(t, x_ref, halo_ref, w_ref, y_ref, *, norm, scale):
+    """One of a key head's three column blocks through convolution, silu and (``norm``) the l2 norm over the block's
+    lanes times ``scale``."""
+    before = jnp.where(t > 0, halo_ref[0].astype(_F32)[HALO - 8:], 0.0)      # zeros left of the row
+
+    def trip(i, before):
+        rows = pl.ds(pl.multiple_of(i * ROWS, ROWS), ROWS)
+        x = x_ref[0, rows, :].astype(_F32)
+        _, c, sig = _conv_silu(jnp.concatenate([before, x], axis=0), w_ref)
+        a = c * sig
+        if norm:
+            a = a * (jax.lax.rsqrt(jnp.sum(a * a, axis=1, keepdims=True) + L2_EPS) * scale)
+        y_ref[0, rows, :] = a.astype(y_ref.dtype)
+        return x[ROWS - 8:]
+
+    jax.lax.fori_loop(0, x_ref.shape[1] // ROWS, trip, before)
+
+
+# A key head's three column blocks, q, k and v: (l2-normed?, scaled by d_k ** -0.5?)
+_PARTS = ((True, True), (True, False), (False, False))
+
+
+def _in_kernel(*refs, scale):
+    """``(block, halo, taps)`` for each of q, k, v, then the three outputs."""
+    for i, (norm, scaled) in enumerate(_PARTS):
+        _in_part(pl.program_id(2), *refs[3 * i:3 * i + 3], refs[9 + i], norm=norm, scale=scale if scaled else 1.0)
+
+
+def _in_part_back(x_ref, halo_ref, w_ref, dy_ref, dx_ref, dw_ref, after_ref, *, norm, scale):
+    """``_in_part``'s backward pass over a block, its trips last first: the convolution's output again from the
+    kept input, the cotangent through scale, norm and silu, the taps against time (the 8 tokens AFTER a trip's rows are
+    the carry, ``after_ref`` from block to block) and the taps' own cotangent added up in ``dw_ref [8 taps, width]``
+    (8 partial sums a tap) over the steps of a key head."""
+    i_row, t = pl.program_id(1), pl.program_id(2)                             # the grid walks t against time
+    taps, trips = w_ref.shape[0], x_ref.shape[1] // ROWS
+
+    @pl.when(t == 0)
+    def _():
+        after_ref[...] = jnp.zeros_like(after_ref)                            # nothing right of the row
+
+    @pl.when((i_row == 0) & (t == 0))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    halo = jnp.where(t < pl.num_programs(2) - 1, halo_ref[0].astype(_F32)[HALO - 8:], 0.0)
+
+    def trip(n, carry):
+        after, acc = carry[0], carry[1:]
+        i = trips - 1 - n
+        first = pl.multiple_of(i * ROWS, ROWS)
+        rows = pl.ds(first, ROWS)
+        x = x_ref[0, rows, :].astype(_F32)
+        lower = x_ref[0, pl.ds(pl.multiple_of(jnp.maximum(first - HALO, 0), HALO), HALO), :].astype(_F32)[HALO - 8:]
+        shifted, c, sig = _conv_silu(jnp.concatenate([jnp.where(i == 0, halo, lower), x], axis=0), w_ref)
+        da = dy_ref[0, rows, :].astype(_F32)
+        if norm:    # y = scale a r, r = rsqrt(sum a^2 + eps):  da = scale r (dy - n sum(dy n)), n = a r
+            a = c * sig
+            r = jax.lax.rsqrt(jnp.sum(a * a, axis=1, keepdims=True) + L2_EPS)
+            n_ = a * r
+            da = (scale * r) * (da - n_ * jnp.sum(da * n_, axis=1, keepdims=True))
+        dc = da * sig * (1.0 + c * (1.0 - sig))
+        back = jnp.concatenate([dc, after], axis=0)
+        dx = sum(w_ref[pl.ds(j, 1), :].astype(_F32) * _shifted(back, taps - 1 - j, ROWS) for j in range(taps))
+        dx_ref[0, rows, :] = dx.astype(dx_ref.dtype)
+        return (dc[:8],) + tuple(a_j + _by_eights(x_j * dc) for a_j, x_j in zip(acc, shifted))
+
+    zeros = jnp.zeros((8, x_ref.shape[2]), _F32)
+    out = jax.lax.fori_loop(0, trips, trip, (after_ref[...],) + (zeros,) * taps)
+    after_ref[...] = out[0]
+    for j, a_j in enumerate(out[1:]):
+        dw_ref[8 * j:8 * j + 8, :] += a_j
+
+
+def _in_back_kernel(*refs, scale):
+    """``(block, halo, taps, the output's cotangent)`` for each of q, k, v, then for each the input's cotangent, the
+    taps' partial sums, and the scratch that carries a block's first 8 tokens to the block before it."""
+    for i, (norm, scaled) in enumerate(_PARTS):
+        _in_part_back(*refs[4 * i:4 * i + 4], *refs[12 + i::3], norm=norm, scale=scale if scaled else 1.0)
+
+
+def _pass_specs(tokens, taps, where):
+    """Spec makers for a pass whose grid indices ``where`` turns into ``(row, token block, head)``: a head's block of
+    ``[b, s, heads x width]``, the ``HALO`` rows before it, its columns of the taps ``[taps, heads x width]`` and of
+    the taps' partial sums ``[8 taps, heads x width]``."""
+    return (
+        lambda width: pl.BlockSpec((1, tokens, width), lambda *g: where(*g)),
+        lambda width: pl.BlockSpec((1, HALO, width), lambda *g: (
+            where(*g)[0], jnp.maximum(where(*g)[1] * (tokens // HALO) - 1, 0), where(*g)[2])),
+        lambda width: pl.BlockSpec((taps, width), lambda *g: (0, where(*g)[2])),
+        lambda width: pl.BlockSpec((8 * taps, width), lambda *g: (0, where(*g)[2])),
+    )
+
+
+def _parts(xq, xk, xv, weight):
+    """The q, k and v columns' arrays, each beside its columns of the taps ``[taps, q | k | v channels]``."""
+    cuts = (0, xq.shape[2], 2 * xq.shape[2], 2 * xq.shape[2] + xv.shape[2])
+    return [(x, weight[:, lo:hi]) for x, lo, hi in zip((xq, xk, xv), cuts, cuts[1:])]
+
+
+@functools.partial(jax.jit, static_argnames=("hk", "interpret"))
+def gdn_in_fwd(xq, xk, xv, weight, *, hk, interpret):
+    """The in pass. ``xq``, ``xk`` ``[b, s, hk d_k]`` and ``xv`` ``[b, s, hv d_v]`` (the projection's q, k and v
+    columns, ``s`` whole token blocks), ``weight [taps, q | k | v channels]`` -> ``q`` (convolution, silu, l2 norm
+    over a head's ``d_k`` lanes, times ``d_k ** -0.5``), ``k`` (the same without the scale) and ``v`` (convolution and
+    silu), shaped and typed like their inputs. Float32 inside, ONE rounding at the output: the XLA form
+    (``_mixer_in_xla``) rounds to the input's dtype after the convolution, after the norm and after the scale."""
+    b, s, kd = xq.shape
+    tokens, taps = _token_block(s), weight.shape[0]
+    block, halo, taps_of, _ = _pass_specs(tokens, taps, lambda i, h, t: (i, t, h))
+    operands, specs = [], []
+    for x, w in _parts(xq, xk, xv, weight):
+        operands += [x, x, w.astype(_F32)]              # (float32: a row of a packed dtype is no load)
+        specs += [block(x.shape[2] // hk), halo(x.shape[2] // hk), taps_of(x.shape[2] // hk)]
+    return pl.pallas_call(
+        functools.partial(_in_kernel, scale=(kd // hk) ** -0.5),
+        grid=(b, hk, s // tokens), in_specs=specs, out_specs=[block(x.shape[2] // hk) for x in (xq, xk, xv)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (xq, xk, xv)],
+        name="gdn_in_fwd", interpret=interpret, **_params(interpret, ("parallel",) * 3),
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("hk", "interpret"))
+def gdn_in_bwd(xq, xk, xv, weight, dq, dk, dv, *, hk, interpret):
+    """The in pass's backward pass from its kept inputs: the cotangents of ``xq``, ``xk``, ``xv`` (like them) and of
+    ``weight`` (float32). The grid walks a key head's rows and, in a row, the token blocks against time."""
+    b, s, kd = xq.shape
+    tokens, taps = _token_block(s), weight.shape[0]
+    steps = s // tokens
+    block, halo, taps_of, sums_of = _pass_specs(tokens, taps, lambda h, i, t: (i, steps - 1 - t, h))
+    operands, specs, widths = [], [], []
+    for (x, w), dy in zip(_parts(xq, xk, xv, weight), (dq, dk, dv)):
+        width = x.shape[2] // hk
+        operands += [x, x, w.astype(_F32), dy]
+        specs += [block(width), halo(width), taps_of(width), block(width)]
+        widths.append(width)
+    *dx, dwq, dwk, dwv = pl.pallas_call(
+        functools.partial(_in_back_kernel, scale=(kd // hk) ** -0.5),
+        grid=(hk, b, steps), in_specs=specs, out_specs=[block(w) for w in widths] + [sums_of(w) for w in widths],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (xq, xk, xv)]
+        + [jax.ShapeDtypeStruct((8 * taps, x.shape[2]), _F32) for x in (xq, xk, xv)],
+        scratch_shapes=[pltpu.VMEM((8, w), _F32) for w in widths],
+        name="gdn_in_bwd", interpret=interpret, **_params(interpret, ("arbitrary",) * 3),
+    )(*operands)
+    dw = jnp.concatenate([dwq, dwk, dwv], axis=1)
+    return (*dx, dw.reshape(taps, 8, -1).sum(axis=1))
+
+
+def _gate_norm_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
+    w = w_ref[...]
+
+    def trip(i, _):
+        rows = pl.ds(pl.multiple_of(i * ROWS, ROWS), ROWS)
+        o, z = o_ref[0, rows, :].astype(_F32), z_ref[0, rows, :].astype(_F32)
+        normed = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+        y_ref[0, rows, :] = (normed * w * (z * _sigmoid(z))).astype(y_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, o_ref.shape[1] // ROWS, trip, 0)
+
+
+def _gate_norm_back_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps):
+    """y = n w silu(z), n = o r, r = rsqrt(mean o^2 + eps). ``dw_ref [8, d_v]`` holds 8 partial sums of the weight's
+    cotangent over the whole grid."""
+    w = w_ref[...]
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def trip(i, acc):
+        rows = pl.ds(pl.multiple_of(i * ROWS, ROWS), ROWS)
+        o, z, dy = (ref[0, rows, :].astype(_F32) for ref in (o_ref, z_ref, dy_ref))
+        r = jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+        normed, sig = o * r, _sigmoid(z)
+        gate = z * sig
+        dn = dy * w * gate
+        do_ref[0, rows, :] = (r * (dn - normed * jnp.mean(dn * normed, axis=1, keepdims=True))).astype(do_ref.dtype)
+        dz_ref[0, rows, :] = (dy * normed * w * sig * (1.0 + z * (1.0 - sig))).astype(dz_ref.dtype)
+        return acc + _by_eights(dy * gate * normed)
+
+    dw_ref[...] += jax.lax.fori_loop(0, o_ref.shape[1] // ROWS, trip, jnp.zeros(dw_ref.shape, _F32))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def gdn_out_fwd(o, z, weight, *, eps, interpret):
+    """The out pass. ``o`` (as the rule's kernel wrote it) and ``z`` ``[b, s, hv d_v]``, ``weight [d_v]`` ->
+    ``rms_norm(o) weight silu(z)`` over each head's ``d_v`` lanes, like ``o``. Float32 inside, ONE rounding at the
+    output: the XLA form (``_gated_norm_xla``) rounds the norm's output to ``o``'s dtype before the gate."""
+    b, s, vd = o.shape
+    dv, tokens = weight.shape[0], _token_block(s)
+    block = pl.BlockSpec((1, tokens, dv), lambda i, t, h: (i, t, h))
+    return pl.pallas_call(
+        functools.partial(_gate_norm_kernel, eps=eps),
+        grid=(b, s // tokens, vd // dv), in_specs=[block, block, pl.BlockSpec((1, dv), lambda i, t, h: (0, 0))],
+        out_specs=block, out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
+        name="gdn_out_fwd", interpret=interpret, **_params(interpret, ("parallel",) * 3),
+    )(o, z, weight.astype(_F32).reshape(1, dv))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def gdn_out_bwd(o, z, weight, dy, *, eps, interpret):
+    """The out pass's backward pass from its kept inputs: the cotangents of ``o`` and ``z`` (like them) and of
+    ``weight`` (float32)."""
+    b, s, vd = o.shape
+    dv, tokens = weight.shape[0], _token_block(s)
+    block = pl.BlockSpec((1, tokens, dv), lambda i, t, h: (i, t, h))
+    do, dz, dw = pl.pallas_call(
+        functools.partial(_gate_norm_back_kernel, eps=eps),
+        grid=(b, s // tokens, vd // dv), in_specs=[block, block, pl.BlockSpec((1, dv), lambda i, t, h: (0, 0)), block],
+        out_specs=[block, block, pl.BlockSpec((8, dv), lambda i, t, h: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype), jax.ShapeDtypeStruct(z.shape, z.dtype), jax.ShapeDtypeStruct((8, dv), _F32)],
+        name="gdn_out_bwd", interpret=interpret, **_params(interpret, ("arbitrary",) * 3),
+    )(o, z, weight.astype(_F32).reshape(1, dv), dy)
+    return do, dz, dw.sum(axis=0)
+
+
+@functools.lru_cache(maxsize=None)
+def _in_pass(hk, interpret):
+    """The in pass as one differentiable function; it keeps its inputs and nothing else."""
+
+    @jax.custom_vjp
+    def run(xq, xk, xv, weight):
+        return tuple(gdn_in_fwd(xq, xk, xv, weight, hk=hk, interpret=interpret))
+
+    def bwd(kept, cotangents):
+        *dx, dw = gdn_in_bwd(*kept, *cotangents, hk=hk, interpret=interpret)
+        return (*dx, dw.astype(kept[3].dtype))
+
+    run.defvjp(lambda *kept: (run(*kept), kept), bwd)
+    return run
+
+
+@functools.lru_cache(maxsize=None)
+def _out_pass(eps, interpret):
+    """The out pass as one differentiable function; it keeps its inputs and nothing else."""
+
+    @jax.custom_vjp
+    def run(o, z, weight):
+        return gdn_out_fwd(o, z, weight, eps=eps, interpret=interpret)
+
+    def bwd(kept, dy):
+        do, dz, dw = gdn_out_bwd(*kept, dy, eps=eps, interpret=interpret)
+        return do, dz, dw.astype(kept[2].dtype)
+
+    run.defvjp(lambda *kept: (run(*kept), kept), bwd)
+    return run
+
+
+def _mixer_in_xla(xq, xk, xv, weight, hk):
+    """The in pass as XLA operations (``causal_conv``, ``l2_norm``): any backend's path, and what the kernel is held
+    to."""
+    b, s, kd = xq.shape
+    q, k, v = (jax.nn.silu(causal_conv(x, w)) for x, w in _parts(xq, xk, xv, weight))
+    heads = lambda x: l2_norm(x.reshape(b, s, hk, kd // hk)).reshape(b, s, kd)  # noqa: E731
+    return heads(q) * jnp.asarray((kd // hk) ** -0.5, q.dtype), heads(k), v
+
+
+def _gated_norm_xla(o, z, weight, eps):
+    """The out pass as XLA operations (``ops/norms.rms_norm``)."""
+    from llm_fine_tune_distributed_tpu.ops.norms import rms_norm
+
+    b, s, vd = o.shape
+    heads = lambda x: x.reshape(b, s, vd // weight.shape[0], weight.shape[0])  # noqa: E731
+    y = rms_norm(heads(o), weight, eps).astype(_F32) * jax.nn.silu(heads(z).astype(_F32))
+    return y.astype(o.dtype).reshape(b, s, vd)
+
+
+def _counted(which, shape, program):
+    entry = PASSES.setdefault((which, *shape), [0, program])
+    entry[0] += 1
+
+
+def mixer_in(xq, xk, xv, weight, hk, *, impl=None):
+    """From the projection's q, k and v columns (``xq``, ``xk`` ``[b, s, hk d_k]``, ``xv`` ``[b, s, hv d_v]``) and
+    ``conv1d/weight [taps, q | k | v channels]`` to what the rule takes, flat: q (convolution, silu, l2 norm a head,
+    times ``d_k ** -0.5``), k (the same without the scale), v (convolution, silu). Which program runs is read from the
+    input as for the rule (``_program``: the kernels on a TPU where a key head's q, k and v columns, ``d_k`` and ``r
+    d_v`` wide, are whole lanes; ``PASSES`` says which); ``impl`` as ``gated_delta_rule``'s."""
+    b, s, kd = xq.shape
+    program = _program(**{"d_k": kd // hk, "r d_v": xv.shape[2] // hk}) if impl is None else impl.split("_")[0]
+    _counted("in", (b, s, 2 * kd + xv.shape[2]), program)
+    if program != "kernels":
+        return _mixer_in_xla(xq, xk, xv, weight, hk)
+    padded, s = _padded_rows((xq, xk, xv), STEP_CHUNKS * CHUNK)
+    return tuple(y[:, :s] for y in _in_pass(hk, impl == "kernels_interpret")(*padded, weight))
+
+
+def gated_norm(o, z, weight, eps, *, impl=None):
+    """``rms_norm(o) weight silu(z)`` over each value head's ``d_v`` lanes: ``o`` (the rule's output) and ``z`` flat
+    ``[b, s, hv d_v]``, ``weight [d_v]``. Which program: as ``mixer_in``."""
+    program = _program(d_v=weight.shape[0]) if impl is None else impl.split("_")[0]
+    _counted("out", o.shape, program)
+    if program != "kernels":
+        return _gated_norm_xla(o, z, weight, eps)
+    (o, z), s = _padded_rows((o, z), STEP_CHUNKS * CHUNK)
+    return _out_pass(float(eps), impl == "kernels_interpret")(o, z, weight)[:, :s]

@@ -166,26 +166,124 @@ def test_chunked_rule_equals_token_by_token(seq, a_max, program):
         assert bool(jnp.isfinite(a).all()) and _rel(a, b) < 1e-4, name
 
 
-@pytest.mark.parametrize("backend, shape, chunk, form", [
-    ("tpu", (2, 128, 16, 32, 128, 128), 64, "chunked 64: kernels"),
-    ("tpu", (2, 100, 2, 2, 256, 128), 64, "chunked 64: kernels"),
-    ("tpu", (2, 128, 2, 4, 16, 16), 64, "chunked 64: xla (d_k 16 is no multiple of 128)"),
-    ("tpu", (2, 128, 2, 4, 128, 64), 64, "chunked 64: xla (d_v 64 is no multiple of 128)"),
-    ("tpu", (2, 128, 2, 4, 128, 128), 32, "chunked 32: xla (chunk 32 is not 64)"),
-    ("cpu", (2, 128, 2, 4, 128, 128), 64, "chunked 64: xla"),
+@pytest.mark.parametrize("backend, shape, chunk, form, passes", [
+    ("tpu", (2, 128, 16, 32, 128, 128), 64, "chunked 64: kernels", ("kernels", "kernels")),
+    ("tpu", (2, 100, 2, 2, 256, 128), 64, "chunked 64: kernels", ("kernels", "kernels")),
+    ("tpu", (2, 128, 2, 4, 16, 16), 64, "chunked 64: xla (d_k 16 is no multiple of 128)",
+     ("xla (d_k 16 is no multiple of 128)", "xla (d_v 16 is no multiple of 128)")),
+    ("tpu", (2, 128, 2, 4, 128, 64), 64, "chunked 64: xla (d_v 64 is no multiple of 128)",
+     ("kernels", "xla (d_v 64 is no multiple of 128)")),  # in: a key head's two value heads fill 128 lanes
+    ("tpu", (2, 128, 2, 4, 128, 128), 32, "chunked 32: xla (chunk 32 is not 64)", ("kernels", "kernels")),
+    ("cpu", (2, 128, 2, 4, 128, 128), 64, "chunked 64: xla", ("xla", "xla")),
 ], ids=["cell", "wide-keys", "narrow-keys", "narrow-values", "other-chunk", "cpu"])
-def test_which_program_takes_the_rule_is_read_from_the_input(monkeypatch, backend, shape, chunk, form):
+def test_which_program_takes_the_rule_is_read_from_the_input(monkeypatch, backend, shape, chunk, form, passes):
     """No knob: the kernels on a TPU where a head is whole lanes and the chunk is
     64, the XLA form elsewhere, and ``CALLS`` says which and, on a TPU, why not
-    (every form starts ``chunked``, what ``gdn_chunked_calls_pct`` reads)."""
+    (every form starts ``chunked``, what ``gdn_chunked_calls_pct`` reads). The
+    mixer's two passes around the rule read the same (the chunk is not theirs)
+    and say it in a dict of their own, ``PASSES``: ``CALLS`` is the rule's."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(gated_delta, "CALLS", {})
+    monkeypatch.setattr(gated_delta, "PASSES", {})
     rows, seq, hk, hv, dk, dv = shape
     like = lambda *x: jax.ShapeDtypeStruct(x, jnp.bfloat16)  # noqa: E731
     out = jax.eval_shape(lambda *a: gated_delta.gated_delta_rule(*a, chunk=chunk), like(rows, seq, hk, dk), like(rows, seq, hk, dk),
                          like(rows, seq, hv, dv), like(rows, seq, hv), like(rows, seq, hv))
     assert out.shape == (rows, seq, hv, dv) and out.dtype == jnp.bfloat16
     assert gated_delta.CALLS == {shape: [1, form]} and form in gated_delta.calls_summary()
+    q, k, v = jax.eval_shape(lambda *a: gated_delta.mixer_in(*a, hk), like(rows, seq, hk * dk), like(rows, seq, hk * dk),
+                             like(rows, seq, hv * dv), like(4, 2 * hk * dk + hv * dv))
+    y = jax.eval_shape(lambda *a: gated_delta.gated_norm(*a, 1e-6), like(rows, seq, hv * dv), like(rows, seq, hv * dv), like(dv))
+    assert (q.shape, k.shape, v.shape, y.shape) == ((rows, seq, hk * dk),) * 2 + ((rows, seq, hv * dv),) * 2 and y.dtype == jnp.bfloat16
+    assert gated_delta.PASSES == {("in", rows, seq, 2 * hk * dk + hv * dv): [1, passes[0]], ("out", rows, seq, hv * dv): [1, passes[1]]}
+    assert gated_delta.CALLS == {shape: [1, form]}  # the passes count nothing there
+    assert f"mixer passes: in {[rows, seq, 2 * hk * dk + hv * dv]}: {passes[0]} x 1; out {[rows, seq, hv * dv]}: {passes[1]} x 1" \
+        in gated_delta.calls_summary()
+
+
+# -- the mixer's two elementwise passes ---------------------------------------
+
+
+def _pass_inputs(which, rows, seq, hk, r, dtype, seed=0):
+    """A pass's arguments at heads of 128 (what the kernels take): ``hk`` key heads, ``r`` value heads each."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + seq), 4)
+    act = lambda key, width: jax.random.normal(key, (rows, seq, width)).astype(dtype)  # noqa: E731
+    if which == "in":
+        return (act(ks[0], hk * 128), act(ks[1], hk * 128), act(ks[2], hk * r * 128),
+                (0.5 * jax.random.normal(ks[3], (4, (2 + r) * hk * 128))).astype(dtype))
+    return act(ks[0], hk * r * 128), act(ks[1], hk * r * 128), (1 + 0.3 * jax.random.normal(ks[2], (128,))).astype(dtype)
+
+
+def _pass(which, hk, impl):
+    if which == "in":
+        return lambda *a: gated_delta.mixer_in(*a, hk, impl=impl)
+    return lambda *a: gated_delta.gated_norm(*a, 1e-6, impl=impl)
+
+
+def _output_and_cotangents(fn, args):
+    """``fn``'s outputs and the cotangent of every argument (the taps' and the norm's weight among them) under a loss
+    that weighs every output element differently."""
+    loss = lambda *a: sum(jnp.sum(jnp.sin(y.astype(jnp.float32) + 0.3)) for y in jax.tree.leaves(fn(*a)))  # noqa: E731
+    return jax.tree.leaves((fn(*args), jax.grad(loss, argnums=tuple(range(len(args))))(*args)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("rows, seq, hk, r", [(1, 512, 1, 2), (1, 1536, 2, 1), (1, 700, 1, 2), (2, 1024, 1, 1)],
+                         ids=["one-block", "three-blocks", "no-whole-block", "two-rows"])
+@pytest.mark.parametrize("which", ["in", "out"])
+def test_a_pass_as_kernels_equals_its_xla_form(which, rows, seq, hk, r, dtype):
+    """Each fused pass under the Pallas interpreter against the XLA form (``causal_conv``, ``l2_norm``, ``rms_norm``),
+    the outputs and every argument's cotangent: a row of one token block, of three (blocks of 512: the taps' 3 tokens
+    cross a block's edge forward and, in the backward pass, against time), a row padded to a whole block, two rows
+    (nothing leaks from the end of one into the start of the next). Float32: the same mathematics, to 1e-5. Bfloat16:
+    the kernels round once where the XLA form rounds at every step, so against the XLA form IN FLOAT32 on the same
+    bfloat16 values they stand no further off than the XLA form in bfloat16 does (and both within bfloat16's grain)."""
+    args = _pass_inputs(which, rows, seq, hk, r, dtype)
+    got = _output_and_cotangents(_pass(which, hk, "kernels_interpret"), args)
+    want = _output_and_cotangents(_pass(which, hk, "xla"), args)
+    assert [a.shape for a in got] == [a.shape for a in want] and [a.dtype for a in got] == [a.dtype for a in want]
+    if dtype == jnp.float32:
+        for a, b in zip(got, want):
+            assert bool(jnp.isfinite(a).all()) and _rel(a, b) < 1e-5
+        return
+    exact = _output_and_cotangents(_pass(which, hk, "xla"), [x.astype(jnp.float32) for x in args])
+    for a, b, c in zip(got, want, exact):
+        assert bool(jnp.isfinite(a).all()) and _rel(a, c) < max(1.25 * _rel(b, c), 2.0 ** -9), (_rel(a, c), _rel(b, c))
+
+
+def test_the_first_three_tokens_of_a_row_see_zeros_left_of_it():
+    """The causal zero: token 0 of EVERY row sees its own tap alone, whatever ends the row before it (two rows of one
+    block each: the block before row 1's first is row 0's last in memory order, and is not read)."""
+    xq, xk, xv, w = _pass_inputs("in", 2, 512, 1, 1, jnp.float32)
+    xv = xv.at[0, -3:].set(1e3)                              # what must not leak into row 1
+    _, _, v = gated_delta.mixer_in(xq, xk, xv, w, 1, impl="kernels_interpret")
+    taps = w[:, 256:]
+    for row in range(2):
+        want = [jax.nn.silu(sum(taps[3 - j] * xv[row, t - j] for j in range(t + 1))) for t in range(3)]
+        np.testing.assert_allclose(np.asarray(v[row, :3]), np.asarray(jnp.stack(want)), rtol=1e-5, atol=1e-6)
+
+
+def test_the_projection_is_cut_by_column_where_its_leaf_can_be():
+    """``in_proj_qkvz`` stays ONE leaf; the mixer cuts it by output column and makes a product a run (with LoRA beside
+    the kernel: ``lora_b`` is cut, ``lora_a`` is not), so that no activation is sliced. A leaf it cannot cut makes one
+    product whose output is cut. Both equal ``lin(hid, p)`` cut."""
+    from llm_fine_tune_distributed_tpu.models import transformer
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    hid = jax.random.normal(ks[0], (2, 8, 16))
+    p = {"kernel": jax.random.normal(ks[1], (16, 24)), "lora_a": jax.random.normal(ks[2], (16, 4)),
+         "lora_b": jax.random.normal(ks[3], (4, 24)), "lora_scale": jnp.asarray(0.5)}
+    products = []
+    lin = lambda x, q: products.append(q) or transformer._linear(x, q, jnp.float32)  # noqa: E731
+    whole = transformer._linear(hid, p, jnp.float32)
+    runs = transformer._by_columns(hid, p, (0, 8, 20, 24), lin)
+    assert len(products) == 3 and [q["kernel"].shape[1] for q in products] == [8, 12, 4]
+    for y, (lo, hi) in zip(runs, ((0, 8), (8, 20), (20, 24))):
+        assert _rel(y, whole[..., lo:hi]) < 1e-6
+    del products[:]
+    other = {"kernel": p["kernel"], "lora_a_pool": jnp.zeros((2, 16, 4)), "lora_b_pool": jnp.zeros((2, 4, 24)), "lora_scale_pool": jnp.ones((2,))}
+    runs = transformer._by_columns(hid, other, (0, 8, 24), lin)
+    assert len(products) == 1 and [y.shape[-1] for y in runs] == [8, 16]
 
 
 def test_unit_lower_inverse_and_its_derivative():

@@ -35,6 +35,9 @@ HQ, HKV, D = 16, 4, 128
 # kernel, and their serialized modules' bytes together (PR 36's tree read 125,980 in the same step, my chip run, PR 37).
 RULE_PROGRAMS = {"gdn_rule_fwd": 1, "gdn_rule_bwd": 1}
 RULE_MODULE_BYTES = 77_064
+# The mixer's two elementwise passes around the rule, the same way (PR 39; budget: 40 KB together).
+MIXER_PROGRAMS = {"gdn_in_fwd": 1, "gdn_in_bwd": 1, "gdn_out_fwd": 1, "gdn_out_bwd": 1}
+MIXER_MODULE_BYTES = 35_816
 
 
 @pytest.fixture(scope="module")
@@ -291,7 +294,8 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
     layer; this chip's share: 32 of 512 experts, an eighth of the vocabulary),
     every parameter trained, 2 rows of 8192 a microbatch, two microbatches.
     The compiler's own count has to fit beside the state (15.49 GiB a program
-    may use; at 4 rows a microbatch it refuses the step, 17.89 G of 15.75 G);
+    may use; at 4 rows a microbatch it refused the step, 17.89 G of 15.75 G,
+    until PR 39's passes took the float32 copies out: 14.13 GiB now, PERF.md);
     the full layer runs the streamed flash kernels at heads of 256, 8 queries
     a kv head (no resident kernel: its dk/dv would ask 300 MiB), its forward
     kernel once (``o`` and ``lse`` kept: 8192 against the hidden 2048); each
@@ -300,7 +304,11 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
     once; grouped products, the sums of rows into tokens and the kept routing
     are in the step. What the rule's kernels cost every start of a process is
     held too (``RULE_PROGRAMS``, ``RULE_MODULE_BYTES``): PR 36's kernels, 126 KB
-    of modules here, added 10.9 s to every warm ``setup_s`` and were refused."""
+    of modules here, added 10.9 s to every warm ``setup_s`` and were refused.
+    Around the rule the mixer's elementwise work is two fused passes (PR 39:
+    ``gdn_in_*`` under ``gdn_conv``, ``gdn_out_*`` under ``gdn_gate_norm``,
+    counted like the sweeps and their text held like the rule's), and between
+    the projections and ``out_proj`` nothing else touches a whole activation."""
     from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -337,14 +345,38 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
             (f"jvp(layer{i})", "", "gdn_rule_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", "gdn_rule_fwd"),
             (f"transpose(jvp(layer{i}))", "", "gdn_rule_bwd"))), sweeps
     assert "triangular" not in text.lower()
-    # not above the parent's count (14.81 GiB with the XLA form's U, W and boundary states in HBM)
-    assert compiled.memory_analysis().peak_memory_in_bytes <= 14.81 * 2**30
+    # the two passes around it (PR 39), the same: forward kernels in the forward and the recomputed pass, backward
+    # kernels once, none in the full layer
+    passes = sorted(re.findall(
+        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/(gdn_conv|gdn_gate_norm)/'
+        r'jit\((gdn_(?:in|out)_\w+)\)/\4/pallas_call"', "\n".join(line for line in text.splitlines() if "tpu_custom_call" in line)))
+    assert passes == sorted(
+        found for i in range(3) for scope, way in (("gdn_conv", "in"), ("gdn_gate_norm", "out")) for found in (
+            (f"jvp(layer{i})", "", scope, f"gdn_{way}_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", scope, f"gdn_{way}_fwd"),
+            (f"transpose(jvp(layer{i}))", "", scope, f"gdn_{way}_bwd"))), passes
+    # what the passes removed: of the instructions under linear_attn that yield a whole [2, 8192, >= 2048] activation
+    # (fused computations' insides apart) none is a pad, a slice, a concatenation, a copy, a transpose or a conversion:
+    # each is a kernel, or a fusion that is a projection's product or the sum of the products' input cotangents
+    whole, computation = [], ""
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            computation = head.group(1)
+        found = re.match(r'\s*(?:ROOT )?%[\w.\-]+ = \w+\[2,8192,(\d+)\]\S* ([\w\-]+)\(.*op_name="([^"]*/linear_attn[^"]*)"', line)
+        if found and not computation.startswith("fused_computation") and int(found.group(1)) >= 2048:
+            whole.append((found.group(2), found.group(3).rsplit("/", 1)[1]))
+    assert whole and {opcode for opcode, _ in whole} <= {"custom-call", "get-tuple-element", "fusion", "bitcast"}, set(whole)
+    assert {last for opcode, last in whole if opcode == "fusion"} <= {"dot_general", "add_any"}, set(whole)
+    # under the landed count (13.47 GiB; the parent's 14.15 held the XLA form's float32 copies and padded cotangents)
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 13.55 * 2**30
     # what a start of the process pays for the rule again, warm cache or not: the text of its kernels, traced and
     # lowered before the cache is even asked (PERF.md, PR 37, step 0: the step's lower() follows the serialized
     # modules' bytes, .compile() on a hit does not move). Held at the landed value plus a fifth.
-    rule = {name: found for name, found in mosaic_programs(lowered.as_text()).items() if name.startswith("gdn_rule")}
-    assert {name: found["programs"] for name, found in rule.items()} == RULE_PROGRAMS, rule
-    assert sum(found["bytes"] for found in rule.values()) <= 1.2 * RULE_MODULE_BYTES, rule
+    programs = mosaic_programs(lowered.as_text())
+    for prefixes, count, landed in ((("gdn_rule",), RULE_PROGRAMS, RULE_MODULE_BYTES), (("gdn_in", "gdn_out"), MIXER_PROGRAMS, MIXER_MODULE_BYTES)):
+        found = {name: x for name, x in programs.items() if name.startswith(prefixes)}
+        assert {name: x["programs"] for name, x in found.items()} == count, found
+        assert sum(x["bytes"] for x in found.values()) <= 1.2 * landed, found
 
 
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
