@@ -32,6 +32,10 @@ _DENSE = dict(freeze_strategy="last_n_and_head", unfreeze_last_n_layers=2, atten
 _ALL = dict(freeze_strategy="none", attention_impl="flash", remat_policy="full", loss_chunk_size=1024)
 _MELLUM = ("mellum2_12b_a2_5b", dict(num_layers=4, vocab_size=24576, held_experts=tuple(range(16))))
 _QWEN3_NEXT = ("qwen3_next_80b_a3b", dict(num_layers=4, vocab_size=18992, held_experts=tuple(range(32))))
+_TRINITY = ("trinity_mini", dict(
+    num_layers=5, first_k_dense_replace=1, vocab_size=25024, held_experts=tuple(range(16)),
+    layer_types=("sliding_attention",) * 3 + ("full_attention", "sliding_attention"), no_rope_layers=(1, 1, 1, 0, 1),
+))
 # name -> (preset, model overrides, rows, accumulation, sequence, recipe)
 STEPS = {
     # the cells of BENCHMARK.json, as their traffic files state them
@@ -48,6 +52,10 @@ STEPS = {
     # the same at 4 rows and at 1 (the cell was sized at 2 when 4 were refused, 17.89 G of 15.75 G; since PR 39 4 fit, 14.13 GiB)
     "qwen3-next-80b-a3b-ep16-d4.8k-4rows": (*_QWEN3_NEXT, 4, 1, 8192, _ALL),
     "qwen3-next-80b-a3b-ep16-d4.8k-1row": (*_QWEN3_NEXT, 1, 4, 8192, _ALL),
+    "trinity-mini-26b-a3b-ep8-d5.sft-8k-gated-swa-allparams": (*_TRINITY, 2, 2, 8192, _ALL),
+    # the same at 1 row and at 4 (2 x 2 if the compiler's count stays under 15.0 GiB, else 1 x 4: ISSUE 40)
+    "trinity-mini-26b-a3b-ep8-d5.8k-1row": (*_TRINITY, 1, 4, 8192, _ALL),
+    "trinity-mini-26b-a3b-ep8-d5.8k-4rows": (*_TRINITY, 4, 1, 8192, _ALL),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),

@@ -34,12 +34,18 @@ class LayerPlan:
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Architecture of a dense decoder-only transformer.
-
-    One config class covers the Llama family: Llama-3, Mistral (sliding
-    window), Qwen-style (qkv bias), and SmolLM3 (NoPE-interleaved RoPE:
-    ``no_rope_layers[i] == 0`` means layer *i* applies no rotary embedding —
-    mirrors HF ``SmolLM3Config.no_rope_layers``).
+    """Architecture of a decoder-only transformer, one class for every family
+    the framework runs: each block is a mixer (softmax attention over heads or
+    over a latent, with or without a window, a rope, q/k norms and an output
+    gate; or a linear recurrence) and a feed-forward (a dense MLP, or routed
+    experts beside shared ones) between two or four norms. The family fields
+    below become one layer's parts in ``layer(i)``, the one place the model
+    code asks: Llama-3, Mistral (sliding window), Qwen-style (qkv bias),
+    SmolLM3 (NoPE-interleaved RoPE: ``no_rope_layers[i] == 0`` means layer *i*
+    applies no rotary embedding, as HF ``SmolLM3Config.no_rope_layers``),
+    Gemma2 (four norms a block), Mixtral, DeepSeek-V3 / Moonlight, Mellum,
+    Qwen3-Next, and ``afmoe`` (Trinity: gated window layers with rope beside
+    gated global layers without, four norms a block around routed experts).
     """
 
     name: str = "unnamed"
@@ -67,11 +73,14 @@ class ModelConfig:
     # only (enforced in __post_init__; ops/moe.py hardcodes the expert MLP).
     hidden_act: str = "silu"
     # Four norms per layer: post-attention and post-feedforward OUTPUT norms
-    # in addition to the two pre-norms (HF Gemma2DecoderLayer ordering)
+    # in addition to the two pre-norms (HF Gemma2DecoderLayer ordering; afmoe's
+    # pre_mlp_layernorm / post_mlp_layernorm), around whatever the layer's
+    # feed-forward is: a layer of routed experts norms the SUM of the routed
+    # and the shared experts' outputs
     sandwich_norms: bool = False
     # RMSNorm weight stored zero-centered: out = normed * (1 + w), w init 0
     zero_centered_norm: bool = False
-    # Multiply embedding output by sqrt(hidden_size) (Gemma normalizer)
+    # Multiply embedding output by sqrt(hidden_size) (Gemma normalizer; afmoe's mup_enabled)
     embed_scale: bool = False
     # Soft caps: score -> cap * tanh(score / cap)
     attn_logit_softcap: Optional[float] = None
@@ -101,8 +110,9 @@ class ModelConfig:
     # global layers only); None = the scaling is every layer's (Llama-3.1).
     rope_scaling_layer_type: Optional[str] = None
     mlp_bias: bool = False
-    # SmolLM3 NoPE: 1 = RoPE on this layer, 0 = no positional embedding.
-    # Empty tuple = RoPE everywhere (Llama/Mistral).
+    # SmolLM3 NoPE: 1 = RoPE on this layer, 0 = no positional embedding
+    # (afmoe: its full_attention layers). Empty tuple = RoPE everywhere
+    # (Llama/Mistral).
     no_rope_layers: tuple = ()
     sliding_window: Optional[int] = None  # Mistral-style local attention
     # HF ``layer_types``, one entry a layer: "sliding_attention" (the window

@@ -453,6 +453,75 @@ PRESETS = {
         router_scoring="softmax",
         held_experts=(0, 1, 2, 3),
     ),
+    "trinity_mini": ModelConfig(
+        # HF arcee-ai/Trinity-Mini (model_type afmoe, 26B-A3B): three window
+        # layers (2048, plain rope) to one global layer WITHOUT rope, GQA 32/4
+        # at 128 with per-head q/k norms and a sigmoid gate on the attention
+        # output (q_proj holds [q | gate] by head), four norms a block; two
+        # leading dense layers of 6144, then 128 experts of 1024 behind a
+        # sigmoid router with a selection bias, 8 a token, weights over their
+        # sum times 2.826, beside one shared expert; embeddings times
+        # sqrt(hidden). Set held_experts to one process's share for expert
+        # parallelism.
+        name="trinity_mini",
+        vocab_size=200192,
+        hidden_size=2048,
+        intermediate_size=6144,
+        num_layers=32,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        rope_theta=10_000.0,
+        max_position_embeddings=131072,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        qk_norm=True,
+        sandwich_norms=True,
+        embed_scale=True,
+        attention_output_gate=True,
+        sliding_window=2048,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 8,
+        no_rope_layers=(1, 1, 1, 0) * 8,
+        n_routed_experts=128,
+        num_experts_per_tok=8,
+        moe_intermediate_size=1024,
+        n_shared_experts=1,
+        first_k_dense_replace=2,
+        routed_scaling_factor=2.826,
+    ),
+    "tiny_trinity": ModelConfig(
+        # Trinity's structure at toy widths (tests, the benchmark's CPU
+        # rehearsal): one leading dense layer, then one period of the 3:1
+        # pattern as the benchmark's cut reads it (layer 3 the global one), a
+        # window shorter than the rows, this process holding 4 of the 16
+        # routed experts
+        name="tiny_trinity",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=10_000.0,
+        max_position_embeddings=2048,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        qk_norm=True,
+        sandwich_norms=True,
+        embed_scale=True,
+        attention_output_gate=True,
+        sliding_window=32,
+        layer_types=("sliding_attention",) * 3 + ("full_attention", "sliding_attention"),
+        no_rope_layers=(1, 1, 1, 0, 1),
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.826,
+        held_experts=(0, 1, 2, 3),
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -715,6 +784,55 @@ def _qwen3_next_fields(g) -> dict:
     )
 
 
+def _afmoe_fields(g) -> dict:
+    """ModelConfig fields of an ``afmoe`` config (arcee-ai Trinity; HF
+    AfmoeConfig): window and global layers by ``layer_types`` (or every
+    ``global_attn_every_n_layers``-th global), rope on the window layers only,
+    per-head q/k norms, a sigmoid gate on every layer's attention output, four
+    norms a block, ``num_dense_layers`` leading dense layers, then
+    ``num_experts`` experts behind a sigmoid router whose ``expert_bias``
+    selects and does not weigh (``route_norm``, ``route_scale``) beside
+    ``num_shared_experts`` shared ones; ``mup_enabled`` multiplies the
+    embeddings by sqrt(hidden). The family's convention where the config has
+    no key (the norms, the gate, which layers rotate) is HF's
+    ``models/afmoe/modeling_afmoe.py``. ``load_balance_coeff`` is pretraining's
+    (the bias is a buffer here: no step updates it). Whatever of it this
+    framework does not implement is refused by name, before any weight loads."""
+    n = g("num_hidden_layers")
+    every = g("global_attn_every_n_layers") or 4
+    layer_types = tuple(g("layer_types") or (
+        "full_attention" if (i + 1) % every == 0 else "sliding_attention" for i in range(n)
+    ))
+    problems = []
+    for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups"):
+        if (g(key) or 1) > 1:
+            problems.append(f"{key} {g(key)} (implemented: one group of experts)")
+    if not g("route_norm", True):
+        problems.append("route_norm false (implemented: weights over the sum of the chosen scores)")
+    if g("score_func", "sigmoid") != "sigmoid":
+        problems.append(f"score_func {g('score_func')!r} (implemented: sigmoid)")
+    if set(layer_types[:n]) - {"sliding_attention", "full_attention"} or len(layer_types) < n:
+        problems.append(f"layer_types {sorted(set(layer_types))} with {len(layer_types)} entries for {n} layers")
+    if problems:
+        raise ValueError("afmoe config has " + "; ".join(problems))
+    return dict(
+        layer_types=layer_types,
+        no_rope_layers=tuple(int(kind == "sliding_attention") for kind in layer_types),
+        qk_norm=True,
+        sandwich_norms=True,
+        attention_output_gate=True,
+        embed_scale=bool(g("mup_enabled", False)),
+        n_routed_experts=g("num_experts"),
+        num_experts_per_tok=g("num_experts_per_tok"),
+        moe_intermediate_size=g("moe_intermediate_size"),
+        n_shared_experts=g("num_shared_experts") or 0,
+        first_k_dense_replace=g("num_dense_layers") or 0,
+        routed_scaling_factor=float(g("route_scale", 1.0)),
+        router_scoring="sigmoid",
+        held_experts=tuple(g("held_experts") or ()),
+    )
+
+
 def load_model_config(path: str) -> ModelConfig:
     """Read ``path/config.json`` (HF layout) into a ModelConfig — the ONE
     place train-time (trainer._resolve_model_config) and inference-time
@@ -912,4 +1030,6 @@ def from_hf_config(hf_config) -> ModelConfig:
         return dataclasses.replace(mc, **_mellum_fields(g))
     if mt == "qwen3_next":
         return dataclasses.replace(mc, **_qwen3_next_fields(g))
+    if mt == "afmoe" and not framework_save:  # (this framework's own save of one carries every field by its own key)
+        return dataclasses.replace(mc, **_afmoe_fields(g))
     return dataclasses.replace(mc, **deepseek) if deepseek else mc

@@ -95,6 +95,45 @@ def _linear_columns(config: ModelConfig, path: tuple):
 # the shared expert beside routed ones: DeepSeek-V3's name is the tree's; Qwen3-Next's differs
 _QWEN_SHARED = ("shared_experts", "shared_expert")
 
+# HF afmoe (arcee-ai Trinity) names that differ from the tree's, stored name -> tree's name (dotted, below a
+# layer): the two norms around the feed-forward, the router and its selection bias. Its attention gate is a
+# ``self_attn.gate_proj`` of its own, which the tree keeps inside ``q_proj`` as ``[q | gate]`` by head (the leaf
+# Qwen3-Next stores that way): joined on load, split on save (``_split_gate``). Its experts are DeepSeek-V3's names.
+_AFMOE_NAMES = {
+    "pre_mlp_layernorm": "pre_feedforward_layernorm",
+    "post_mlp_layernorm": "post_feedforward_layernorm",
+    "mlp.router.gate": "mlp.gate",
+    "mlp.expert_bias": "mlp.gate.e_score_correction_bias",
+}
+_AFMOE_GATE = ("self_attn", "gate_proj", _KERNEL_LEAF)
+
+
+def _afmoe(config: Optional[ModelConfig]) -> bool:
+    """Whether a checkpoint of ``config`` carries HF afmoe's names: the one family whose blocks have an output
+    gate on softmax attention AND four norms (Qwen3-Next has the gate and two norms, Gemma2 four norms and no gate)."""
+    return config is not None and config.attention_output_gate and config.sandwich_norms
+
+
+def _afmoe_renamed(name: str, to_stored: bool) -> str:
+    """A dotted name with afmoe's parts renamed, whole parts only (``mlp.gate`` and not ``mlp.gate_proj``); the
+    bias before the gate it lies under."""
+    name += "."
+    for stored, ours in reversed(_AFMOE_NAMES.items()):
+        name = name.replace(f".{ours}.", f".{stored}.") if to_stored else name.replace(f".{stored}.", f".{ours}.")
+    return name[:-1]
+
+
+def _split_gate(kernel: np.ndarray, heads: int):
+    """``q_proj``'s ``[hidden, heads x (q | gate)]`` -> (q, gate), each ``[hidden, heads x d]``."""
+    h = kernel.shape[0]
+    both = kernel.reshape(h, heads, 2, -1)
+    return both[:, :, 0].reshape(h, -1), both[:, :, 1].reshape(h, -1)
+
+
+def _join_gate(q: np.ndarray, gate: np.ndarray, heads: int) -> np.ndarray:
+    h = q.shape[0]
+    return np.stack([q.reshape(h, heads, -1), gate.reshape(h, heads, -1)], axis=2).reshape(h, -1)
+
 
 def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dict[str, np.ndarray]:
     """params pytree -> {hf_name: numpy array (torch layout)}. ``config`` is
@@ -126,6 +165,13 @@ def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dic
             raise ValueError("exporting a model with linear-attention layers needs its config")
         if config is not None and config.shared_expert_gate and _QWEN_SHARED[0] in path:
             path = tuple(_QWEN_SHARED[1] if part == _QWEN_SHARED[0] else part for part in path)
+        if _afmoe(config):
+            if path[-3:] == ("self_attn", "q_proj", _KERNEL_LEAF):
+                base = ".".join(path[:-2])
+                for name, part in zip(("q_proj", "gate_proj"), _split_gate(arr, config.num_heads)):
+                    state[f"{base}.{name}.weight"] = np.ascontiguousarray(part.T)
+                continue
+            path = tuple(_afmoe_renamed(".".join(path), to_stored=True).split("."))
         if path[-2:] == ("conv1d", "weight"):
             # [taps, channels] -> torch Conv1d's [channels, 1, taps]
             state[".".join(path)] = np.ascontiguousarray(arr.T[:, None, :])
@@ -195,6 +241,7 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
             experts.setdefault(key, {})[int(m.group(2))] = np.ascontiguousarray(arr.T)
             continue
         name = name.replace(f".mlp.{_QWEN_SHARED[1]}.", f".mlp.{_QWEN_SHARED[0]}.")
+        name = _afmoe_renamed(name, to_stored=False)  # (no other family stores one of these names)
         if needs_transpose(name):
             path = tuple(name[: -len(".weight")].split(".")) + (_KERNEL_LEAF,)
             arr = np.ascontiguousarray(arr.T)
@@ -208,6 +255,9 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
             if path[-2:] == ("conv1d", "weight"):  # torch Conv1d's [channels, 1, taps] -> [taps, channels]
                 arr = np.ascontiguousarray(arr[:, 0, :].T)
         flat[path] = arr
+    for path in [p for p in flat if p[-3:] == _AFMOE_GATE]:  # afmoe's gate_proj into q_proj, [q | gate] by head
+        q_path = path[:-2] + ("q_proj", _KERNEL_LEAF)
+        flat[q_path] = _join_gate(flat[q_path], flat.pop(path), config.num_heads)
     for key, rows in experts.items():
         n = config.num_experts or (max(rows) + 1)
         missing = [i for i in range(n) if i not in rows]

@@ -122,7 +122,8 @@ def _init_heads_attention(keys, config: ModelConfig, dense, dtype):
     h, d = config.hidden_size, config.resolved_head_dim
     qd, kvd = config.num_heads * d, config.num_kv_heads * d
     attn = {
-        # with an output gate each head's query and gate lie side by side (HF Qwen3NextAttention)
+        # with an output gate each head's query and gate lie side by side (HF Qwen3NextAttention;
+        # HF afmoe keeps the gate as a gate_proj of its own, which models/hf_io.py joins by head)
         "q_proj": {"kernel": dense(next(keys), (h, qd * (2 if config.attention_output_gate else 1)))},
         "k_proj": {"kernel": dense(next(keys), (h, kvd))},
         "v_proj": {"kernel": dense(next(keys), (h, kvd))},
@@ -161,11 +162,12 @@ def _heads_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
     k = lin(hid, attn_p["k_proj"]).reshape(b, s, config.num_kv_heads, d)
     v = lin(hid, attn_p["v_proj"]).reshape(b, s, config.num_kv_heads, d)
     if config.qk_norm:
-        # Qwen3: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention);
+        # Qwen3, afmoe: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention);
         # zero-centred where the model's norms are (Qwen3-Next)
         zc = config.zero_centered_norm
-        q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
-        k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
+        with scope("qk_norm"):
+            q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
+            k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
     if not isinstance(rope, bool):
         qr, kr = apply_rope(q, k, cos, sin)
         q = jnp.where(rope, qr, q)
@@ -481,8 +483,9 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
             "post_attention_layernorm": norm_init(),
         }
         if config.sandwich_norms:
-            # Gemma2: post_attention_layernorm norms the attention OUTPUT;
+            # Gemma2, afmoe: post_attention_layernorm norms the attention OUTPUT;
             # pre_feedforward replaces Llama's post_attention pre-MLP role
+            # (HF afmoe's names, pre_mlp / post_mlp_layernorm: models/hf_io.py)
             layer["pre_feedforward_layernorm"] = norm_init()
             layer["post_feedforward_layernorm"] = norm_init()
         subtree, init, _ = _FEED_FORWARD[plan.feed_forward]
@@ -631,8 +634,11 @@ def _block(
     w8a8: bool = False,
 ):
     """One transformer block, composed from its layer's ``plan``
-    (``ModelConfig.layer``). Returns ``(x, new_cache_entry, counted)``:
-    ``counted`` is what the feed-forward counted (``_FEED_FORWARD``).
+    (``ModelConfig.layer``): ``x + [norm](mixer(norm(x)))``, then ``x +
+    [norm](feed_forward(norm(x)))``, the two output norms where the model has
+    four a block (``sandwich_norms``), around any mixer and any feed-forward.
+    Returns ``(x, new_cache_entry, counted)``: ``counted`` is what the
+    feed-forward counted (``_FEED_FORWARD``).
 
     ``mask`` ([batch, q, kv] bool): the explicit attention mask where the
     caller made one (packing with a window, the KV cache), the windowed
@@ -655,8 +661,9 @@ def _block(
             mask=mask, cache_entry=cache_entry, cache_pos=cache_pos, block_tables=block_tables,
         )
         if config.sandwich_norms:
-            # Gemma2: post_attention_layernorm norms the attention OUTPUT
-            attn_out = rms_norm(attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc)
+            # Gemma2, afmoe: post_attention_layernorm norms the mixer's OUTPUT
+            with scope("out_norm"):
+                attn_out = rms_norm(attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc)
         x = x + attn_out
 
     with scope("mlp"):
@@ -667,9 +674,11 @@ def _block(
             lp[subtree], hid, lin, config, compute_dtype=compute_dtype, mesh=mesh,
             padding_mask=padding_mask, segment_ids=segment_ids, serving=cache_entry is not None,
         )
-        if config.sandwich_norms and plan.feed_forward != "grouped_experts":
-            # (HF DeepseekV3's layer has no output norms; no model asks for both)
-            y = rms_norm(y, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc)
+        if config.sandwich_norms:
+            # whatever the feed-forward is: of a layer of routed experts the norm takes the SUM of the routed
+            # and the shared experts' outputs (afmoe's post_mlp_layernorm), of a share of them the partial sum
+            with scope("out_norm"):
+                y = rms_norm(y, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc)
         x = x + y
     return x, new_entry, counted
 

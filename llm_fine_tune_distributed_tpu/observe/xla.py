@@ -828,6 +828,9 @@ STEP_SCOPES = (
     # a linear-attention layer's mixer (in place of "attn") and its parts; the
     # output gate of a gated softmax-attention layer, inside "attn"
     "linear_attn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "attn_gate",
+    # the per-head q and k norms, inside "attn"; the norm of a half's OUTPUT in
+    # a block of four norms, inside "attn" (or "linear_attn") and inside "mlp"
+    "qk_norm", "out_norm",
 )
 
 

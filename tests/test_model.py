@@ -119,6 +119,11 @@ _QWEN3_NEXT = dict(
     attention=lambda i: "heads" if i % 4 == 3 else "linear", nope=lambda i: i % 4 != 3,
     feed_forward=lambda i: "grouped_experts",
 )
+# afmoe (Trinity): three window layers with rope to one global layer WITHOUT; leading dense layers, then experts
+_TRINITY = lambda w, dense: dict(  # noqa: E731
+    window=lambda i: None if i % 4 == 3 else w, nope=lambda i: i % 4 == 3,
+    feed_forward=lambda i: "dense" if i < dense else "grouped_experts",
+)
 PLAN_OF_PRESET = {
     "llama3_1_8b": _SCALED, "llama3_2_1b": _SCALED, "llama3_2_3b": _SCALED,
     "mellum2_12b_a2_5b": _MELLUM(1024), "tiny_mellum": _MELLUM(32),
@@ -132,6 +137,7 @@ PLAN_OF_PRESET = {
     "moonlight_16b_a3b": dict(attention="latent", feed_forward=_LEADING_DENSE),
     "tiny_mla_moe": dict(attention="latent", feed_forward=_LEADING_DENSE),
     "qwen3_next_80b_a3b": _QWEN3_NEXT, "tiny_qwen3_next": _QWEN3_NEXT,
+    "trinity_mini": _TRINITY(2048, 2), "tiny_trinity": _TRINITY(32, 1),
 }
 
 
@@ -148,7 +154,8 @@ def test_layer_plan_of_every_preset(name):
             attention=attention(i), rope=not nope(i), rope_kind=want["rope_kind"](i),
             window=want["window"](i), feed_forward=want["feed_forward"](i),
         ), (name, i)
-    assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= 2  # hashable; no preset has more than two kinds
+    # hashable; no preset has more than two kinds but afmoe's three (dense + window, experts + window, experts + global)
+    assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= (3 if "trinity" in name else 2)
 
 
 @pytest.mark.parametrize("name, counted, step_counters", [

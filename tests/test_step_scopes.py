@@ -94,7 +94,12 @@ EXPERT_LAYER_SCOPES = ("router", "experts", "shared_expert")
 LINEAR_LAYER_SCOPES = ("linear_attn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "attn_gate")
 
 
-@pytest.mark.parametrize("name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES])
+# the q/k norms and the output norms of a block of four norms: ``test_the_gated_four_norm_blocks_scopes`` below
+GATED_BLOCK_SCOPES = ("qk_norm", "out_norm")
+
+
+@pytest.mark.parametrize(
+    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES])
 def test_every_scope_of_the_vocabulary_is_named(paths, name):
     want = re.compile(r"^layer\d+$") if name == "layer" else re.compile(f"^{name}$")
     assert any(want.match(c) for p in paths for c in _components(p)), name
@@ -170,3 +175,34 @@ def test_most_of_the_compiled_step_is_scoped(paths):
     step shows here before it shows on the chip."""
     classes = [reader.classify(p, FIRST_TRAINABLE)[0] for p in paths if p.startswith("jit(train_step)")]
     assert sum(c is not None for c in classes) / len(classes) > 0.75
+
+
+def _scoped_forward(preset):
+    """The scope paths of a preset's forward pass, from its lowering with debug information."""
+    from llm_fine_tune_distributed_tpu.models.transformer import forward
+
+    mc = get_preset(preset)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), mc, dtype=jnp.float32))
+    ids = jax.ShapeDtypeStruct((BATCH, 64), jnp.int32)
+    text = jax.jit(lambda p, x: forward(p, x, mc, compute_dtype=jnp.float32)[0]).lower(params, ids).as_text(debug_info=True)
+    return set(re.findall(r'"jit\(<lambda>\)/([^"]*?)/[^/"]*"', text))
+
+
+def test_the_gated_four_norm_blocks_scopes():
+    """A block with q/k norms, an output gate and four norms (``tiny_trinity``)
+    names all three inside ``attn`` and the feed-forward's output norm inside
+    ``mlp``, on the dense layer and on the expert layers; and every other model
+    that has one of the parts carries its scope too: Gemma2's output norms,
+    the q/k norms and the gate of Qwen3-Next's full layer (its linear layers
+    have neither)."""
+    paths = _scoped_forward("tiny_trinity")
+    for layer in (0, 3):  # the dense layer behind a window, an expert layer behind the global mixer
+        for inside in ("attn/qk_norm", "attn/attn_gate", "attn/out_norm", "mlp/out_norm"):
+            assert any(p.startswith(f"layer{layer}/{inside}") for p in paths), (layer, inside)
+    gemma = _scoped_forward("tiny_gemma2")
+    assert any(p.startswith("layer0/attn/out_norm") for p in gemma) and any(p.startswith("layer1/mlp/out_norm") for p in gemma)
+    assert not any("qk_norm" in p or "attn_gate" in p for p in gemma)
+    qwen = _scoped_forward("tiny_qwen3_next")
+    assert any(p.startswith("layer3/attn/qk_norm") for p in qwen) and any(p.startswith("layer3/attn/attn_gate") for p in qwen)
+    assert not any("out_norm" in p for p in qwen) and not any(p.startswith("layer0/") and "qk_norm" in p for p in qwen)
+    assert not any("qk_norm" in p or "out_norm" in p for p in _scoped_forward("tiny"))

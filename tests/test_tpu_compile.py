@@ -287,6 +287,56 @@ def test_step_with_window_and_global_layers_and_softmax_experts_compiles_for_v5e
     assert not again, again
 
 
+def test_step_with_gated_window_and_global_layers_compiles_for_v5e(topo, monkeypatch):
+    """The leading dense layer, one window layer and the global layer of
+    Trinity-Mini (``afmoe``) at its published widths (this chip's share: 16 of
+    128 experts, an eighth of the vocabulary), every parameter trained but the
+    selection bias, one row of 8192 a microbatch. The window of 2048 is a band
+    three blocks of 1024 wide (Mellum's 1024: two): both kinds of layer run the
+    streamed flash kernels behind the gate and the q/k norms, each forward
+    kernel ONCE (``o`` and ``lse`` kept on the window layers too: 2048 x 1.75 =
+    3584 keys' worth against the hidden 2048); the post-norm sits on the expert
+    layers' output; the grouped products, the sums of rows into tokens and the
+    kept routing are in the step; and the block's three scopes are on its
+    operations."""
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "trinity_mini",
+        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=1, param_dtype="bfloat16",
+        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
+        model_overrides=dict(num_layers=3, first_k_dense_replace=1, vocab_size=25024, held_experts=tuple(range(16)),
+                             layer_types=("sliding_attention", "sliding_attention", "full_attention"),
+                             no_rope_layers=(1, 1, 0)),
+    )
+    text = setup.compile().as_text()
+    mosaic_calls = lambda kernel: sum(  # noqa: E731
+        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
+    )
+    for kernel in ("fwd", "dq", "dkv"):
+        assert mosaic_calls(f"flash_attention_window_{kernel}") == 2, kernel  # layers 0 and 1, the forward kernel kept
+        assert mosaic_calls(f"flash_attention_causal_{kernel}") == 1, kernel
+    assert mosaic_calls("flash_attention_fwd") == 0  # no resident kernel at 8 queries a kv head and 8192
+    band = fa._band(8192, 1024, 2048)
+    assert band.steps == 3 and fa.GRID_TILES["flash_attention_window_fwd", band] == (21, 36)
+    assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    # the sums of rows into tokens: forward and backward an expert layer as everywhere, and here a THIRD, recomputed:
+    # the expert layer's output is no longer the block's last operation, the output norm's backward reads it
+    # (64 MiB a layer and microbatch to keep instead: not kept), and the same three again behind the overflow's cond
+    sums = _sum_kernel_calls(text)
+    first_chunk = [c for c in sums if "/cond/" not in c]
+    assert len(sums) == 6 * 2 and len(first_chunk) == 3 * 2, sums
+    assert sum("transpose(" in c and "rematted_computation" not in c for c in first_chunk) == 2
+    assert sum("rematted_computation" in c for c in first_chunk) == 2
+    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
+    assert not again, again
+    names = re.findall(r'op_name="([^"]+)"', text)
+    for inside in ("attn/attn_gate", "attn/qk_norm", "attn/out_norm", "mlp/out_norm"):
+        assert any(f"/{inside}/" in name for name in names), inside
+    assert any("layer2" in name and "mlp/out_norm" in name for name in names)  # the post-norm of an EXPERT layer
+
+
 def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
     """The step of ``qwen3-next-80b-a3b-ep16-d4.sft-8k-linear-allparams`` as
     its traffic file states it: one period of Qwen3-Next-80B-A3B at its
