@@ -32,7 +32,8 @@ from jax.ad_checkpoint import checkpoint_name
 from llm_fine_tune_distributed_tpu.config import LayerPlan, ModelConfig
 from llm_fine_tune_distributed_tpu.observe.xla import scope
 from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
-from llm_fine_tune_distributed_tpu.ops.attention import attention, softcap, xla_attention
+from llm_fine_tune_distributed_tpu.ops import rope as rope_ops
+from llm_fine_tune_distributed_tpu.ops.attention import attention, head_major_reason, softcap, xla_attention
 from llm_fine_tune_distributed_tpu.ops.int8 import (
     KV_QUANT_MODES,
     dequantize_kv_gather,
@@ -150,30 +151,64 @@ def _heads_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
     ``attention_output_gate``). ``rope``: the plan's bool, or a traced bool
     scalar where the layer index is data (the pipeline's layer scan over
     NoPE-interleaved layers): then both are computed and one selected. Tables
-    narrower than the head rotate its first dimensions (``ops/rope.apply_rope``)."""
+    narrower than the head rotate its first dimensions (``ops/rope.apply_rope``).
+
+    This is the XLA form of what stands between the projections and the
+    attention call, under the scope ``attn_in``: every backend's, serving's,
+    and the reference ``_heads_qkv_head_major`` (the fused pass) is held to."""
     b, s, _ = hid.shape
     d = config.resolved_head_dim
-    gate = None
+    xq, xk, xv = lin(hid, attn_p["q_proj"]), lin(hid, attn_p["k_proj"]), lin(hid, attn_p["v_proj"])
+    with scope("attn_in"):
+        gate = None
+        if config.attention_output_gate:
+            q = xq.reshape(b, s, config.num_heads, 2 * d)
+            q, gate = q[..., :d], q[..., d:].reshape(b, s, config.num_heads * d)
+        else:
+            q = xq.reshape(b, s, config.num_heads, d)
+        k = xk.reshape(b, s, config.num_kv_heads, d)
+        v = xv.reshape(b, s, config.num_kv_heads, d)
+        if config.qk_norm:
+            # Qwen3, afmoe: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention);
+            # zero-centred where the model's norms are (Qwen3-Next)
+            zc = config.zero_centered_norm
+            with scope("qk_norm"):
+                q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
+                k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
+        if not isinstance(rope, bool):
+            qr, kr = apply_rope(q, k, cos, sin)
+            q = jnp.where(rope, qr, q)
+            k = jnp.where(rope, kr, k)
+        elif rope:
+            q, k = apply_rope(q, k, cos, sin)
+    return q, k, v, gate
+
+
+def _heads_qkv_head_major(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
+    """``_heads_qkv``'s q, k, v HEAD-MAJOR, ``[b, heads, s, d]`` and ``[b,
+    kv_heads, s, d]`` as the flash kernels read them, through the fused IN
+    pass (``ops/rope.heads_in``: q/k norms, rope and the layout in one kernel,
+    under the same scope ``attn_in``), and the gate ``[b, s, heads * d]``.
+    The pass reads the projections' flat outputs. Where ``q_proj`` holds each
+    head's ``[q | gate]`` it is the LEAF that is cut by head (``_by_columns``),
+    and q and the gate are two products: the gate never meets the pass, and
+    its cotangent feeds its own product (as ONE product the gate's cotangent
+    had to lie beside dq in one array, 128 MiB more a layer at the step's
+    peak: PERF.md, PR 41)."""
+    d = config.resolved_head_dim
     if config.attention_output_gate:
-        q = lin(hid, attn_p["q_proj"]).reshape(b, s, config.num_heads, 2 * d)
-        q, gate = q[..., :d], q[..., d:].reshape(b, s, config.num_heads * d)
+        xq, gate = _by_columns(hid, attn_p["q_proj"], (0, d, 2 * d), lin, heads=config.num_heads)
     else:
-        q = lin(hid, attn_p["q_proj"]).reshape(b, s, config.num_heads, d)
-    k = lin(hid, attn_p["k_proj"]).reshape(b, s, config.num_kv_heads, d)
-    v = lin(hid, attn_p["v_proj"]).reshape(b, s, config.num_kv_heads, d)
-    if config.qk_norm:
-        # Qwen3, afmoe: per-head RMSNorm over head_dim, before RoPE (HF Qwen3Attention);
-        # zero-centred where the model's norms are (Qwen3-Next)
-        zc = config.zero_centered_norm
-        with scope("qk_norm"):
-            q = rms_norm(q, attn_p["q_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
-            k = rms_norm(k, attn_p["k_norm"]["weight"], config.rms_norm_eps, zero_centered=zc)
-    if not isinstance(rope, bool):
-        qr, kr = apply_rope(q, k, cos, sin)
-        q = jnp.where(rope, qr, q)
-        k = jnp.where(rope, kr, k)
-    elif rope:
-        q, k = apply_rope(q, k, cos, sin)
+        xq, gate = lin(hid, attn_p["q_proj"]), None
+    xk, xv = lin(hid, attn_p["k_proj"]), lin(hid, attn_p["v_proj"])
+    weights = {}
+    if config.qk_norm:  # the pass takes a norm's MULTIPLIER: 1 + w where the model's norms are zero-centred
+        one = 1.0 if config.zero_centered_norm else 0.0
+        weights = {f"{x}_weight": one + attn_p[f"{x}_norm"]["weight"].astype(jnp.float32) for x in "qk"}
+    with scope("attn_in"):
+        q, k, v = rope_ops.heads_in(
+            xq, xk, xv, cos, sin, heads=config.num_heads, kv_heads=config.num_kv_heads, eps=config.rms_norm_eps,
+            rope=rope, **weights)
     return q, k, v, gate
 
 
@@ -212,40 +247,67 @@ def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope=True):
     return q, k, kv[..., dn:], None
 
 
-def _softmax_mixer(qkv):
+def _why_not_head_major(hid, cos, config, plan, *, attention_impl, mesh, scale, mask, cache_entry):
+    """Why a layer of heads cannot hand its q, k, v to the flash kernels head-major through the fused IN pass
+    (``_heads_qkv_head_major``), or None: read from what the call can see, no switch. It can where ``attention()``
+    would run the kernels on the whole row in one device's program (``ops/attention.head_major_reason``: training and
+    full-row evaluation on a TPU, a head of whole lane registers) and the pass takes the tables; with a cache entry,
+    an explicit mask, ring or Ulysses attention over a live seq axis, the pipeline's stages, a mesh of several devices
+    or a CPU the XLA form stands."""
+    if cache_entry is not None:
+        return "a cache entry"
+    if mask is not None:
+        return "an explicit mask"
+    b, s, _ = hid.shape
+    d = config.resolved_head_dim
+    q, k = (jax.ShapeDtypeStruct((b, s, n, d), hid.dtype) for n in (config.num_heads, config.num_kv_heads))
+    return head_major_reason(
+        q, k, k, impl=attention_impl, mesh=mesh, scale=scale, logit_softcap=config.attn_logit_softcap, sliding_window=plan.window,
+    ) or rope_ops.why_not_fused(b, s, d, cos)
+
+
+def _softmax_mixer(qkv, qkv_head_major=None):
     """The mixer of a layer of softmax attention, over ``qkv`` (``_heads_qkv``
     or ``_latent_qkv``): q, k, v, the cache where there is one, the attention
-    dispatch, the output gate where the model has one, ``o_proj``."""
+    dispatch, the output gate where the model has one, ``o_proj``.
+    ``qkv_head_major``: the same q, k, v through a fused pass that writes them
+    as the flash kernels read them, for the kind of layer that has one; the
+    call takes it where ``_why_not_head_major`` has no objection, and
+    ``ops/rope.CALLS`` counts which form each traced call took and why."""
 
     def mixer(
         attn_p, hid, cos, sin, *, config, plan, lin, rope, compute_dtype, attention_impl, mesh,
         padding_mask, segment_ids, mask, cache_entry, cache_pos, block_tables,
     ):
         b, s, _ = hid.shape
-        q, k, v, gate = qkv(attn_p, hid, cos, sin, config, lin, rope)
         # Gemma2: query_pre_attn_scalar scale, logit softcap (None for Llama-family)
         scale = None if config.query_pre_attn_scalar is None else float(config.query_pre_attn_scalar) ** -0.5
-        heads_width = config.num_heads * v.shape[-1]
-        k, v, new_entry, out = _cache_write_and_view(
-            cache_entry, q, k, v, cache_pos, block_tables, plan=plan, compute_dtype=compute_dtype, scale=scale,
-            fusable=padding_mask is None and plan.window is None and config.attn_logit_softcap is None,
+        asked = dict(
+            impl=attention_impl, padding_mask=padding_mask, segment_ids=segment_ids, causal=True, sliding_window=plan.window,
+            mesh=mesh, scale=scale, logit_softcap=config.attn_logit_softcap,
         )
+        why_xla = "this kind of layer has no fused pass"
+        if qkv_head_major is not None:
+            why_xla = _why_not_head_major(
+                hid, cos, config, plan, attention_impl=attention_impl, mesh=mesh, scale=scale, mask=mask, cache_entry=cache_entry)
+            rope_ops.count_call(
+                (b, s, config.num_heads, config.num_kv_heads, config.resolved_head_dim, cos.shape[-1],
+                 "norm" * config.qk_norm, "gate" * config.attention_output_gate), why_xla)
+        q, k, v, gate = (qkv_head_major if why_xla is None else qkv)(attn_p, hid, cos, sin, config, lin, rope)
+        heads_width = config.num_heads * v.shape[-1]
+        if why_xla is None:
+            new_entry, out = None, attention(q, k, v, head_major=True, **asked)
+        else:
+            k, v, new_entry, out = _cache_write_and_view(
+                cache_entry, q, k, v, cache_pos, block_tables, plan=plan, compute_dtype=compute_dtype, scale=scale,
+                fusable=padding_mask is None and plan.window is None and config.attn_logit_softcap is None,
+            )
         if out is None and mask is not None:
             out = xla_attention(
                 q, k, v, mask=mask, causal=False, scale=scale, logit_softcap=config.attn_logit_softcap
             )
         elif out is None:
-            out = attention(
-                q, k, v,
-                impl=attention_impl,
-                padding_mask=padding_mask,
-                segment_ids=segment_ids,
-                causal=True,
-                sliding_window=plan.window,
-                mesh=mesh,
-                scale=scale,
-                logit_softcap=config.attn_logit_softcap,
-            )
+            out = attention(q, k, v, **asked)
         out = out.reshape(b, s, heads_width)
         if gate is not None:
             with scope("attn_gate"):  # outside the kernel: it multiplies what the kernel wrote
@@ -280,17 +342,24 @@ def _init_linear_attention(keys, config: ModelConfig, dense, dtype):
 _CUT_BY_COLUMN = ("kernel", "lora_b", "bias")
 
 
-def _by_columns(hid, p, cuts, lin):
-    """``lin(hid, p)`` as one array for each run of output columns ``cuts[i] .. cuts[i + 1]``. Where the leaf can be
+def _by_columns(hid, p, cuts, lin, heads: int = 1):
+    """``lin(hid, p)`` as one array for each run of output columns ``cuts[i] .. cuts[i + 1]`` (with ``heads``: of every
+    head's ``cuts[-1]`` columns, a run's heads side by side: ``q_proj``'s ``[q | gate]`` by head). Where the leaf can be
     cut (a plain kernel, with or without LoRA and a bias) it is the LEAF that is cut, a few MB once a call, and each run
     is a product of its own: the runs and their cotangents are separate arrays from birth, and no ``[b, s, .]``
     activation is sliced, nor its cotangent padded and added (PERF.md, PR 39). Any other leaf (a quantized kernel, a
     pool of adapters) makes one product, whose output is cut."""
     runs = list(zip(cuts, cuts[1:]))
+
+    def run_of(x, lo, hi):
+        if heads == 1:
+            return x[..., lo:hi]
+        return x.reshape(*x.shape[:-1], heads, cuts[-1])[..., lo:hi].reshape(*x.shape[:-1], heads * (hi - lo))
+
     if set(p) - {*_CUT_BY_COLUMN, "lora_a", "lora_scale"}:
         y = lin(hid, p)
-        return [y[..., lo:hi] for lo, hi in runs]
-    return [lin(hid, {name: x[..., lo:hi] if name in _CUT_BY_COLUMN else x for name, x in p.items()}) for lo, hi in runs]
+        return [run_of(y, lo, hi) for lo, hi in runs]
+    return [lin(hid, {name: run_of(x, lo, hi) if name in _CUT_BY_COLUMN else x for name, x in p.items()}) for lo, hi in runs]
 
 
 def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, **_):
@@ -335,7 +404,7 @@ def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entr
 # time is read under, its half of init_params, the mixer: normed input ->
 # (output [b, s, hidden], new cache entry))
 _ATTENTION = {
-    "heads": ("self_attn", "attn", _init_heads_attention, _softmax_mixer(_heads_qkv)),
+    "heads": ("self_attn", "attn", _init_heads_attention, _softmax_mixer(_heads_qkv, _heads_qkv_head_major)),
     "latent": ("self_attn", "attn", _init_latent_attention, _softmax_mixer(_latent_qkv)),
     "linear": ("linear_attn", "linear_attn", _init_linear_attention, _linear_mixer),
 }

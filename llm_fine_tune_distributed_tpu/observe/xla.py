@@ -831,6 +831,10 @@ STEP_SCOPES = (
     # the per-head q and k norms, inside "attn"; the norm of a half's OUTPUT in
     # a block of four norms, inside "attn" (or "linear_attn") and inside "mlp"
     "qk_norm", "out_norm",
+    # between a softmax layer's projections and the attention call, inside
+    # "attn": the q/k norms, rope and the hand-over of the layout, as the
+    # fused pass (ops/rope.heads_in) or as the XLA form ("qk_norm" inside it)
+    "attn_in",
 )
 
 

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import collections
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -153,16 +153,17 @@ def xla_attention(
     return out.reshape(b, q_len, num_heads, v_dim).astype(q.dtype)
 
 
-def _seq_parallel_fallback(impl: str, q, mesh) -> str:
-    """Fallback target when a sequence-parallel impl cannot apply.
+def _warn_seq_axis_unused(impl: str, q, mesh) -> None:
+    """A sequence-parallel impl that cannot apply falls back to "flash"
+    (linear memory), which itself degrades to XLA attention only when truly
+    unsupported.
 
     A missing/size-1 seq axis is the ordinary single-device case — fall back
     quietly. A PROVISIONED seq axis with an unsupported shape (e.g. ulysses
     capped by kv heads, or an indivisible seq length) means the user's
     parallelism is silently dead — be loud, because at long-context shapes
     the difference between the flash kernel and quadratic XLA attention is
-    an OOM. Either way prefer "flash" (linear memory), which itself degrades
-    to XLA attention only when truly unsupported."""
+    an OOM."""
     if mesh is not None and mesh.shape.get("seq", 1) > 1:
         import warnings
 
@@ -173,7 +174,101 @@ def _seq_parallel_fallback(impl: str, q, mesh) -> str:
             "kv-head divisibility by the seq axis and seq-length alignment)",
             stacklevel=3,
         )
-    return "flash"
+
+
+class _Route(NamedTuple):
+    """The program a call of ``attention()`` runs, read from its arguments
+    alone by ``_route``: nothing is counted, warned or traced there, so the
+    question can be asked before q, k and v exist (``head_major_reason``)."""
+
+    path: str  # "xla" | "ulysses" | "ring" | "ulysses_manual" | "ring_manual" | "flash"
+    why_not_flash: Optional[str] = None  # path "xla" for a call that asked for more
+    unused_seq_impl: Optional[str] = None  # "ring" | "ulysses" asked for and not applicable
+    shards: Tuple[int, int] = (1, 1)  # path "flash": batch and head shards, each a kernel call under a shard_map
+
+
+def _route(q, k, v, *, impl, mesh, scale, logit_softcap, sliding_window, causal) -> _Route:
+    """``q``, ``k``, ``v``: arrays or shapes ``[b, s, h, d]``. THE chain of
+    questions: ``attention()`` runs what it answers and nothing else decides."""
+    unused = None
+    if scale is not None or logit_softcap is not None:
+        if impl in ("ring_manual", "ulysses_manual"):
+            # inside a shard_map manual over seq, a block-local xla fallback
+            # would silently drop cross-shard attention — refuse instead
+            raise ValueError(
+                f"{impl} does not support custom scale / logit softcap"
+            )
+        # loud when a provisioned seq axis goes unused (same contract as
+        # the shape-based fallback)
+        return _Route(
+            "xla", "a custom scale or logit softcap takes XLA attention", impl if impl in ("ring", "ulysses") else None
+        )
+    if impl == "ulysses":
+        from llm_fine_tune_distributed_tpu.parallel.ulysses import ulysses_attention_supported
+
+        if ulysses_attention_supported(q, k, mesh, sliding_window=sliding_window, causal=causal):
+            return _Route("ulysses")
+        impl, unused = "flash", "ulysses"
+    if impl == "ring":
+        from llm_fine_tune_distributed_tpu.parallel.ring_attention import ring_attention_supported
+
+        if ring_attention_supported(q, k, mesh, sliding_window=sliding_window, causal=causal):
+            return _Route("ring")
+        impl, unused = "flash", "ring"
+    if impl in ("ulysses_manual", "ring_manual"):
+        if sliding_window is not None:
+            raise ValueError(f"{impl.split('_')[0]} attention has no sliding-window support")
+        return _Route(impl)
+    if impl == "flash":
+        # The Pallas kernel runs compiled or the run fails: it is skipped
+        # only for a reason visible here, at trace time, and the reason is
+        # kept for dispatch_summary().
+        from llm_fine_tune_distributed_tpu.ops.flash_attention import flash_unsupported_reason
+
+        # Mosaic kernels cannot be partitioned by GSPMD: on a mesh of more
+        # than one device the kernel runs per shard under a shard_map over
+        # the batch (data, fsdp) and head (tensor) axes, and eligibility is
+        # judged on the per-shard shape.
+        sharded = mesh is not None and mesh.size > 1
+        shards = (mesh.shape["data"] * mesh.shape["fsdp"], mesh.shape["tensor"]) if sharded else (1, 1)
+        if q.shape[0] % shards[0] or k.shape[2] % shards[1]:
+            reason = (
+                f"batch {q.shape[0]} x kv heads {k.shape[2]} do not divide "
+                f"over mesh {dict(mesh.shape)}"
+            )
+        else:
+            reason = flash_unsupported_reason(
+                *(_shard_shape(x, shards) for x in (q, k, v)), sliding_window=sliding_window, causal=causal
+            )
+        return _Route("flash", None, unused, shards) if reason is None else _Route("xla", reason, unused)
+    if impl == "xla":
+        return _Route("xla")
+    raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def _shard_shape(x, shards):
+    return jax.ShapeDtypeStruct((x.shape[0] // shards[0], x.shape[1], x.shape[2] // shards[1], x.shape[3]), x.dtype)
+
+
+def _rows_first(x):
+    """The ``[b, s, h, d]`` shape of a head-major ``[b, h, s, d]`` operand."""
+    return jax.ShapeDtypeStruct((x.shape[0], x.shape[2], x.shape[1], x.shape[3]), x.dtype)
+
+
+def head_major_reason(q, k, v, *, impl, mesh=None, scale=None, logit_softcap=None, sliding_window=None) -> Optional[str]:
+    """Why a causal call of ``attention()`` with these arguments (``q``, ``k``,
+    ``v``: shapes ``[b, s, h, d]``) would NOT run the flash kernels on the
+    whole row in one device's program; None: it would, and the same call
+    may hand its operands head-major (``attention(..., head_major=True)``).
+    ``_route``'s answer, worded."""
+    route = _route(
+        q, k, v, impl=impl, mesh=mesh, scale=scale, logit_softcap=logit_softcap, sliding_window=sliding_window, causal=True
+    )
+    if route.path != "flash":
+        return route.why_not_flash or f"attention_impl is {impl!r}"
+    if route.shards != (1, 1):
+        return f"a mesh of {mesh.size} devices: the kernel runs per shard, inside a shard_map over [b, s, h, d]"
+    return None
 
 
 def attention(
@@ -189,8 +284,10 @@ def attention(
     mesh=None,
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
+    head_major: bool = False,
 ):
-    """Dispatch to the selected attention implementation.
+    """Dispatch to the selected attention implementation (``_route`` decides
+    which, from the arguments alone).
 
     ``mesh`` is consulted by the sequence-parallel paths (ring and ulysses);
     the trainer passes the active mesh whenever ``attention_impl`` is one of
@@ -203,147 +300,88 @@ def attention(
     non-default value routes there directly — the tanh softcap breaks the
     flash kernel's running-max algebra, and correctness beats kernel speed
     for the families that need it.
+
+    ``head_major``: q ``[b, hq, s, d]``, k ``[b, hkv, s, d]`` and v ``[b, hkv,
+    s, d_v]`` arrive as the flash kernels read them (``ops/rope.heads_in``
+    writes them so); the output is ``[b, s, hq, d_v]`` either way. Only for a
+    call ``head_major_reason`` has no objection to: any other raises.
     """
+    asked = dict(impl=impl, mesh=mesh, scale=scale, logit_softcap=logit_softcap, sliding_window=sliding_window, causal=causal)
+    route = _route(*((_rows_first(x) for x in (q, k, v)) if head_major else (q, k, v)), **asked)
+    if head_major and (route.path, route.shards) != ("flash", (1, 1)):
+        raise ValueError(f"head-major operands are for the flash kernels in one device's program; this call takes {route}")
+    if route.unused_seq_impl is not None:
+        _warn_seq_axis_unused(route.unused_seq_impl, q, mesh)
     if scale is not None or logit_softcap is not None:
-        if impl in ("ring_manual", "ulysses_manual"):
-            # inside a shard_map manual over seq, a block-local xla fallback
-            # would silently drop cross-shard attention — refuse instead
-            raise ValueError(
-                f"{impl} does not support custom scale / logit softcap"
-            )
-        if impl in ("ring", "ulysses"):
-            # loud when a provisioned seq axis goes unused (same contract as
-            # the shape-based fallback)
-            impl = _seq_parallel_fallback(impl, q, mesh)
         return xla_attention(
             q, k, v, padding_mask=padding_mask, segment_ids=segment_ids,
             causal=causal, sliding_window=sliding_window,
             scale=scale, logit_softcap=logit_softcap,
         )
-    if impl == "ulysses":
-        from llm_fine_tune_distributed_tpu.parallel.ulysses import (
-            ulysses_attention,
-            ulysses_attention_supported,
-        )
+    if route.path in ("ulysses_manual", "ring_manual") and segment_ids is not None:
+        # the pipeline schedule (the only manual-context caller) rejects
+        # packing up front; reaching here would silently drop the mask
+        raise ValueError(f"{route.path} has no segment support")
+    _DISPATCH_COUNTS[route.path] += 1
+    if route.path == "ulysses":
+        from llm_fine_tune_distributed_tpu.parallel.ulysses import ulysses_attention
 
-        if ulysses_attention_supported(
-            q, k, mesh, sliding_window=sliding_window, causal=causal
-        ):
-            _DISPATCH_COUNTS["ulysses"] += 1
-            return ulysses_attention(
-                q, k, v, mesh=mesh, padding_mask=padding_mask,
-                segment_ids=segment_ids, causal=causal
-            )
-        impl = _seq_parallel_fallback("ulysses", q, mesh)
-    if impl == "ring":
-        from llm_fine_tune_distributed_tpu.parallel.ring_attention import (
-            ring_attention,
-            ring_attention_supported,
+        return ulysses_attention(
+            q, k, v, mesh=mesh, padding_mask=padding_mask,
+            segment_ids=segment_ids, causal=causal
         )
+    if route.path == "ring":
+        from llm_fine_tune_distributed_tpu.parallel.ring_attention import ring_attention
 
-        if ring_attention_supported(
-            q, k, mesh, sliding_window=sliding_window, causal=causal
-        ):
-            _DISPATCH_COUNTS["ring"] += 1
-            return ring_attention(
-                q, k, v, mesh=mesh, padding_mask=padding_mask,
-                segment_ids=segment_ids, causal=causal
-            )
-        impl = _seq_parallel_fallback("ring", q, mesh)
-    if impl == "ulysses_manual":
-        # Same manual-context contract as ring_manual below: the caller is
-        # inside a shard_map manual over "seq", q/k/v are sequence chunks,
-        # and the local kernel's all_to_all/all_gather ride that axis.
-        from llm_fine_tune_distributed_tpu.parallel.ulysses import (
-            _local_ulysses_attention,
+        return ring_attention(
+            q, k, v, mesh=mesh, padding_mask=padding_mask,
+            segment_ids=segment_ids, causal=causal
         )
-
-        if sliding_window is not None:
-            raise ValueError("ulysses attention has no sliding-window support")
-        if segment_ids is not None:
-            # the pipeline schedule (the only manual-context caller) rejects
-            # packing up front; reaching here would silently drop the mask
-            raise ValueError("ulysses_manual has no segment support")
-        _DISPATCH_COUNTS["ulysses_manual"] += 1
-        return _local_ulysses_attention(
-            q, k, v, padding_mask,
-            axis_name="seq", causal=causal, attention_impl="flash",
-        )
-    if impl == "ring_manual":
+    if route.path in ("ulysses_manual", "ring_manual"):
         # The caller is ALREADY inside a shard_map that is manual over the
         # "seq" axis (the pipeline schedule, pipe x ring composition):
         # q/k/v here are one device's sequence CHUNKS, so dispatch straight
-        # to the local ring kernel — wrapping the global-view entry would
-        # illegally nest a manual "seq" shard_map.
-        from llm_fine_tune_distributed_tpu.parallel.ring_attention import (
-            _local_ring_attention,
-        )
+        # to the local kernel (its all_to_all/all_gather ride that axis) —
+        # wrapping the global-view entry would illegally nest a manual "seq"
+        # shard_map.
+        if route.path == "ulysses_manual":
+            from llm_fine_tune_distributed_tpu.parallel.ulysses import _local_ulysses_attention
 
-        if sliding_window is not None:
-            raise ValueError("ring attention has no sliding-window support")
-        if segment_ids is not None:
-            raise ValueError("ring_manual has no segment support")
-        _DISPATCH_COUNTS["ring_manual"] += 1
+            return _local_ulysses_attention(
+                q, k, v, padding_mask,
+                axis_name="seq", causal=causal, attention_impl="flash",
+            )
+        from llm_fine_tune_distributed_tpu.parallel.ring_attention import _local_ring_attention
+
         return _local_ring_attention(
             q, k, v, padding_mask,
             axis_name="seq", axis_size=mesh.shape["seq"], causal=causal,
         )
-    if impl == "flash":
-        # The Pallas kernel runs compiled or the run fails: it is skipped
-        # only for a reason visible here, at trace time, and the reason is
-        # kept for dispatch_summary().
-        from llm_fine_tune_distributed_tpu.ops.flash_attention import (
-            flash_unsupported_reason,
-            pallas_flash_attention,
-            program_label,
+    if route.path == "flash":
+        from llm_fine_tune_distributed_tpu.ops.flash_attention import pallas_flash_attention, program_label
+
+        seen = [_shard_shape(_rows_first(x) if head_major else x, route.shards) for x in (q, k, v)]
+        _FLASH_INPUT_DTYPES.add(jnp.dtype(q.dtype).name)
+        _FLASH_PROGRAMS[program_label(*seen, sliding_window=sliding_window)] += 1
+        if route.shards == (1, 1):
+            return pallas_flash_attention(
+                q, k, v, padding_mask=padding_mask, segment_ids=segment_ids, sliding_window=sliding_window,
+                head_major=head_major,
+            )
+        from llm_fine_tune_distributed_tpu.parallel.ring_attention import (
+            shard_map_seq_attention,
         )
 
-        # Mosaic kernels cannot be partitioned by GSPMD: on a mesh of more
-        # than one device the kernel runs per shard under a shard_map over
-        # the batch (data, fsdp) and head (tensor) axes, and eligibility is
-        # judged on the per-shard shape.
-        sharded = mesh is not None and mesh.size > 1
-        batch_n = mesh.shape["data"] * mesh.shape["fsdp"] if sharded else 1
-        head_n = mesh.shape["tensor"] if sharded else 1
-        if q.shape[0] % batch_n or k.shape[2] % head_n:
-            reason = (
-                f"batch {q.shape[0]} x kv heads {k.shape[2]} do not divide "
-                f"over mesh {dict(mesh.shape)}"
-            )
-        else:
-            local = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
-                (x.shape[0] // batch_n, x.shape[1], x.shape[2] // head_n, x.shape[3]),
-                x.dtype,
-            )
-            reason = flash_unsupported_reason(
-                local(q), local(k), local(v),
-                sliding_window=sliding_window, causal=causal,
-            )
-        if reason is None:
-            _DISPATCH_COUNTS["flash"] += 1
-            _FLASH_INPUT_DTYPES.add(jnp.dtype(q.dtype).name)
-            _FLASH_PROGRAMS[program_label(local(q), local(k), local(v), sliding_window=sliding_window)] += 1
-            if not sharded:
-                return pallas_flash_attention(
-                    q, k, v, padding_mask=padding_mask, segment_ids=segment_ids, sliding_window=sliding_window
-                )
-            from llm_fine_tune_distributed_tpu.parallel.ring_attention import (
-                shard_map_seq_attention,
-            )
-
-            return shard_map_seq_attention(
-                lambda q_, k_, v_, p_, s_: pallas_flash_attention(
-                    q_, k_, v_, padding_mask=p_, segment_ids=s_, sliding_window=sliding_window
-                ),
-                mesh, None, q, k, v,
-                padding_mask=padding_mask, segment_ids=segment_ids,
-            )
-        _FLASH_FALLBACK_REASONS[reason] += 1
-        impl = "xla"
-    if impl == "xla":
-        _DISPATCH_COUNTS["xla"] += 1
-        return xla_attention(
-            q, k, v, padding_mask=padding_mask, segment_ids=segment_ids,
-            causal=causal, sliding_window=sliding_window,
+        return shard_map_seq_attention(
+            lambda q_, k_, v_, p_, s_: pallas_flash_attention(
+                q_, k_, v_, padding_mask=p_, segment_ids=s_, sliding_window=sliding_window
+            ),
+            mesh, None, q, k, v,
+            padding_mask=padding_mask, segment_ids=segment_ids,
         )
-    raise ValueError(f"unknown attention impl {impl!r}")
+    if route.why_not_flash is not None:
+        _FLASH_FALLBACK_REASONS[route.why_not_flash] += 1
+    return xla_attention(
+        q, k, v, padding_mask=padding_mask, segment_ids=segment_ids,
+        causal=causal, sliding_window=sliding_window,
+    )

@@ -71,6 +71,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# (the cap under this module's own name: tests and benchmarks/chipbench/tools lower it HERE to force the streamed kernels)
+from llm_fine_tune_distributed_tpu.ops.tiling import VMEM_CAP_BYTES as _VMEM_CAP_BYTES, lanes, tiled_bytes
+
 _NEG_INF = -1.0e30
 
 # In the resident kernels the whole K/V (or a kv head's whole query group)
@@ -80,21 +83,8 @@ _NEG_INF = -1.0e30
 # the same kernel (the compiler's process-wide default is 16 MiB, and the
 # backward at seq 4096 needs more). A call asks for its pipelined blocks,
 # double-buffered at their tiled size, plus room for the body's [BQ, BK] f32
-# temporaries; nothing may ask for more than the cap, which leaves the rest
-# of a v5e core's 128 MiB to XLA's own fusions around the kernel.
+# temporaries; nothing may ask for more than the cap (``ops/tiling.py``).
 _VMEM_BODY_BYTES = 16 * 1024 * 1024
-_VMEM_CAP_BYTES = 100 * 1024 * 1024
-
-
-def _tiled_bytes(shape, dtype) -> int:
-    """Bytes of one VMEM buffer of ``shape``: the last dim pads to 128 lanes
-    and the one before to the dtype's sublane tile (8 rows of 32 bits)."""
-    itemsize = np.dtype(dtype).itemsize
-    *lead, rows, lanes = shape
-    sublanes = 8 * (4 // itemsize)
-    rows = -(-rows // sublanes) * sublanes
-    lanes = -(-lanes // 128) * 128
-    return int(np.prod(lead, dtype=np.int64)) * rows * lanes * itemsize
 
 
 def _vmem_budget(operands, d: int = 128) -> int:
@@ -105,7 +95,7 @@ def _vmem_budget(operands, d: int = 128) -> int:
     float32 copies of its blocks and its accumulators grow with it, so heads
     of two lane registers (latent attention's 192, padded to 256) get twice
     the body's room."""
-    return 2 * sum(_tiled_bytes(s, t) for s, t, _ in operands) + _VMEM_BODY_BYTES * max(1, _lanes(d) // 128)
+    return 2 * sum(tiled_bytes(s, t) for s, t, _ in operands) + _VMEM_BODY_BYTES * max(1, lanes(d) // 128)
 
 
 def _compiler_params(operands, d: int = 128):
@@ -853,7 +843,7 @@ def _stream_call(kernel, body, band: _Band, q, k, v, groups, out_shape, interpre
     grid = (b, hkv, band.blocks, groups, band.steps) if kernel == "dkv" else (b, hq, band.blocks, band.steps)
     name = _stream_name(kernel, band)
     GRID_TILES[name, band] = (band.tiles, band.blocks * (band.blocks + 1) // 2)
-    budget = _vmem_budget(ins + outs, d) + sum(_tiled_bytes(shape, t) for shape, t in scratch)
+    budget = _vmem_budget(ins + outs, d) + sum(tiled_bytes(shape, t) for shape, t in scratch)
     return pl.pallas_call(
         functools.partial(body, band=band, **static),
         grid=grid,
@@ -1015,11 +1005,6 @@ def _make_flash_fn(scale: float, block: int, groups: int, interpret: bool, windo
 _ONE_BLOCK_SEQ = 2048
 
 
-def _lanes(d: int) -> int:
-    """``d`` rounded up to whole 128-lane registers."""
-    return -(-d // 128) * 128
-
-
 def _pick_block(s: int) -> int:
     import os
 
@@ -1097,7 +1082,7 @@ def flash_unsupported_reason(
     # length: their need grows with the block and the head widths alone.
     if _streamed(q.dtype, sq, d, d_v, block, hq // hkv, sliding_window):
         ins, outs, scratch = _stream_operands("dkv", q.dtype, d, d_v, _band(sq, block, sliding_window), hq // hkv)
-        need = _vmem_budget(ins + outs, d) + sum(_tiled_bytes(shape, t) for shape, t in scratch)
+        need = _vmem_budget(ins + outs, d) + sum(tiled_bytes(shape, t) for shape, t in scratch)
         if need > _VMEM_CAP_BYTES:
             return (
                 f"streamed backward needs {need >> 20} MiB of VMEM at blocks of {block} x {d}, over the "
@@ -1287,10 +1272,18 @@ def paged_decode_attention(
 
 
 def pallas_flash_attention(
-    q, k, v, *, padding_mask=None, segment_ids=None, sliding_window=None, interpret: bool = False
+    q, k, v, *, padding_mask=None, segment_ids=None, sliding_window=None, interpret: bool = False,
+    head_major: bool = False,
 ):
     """q [b, sq, hq, d], k [b, sk, hkv, d], v [b, sk, hkv, d_v] ->
     [b, sq, hq, d_v] (q.dtype). The softmax scale is ``d ** -0.5``.
+
+    ``head_major``: q, k and v arrive as the kernels read them, ``[b, hq, sq,
+    d]``, ``[b, hkv, sk, d]``, ``[b, hkv, sk, d_v]`` (``ops/rope.heads_in``
+    writes them so), and the three transposes on the way in are not made, nor
+    their three on the way back for dq, dk, dv; the output is ``[b, sq, hq,
+    d_v]`` either way. Every other caller keeps the ``[b, s, h, d]`` contract
+    of ``ops.attention.attention``.
 
     q/k heads may be wider than v heads and need not fill whole lane
     registers (latent attention: 128 + 64 rope dimensions against v heads of
@@ -1307,9 +1300,8 @@ def pallas_flash_attention(
     Softmax in f32; causal; with ``sliding_window`` a query sees the
     ``sliding_window`` keys up to and including its own.
     """
-    b, sq, hq, d = q.shape
-    hkv = k.shape[2]
-    groups = hq // hkv
+    b, hq, sq, d = q.shape if head_major else (q.shape[0], q.shape[2], q.shape[1], q.shape[3])
+    groups = hq // k.shape[1 if head_major else 2]
     if segment_ids is not None:
         segments = segment_ids.astype(jnp.int32)
     elif padding_mask is not None:
@@ -1327,9 +1319,6 @@ def pallas_flash_attention(
         fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, groups, interpret, sliding_window, True)
     else:
         fn = _make_flash_fn(float(1.0 / np.sqrt(d)), block, groups, interpret)
-    # head-major layout for clean blocking
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out = fn(qt, kt, vt, segments)
-    return out.transpose(0, 2, 1, 3)
+    if not head_major:  # head-major layout for clean blocking
+        q, k, v = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    return fn(q, k, v, segments).transpose(0, 2, 1, 3)

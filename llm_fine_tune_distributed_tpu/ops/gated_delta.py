@@ -109,6 +109,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_fine_tune_distributed_tpu.ops.tiling import by_eights
+
 # Tokens a chunk. HF's torch fallback and the flash-linear-attention kernels
 # use 64. (What 128 costs on a v5e: PERF.md, PR 32.)
 CHUNK = 64
@@ -810,11 +812,6 @@ def _sigmoid(x):
     return jax.nn.sigmoid(x)
 
 
-def _by_eights(x):
-    """``[rows, width]`` -> ``[8, width]``: rows 8 apart added (whole tiles; the caller adds the 8 sublanes up)."""
-    return x.reshape(x.shape[0] // 8, 8, x.shape[1]).sum(axis=0)
-
-
 def _conv_silu(ext, w_ref):
     """A trip's convolution: ``ext`` its rows under the 8 before them. ``(the shifted rows a tap, c, sigmoid(c))``."""
     taps = w_ref.shape[0]
@@ -887,7 +884,7 @@ def _in_part_back(x_ref, halo_ref, w_ref, dy_ref, dx_ref, dw_ref, after_ref, *, 
         back = jnp.concatenate([dc, after], axis=0)
         dx = sum(w_ref[pl.ds(j, 1), :].astype(_F32) * _shifted(back, taps - 1 - j, ROWS) for j in range(taps))
         dx_ref[0, rows, :] = dx.astype(dx_ref.dtype)
-        return (dc[:8],) + tuple(a_j + _by_eights(x_j * dc) for a_j, x_j in zip(acc, shifted))
+        return (dc[:8],) + tuple(a_j + by_eights(x_j * dc) for a_j, x_j in zip(acc, shifted))
 
     zeros = jnp.zeros((8, x_ref.shape[2]), _F32)
     out = jax.lax.fori_loop(0, trips, trip, (after_ref[...],) + (zeros,) * taps)
@@ -1001,7 +998,7 @@ def _gate_norm_back_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, 
         dn = dy * w * gate
         do_ref[0, rows, :] = (r * (dn - normed * jnp.mean(dn * normed, axis=1, keepdims=True))).astype(do_ref.dtype)
         dz_ref[0, rows, :] = (dy * normed * w * sig * (1.0 + z * (1.0 - sig))).astype(dz_ref.dtype)
-        return acc + _by_eights(dy * gate * normed)
+        return acc + by_eights(dy * gate * normed)
 
     dw_ref[...] += jax.lax.fori_loop(0, o_ref.shape[1] // ROWS, trip, jnp.zeros(dw_ref.shape, _F32))
 

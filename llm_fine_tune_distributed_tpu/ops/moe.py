@@ -419,11 +419,11 @@ def sum_kernel_refused(rows, rank, ends):
         return f"hidden size {h} is not a multiple of {_LANES}"
     if c % _block_rows(rows.dtype):
         return f"a chunk of {c} rows is not whole blocks of {_block_rows(rows.dtype)}"
-    from llm_fine_tune_distributed_tpu.ops.flash_attention import _VMEM_CAP_BYTES  # one cap for every kernel of the chip
+    from llm_fine_tune_distributed_tpu.ops.tiling import VMEM_CAP_BYTES  # one cap for every kernel of the chip
 
     need = _sum_vmem_bytes(h, k, ends.shape[0], rows.dtype)
-    if need > _VMEM_CAP_BYTES:
-        return f"needs {need >> 20} MiB of VMEM, a kernel may ask for {_VMEM_CAP_BYTES >> 20}"
+    if need > VMEM_CAP_BYTES:
+        return f"needs {need >> 20} MiB of VMEM, a kernel may ask for {VMEM_CAP_BYTES >> 20}"
     return None
 
 

@@ -2,7 +2,7 @@
 
 The sequence-parallel attention impls ("ring", "ulysses") fall back to
 flash/XLA attention when their shape preconditions fail
-(ops/attention._seq_parallel_fallback). The fallback warns when a provisioned
+(ops/attention._route; _warn_seq_axis_unused). The fallback warns when a provisioned
 seq axis goes unused, but a warning is easy to miss — an earlier review found a
 "ulysses parity test" whose mesh violated the batch-divisibility precondition,
 so it silently tested the fallback and passed anyway. ``assert_seq_parallel``

@@ -1211,6 +1211,7 @@ class SFTTrainer:
                             # path it holds (a flash request that took XLA
                             # attention names its reason) and on what it runs
                             from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
+                            from llm_fine_tune_distributed_tpu.ops import rope as rope_ops
                             from llm_fine_tune_distributed_tpu.ops.attention import (
                                 dispatch_summary,
                             )
@@ -1224,6 +1225,8 @@ class SFTTrainer:
                                 print(f"[train] {moe.sum_programs_summary()}", flush=True)
                             if gated_delta.CALLS:  # linear-attention layers: the form their rule took
                                 print(f"[train] {gated_delta.calls_summary()}", flush=True)
+                            if rope_ops.CALLS:  # layers of heads: the fused IN pass or the XLA form, and why
+                                print(f"[train] {rope_ops.calls_summary()}", flush=True)
                     pending_samples += samples_per_step
                     # real-token accounting for the throughput meter: a host
                     # numpy mean over the loader's (pre-device) mask — cheap

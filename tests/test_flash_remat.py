@@ -24,6 +24,7 @@ from llm_fine_tune_distributed_tpu.models.transformer import (
     keeps_flash_outputs,
 )
 from llm_fine_tune_distributed_tpu.ops import flash_attention as fa
+from llm_fine_tune_distributed_tpu.ops import rope
 
 LAYERS, SEQ = 2, 128
 
@@ -49,6 +50,8 @@ def interpreted_kernel(monkeypatch):
     monkeypatch.setattr(
         fa, "pallas_flash_attention", partial(fa.pallas_flash_attention, interpret=True)
     )
+    # a layer of heads hands the kernel its operands through the fused IN pass there (ops/rope.heads_in, PR 41)
+    monkeypatch.setattr(rope, "heads_in", partial(rope.heads_in, interpret=True))
 
 
 def _kernel_calls(jaxpr, name: str) -> int:

@@ -197,12 +197,14 @@ def test_the_gated_four_norm_blocks_scopes():
     have neither)."""
     paths = _scoped_forward("tiny_trinity")
     for layer in (0, 3):  # the dense layer behind a window, an expert layer behind the global mixer
-        for inside in ("attn/qk_norm", "attn/attn_gate", "attn/out_norm", "mlp/out_norm"):
+        # (the q/k norms inside attn_in: everything between the projections and the attention call, PR 41)
+        for inside in ("attn/attn_in/qk_norm", "attn/attn_gate", "attn/out_norm", "mlp/out_norm"):
             assert any(p.startswith(f"layer{layer}/{inside}") for p in paths), (layer, inside)
     gemma = _scoped_forward("tiny_gemma2")
     assert any(p.startswith("layer0/attn/out_norm") for p in gemma) and any(p.startswith("layer1/mlp/out_norm") for p in gemma)
     assert not any("qk_norm" in p or "attn_gate" in p for p in gemma)
     qwen = _scoped_forward("tiny_qwen3_next")
-    assert any(p.startswith("layer3/attn/qk_norm") for p in qwen) and any(p.startswith("layer3/attn/attn_gate") for p in qwen)
+    assert any(p.startswith("layer3/attn/attn_in/qk_norm") for p in qwen) and any(p.startswith("layer3/attn/attn_gate") for p in qwen)
     assert not any("out_norm" in p for p in qwen) and not any(p.startswith("layer0/") and "qk_norm" in p for p in qwen)
-    assert not any("qk_norm" in p or "out_norm" in p for p in _scoped_forward("tiny"))
+    tiny = _scoped_forward("tiny")
+    assert not any("qk_norm" in p or "out_norm" in p for p in tiny) and any(p.startswith("layer0/attn/attn_in") for p in tiny)

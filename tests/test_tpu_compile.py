@@ -38,6 +38,20 @@ RULE_MODULE_BYTES = 77_064
 # The mixer's two elementwise passes around the rule, the same way (PR 39; budget: 40 KB together).
 MIXER_PROGRAMS = {"gdn_in_fwd": 1, "gdn_in_bwd": 1, "gdn_out_fwd": 1, "gdn_out_bwd": 1}
 MIXER_MODULE_BYTES = 35_816
+# The softmax mixer's IN pass (PR 41: q/k norms, rope and the head-major layout, ops/rope.heads_in): one forward and one
+# backward program a MODEL (a layer without rope runs the program of the layers with), and a budget for their serialized
+# modules' bytes together, by cell at a microbatch's shape: (rows, seq, q heads, kv heads, head, table width, norm) ->
+# bytes. A module carries its operations' call stacks, up to ten frames each, and a bare call like this test's is
+# shallower than that: pytest's own frames get in and the same two kernels read 20.6 KB in a bare process and 25.8 KB
+# under six of the suite's workers. So the text is counted with the stacks left out (``_without_call_stacks``: the
+# innermost frame alone, the same under any runner) and held at the landed count plus a fifth, as the rule's is.
+IN_PASS_SHAPES = {
+    "trinity": ((2, 8192, 32, 4, 128, 128, True), 28_780),
+    "mellum": ((4, 8192, 32, 4, 128, 128, False), 18_408),
+    "smollm3": ((2, 1024, 16, 4, 128, 128, False), 15_740),
+    "qwen3-next": ((2, 8192, 16, 2, 256, 64, True), 31_952),
+}
+IN_PASS_PROGRAMS = {"attn_in_fwd": 1, "attn_in_bwd": 1}
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +85,12 @@ def production_matmul_precision():
     the default, and that is the kernel these tests must compile."""
     with jax.default_matmul_precision("default"):
         yield
+
+
+def _without_call_stacks():
+    """While it lasts, a lowered operation's location is its innermost frame alone: a Mosaic module's text then does
+    not depend on how deep the caller stood (a bare test under a runner's frames reads kilobytes more)."""
+    return jax._src.config.include_full_tracebacks_in_locations(False)
 
 
 def _compile(fn, sharding, *shapes):
@@ -287,6 +307,36 @@ def test_step_with_window_and_global_layers_and_softmax_experts_compiles_for_v5e
     assert not again, again
 
 
+@pytest.mark.parametrize("cell", list(IN_PASS_SHAPES))
+def test_in_pass_compiles_for_v5e_and_its_text_is_held(one_chip, cell):
+    """The IN pass's two kernels at a microbatch of each claimed cell (Trinity: gate and q/k norms; Mellum: neither),
+    of SmolLM3's (rows of 1024: one token block) and of Qwen3-Next's full layer (heads of two registers, a table of 64
+    lanes: two rolls and a select), forward and backward through the ``custom_vjp``: Mosaic takes the narrow column
+    blocks of the flat projections, the head-major output blocks, the lane rolls and the flag in SMEM. One program
+    each way, and their text (what every start of a process traces and lowers again, warm cache or not) inside its
+    budget."""
+    from llm_fine_tune_distributed_tpu.ops import rope
+
+    (b, s, heads, kv, d, width, norm), landed = IN_PASS_SHAPES[cell]
+    shapes = [((b, s, heads * d), jnp.bfloat16), ((b, s, kv * d), jnp.bfloat16), ((b, s, kv * d), jnp.bfloat16),
+              ((b, s, width), jnp.float32), ((b, s, width), jnp.float32)] + [((d,), jnp.float32)] * (2 * norm)
+
+    def grads(xq, xk, xv, cos, sin, *w):
+        def loss(xq, xk, xv, *w):
+            out = rope.heads_in(xq, xk, xv, cos, sin, heads=heads, kv_heads=kv,
+                                **(dict(q_weight=w[0], k_weight=w[1]) if w else {}))
+            return sum(jnp.sum(o.astype(jnp.float32) ** 2) for o in out)
+
+        return jax.grad(loss, argnums=tuple(range(3 + len(w))))(xq, xk, xv, *w)
+
+    with _without_call_stacks():
+        lowered = jax.jit(grads).lower(*(jax.ShapeDtypeStruct(shape, t, sharding=one_chip) for shape, t in shapes))
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    programs = mosaic_programs(lowered.as_text())
+    assert {name: x["programs"] for name, x in programs.items()} == IN_PASS_PROGRAMS, programs
+    assert sum(x["bytes"] for x in programs.values()) <= 1.2 * landed, programs
+
+
 def test_step_with_gated_window_and_global_layers_compiles_for_v5e(topo, monkeypatch):
     """The leading dense layer, one window layer and the global layer of
     Trinity-Mini (``afmoe``) at its published widths (this chip's share: 16 of
@@ -298,7 +348,11 @@ def test_step_with_gated_window_and_global_layers_compiles_for_v5e(topo, monkeyp
     3584 keys' worth against the hidden 2048); the post-norm sits on the expert
     layers' output; the grouped products, the sums of rows into tokens and the
     kept routing are in the step; and the block's three scopes are on its
-    operations."""
+    operations. Between the projections and the flash kernels stands the IN
+    pass (PR 41): ``attn_in_fwd`` in each layer's forward and recomputed pass,
+    ``attn_in_bwd`` once, ONE program each for the window layers with rope and
+    the global layer without, and the flash kernels read q, k and v as the
+    pass wrote them: no transpose, no copy, no fusion between."""
     from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -310,7 +364,8 @@ def test_step_with_gated_window_and_global_layers_compiles_for_v5e(topo, monkeyp
                              layer_types=("sliding_attention", "sliding_attention", "full_attention"),
                              no_rope_layers=(1, 1, 0)),
     )
-    text = setup.compile().as_text()
+    lowered = setup.lower()
+    text = lowered.compile().as_text()
     mosaic_calls = lambda kernel: sum(  # noqa: E731
         "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
     )
@@ -332,9 +387,29 @@ def test_step_with_gated_window_and_global_layers_compiles_for_v5e(topo, monkeyp
     again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
     assert not again, again
     names = re.findall(r'op_name="([^"]+)"', text)
-    for inside in ("attn/attn_gate", "attn/qk_norm", "attn/out_norm", "mlp/out_norm"):
+    for inside in ("attn/attn_gate", "attn/attn_in", "attn/out_norm", "mlp/out_norm"):
         assert any(f"/{inside}/" in name for name in names), inside
+    assert not any("/qk_norm/" in name for name in names)  # the norms are inside the pass: the scope is the XLA form's
     assert any("layer2" in name and "mlp/out_norm" in name for name in names)  # the post-norm of an EXPERT layer
+    # the IN pass: forward kernel in the forward and the recomputed pass, backward kernel once, every layer
+    passes = sorted(re.findall(
+        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?attn/attn_in/'
+        r'jit\((attn_in_\w+)\)/\3/pallas_call"', "\n".join(line for line in text.splitlines() if "tpu_custom_call" in line)))
+    assert passes == sorted(found for i in range(3) for found in (
+        (f"jvp(layer{i})", "", "attn_in_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", "attn_in_fwd"),
+        (f"transpose(jvp(layer{i}))", "", "attn_in_bwd"))), passes
+    programs = {name: x for name, x in mosaic_programs(lowered.as_text()).items() if name.startswith("attn_in")}
+    assert {name: x["programs"] for name, x in programs.items()} == IN_PASS_PROGRAMS, programs  # (rope or none: data)
+    # (30,048 B landed, plus a fifth; a whole step stands deeper than the ten frames a location keeps: the same anywhere)
+    assert sum(x["bytes"] for x in programs.values()) <= 36_000, programs
+    # what the forward flash kernels read as q, k, v IS what the pass wrote: get-tuple-elements of its call
+    defined = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = \S+ ([\w\-]+)\(", text, flags=re.M))
+    reads = re.findall(r"= \S+ \S+ custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\".*?"
+                       r'op_name="[^"]*/flash_attention_(?:window|causal)_fwd/pallas_call"', text)
+    assert len(reads) == 3
+    for operands in reads:
+        q_k_v = [name.split("*/")[-1].strip() for name in operands.split(",")][-3:]
+        assert all(name.startswith("%jit_attn_in_fwd_") and defined[name] == "get-tuple-element" for name in q_k_v), q_k_v
 
 
 def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
@@ -417,8 +492,13 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
             whole.append((found.group(2), found.group(3).rsplit("/", 1)[1]))
     assert whole and {opcode for opcode, _ in whole} <= {"custom-call", "get-tuple-element", "fusion", "bitcast"}, set(whole)
     assert {last for opcode, last in whole if opcode == "fusion"} <= {"dot_general", "add_any"}, set(whole)
-    # under the landed count (13.47 GiB; the parent's 14.15 held the XLA form's float32 copies and padded cotangents)
+    # under the landed count (13.47 GiB at PR 39; the parent's 14.15 held the XLA form's float32 copies and padded
+    # cotangents; PR 41's IN pass leaves it where it was: the full layer's gate is a product of its own)
     assert compiled.memory_analysis().peak_memory_in_bytes <= 13.55 * 2**30
+    # the full layer's IN pass (PR 41): forward kernel in the forward and the recomputed pass, backward kernel once
+    in_pass = mosaic_programs(lowered.as_text())
+    assert {name: in_pass[name]["programs"] for name in IN_PASS_PROGRAMS} == IN_PASS_PROGRAMS
+    assert (mosaic_calls("attn_in_fwd"), mosaic_calls("attn_in_bwd")) == (2, 1)
     # what a start of the process pays for the rule again, warm cache or not: the text of its kernels, traced and
     # lowered before the cache is even asked (PERF.md, PR 37, step 0: the step's lower() follows the serialized
     # modules' bytes, .compile() on a hit does not move). Held at the landed value plus a fifth.
@@ -451,6 +531,18 @@ def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
     pad = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=rows)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args, pad).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+    # the softmax mixer's IN pass (ops/rope.heads_in) hands the kernels head-major operands in ONE device's program:
+    # under this mesh the kernel runs per shard inside a shard_map over [b, s, h, d] and the pass runs not at all
+    from llm_fine_tune_distributed_tpu.models import transformer
+    from llm_fine_tune_distributed_tpu.models.configs import get_preset
+
+    config = get_preset("smollm3_3b")
+    hid = jax.ShapeDtypeStruct((8, 1024, config.hidden_size), jnp.bfloat16)
+    cos = jax.ShapeDtypeStruct((8, 1024, 128), jnp.float32)
+    asked = dict(attention_impl="flash", scale=None, mask=None, cache_entry=None)
+    why = transformer._why_not_head_major(hid, cos, config, config.layer(0), mesh=mesh, **asked)
+    assert why.startswith("a mesh of 4 devices"), why
+    assert transformer._why_not_head_major(hid, cos, config, config.layer(0), mesh=None, **asked) is None
 
 
 def test_fsdp4_step_keeps_plain_all_gathers_on_a_ring_ordered_mesh(topo, monkeypatch):
@@ -480,6 +572,7 @@ def test_fsdp4_step_keeps_plain_all_gathers_on_a_ring_ordered_mesh(topo, monkeyp
     compiled = setup.compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "the flash kernel is not in the step"
+    assert "attn_in_fwd" not in text  # (the IN pass is one device's: under this mesh the XLA form stands)
     gathers = len(re.findall(r"= .*\ball-gather(-start)?\(", text))
     permutes = len(re.findall(r"= .*\bcollective-permute(-start)?\(", text))
     assert gathers > 100 and permutes < 20, (gathers, permutes)
