@@ -36,6 +36,7 @@ _TRINITY = ("trinity_mini", dict(
     num_layers=5, first_k_dense_replace=1, vocab_size=25024, held_experts=tuple(range(16)),
     layer_types=("sliding_attention",) * 3 + ("full_attention", "sliding_attention"), no_rope_layers=(1, 1, 1, 0, 1),
 ))
+_KIMI = ("kimi_linear_48b_a3b", dict(num_layers=5, vocab_size=20480, held_experts=tuple(range(8))))
 # name -> (preset, model overrides, rows, accumulation, sequence, recipe)
 STEPS = {
     # the cells of BENCHMARK.json, as their traffic files state them
@@ -56,6 +57,9 @@ STEPS = {
     # the same at 1 row and at 4 (2 x 2 if the compiler's count stays under 15.0 GiB, else 1 x 4: ISSUE 40)
     "trinity-mini-26b-a3b-ep8-d5.8k-1row": (*_TRINITY, 1, 4, 8192, _ALL),
     "trinity-mini-26b-a3b-ep8-d5.8k-4rows": (*_TRINITY, 4, 1, 8192, _ALL),
+    "kimi-linear-48b-a3b-ep32-d5.sft-8k-kda-mla-allparams": (*_KIMI, 2, 2, 8192, _ALL),
+    # the same at 1 row (2 x 2 if the compiler's count stays under 15.0 GiB, else 1 x 4: ISSUE 42)
+    "kimi-linear-48b-a3b-ep32-d5.8k-1row": (*_KIMI, 1, 4, 8192, _ALL),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),

@@ -24,7 +24,8 @@ class LayerPlan:
     config: exactly what the model code branches on."""
 
     # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent) |
-    # "linear" (Gated DeltaNet: a recurrence over time, no softmax, no rope, no window)
+    # "linear" (Gated DeltaNet: a recurrence over time, no softmax, no rope, no window) |
+    # "kda" (Kimi Delta Attention: that recurrence with a decay a channel behind low-rank gates)
     attention: str
     rope: bool  # rotary embedding on this layer's q and k (SmolLM3's NoPE layers: False)
     rope_kind: str  # which of the forward's cos/sin tables: "plain" | "scaled" (the config's context extension)
@@ -44,8 +45,10 @@ class ModelConfig:
     SmolLM3 (NoPE-interleaved RoPE: ``no_rope_layers[i] == 0`` means layer *i*
     applies no rotary embedding, as HF ``SmolLM3Config.no_rope_layers``),
     Gemma2 (four norms a block), Mixtral, DeepSeek-V3 / Moonlight, Mellum,
-    Qwen3-Next, and ``afmoe`` (Trinity: gated window layers with rope beside
-    gated global layers without, four norms a block around routed experts).
+    Qwen3-Next, ``afmoe`` (Trinity: gated window layers with rope beside
+    gated global layers without, four norms a block around routed experts),
+    and ``kimi_linear`` (Kimi Delta Attention layers beside latent attention
+    without rope).
     """
 
     name: str = "unnamed"
@@ -129,6 +132,14 @@ class ModelConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
+    # --- Kimi Linear (moonshotai ``kimi_linear``) ---
+    # Both > 0 make a "linear_attention" layer a Kimi Delta Attention mixer: q, k, v
+    # from projections and convolutions of their own, the decay a VECTOR over a
+    # head's d_k channels, ``-exp(A_log[head]) softplus((x W_fa) W_fb + dt_bias)``
+    # through a pair of matrices of this rank, and the norm after the rule gated by
+    # ``sigmoid((x W_ga) W_gb)`` through another pair (as many key heads as value heads).
+    linear_decay_rank: int = 0
+    linear_gate_rank: int = 0
     # The share of a head's dimensions that rope rotates, from dimension 0
     # (rotate-half inside them); the rest pass unrotated. 1.0 = the whole head.
     partial_rotary_factor: float = 1.0
@@ -153,11 +164,15 @@ class ModelConfig:
     # compete for capacity within their chunk only.
     moe_dispatch_chunk: int = 1024
     # --- Latent attention (MLA, HF DeepseekV3Attention; q_lora_rank null) ---
-    # kv_lora_rank > 0 switches every layer's attention: k and v come from one
+    # kv_lora_rank > 0 switches every softmax layer's attention (a model's
+    # "linear_attention" layers stay what they are): k and v come from one
     # low-rank latent of this width (normed) plus ONE rope key of
     # qk_rope_head_dim shared by all heads; q/k heads are qk_nope_head_dim +
     # qk_rope_head_dim wide, v heads v_head_dim. head_dim is unused then.
+    # mla_use_nope (Kimi Linear): no rotation at all, the qk_rope_head_dim
+    # columns of q and of the shared key take part in the scores as they come.
     kv_lora_rank: int = 0
+    mla_use_nope: bool = False
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
@@ -212,6 +227,12 @@ class ModelConfig:
                     raise ValueError("layer_types has linear_attention layers: set the linear_* fields")
                 if hv % hk:
                     raise ValueError(f"linear_num_value_heads={hv} must be a multiple of linear_num_key_heads={hk}")
+                if bool(self.linear_decay_rank) != bool(self.linear_gate_rank) or (self.linear_decay_rank and hv != hk):
+                    raise ValueError(
+                        "linear_decay_rank and linear_gate_rank go together (a Kimi Delta Attention mixer), with "
+                        f"as many key heads as value heads (got ranks {self.linear_decay_rank}, "
+                        f"{self.linear_gate_rank}, heads {hk} and {hv})"
+                    )
         rotary = self.resolved_head_dim * self.partial_rotary_factor
         if not 0 < self.partial_rotary_factor <= 1 or rotary != int(rotary) or int(rotary) % 2:
             raise ValueError(
@@ -247,69 +268,65 @@ class ModelConfig:
 
     @property
     def num_params(self) -> int:
-        """Exact parameter count (matches HF model.num_parameters())."""
+        """Exact parameter count (matches HF model.num_parameters()): a layer
+        is its norms, its mixer and its feed-forward, each counted by the kind
+        ``layer(i)`` says it is."""
         h, v, f, L = self.hidden_size, self.vocab_size, self.intermediate_size, self.num_layers
         d = self.resolved_head_dim
-        embed = v * h
-        if self.num_experts:
-            # router gate [h, E] + E SwiGLU experts (w1/w3 [h, f], w2 [f, h])
-            mlp = h * self.num_experts + self.num_experts * 3 * h * f
-        else:
-            mlp = 3 * h * f                    # gate, up, down
-        per_layer = (
-            h * (self.num_heads * d)          # q_proj
-            + h * (self.num_kv_heads * d) * 2  # k_proj, v_proj
-            + (self.num_heads * d) * h         # o_proj
-            + mlp
-            + 2 * h                            # two RMSNorms
-        )
-        if self.attention_bias:
-            per_layer += (self.num_heads + 2 * self.num_kv_heads) * d
-            if self.attention_out_bias:
-                per_layer += h
-        if self.qk_norm:
-            per_layer += 2 * d                 # q_norm, k_norm (per head_dim)
-        if self.sandwich_norms:
-            per_layer += 2 * h                 # post-attn + post-ffn norms
-        if self.mlp_bias:
-            per_layer += 2 * f + h
-        total = embed + L * per_layer + h  # + final norm
         if self.kv_lora_rank:
             # MLA: q, kv_a (latent + shared rope key), latent norm, kv_b, o
-            # instead of q, k, v, o (counted above at head_dim d)
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
             r = self.kv_lora_rank
-            mla = (
+            softmax = (
                 h * self.num_heads * qk + h * (r + self.qk_rope_head_dim) + r
                 + r * self.num_heads * (self.qk_nope_head_dim + self.v_head_dim)
                 + self.num_heads * self.v_head_dim * h
             )
-            total += L * (mla - 2 * h * (self.num_heads + self.num_kv_heads) * d)
-        if self.n_routed_experts:
-            # expert layers: router [h, E] + (sigmoid) its bias buffer [E], the HELD
-            # experts and the shared expert, instead of the dense MLP
-            fe, e = self.moe_intermediate_size, self.n_routed_experts
-            held = len(self.held_expert_ids)
-            bias = e if self.router_scoring == "sigmoid" else 0
-            experts = h * e + bias + 3 * h * fe * (held + self.n_shared_experts)
-            if self.shared_expert_gate:
-                experts += h  # one column
-            total += (L - min(L, self.first_k_dense_replace)) * (experts - 3 * h * f)
+        else:
+            softmax = 2 * h * (self.num_heads + self.num_kv_heads) * d  # q, k, v, o
+        if self.attention_bias:
+            softmax += (self.num_heads + 2 * self.num_kv_heads) * d
+            if self.attention_out_bias:
+                softmax += h
+        if self.qk_norm:
+            softmax += 2 * d                  # q_norm, k_norm (per head_dim)
         if self.attention_output_gate:
-            total += (L - len(self.linear_layers)) * h * self.num_heads * d  # q_proj carries the gate
-        if self.linear_layers:
-            # in_proj_qkvz, in_proj_ba, the convolution over q, k, v, A_log and
-            # dt_bias, the gated norm, out_proj, instead of q, k, v, o (and
-            # their norms and the gate's half of q_proj, counted above)
-            kd = self.linear_num_key_heads * self.linear_key_head_dim
-            vd = self.linear_num_value_heads * self.linear_value_head_dim
-            mixer = (
-                h * (2 * kd + 2 * vd) + h * 2 * self.linear_num_value_heads
-                + (2 * kd + vd) * self.linear_conv_kernel_dim + 2 * self.linear_num_value_heads
-                + self.linear_value_head_dim + vd * h
-            )
-            heads = 2 * h * (self.num_heads + self.num_kv_heads) * d + (2 * d if self.qk_norm else 0)
-            total += len(self.linear_layers) * (mixer - heads)
+            softmax += h * self.num_heads * d  # q_proj carries the gate
+        kd = self.linear_num_key_heads * self.linear_key_head_dim
+        vd = self.linear_num_value_heads * self.linear_value_head_dim
+        taps, hv = self.linear_conv_kernel_dim, self.linear_num_value_heads
+        mixers = {
+            "heads": softmax,
+            "latent": softmax,
+            # in_proj_qkvz, in_proj_ba, the convolution over q, k, v, A_log and dt_bias, the gated norm, out_proj
+            "linear": h * (2 * kd + 2 * vd) + h * 2 * hv + (2 * kd + vd) * taps + 2 * hv + self.linear_value_head_dim + vd * h,
+            # q, k, v and their convolutions, b_proj, the decay's pair with A_log (a head) and dt_bias (a channel),
+            # the gate's pair, the gated norm, o_proj
+            "kda": (
+                h * (2 * kd + vd) + (2 * kd + vd) * taps + h * hv
+                + self.linear_decay_rank * (h + kd) + hv + kd
+                + self.linear_gate_rank * (h + vd) + self.linear_value_head_dim + vd * h
+            ),
+        }
+        if self.num_experts:
+            # router gate [h, E] + E SwiGLU experts (w1/w3 [h, f], w2 [f, h])
+            dense = h * self.num_experts + self.num_experts * 3 * h * f
+        else:
+            dense = 3 * h * f + (2 * f + h if self.mlp_bias else 0)  # gate, up, down
+        # expert layers: router [h, E] + (sigmoid) its bias buffer [E], the HELD
+        # experts and the shared expert, instead of the dense MLP
+        fe, e = self.moe_intermediate_size, self.n_routed_experts
+        experts = (
+            h * e + (e if self.router_scoring == "sigmoid" else 0)
+            + 3 * h * fe * (len(self.held_expert_ids) + self.n_shared_experts)
+            + (h if self.shared_expert_gate else 0)  # one column
+        )
+        feed_forwards = {"dense": dense, "capacity_experts": dense, "grouped_experts": experts}
+        norms = (4 if self.sandwich_norms else 2) * h
+        total = v * h + h  # the embedding, the final norm
+        for i in range(L):
+            plan = self.layer(i)
+            total += norms + mixers[plan.attention] + feed_forwards[plan.feed_forward]
         if not self.tie_word_embeddings:
             total += v * h
         return total
@@ -330,7 +347,8 @@ class ModelConfig:
         window = self.sliding_window
         kind = self.layer_types[i] if self.layer_types else None
         if kind == "linear_attention":
-            return LayerPlan(attention="linear", rope=False, rope_kind="plain", window=None, feed_forward=feed_forward)
+            mixer = "kda" if self.linear_decay_rank else "linear"
+            return LayerPlan(attention=mixer, rope=False, rope_kind="plain", window=None, feed_forward=feed_forward)
         if self.layer_types:
             if kind == "full_attention":
                 window = None
@@ -341,7 +359,7 @@ class ModelConfig:
         )
         return LayerPlan(
             attention="latent" if self.kv_lora_rank else "heads",
-            rope=bool(self.no_rope_layers[i]) if self.no_rope_layers else True,
+            rope=bool(self.no_rope_layers[i]) if self.no_rope_layers else not self.mla_use_nope,
             rope_kind="scaled" if scaled else "plain",
             window=window,
             feed_forward=feed_forward,

@@ -120,6 +120,7 @@ class LatentAttentionNotServed(NotImplementedError):
         "linear": "linear-attention layers: it is supported on the training path only; serving it needs a "
                   "recurrent state (and the convolution's last inputs) as a cache entry, which infer/ lacks",
     }
+    _LACKS["kda"] = _LACKS["linear"]  # Kimi Delta Attention: the same kind of state, a decay a channel
 
     def __init__(self, name: str, kind: str = "latent"):
         super().__init__(f"model {name!r} has {self._LACKS[kind]}")
@@ -128,7 +129,7 @@ class LatentAttentionNotServed(NotImplementedError):
 def unserved_layer_kind(config: ModelConfig):
     """The first kind of layer of ``config`` that has no cache here, or None."""
     kinds = {config.layer(i).attention for i in range(config.num_layers)}
-    return next((kind for kind in ("latent", "linear") if kind in kinds), None)
+    return next((kind for kind in ("latent", "linear", "kda") if kind in kinds), None)
 
 
 class Generator:

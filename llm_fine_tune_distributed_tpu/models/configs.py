@@ -522,6 +522,87 @@ PRESETS = {
         routed_scaling_factor=2.826,
         held_experts=(0, 1, 2, 3),
     ),
+    "kimi_linear_48b_a3b": ModelConfig(
+        # HF moonshotai/Kimi-Linear-48B-A3B-Instruct (model_type kimi_linear):
+        # three Kimi Delta Attention layers (32 heads of 128, a causal
+        # convolution of 4 taps each for q, k, v, a decay a channel and an output
+        # gate through low-rank pairs of 128) to one latent-attention layer
+        # WITHOUT rope (q/k heads of 128 + 64, v heads of 128, a latent of 512),
+        # the 27th layer latent too; one leading dense layer of 9216, then 256
+        # experts of 1024 behind a sigmoid router with a selection bias, 8 a
+        # token, weights over their sum times 2.446, beside one shared expert.
+        # Training path only. Set held_experts to one process's share for
+        # expert parallelism.
+        name="kimi_linear_48b_a3b",
+        vocab_size=163840,
+        hidden_size=2304,
+        intermediate_size=9216,
+        num_layers=27,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=72,
+        rope_theta=10_000.0,
+        max_position_embeddings=1048576,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 6 + ("linear_attention",) * 2 + ("full_attention",),
+        linear_num_key_heads=32,
+        linear_num_value_heads=32,
+        linear_key_head_dim=128,
+        linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+        linear_decay_rank=128,
+        linear_gate_rank=128,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        mla_use_nope=True,
+        n_routed_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=1024,
+        n_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.446,
+    ),
+    "tiny_kimi_linear": ModelConfig(
+        # Kimi Linear's structure at toy widths (tests, the benchmark's CPU
+        # rehearsal): the first five layers of the pattern as the benchmark's
+        # cut reads them (a leading dense layer, layer 3 the latent one), this
+        # process holding 4 of the 16 routed experts
+        name="tiny_kimi_linear",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        rope_theta=10_000.0,
+        max_position_embeddings=2048,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        layer_types=("linear_attention",) * 3 + ("full_attention", "linear_attention"),
+        linear_num_key_heads=4,
+        linear_num_value_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
+        linear_decay_rank=16,
+        linear_gate_rank=16,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        mla_use_nope=True,
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.446,
+        held_experts=(0, 1, 2, 3),
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -613,6 +694,8 @@ def to_hf_dict(mc: ModelConfig) -> dict:
             "linear_key_head_dim": mc.linear_key_head_dim,
             "linear_value_head_dim": mc.linear_value_head_dim,
             "linear_conv_kernel_dim": mc.linear_conv_kernel_dim,
+            "linear_decay_rank": mc.linear_decay_rank,
+            "linear_gate_rank": mc.linear_gate_rank,
         } if mc.linear_layers else {}),
         # MoE round trip (HF MixtralConfig naming — consumed by
         # models/configs.from_hf_config at inference load time)
@@ -631,6 +714,7 @@ def _deepseek_v3_keys(mc: ModelConfig) -> dict:
         return {}
     return {
         "kv_lora_rank": mc.kv_lora_rank,
+        "mla_use_nope": mc.mla_use_nope,
         "q_lora_rank": None,
         "qk_nope_head_dim": mc.qk_nope_head_dim,
         "qk_rope_head_dim": mc.qk_rope_head_dim,
@@ -672,6 +756,7 @@ def _deepseek_v3_fields(g) -> dict:
             )
     return dict(
         kv_lora_rank=g("kv_lora_rank"),
+        mla_use_nope=bool(g("mla_use_nope", False)),
         qk_nope_head_dim=g("qk_nope_head_dim"),
         qk_rope_head_dim=g("qk_rope_head_dim"),
         v_head_dim=g("v_head_dim"),
@@ -833,6 +918,65 @@ def _afmoe_fields(g) -> dict:
     )
 
 
+def _kimi_linear_fields(g) -> dict:
+    """ModelConfig fields of a ``kimi_linear`` config (moonshotai Kimi Linear): Kimi Delta Attention layers and
+    latent-attention layers by ``linear_attn_config``'s 1-based ``kda_layers`` / ``full_attn_layers``, the latter
+    without rope where ``mla_use_nope``; ``first_k_dense_replace`` leading dense layers, then ``num_experts`` experts
+    behind a sigmoid router whose bias selects and does not weigh (``moe_renormalize``, ``routed_scaling_factor``)
+    beside ``num_shared_experts`` shared ones. The config has no key for the rank of the decay's and the output gate's
+    pairs of matrices: the family's convention is the linear head's width (HF ``modeling_kimi.py``). Whatever of it this
+    framework does not implement is refused by name, before any weight loads."""
+    n = g("num_hidden_layers")
+    linear = dict(g("linear_attn_config") or {})
+    # (a cut in depth keeps the published lists and reads them up to num_hidden_layers)
+    kda, full = ({i for i in linear.get(key) or () if i <= n} for key in ("kda_layers", "full_attn_layers"))
+    problems = []
+    for key in ("num_expert_group", "topk_group"):
+        if (g(key) or 1) > 1:
+            problems.append(f"{key} {g(key)} (implemented: one group of experts)")
+    if (g("moe_layer_freq") or 1) != 1:
+        problems.append(f"moe_layer_freq {g('moe_layer_freq')} (implemented: 1, every layer past the leading dense ones with experts)")
+    if not g("moe_renormalize", True):
+        problems.append("moe_renormalize false (implemented: weights over the sum of the chosen scores)")
+    if g("moe_router_activation_func", "sigmoid") != "sigmoid":
+        problems.append(f"moe_router_activation_func {g('moe_router_activation_func')!r} (implemented: sigmoid)")
+    if g("q_lora_rank") is not None:
+        problems.append(f"q_lora_rank {g('q_lora_rank')} (implemented: q as one matrix)")
+    if (g("num_nextn_predict_layers") or 0) > 0:
+        problems.append(f"num_nextn_predict_layers {g('num_nextn_predict_layers')} (implemented: no multi-token-prediction layer)")
+    if g("rope_scaling"):
+        problems.append(f"rope_scaling {g('rope_scaling')!r} (implemented: none)")
+    if kda & full or (kda | full) != set(range(1, n + 1)):
+        problems.append(f"linear_attn_config whose kda_layers and full_attn_layers do not name each of the layers 1..{n} once")
+    if problems:
+        raise ValueError("kimi_linear config has " + "; ".join(problems))
+    width = linear["head_dim"]
+    return dict(
+        layer_types=tuple("linear_attention" if i + 1 in kda else "full_attention" for i in range(n)),
+        linear_num_key_heads=linear["num_heads"],
+        linear_num_value_heads=linear["num_heads"],
+        linear_key_head_dim=width,
+        linear_value_head_dim=width,
+        linear_conv_kernel_dim=linear.get("short_conv_kernel_size", 4),
+        linear_decay_rank=width,
+        linear_gate_rank=width,
+        kv_lora_rank=g("kv_lora_rank"),
+        qk_nope_head_dim=g("qk_nope_head_dim"),
+        qk_rope_head_dim=g("qk_rope_head_dim"),
+        v_head_dim=g("v_head_dim"),
+        mla_use_nope=bool(g("mla_use_nope", False)),
+        n_routed_experts=g("num_experts"),
+        num_experts_per_tok=g("num_experts_per_token"),
+        moe_intermediate_size=g("moe_intermediate_size"),
+        n_shared_experts=g("num_shared_experts") or 0,
+        first_k_dense_replace=g("first_k_dense_replace") or 0,
+        routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
+        router_scoring="sigmoid",
+        held_experts=tuple(g("held_experts") or ()),
+        max_position_embeddings=g("max_position_embeddings") or g("model_max_length") or 4096,
+    )
+
+
 def load_model_config(path: str) -> ModelConfig:
     """Read ``path/config.json`` (HF layout) into a ModelConfig — the ONE
     place train-time (trainer._resolve_model_config) and inference-time
@@ -905,7 +1049,8 @@ def from_hf_config(hf_config) -> ModelConfig:
     # deepseek_v3 (Moonlight, DeepSeek-V3): by model_type, or by this
     # framework's own save, which carries kv_lora_rank under any name
     deepseek = {}
-    if mt == "deepseek_v3" or g("kv_lora_rank") or g("n_routed_experts"):
+    kimi = mt == "kimi_linear" and g("linear_attn_config") is not None  # (this framework's own save of one: its own keys)
+    if not kimi and (mt == "deepseek_v3" or g("kv_lora_rank") or g("n_routed_experts")):
         deepseek = _deepseek_v3_fields(g)
     no_rope = g("no_rope_layers") or ()
     # HF rope_scaling dict: {"rope_type"|"type": "llama3"|"linear"|"default",
@@ -1018,6 +1163,8 @@ def from_hf_config(hf_config) -> ModelConfig:
         linear_key_head_dim=g("linear_key_head_dim") or 0,
         linear_value_head_dim=g("linear_value_head_dim") or 0,
         linear_conv_kernel_dim=g("linear_conv_kernel_dim") or 4,
+        linear_decay_rank=g("linear_decay_rank") or 0,
+        linear_gate_rank=g("linear_gate_rank") or 0,
         # MoE (HF MixtralConfig naming). router_aux_loss_coef=0.0 is a
         # legitimate explicit choice (aux disabled) — only None falls back.
         num_experts=g("num_local_experts", 0) or 0,
@@ -1032,4 +1179,6 @@ def from_hf_config(hf_config) -> ModelConfig:
         return dataclasses.replace(mc, **_qwen3_next_fields(g))
     if mt == "afmoe" and not framework_save:  # (this framework's own save of one carries every field by its own key)
         return dataclasses.replace(mc, **_afmoe_fields(g))
+    if kimi:
+        return dataclasses.replace(mc, **_kimi_linear_fields(g))
     return dataclasses.replace(mc, **deepseek) if deepseek else mc

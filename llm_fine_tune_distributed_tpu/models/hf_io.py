@@ -57,8 +57,8 @@ def _rope_pairs(config: ModelConfig, path: tuple):
     DeepseekV3 attention makes of q_pe and k_pe before it rotates), else
     None. A dot product of q and k is the same under any permutation both
     share, so only the two projections that produce rope dimensions move."""
-    if not config.kv_lora_rank or len(path) < 3 or path[-3] != "self_attn":
-        return None
+    if not config.kv_lora_rank or config.mla_use_nope or len(path) < 3 or path[-3] != "self_attn":
+        return None  # (without rope nothing is rotated, and q and the shared key keep the stored order alike)
     dr = config.qk_rope_head_dim
     halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
     if path[-2] == "q_proj":
@@ -135,6 +135,74 @@ def _join_gate(q: np.ndarray, gate: np.ndarray, heads: int) -> np.ndarray:
     return np.stack([q.reshape(h, heads, -1), gate.reshape(h, heads, -1)], axis=2).reshape(h, -1)
 
 
+# HF kimi_linear (moonshotai Kimi Linear) names that differ from the tree's. Both kinds of mixer are a layer's
+# ``self_attn`` there; the tree keeps a Kimi Delta Attention mixer under ``linear_attn`` (the other linear mixer's
+# subtree) with the parts both have under one name. HF keeps a convolution each for q, k and v, ``[channels, 1,
+# taps]``; the tree ONE ``conv1d/weight [taps, q | k | v channels]`` leaf, the one the in pass reads
+# (``ops/gated_delta.mixer_in``). ``A_log`` is stored ``[1, 1, heads, 1]``. The experts are a layer's
+# ``block_sparse_moe`` with Mixtral's ``w1``/``w3``/``w2`` under each expert's GLOBAL id, the router its ``gate``
+# with DeepSeek-V3's ``e_score_correction_bias``; a dense layer keeps ``mlp``.
+_KIMI_MIXER = {"norm": "o_norm", "out_proj": "o_proj"}                   # tree's name -> stored name, below the mixer
+_KIMI_EXPERT = {"gate_proj": "w1", "up_proj": "w3", "down_proj": "w2"}   # DeepSeek-V3's name -> stored name
+_KIMI_LAYER = re.compile(r"^(model\.layers\.(\d+))\.(self_attn|linear_attn|mlp|block_sparse_moe)\.(.*)$")
+
+
+def _kimi(config: Optional[ModelConfig]) -> bool:
+    return config is not None and bool(config.linear_decay_rank)
+
+
+def _kimi_to_stored(state: Dict[str, np.ndarray], config: ModelConfig) -> Dict[str, np.ndarray]:
+    """A state dict under the tree's dotted names (torch layout) -> under HF kimi_linear's."""
+    kd = config.linear_num_key_heads * config.linear_key_head_dim
+    out = {}
+    for name, arr in state.items():
+        m = _KIMI_LAYER.match(name)
+        if m and m.group(3) == "linear_attn":
+            base, part = f"{m.group(1)}.self_attn.", m.group(4)
+            if part == "conv1d.weight":
+                for which, lo, hi in (("q", 0, kd), ("k", kd, 2 * kd), ("v", 2 * kd, arr.shape[0])):
+                    out[f"{base}{which}_conv1d.weight"] = np.ascontiguousarray(arr[lo:hi])
+                continue
+            if part == "A_log":
+                arr = arr.reshape(1, 1, -1, 1)
+            head, _, tail = part.partition(".")
+            name = base + _KIMI_MIXER.get(head, head) + (f".{tail}" if tail else "")
+        elif m and m.group(3) == "mlp" and m.group(4).split(".")[0] in ("gate", "experts", "shared_experts"):
+            parts = m.group(4).split(".")
+            if parts[0] == "experts":
+                parts[2] = _KIMI_EXPERT[parts[2]]
+            name = f"{m.group(1)}.block_sparse_moe." + ".".join(parts)
+        out[name] = arr
+    return out
+
+
+def _kimi_from_stored(state: Dict[str, np.ndarray], config: ModelConfig) -> Dict[str, np.ndarray]:
+    """``_kimi_to_stored`` backwards: which layers' ``self_attn`` is a Kimi Delta Attention mixer is the config's to say."""
+    ours_mixer = {v: k for k, v in _KIMI_MIXER.items()}
+    ours_expert = {v: k for k, v in _KIMI_EXPERT.items()}
+    out, convs = {}, {}
+    for name, arr in state.items():
+        m = _KIMI_LAYER.match(name)
+        if m and m.group(3) == "self_attn" and config.layer(int(m.group(2))).attention == "kda":
+            base, part = f"{m.group(1)}.linear_attn.", m.group(4)
+            if part.endswith("_conv1d.weight"):
+                convs.setdefault(base, {})[part[0]] = np.asarray(arr)
+                continue
+            if part == "A_log":
+                arr = np.asarray(arr).reshape(-1)
+            head, _, tail = part.partition(".")
+            name = base + ours_mixer.get(head, head) + (f".{tail}" if tail else "")
+        elif m and m.group(3) == "block_sparse_moe":
+            parts = m.group(4).split(".")
+            if parts[0] == "experts":
+                parts[2] = ours_expert[parts[2]]
+            name = f"{m.group(1)}.mlp." + ".".join(parts)
+        out[name] = arr
+    for base, parts in convs.items():
+        out[base + "conv1d.weight"] = np.concatenate([parts[which] for which in "qkv"], axis=0)
+    return out
+
+
 def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dict[str, np.ndarray]:
     """params pytree -> {hf_name: numpy array (torch layout)}. ``config`` is
     needed for a model with latent attention or held experts (the rope
@@ -191,7 +259,7 @@ def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dic
         else:
             hf_name = ".".join(path)
         state[hf_name] = np.ascontiguousarray(arr)
-    return state
+    return _kimi_to_stored(state, config) if _kimi(config) else state
 
 
 def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, dtype=None):
@@ -211,9 +279,12 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
                 "gate_proj", "up_proj", "down_proj", "lm_head",
                 "block_sparse_moe.gate", "kv_a_proj_with_mqa", "kv_b_proj", "mlp.gate.",
                 "in_proj_qkvz", "in_proj_ba", "linear_attn.out_proj", "shared_expert_gate",
+                "linear_attn.b_proj", "f_a_proj", "f_b_proj", "g_a_proj", "g_b_proj",
             )
         )
 
+    if _kimi(config):
+        state = _kimi_from_stored(state, config)
     deepseek_re = re.compile(r"^(.*\.mlp\.experts)\.(\d+)\.(gate_proj|up_proj|down_proj)\.weight$")
     stacked_name = {v: k for k, v in _DEEPSEEK_EXPERT.items()}
     held_row = {expert: row for row, expert in enumerate(config.held_expert_ids)}

@@ -22,6 +22,7 @@ name ``kernel`` (transpose of torch ``weight``); norm/embedding leaves are
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -230,10 +231,13 @@ def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope=True):
     ordinary attention paths: ``hid [b, s, h]`` -> q, k ``[b, s, heads,
     qk_nope + qk_rope]`` and v ``[b, s, heads, v_head_dim]``. k and v come up
     from one normed latent of ``kv_lora_rank``; the rope key (one per token)
-    is rotated once and shared by every head, on every layer (``rope`` is not
-    asked). ``cos``/``sin`` are tables of ``qk_rope_head_dim``; halves are
-    rotated (HF de-interleaves DeepSeek's stored pairs first;
-    ``models/hf_io.py`` does that to the weights)."""
+    is rotated once and shared by every head where the layer's plan says
+    ``rope`` (DeepSeek-V3, Moonlight: every layer), and taken as it comes where
+    it does not (Kimi Linear's ``mla_use_nope``: no position signal at all, the
+    scores run over all ``qk_nope + qk_rope`` columns unrotated). ``cos``/``sin``
+    are tables of ``qk_rope_head_dim``; halves are rotated (HF de-interleaves
+    DeepSeek's stored pairs first; ``models/hf_io.py`` does that to the
+    weights)."""
     b, s, _ = hid.shape
     nh, r = config.num_heads, config.kv_lora_rank
     dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
@@ -241,7 +245,12 @@ def _latent_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope=True):
     latent = lin(hid, attn_p["kv_a_proj_with_mqa"])
     c_kv = rms_norm(latent[..., :r], attn_p["kv_a_layernorm"]["weight"], config.rms_norm_eps)
     kv = lin(c_kv, attn_p["kv_b_proj"]).reshape(b, s, nh, dn + dv)
-    q_pe, k_pe = apply_rope(q[..., dn:], latent[..., r:].reshape(b, s, 1, dr), cos, sin)
+    q_pe, k_pe = q[..., dn:], latent[..., r:].reshape(b, s, 1, dr)
+    if not isinstance(rope, bool):  # (the pipeline's layer scan: the layer index is data)
+        rotated = apply_rope(q_pe, k_pe, cos, sin)
+        q_pe, k_pe = jnp.where(rope, rotated[0], q_pe), jnp.where(rope, rotated[1], k_pe)
+    elif rope:
+        q_pe, k_pe = apply_rope(q_pe, k_pe, cos, sin)
     q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
     k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, nh, dr))], axis=-1)
     return q, k, kv[..., dn:], None
@@ -362,14 +371,8 @@ def _by_columns(hid, p, cuts, lin, heads: int = 1):
     return [lin(hid, {name: run_of(x, lo, hi) if name in _CUT_BY_COLUMN else x for name, x in p.items()}) for lo, hi in runs]
 
 
-def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, **_):
-    """A Gated DeltaNet mixer (``ops/gated_delta.py``): q, k, v through a
-    causal convolution and silu, q and k l2-normed a head (``mixer_in``, one
-    pass), the gated delta rule per value head in place of softmax attention,
-    a norm gated by ``silu(z)`` (``gated_norm``, one pass), ``out_proj``;
-    everything between the projections flat, ``[b, s, heads x d]``. No rope
-    (``cos``/``sin`` unused), no mask: the rule is causal, and what a
-    right-padded row computes at its pads reaches no real token."""
+def _whole_rows_only(config: ModelConfig, segment_ids, cache_entry):
+    """What a mixer that is a recurrence over time refuses, and why."""
     if segment_ids is not None:
         raise NotImplementedError(
             f"model {config.name!r} has linear-attention layers and the batch is packed (segment_ids): the "
@@ -380,6 +383,17 @@ def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entr
         raise NotImplementedError(
             "a linear-attention layer has the training form only; its cache is a state, not keys and values"
         )
+
+
+def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, **_):
+    """A Gated DeltaNet mixer (``ops/gated_delta.py``): q, k, v through a
+    causal convolution and silu, q and k l2-normed a head (``mixer_in``, one
+    pass), the gated delta rule per value head in place of softmax attention,
+    a norm gated by ``silu(z)`` (``gated_norm``, one pass), ``out_proj``;
+    everything between the projections flat, ``[b, s, heads x d]``. No rope
+    (``cos``/``sin`` unused), no mask: the rule is causal, and what a
+    right-padded row computes at its pads reaches no real token."""
+    _whole_rows_only(config, segment_ids, cache_entry)
     b, s, _ = hid.shape
     hk, hv = config.linear_num_key_heads, config.linear_num_value_heads
     dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
@@ -400,6 +414,61 @@ def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entr
     return lin(o.astype(hid.dtype), attn_p["out_proj"]), None
 
 
+def _init_kda_attention(keys, config: ModelConfig, dense, dtype):
+    """HF ``KimiDeltaAttention``'s leaves (moonshotai ``kimi_linear``), under the subtree and the names the other
+    linear mixer has where the part is the same (``conv1d``, ``norm``, ``out_proj``; HF: three ``*_conv1d``, ``o_norm``,
+    ``o_proj``: ``models/hf_io.py``). ``conv1d/weight`` is the ONE ``[taps, q | k | v channels]`` leaf the in pass
+    reads (HF keeps a convolution each for q, k and v). The decay's pair ``f_a_proj`` / ``f_b_proj`` and the output
+    gate's ``g_a_proj`` / ``g_b_proj`` have no bias; ``A_log`` is one a head (``log U(1, 16)``), ``dt_bias`` one a
+    channel, drawn so that ``softplus(dt_bias)`` lies in [0.001, 0.1] (log-uniform), as the family draws them."""
+    h, taps = config.hidden_size, config.linear_conv_kernel_dim
+    hv, dk = config.linear_num_value_heads, config.linear_key_head_dim
+    kd, vd = hv * dk, hv * config.linear_value_head_dim
+    dt = jnp.exp(jax.random.uniform(next(keys), (kd,), jnp.float32, math.log(1e-3), math.log(0.1)))
+    return {
+        "q_proj": {"kernel": dense(next(keys), (h, kd))},
+        "k_proj": {"kernel": dense(next(keys), (h, kd))},
+        "v_proj": {"kernel": dense(next(keys), (h, vd))},
+        "conv1d": {"weight": dense(next(keys), (taps, 2 * kd + vd))},
+        "b_proj": {"kernel": dense(next(keys), (h, hv))},
+        "f_a_proj": {"kernel": dense(next(keys), (h, config.linear_decay_rank))},
+        "f_b_proj": {"kernel": dense(next(keys), (config.linear_decay_rank, kd))},
+        "A_log": jnp.log(jax.random.uniform(next(keys), (hv,), jnp.float32, 1.0, 16.0)).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),  # softplus^-1
+        "g_a_proj": {"kernel": dense(next(keys), (h, config.linear_gate_rank))},
+        "g_b_proj": {"kernel": dense(next(keys), (config.linear_gate_rank, vd))},
+        "norm": {"weight": jnp.ones((config.linear_value_head_dim,), dtype)},
+        "out_proj": {"kernel": dense(next(keys), (vd, h))},
+    }
+
+
+def _kda_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, **_):
+    """A Kimi Delta Attention mixer: ``_linear_mixer``'s passes and rule around what differs. q, k, v come from
+    projections of their own (so nothing is cut by column); the decay is a VECTOR over a head's ``d_k`` channels,
+    ``g = -exp(A_log[head]) softplus((x W_fa) W_fb + dt_bias)``, which ``gated_delta_rule`` reads from ``g``'s shape;
+    beta ``sigmoid(x W_b)`` a head; the norm after the rule is gated by ``sigmoid((x W_ga) W_gb)``. The four low-rank
+    products and the softplus stand under the scope ``kda_gates``. As many key heads as value heads."""
+    _whole_rows_only(config, segment_ids, cache_entry)
+    b, s, _ = hid.shape
+    hv, dk, dv = config.linear_num_value_heads, config.linear_key_head_dim, config.linear_value_head_dim
+    xq, xk, xv = lin(hid, attn_p["q_proj"]), lin(hid, attn_p["k_proj"]), lin(hid, attn_p["v_proj"])
+    beta = jax.nn.sigmoid(lin(hid, attn_p["b_proj"]).astype(jnp.float32))
+    with scope("kda_gates"):
+        decay_in = lin(lin(hid, attn_p["f_a_proj"]), attn_p["f_b_proj"]).astype(jnp.float32)
+        gate = lin(lin(hid, attn_p["g_a_proj"]), attn_p["g_b_proj"])
+        g = -jnp.exp(attn_p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+            decay_in + attn_p["dt_bias"].astype(jnp.float32)).reshape(b, s, hv, dk)  # a log decay a channel, <= 0
+    with scope("gdn_conv"):
+        q, k, v = gated_delta.mixer_in(xq, xk, xv, attn_p["conv1d"]["weight"], hv)
+    with scope("gdn_scan"):
+        o = checkpoint_name(gated_delta.gated_delta_rule(
+            q.reshape(b, s, hv, dk), k.reshape(b, s, hv, dk), v.reshape(b, s, hv, dv), g, beta), "gdn_o")
+    with scope("gdn_gate_norm"):
+        o = gated_delta.gated_norm(o.reshape(b, s, hv * dv), gate, attn_p["norm"]["weight"], config.rms_norm_eps,
+                                   activation="sigmoid")
+    return lin(o.astype(hid.dtype), attn_p["out_proj"]), None
+
+
 # LayerPlan.attention -> (the layer's subtree of that kind, the scope its device
 # time is read under, its half of init_params, the mixer: normed input ->
 # (output [b, s, hidden], new cache entry))
@@ -407,6 +476,7 @@ _ATTENTION = {
     "heads": ("self_attn", "attn", _init_heads_attention, _softmax_mixer(_heads_qkv, _heads_qkv_head_major)),
     "latent": ("self_attn", "attn", _init_latent_attention, _softmax_mixer(_latent_qkv)),
     "linear": ("linear_attn", "linear_attn", _init_linear_attention, _linear_mixer),
+    "kda": ("linear_attn", "linear_attn", _init_kda_attention, _kda_mixer),
 }
 
 
@@ -530,7 +600,8 @@ def _report(counted_by_layer) -> Dict[str, jax.Array]:
 def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
     """Random init (normal 0.02, HF convention). Returns the params pytree."""
     h, v = config.hidden_size, config.vocab_size
-    keys = iter(jax.random.split(rng, 2 + config.num_layers * 7))  # (a layer draws at most 7)
+    kda = any(config.layer(i).attention == "kda" for i in range(config.num_layers))
+    keys = iter(jax.random.split(rng, 2 + config.num_layers * (17 if kda else 7)))  # (a layer draws at most 7, a KDA mixer's 14)
 
     def dense(key, shape):
         return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
@@ -840,7 +911,7 @@ def _remat_policy(
             f"unknown remat_policy {remat_policy!r}; expected one of {sorted(policies)}"
         )
     policy = policies[remat_policy]
-    if attention == "linear":
+    if attention in ("linear", "kda"):
         names = ("gdn_o",) if keeps_scan_output(config) else ()
     else:
         names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq, window) else ()

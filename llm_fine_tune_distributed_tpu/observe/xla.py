@@ -835,6 +835,10 @@ STEP_SCOPES = (
     # "attn": the q/k norms, rope and the hand-over of the layout, as the
     # fused pass (ops/rope.heads_in) or as the XLA form ("qk_norm" inside it)
     "attn_in",
+    # inside "linear_attn" of a Kimi Delta Attention mixer, around what the other
+    # linear mixer does not have: the low-rank products of the decay and of the
+    # output gate, the softplus and the decay's sign and scale
+    "kda_gates",
 )
 
 

@@ -268,6 +268,175 @@ def _rule_xla(q, k, v, g, beta, *, chunk: int = CHUNK):
     return o[:, :s]
 
 
+# -- the rule with a decay a CHANNEL (Kimi Delta Attention) -----------------------
+#
+# ``g [b, s, value heads, d_k]``: ``S = diag(exp(g_t)) S`` in place of ``S *= exp(g_t)``; the rest of the rule as it
+# stands. The chunked form is the same algebra with the decays inside the products: with ``G_i`` (a vector now) the
+# running sum from the chunk's start,
+#
+#     A_ij = beta_i sum_d k_id k_jd exp(G_id - G_jd)  (j < i),   P_ij = sum_d q_id k_jd exp(G_id - G_jd)  (j <= i),
+#     W = T (beta (K * exp(G))),   O = (Q * exp(G)) S + P D,   S' = diag(exp(G_C)) S + (K * exp(G_C - G))^T D.
+#
+# With a scalar decay ``exp(G_i - G_j)`` leaves the sum and one product ``k k^T`` serves a chunk. With a vector it
+# does not, and the split ``(k_i * exp(G_i)) . (k_j * exp(-G_j))`` overflows: ``exp(A_log)`` reaches 16 a token, ``-G``
+# passes 1000 inside one chunk of 64, float32 ends at ``exp(88)``. So a chunk is cut a second time, into sub-blocks of
+# ``SUB`` tokens:
+#
+# - a sub-block against an EARLIER one, relative to the boundary between them: ``R_I`` the running sum at the last
+#   token before sub-block I, ``(k_i * exp(G_i - R_I)) . (k_j * exp(R_I - G_j))`` for i in I and every j before I.
+#   Both exponents are sums of g over whole runs of tokens, so <= 0 whatever g is: a factor can underflow (to a
+#   product that is below float32 anyway), none can overflow. One product a sub-block I, against all tokens before it;
+# - a sub-block against ITSELF from pairwise differences, ``exp(G_i - G_j)`` for j <= i and masked before the ``exp``
+#   as everywhere in this file: ``[SUB, SUB, d_k]`` elementwise work and a sum over the channels, no product.
+#
+# Both in float32 (the products at HIGHEST: their operands are k times a decay, which bfloat16 would round a second
+# time before the triangular inverse sees them), inside ONE ``jax.checkpoint``: its backward pass makes the decayed
+# operands again, so none of them outlives the few operations that read it, and the pairwise block is never written
+# at all (``_own_products``: of all chunks at once it would be 4 GiB a call at the Kimi cell's shapes). XLA operations
+# only: kernels are the next step (PERF.md), and ``CALLS`` says so.
+
+SUB = 16
+
+
+def _running_sums(local):
+    """``(R_I, G_i)`` from ``local [..., m, SUB, d_k]``, g's running sum from each sub-block's start: the running sum at
+    the last token before sub-block I ``[..., m, d_k]``, and at every token, both from the CHUNK's start."""
+    totals = local[..., -1, :]
+    before = jnp.cumsum(totals, axis=-2) - totals
+    return before, before[..., None, :] + local
+
+
+def _pairwise_decay(local):
+    """``exp(G_i - G_j)`` for ``j <= i`` inside each sub-block, 0 above: ``[..., m, SUB, SUB, d_k]``, masked before
+    the ``exp``. Never meant to reach memory: every use below is one fusion that sums it away."""
+    c = local.shape[-2]
+    rows, cols = jnp.arange(c)[:, None, None], jnp.arange(c)[None, :, None]
+    return jnp.exp(jnp.where(rows >= cols, local[..., :, None, :] - local[..., None, :, :], -jnp.inf))
+
+
+@jax.custom_vjp
+def _own_products(x, k, local):
+    """``sum_d x_id k_jd exp(G_id - G_jd)`` for ``j <= i`` of one sub-block (0 above): ``x [2, ..., m, SUB, d_k]``
+    (k and q stacked, so that the pairwise decay has ONE reader), ``k``, ``local`` ``[..., m, SUB, d_k]``, all float32
+    -> ``[2, ..., m, SUB, SUB]``. The backward pass is written out because autodiff's is not affordable: it reads the
+    pairwise block (``SUB`` times the inputs' size, 4 GiB a call at the Kimi cell's shapes) from several fusions, and
+    XLA then writes it out rather than take an ``exp`` twice. Here it has two readers, the sum over j and the sum
+    over i, each handed its own copy of ``local`` behind an optimization barrier so that each takes its own ``exp``;
+    the decay's cotangent needs no third: ``dG_i = sum_z x_zi dx_zi`` and ``dG_j = -k_j dk_j`` follow from the other two."""
+    return jnp.stack([jnp.sum(x_z[..., :, None, :] * k[..., None, :, :] * _pairwise_decay(local), axis=-1) for x_z in x])
+
+
+def _own_products_bwd(kept, d):
+    x, k, local = kept
+    over_j, over_i = jax.lax.optimization_barrier((local, local))
+    dx = jnp.stack([jnp.sum(d_z[..., None] * k[..., None, :, :] * _pairwise_decay(over_j), axis=-2) for d_z in d])  # [2, ..., i, d]
+    through = sum(d_z[..., None] * x_z[..., :, None, :] for d_z, x_z in zip(d, x))                     # [..., i, j, d]
+    dk = jnp.sum(through * _pairwise_decay(over_i), axis=-3)                                           # [..., j, d]
+    return dx, dk, jnp.sum(x * dx, axis=0) - k * dk
+
+
+_own_products.defvjp(lambda x, k, local: (_own_products(x, k, local), (x, k, local)), _own_products_bwd)
+
+
+@jax.checkpoint
+def _decayed_products(q, k, local):
+    """``(sum_d k_id k_jd exp(G_id - G_jd), sum_d q_id k_jd exp(G_id - G_jd))`` for ``j <= i`` (anything above the
+    diagonal), each ``[..., C, C]`` float32, of ``q``, ``k`` ``[..., m, SUB, d_k]`` (a chunk's sub-blocks) and
+    ``local``, g's running sum from each SUB-BLOCK's start, float32 (the section's comment)."""
+    f32 = jnp.float32
+    m, c = q.shape[-3], q.shape[-2]
+    q, k = q.astype(f32), k.astype(f32)
+    before, cum = _running_sums(local)                               # R_I and G_i, from the chunk's start
+    x = jnp.stack([k, q])
+    own = _own_products(x, k, local)                                 # [2, ..., m, c, c]
+    x_in = x * jnp.exp(local)                                        # relative to the boundary before the sub-block
+    flat = lambda y: y.reshape(y.shape[:-3] + (m * c, y.shape[-1]))  # noqa: E731
+    k_flat, cum_flat = flat(k), flat(cum)
+    blocks = []
+    for i in range(m):
+        parts = []
+        if i:
+            k_out = k_flat[..., :i * c, :] * jnp.exp(before[..., i, None, :] - cum_flat[..., :i * c, :])
+            parts.append(jnp.einsum("z...ad,...jd->z...aj", x_in[..., i, :, :], k_out,
+                                    precision=jax.lax.Precision.HIGHEST, preferred_element_type=f32))
+        parts.append(own[..., i, :, :])
+        if i < m - 1:
+            parts.append(jnp.zeros(own.shape[:-3] + (c, (m - 1 - i) * c), f32))
+        blocks.append(jnp.concatenate(parts, axis=-1))
+    both = jnp.concatenate(blocks, axis=-2)
+    return both[0], both[1]
+
+
+@jax.checkpoint
+def _decayed_operands(q, k, beta, local):
+    """What the walk over chunks multiplies by, in ``q``'s dtype: ``beta K * exp(G)``, ``Q * exp(G)``, ``K * exp(G_C -
+    G)`` (each ``[..., C, d_k]``) and ``exp(G_C)`` ``[..., d_k]`` float32, of ``q``, ``k`` ``[..., C, d_k]``, ``beta
+    [..., C, 1]`` and ``local`` as ``_decayed_products`` takes it. Rematerialized: what it keeps is its arguments, not
+    five float32 arrays a token and channel."""
+    f32 = jnp.float32
+    cum = _running_sums(local)[1].reshape(k.shape)                   # G_i, from the chunk's start
+    e, last = jnp.exp(cum), cum[..., -1:, :]
+    k32 = k.astype(f32)
+    return ((k32 * (beta * e)).astype(k.dtype), (q.astype(f32) * e).astype(q.dtype),
+            (k32 * jnp.exp(last - cum)).astype(k.dtype), jnp.exp(last[..., 0, :]))
+
+
+def _rule_xla_by_channel(q, k, v, g, beta, *, chunk: int = CHUNK):
+    """``_rule_xla`` for ``g [b, s, value heads, d_k]`` (the section's comment): the same arguments otherwise, the
+    same dtypes (products of bfloat16 operands added up in float32; decays, the two decayed products, the triangular
+    inverse and the carried state float32), the same scan over chunks with its body rematerialized."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    cd, f32 = v.dtype, jnp.float32
+    if hv != hk:  # a key head's value heads decay apart: each gets its own copy
+        q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+    sub = min(SUB, chunk)
+    if chunk % sub:
+        raise ValueError(f"chunk {chunk} is no multiple of the sub-block {sub}")
+
+    (q, k, v, g, beta), s = _padded_rows((q, k, v, g, beta), chunk)
+    n, m = q.shape[1] // chunk, chunk // sub
+    rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+
+    @jax.checkpoint
+    def before_the_walk(q, k, v, g, beta):
+        """What ONE row's chunks are before any state reaches them, chunks leading (what the scan walks): ``U``,
+        ``W``, the decayed ``q k^T``, ``Q * exp(G)``, ``K * exp(G_C - G)``, ``exp(G_C)``. A row at a time and
+        rematerialized: the float32 operands of the decayed products of ALL rows at once do not fit beside the
+        Kimi cell's state (2 rows of 8192: 16.19 G of the chip's 15.75, my deviceless compile, PR 42), so the
+        backward pass makes a row's again while it holds only that row's."""
+        by_head = lambda x: jnp.moveaxis(x.reshape(n, chunk, hv, -1), 2, 1)  # noqa: E731  [n, h, C, .]
+        qc, kc, vc, gc = by_head(q), by_head(k), by_head(v), by_head(g.astype(f32))
+        bc = by_head(beta.astype(f32))                                       # [n, h, C, 1]
+        blocks = lambda x: x.reshape(n, hv, m, sub, x.shape[-1])             # noqa: E731
+        local = jnp.cumsum(blocks(gc), axis=-2)                              # from each sub-block's start
+        kk, qk = _decayed_products(blocks(qc), blocks(kc), local)
+        k_beta, q_e, k_rest, e_all = _decayed_operands(qc, kc, bc, local)
+        t = unit_lower_inverse(jnp.where(rows > cols, bc * kk, 0.0)).astype(cd)  # [n, h, C, C]
+        p = jnp.where(rows >= cols, qk, 0.0).astype(cd)                      # diagonal included
+        u = jnp.einsum("nhij,nhjv->nhiv", t, vc * bc.astype(cd), preferred_element_type=f32).astype(cd)
+        w = jnp.einsum("nhij,nhjd->nhid", t, k_beta, preferred_element_type=f32).astype(cd)
+        return u, w, p, q_e, k_rest, e_all
+
+    xs = jax.lax.map(lambda row: before_the_walk(*row), (q, k, v, g, beta))  # [b, n, h, ...]
+    xs = tuple(jnp.moveaxis(x, 0, 1) for x in xs)                            # [n, b, h, ...]
+
+    @jax.checkpoint  # as _rule_xla's: the backward pass holds the state at each boundary and makes d again
+    def step(state, x):
+        u_c, w_c, p_c, q_e, k_rest, e_all = x
+        s_c = state.astype(cd)
+        d = u_c.astype(f32) - jnp.einsum("bhid,bhdv->bhiv", w_c, s_c, preferred_element_type=f32)
+        o = jnp.einsum("bhid,bhdv->bhiv", q_e, s_c, preferred_element_type=f32)
+        o = o + jnp.einsum("bhij,bhjv->bhiv", p_c, d.astype(cd), preferred_element_type=f32)
+        grown = jnp.einsum("bhid,bhiv->bhdv", k_rest, d.astype(cd), preferred_element_type=f32)
+        state = (e_all[..., None] * state.astype(f32) + grown).astype(STATE_DTYPE)
+        return state, o.astype(cd)
+
+    _, o = jax.lax.scan(step, jnp.zeros((b, hv, dk, dv), STATE_DTYPE), xs)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, n * chunk, hv, dv)     # [n, b, h, C, dv] -> rows
+    return o[:, :s]
+
+
 # -- the same chunked rule as Pallas kernels (TPU) ---------------------------------
 #
 # One grid step holds one row's ``STEP_CHUNKS`` chunks of ONE key head and ``rs``
@@ -763,12 +932,20 @@ def _program(chunk=CHUNK, **head_sizes):
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, impl=None):
     """The gated delta rule over whole rows, chunked (module docstring; the
     arguments and the dtypes: ``_rule_xla``). Which program runs is read from
-    the input: the kernels on a TPU where a head is whole lanes and the chunk
-    is ``CHUNK``, the XLA form elsewhere. ``impl`` is the tests' and the
-    tools' handle: ``"xla"``, ``"kernels"``, ``"kernels_interpret"`` (the
-    kernels under the Pallas interpreter)."""
+    the input: ``g [b, s, value heads]`` is a decay a head, and takes the
+    kernels on a TPU where a head is whole lanes and the chunk is ``CHUNK``,
+    the XLA form elsewhere; ``g [b, s, value heads, d_k]`` is a decay a
+    channel (``_rule_xla_by_channel``: XLA operations on every backend, it has
+    no kernels yet). ``impl`` is the tests' and the tools' handle: ``"xla"``,
+    ``"kernels"``, ``"kernels_interpret"`` (the kernels under the Pallas
+    interpreter)."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
+    if g.ndim == 4:
+        entry = CALLS.setdefault((b, s, hk, hv, dk, dv, "by channel"), [
+            0, f"chunked {chunk}, a decay a channel in sub-blocks of {min(SUB, chunk)}: xla (no kernels for it yet)"])
+        entry[0] += 1
+        return _rule_xla_by_channel(q, k, v, g, beta, chunk=chunk)
     program = _program(chunk, d_k=dk, d_v=dv) if impl is None else impl.split("_")[0]
     entry = CALLS.setdefault((b, s, hk, hv, dk, dv), [0, f"chunked {chunk}: {program}"])
     entry[0] += 1
@@ -967,21 +1144,26 @@ def gdn_in_bwd(xq, xk, xv, weight, dq, dk, dv, *, hk, interpret):
     return (*dx, dw.reshape(taps, 8, -1).sum(axis=1))
 
 
-def _gate_norm_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
+def _gate_of(z, sig, sigmoid_gate):
+    """The gate from ``z`` and ``sigmoid(z)``: ``silu(z)`` (Gated DeltaNet), or the sigmoid itself (Kimi Delta Attention)."""
+    return sig if sigmoid_gate else z * sig
+
+
+def _gate_norm_kernel(o_ref, z_ref, w_ref, y_ref, *, eps, sigmoid_gate):
     w = w_ref[...]
 
     def trip(i, _):
         rows = pl.ds(pl.multiple_of(i * ROWS, ROWS), ROWS)
         o, z = o_ref[0, rows, :].astype(_F32), z_ref[0, rows, :].astype(_F32)
         normed = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
-        y_ref[0, rows, :] = (normed * w * (z * _sigmoid(z))).astype(y_ref.dtype)
+        y_ref[0, rows, :] = (normed * w * _gate_of(z, _sigmoid(z), sigmoid_gate)).astype(y_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, o_ref.shape[1] // ROWS, trip, 0)
 
 
-def _gate_norm_back_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps):
-    """y = n w silu(z), n = o r, r = rsqrt(mean o^2 + eps). ``dw_ref [8, d_v]`` holds 8 partial sums of the weight's
+def _gate_norm_back_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps, sigmoid_gate):
+    """y = n w gate(z), n = o r, r = rsqrt(mean o^2 + eps), gate silu or (``sigmoid_gate``) sigmoid. ``dw_ref [8, d_v]`` holds 8 partial sums of the weight's
     cotangent over the whole grid."""
     w = w_ref[...]
 
@@ -994,40 +1176,40 @@ def _gate_norm_back_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, 
         o, z, dy = (ref[0, rows, :].astype(_F32) for ref in (o_ref, z_ref, dy_ref))
         r = jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
         normed, sig = o * r, _sigmoid(z)
-        gate = z * sig
+        gate = _gate_of(z, sig, sigmoid_gate)
         dn = dy * w * gate
         do_ref[0, rows, :] = (r * (dn - normed * jnp.mean(dn * normed, axis=1, keepdims=True))).astype(do_ref.dtype)
-        dz_ref[0, rows, :] = (dy * normed * w * sig * (1.0 + z * (1.0 - sig))).astype(dz_ref.dtype)
+        dz_ref[0, rows, :] = (dy * normed * w * sig * ((1.0 - sig) if sigmoid_gate else 1.0 + z * (1.0 - sig))).astype(dz_ref.dtype)
         return acc + by_eights(dy * gate * normed)
 
     dw_ref[...] += jax.lax.fori_loop(0, o_ref.shape[1] // ROWS, trip, jnp.zeros(dw_ref.shape, _F32))
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def gdn_out_fwd(o, z, weight, *, eps, interpret):
+@functools.partial(jax.jit, static_argnames=("eps", "interpret", "sigmoid_gate"))
+def gdn_out_fwd(o, z, weight, *, eps, interpret, sigmoid_gate=False):
     """The out pass. ``o`` (as the rule's kernel wrote it) and ``z`` ``[b, s, hv d_v]``, ``weight [d_v]`` ->
-    ``rms_norm(o) weight silu(z)`` over each head's ``d_v`` lanes, like ``o``. Float32 inside, ONE rounding at the
+    ``rms_norm(o) weight silu(z)`` (``sigmoid(z)`` with ``sigmoid_gate``) over each head's ``d_v`` lanes, like ``o``. Float32 inside, ONE rounding at the
     output: the XLA form (``_gated_norm_xla``) rounds the norm's output to ``o``'s dtype before the gate."""
     b, s, vd = o.shape
     dv, tokens = weight.shape[0], _token_block(s)
     block = pl.BlockSpec((1, tokens, dv), lambda i, t, h: (i, t, h))
     return pl.pallas_call(
-        functools.partial(_gate_norm_kernel, eps=eps),
+        functools.partial(_gate_norm_kernel, eps=eps, sigmoid_gate=sigmoid_gate),
         grid=(b, s // tokens, vd // dv), in_specs=[block, block, pl.BlockSpec((1, dv), lambda i, t, h: (0, 0))],
         out_specs=block, out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
         name="gdn_out_fwd", interpret=interpret, **_params(interpret, ("parallel",) * 3),
     )(o, z, weight.astype(_F32).reshape(1, dv))
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def gdn_out_bwd(o, z, weight, dy, *, eps, interpret):
+@functools.partial(jax.jit, static_argnames=("eps", "interpret", "sigmoid_gate"))
+def gdn_out_bwd(o, z, weight, dy, *, eps, interpret, sigmoid_gate=False):
     """The out pass's backward pass from its kept inputs: the cotangents of ``o`` and ``z`` (like them) and of
     ``weight`` (float32)."""
     b, s, vd = o.shape
     dv, tokens = weight.shape[0], _token_block(s)
     block = pl.BlockSpec((1, tokens, dv), lambda i, t, h: (i, t, h))
     do, dz, dw = pl.pallas_call(
-        functools.partial(_gate_norm_back_kernel, eps=eps),
+        functools.partial(_gate_norm_back_kernel, eps=eps, sigmoid_gate=sigmoid_gate),
         grid=(b, s // tokens, vd // dv), in_specs=[block, block, pl.BlockSpec((1, dv), lambda i, t, h: (0, 0)), block],
         out_specs=[block, block, pl.BlockSpec((8, dv), lambda i, t, h: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype), jax.ShapeDtypeStruct(z.shape, z.dtype), jax.ShapeDtypeStruct((8, dv), _F32)],
@@ -1053,15 +1235,16 @@ def _in_pass(hk, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _out_pass(eps, interpret):
+def _out_pass(eps, interpret, sigmoid_gate=False):
     """The out pass as one differentiable function; it keeps its inputs and nothing else."""
+    static = dict(eps=eps, interpret=interpret, **({"sigmoid_gate": True} if sigmoid_gate else {}))
 
     @jax.custom_vjp
     def run(o, z, weight):
-        return gdn_out_fwd(o, z, weight, eps=eps, interpret=interpret)
+        return gdn_out_fwd(o, z, weight, **static)
 
     def bwd(kept, dy):
-        do, dz, dw = gdn_out_bwd(*kept, dy, eps=eps, interpret=interpret)
+        do, dz, dw = gdn_out_bwd(*kept, dy, **static)
         return do, dz, dw.astype(kept[2].dtype)
 
     run.defvjp(lambda *kept: (run(*kept), kept), bwd)
@@ -1077,13 +1260,14 @@ def _mixer_in_xla(xq, xk, xv, weight, hk):
     return heads(q) * jnp.asarray((kd // hk) ** -0.5, q.dtype), heads(k), v
 
 
-def _gated_norm_xla(o, z, weight, eps):
+def _gated_norm_xla(o, z, weight, eps, activation="silu"):
     """The out pass as XLA operations (``ops/norms.rms_norm``)."""
     from llm_fine_tune_distributed_tpu.ops.norms import rms_norm
 
     b, s, vd = o.shape
     heads = lambda x: x.reshape(b, s, vd // weight.shape[0], weight.shape[0])  # noqa: E731
-    y = rms_norm(heads(o), weight, eps).astype(_F32) * jax.nn.silu(heads(z).astype(_F32))
+    gate = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}[activation]
+    y = rms_norm(heads(o), weight, eps).astype(_F32) * gate(heads(z).astype(_F32))
     return y.astype(o.dtype).reshape(b, s, vd)
 
 
@@ -1107,12 +1291,13 @@ def mixer_in(xq, xk, xv, weight, hk, *, impl=None):
     return tuple(y[:, :s] for y in _in_pass(hk, impl == "kernels_interpret")(*padded, weight))
 
 
-def gated_norm(o, z, weight, eps, *, impl=None):
-    """``rms_norm(o) weight silu(z)`` over each value head's ``d_v`` lanes: ``o`` (the rule's output) and ``z`` flat
-    ``[b, s, hv d_v]``, ``weight [d_v]``. Which program: as ``mixer_in``."""
+def gated_norm(o, z, weight, eps, *, activation="silu", impl=None):
+    """``rms_norm(o) weight gate(z)`` over each value head's ``d_v`` lanes, the gate's ``activation`` ``"silu"`` (Gated
+    DeltaNet) or ``"sigmoid"`` (Kimi Delta Attention): ``o`` (the rule's output) and ``z`` flat ``[b, s, hv d_v]``,
+    ``weight [d_v]``. Which program: as ``mixer_in``."""
     program = _program(d_v=weight.shape[0]) if impl is None else impl.split("_")[0]
     _counted("out", o.shape, program)
     if program != "kernels":
-        return _gated_norm_xla(o, z, weight, eps)
+        return _gated_norm_xla(o, z, weight, eps, activation)
     (o, z), s = _padded_rows((o, z), STEP_CHUNKS * CHUNK)
-    return _out_pass(float(eps), impl == "kernels_interpret")(o, z, weight)[:, :s]
+    return _out_pass(float(eps), impl == "kernels_interpret", *((True,) if activation == "sigmoid" else ()))(o, z, weight)[:, :s]

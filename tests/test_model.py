@@ -124,6 +124,11 @@ _TRINITY = lambda w, dense: dict(  # noqa: E731
     window=lambda i: None if i % 4 == 3 else w, nope=lambda i: i % 4 == 3,
     feed_forward=lambda i: "dense" if i < dense else "grouped_experts",
 )
+# Kimi Linear: three Kimi Delta Attention layers to one latent layer WITHOUT rope (the 27th latent too: ``latent``
+# the 0-based latent layers); one leading dense layer, then experts
+_KIMI = lambda latent: dict(  # noqa: E731
+    attention=lambda i: "latent" if i in latent else "kda", nope=lambda i: True, feed_forward=_LEADING_DENSE,
+)
 PLAN_OF_PRESET = {
     "llama3_1_8b": _SCALED, "llama3_2_1b": _SCALED, "llama3_2_3b": _SCALED,
     "mellum2_12b_a2_5b": _MELLUM(1024), "tiny_mellum": _MELLUM(32),
@@ -138,6 +143,7 @@ PLAN_OF_PRESET = {
     "tiny_mla_moe": dict(attention="latent", feed_forward=_LEADING_DENSE),
     "qwen3_next_80b_a3b": _QWEN3_NEXT, "tiny_qwen3_next": _QWEN3_NEXT,
     "trinity_mini": _TRINITY(2048, 2), "tiny_trinity": _TRINITY(32, 1),
+    "kimi_linear_48b_a3b": _KIMI((3, 7, 11, 15, 19, 23, 26)), "tiny_kimi_linear": _KIMI((3,)),
 }
 
 
@@ -155,7 +161,8 @@ def test_layer_plan_of_every_preset(name):
             window=want["window"](i), feed_forward=want["feed_forward"](i),
         ), (name, i)
     # hashable; no preset has more than two kinds but afmoe's three (dense + window, experts + window, experts + global)
-    assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= (3 if "trinity" in name else 2)
+    # and Kimi Linear's (two mixers and two feed-forwards in one model: dense + KDA, experts + KDA, experts + latent)
+    assert len({cfg.layer(i) for i in range(cfg.num_layers)}) <= (3 if "trinity" in name or "kimi" in name else 2)
 
 
 @pytest.mark.parametrize("name, counted, step_counters", [

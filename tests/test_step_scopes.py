@@ -96,10 +96,12 @@ LINEAR_LAYER_SCOPES = ("linear_attn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "
 
 # the q/k norms and the output norms of a block of four norms: ``test_the_gated_four_norm_blocks_scopes`` below
 GATED_BLOCK_SCOPES = ("qk_norm", "out_norm")
+# what a Kimi Delta Attention mixer has and the other linear mixer has not: ``test_the_kda_mixers_scopes`` below
+KDA_SCOPES = ("kda_gates",)
 
 
 @pytest.mark.parametrize(
-    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES])
+    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES + KDA_SCOPES])
 def test_every_scope_of_the_vocabulary_is_named(paths, name):
     want = re.compile(r"^layer\d+$") if name == "layer" else re.compile(f"^{name}$")
     assert any(want.match(c) for p in paths for c in _components(p)), name
@@ -208,3 +210,19 @@ def test_the_gated_four_norm_blocks_scopes():
     assert not any("out_norm" in p for p in qwen) and not any(p.startswith("layer0/") and "qk_norm" in p for p in qwen)
     tiny = _scoped_forward("tiny")
     assert not any("qk_norm" in p or "out_norm" in p for p in tiny) and any(p.startswith("layer0/attn/attn_in") for p in tiny)
+
+
+def test_the_kda_mixers_scopes():
+    """A Kimi Delta Attention mixer (``tiny_kimi_linear``) stands under ``linear_attn`` with the same names for the
+    same parts as the other linear mixer (``gdn_conv``, ``gdn_scan``, ``gdn_gate_norm``) and ONE more, ``kda_gates``,
+    around what that mixer does not have (the low-rank products, the softplus); its latent layer keeps ``attn``, and
+    its expert layers' scopes are the expert layers'. Qwen3-Next's linear layers have no ``kda_gates``."""
+    paths = _scoped_forward("tiny_kimi_linear")
+    for layer in (0, 1, 2, 4):
+        for inside in ("kda_gates", "gdn_conv", "gdn_scan", "gdn_gate_norm"):
+            assert any(p.startswith(f"layer{layer}/linear_attn/{inside}") for p in paths), (layer, inside)
+        assert not any(p.startswith(f"layer{layer}/attn") for p in paths)
+    assert any(p.startswith("layer3/attn") for p in paths) and not any(p.startswith("layer3/linear_attn") for p in paths)
+    assert any(p.startswith("layer1/mlp/router") for p in paths) and any(p.startswith("layer1/mlp/shared_expert") for p in paths)
+    assert not any(p.startswith("layer0/mlp/router") for p in paths)  # the leading dense layer
+    assert not any("kda_gates" in p for p in _scoped_forward("tiny_qwen3_next"))

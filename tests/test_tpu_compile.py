@@ -509,6 +509,57 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
         assert sum(x["bytes"] for x in found.values()) <= 1.2 * landed, found
 
 
+def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
+    """One period of Kimi-Linear-48B-A3B at its published widths (Kimi Delta Attention, KDA, latent attention without
+    rope, KDA; this chip's share: 8 of 256 experts, an eighth of the vocabulary), every parameter trained but the
+    selection bias, the cell's 2 rows of 8192 a microbatch, two microbatches. The compiler's own count stays under the
+    cell's memory line (15.0 GiB for the five layers: these four hold 0.1 G of state less); the latent layer takes the
+    RESIDENT flash kernels at q/k 192 against v 128, one query a kv head, on a row of 8192 (``dispatch_summary()`` says
+    which set), its forward kernel once (``o`` and ``lse`` kept); each KDA layer's rule is the XLA form with a decay a
+    channel (no rule kernel in the step, XLA's triangular solve is) between the two fused passes' kernels, the out
+    pass with its sigmoid gate; ``kda_gates`` is on the step's operations; ``CALLS`` names the form."""
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+    from llm_fine_tune_distributed_tpu.ops import gated_delta
+    from llm_fine_tune_distributed_tpu.ops.attention import dispatch_summary
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(gated_delta, "CALLS", {})
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "kimi_linear_48b_a3b",
+        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=2, param_dtype="bfloat16",
+        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
+        model_overrides=dict(num_layers=4, first_k_dense_replace=0, vocab_size=20480, held_experts=tuple(range(8)),
+                             layer_types=("linear_attention", "linear_attention", "full_attention", "linear_attention")),
+    )
+    state = setup.state.replace(opt_state=jax.tree.map(  # Adam's moments float32, as the cell holds them
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+        setup.state.opt_state))
+    compiled = dataclasses.replace(setup, state=state).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.0 * 2**30
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    mosaic_calls = lambda kernel: sum(f"/{kernel}/" in line for line in calls)  # noqa: E731
+    for kernel in ("fwd", "dq", "dkv"):
+        assert mosaic_calls(f"flash_attention_{kernel}") == 1, kernel  # the resident set, the forward kernel kept
+        assert mosaic_calls(f"flash_attention_causal_{kernel}") == 0, kernel
+    assert "resident causal" in dispatch_summary()
+    assert mosaic_calls("gdn_rule_fwd") == 0 and "triangular" in text.lower()
+    passes = sorted(re.findall(
+        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/(gdn_conv|gdn_gate_norm)/'
+        r'jit\((gdn_(?:in|out)_\w+)\)/\4/pallas_call"', "\n".join(calls)))
+    assert passes == sorted(
+        found for i in (0, 1, 3) for scope, way in (("gdn_conv", "in"), ("gdn_gate_norm", "out")) for found in (
+            (f"jvp(layer{i})", "", scope, f"gdn_{way}_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", scope, f"gdn_{way}_fwd"),
+            (f"transpose(jvp(layer{i}))", "", scope, f"gdn_{way}_bwd"))), passes
+    names = re.findall(r'op_name="([^"]+)"', text)
+    for inside in ("linear_attn/kda_gates", "linear_attn/gdn_scan", "attn/"):
+        assert any(f"/{inside}" in name for name in names), inside
+    assert "jit(gmm)" in text, "no grouped product kernel in the step"
+    assert {form for _, form in gated_delta.CALLS.values()} == {
+        "chunked 64, a decay a channel in sub-blocks of 16: xla (no kernels for it yet)"}
+    assert set(gated_delta.CALLS) == {(2, 8192, 32, 32, 128, 128, "by channel")}
+
+
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
     """Mosaic kernels cannot be partitioned by GSPMD: on the default fsdp=4
     mesh the dispatcher must run the kernel per shard under a shard_map
