@@ -26,7 +26,11 @@ the kernels' distance from the XLA form; then the whole mixer (``models/transfor
 passes, the rule), forward+backward to its input and every leaf, with the passes in either form (the rule in the form
 the backend gives it).
 
-    chiprun -- python benchmarks/gdn_kernels.py [--only rule|mixer|inverse] [--inverse solve kernels]
+``--only kda``: the rule with a decay a CHANNEL (Kimi Delta Attention) at the Kimi cell's shapes (2 rows of 8192, 32
+heads of 128), the XLA form (``_rule_xla_by_channel``) beside the two Pallas sweeps (``kda_rule_fwd``,
+``kda_rule_bwd``) and the choices their builder weighed (``kda_variants``).
+
+    chiprun -- python benchmarks/gdn_kernels.py [--only rule|kda|mixer|inverse] [--inverse solve kernels]
 """
 import argparse
 import functools
@@ -136,6 +140,67 @@ def rule_variants(args, small):
         print(json.dumps(line), flush=True)
         jax.clear_caches()
     gd.unit_lower_inverse = solve
+
+
+def kda_inputs(rows, seq, h, d, dtype):
+    """The rule's inputs with a decay a CHANNEL, as the Kimi cell draws it (as many key heads as value heads)."""
+    q, k, v, _, beta = inputs(rows, seq, h, h, d, dtype)
+    g = -jnp.exp(jax.random.uniform(jax.random.key(7), (rows, seq, h, d), minval=jnp.log(1e-3), maxval=jnp.log(1.6)))
+    return q, k, v, g, beta
+
+
+def kda_variants(args, small):
+    """The rule with a decay a channel at the Kimi cell's shapes (2 rows of 8192, 32 heads of 128): the XLA form
+    (``_rule_xla_by_channel``) beside the two sweeps, and the sweeps by what the builder weighed (PR 43): chunks of a
+    group side by side (``GROUP`` 4, the landed one, or 2), g summed from each sub-block's start inside the kernels
+    (landed) or by XLA outside them. Each with its distance from the token-by-token rule in float32 at 512 tokens and,
+    on the timed inputs, from the XLA form in output and every cotangent."""
+    from benchmarks.chipbench import reference_kda_moe
+
+    rows, seq, h, d = (1, 256, 2, 16) if small else (args.rows, args.seq, 32, 128)
+    check = kda_inputs(1, 256 if small else 512, 2 if small else 4, d, jnp.float32)
+    want = reference_gdn_moe._highest(reference_kda_moe.delta_rule)(*check)
+    x = kda_inputs(rows, seq, h, d, jnp.bfloat16)
+    inside, group = gd._from_sub_block_start, gd.GROUP
+
+    def summed_outside(q, k, v, g, beta, impl):
+        b, s = g.shape[:2]
+        (g,), s = gd._padded_rows((g,), gd.SUB)
+        local = jnp.cumsum(g.reshape(b, -1, gd.SUB, *g.shape[2:]), axis=2).reshape(g.shape)[:, :s]
+        return gd.gated_delta_rule(q, k, v, local, beta, impl=impl)
+
+    xla = lambda *a: gd.gated_delta_rule(*a, impl="xla")  # noqa: E731
+    loss = lambda rule: jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4)))  # noqa: E731
+    held_to = None
+    variants = [("xla", group, inside)] + ([] if small else [("kernels", 4, inside), ("kernels", 2, inside), ("kernels, local summed outside", 4, None)])
+    for name, chunks_side_by_side, sums in variants:
+        gd.GROUP, gd._from_sub_block_start = chunks_side_by_side, sums or (lambda g, against_time=False: g)
+        impl = name.split(",")[0]
+        rule = (lambda *a: gd.gated_delta_rule(*a, impl=impl)) if sums else (lambda *a: summed_outside(*a, impl))  # noqa: E731,B023
+        fwd, both = jax.jit(rule), loss(rule)
+        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "heads": h, "rule": "a decay a channel", "form": name,
+                "group": gd.GROUP}
+        try:
+            got = fwd(*check)
+            line["rel_err_f32_512"] = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+            line["fwd_ms"] = round(timed(fwd, x, args.iters), 3)
+            line["fwd_bwd_ms"] = round(timed(both, x, args.iters), 3)
+            if impl == "xla":
+                held_to = (fwd(*x), both(*x))
+            else:
+                jax.clear_caches()
+                t0 = time.perf_counter()
+                lowered = both.lower(*x)
+                line["trace_lower_s"] = round(time.perf_counter() - t0, 2)
+                line["programs"] = mosaic_programs(lowered.as_text())
+                line["rel_to_xla_fwd"] = rel(fwd(*x), held_to[0])
+                line["rel_to_xla_grads"] = {n: rel(a, b) for n, a, b in zip("q k v g beta".split(), both(*x), held_to[1])}
+        except Exception as e:  # a refusal (memory, a shape) is the reading
+            line["refused"] = str(e).split("\n")[0][:300]
+        print(json.dumps(line), flush=True)
+        jax.clear_caches()
+        gd._flat_rule.cache_clear()
+    gd._from_sub_block_start, gd.GROUP = inside, group
 
 
 def rel(got, want):
@@ -248,7 +313,7 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--rows", type=int, default=2)
     ap.add_argument("--seq", type=int, default=8192)
-    ap.add_argument("--only", choices=("rule", "mixer", "inverse"))
+    ap.add_argument("--only", choices=("rule", "kda", "mixer", "inverse"))
     ap.add_argument("--inverse", nargs="*", help="of the rule's variants, only these (solve HIGHEST HIGH DEFAULT kernels)")
     args = ap.parse_args(argv)
     small = not on_accelerator(jax.devices()[0].platform)  # a CPU rehearsal of the control flow: its numbers are not rates
@@ -256,6 +321,8 @@ def main(argv=None) -> int:
         inverse_alone(args, small)
     if args.only in (None, "rule"):
         rule_variants(args, small)
+    if args.only in (None, "kda"):
+        kda_variants(args, small)
     if args.only in (None, "mixer"):
         mixer_variants(args, small)
     return 0

@@ -58,8 +58,10 @@ STEPS = {
     "trinity-mini-26b-a3b-ep8-d5.8k-1row": (*_TRINITY, 1, 4, 8192, _ALL),
     "trinity-mini-26b-a3b-ep8-d5.8k-4rows": (*_TRINITY, 4, 1, 8192, _ALL),
     "kimi-linear-48b-a3b-ep32-d5.sft-8k-kda-mla-allparams": (*_KIMI, 2, 2, 8192, _ALL),
-    # the same at 1 row (2 x 2 if the compiler's count stays under 15.0 GiB, else 1 x 4: ISSUE 42)
+    # the same at 1 row (2 x 2 if the compiler's count stays under 15.0 GiB, else 1 x 4: ISSUE 42) and at 4 (ISSUE 43:
+    # for the benchmark issue that may change the cell's microbatch now that the rule's kernels hold no chunk in HBM)
     "kimi-linear-48b-a3b-ep32-d5.8k-1row": (*_KIMI, 1, 4, 8192, _ALL),
+    "kimi-linear-48b-a3b-ep32-d5.8k-4rows": (*_KIMI, 4, 1, 8192, _ALL),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),

@@ -50,6 +50,14 @@ same thing in the same dtypes:
   backend, another chunk): XLA operations (``_rule_xla``), which is also what
   the tests hold the kernels to.
 
+The decay is a scalar a head and token there (``g [b, s, value heads]``, Gated
+DeltaNet). Kimi Delta Attention decays every CHANNEL of a head apart (``g [b, s,
+value heads, d_k]``: ``S = diag(exp(g_t)) S``), which ``gated_delta_rule`` reads
+from ``g``'s rank; that rule has the same two forms under the same conditions,
+``kda_rule_fwd`` / ``kda_rule_bwd`` and ``_rule_xla_by_channel`` (their sections'
+comments: what a vector decay does to a chunk), and shares the walk, the inverse
+and the ``custom_vjp`` with the scalar one.
+
 The chunked form:
 
 - a row is cut into chunks of ``CHUNK`` tokens; inside a chunk, with ``G_i``
@@ -292,8 +300,8 @@ def _rule_xla(q, k, v, g, beta, *, chunk: int = CHUNK):
 # Both in float32 (the products at HIGHEST: their operands are k times a decay, which bfloat16 would round a second
 # time before the triangular inverse sees them), inside ONE ``jax.checkpoint``: its backward pass makes the decayed
 # operands again, so none of them outlives the few operations that read it, and the pairwise block is never written
-# at all (``_own_products``: of all chunks at once it would be 4 GiB a call at the Kimi cell's shapes). XLA operations
-# only: kernels are the next step (PERF.md), and ``CALLS`` says so.
+# at all (``_own_products``: of all chunks at once it would be 4 GiB a call at the Kimi cell's shapes). This is the
+# form of every backend but a TPU at heads of whole lanes, and what the kernels further down are held to.
 
 SUB = 16
 
@@ -871,50 +879,394 @@ def gdn_rule_bwd(q, k, v, cum, beta, do, states, *, hk, rs, interpret):
     )(q, k, v, cum, beta, do, states)
 
 
+# -- the rule with a decay a channel as Pallas kernels (TPU) ------------------------
+#
+# ``_rule_xla_by_channel`` line by line, a chunk at a time in VMEM: the same two sweeps behind one ``custom_vjp`` as
+# the scalar rule's, the same walk (``_in_step`` over a group's chunks, ``_over_groups`` over a grid step's groups,
+# the state ``[d_k, d_v]`` in scratch along the sequential grid axis, the state each step starts from kept for the
+# backward sweep), the same inverse (``_inverse_in_vmem``). What differs is what a chunk is before the state reaches
+# it, ``_kda_alone``: the decay does not leave the products, so ``k k^T`` and ``q k^T`` are made from sub-blocks of
+# ``SUB`` tokens as ``_decayed_products`` makes them (against an earlier sub-block a float32 product at HIGHEST of
+# operands decayed towards the boundary between them; against itself the ``[SUB, SUB, d_k]`` pairwise block, which
+# lives in vector registers and is summed over its channels at once), the walk's operands ``Q exp(G)``, ``K exp(G_C -
+# G)``, ``beta K exp(G)`` are decayed a channel, and ``exp(G_C)`` scales the state's ROWS. Nothing is shared between
+# heads (each value head decays apart), so a grid step holds ONE value head and every matrix of a chunk is ``[C, C]``:
+# two heads stacked into ``[2 C, 2 C]`` matrices of diagonal blocks, as the scalar kernels stack them, would double the
+# float32 sub-block products for blocks that are zero. q, k, v, o and g (float32, ``[b, s, value heads x d_k]``, as
+# ``kda_gates`` makes it) are read and written where they lie; g is summed from each sub-block's start in VMEM (four
+# shifted adds along sublanes), beta comes as rows like the scalar kernels'.
+
+_SUBS = CHUNK // SUB
+
+
+def _mm32_over(x, y, axis_x, axis_y):
+    """``_mm32`` with the contraction over ``axis_x`` of x and ``axis_y`` of y: ``x y^T`` at (1, 1), ``x^T y`` at (0, 0)."""
+    return jax.lax.dot_general(x, y, (((axis_x,), (axis_y,)), ((), ())), preferred_element_type=_F32, precision=jax.lax.Precision.HIGHEST)
+
+
+def _from_sub_block_start(g, against_time=False):
+    """``g [C, d_k]`` summed along the tokens of each sub-block from its start (``against_time``: to its end, what the
+    sum's cotangent takes): ``log2(SUB)`` shifted adds."""
+    at = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0) & (SUB - 1)
+    s = 1
+    while s < SUB:
+        g = g + (jnp.where(at < SUB - s, pltpu.roll(g, CHUNK - s, 0), 0.0) if against_time
+                 else jnp.where(at >= s, pltpu.roll(g, s, 0), 0.0))
+        s *= 2
+    return g
+
+
+def _sub(x, i):
+    return x[i * SUB:(i + 1) * SUB]
+
+
+def _kda_decays(g, q, k):
+    """What hangs on a chunk's g alone, and q and k in float32: ``local`` (the running sum from each sub-block's
+    start), ``before`` (at the last token before each sub-block, from the chunk's start: ``[1, d_k]`` each), ``cum``
+    (at every token), ``last`` (at the chunk's end), their ``exp``s (``whole``, ``exp(G_C)``, as a row and as the column
+    that scales the state's rows), and the pairwise block's mask."""
+    local = _from_sub_block_start(g)
+    before = [jnp.zeros_like(local[:1])]
+    for i in range(_SUBS):
+        before.append(before[-1] + _sub(local, i)[SUB - 1:])
+    cum = jnp.concatenate([_sub(local, i) + before[i] for i in range(_SUBS)], axis=0)
+    last = before.pop()
+    shape = (SUB, SUB, g.shape[1])
+    rk, ck = _iotas(g.shape[1])
+    whole = jnp.exp(last)
+    return dict(local=local, before=before, cum=cum, last=last, e=jnp.exp(cum), rest=jnp.exp(last - cum), whole_row=whole,
+                eye_k=rk == ck, whole=_col(whole, rk == ck), q32=q.astype(_F32),
+                k32=k.astype(_F32), at=jax.lax.broadcasted_iota(jnp.int32, g.shape, 0),
+                earlier=jax.lax.broadcasted_iota(jnp.int32, shape, 1) <= jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+
+
+def _pairwise(x, i):
+    """``exp(G_a - G_b)`` for ``b <= a`` inside sub-block ``i``, 0 above: ``[SUB (a), SUB (b), d_k]``, masked before the
+    ``exp`` (``_pairwise_decay``)."""
+    local = _sub(x["local"], i)
+    return jnp.exp(jnp.where(x["earlier"], local[:, None, :] - local[None, :, :], -jnp.inf))
+
+
+def _towards_boundary(x, i):
+    """Sub-block ``i``'s two operands of its product against every earlier token (``_decayed_products``): k and q of
+    the sub-block decayed from the boundary before it ``[2 SUB, d_k]``, and every EARLIER token's k decayed up to that
+    boundary ``[C, d_k]`` (0 from the sub-block on), with the two decays."""
+    e_in = jnp.exp(_sub(x["local"], i))
+    f = jnp.exp(jnp.where(x["at"] < i * SUB, x["before"][i] - x["cum"], -jnp.inf))
+    return jnp.concatenate([_sub(x["k32"], i) * e_in, _sub(x["q32"], i) * e_in], axis=0), x["k32"] * f, e_in, f
+
+
+def _kda_products(x):
+    """The decayed ``k k^T`` and ``q k^T`` of a chunk, ``[C, C]`` float32 each, ``j <= i`` (0 above): what
+    ``_decayed_products`` computes (a generator, ``_in_step``)."""
+    rows = []
+    zeros = lambda n: [jnp.zeros((SUB, n * SUB), _F32)] if n else []  # noqa: E731
+    for i in range(_SUBS):
+        k_i, q_i = _sub(x["k32"], i), _sub(x["q32"], i)
+        k_decayed = k_i[None, :, :] * _pairwise(x, i)
+        own = [jnp.sum(z[:, None, :] * k_decayed, axis=2) for z in (k_i, q_i)]                     # [SUB, SUB] each
+        own = jnp.concatenate([jnp.concatenate(zeros(i) + [z] + zeros(_SUBS - 1 - i), axis=1) for z in own], axis=0)  # [2 SUB, C]: in its columns
+        if i:
+            x_in, k_out, _, _ = _towards_boundary(x, i)
+            own = own + _mm32_over(x_in, k_out, 1, 1)
+            yield
+        rows.append(own)
+    return jnp.concatenate([z[:SUB] for z in rows], axis=0), jnp.concatenate([z[SUB:] for z in rows], axis=0)
+
+
+def _kda_products_back(x, dkk, dqk):
+    """``_kda_products``'s backward pass from the cotangents of both products (0 above the diagonal): ``(dq, dk, the
+    cotangents of local and of cum [C, d_k], of before [1, d_k] a sub-block)``, float32. The pairwise block is made
+    again and its cotangent never: ``_own_products_bwd``'s identities (a generator, ``_in_step``)."""
+    dq, dk, dlocal, dbefore = [], [], [], []
+    dk_earlier = dcum = jnp.zeros_like(x["k32"])
+    for i in range(_SUBS):
+        k_i, q_i = _sub(x["k32"], i), _sub(x["q32"], i)
+        d_k, d_q = (_sub(z, i)[:, i * SUB:(i + 1) * SUB][:, :, None] for z in (dkk, dqk))         # [SUB (a), SUB (b), 1]
+        decay = _pairwise(x, i)
+        k_decayed = k_i[None, :, :] * decay
+        dx_k, dx_q = jnp.sum(d_k * k_decayed, axis=1), jnp.sum(d_q * k_decayed, axis=1)            # [SUB (a), d_k]
+        dk_b = jnp.sum((d_k * k_i[:, None, :] + d_q * q_i[:, None, :]) * decay, axis=0)            # [SUB (b), d_k]
+        dl_i = k_i * dx_k + q_i * dx_q - k_i * dk_b
+        dk_i, dq_i = dx_k + dk_b, dx_q
+        if i:
+            x_in, k_out, e_in, f = _towards_boundary(x, i)
+            d_both = jnp.concatenate([_sub(dkk, i), _sub(dqk, i)], axis=0)                         # [2 SUB, C]
+            dx_in = _mm32(d_both, k_out)
+            dk_out = _mm32_over(d_both, x_in, 0, 0)
+            yield
+            dl_i = dl_i + x_in[:SUB] * dx_in[:SUB] + x_in[SUB:] * dx_in[SUB:]
+            dk_i, dq_i = dk_i + dx_in[:SUB] * e_in, dq_i + dx_in[SUB:] * e_in
+            dk_earlier = dk_earlier + dk_out * f
+            through = k_out * dk_out
+            dcum = dcum - through
+            dbefore.append(jnp.sum(through, axis=0, keepdims=True))
+        else:
+            dbefore.append(jnp.zeros_like(x["last"]))
+        dq.append(dq_i)
+        dk.append(dk_i)
+        dlocal.append(dl_i)
+    return jnp.concatenate(dq, axis=0), jnp.concatenate(dk, axis=0) + dk_earlier, jnp.concatenate(dlocal, axis=0), dcum, dbefore
+
+
+def _kda_alone(q, k, v, g, b_ref, c, with_output=True):
+    """What a chunk of ONE head is before any state reaches it (a generator, ``_in_step``): ``T``, ``U``, ``W``, the
+    decayed ``k k^T`` and ``q k^T``, ``(K exp(G_C - G))^T``, ``Q exp(G)`` and ``exp(G_C)`` as a column. Line by line
+    what ``_rule_xla_by_channel`` computes for a row's chunks at once, in its dtypes."""
+    cd = k.dtype
+    ri, ci = _iotas(CHUNK)
+    bc = _col(b_ref[0, 0, pl.ds(c, 1), :], ri == ci)
+    x = _kda_decays(g, q, k)
+    kk, qk = yield from _kda_products(x)
+    k_t = _transposed((x["k32"] * x["rest"]).astype(cd))
+    yield
+    t = yield from _inverse_in_vmem(jnp.where(ri > ci, bc * kk, 0.0), ri, ci)
+    tc = t.astype(cd)
+    return dict(x, t=t, kk=kk, u=_dot(tc, v * bc.astype(cd)).astype(cd), w=_dot(tc, (x["k32"] * (bc * x["e"])).astype(cd)).astype(cd),
+                p=jnp.where(ri >= ci, qk, 0.0).astype(cd), k_t=k_t, q_e=(x["q32"] * x["e"]).astype(cd) if with_output else None)
+
+
+def _kda_through(alone, s, state_dtype):
+    """``_chunk_through`` for a decay a channel: ``exp(G_C)`` scales the state's rows, and k comes decayed."""
+    cd = alone["u"].dtype
+    d = alone["u"].astype(_F32) - _dot(alone["w"], s.astype(cd))
+    return (alone["whole"] * s.astype(_F32) + _dot(alone["k_t"], d.astype(cd))).astype(state_dtype), d
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, state):
+    """A grid step of the forward sweep, as ``_fwd_kernel``: one value head's ``STEP_CHUNKS`` chunks."""
+    cd = v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    s0_ref[0, 0, 0] = state[...]
+
+    def group(first, s):
+        chunks = [first + j for j in range(GROUP)]
+        alone = _in_step(_kda_alone(q_ref[0, _rows(c), :], k_ref[0, _rows(c), :], v_ref[0, _rows(c), :], g_ref[0, _rows(c), :], b_ref, c)
+                         for c in chunks)
+        walked = []
+        for x in alone:
+            of_state = _dot(x["q_e"], s.astype(cd))                   # (Q exp(G)) S, beside the walk
+            s, d = _kda_through(x, s, state.dtype)
+            walked.append((of_state, d))
+        for c, x, (of_state, d) in zip(chunks, alone, walked):
+            o_ref[0, _rows(c), :] = (of_state + _dot(x["p"], d.astype(cd))).astype(o_ref.dtype)
+        return s
+
+    state[...] = _over_groups(group, state[...])
+
+
+def _kda_backward_alone(q, k, v, do, g, b_ref, c, s, d, w, p):
+    """The backward sweep's chunk before the state's cotangent reaches it (a generator, ``_in_step``)."""
+    cd = k.dtype
+    ri, ci = _iotas(CHUNK)
+    x = _kda_decays(g, q, k)
+    sc, dc = s.astype(cd), d.astype(cd)
+    q_e, k_rest = (x["q32"] * x["e"]).astype(cd), (x["k32"] * x["rest"]).astype(cd)
+    yield
+    return dict(
+        x, ri=ri, ci=ci, eye=ri == ci, bc=_col(b_ref[0, 0, pl.ds(c, 1), :], ri == ci), sc=sc, k_rest=k_rest, w_t=_transposed(w),
+        dq_e=_dot_nt(do, sc),                                          # through O = (Q exp(G)) S + P D
+        ds=_dot(_transposed(q_e), do),
+        dp=jnp.where(ri >= ci, _dot_nt(do, dc), 0.0),
+        dd=_dot(_transposed(p), do),
+        to_state=dc,
+    )
+
+
+def _kda_backward_through(x, s, ds_next):
+    """The state's cotangent through the chunk (``_chunk_backward_through``): two dependent products."""
+    cd = x["sc"].dtype
+    dsc = ds_next.astype(cd)
+    dd = x["dd"] + _dot(x["k_rest"], dsc)                               # S' = exp(G_C) S + (K exp(G_C - G))^T D
+    ddc = dd.astype(cd)
+    ds = x["whole"] * ds_next + x["ds"] - _dot(x["w_t"], ddc)           # D = U - W S
+    d_whole = _row(_row_sum(ds_next * s.astype(_F32)), x["eye_k"])      # [1, d_k]
+    return ds, dict(ddc=ddc, d_whole=d_whole, dk_rest=_dot_nt(x["to_state"], dsc))
+
+
+def _kda_backward_rest(x, y, v, t, kk):
+    """The rest of the chunk's backward pass (a generator, ``_in_step``): ``(dq, dk [C, d_k], dv [C, d_v], dg [C,
+    d_k], the cotangent of beta as a row [1, C])``, float32."""
+    cd = v.dtype
+    ri, ci, bc, e, rest, k32, q32, ddc = x["ri"], x["ci"], x["bc"], x["e"], x["rest"], x["k32"], x["q32"], y["ddc"]
+    # D = U - W S;  U = T (beta v), W = T (beta exp(G) k)
+    dwc = (-_dot_nt(ddc, x["sc"])).astype(cd)
+    tt = t.T
+    ttc = tt.astype(cd)
+    v_beta, k_beta = v * bc.astype(cd), (k32 * (bc * e)).astype(cd)
+    dt = _dot_nt(ddc, v_beta) + _dot_nt(dwc, k_beta)
+    d_vb, d_kb = _dot(ttc, ddc), _dot(ttc, dwc)
+    yield
+    # T = (I + A)^-1:  dA = -T^T dT T^T
+    da = _mm32(tt, dt)
+    yield
+    da = jnp.where(ri > ci, -_mm32(da, tt), 0.0)
+    yield
+    # A = beta (k k^T) below the diagonal, P = q k^T from the diagonal down
+    dq, dk, dlocal, dcum, dbefore = yield from _kda_products_back(x, bc * da, x["dp"])
+    db = _row_sum(da * kk) + _row_sum(d_vb * v.astype(_F32)) + _row_sum(d_kb * k32 * e)
+    d_rest = k32 * y["dk_rest"]
+    d_e = bc * k32 * d_kb + q32 * x["dq_e"]
+    dq = dq + e * x["dq_e"]
+    dk = dk + bc * e * d_kb + rest * y["dk_rest"]
+    dcum = dcum + e * d_e - rest * d_rest
+    d_last = jnp.sum(rest * d_rest, axis=0, keepdims=True) + x["whole_row"] * y["d_whole"]
+    dcum = dcum + jnp.where(x["at"] == CHUNK - 1, d_last, 0.0)
+    # cum = before + local, before a sub-block = the sum of the earlier sub-blocks' last local
+    dlocal = dlocal + dcum
+    later = jnp.zeros_like(d_last)
+    for i in reversed(range(1, _SUBS)):
+        later = later + dbefore[i] + jnp.sum(_sub(dcum, i), axis=0, keepdims=True)
+        dlocal = dlocal + jnp.where(x["at"] == i * SUB - 1, later, 0.0)
+    return dq, dk, bc * d_vb, _from_sub_block_start(dlocal, against_time=True), _row(db, x["eye"])
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                    ds_ref, s_at, d_at, t_at, w_at, kk_at, p_at):
+    """A grid step of the backward sweep, as ``_bwd_kernel``: the step's chunks forward once more from the kept state
+    (each chunk's starting state, ``D``, ``T``, ``W`` and its two decayed products left in scratch), then the groups
+    against time with the state's cotangent carried."""
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    def inputs(chunks):
+        return [[ref[0, _rows(c), :] for c in chunks] for ref in (q_ref, k_ref, v_ref, g_ref)]
+
+    def forward(first, s):
+        chunks = [first + j for j in range(GROUP)]
+        q, k, v, g = inputs(chunks)
+        alone = _in_step(_kda_alone(q[j], k[j], v[j], g[j], b_ref, c, with_output=False) for j, c in enumerate(chunks))
+        for c, x in zip(chunks, alone):
+            s_at[c] = s
+            s, d = _kda_through(x, s, s.dtype)
+            d_at[c], t_at[c], w_at[c], kk_at[c], p_at[c] = d, x["t"], x["w"], x["kk"], x["p"]
+        return s
+
+    _over_groups(forward, s0_ref[0, 0, 0])
+
+    def backward(first, ds):
+        chunks = [first + j for j in range(GROUP)]
+        q, k, v, g = inputs(chunks)
+        back = _in_step(_kda_backward_alone(q[j], k[j], v[j], do_ref[0, _rows(c), :], g[j], b_ref, c, s_at[c], d_at[c], w_at[c], p_at[c])
+                        for j, c in enumerate(chunks))
+        through = [None] * GROUP
+        for j in reversed(range(GROUP)):
+            ds, through[j] = _kda_backward_through(back[j], s_at[chunks[j]], ds)
+        out = _in_step(_kda_backward_rest(back[j], through[j], v[j], t_at[c], kk_at[c]) for j, c in enumerate(chunks))
+        for c, (dq, dk, dv, dg, db) in zip(chunks, out):
+            dq_ref[0, _rows(c), :] = dq.astype(dq_ref.dtype)
+            dk_ref[0, _rows(c), :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, _rows(c), :] = dv.astype(dv_ref.dtype)
+            dg_ref[0, _rows(c), :] = dg
+            db_ref[0, 0, pl.ds(c, 1), :] = db
+        return ds
+
+    ds_ref[...] = _over_groups(backward, ds_ref[...], against_time=True)
+
+
+def _kda_specs(r, dk, dv, at):
+    """Block specs over ``(row, value head, step)``, the step's block ``at(t)``: q or k (its key head's columns), v or
+    o, g or a cotangent a value head ``[b, s, value heads x d_k]``, beta's rows, the kept state."""
+    tokens = STEP_CHUNKS * CHUNK
+    return (pl.BlockSpec((1, tokens, dk), lambda i, j, t: (i, at(t), j // r)),
+            pl.BlockSpec((1, tokens, dv), lambda i, j, t: (i, at(t), j)),
+            pl.BlockSpec((1, tokens, dk), lambda i, j, t: (i, at(t), j)),
+            pl.BlockSpec((1, 1, STEP_CHUNKS, CHUNK), lambda i, j, t: (i, j, at(t), 0)),
+            pl.BlockSpec((1, 1, 1, dk, dv), lambda i, j, t: (i, j, at(t), 0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("hk", "state_dtype", "interpret"))
+def kda_rule_fwd(q, k, v, g, beta, *, hk, state_dtype, interpret):
+    """The forward sweep with a decay a channel. ``q``, ``k`` ``[b, s, hk d_k]``, ``v`` ``[b, s, hv d_v]``, ``g [b, s,
+    hv d_k]`` float32, ``beta [b, hv, s / C, C]`` -> ``o`` like v and the state each step starts from ``[b, hv,
+    steps, d_k, d_v]``."""
+    b, s, _ = q.shape
+    hv, dk = beta.shape[1], q.shape[2] // hk
+    dv, steps = v.shape[2] // hv, s // (STEP_CHUNKS * CHUNK)
+    qk, vo, gs, bs, ss = _kda_specs(hv // hk, dk, dv, lambda t: t)
+    return pl.pallas_call(
+        _kda_fwd_kernel, grid=(b, hv, steps), in_specs=[qk, qk, vo, gs, bs], out_specs=[vo, ss],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype), jax.ShapeDtypeStruct((b, hv, steps, dk, dv), state_dtype)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), state_dtype)], name="kda_rule_fwd", interpret=interpret, **_params(interpret),
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("hk", "interpret"))
+def kda_rule_bwd(q, k, v, g, beta, do, states, *, hk, interpret):
+    """The backward sweep: ``kda_rule_fwd``'s inputs, ``do`` like v and the kept states -> cotangents of q and k ``[b,
+    s, hv d_k]`` (a value head each: the caller adds a key head's), of v, of g (by channel, like g) and of beta in its
+    row form."""
+    b, s, _ = q.shape
+    hv, dk = beta.shape[1], q.shape[2] // hk
+    dv, steps = v.shape[2] // hv, s // (STEP_CHUNKS * CHUNK)
+    qk, vo, gs, bs, ss = _kda_specs(hv // hk, dk, dv, lambda t: steps - 1 - t)
+    like = jax.ShapeDtypeStruct
+    return pl.pallas_call(
+        _kda_bwd_kernel, grid=(b, hv, steps), in_specs=[qk, qk, vo, gs, bs, vo, ss], out_specs=[gs, gs, vo, gs, bs],
+        out_shape=[like(g.shape, q.dtype), like(g.shape, k.dtype), like(v.shape, v.dtype), like(g.shape, _F32), like(beta.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), _F32), pltpu.VMEM((STEP_CHUNKS, dk, dv), states.dtype),
+                        pltpu.VMEM((STEP_CHUNKS, CHUNK, dv), _F32), pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32),
+                        pltpu.VMEM((STEP_CHUNKS, CHUNK, dk), k.dtype), pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32),
+                        pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), k.dtype)],
+        name="kda_rule_bwd", interpret=interpret, **_params(interpret),
+    )(q, k, v, g, beta, do, states)
+
+
 def _rows_of(x, b, n, groups, rs):
     """``[b, n C, hv]`` -> ``[b, groups, n, rs C]``: a chunk's tokens along lanes, a group's heads side by side."""
     return jnp.transpose(x.reshape(b, n, CHUNK, groups, rs), (0, 3, 1, 4, 2)).reshape(b, groups, n, rs * CHUNK)
 
 
 @functools.lru_cache(maxsize=None)
-def _flat_rule(hk, rs, state_dtype, interpret):
-    """The two sweeps as one differentiable function of the kernels' own layouts. What the backward sweep
-    keeps besides the inputs is the state each step starts from (``rows x seq / (8 C) x value heads x d_k x
-    d_v``, an eighth of what the scan's autodiff held), which the forward sweep always writes (under a
-    block's remat the recomputed sweep's is the one kept)."""
-    static = dict(hk=hk, rs=rs, interpret=interpret)
+def _flat_rule(forward, backward, hk, state_dtype, interpret, **static):
+    """Two sweeps (the scalar decay's or the decay by channel's) as one differentiable function of the kernels' own
+    layouts. What the backward sweep keeps besides the inputs is the state each step starts from (``rows x seq / (8 C)
+    x value heads x d_k x d_v``, an eighth of what the scan's autodiff held), which the forward sweep always writes
+    (under a block's remat the recomputed sweep's is the one kept)."""
+    static = dict(static, hk=hk, interpret=interpret)
 
-    def fwd(q, k, v, cum, beta):
-        o, states = gdn_rule_fwd(q, k, v, cum, beta, state_dtype=state_dtype, **static)
-        return o, (q, k, v, cum, beta, states)
+    def fwd(q, k, v, decay, beta):
+        o, states = forward(q, k, v, decay, beta, state_dtype=state_dtype, **static)
+        return o, (q, k, v, decay, beta, states)
 
     @jax.custom_vjp
-    def rule(q, k, v, cum, beta):
-        return fwd(q, k, v, cum, beta)[0]
+    def rule(q, k, v, decay, beta):
+        return fwd(q, k, v, decay, beta)[0]
 
     def bwd(kept, do):
-        dq, dk, dv, dcum, dbeta = gdn_rule_bwd(*kept[:5], do, kept[5], **static)
+        dq, dk, dv, ddecay, dbeta = backward(*kept[:5], do, kept[5], **static)
         b, s, _ = dq.shape
         of_key_head = lambda x: x.reshape(b, s, hk, -1, kept[0].shape[2] // hk).sum(axis=3).reshape(kept[0].shape)  # noqa: E731
-        return of_key_head(dq), of_key_head(dk), dv, dcum, dbeta
+        return of_key_head(dq), of_key_head(dk), dv, ddecay, dbeta
 
     rule.defvjp(fwd, bwd)
     return rule
 
 
 def _rule_kernels(q, k, v, g, beta, *, interpret=False):
-    """The chunked rule through the kernels (``_rule_xla``'s signature at ``chunk=CHUNK``): rows padded to whole
-    steps with tokens that change nothing, heads flattened into lanes, g summed from each chunk's start and laid
-    out with beta as rows; JAX differentiates these layouts, the kernels' ``custom_vjp`` the rule."""
+    """The chunked rule through the kernels (``_rule_xla``'s and ``_rule_xla_by_channel``'s signature at
+    ``chunk=CHUNK``): rows padded to whole steps with tokens that change nothing, heads flattened into lanes, beta laid
+    out as rows; a decay a head summed from each chunk's start and laid out like beta, a decay a channel (``g`` of rank
+    4) handed over flat as it is; JAX differentiates these layouts, the kernels' ``custom_vjp`` the rule."""
     b, s, hk, _ = q.shape
     hv = v.shape[2]
-    rs = 2 if (hv // hk) % 2 == 0 else 1
     (q, k, v, g, beta), s = _padded_rows((q, k, v, g, beta), STEP_CHUNKS * CHUNK)
     n = q.shape[1] // CHUNK
-    cum = jnp.cumsum(g.astype(_F32).reshape(b, n, CHUNK, hv), axis=2).reshape(b, n * CHUNK, hv)
     flat = lambda x: x.reshape(b, n * CHUNK, -1)  # noqa: E731
-    o = _flat_rule(hk, rs, jnp.dtype(STATE_DTYPE), interpret)(
-        flat(q), flat(k), flat(v), _rows_of(cum, b, n, hv // rs, rs), _rows_of(beta.astype(_F32), b, n, hv // rs, rs))
+    if g.ndim == 4:
+        rs, sweeps, more, decay = 1, (kda_rule_fwd, kda_rule_bwd), {}, flat(g.astype(_F32))
+    else:
+        rs = 2 if (hv // hk) % 2 == 0 else 1
+        cum = jnp.cumsum(g.astype(_F32).reshape(b, n, CHUNK, hv), axis=2).reshape(b, n * CHUNK, hv)
+        sweeps, more, decay = (gdn_rule_fwd, gdn_rule_bwd), dict(rs=rs), _rows_of(cum, b, n, hv // rs, rs)
+    o = _flat_rule(*sweeps, hk, jnp.dtype(STATE_DTYPE), interpret, **more)(
+        flat(q), flat(k), flat(v), decay, _rows_of(beta.astype(_F32), b, n, hv // rs, rs))
     return o.reshape(b, n * CHUNK, hv, -1)[:, :s]
 
 
@@ -935,23 +1287,21 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, impl=None):
     the input: ``g [b, s, value heads]`` is a decay a head, and takes the
     kernels on a TPU where a head is whole lanes and the chunk is ``CHUNK``,
     the XLA form elsewhere; ``g [b, s, value heads, d_k]`` is a decay a
-    channel (``_rule_xla_by_channel``: XLA operations on every backend, it has
-    no kernels yet). ``impl`` is the tests' and the tools' handle: ``"xla"``,
+    channel, and takes its own two kernels under the same conditions and
+    ``_rule_xla_by_channel`` elsewhere. ``CALLS`` says which form a call took
+    and, on a TPU, why not the kernels. ``impl`` is the tests' and the tools' handle: ``"xla"``,
     ``"kernels"``, ``"kernels_interpret"`` (the kernels under the Pallas
     interpreter)."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    if g.ndim == 4:
-        entry = CALLS.setdefault((b, s, hk, hv, dk, dv, "by channel"), [
-            0, f"chunked {chunk}, a decay a channel in sub-blocks of {min(SUB, chunk)}: xla (no kernels for it yet)"])
-        entry[0] += 1
-        return _rule_xla_by_channel(q, k, v, g, beta, chunk=chunk)
+    by_channel = g.ndim == 4
     program = _program(chunk, d_k=dk, d_v=dv) if impl is None else impl.split("_")[0]
-    entry = CALLS.setdefault((b, s, hk, hv, dk, dv), [0, f"chunked {chunk}: {program}"])
+    form = f"chunked {chunk}, a decay a channel in sub-blocks of {min(SUB, chunk)}: {program}" if by_channel else f"chunked {chunk}: {program}"
+    entry = CALLS.setdefault((b, s, hk, hv, dk, dv) + ("by channel",) * by_channel, [0, form])
     entry[0] += 1
     if program == "kernels":
         return _rule_kernels(q, k, v, g, beta, interpret=impl == "kernels_interpret")
-    return _rule_xla(q, k, v, g, beta, chunk=chunk)
+    return (_rule_xla_by_channel if by_channel else _rule_xla)(q, k, v, g, beta, chunk=chunk)
 
 
 # -- the mixer's elementwise work as two fused passes (TPU) -----------------------

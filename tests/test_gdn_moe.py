@@ -166,6 +166,9 @@ def test_chunked_rule_equals_token_by_token(seq, a_max, program):
         assert bool(jnp.isfinite(a).all()) and _rel(a, b) < 1e-4, name
 
 
+BY_CHANNEL = "chunked 64, a decay a channel in sub-blocks of 16"
+
+
 @pytest.mark.parametrize("backend, shape, chunk, form, passes", [
     ("tpu", (2, 128, 16, 32, 128, 128), 64, "chunked 64: kernels", ("kernels", "kernels")),
     ("tpu", (2, 100, 2, 2, 256, 128), 64, "chunked 64: kernels", ("kernels", "kernels")),
@@ -175,20 +178,32 @@ def test_chunked_rule_equals_token_by_token(seq, a_max, program):
      ("kernels", "xla (d_v 64 is no multiple of 128)")),  # in: a key head's two value heads fill 128 lanes
     ("tpu", (2, 128, 2, 4, 128, 128), 32, "chunked 32: xla (chunk 32 is not 64)", ("kernels", "kernels")),
     ("cpu", (2, 128, 2, 4, 128, 128), 64, "chunked 64: xla", ("xla", "xla")),
-], ids=["cell", "wide-keys", "narrow-keys", "narrow-values", "other-chunk", "cpu"])
+    # g of rank 4, a decay a CHANNEL (Kimi Delta Attention; PR 43): the same questions, its own two sweeps
+    ("tpu", (2, 8192, 32, 32, 128, 128, "by channel"), 64, f"{BY_CHANNEL}: kernels", ("kernels", "kernels")),
+    ("tpu", (2, 100, 2, 4, 128, 128, "by channel"), 64, f"{BY_CHANNEL}: kernels", ("kernels", "kernels")),
+    ("tpu", (2, 128, 2, 2, 16, 16, "by channel"), 64, f"{BY_CHANNEL}: xla (d_k 16 is no multiple of 128)",
+     ("xla (d_k 16 is no multiple of 128)", "xla (d_v 16 is no multiple of 128)")),
+    ("tpu", (2, 128, 2, 2, 128, 128, "by channel"), 32, "chunked 32, a decay a channel in sub-blocks of 16: xla (chunk 32 is not 64)",
+     ("kernels", "kernels")),
+    ("cpu", (2, 128, 2, 2, 128, 128, "by channel"), 64, f"{BY_CHANNEL}: xla", ("xla", "xla")),
+], ids=["cell", "wide-keys", "narrow-keys", "narrow-values", "other-chunk", "cpu",
+        "by-channel-cell", "by-channel-shared-keys", "by-channel-narrow", "by-channel-other-chunk", "by-channel-cpu"])
 def test_which_program_takes_the_rule_is_read_from_the_input(monkeypatch, backend, shape, chunk, form, passes):
     """No knob: the kernels on a TPU where a head is whole lanes and the chunk is
     64, the XLA form elsewhere, and ``CALLS`` says which and, on a TPU, why not
     (every form starts ``chunked``, what ``gdn_chunked_calls_pct`` reads). The
     mixer's two passes around the rule read the same (the chunk is not theirs)
-    and say it in a dict of their own, ``PASSES``: ``CALLS`` is the rule's."""
+    and say it in a dict of their own, ``PASSES``: ``CALLS`` is the rule's.
+    Which RULE it is is read from ``g``'s rank: a decay a channel is counted
+    under a key of its own and takes its own kernels under the same conditions."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(gated_delta, "CALLS", {})
     monkeypatch.setattr(gated_delta, "PASSES", {})
-    rows, seq, hk, hv, dk, dv = shape
+    rows, seq, hk, hv, dk, dv = shape[:6]
     like = lambda *x: jax.ShapeDtypeStruct(x, jnp.bfloat16)  # noqa: E731
+    g = jax.ShapeDtypeStruct((rows, seq, hv) + (dk,) * (len(shape) == 7), jnp.float32)
     out = jax.eval_shape(lambda *a: gated_delta.gated_delta_rule(*a, chunk=chunk), like(rows, seq, hk, dk), like(rows, seq, hk, dk),
-                         like(rows, seq, hv, dv), like(rows, seq, hv), like(rows, seq, hv))
+                         like(rows, seq, hv, dv), g, like(rows, seq, hv))
     assert out.shape == (rows, seq, hv, dv) and out.dtype == jnp.bfloat16
     assert gated_delta.CALLS == {shape: [1, form]} and form in gated_delta.calls_summary()
     q, k, v = jax.eval_shape(lambda *a: gated_delta.mixer_in(*a, hk), like(rows, seq, hk * dk), like(rows, seq, hk * dk),

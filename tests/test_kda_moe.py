@@ -282,7 +282,7 @@ def test_a_decay_averaged_over_the_channels_is_another_rule_and_calls_say_which(
     assert _rel(by_channel, want) < 1e-5 < 1e-1 < _rel(by_head, want)
     forms = {shape: form for shape, (_, form) in gated_delta.CALLS.items()}
     assert forms == {(1, 100, 2, 2, 16, 16): "chunked 64: xla",
-                     (1, 100, 2, 2, 16, 16, "by channel"): "chunked 64, a decay a channel in sub-blocks of 16: xla (no kernels for it yet)"}
+                     (1, 100, 2, 2, 16, 16, "by channel"): "chunked 64, a decay a channel in sub-blocks of 16: xla"}
     assert "a decay a channel in sub-blocks of 16" in gated_delta.calls_summary()
 
 

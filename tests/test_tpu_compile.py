@@ -35,6 +35,10 @@ HQ, HKV, D = 16, 4, 128
 # kernel, and their serialized modules' bytes together (PR 36's tree read 125,980 in the same step, my chip run, PR 37).
 RULE_PROGRAMS = {"gdn_rule_fwd": 1, "gdn_rule_bwd": 1}
 RULE_MODULE_BYTES = 77_064
+# The rule with a decay a CHANNEL (Kimi Delta Attention) in the Kimi cell's step as its two sweeps landed (PR 43), the
+# same way: a warm start of that cell pays for this text (and no longer for the XLA form's Python loops over sub-blocks).
+KDA_RULE_PROGRAMS = {"kda_rule_fwd": 1, "kda_rule_bwd": 1}
+KDA_RULE_MODULE_BYTES = 112_700
 # The mixer's two elementwise passes around the rule, the same way (PR 39; budget: 40 KB together).
 MIXER_PROGRAMS = {"gdn_in_fwd": 1, "gdn_in_bwd": 1, "gdn_out_fwd": 1, "gdn_out_bwd": 1}
 MIXER_MODULE_BYTES = 35_816
@@ -513,11 +517,16 @@ def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
     """One period of Kimi-Linear-48B-A3B at its published widths (Kimi Delta Attention, KDA, latent attention without
     rope, KDA; this chip's share: 8 of 256 experts, an eighth of the vocabulary), every parameter trained but the
     selection bias, the cell's 2 rows of 8192 a microbatch, two microbatches. The compiler's own count stays under the
-    cell's memory line (15.0 GiB for the five layers: these four hold 0.1 G of state less); the latent layer takes the
+    cell's memory line (15.0 GiB for the five layers: these four hold 0.1 G of state less) and at its landed value
+    (11.79 GiB since the rule's kernels, PR 43; 14.576 while the XLA form held a row's ``U``, ``W``, ``P`` and decayed
+    operands of all chunks); the latent layer takes the
     RESIDENT flash kernels at q/k 192 against v 128, one query a kv head, on a row of 8192 (``dispatch_summary()`` says
-    which set), its forward kernel once (``o`` and ``lse`` kept); each KDA layer's rule is the XLA form with a decay a
-    channel (no rule kernel in the step, XLA's triangular solve is) between the two fused passes' kernels, the out
-    pass with its sigmoid gate; ``kda_gates`` is on the step's operations; ``CALLS`` names the form."""
+    which set), its forward kernel once (``o`` and ``lse`` kept); each KDA layer's rule is the two Pallas sweeps for a
+    decay a channel (``kda_rule_fwd`` in the forward and in the recomputed pass, ``kda_rule_bwd`` once; XLA's
+    triangular solve is out of the step) between the two fused passes' kernels, the out pass with its sigmoid gate;
+    ``kda_gates`` is on the step's operations; ``CALLS`` names the kernel form; and the sweeps' Mosaic programs and
+    serialized bytes are held where they landed (``KDA_RULE_PROGRAMS``, ``KDA_RULE_MODULE_BYTES``), as the scalar rule's
+    are in the Qwen3-Next step."""
     from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
     from llm_fine_tune_distributed_tpu.ops import gated_delta
     from llm_fine_tune_distributed_tpu.ops.attention import dispatch_summary
@@ -534,8 +543,9 @@ def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
     state = setup.state.replace(opt_state=jax.tree.map(  # Adam's moments float32, as the cell holds them
         lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
         setup.state.opt_state))
-    compiled = dataclasses.replace(setup, state=state).compile()
-    assert compiled.memory_analysis().peak_memory_in_bytes < 15.0 * 2**30
+    lowered = dataclasses.replace(setup, state=state).lower()
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 11.9 * 2**30 < 15.0 * 2**30
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     mosaic_calls = lambda kernel: sum(f"/{kernel}/" in line for line in calls)  # noqa: E731
@@ -543,7 +553,14 @@ def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
         assert mosaic_calls(f"flash_attention_{kernel}") == 1, kernel  # the resident set, the forward kernel kept
         assert mosaic_calls(f"flash_attention_causal_{kernel}") == 0, kernel
     assert "resident causal" in dispatch_summary()
-    assert mosaic_calls("gdn_rule_fwd") == 0 and "triangular" in text.lower()
+    sweeps = sorted(re.findall(
+        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/gdn_scan/'
+        r'jit\((\w+_rule_\w+)\)/\3/pallas_call"', "\n".join(calls)))
+    assert sweeps == sorted(
+        sweep for i in (0, 1, 3) for sweep in (
+            (f"jvp(layer{i})", "", "kda_rule_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", "kda_rule_fwd"),
+            (f"transpose(jvp(layer{i}))", "", "kda_rule_bwd"))), sweeps
+    assert "triangular" not in text.lower()
     passes = sorted(re.findall(
         r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/(gdn_conv|gdn_gate_norm)/'
         r'jit\((gdn_(?:in|out)_\w+)\)/\4/pallas_call"', "\n".join(calls)))
@@ -555,9 +572,12 @@ def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
     for inside in ("linear_attn/kda_gates", "linear_attn/gdn_scan", "attn/"):
         assert any(f"/{inside}" in name for name in names), inside
     assert "jit(gmm)" in text, "no grouped product kernel in the step"
-    assert {form for _, form in gated_delta.CALLS.values()} == {
-        "chunked 64, a decay a channel in sub-blocks of 16: xla (no kernels for it yet)"}
+    assert {form for _, form in gated_delta.CALLS.values()} == {"chunked 64, a decay a channel in sub-blocks of 16: kernels"}
     assert set(gated_delta.CALLS) == {(2, 8192, 32, 32, 128, 128, "by channel")}
+    # what a start of the process pays for the sweeps, warm cache or not (the Qwen3-Next step's test: why): their text
+    found = {name: x for name, x in mosaic_programs(lowered.as_text()).items() if name.endswith(("_rule_fwd", "_rule_bwd"))}
+    assert {name: x["programs"] for name, x in found.items()} == KDA_RULE_PROGRAMS, found
+    assert sum(x["bytes"] for x in found.values()) <= 1.2 * KDA_RULE_MODULE_BYTES, found
 
 
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
