@@ -407,8 +407,8 @@ def _linear_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entr
         ba[..., hv:] + attn_p["dt_bias"].astype(jnp.float32)
     )  # a log decay, <= 0
     with scope("gdn_scan"):
-        o = checkpoint_name(gated_delta.gated_delta_rule(
-            q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk), v.reshape(b, s, hv, dv), g, beta), "gdn_o")
+        o = gated_delta.gated_delta_rule(
+            q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk), v.reshape(b, s, hv, dv), g, beta)
     with scope("gdn_gate_norm"):
         o = gated_delta.gated_norm(o.reshape(b, s, vd), z, attn_p["norm"]["weight"], config.rms_norm_eps)
     return lin(o.astype(hid.dtype), attn_p["out_proj"]), None
@@ -461,8 +461,8 @@ def _kda_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, 
     with scope("gdn_conv"):
         q, k, v = gated_delta.mixer_in(xq, xk, xv, attn_p["conv1d"]["weight"], hv)
     with scope("gdn_scan"):
-        o = checkpoint_name(gated_delta.gated_delta_rule(
-            q.reshape(b, s, hv, dk), k.reshape(b, s, hv, dk), v.reshape(b, s, hv, dv), g, beta), "gdn_o")
+        o = gated_delta.gated_delta_rule(
+            q.reshape(b, s, hv, dk), k.reshape(b, s, hv, dk), v.reshape(b, s, hv, dv), g, beta)
     with scope("gdn_gate_norm"):
         o = gated_delta.gated_norm(o.reshape(b, s, hv * dv), gate, attn_p["norm"]["weight"], config.rms_norm_eps,
                                    activation="sigmoid")
@@ -823,6 +823,25 @@ def _block(
     return x, new_entry, counted
 
 
+# {kind of block (its mixer, and its window where it has one): (remat policy, the names kept besides)} of every
+# rematerialized block a forward of this process was traced with (``_remat_policy``), as ``gated_delta.CALLS`` says which
+# program a rule took: whether a block kept the flash kernels', the delta rule's or the expert layer's named values.
+REMAT_KEEPS: dict = {}
+
+
+def remat_summary() -> str:
+    """One line for entry points to print beside ``dispatch_summary()``: e.g. ``a rematerialized block keeps, besides
+    what its policy does: linear (full): gdn_o, gdn_states, moe_*; heads (full): flash_o, flash_lse, moe_*``."""
+    from llm_fine_tune_distributed_tpu.ops.moe import KEPT_ACROSS_REMAT as routed
+
+    def said(names):
+        own = [name for name in names if name not in routed]
+        return ", ".join(own + ["moe_*"] * (len(own) < len(names))) or "nothing"
+
+    kinds = "; ".join(f"{kind} ({policy}): {said(names)}" for kind, (policy, names) in sorted(REMAT_KEEPS.items()))
+    return f"a rematerialized block keeps, besides what its policy does: {kinds or 'no block traced'}"
+
+
 def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = None) -> bool:
     """Whether a rematerialized block of softmax attention of this model, with this
     ``window`` (None: global attention), keeps the flash forward kernel's output and row
@@ -840,22 +859,37 @@ def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = N
     return worth_keeping_across_remat(seq, d_qk, d_v, config.hidden_size, window=window)
 
 
-def keeps_scan_output(config: ModelConfig) -> bool:
-    """Whether a rematerialized block with a linear-attention mixer keeps the
-    gated delta rule's output (``gdn_o``, ``[b, s, value heads * d_v]``), so
-    that the recomputed scan only has to carry its state forward and the
-    output half of each step (``Q S`` and ``(decay * Q K^T) D``) is dead code.
-    The rule of ``worth_keeping_across_remat``, from shapes alone: per byte of
-    ``o`` that half costs ``d_k + chunk * (1 + d_k / (r d_v))`` FLOPs (``2 d_k
-    d_v`` for ``Q S``, ``2 chunk d_v`` for the product with D and ``2 chunk
-    d_k / r`` for ``Q K^T`` a token and value head, for ``2 d_v`` bytes),
-    against ``hidden_size`` a byte a projection's output buys. The row's
-    length is not in it: the rule's work grows with the row as what is kept
-    does. Qwen3-Next: 128 + 64 x 1.5 = 224 against 2048, recompute (and on
-    the chip: PERF.md, PR 32)."""
-    r = config.linear_num_value_heads // config.linear_num_key_heads
+def keeps_scan_output(config: ModelConfig) -> tuple:
+    """Which of the gated delta rule's names (``ops/gated_delta.KEPT_ACROSS_REMAT``) a rematerialized block with a
+    linear-attention mixer keeps, from what the code can observe: the program the rule runs as
+    (``gated_delta._program`` at this model's linear heads: the backend and the widths) and, for the XLA form, shapes.
+
+    **The kernels** (a TPU, heads of whole lanes): both, the output ``gdn_o`` (``[b, s, value heads * d_v]``) and the
+    state each step of the forward sweep starts from, ``gdn_states`` (``[b, value heads, s / 512, d_k, d_v]`` float32),
+    so that the recomputed pass holds no forward sweep. The backward sweep reads the states and the gated norm reads
+    ``o``, so with ``o`` alone kept the whole sweep still runs a second time (counted in the compiled step: 6 forward
+    sweeps for 3 layers either way). A sweep is a chain of dependent products on one MXU at a tenth of the recurrence's
+    roofline, and the count below, which prices a step's output half at the matmuls' rate, is not about it. By the
+    chip (PERF.md, PR 44's traces; a microbatch of 2 rows of 8192 at 32 value heads of 128 keeps 192 MiB a layer, 128
+    of ``o`` and 64 of states): the scalar rule's sweep 4.78 ms a call, 28.7 ms of the Qwen3-Next cell's step for 576
+    MiB more held (0.050 ms a MiB), the sweep with a decay a channel 9.00 ms a call, 72 ms of the Kimi cell's step for
+    768 MiB (0.094), against 0.04 ms a MiB for the routing PR 29 keeps and about 0.014 for a projection's output.
+    What comes back is the one ``reduce_precision`` pass ``jax.checkpoint`` puts on a saved residual's producer, 0.4
+    ms a call over ``o``.
+
+    **The XLA form** (a CPU, heads that are no whole lanes): ``gdn_o`` alone, where the rule of
+    ``worth_keeping_across_remat`` says so from shapes; that form carries its state through its own scan, and with
+    ``o`` kept the recomputed scan only carries the state forward and the output half of each step (``Q S`` and
+    ``(decay * Q K^T) D``) is dead code. Per byte of ``o`` that half costs ``d_k + chunk * (1 + d_k / (r d_v))`` FLOPs
+    (``2 d_k d_v`` for ``Q S``, ``2 chunk d_v`` for the product with D and ``2 chunk d_k / r`` for ``Q K^T`` a token
+    and value head, for ``2 d_v`` bytes), against ``hidden_size`` a byte a projection's output buys. The row's length
+    is not in it: the rule's work grows with the row as what is kept does. Qwen3-Next: 128 + 64 x 1.5 = 224 against
+    2048, Kimi Linear 128 + 64 x 2 = 256 against 2304: recompute (and on the chip: PERF.md, PR 32)."""
     d_k, d_v = config.linear_key_head_dim, config.linear_value_head_dim
-    return d_k + gated_delta.CHUNK * (1 + d_k / (r * d_v)) > config.hidden_size
+    if gated_delta._program(d_k=d_k, d_v=d_v) == "kernels":
+        return gated_delta.KEPT_ACROSS_REMAT
+    r = config.linear_num_value_heads // config.linear_num_key_heads
+    return gated_delta.KEPT_ACROSS_REMAT[:1] if d_k + gated_delta.CHUNK * (1 + d_k / (r * d_v)) > config.hidden_size else ()
 
 
 def keeps_routing(config: ModelConfig) -> bool:
@@ -882,9 +916,10 @@ def _remat_policy(
     forward kernel's ``o`` and ``lse``, so that the kernel runs once a layer
     and not a second time in the backward pass. Where the kernel is not in
     the block (XLA attention) the two names are in no program and the policy
-    is the plain one. A block whose mixer is the linear recurrence has no
-    such kernel; it keeps the recurrence's output where ``keeps_scan_output``
-    says so (from the mixer's widths).
+    is the plain one. A block whose mixer is the linear recurrence keeps what
+    ``keeps_scan_output`` names of the rule: where the rule runs as the Pallas
+    sweeps their two outputs, so that the forward sweep runs once a layer too;
+    where it is XLA's scan its output, by a rule of the mixer's widths.
 
     And a model with a ``grouped_experts`` layer (``keeps_routing``: the
     layer's kind, no switch) keeps what that layer names
@@ -895,7 +930,10 @@ def _remat_policy(
     1,006 ms step to make again (0.04 ms a MiB; ISSUE 29 had counted on 0.1),
     as much as the best of what else such a block could keep and above the
     0.011 of a byte of ``o`` at 1024 tokens (PERF.md, PRs 27 and 29). A model
-    without such a layer gets the policy object it got before."""
+    without such a layer gets the policy object it got before.
+
+    What each kind of block keeps is recorded (``REMAT_KEEPS``) for
+    ``remat_summary()``, the line entry points print."""
     from llm_fine_tune_distributed_tpu.ops import flash_attention, moe
 
     saveable = jax.checkpoint_policies
@@ -912,11 +950,12 @@ def _remat_policy(
         )
     policy = policies[remat_policy]
     if attention in ("linear", "kda"):
-        names = ("gdn_o",) if keeps_scan_output(config) else ()
+        names = keeps_scan_output(config)
     else:
         names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq, window) else ()
     if keeps_routing(config):
         names += moe.KEPT_ACROSS_REMAT
+    REMAT_KEEPS[attention if window is None else f"{attention}, window {window}"] = (remat_policy, names)
     if not names:
         return policy
     kept = saveable.save_only_these_names(*names)

@@ -114,6 +114,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -125,6 +126,13 @@ CHUNK = 64
 # The carried state's dtype (a test lowers it to show that the comparison with
 # the reference sees it; nothing else sets it).
 STATE_DTYPE = jnp.float32
+
+# What the rule names (``checkpoint_name``) for a rematerialized block to keep (``models/transformer._remat_policy``, as
+# the flash kernels' ``o`` and ``lse`` and the expert layer's routing are): its output ``o`` and, where the kernels run,
+# the state each step of the forward sweep starts from, which the backward sweep reads. Under any policy that does not
+# save them the names are inert. The two go together: with ``o`` alone kept, the recomputed pass still runs the whole
+# forward sweep for the states (``_flat_rule``). The XLA forms carry their state through their own scan and name ``o`` only.
+KEPT_ACROSS_REMAT = ("gdn_o", "gdn_states")
 
 # {(rows, seq, key heads, value heads, d_k, d_v): [calls traced, form]} of every
 # ``gated_delta_rule`` traced in this process (as ``flash_attention.GRID_TILES``
@@ -1227,12 +1235,18 @@ def _rows_of(x, b, n, groups, rs):
 def _flat_rule(forward, backward, hk, state_dtype, interpret, **static):
     """Two sweeps (the scalar decay's or the decay by channel's) as one differentiable function of the kernels' own
     layouts. What the backward sweep keeps besides the inputs is the state each step starts from (``rows x seq / (8 C)
-    x value heads x d_k x d_v``, an eighth of what the scan's autodiff held), which the forward sweep always writes
-    (under a block's remat the recomputed sweep's is the one kept)."""
+    x value heads x d_k x d_v``, an eighth of what the scan's autodiff held), which the forward sweep always writes.
+    Both of the sweep's outputs are named (``KEPT_ACROSS_REMAT``), and the NAMED values are the primal output and the
+    residual (autodiff would read an unnamed one past the name): a ``jax.checkpoint`` whose policy saves the two names
+    has no forward sweep left in its recomputed pass (q, k, v, the decay and beta are rebuilt from the block's input,
+    the sweep itself is dead code and dropped); under a policy that saves neither, or ``o`` alone, the sweep runs a
+    second time there."""
     static = dict(static, hk=hk, interpret=interpret)
 
     def fwd(q, k, v, decay, beta):
         o, states = forward(q, k, v, decay, beta, state_dtype=state_dtype, **static)
+        o = checkpoint_name(o, KEPT_ACROSS_REMAT[0])
+        states = checkpoint_name(states, KEPT_ACROSS_REMAT[1])
         return o, (q, k, v, decay, beta, states)
 
     @jax.custom_vjp
@@ -1289,7 +1303,8 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, impl=None):
     the XLA form elsewhere; ``g [b, s, value heads, d_k]`` is a decay a
     channel, and takes its own two kernels under the same conditions and
     ``_rule_xla_by_channel`` elsewhere. ``CALLS`` says which form a call took
-    and, on a TPU, why not the kernels. ``impl`` is the tests' and the tools' handle: ``"xla"``,
+    and, on a TPU, why not the kernels. Either form names what a
+    rematerialized block may keep of it (``KEPT_ACROSS_REMAT``). ``impl`` is the tests' and the tools' handle: ``"xla"``,
     ``"kernels"``, ``"kernels_interpret"`` (the kernels under the Pallas
     interpreter)."""
     b, s, hk, dk = q.shape
@@ -1301,7 +1316,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, impl=None):
     entry[0] += 1
     if program == "kernels":
         return _rule_kernels(q, k, v, g, beta, interpret=impl == "kernels_interpret")
-    return (_rule_xla_by_channel if by_channel else _rule_xla)(q, k, v, g, beta, chunk=chunk)
+    return checkpoint_name((_rule_xla_by_channel if by_channel else _rule_xla)(q, k, v, g, beta, chunk=chunk), KEPT_ACROSS_REMAT[0])
 
 
 # -- the mixer's elementwise work as two fused passes (TPU) -----------------------

@@ -1210,6 +1210,7 @@ class SFTTrainer:
                             # the step program is traced now: say which attention
                             # path it holds (a flash request that took XLA
                             # attention names its reason) and on what it runs
+                            from llm_fine_tune_distributed_tpu.models import transformer
                             from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
                             from llm_fine_tune_distributed_tpu.ops import rope as rope_ops
                             from llm_fine_tune_distributed_tpu.ops.attention import (
@@ -1227,6 +1228,8 @@ class SFTTrainer:
                                 print(f"[train] {gated_delta.calls_summary()}", flush=True)
                             if rope_ops.CALLS:  # layers of heads: the fused IN pass or the XLA form, and why
                                 print(f"[train] {rope_ops.calls_summary()}", flush=True)
+                            if transformer.REMAT_KEEPS:  # which named values each kind of block kept across its remat
+                                print(f"[train] {transformer.remat_summary()}", flush=True)
                     pending_samples += samples_per_step
                     # real-token accounting for the throughput meter: a host
                     # numpy mean over the loader's (pre-device) mask — cheap

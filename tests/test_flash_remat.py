@@ -4,7 +4,9 @@ holding them, a block's ``jax.checkpoint`` policy keeps them and the gradient
 program holds one forward kernel call a layer, not two. The kernels are
 counted in the jaxpr (nothing runs) or run under the Pallas interpreter at
 tiny widths; what the chip's compiler makes of the step is in
-``tests/test_tpu_compile.py``.
+``tests/test_tpu_compile.py``. The gated delta rule's forward sweep and its
+two outputs (``ops/gated_delta.KEPT_ACROSS_REMAT``) are held the same way at
+the end of the file.
 """
 
 from __future__ import annotations
@@ -17,14 +19,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_fine_tune_distributed_tpu.models import transformer
 from llm_fine_tune_distributed_tpu.models.configs import get_preset
 from llm_fine_tune_distributed_tpu.models.transformer import (
+    _remat_policy,
     forward,
     init_params,
     keeps_flash_outputs,
+    keeps_scan_output,
 )
 from llm_fine_tune_distributed_tpu.ops import flash_attention as fa
-from llm_fine_tune_distributed_tpu.ops import rope
+from llm_fine_tune_distributed_tpu.ops import gated_delta, rope
 
 LAYERS, SEQ = 2, 128
 
@@ -171,3 +176,49 @@ def test_rule_is_the_cost_of_a_kept_byte():
     assert not fa.worth_keeping_across_remat(2048, 128, 128, 2048)
     assert fa.worth_keeping_across_remat(2048 + 128, 128, 128, 2048)
     assert fa.worth_keeping_across_remat(2048, 192, 128, 2048)  # wider q/k heads: more work a byte
+
+
+# -- the gated delta rule's forward sweep ------------------------------------------
+
+
+@pytest.mark.parametrize("preset, attention, sweep", [
+    ("qwen3_next_80b_a3b", "linear", "gdn_rule_fwd"), ("kimi_linear_48b_a3b", "kda", "kda_rule_fwd"),
+], ids=["a-decay-a-head", "a-decay-a-channel"])
+def test_the_delta_rules_forward_sweep_runs_once_where_the_block_keeps_its_two_outputs(monkeypatch, preset, attention, sweep):
+    """The rule as its two Pallas sweeps (under the interpreter, one key head of 128 serving two value heads, a row of
+    100 tokens padded to a step) inside ``jax.checkpoint`` with the policy a block of that model gets. Where the
+    kernels run (a TPU at these heads) the policy saves the sweep's output AND the states its backward sweep reads:
+    one forward sweep in the gradient's program. With ``o`` alone saved nothing is removed (the states still need the
+    whole sweep), as under the policy of a backend that runs the XLA form, which keeps nothing of the rule at these
+    widths: two. Same kernels on the same operands either way: every cotangent equal bit for bit."""
+    config, policies = get_preset(preset), jax.checkpoint_policies
+    by_channel = attention == "kda"
+    assert keeps_scan_output(config) == ()  # this CPU: the XLA form's count, 224 against 2048 and 256 against 2304
+    monkeypatch.setattr(transformer, "REMAT_KEEPS", {})
+    recomputes = _remat_policy("full", config, 8192, None, attention)
+    assert f"{attention} (full): moe_*" in transformer.remat_summary()
+    with monkeypatch.context() as on_a_tpu:
+        on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        assert keeps_scan_output(config) == gated_delta.KEPT_ACROSS_REMAT == ("gdn_o", "gdn_states")
+        keeps = _remat_policy("full", config, 8192, None, attention)
+        _remat_policy("mlp", config, 8192, None, "latent" if by_channel else "heads")  # the model's one softmax layer
+    kinds = sorted([f"{'latent' if by_channel else 'heads'} (mlp): flash_o, flash_lse, moe_*", f"{attention} (full): gdn_o, gdn_states, moe_*"])
+    # the line a run prints: whether the mechanism engaged
+    assert transformer.remat_summary() == f"a rematerialized block keeps, besides what its policy does: {'; '.join(kinds)}"
+    keys = jax.random.split(jax.random.PRNGKey(44), 5)
+    q, k = (gated_delta.l2_norm(jax.random.normal(key, (1, 100, 1, 128))) for key in keys[:2])
+    v = jax.random.normal(keys[2], (1, 100, 2, 128))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (1, 100, 2) + (128,) * by_channel))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 100, 2)))
+
+    def gradient(policy):
+        rule = jax.checkpoint(lambda *a: gated_delta.gated_delta_rule(*a, impl="kernels_interpret"), policy=policy)
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(rule(*a))), argnums=(0, 1, 2, 3, 4))
+
+    calls = {name: _kernel_calls(jax.make_jaxpr(gradient(policy))(q, k, v, g, beta).jaxpr, sweep) for name, policy in (
+        ("both kept", keeps), ("o alone", policies.save_only_these_names("gdn_o")), ("neither", recomputes))}
+    assert calls == {"both kept": 1, "o alone": 2, "neither": 2}
+    kept, recomputed = (jax.jit(gradient(policy))(q, k, v, g, beta) for policy in (keeps, recomputes))
+    for name, a, b in zip("q k v g beta".split(), kept, recomputed):
+        assert float(jnp.abs(a).max()) > 0, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)

@@ -598,14 +598,22 @@ def test_packed_rows_and_serving_are_refused_with_a_reason(flat):
         forward_with_report(_params(flat), jnp.zeros((1, 4), jnp.int32), MC, cache=init_cache(MC, 1, 8))
 
 
-def test_what_a_rematerialized_block_keeps_is_read_from_the_shapes():
+def test_what_a_rematerialized_block_keeps_is_read_from_the_shapes(monkeypatch):
     """The full layer at 8192 x 256-wide heads keeps the flash kernel's ``o``
-    and ``lse`` (8192 x 512 / 512 = 8192 against the hidden 2048); a linear
-    layer's rule costs 128 + 64 x 1.5 = 224 operations a kept byte against
-    2048: recomputed. At a hidden size under that it would be kept."""
+    and ``lse`` (8192 x 512 / 512 = 8192 against the hidden 2048). A linear
+    layer keeps what the program its rule runs as makes worth keeping. As
+    XLA's scan (this CPU; heads that are no whole lanes on a TPU) the count
+    from shapes: 128 + 64 x 1.5 = 224 operations a kept byte of ``o`` against
+    2048, recomputed; at a hidden size under that ``gdn_o`` alone, the scan
+    carries its own state. As the Pallas sweeps (a TPU at the model's heads
+    of 128) both of the forward sweep's outputs, whatever the hidden size:
+    ``o`` alone would free no sweep (``tests/test_flash_remat.py`` counts)."""
     big = get_preset("qwen3_next_80b_a3b")
     assert keeps_flash_outputs(big, 8192, None) and not keeps_flash_outputs(big, 1024, None)
-    assert not keeps_scan_output(big) and keeps_scan_output(big.replace(hidden_size=128))
+    assert keeps_scan_output(big) == () and keeps_scan_output(big.replace(hidden_size=128)) == ("gdn_o",)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert keeps_scan_output(big) == keeps_scan_output(big.replace(hidden_size=128)) == ("gdn_o", "gdn_states")
+    assert keeps_scan_output(get_preset("tiny_qwen3_next")) == ("gdn_o",)  # heads of 16: the XLA form there too, 16 + 64 x 1.5 = 112 against 64
 
 
 def test_the_new_scopes_reach_the_lowered_step(flat, ids):

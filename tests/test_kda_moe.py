@@ -452,7 +452,7 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
     assert sum(loads) == 2 * 40 * 8  # every pair of every token is some share's
 
 
-def test_sharding_freeze_and_what_a_block_keeps():
+def test_sharding_freeze_and_what_a_block_keeps(monkeypatch):
     spec = jax.sharding.PartitionSpec
     assert param_spec("model/layers/1/linear_attn/q_proj/kernel", 2) == spec("fsdp", None)
     assert param_spec("model/layers/1/linear_attn/f_b_proj/kernel", 2) == spec(None, "fsdp")
@@ -465,10 +465,15 @@ def test_sharding_freeze_and_what_a_block_keeps():
                                                                 unfreeze_last_n_layers=2)))
     assert tail["model/layers/4/linear_attn/dt_bias"] and tail["model/layers/3/self_attn/kv_a_layernorm/weight"]
     assert not tail["model/layers/2/linear_attn/A_log"] and not tail[f"model/layers/4/mlp/gate/{BIAS}"]
-    # a KDA block recomputes its rule (128 + 64 x 2 = 256 operations a kept byte against the hidden 2304); the latent
-    # layer at 8192 keeps the flash kernel's o and lse (Moonlight's widths)
+    # a KDA block whose rule is XLA's scan (this CPU) recomputes it (128 + 64 x 2 = 256 operations a kept byte against
+    # the hidden 2304); where the rule is the Pallas sweeps (a TPU at the model's heads of 128) it keeps both of the
+    # forward sweep's outputs; the latent layer at 8192 keeps the flash kernel's o and lse (Moonlight's widths)
     big = get_preset("kimi_linear_48b_a3b")
-    assert not keeps_scan_output(big) and keeps_scan_output(big.replace(hidden_size=128))
+    assert keeps_scan_output(big) == () and keeps_scan_output(big.replace(hidden_size=128)) == ("gdn_o",)
+    with monkeypatch.context() as on_a_tpu:
+        on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        assert keeps_scan_output(big) == ("gdn_o", "gdn_states")
+        assert keeps_scan_output(MC) == ("gdn_o",)  # heads of 16: the XLA form there too, 16 + 64 x 2 = 144 against 64
     assert keeps_flash_outputs(big, 8192, None) and not keeps_flash_outputs(big, 1024, None)
     assert moe.pairs_a_chunk(big.replace(held_experts=tuple(range(8)))) >= 1
 

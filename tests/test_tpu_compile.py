@@ -97,6 +97,11 @@ def _without_call_stacks():
     return jax._src.config.include_full_tracebacks_in_locations(False)
 
 
+def _xla_remats(text):
+    """The instructions XLA's own rematerialization added to an optimized program (it names them ``<name>.remat``)."""
+    return re.findall(r"%[\w.\-]*\.remat[\w.\-]* = ", text)
+
+
 def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -429,9 +434,11 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
     a kv head (no resident kernel: its dk/dv would ask 300 MiB), its forward
     kernel once (``o`` and ``lse`` kept: 8192 against the hidden 2048); each
     linear layer's rule is the Pallas kernels of ``ops/gated_delta.py``, the
-    forward sweep in the forward and the recomputed pass and the backward sweep
-    once; grouped products, the sums of rows into tokens and the kept routing
-    are in the step. What the rule's kernels cost every start of a process is
+    forward sweep ONCE (the block keeps its ``o`` and its per-step states, PR
+    44: none in the recomputed pass) and the backward sweep once, and XLA adds
+    no rematerialization of its own (no ``.remat`` instruction); grouped
+    products, the sums of rows into tokens and the kept routing are in the
+    step. What the rule's kernels cost every start of a process is
     held too (``RULE_PROGRAMS``, ``RULE_MODULE_BYTES``): PR 36's kernels, 126 KB
     of modules here, added 10.9 s to every warm ``setup_s`` and were refused.
     Around the rule the mixer's elementwise work is two fused passes (PR 39:
@@ -464,16 +471,15 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
     _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers)
     again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
     assert not again, again
-    # each linear layer's rule is the kernels (PR 37): the forward sweep in the forward pass and recomputed, the
-    # backward sweep once; the full layer has none, and XLA's triangular solve is out of the step
+    # each linear layer's rule is the kernels (PR 37): the forward sweep in the forward pass and NOT recomputed (its o
+    # and states are kept, PR 44), the backward sweep once; the full layer has none, XLA's triangular solve is out of
+    # the step and XLA rematerializes nothing of its own
     sweeps = sorted(re.findall(
         r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/gdn_scan/'
         r'jit\((gdn_rule_\w+)\)/\3/pallas_call"', "\n".join(line for line in text.splitlines() if "tpu_custom_call" in line)))
     assert sweeps == sorted(
-        sweep for i in range(3) for sweep in (
-            (f"jvp(layer{i})", "", "gdn_rule_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", "gdn_rule_fwd"),
-            (f"transpose(jvp(layer{i}))", "", "gdn_rule_bwd"))), sweeps
-    assert "triangular" not in text.lower()
+        sweep for i in range(3) for sweep in ((f"jvp(layer{i})", "", "gdn_rule_fwd"), (f"transpose(jvp(layer{i}))", "", "gdn_rule_bwd"))), sweeps
+    assert "triangular" not in text.lower() and not _xla_remats(text)
     # the two passes around it (PR 39), the same: forward kernels in the forward and the recomputed pass, backward
     # kernels once, none in the full layer
     passes = sorted(re.findall(
@@ -485,7 +491,9 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
             (f"transpose(jvp(layer{i}))", "", scope, f"gdn_{way}_bwd"))), passes
     # what the passes removed: of the instructions under linear_attn that yield a whole [2, 8192, >= 2048] activation
     # (fused computations' insides apart) none is a pad, a slice, a concatenation, a copy, a transpose or a conversion:
-    # each is a kernel, or a fusion that is a projection's product or the sum of the products' input cotangents
+    # each is a kernel, or a fusion that is a projection's product or the sum of the products' input cotangents, or (PR
+    # 44) the ONE pass ``jax.checkpoint`` puts on the producer of a float residual it saves (``reduce_precision``, a
+    # layer's kept ``o`` of the rule; the flash kernel's kept ``o`` passes the same under ``attn``)
     whole, computation = [], ""
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
@@ -494,11 +502,13 @@ def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
         found = re.match(r'\s*(?:ROOT )?%[\w.\-]+ = \w+\[2,8192,(\d+)\]\S* ([\w\-]+)\(.*op_name="([^"]*/linear_attn[^"]*)"', line)
         if found and not computation.startswith("fused_computation") and int(found.group(1)) >= 2048:
             whole.append((found.group(2), found.group(3).rsplit("/", 1)[1]))
-    assert whole and {opcode for opcode, _ in whole} <= {"custom-call", "get-tuple-element", "fusion", "bitcast"}, set(whole)
+    assert whole and {opcode for opcode, _ in whole} <= {"custom-call", "get-tuple-element", "fusion", "bitcast", "reduce-precision"}, set(whole)
     assert {last for opcode, last in whole if opcode == "fusion"} <= {"dot_general", "add_any"}, set(whole)
-    # under the landed count (13.47 GiB at PR 39; the parent's 14.15 held the XLA form's float32 copies and padded
-    # cotangents; PR 41's IN pass leaves it where it was: the full layer's gate is a product of its own)
-    assert compiled.memory_analysis().peak_memory_in_bytes <= 13.55 * 2**30
+    assert [last for opcode, last in whole if opcode == "reduce-precision"] == ["reduce_precision"] * 3, set(whole)
+    # at the landed count (13.47 GiB at PR 39; the parent's 14.15 held the XLA form's float32 copies and padded
+    # cotangents; PR 41's IN pass leaves it where it was: the full layer's gate is a product of its own; 13.56 since
+    # the three linear blocks keep 192 MiB each of the rule's o and states, PR 44)
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 13.57 * 2**30
     # the full layer's IN pass (PR 41): forward kernel in the forward and the recomputed pass, backward kernel once
     in_pass = mosaic_programs(lowered.as_text())
     assert {name: in_pass[name]["programs"] for name in IN_PASS_PROGRAMS} == IN_PASS_PROGRAMS
@@ -522,8 +532,8 @@ def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
     operands of all chunks); the latent layer takes the
     RESIDENT flash kernels at q/k 192 against v 128, one query a kv head, on a row of 8192 (``dispatch_summary()`` says
     which set), its forward kernel once (``o`` and ``lse`` kept); each KDA layer's rule is the two Pallas sweeps for a
-    decay a channel (``kda_rule_fwd`` in the forward and in the recomputed pass, ``kda_rule_bwd`` once; XLA's
-    triangular solve is out of the step) between the two fused passes' kernels, the out pass with its sigmoid gate;
+    decay a channel (``kda_rule_fwd`` ONCE, its ``o`` and per-step states kept across the block's remat since PR 44,
+    ``kda_rule_bwd`` once; XLA's triangular solve is out of the step and it rematerializes nothing of its own) between the two fused passes' kernels, the out pass with its sigmoid gate;
     ``kda_gates`` is on the step's operations; ``CALLS`` names the kernel form; and the sweeps' Mosaic programs and
     serialized bytes are held where they landed (``KDA_RULE_PROGRAMS``, ``KDA_RULE_MODULE_BYTES``), as the scalar rule's
     are in the Qwen3-Next step."""
@@ -557,10 +567,8 @@ def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
         r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/gdn_scan/'
         r'jit\((\w+_rule_\w+)\)/\3/pallas_call"', "\n".join(calls)))
     assert sweeps == sorted(
-        sweep for i in (0, 1, 3) for sweep in (
-            (f"jvp(layer{i})", "", "kda_rule_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", "kda_rule_fwd"),
-            (f"transpose(jvp(layer{i}))", "", "kda_rule_bwd"))), sweeps
-    assert "triangular" not in text.lower()
+        sweep for i in (0, 1, 3) for sweep in ((f"jvp(layer{i})", "", "kda_rule_fwd"), (f"transpose(jvp(layer{i}))", "", "kda_rule_bwd"))), sweeps
+    assert "triangular" not in text.lower() and not _xla_remats(text)
     passes = sorted(re.findall(
         r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/(gdn_conv|gdn_gate_norm)/'
         r'jit\((gdn_(?:in|out)_\w+)\)/\4/pallas_call"', "\n".join(calls)))
