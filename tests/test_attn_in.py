@@ -79,7 +79,7 @@ def fused_form(xq, xk, xv, cos, sin, wq, wk, *, heads, kv, gated, zc, do_rope):
                            rope=do_rope, interpret=True), gate)
 
 
-def both_ways(cell, dtype, do_rope, rows=2, seq=SEQ):
+def _both_ways(cell, dtype, do_rope, rows, seq):
     heads, kv, d, width, gated, norm, zc = SHAPES[cell]
     ks = jax.random.split(jax.random.PRNGKey(41), 9)
     xq = jax.random.normal(ks[0], (rows, seq, heads * d * (2 if gated else 1)), jnp.float32).astype(dtype)
@@ -96,6 +96,16 @@ def both_ways(cell, dtype, do_rope, rows=2, seq=SEQ):
         cts = tuple(None if o is None else jax.random.normal(k, o.shape, jnp.float32).astype(o.dtype) for k, o in zip(ks[5:], y))
         out[name] = (y, vjp(cts))
     return out
+
+
+@functools.cache
+def _compiled(cell, dtype, do_rope, rows, seq):
+    return jax.jit(lambda: _both_ways(cell, dtype, do_rope, rows, seq))
+
+
+def both_ways(cell, dtype, do_rope, rows=2, seq=SEQ):
+    """Both forms' outputs and cotangents as ONE compiled program a case (eagerly every primitive of both compiled by itself)."""
+    return _compiled(cell, jnp.dtype(dtype), do_rope, rows, seq)()
 
 
 def worst_distance(a, b):
@@ -134,7 +144,7 @@ def test_a_layer_without_rope_takes_the_same_kernel(cell, dtype):
 @pytest.mark.parametrize("on", [True, False])
 @pytest.mark.parametrize("cell", ["smollm3", "qwen3-next"])
 def test_a_traced_bool_selects_between_the_tables(cell, on):
-    pick = jax.jit(lambda flag: both_ways(cell, jnp.float32, flag, rows=1, seq=128))(jnp.asarray(on))
+    pick = jax.jit(lambda flag: _both_ways(cell, jnp.float32, flag, rows=1, seq=128))(jnp.asarray(on))
     fixed = both_ways(cell, jnp.float32, on, rows=1, seq=128)
     assert worst_distance(pick["fused"], pick["xla"]) <= TOLERANCE["float32"]
     assert worst_distance(pick["fused"], fixed["fused"]) <= TOLERANCE["float32"]
@@ -203,10 +213,11 @@ def _loss(params, ids, impl):
 def test_a_gated_model_through_the_pass_and_the_head_major_flash_entry(as_on_a_tpu):
     params = init_params(jax.random.PRNGKey(1), GATED, dtype=jnp.float32)
     ids = jax.random.randint(jax.random.PRNGKey(2), (2, 128), 0, GATED.vocab_size)
-    loss, grads = jax.value_and_grad(_loss)(params, ids, "flash")
+    loss_and_grads = jax.jit(jax.value_and_grad(_loss), static_argnums=2)
+    loss, grads = loss_and_grads(params, ids, "flash")
     shape = (2, 128, 4, 2, 128, 128, "norm", "gate")
     assert set(rope.CALLS) == {(shape, "fused")}
-    loss_ref, grads_ref = jax.value_and_grad(_loss)(params, ids, "xla")
+    loss_ref, grads_ref = loss_and_grads(params, ids, "xla")
     assert set(rope.CALLS) == {(shape, "fused"), (shape, "xla (attention_impl is 'xla')")}
     np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
     flat, flat_ref = jax.tree.leaves(grads), jax.tree.leaves(grads_ref)

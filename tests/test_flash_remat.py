@@ -4,7 +4,7 @@ holding them, a block's ``jax.checkpoint`` policy keeps them and the gradient
 program holds one forward kernel call a layer, not two. The kernels are
 counted in the jaxpr (nothing runs) or run under the Pallas interpreter at
 tiny widths; what the chip's compiler makes of the step is in
-``tests/test_tpu_compile.py``. The gated delta rule's forward sweep and its
+the families' files (``FamilySuite.test_the_cells_step_compiles_for_v5e``). The gated delta rule's forward sweep and its
 two outputs (``ops/gated_delta.KEPT_ACROSS_REMAT``) are held the same way at
 the end of the file.
 """

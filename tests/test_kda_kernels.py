@@ -1,38 +1,37 @@
-"""The delta rule with a decay a CHANNEL (Kimi Delta Attention) as its two Pallas sweeps (``ops/gated_delta.py``
-``kda_rule_fwd``, ``kda_rule_bwd``), under the Pallas interpreter on a CPU at heads of 128 (whole lanes: what the
-kernels take), against the program's XLA form (``_rule_xla_by_channel``, what a CPU, heads of 16 or another chunk
-run) and the benchmark reference's walk token by token (``benchmarks/chipbench/reference_kda_moe.delta_rule``, which
-imports nothing of the program).
+"""The delta rule with a decay a CHANNEL (Kimi Delta Attention) alone (``ops/gated_delta.py``): the program's XLA form
+(``_rule_xla_by_channel``: chunks of 64 in sub-blocks of 16 with a triangular inverse; what a CPU, heads of 16 or
+another chunk run) against the benchmark reference's walk token by token
+(``benchmarks/chipbench/reference_kda_moe.delta_rule``, which imports nothing of the program), and its two Pallas sweeps
+(``kda_rule_fwd``, ``kda_rule_bwd``) under the Pallas interpreter on a CPU at heads of 128 (whole lanes: what the
+kernels take) against both. The model around the rule is held in ``tests/test_kda_moe.py``.
 
 Both forms compute in float32 at ``highest`` matmul precision here (``conftest.py``), so they differ by summation
 order alone: the sub-block products in one product a sub-block against four of XLA's, the triangular inverse by
 levels and Newton steps against a triangular solve, the sums from each sub-block's start by shifted adds against a
 ``cumsum``. Errors are read against each array's own scale with a floor (at the hardest decay ``dg`` is of the order
-of 1e-9 itself)."""
+of 1e-9 itself). Every comparison runs under ``jax.jit``, output and cotangents in ONE program a side, compiled once
+a shape: the decays of a shape call the same executable (eagerly each compiled every primitive by itself, PR 45)."""
 
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from family_suite import _rel  # (and the repo's root on ``sys.path``, for the benchmark's reference)
 from llm_fine_tune_distributed_tpu.ops import gated_delta
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
-
-from benchmarks.chipbench import reference_kda_moe as ref  # noqa: E402
+from benchmarks.chipbench import reference_kda_moe as ref
 
 NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 _KERNELS = lambda *a: gated_delta.gated_delta_rule(*a, impl="kernels_interpret")  # noqa: E731
 _XLA = lambda *a: gated_delta.gated_delta_rule(*a, impl="xla")  # noqa: E731
+_RULE = lambda *a: gated_delta.gated_delta_rule(*a)  # noqa: E731 (as the model calls it: here the XLA form)
 
 
 def _rule_inputs(seed, b, s, hk, hv, decay, dtype=jnp.float32, d=128):
-    """``tests/test_kda_moe._rule_inputs`` at a kernel's head: ``hk`` key heads serving ``hv`` value heads."""
+    """The rule's inputs: ``hk`` key heads serving ``hv`` value heads of ``d`` (128: a kernel's head)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = ref.l2_norm(jax.random.normal(ks[0], (b, s, hk, d))) * d ** -0.5
     k = ref.l2_norm(jax.random.normal(ks[1], (b, s, hk, d)))
@@ -45,19 +44,87 @@ def _rule_inputs(seed, b, s, hk, hv, decay, dtype=jnp.float32, d=128):
     return tuple(x.astype(dtype) for x in (q, k, v)) + (g, beta)
 
 
-def _outputs_and_cotangents(fn, args):
-    weigh = jnp.cos(jnp.arange(np.prod(args[2].shape), dtype=jnp.float32)).reshape(args[2].shape)
-    loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weigh)  # noqa: E731
-    return (fn(*args),) + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+@functools.cache
+def _outputs_and_cotangents(fn):
+    """``fn``'s output and all five cotangents, under a loss that weighs every output element differently, as ONE jitted
+    program (compiled once a shape, whatever the case)."""
+    def both(*args):
+        def loss(*a):
+            out = fn(*a)
+            weigh = jnp.cos(jnp.arange(np.prod(out.shape), dtype=jnp.float32)).reshape(out.shape)
+            return jnp.sum(out.astype(jnp.float32) * weigh), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        return (out,) + grads
+    return jax.jit(both)
 
 
 def _gap(a, b):
     return float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max()) / max(float(jnp.abs(b.astype(jnp.float32)).max()), 1e-2)
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+# -- the XLA form against the walk ------------------------------------------------
+
+
+@pytest.mark.parametrize("seq", [150, 37], ids=["two-chunks-and-a-part", "less-than-a-chunk"])
+@pytest.mark.parametrize("decay", ["drawn", "hardest", "none"])
+def test_chunked_rule_by_channel_equals_the_rule_token_by_token(decay, seq):
+    """The chunked form (chunks of 64, sub-blocks of 16, a triangular inverse) against the reference's walk, the
+    output and EVERY cotangent (``dg`` a channel), at rows that are no multiple of the chunk or of the sub-block; at
+    ``g = -16`` a token on every channel, where ``exp(-G)`` passes ``exp(1000)`` inside a chunk (the overflow the
+    sub-blocks exist for), everything is finite and right, and at ``g = 0`` (no decay at all) too. Errors against
+    each array's own scale, with a floor: at the hardest decay ``dg`` is of the order of 1e-7 itself."""
+    args = _rule_inputs(3, 2, seq, 3, 3, decay, d=16)
+    got = _outputs_and_cotangents(_RULE)(*args)
+    want = _outputs_and_cotangents(ref.delta_rule)(*args)
+    for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
+        assert float(jnp.abs(a - b).max()) < 1e-5 * max(float(jnp.abs(b).max()), 1e-2), name
+
+
+def test_a_decay_averaged_over_the_channels_is_another_rule_and_calls_say_which(monkeypatch):
+    monkeypatch.setattr(gated_delta, "CALLS", {})
+    args = _rule_inputs(4, 1, 100, 2, 2, "drawn", d=16)
+    want = jax.jit(ref.delta_rule)(*args)
+    by_channel = jax.jit(lambda *a: gated_delta.gated_delta_rule(*a))(*args)  # (a program of its own: ``CALLS`` counts traces)
+    by_head = jax.jit(lambda *a: gated_delta.gated_delta_rule(*a))(*args[:3], args[3].mean(-1), args[4])
+    assert _rel(by_channel, want) < 1e-5 < 1e-1 < _rel(by_head, want)
+    forms = {shape: form for shape, (_, form) in gated_delta.CALLS.items()}
+    assert forms == {(1, 100, 2, 2, 16, 16): "chunked 64: xla",
+                     (1, 100, 2, 2, 16, 16, "by channel"): "chunked 64, a decay a channel in sub-blocks of 16: xla"}
+    assert "a decay a channel in sub-blocks of 16" in gated_delta.calls_summary()
+
+
+def test_bfloat16_operands_and_key_heads_that_serve_several_value_heads():
+    """In the cell's dtype the rule stands by the float32 walk to bfloat16's grain (products of bfloat16 operands
+    added up in float32, the decayed products and the state float32); a key head's value heads decay apart."""
+    args = _rule_inputs(5, 2, 130, 2, 2, "drawn", jnp.bfloat16, d=16)
+    want = jax.jit(ref.delta_rule)(*(x.astype(jnp.float32) for x in args))
+    got = jax.jit(_RULE)(*args)
+    assert got.dtype == jnp.bfloat16 and _rel(got, want) < 2e-2
+    q, k, v, g, beta = _rule_inputs(6, 1, 70, 4, 4, "drawn", d=16)
+    want = jax.jit(ref.delta_rule)(jnp.repeat(q[:, :, :2], 2, axis=2), jnp.repeat(k[:, :, :2], 2, axis=2), v, g, beta)
+    assert _rel(jax.jit(_RULE)(q[:, :, :2], k[:, :, :2], v, g, beta), want) < 1e-5
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_out_pass_with_a_sigmoid_gate_as_kernels_equals_its_xla_form(dtype):
+    """The gated norm's kernels (Pallas interpreter) with the gate's activation a sigmoid against the XLA form,
+    output and every cotangent, on a row that is no whole token block; and it is not the silu's."""
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    o, z = (jax.random.normal(key, (1, 700, 256)).astype(dtype) for key in ks[:2])
+    w = (1 + 0.3 * jax.random.normal(ks[2], (128,))).astype(dtype)
+    run = lambda impl, act: (lambda *a: gated_delta.gated_norm(*a, 1e-5, activation=act, impl=impl))  # noqa: E731
+    loss = lambda fn: (lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32) + 0.3)))  # noqa: E731
+    every = lambda fn, args: jax.jit(lambda *a: jax.tree.leaves((fn(*a), jax.grad(loss(fn), argnums=(0, 1, 2))(*a))))(*args)  # noqa: E731
+    got, want = every(run("kernels_interpret", "sigmoid"), (o, z, w)), every(run("xla", "sigmoid"), (o, z, w))
+    exact = every(run("xla", "sigmoid"), tuple(x.astype(jnp.float32) for x in (o, z, w)))
+    for a, b, c in zip(got, want, exact):
+        assert a.dtype == b.dtype and bool(jnp.isfinite(a).all())
+        assert _rel(a, c) < (1e-5 if dtype == jnp.float32 else max(1.25 * _rel(b, c), 2.0 ** -9))
+    assert _rel(got[0], run("kernels_interpret", "silu")(o, z, w)) > 0.1
+
+
+# -- the two sweeps under the interpreter -----------------------------------------
 
 
 @pytest.mark.parametrize("rows, seq", [(2, 1100), (1, 37)], ids=["two-steps-and-a-part", "less-than-a-chunk"])
@@ -69,9 +136,9 @@ def test_by_channel_kernels_equal_the_xla_form_and_the_rule_token_by_token(decay
     token on every channel ``exp(-G)`` passes ``exp(1000)`` inside a chunk: everything is finite and right, and at
     ``g = 0`` too. Held to the XLA form in everything and to the reference's walk in everything as well."""
     args = _rule_inputs(3, rows, seq, 1, 1, decay)
-    got = _outputs_and_cotangents(_KERNELS, args)
+    got = _outputs_and_cotangents(_KERNELS)(*args)
     for held_to, limit in ((_XLA, 2e-5), (ref.delta_rule, 2e-5)):
-        want = _outputs_and_cotangents(held_to, args)
+        want = _outputs_and_cotangents(held_to)(*args)
         for name, a, b in zip(NAMES, got, want):
             assert a.shape == b.shape and a.dtype == b.dtype and bool(jnp.isfinite(a).all()), name
             assert _gap(a, b) < limit, (name, held_to, _gap(a, b))
@@ -81,12 +148,12 @@ def test_by_channel_kernels_serve_a_key_heads_value_heads_each_with_its_own_deca
     """One key head serving THREE value heads (each decays apart: nothing of a chunk is shared but the q and k
     loads), beside a second key head: the cotangents of q and k add up over a key head's value heads."""
     args = _rule_inputs(6, 1, 200, 2, 6, "drawn")
-    got = _outputs_and_cotangents(_KERNELS, args)
-    want = _outputs_and_cotangents(_XLA, args)
+    got = _outputs_and_cotangents(_KERNELS)(*args)
+    want = _outputs_and_cotangents(_XLA)(*args)
     for name, a, b in zip(NAMES, got, want):
         assert a.shape == b.shape and _gap(a, b) < 2e-5, (name, _gap(a, b))
     q, k, v, g, beta = args
-    walked = ref.delta_rule(jnp.repeat(q, 3, axis=2), jnp.repeat(k, 3, axis=2), v, g, beta)
+    walked = jax.jit(ref.delta_rule)(jnp.repeat(q, 3, axis=2), jnp.repeat(k, 3, axis=2), v, g, beta)
     assert _rel(got[0], walked) < 1e-5
 
 
@@ -96,9 +163,9 @@ def test_by_channel_kernels_on_bfloat16_operands_stand_where_the_xla_form_stands
     form IN FLOAT32 on the same bfloat16 values the kernels stand no further off than the XLA form in bfloat16 does
     (and both within bfloat16's grain); g and beta and their cotangents are float32 in both."""
     args = _rule_inputs(5, 1, 600, 1, 2, "drawn", jnp.bfloat16)
-    got = _outputs_and_cotangents(_KERNELS, args)
-    want = _outputs_and_cotangents(_XLA, args)
-    exact = _outputs_and_cotangents(_XLA, tuple(x.astype(jnp.float32) for x in args))
+    got = _outputs_and_cotangents(_KERNELS)(*args)
+    want = _outputs_and_cotangents(_XLA)(*args)
+    exact = _outputs_and_cotangents(_XLA)(*tuple(x.astype(jnp.float32) for x in args))
     assert [a.dtype for a in got] == [a.dtype for a in want] == [jnp.bfloat16] * 4 + [jnp.float32] * 2
     for name, a, b, c in zip(NAMES, got, want, exact):
         assert bool(jnp.isfinite(a).all()) and _rel(a, c) < max(1.25 * _rel(b, c), 2.0 ** -7), (name, _rel(a, c), _rel(b, c))

@@ -401,3 +401,53 @@ def test_new_families_shard_on_mesh():
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-5
             )
+
+
+# -- the families' suite keeps its promise (tests/family_suite.py) ----------------------------
+
+
+def test_every_family_states_the_whole_description_and_keeps_no_helper_of_its_own():
+    """One suite, one file a family: every file that subclasses ``FamilySuite`` states a whole ``Family`` (a field has
+    no default, so a family that lacks one fails at import and no shared test is silently skipped for it), inherits
+    every shared test as the suite wrote it, says what its cell's compiled step must hold, and defines none of the
+    suite's helpers or fixtures under its own roof (other files' helpers of those names are theirs)."""
+    import ast
+    import dataclasses
+    import glob
+    import importlib
+    import os
+
+    import family_suite
+    from family_suite import Family, FamilySuite
+
+    described = [c for c in vars(family_suite).values() if dataclasses.is_dataclass(c) and c.__module__ == "family_suite"]
+    assert Family in described and len(described) == 6
+    assert all(f.default is dataclasses.MISSING and f.default_factory is dataclasses.MISSING
+               for c in described for f in dataclasses.fields(c))
+    may_be_none = {"redraw", "shared_once", "mc"}  # each read by the suite where it is None: "as drawn", "nothing", "its own"
+    shared = {name for name in vars(FamilySuite) if name.startswith("test_")}
+    assert len(shared) == 13
+    theirs = {"_params", "_rel", "_state", "_train_config", "_batch", "_logits", "_logit_gap", "_bfloat16_gaps",
+              "flat", "ids", "two_steps", "one_step"}
+    families = {}
+    for path in sorted(glob.glob(os.path.join(os.path.dirname(__file__), "test_*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        suites = [n.name for n in tree.body if isinstance(n, ast.ClassDef) and any(getattr(b, "id", "") == "FamilySuite" for b in n.bases)]
+        if not suites:
+            continue
+        defined = {n.name for n in ast.walk(tree) if isinstance(n, (ast.FunctionDef, ast.ClassDef))}
+        defined |= {t.id for n in ast.walk(tree) if isinstance(n, ast.Assign) for t in n.targets if isinstance(t, ast.Name)}
+        assert not defined & theirs, (path, defined & theirs)
+        module = importlib.import_module(os.path.splitext(os.path.basename(path))[0])
+        for name in suites:
+            suite = getattr(module, name)
+            families[os.path.basename(path)] = suite.family.mc.name
+            for part in [suite.family] + [v for v in vars(suite.family).values() if type(v) in described]:
+                assert type(part) in described
+                assert not [k for k, v in vars(part).items() if v is None and k not in may_be_none], (path, part)
+            assert all(getattr(suite, test) is getattr(FamilySuite, test) for test in shared), path
+            assert suite.check_the_cells_step is not FamilySuite.check_the_cells_step, path
+            assert not hasattr(suite, "pytestmark") and not hasattr(module, "pytestmark"), path  # nothing skipped wholesale
+    assert set(families) == {"test_mla_moe.py", "test_swa_moe.py", "test_gdn_moe.py", "test_afmoe.py", "test_kda_moe.py"}
+    assert len(set(families.values())) == 5  # five models, not one description five times

@@ -4,7 +4,8 @@ a rematerialized block whose feed-forward is ``grouped_experts`` keeps what
 gradient program holds the router's product, the selection, the two sorts and
 the first chunk's row gather once an expert layer and not twice. Counted in
 the jaxpr (nothing runs) after the pattern of ``tests/test_flash_remat.py``;
-what the chip's compiler makes of the step is in ``tests/test_tpu_compile.py``.
+what the chip's compiler makes of the step is in the families' files (``tests/family_suite.py``,
+``test_the_cells_step_compiles_for_v5e``).
 """
 
 from __future__ import annotations

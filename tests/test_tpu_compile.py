@@ -7,41 +7,30 @@ kernel GSPMD cannot partition. These compiles can, at no chip time; what they
 cannot say is whether the results are right or how long they take
 (``chip_smoke.py`` checks the numbers on the chip).
 
-Rules that keep this file safe under pytest-xdist (only one process may load
-libtpu): the topology is described inside a module-scoped, non-autouse
-fixture, never at import, in a ``skipif`` or in ``parametrize`` arguments;
-every compile happens in the test's own process; all of them live in this
-one file, so one worker loads the library once.
+This file holds the kernels' compiles and the four-chip ones. A family's whole
+step at its cell's shapes is compiled in the family's own file
+(``FamilySuite.test_the_cells_step_compiles_for_v5e``, ``tests/family_suite.py``).
+The topology comes from ``conftest.py``'s module-scoped ``topo`` fixture: only one
+process may load libtpu unless ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` (the tier-1
+command sets it), so it is never described at import, in a ``skipif`` or in
+``parametrize`` arguments, and every compile happens in the test's own process.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
+from family_suite import IN_PASS_PROGRAMS
 from llm_fine_tune_distributed_tpu.observe.xla import mosaic_programs
 from llm_fine_tune_distributed_tpu.ops import flash_attention as fa
 from llm_fine_tune_distributed_tpu.ops.int8_matmul import _w8a8_pallas
 
 # SmolLM3-3B attention geometry
 HQ, HKV, D = 16, 4, 128
-# The gated delta rule's kernels in the Qwen3-Next cell's step as they landed (PR 37): distinct Mosaic programs by
-# kernel, and their serialized modules' bytes together (PR 36's tree read 125,980 in the same step, my chip run, PR 37).
-RULE_PROGRAMS = {"gdn_rule_fwd": 1, "gdn_rule_bwd": 1}
-RULE_MODULE_BYTES = 77_064
-# The rule with a decay a CHANNEL (Kimi Delta Attention) in the Kimi cell's step as its two sweeps landed (PR 43), the
-# same way: a warm start of that cell pays for this text (and no longer for the XLA form's Python loops over sub-blocks).
-KDA_RULE_PROGRAMS = {"kda_rule_fwd": 1, "kda_rule_bwd": 1}
-KDA_RULE_MODULE_BYTES = 112_700
-# The mixer's two elementwise passes around the rule, the same way (PR 39; budget: 40 KB together).
-MIXER_PROGRAMS = {"gdn_in_fwd": 1, "gdn_in_bwd": 1, "gdn_out_fwd": 1, "gdn_out_bwd": 1}
-MIXER_MODULE_BYTES = 35_816
 # The softmax mixer's IN pass (PR 41: q/k norms, rope and the head-major layout, ops/rope.heads_in): one forward and one
 # backward program a MODEL (a layer without rope runs the program of the layers with), and a budget for their serialized
 # modules' bytes together, by cell at a microbatch's shape: (rows, seq, q heads, kv heads, head, table width, norm) ->
@@ -55,32 +44,6 @@ IN_PASS_SHAPES = {
     "smollm3": ((2, 1024, 16, 4, 128, 128, False), 15_740),
     "qwen3-next": ((2, 8192, 16, 2, 256, 64, True), 31_952),
 }
-IN_PASS_PROGRAMS = {"attn_in_fwd": 1, "attn_in_bwd": 1}
-
-
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a deviceless executable can be written to the persistent cache but not
-    # read back without a chip: keep the cache out of these compiles
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -95,11 +58,6 @@ def _without_call_stacks():
     """While it lasts, a lowered operation's location is its innermost frame alone: a Mosaic module's text then does
     not depend on how deep the caller stood (a bare test under a runner's frames reads kilobytes more)."""
     return jax._src.config.include_full_tracebacks_in_locations(False)
-
-
-def _xla_remats(text):
-    """The instructions XLA's own rematerialization added to an optimized program (it names them ``<name>.remat``)."""
-    return re.findall(r"%[\w.\-]*\.remat[\w.\-]* = ", text)
 
 
 def _compile(fn, sharding, *shapes):
@@ -183,60 +141,6 @@ def test_sum_kernel_compiles_for_v5e(one_chip, rows, hidden, dtype, tokens, k, h
     assert "sum_held_rows" in text and text.count("tpu_custom_call") >= 1
 
 
-def _sum_kernel_calls(text):
-    """The paths (``op_name``) of the Mosaic calls that sum rows into tokens."""
-    found = (re.search(r'op_name="([^"]*/sum_held_rows/pallas_call)"', ln) for ln in text.splitlines() if "tpu_custom_call" in ln)
-    return [m.group(1) for m in found if m]
-
-
-def _assert_two_sums_an_expert_layer(text, expert_layers):
-    """One call forward (``sum_rows``) and one backward (``take_rows``'
-    transpose) an expert layer for the first chunk, the same two again inside
-    the overflow chunks' ``cond``, and none recomputed: the block's last
-    operation is dead in the recompute."""
-    calls = _sum_kernel_calls(text)
-    assert len(calls) == 4 * expert_layers, calls
-    first_chunk = [c for c in calls if "/cond/" not in c]
-    assert len(first_chunk) == 2 * expert_layers and sum("transpose(" in c for c in first_chunk) == expert_layers
-    assert not [c for c in calls if "rematted_computation" in c]
-    assert all("/mlp/experts/" in c and "gmm" not in c and "flash_attention" not in c for c in calls)
-
-
-def test_step_with_latent_attention_and_routed_experts_compiles_for_v5e(topo, monkeypatch):
-    """The dense layer and one expert layer of Moonlight-16B-A3B at its
-    published widths (this chip's share: 8 of 64 experts, an eighth of the
-    vocabulary), every parameter trained, one chip: the flash kernels at
-    192/128 and the grouped products are in the step, and the step is what the
-    chip's compiler accepts. (With the held experts' load counted by
-    ``bincount`` this program aborted the compiler: ops/moe.py.) The forward
-    kernel is in it once a layer (tests/test_flash_remat.py)."""
-    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    setup = abstract_train_setup(
-        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "moonlight_16b_a3b",
-        devices=topo.devices[:1], accum=2, seq=4096, per_dp_batch=1, param_dtype="bfloat16",
-        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
-        model_overrides=dict(num_layers=2, vocab_size=20480, held_experts=tuple(range(8))),
-    )
-    text = setup.compile().as_text()
-    mosaic_calls = lambda kernel: sum(  # noqa: E731
-        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
-    )
-    # rows of 4096 at heads of 192/128 over a hidden size of 2048: each block
-    # keeps the forward kernel's o and lse across its remat boundary, so the
-    # forward kernel is in the step once a layer and not a second time in the
-    # backward pass (under ``full`` too)
-    assert mosaic_calls("flash_attention_fwd") == setup.model_config.num_layers
-    assert mosaic_calls("flash_attention_dq") == mosaic_calls("flash_attention_dkv") == setup.model_config.num_layers
-    assert "jit(gmm)" in text, "no grouped product kernel in the step"
-    _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers - 1)
-    # and each expert layer keeps its routing and gathered rows: the backward
-    # pass holds no second router product, selection or sort (tests/test_moe_remat.py)
-    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
-    assert not again, again
-
-
 def test_flash_says_no_past_its_vmem_cap(monkeypatch):
     """One block past the largest sequence whose query group the resident
     dk/dv kernel holds, the streamed kernels take the call; what they cannot
@@ -283,39 +187,6 @@ def test_streamed_flash_forward_backward_compiles_for_v5e(one_chip, window):
     assert fa.GRID_TILES[f"flash_attention_{kind}_dkv", band] == ((15, 36) if window else (36, 36))
 
 
-def test_step_with_window_and_global_layers_and_softmax_experts_compiles_for_v5e(topo, monkeypatch):
-    """One window layer and one global layer of Mellum2-12B-A2.5B at its
-    published widths (this chip's share: 16 of 64 experts, a quarter of the
-    vocabulary), every parameter trained, one row of 8192 a microbatch: both
-    kinds of layer run the streamed flash kernels (no ``[8192, 8192]`` scores
-    in the program), the window layer's forward kernel twice (recomputed: 1920
-    against the hidden 2304) and the global layer's once (kept), and the
-    grouped products and the kept routing are in the step."""
-    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    setup = abstract_train_setup(
-        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "mellum2_12b_a2_5b",
-        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=1, param_dtype="bfloat16",
-        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
-        model_overrides=dict(num_layers=2, vocab_size=24576, held_experts=tuple(range(16)),
-                             layer_types=("sliding_attention", "full_attention")),
-    )
-    text = setup.compile().as_text()
-    mosaic_calls = lambda kernel: sum(  # noqa: E731
-        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
-    )
-    assert mosaic_calls("flash_attention_window_fwd") == 2 and mosaic_calls("flash_attention_causal_fwd") == 1
-    for kernel in ("window_dq", "window_dkv", "causal_dq", "causal_dkv"):
-        assert mosaic_calls(f"flash_attention_{kernel}") == 1
-    assert mosaic_calls("flash_attention_fwd") == 0  # no resident kernel at 8 queries a kv head and 8192
-    assert not re.search(r"\[[0-9,]*8192,8192\]", text), "a [seq, seq] buffer in the step"
-    assert "jit(gmm)" in text, "no grouped product kernel in the step"
-    _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers)
-    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
-    assert not again, again
-
-
 @pytest.mark.parametrize("cell", list(IN_PASS_SHAPES))
 def test_in_pass_compiles_for_v5e_and_its_text_is_held(one_chip, cell):
     """The IN pass's two kernels at a microbatch of each claimed cell (Trinity: gate and q/k norms; Mellum: neither),
@@ -344,248 +215,6 @@ def test_in_pass_compiles_for_v5e_and_its_text_is_held(one_chip, cell):
     programs = mosaic_programs(lowered.as_text())
     assert {name: x["programs"] for name, x in programs.items()} == IN_PASS_PROGRAMS, programs
     assert sum(x["bytes"] for x in programs.values()) <= 1.2 * landed, programs
-
-
-def test_step_with_gated_window_and_global_layers_compiles_for_v5e(topo, monkeypatch):
-    """The leading dense layer, one window layer and the global layer of
-    Trinity-Mini (``afmoe``) at its published widths (this chip's share: 16 of
-    128 experts, an eighth of the vocabulary), every parameter trained but the
-    selection bias, one row of 8192 a microbatch. The window of 2048 is a band
-    three blocks of 1024 wide (Mellum's 1024: two): both kinds of layer run the
-    streamed flash kernels behind the gate and the q/k norms, each forward
-    kernel ONCE (``o`` and ``lse`` kept on the window layers too: 2048 x 1.75 =
-    3584 keys' worth against the hidden 2048); the post-norm sits on the expert
-    layers' output; the grouped products, the sums of rows into tokens and the
-    kept routing are in the step; and the block's three scopes are on its
-    operations. Between the projections and the flash kernels stands the IN
-    pass (PR 41): ``attn_in_fwd`` in each layer's forward and recomputed pass,
-    ``attn_in_bwd`` once, ONE program each for the window layers with rope and
-    the global layer without, and the flash kernels read q, k and v as the
-    pass wrote them: no transpose, no copy, no fusion between."""
-    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    setup = abstract_train_setup(
-        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "trinity_mini",
-        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=1, param_dtype="bfloat16",
-        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
-        model_overrides=dict(num_layers=3, first_k_dense_replace=1, vocab_size=25024, held_experts=tuple(range(16)),
-                             layer_types=("sliding_attention", "sliding_attention", "full_attention"),
-                             no_rope_layers=(1, 1, 0)),
-    )
-    lowered = setup.lower()
-    text = lowered.compile().as_text()
-    mosaic_calls = lambda kernel: sum(  # noqa: E731
-        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
-    )
-    for kernel in ("fwd", "dq", "dkv"):
-        assert mosaic_calls(f"flash_attention_window_{kernel}") == 2, kernel  # layers 0 and 1, the forward kernel kept
-        assert mosaic_calls(f"flash_attention_causal_{kernel}") == 1, kernel
-    assert mosaic_calls("flash_attention_fwd") == 0  # no resident kernel at 8 queries a kv head and 8192
-    band = fa._band(8192, 1024, 2048)
-    assert band.steps == 3 and fa.GRID_TILES["flash_attention_window_fwd", band] == (21, 36)
-    assert "jit(gmm)" in text, "no grouped product kernel in the step"
-    # the sums of rows into tokens: forward and backward an expert layer as everywhere, and here a THIRD, recomputed:
-    # the expert layer's output is no longer the block's last operation, the output norm's backward reads it
-    # (64 MiB a layer and microbatch to keep instead: not kept), and the same three again behind the overflow's cond
-    sums = _sum_kernel_calls(text)
-    first_chunk = [c for c in sums if "/cond/" not in c]
-    assert len(sums) == 6 * 2 and len(first_chunk) == 3 * 2, sums
-    assert sum("transpose(" in c and "rematted_computation" not in c for c in first_chunk) == 2
-    assert sum("rematted_computation" in c for c in first_chunk) == 2
-    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
-    assert not again, again
-    names = re.findall(r'op_name="([^"]+)"', text)
-    for inside in ("attn/attn_gate", "attn/attn_in", "attn/out_norm", "mlp/out_norm"):
-        assert any(f"/{inside}/" in name for name in names), inside
-    assert not any("/qk_norm/" in name for name in names)  # the norms are inside the pass: the scope is the XLA form's
-    assert any("layer2" in name and "mlp/out_norm" in name for name in names)  # the post-norm of an EXPERT layer
-    # the IN pass: forward kernel in the forward and the recomputed pass, backward kernel once, every layer
-    passes = sorted(re.findall(
-        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?attn/attn_in/'
-        r'jit\((attn_in_\w+)\)/\3/pallas_call"', "\n".join(line for line in text.splitlines() if "tpu_custom_call" in line)))
-    assert passes == sorted(found for i in range(3) for found in (
-        (f"jvp(layer{i})", "", "attn_in_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", "attn_in_fwd"),
-        (f"transpose(jvp(layer{i}))", "", "attn_in_bwd"))), passes
-    programs = {name: x for name, x in mosaic_programs(lowered.as_text()).items() if name.startswith("attn_in")}
-    assert {name: x["programs"] for name, x in programs.items()} == IN_PASS_PROGRAMS, programs  # (rope or none: data)
-    # (30,048 B landed, plus a fifth; a whole step stands deeper than the ten frames a location keeps: the same anywhere)
-    assert sum(x["bytes"] for x in programs.values()) <= 36_000, programs
-    # what the forward flash kernels read as q, k, v IS what the pass wrote: get-tuple-elements of its call
-    defined = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = \S+ ([\w\-]+)\(", text, flags=re.M))
-    reads = re.findall(r"= \S+ \S+ custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\".*?"
-                       r'op_name="[^"]*/flash_attention_(?:window|causal)_fwd/pallas_call"', text)
-    assert len(reads) == 3
-    for operands in reads:
-        q_k_v = [name.split("*/")[-1].strip() for name in operands.split(",")][-3:]
-        assert all(name.startswith("%jit_attn_in_fwd_") and defined[name] == "get-tuple-element" for name in q_k_v), q_k_v
-
-
-def test_step_with_linear_and_full_layers_compiles_for_v5e(topo, monkeypatch):
-    """The step of ``qwen3-next-80b-a3b-ep16-d4.sft-8k-linear-allparams`` as
-    its traffic file states it: one period of Qwen3-Next-80B-A3B at its
-    published widths (three Gated DeltaNet layers, one gated full-attention
-    layer; this chip's share: 32 of 512 experts, an eighth of the vocabulary),
-    every parameter trained, 2 rows of 8192 a microbatch, two microbatches.
-    The compiler's own count has to fit beside the state (15.49 GiB a program
-    may use; at 4 rows a microbatch it refused the step, 17.89 G of 15.75 G,
-    until PR 39's passes took the float32 copies out: 14.13 GiB now, PERF.md);
-    the full layer runs the streamed flash kernels at heads of 256, 8 queries
-    a kv head (no resident kernel: its dk/dv would ask 300 MiB), its forward
-    kernel once (``o`` and ``lse`` kept: 8192 against the hidden 2048); each
-    linear layer's rule is the Pallas kernels of ``ops/gated_delta.py``, the
-    forward sweep ONCE (the block keeps its ``o`` and its per-step states, PR
-    44: none in the recomputed pass) and the backward sweep once, and XLA adds
-    no rematerialization of its own (no ``.remat`` instruction); grouped
-    products, the sums of rows into tokens and the kept routing are in the
-    step. What the rule's kernels cost every start of a process is
-    held too (``RULE_PROGRAMS``, ``RULE_MODULE_BYTES``): PR 36's kernels, 126 KB
-    of modules here, added 10.9 s to every warm ``setup_s`` and were refused.
-    Around the rule the mixer's elementwise work is two fused passes (PR 39:
-    ``gdn_in_*`` under ``gdn_conv``, ``gdn_out_*`` under ``gdn_gate_norm``,
-    counted like the sweeps and their text held like the rule's), and between
-    the projections and ``out_proj`` nothing else touches a whole activation."""
-    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    setup = abstract_train_setup(
-        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "qwen3_next_80b_a3b",
-        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=2, param_dtype="bfloat16",
-        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
-        model_overrides=dict(num_layers=4, vocab_size=18992, held_experts=tuple(range(32))),
-    )
-    state = setup.state.replace(opt_state=jax.tree.map(  # Adam's moments float32, as the cell holds them
-        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
-        setup.state.opt_state))
-    lowered = dataclasses.replace(setup, state=state).lower()
-    compiled = lowered.compile()
-    assert compiled.memory_analysis().peak_memory_in_bytes < 15.49 * 2**30
-    text = compiled.as_text()
-    mosaic_calls = lambda kernel: sum(  # noqa: E731
-        "tpu_custom_call" in line and f"/{kernel}/" in line for line in text.splitlines()
-    )
-    for kernel in ("causal_fwd", "causal_dq", "causal_dkv"):
-        assert mosaic_calls(f"flash_attention_{kernel}") == 1, kernel
-    assert mosaic_calls("flash_attention_fwd") == 0 and mosaic_calls("flash_attention_window_fwd") == 0
-    assert "jit(gmm)" in text, "no grouped product kernel in the step"
-    _assert_two_sums_an_expert_layer(text, setup.model_config.num_layers)
-    again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
-    assert not again, again
-    # each linear layer's rule is the kernels (PR 37): the forward sweep in the forward pass and NOT recomputed (its o
-    # and states are kept, PR 44), the backward sweep once; the full layer has none, XLA's triangular solve is out of
-    # the step and XLA rematerializes nothing of its own
-    sweeps = sorted(re.findall(
-        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/gdn_scan/'
-        r'jit\((gdn_rule_\w+)\)/\3/pallas_call"', "\n".join(line for line in text.splitlines() if "tpu_custom_call" in line)))
-    assert sweeps == sorted(
-        sweep for i in range(3) for sweep in ((f"jvp(layer{i})", "", "gdn_rule_fwd"), (f"transpose(jvp(layer{i}))", "", "gdn_rule_bwd"))), sweeps
-    assert "triangular" not in text.lower() and not _xla_remats(text)
-    # the two passes around it (PR 39), the same: forward kernels in the forward and the recomputed pass, backward
-    # kernels once, none in the full layer
-    passes = sorted(re.findall(
-        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/(gdn_conv|gdn_gate_norm)/'
-        r'jit\((gdn_(?:in|out)_\w+)\)/\4/pallas_call"', "\n".join(line for line in text.splitlines() if "tpu_custom_call" in line)))
-    assert passes == sorted(
-        found for i in range(3) for scope, way in (("gdn_conv", "in"), ("gdn_gate_norm", "out")) for found in (
-            (f"jvp(layer{i})", "", scope, f"gdn_{way}_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", scope, f"gdn_{way}_fwd"),
-            (f"transpose(jvp(layer{i}))", "", scope, f"gdn_{way}_bwd"))), passes
-    # what the passes removed: of the instructions under linear_attn that yield a whole [2, 8192, >= 2048] activation
-    # (fused computations' insides apart) none is a pad, a slice, a concatenation, a copy, a transpose or a conversion:
-    # each is a kernel, or a fusion that is a projection's product or the sum of the products' input cotangents, or (PR
-    # 44) the ONE pass ``jax.checkpoint`` puts on the producer of a float residual it saves (``reduce_precision``, a
-    # layer's kept ``o`` of the rule; the flash kernel's kept ``o`` passes the same under ``attn``)
-    whole, computation = [], ""
-    for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
-        if head:
-            computation = head.group(1)
-        found = re.match(r'\s*(?:ROOT )?%[\w.\-]+ = \w+\[2,8192,(\d+)\]\S* ([\w\-]+)\(.*op_name="([^"]*/linear_attn[^"]*)"', line)
-        if found and not computation.startswith("fused_computation") and int(found.group(1)) >= 2048:
-            whole.append((found.group(2), found.group(3).rsplit("/", 1)[1]))
-    assert whole and {opcode for opcode, _ in whole} <= {"custom-call", "get-tuple-element", "fusion", "bitcast", "reduce-precision"}, set(whole)
-    assert {last for opcode, last in whole if opcode == "fusion"} <= {"dot_general", "add_any"}, set(whole)
-    assert [last for opcode, last in whole if opcode == "reduce-precision"] == ["reduce_precision"] * 3, set(whole)
-    # at the landed count (13.47 GiB at PR 39; the parent's 14.15 held the XLA form's float32 copies and padded
-    # cotangents; PR 41's IN pass leaves it where it was: the full layer's gate is a product of its own; 13.56 since
-    # the three linear blocks keep 192 MiB each of the rule's o and states, PR 44)
-    assert compiled.memory_analysis().peak_memory_in_bytes <= 13.57 * 2**30
-    # the full layer's IN pass (PR 41): forward kernel in the forward and the recomputed pass, backward kernel once
-    in_pass = mosaic_programs(lowered.as_text())
-    assert {name: in_pass[name]["programs"] for name in IN_PASS_PROGRAMS} == IN_PASS_PROGRAMS
-    assert (mosaic_calls("attn_in_fwd"), mosaic_calls("attn_in_bwd")) == (2, 1)
-    # what a start of the process pays for the rule again, warm cache or not: the text of its kernels, traced and
-    # lowered before the cache is even asked (PERF.md, PR 37, step 0: the step's lower() follows the serialized
-    # modules' bytes, .compile() on a hit does not move). Held at the landed value plus a fifth.
-    programs = mosaic_programs(lowered.as_text())
-    for prefixes, count, landed in ((("gdn_rule",), RULE_PROGRAMS, RULE_MODULE_BYTES), (("gdn_in", "gdn_out"), MIXER_PROGRAMS, MIXER_MODULE_BYTES)):
-        found = {name: x for name, x in programs.items() if name.startswith(prefixes)}
-        assert {name: x["programs"] for name, x in found.items()} == count, found
-        assert sum(x["bytes"] for x in found.values()) <= 1.2 * landed, found
-
-
-def test_step_with_kda_and_latent_layers_compiles_for_v5e(topo, monkeypatch):
-    """One period of Kimi-Linear-48B-A3B at its published widths (Kimi Delta Attention, KDA, latent attention without
-    rope, KDA; this chip's share: 8 of 256 experts, an eighth of the vocabulary), every parameter trained but the
-    selection bias, the cell's 2 rows of 8192 a microbatch, two microbatches. The compiler's own count stays under the
-    cell's memory line (15.0 GiB for the five layers: these four hold 0.1 G of state less) and at its landed value
-    (11.79 GiB since the rule's kernels, PR 43; 14.576 while the XLA form held a row's ``U``, ``W``, ``P`` and decayed
-    operands of all chunks); the latent layer takes the
-    RESIDENT flash kernels at q/k 192 against v 128, one query a kv head, on a row of 8192 (``dispatch_summary()`` says
-    which set), its forward kernel once (``o`` and ``lse`` kept); each KDA layer's rule is the two Pallas sweeps for a
-    decay a channel (``kda_rule_fwd`` ONCE, its ``o`` and per-step states kept across the block's remat since PR 44,
-    ``kda_rule_bwd`` once; XLA's triangular solve is out of the step and it rematerializes nothing of its own) between the two fused passes' kernels, the out pass with its sigmoid gate;
-    ``kda_gates`` is on the step's operations; ``CALLS`` names the kernel form; and the sweeps' Mosaic programs and
-    serialized bytes are held where they landed (``KDA_RULE_PROGRAMS``, ``KDA_RULE_MODULE_BYTES``), as the scalar rule's
-    are in the Qwen3-Next step."""
-    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-    from llm_fine_tune_distributed_tpu.ops import gated_delta
-    from llm_fine_tune_distributed_tpu.ops.attention import dispatch_summary
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(gated_delta, "CALLS", {})
-    setup = abstract_train_setup(
-        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, "kimi_linear_48b_a3b",
-        devices=topo.devices[:1], accum=2, seq=8192, per_dp_batch=2, param_dtype="bfloat16",
-        train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024),
-        model_overrides=dict(num_layers=4, first_k_dense_replace=0, vocab_size=20480, held_experts=tuple(range(8)),
-                             layer_types=("linear_attention", "linear_attention", "full_attention", "linear_attention")),
-    )
-    state = setup.state.replace(opt_state=jax.tree.map(  # Adam's moments float32, as the cell holds them
-        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
-        setup.state.opt_state))
-    lowered = dataclasses.replace(setup, state=state).lower()
-    compiled = lowered.compile()
-    assert compiled.memory_analysis().peak_memory_in_bytes <= 11.9 * 2**30 < 15.0 * 2**30
-    text = compiled.as_text()
-    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    mosaic_calls = lambda kernel: sum(f"/{kernel}/" in line for line in calls)  # noqa: E731
-    for kernel in ("fwd", "dq", "dkv"):
-        assert mosaic_calls(f"flash_attention_{kernel}") == 1, kernel  # the resident set, the forward kernel kept
-        assert mosaic_calls(f"flash_attention_causal_{kernel}") == 0, kernel
-    assert "resident causal" in dispatch_summary()
-    sweeps = sorted(re.findall(
-        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/gdn_scan/'
-        r'jit\((\w+_rule_\w+)\)/\3/pallas_call"', "\n".join(calls)))
-    assert sweeps == sorted(
-        sweep for i in (0, 1, 3) for sweep in ((f"jvp(layer{i})", "", "kda_rule_fwd"), (f"transpose(jvp(layer{i}))", "", "kda_rule_bwd"))), sweeps
-    assert "triangular" not in text.lower() and not _xla_remats(text)
-    passes = sorted(re.findall(
-        r'op_name="[^"]*?/(transpose\(jvp\(layer\d\)\)|jvp\(layer\d\))/(?:[^"]*?/)?(rematted_computation/)?linear_attn/(gdn_conv|gdn_gate_norm)/'
-        r'jit\((gdn_(?:in|out)_\w+)\)/\4/pallas_call"', "\n".join(calls)))
-    assert passes == sorted(
-        found for i in (0, 1, 3) for scope, way in (("gdn_conv", "in"), ("gdn_gate_norm", "out")) for found in (
-            (f"jvp(layer{i})", "", scope, f"gdn_{way}_fwd"), (f"transpose(jvp(layer{i}))", "rematted_computation/", scope, f"gdn_{way}_fwd"),
-            (f"transpose(jvp(layer{i}))", "", scope, f"gdn_{way}_bwd"))), passes
-    names = re.findall(r'op_name="([^"]+)"', text)
-    for inside in ("linear_attn/kda_gates", "linear_attn/gdn_scan", "attn/"):
-        assert any(f"/{inside}" in name for name in names), inside
-    assert "jit(gmm)" in text, "no grouped product kernel in the step"
-    assert {form for _, form in gated_delta.CALLS.values()} == {"chunked 64, a decay a channel in sub-blocks of 16: kernels"}
-    assert set(gated_delta.CALLS) == {(2, 8192, 32, 32, 128, 128, "by channel")}
-    # what a start of the process pays for the sweeps, warm cache or not (the Qwen3-Next step's test: why): their text
-    found = {name: x for name, x in mosaic_programs(lowered.as_text()).items() if name.endswith(("_rule_fwd", "_rule_bwd"))}
-    assert {name: x["programs"] for name, x in found.items()} == KDA_RULE_PROGRAMS, found
-    assert sum(x["bytes"] for x in found.values()) <= 1.2 * KDA_RULE_MODULE_BYTES, found
 
 
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
