@@ -37,6 +37,7 @@ _TRINITY = ("trinity_mini", dict(
     layer_types=("sliding_attention",) * 3 + ("full_attention", "sliding_attention"), no_rope_layers=(1, 1, 1, 0, 1),
 ))
 _KIMI = ("kimi_linear_48b_a3b", dict(num_layers=5, vocab_size=20480, held_experts=tuple(range(8))))
+_EVA_RECIPE = dict(_DENSE, remat_policy="full", loss_chunk_size=1024)
 # name -> (preset, model overrides, rows, accumulation, sequence, recipe)
 STEPS = {
     # the cells of BENCHMARK.json, as their traffic files state them
@@ -62,6 +63,11 @@ STEPS = {
     # for the benchmark issue that may change the cell's microbatch now that the rule's kernels hold no chunk in HBM)
     "kimi-linear-48b-a3b-ep32-d5.8k-1row": (*_KIMI, 1, 4, 8192, _ALL),
     "kimi-linear-48b-a3b-ep32-d5.8k-4rows": (*_KIMI, 4, 1, 8192, _ALL),
+    # the last pipeline stage of a last-two-layers job on EvaByte: 8 frozen + 2 trained layers, one row of 32,768 bytes
+    "evabyte-6.5b-d10.sft-32k-eva-last2": ("evabyte_6_5b", dict(num_layers=10), 1, 1, 32768, _EVA_RECIPE),
+    # its neighbours: two more frozen layers, and the same tokens as two rows of 16,384 (ISSUE 46)
+    "evabyte-6.5b-d12.32k-1row": ("evabyte_6_5b", dict(num_layers=12), 1, 1, 32768, _EVA_RECIPE),
+    "evabyte-6.5b-d10.16k-2rows": ("evabyte_6_5b", dict(num_layers=10), 2, 1, 16384, _EVA_RECIPE),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),
@@ -118,9 +124,16 @@ def main(names) -> int:
                     for kernel in (f"flash_attention_{kind}_{k}" for kind in ("window", "causal") for k in ("fwd", "dq", "dkv"))
                     if (n := sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text))
                 },
+                # EVA attention's remote kernels (ops/eva_attention.py; its local ones are the resident flash kernels above)
+                eva={
+                    kernel: n
+                    for kernel in ("eva_remote_fwd", "eva_remote_dq", "eva_remote_dkv")
+                    if (n := sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text))
+                },
                 # the expert layer's sum of rows into tokens (ops/moe._sum_held_rows): 4 an expert layer, 2 of them behind the overflow cond
                 sum_held_rows=sum("tpu_custom_call" in ln and "/sum_held_rows/" in ln for ln in text),
                 seq_by_seq_buffers=sum(f",{seq},{seq}]" in ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in text),
+                seq_by_chunks_buffers=sum(f",{seq},{seq // 16}]" in ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in text),
             )
         line["compile_s"] = round(time.time() - started, 1)
         print(json.dumps(line), flush=True)

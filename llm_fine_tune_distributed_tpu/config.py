@@ -25,7 +25,8 @@ class LayerPlan:
 
     # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent) |
     # "linear" (Gated DeltaNet: a recurrence over time, no softmax, no rope, no window) |
-    # "kda" (Kimi Delta Attention: that recurrence with a decay a channel behind low-rank gates)
+    # "kda" (Kimi Delta Attention: that recurrence with a decay a channel behind low-rank gates) |
+    # "eva" (EvaByte: softmax over the query's own aligned window and one learned summary a chunk of every earlier window)
     attention: str
     rope: bool  # rotary embedding on this layer's q and k (SmolLM3's NoPE layers: False)
     rope_kind: str  # which of the forward's cos/sin tables: "plain" | "scaled" (the config's context extension)
@@ -47,8 +48,9 @@ class ModelConfig:
     Gemma2 (four norms a block), Mixtral, DeepSeek-V3 / Moonlight, Mellum,
     Qwen3-Next, ``afmoe`` (Trinity: gated window layers with rope beside
     gated global layers without, four norms a block around routed experts),
-    and ``kimi_linear`` (Kimi Delta Attention layers beside latent attention
-    without rope).
+    ``kimi_linear`` (Kimi Delta Attention layers beside latent attention
+    without rope), and ``evabyte`` (EVA attention in every layer, a float32
+    residual stream, several next-token heads).
     """
 
     name: str = "unnamed"
@@ -140,6 +142,18 @@ class ModelConfig:
     # ``sigmoid((x W_ga) W_gb)`` through another pair (as many key heads as value heads).
     linear_decay_rank: int = 0
     linear_gate_rank: int = 0
+    # --- EvaByte (``evabyte``, ``attention_class`` eva) ---
+    # eva_window > 0 makes every layer's mixer EVA attention (ops/eva_attention.py): token n sees the tokens of its
+    # own ALIGNED window ``[eva_window * (n // eva_window), n]`` exactly, and every EARLIER window through one pooled
+    # key/value a chunk of ``eva_chunk`` tokens (two learned vectors a head, ``adaptive_phi`` and ``adaptive_mu_k``).
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # The head has this many times vocab_size columns: head i at position t answers token t + 1 + i, and the loss
+    # is the mean of the heads' cross-entropies (train/step.py). 1 = the usual next-token head.
+    num_pred_heads: int = 1
+    # The residual stream and both adds of a block in float32 (``fp32_skip_add``); the norms' outputs and everything
+    # after them stay in the compute dtype.
+    fp32_residual: bool = False
     # The share of a head's dimensions that rope rotates, from dimension 0
     # (rotate-half inside them); the rest pass unrotated. 1.0 = the whole head.
     partial_rotary_factor: float = 1.0
@@ -233,6 +247,14 @@ class ModelConfig:
                         f"as many key heads as value heads (got ranks {self.linear_decay_rank}, "
                         f"{self.linear_gate_rank}, heads {hk} and {hv})"
                     )
+        if self.eva_window:
+            if self.eva_chunk < 1 or self.eva_window % self.eva_chunk:
+                raise ValueError(f"eva_window={self.eva_window} must be a multiple of eva_chunk={self.eva_chunk}")
+            if self.num_kv_heads != self.num_heads or self.layer_types or self.kv_lora_rank or self.sliding_window:
+                raise ValueError("EVA attention (eva_window) is every layer's mixer, with as many key heads as query "
+                                 "heads: no layer_types, latent attention or sliding_window beside it")
+        if self.num_pred_heads < 1 or (self.num_pred_heads > 1 and self.tie_word_embeddings):
+            raise ValueError(f"num_pred_heads={self.num_pred_heads}: at least 1, and more than one needs an untied head")
         rotary = self.resolved_head_dim * self.partial_rotary_factor
         if not 0 < self.partial_rotary_factor <= 1 or rotary != int(rotary) or int(rotary) % 2:
             raise ValueError(
@@ -298,6 +320,7 @@ class ModelConfig:
         mixers = {
             "heads": softmax,
             "latent": softmax,
+            "eva": softmax + 2 * self.num_heads * d,  # adaptive_phi, adaptive_mu_k
             # in_proj_qkvz, in_proj_ba, the convolution over q, k, v, A_log and dt_bias, the gated norm, out_proj
             "linear": h * (2 * kd + 2 * vd) + h * 2 * hv + (2 * kd + vd) * taps + 2 * hv + self.linear_value_head_dim + vd * h,
             # q, k, v and their convolutions, b_proj, the decay's pair with A_log (a head) and dt_bias (a channel),
@@ -328,7 +351,7 @@ class ModelConfig:
             plan = self.layer(i)
             total += norms + mixers[plan.attention] + feed_forwards[plan.feed_forward]
         if not self.tie_word_embeddings:
-            total += v * h
+            total += v * h * self.num_pred_heads
         return total
 
     def layer(self, i: int) -> "LayerPlan":
@@ -357,6 +380,8 @@ class ModelConfig:
         scaled = bool(self.rope_scaling_type) and self.rope_scaling_layer_type in (
             None, self.layer_types[i] if self.layer_types else None
         )
+        if self.eva_window:
+            return LayerPlan(attention="eva", rope=True, rope_kind="plain", window=None, feed_forward=feed_forward)
         return LayerPlan(
             attention="latent" if self.kv_lora_rank else "heads",
             rope=bool(self.no_rope_layers[i]) if self.no_rope_layers else not self.mla_use_nope,

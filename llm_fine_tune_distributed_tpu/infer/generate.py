@@ -121,6 +121,9 @@ class LatentAttentionNotServed(NotImplementedError):
                   "recurrent state (and the convolution's last inputs) as a cache entry, which infer/ lacks",
     }
     _LACKS["kda"] = _LACKS["linear"]  # Kimi Delta Attention: the same kind of state, a decay a channel
+    _LACKS["eva"] = ("EVA attention: it is supported on the training path only; serving it needs a cache of the "
+                     "current window's keys and values beside a growing list of pooled summaries, and a decode path "
+                     "for its several next-token heads, which infer/ lacks")
 
     def __init__(self, name: str, kind: str = "latent"):
         super().__init__(f"model {name!r} has {self._LACKS[kind]}")
@@ -129,7 +132,7 @@ class LatentAttentionNotServed(NotImplementedError):
 def unserved_layer_kind(config: ModelConfig):
     """The first kind of layer of ``config`` that has no cache here, or None."""
     kinds = {config.layer(i).attention for i in range(config.num_layers)}
-    return next((kind for kind in ("latent", "linear", "kda") if kind in kinds), None)
+    return next((kind for kind in ("latent", "linear", "kda", "eva") if kind in kinds), None)
 
 
 class Generator:

@@ -603,6 +603,52 @@ PRESETS = {
         routed_scaling_factor=2.446,
         held_experts=(0, 1, 2, 3),
     ),
+    "evabyte_6_5b": ModelConfig(
+        # HF EvaByte/EvaByte (model_type evabyte, attention_class eva): a byte-level model, vocabulary 320. Every
+        # layer a pre-norm Llama block (norms with a unit offset, no bias) whose mixer is EVA attention: exact
+        # softmax inside the token's own aligned window of 2048, every earlier window through one learned summary a
+        # chunk of 16 (ops/eva_attention.py); the residual stream in float32; eight next-byte heads side by side in
+        # one untied lm_head (head i at position t answers byte t + 1 + i). 32 x 202,391,552 (4 x 4096^2 + 3 x 4096 x
+        # 11008, + 8,192 of phi and mu, + 8,192 of norms) + 1,310,720 (embedding) + 10,485,760 (heads) + 4,096 (final
+        # norm) = 6,488,330,240 parameters. Training path only.
+        name="evabyte_6_5b",
+        vocab_size=320,
+        hidden_size=4096,
+        intermediate_size=11008,
+        num_layers=32,
+        num_heads=32,
+        num_kv_heads=32,
+        rope_theta=100_000.0,
+        max_position_embeddings=32768,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        zero_centered_norm=True,
+        eva_window=2048,
+        eva_chunk=16,
+        num_pred_heads=8,
+        fp32_residual=True,
+    ),
+    "tiny_evabyte": ModelConfig(
+        # EvaByte's structure at toy widths (tests, the benchmark's CPU rehearsal): windows of 32 tokens in chunks
+        # of 4, so a row of 160 is five windows
+        name="tiny_evabyte",
+        vocab_size=320,
+        hidden_size=64,
+        intermediate_size=176,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        rope_theta=100_000.0,
+        max_position_embeddings=2048,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        zero_centered_norm=True,
+        eva_window=32,
+        eva_chunk=4,
+        num_pred_heads=8,
+        fp32_residual=True,
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -688,6 +734,10 @@ def to_hf_dict(mc: ModelConfig) -> dict:
         "partial_rotary_factor": mc.partial_rotary_factor,
         "attention_output_gate": mc.attention_output_gate,
         "shared_expert_gate": mc.shared_expert_gate,
+        "eva_window": mc.eva_window,
+        "eva_chunk": mc.eva_chunk,
+        "num_pred_heads": mc.num_pred_heads,
+        "fp32_residual": mc.fp32_residual,
         **({
             "linear_num_key_heads": mc.linear_num_key_heads,
             "linear_num_value_heads": mc.linear_num_value_heads,
@@ -977,6 +1027,37 @@ def _kimi_linear_fields(g) -> dict:
     )
 
 
+def _evabyte_fields(g) -> dict:
+    """ModelConfig fields of an ``evabyte`` config (EvaByte/EvaByte): EVA attention in every layer by ``window_size``
+    and ``chunk_size``, norms with a unit offset (``norm_add_unit_offset``), the residual stream in float32
+    (``fp32_skip_add``), ``num_pred_heads`` next-byte heads in one untied head. Whatever of it this framework does not
+    implement is refused by name, before any weight loads."""
+    problems = []
+    if g("attention_class", "eva") != "eva":
+        problems.append(f"attention_class {g('attention_class')!r} (implemented: 'eva')")
+    if g("num_chunks") is not None:
+        problems.append(f"num_chunks {g('num_chunks')} (implemented: chunks of chunk_size tokens, their number by the row)")
+    window, chunk = g("window_size"), g("chunk_size")
+    if not window or not chunk or window % chunk:
+        problems.append(f"window_size {window} that chunk_size {chunk} does not divide")
+    if g("rope_scaling"):
+        problems.append(f"rope_scaling {g('rope_scaling')!r} (implemented: none)")
+    if g("tie_word_embeddings"):
+        problems.append("tie_word_embeddings (implemented: an untied head of num_pred_heads x vocab_size columns)")
+    if (g("num_key_value_heads") or g("num_attention_heads")) != g("num_attention_heads"):
+        problems.append(f"num_key_value_heads {g('num_key_value_heads')} (implemented: one key head a query head)")
+    if problems:
+        raise ValueError("evabyte config has " + "; ".join(problems))
+    return dict(
+        eva_window=window,
+        eva_chunk=chunk,
+        num_pred_heads=g("num_pred_heads") or 1,
+        fp32_residual=bool(g("fp32_skip_add", False)),
+        zero_centered_norm=bool(g("norm_add_unit_offset", False)),
+        sliding_window=None,
+    )
+
+
 def load_model_config(path: str) -> ModelConfig:
     """Read ``path/config.json`` (HF layout) into a ModelConfig — the ONE
     place train-time (trainer._resolve_model_config) and inference-time
@@ -1069,6 +1150,8 @@ def from_hf_config(hf_config) -> ModelConfig:
             f"unsupported rope_scaling type {rs_type!r}; supported: "
             "'llama3' (Llama-3.1 smoothed NTK), 'yarn', 'linear', 'default'"
         )
+    # (an evabyte config is read, and refused by name, before the dataclass's own checks see its keys)
+    evabyte = _evabyte_fields(g) if mt == "evabyte" and not framework_save else {}
     mc = ModelConfig(
         name=g("model_type", "hf_model"),
         vocab_size=g("vocab_size"),
@@ -1165,6 +1248,11 @@ def from_hf_config(hf_config) -> ModelConfig:
         linear_conv_kernel_dim=g("linear_conv_kernel_dim") or 4,
         linear_decay_rank=g("linear_decay_rank") or 0,
         linear_gate_rank=g("linear_gate_rank") or 0,
+        # (explicit keys of this framework's own save; an evabyte config's come from _evabyte_fields)
+        eva_window=g("eva_window") or 0,
+        eva_chunk=g("eva_chunk") or 0,
+        num_pred_heads=g("num_pred_heads") or 1,
+        fp32_residual=bool(g("fp32_residual", False)),
         # MoE (HF MixtralConfig naming). router_aux_loss_coef=0.0 is a
         # legitimate explicit choice (aux disabled) — only None falls back.
         num_experts=g("num_local_experts", 0) or 0,
@@ -1181,4 +1269,6 @@ def from_hf_config(hf_config) -> ModelConfig:
         return dataclasses.replace(mc, **_afmoe_fields(g))
     if kimi:
         return dataclasses.replace(mc, **_kimi_linear_fields(g))
+    if evabyte:
+        return dataclasses.replace(mc, **evabyte)
     return dataclasses.replace(mc, **deepseek) if deepseek else mc

@@ -203,6 +203,12 @@ def _kimi_from_stored(state: Dict[str, np.ndarray], config: ModelConfig) -> Dict
     return out
 
 
+# HF evabyte (EvaByte/EvaByte) stores EVA attention's two leaves a head as ``[1, heads, 1, 1, d]`` (as remembered: they
+# broadcast against ``[batch, heads, windows, chunks, d]``); the tree keeps them ``[heads, d]``. Every other name of
+# the checkpoint is a Llama block's, and ``lm_head.weight`` is ``[num_pred_heads x vocab, hidden]``, head by head.
+_EVA_LEAVES = ("adaptive_phi", "adaptive_mu_k")
+
+
 def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dict[str, np.ndarray]:
     """params pytree -> {hf_name: numpy array (torch layout)}. ``config`` is
     needed for a model with latent attention or held experts (the rope
@@ -240,6 +246,9 @@ def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dic
                     state[f"{base}.{name}.weight"] = np.ascontiguousarray(part.T)
                 continue
             path = tuple(_afmoe_renamed(".".join(path), to_stored=True).split("."))
+        if leaf_name in _EVA_LEAVES:
+            state[".".join(path)] = np.ascontiguousarray(arr[None, :, None, None, :])
+            continue
         if path[-2:] == ("conv1d", "weight"):
             # [taps, channels] -> torch Conv1d's [channels, 1, taps]
             state[".".join(path)] = np.ascontiguousarray(arr.T[:, None, :])
@@ -325,6 +334,8 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
             path = tuple(name.split("."))
             if path[-2:] == ("conv1d", "weight"):  # torch Conv1d's [channels, 1, taps] -> [taps, channels]
                 arr = np.ascontiguousarray(arr[:, 0, :].T)
+            elif path[-1] in _EVA_LEAVES:
+                arr = np.ascontiguousarray(arr.reshape(config.num_heads, -1))
         flat[path] = arr
     for path in [p for p in flat if p[-3:] == _AFMOE_GATE]:  # afmoe's gate_proj into q_proj, [q | gate] by head
         q_path = path[:-2] + ("q_proj", _KERNEL_LEAF)

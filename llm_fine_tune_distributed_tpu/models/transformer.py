@@ -32,7 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from llm_fine_tune_distributed_tpu.config import LayerPlan, ModelConfig
 from llm_fine_tune_distributed_tpu.observe.xla import scope
-from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
+from llm_fine_tune_distributed_tpu.ops import eva_attention, gated_delta, moe
 from llm_fine_tune_distributed_tpu.ops import rope as rope_ops
 from llm_fine_tune_distributed_tpu.ops.attention import attention, head_major_reason, softcap, xla_attention
 from llm_fine_tune_distributed_tpu.ops.int8 import (
@@ -469,6 +469,52 @@ def _kda_mixer(attn_p, hid, cos, sin, *, config, lin, segment_ids, cache_entry, 
     return lin(o.astype(hid.dtype), attn_p["out_proj"]), None
 
 
+def _init_eva_attention(keys, config: ModelConfig, dense, dtype):
+    """A layer of heads (no bias, as many key heads as query heads) with EVA attention's two leaves a head, ``[heads,
+    d]`` each: ``adaptive_phi``, the pooling's query, and ``adaptive_mu_k``, what every summary key is moved by; both
+    drawn ``clip(normal, -1, 1) * d ** -0.5`` as the family draws them."""
+    attn = _init_heads_attention(keys, config, dense, dtype)
+    shape, scale = (config.num_heads, config.resolved_head_dim), config.resolved_head_dim ** -0.5
+    for name in ("adaptive_phi", "adaptive_mu_k"):
+        attn[name] = (jnp.clip(jax.random.normal(next(keys), shape, jnp.float32), -1.0, 1.0) * scale).astype(dtype)
+    return attn
+
+
+def _eva_mixer(attn_p, hid, cos, sin, *, config, plan, lin, rope, attention_impl, mesh, segment_ids, mask, cache_entry, **_):
+    """An EVA mixer (``ops/eva_attention.py``): the projections, rope and the hand-over of the layout as any layer of
+    heads makes them (``_heads_qkv``, or the fused IN pass where the operator runs its kernels), then the operator in
+    the attention call's place: softmax over the token's own aligned window and one learned summary a chunk of every
+    earlier window. No padding mask: the operator is causal, and what a right-padded row computes at its pads
+    reaches no real token."""
+    if segment_ids is not None:
+        raise NotImplementedError(
+            f"model {config.name!r} has EVA attention and the batch is packed (segment_ids): windows and chunks would "
+            "have to restart at every document's start, which ops/eva_attention.py does not do yet (ROADMAP.md, "
+            "Reach); train it with packing off"
+        )
+    if cache_entry is not None or mask is not None:
+        raise NotImplementedError(
+            "an EVA layer has the training form only; its cache is a window of keys and values beside a growing list "
+            "of summaries, not one buffer of keys and values"
+        )
+    b, s, _ = hid.shape
+    d = config.resolved_head_dim
+    # the fused IN pass hands q, k, v over head-major, as the operator's kernels read them: where those run
+    if eva_attention._program((b, config.num_heads, s, d), hid.dtype, window=config.eva_window, chunk=config.eva_chunk, mesh=mesh) != "kernels":
+        why_xla = "the operator runs its XLA form"
+    elif attention_impl != "flash":
+        why_xla = f"attention_impl is {attention_impl!r}"
+    else:
+        why_xla = rope_ops.why_not_fused(b, s, d, cos)
+    rope_ops.count_call((b, s, config.num_heads, config.num_kv_heads, d, cos.shape[-1], "", ""), why_xla)
+    q, k, v, _ = (_heads_qkv_head_major if why_xla is None else _heads_qkv)(attn_p, hid, cos, sin, config, lin, rope)
+    out = eva_attention.eva_attention(
+        q, k, v, attn_p["adaptive_phi"], attn_p["adaptive_mu_k"], window=config.eva_window, chunk=config.eva_chunk,
+        scale=d ** -0.5, head_major=why_xla is None, mesh=mesh,
+    )
+    return lin(out.reshape(b, s, config.num_heads * d), attn_p["o_proj"]), None
+
+
 # LayerPlan.attention -> (the layer's subtree of that kind, the scope its device
 # time is read under, its half of init_params, the mixer: normed input ->
 # (output [b, s, hidden], new cache entry))
@@ -477,6 +523,7 @@ _ATTENTION = {
     "latent": ("self_attn", "attn", _init_latent_attention, _softmax_mixer(_latent_qkv)),
     "linear": ("linear_attn", "linear_attn", _init_linear_attention, _linear_mixer),
     "kda": ("linear_attn", "linear_attn", _init_kda_attention, _kda_mixer),
+    "eva": ("self_attn", "attn", _init_eva_attention, _eva_mixer),
 }
 
 
@@ -601,7 +648,7 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
     """Random init (normal 0.02, HF convention). Returns the params pytree."""
     h, v = config.hidden_size, config.vocab_size
     kda = any(config.layer(i).attention == "kda" for i in range(config.num_layers))
-    keys = iter(jax.random.split(rng, 2 + config.num_layers * (17 if kda else 7)))  # (a layer draws at most 7, a KDA mixer's 14)
+    keys = iter(jax.random.split(rng, 2 + config.num_layers * (17 if kda else 9)))  # (a layer draws at most 9, a KDA mixer's 14)
 
     def dense(key, shape):
         return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
@@ -640,7 +687,8 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
         }
     }
     if not config.tie_word_embeddings:
-        params["lm_head"] = {"kernel": dense(next(keys), (h, v))}
+        # (several next-token heads lie side by side by column: head i's vocabulary at columns [i * v, (i + 1) * v))
+        params["lm_head"] = {"kernel": dense(next(keys), (h, v * config.num_pred_heads))}
     return params
 
 
@@ -794,6 +842,8 @@ def _block(
     subtree, mixer_scope, _, mixer = _ATTENTION[plan.attention]
     with scope(mixer_scope):
         hid = rms_norm(x, lp["input_layernorm"]["weight"], eps, zero_centered=zc)
+        if config.fp32_residual:  # the stream and its two adds in float32, the norms' outputs in the compute dtype
+            hid = hid.astype(compute_dtype)
         attn_out, new_entry = mixer(
             lp[subtree], hid, cos, sin, config=config, plan=plan, lin=lin,
             rope=plan.rope if rope_flag is None else rope_flag, compute_dtype=compute_dtype,
@@ -809,6 +859,8 @@ def _block(
     with scope("mlp"):
         pre_ffn = "pre_feedforward_layernorm" if config.sandwich_norms else "post_attention_layernorm"
         hid = rms_norm(x, lp[pre_ffn]["weight"], eps, zero_centered=zc)
+        if config.fp32_residual:
+            hid = hid.astype(compute_dtype)
         subtree, _, feed_forward = _FEED_FORWARD[plan.feed_forward]
         y, counted = feed_forward(
             lp[subtree], hid, lin, config, compute_dtype=compute_dtype, mesh=mesh,
@@ -849,8 +901,16 @@ def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = N
     ``ops/flash_attention.worth_keeping_across_remat`` at this model's head
     widths. What the kernel sees is the whole row on every mesh that calls it
     (batch and heads are sharded around it, Ulysses hands it the whole
-    sequence of a head subset; ring attention does not call it)."""
+    sequence of a head subset; ring attention does not call it).
+
+    An EVA layer (``eva_window``) keeps the same two names of its operator by the same rule, with the row's length
+    standing for twice the mean number of keys a query reads, tokens of its window and summaries of the earlier ones
+    together (at rows of 32,768, windows of 2048 and chunks of 16: 3,969 against a hidden size of 4096, recompute;
+    what would be kept there is 256 MiB of ``o`` and 512 MiB of ``lse`` in its padded layout a layer)."""
     from llm_fine_tune_distributed_tpu.ops.flash_attention import worth_keeping_across_remat
+
+    if config.eva_window:
+        seq = 2 * eva_attention.pairs_a_row(seq, config.eva_window, config.eva_chunk) // seq
 
     if config.kv_lora_rank:
         d_qk, d_v = config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim
@@ -1092,6 +1152,8 @@ def forward_with_report(
             # Gemma normalizer: HF multiplies by a sqrt(hidden) scalar cast to
             # the activation dtype first — mirror the cast for bf16 bit-parity
             x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
+        if config.fp32_residual:
+            x = x.astype(jnp.float32)
     tables = rope_tables(config, positions)
 
     explicit_mask = None

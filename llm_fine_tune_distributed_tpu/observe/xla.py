@@ -839,6 +839,9 @@ STEP_SCOPES = (
     # linear mixer does not have: the low-rank products of the decay and of the
     # output gate, the softplus and the decay's sign and scale
     "kda_gates",
+    # inside "attn" of an EVA layer (ops/eva_attention.py), between the IN pass and o_proj: the pooling of keys and
+    # values into one summary a chunk (both passes), and everything else (the kernels of both key sources)
+    "eva_pool", "eva_agg",
 )
 
 

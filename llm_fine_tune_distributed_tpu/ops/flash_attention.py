@@ -283,7 +283,14 @@ def _key_rows(segments, block):
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _fwd(q, k, v, segments, *, scale, block, groups, interpret):
+def _limit(operands, d, vmem_limit_bytes):
+    """A resident call's compiler parameters: its own budget, or the limit a caller that knows its body's room
+    better hands over (``ops/eva_attention.py``: one query head a kv head at a block of 2048 lists few operands,
+    and the body's strips against 2048 keys need more than the 16 MiB the budget leaves them)."""
+    return _compiler_params(operands, d) if vmem_limit_bytes is None else pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
+
+
+def _fwd(q, k, v, segments, *, scale, block, groups, interpret, vmem_limit_bytes=None):
     b, hq, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     out_shape = (
@@ -299,7 +306,7 @@ def _fwd(q, k, v, segments, *, scale, block, groups, interpret):
         in_specs=[_SMEM] + _block_specs(ins),
         out_specs=tuple(_block_specs(outs)),
         out_shape=out_shape,
-        compiler_params=_compiler_params(ins + outs, d),
+        compiler_params=_limit(ins + outs, d, vmem_limit_bytes),
         interpret=interpret,
         name="flash_attention_fwd",
     )(
@@ -510,7 +517,7 @@ def _dkv_kernel(one_segment_ref, seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
     _with_or_without_segments(one_segment_ref[batch * pl.num_programs(2) + jk], program)
 
 
-def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
+def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret, vmem_limit_bytes=None):
     """Head-major inputs: q/o/do/lse [b, hq, ...], k/v [b, hkv, s, d]."""
     b, hq, sq, d = q.shape
     hkv, d_v = k.shape[1], v.shape[3]
@@ -524,7 +531,7 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
         in_specs=[_SMEM] + _block_specs(ins),
         out_specs=_block_specs(outs)[0],
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        compiler_params=_compiler_params(ins + outs, d),
+        compiler_params=_limit(ins + outs, d, vmem_limit_bytes),
         interpret=interpret,
         name="flash_attention_dq",
     )(
@@ -542,7 +549,7 @@ def _bwd(q, k, v, segments, o, lse, do, *, scale, block, groups, interpret):
             jax.ShapeDtypeStruct((b, hkv, sq, d), k.dtype),
             jax.ShapeDtypeStruct((b, hkv, sq, d_v), v.dtype),
         ),
-        compiler_params=_compiler_params(ins + outs, d),
+        compiler_params=_limit(ins + outs, d, vmem_limit_bytes),
         interpret=interpret,
         name="flash_attention_dkv",
     )(_one_segment(segments, block, from_start=False), seg_column, q, k, v, do, lse, delta)

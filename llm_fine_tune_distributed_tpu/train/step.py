@@ -37,26 +37,35 @@ def chunked_ce_sum(params, hidden, targets, mask, model_config: ModelConfig, chu
     ``extra_mask``: optional second mask — returns (sum, extra_sum) from ONE
     streamed unembed (the answer-only eval metric must not double the eval
     pause it exists to diagnose).
+
+    A model of several next-token heads (``num_pred_heads`` P, ``heads_ahead``)
+    hands ``targets`` and the masks as ``[batch, seq, P]``: one ``[chunk, P x
+    vocab]`` product a chunk, read as P blocks of columns, each against its
+    own targets under its own mask, in the same pass over the hidden rows.
     """
     b, s, h = hidden.shape
+    heads = targets.shape[2:]  # () for the one head, (P,) for several
     pad = (-s) % chunk_size
     if pad:
         hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
-        targets = jnp.pad(targets, ((0, 0), (0, pad)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad)))
+        by_position = ((0, 0), (0, pad)) + ((0, 0),) * len(heads)
+        targets = jnp.pad(targets, by_position)
+        mask = jnp.pad(mask, by_position)
         if extra_mask is not None:
-            extra_mask = jnp.pad(extra_mask, ((0, 0), (0, pad)))
+            extra_mask = jnp.pad(extra_mask, by_position)
     n = (s + pad) // chunk_size
     # [n_chunks, batch, chunk, ...] so lax.map scans over chunks
     hc = hidden.reshape(b, n, chunk_size, h).transpose(1, 0, 2, 3)
-    tc = targets.reshape(b, n, chunk_size).transpose(1, 0, 2)
+    tc = jnp.moveaxis(targets.reshape(b, n, chunk_size, *heads), 1, 0)
     masks = (mask,) if extra_mask is None else (mask, extra_mask)
-    mcs = tuple(m.reshape(b, n, chunk_size).transpose(1, 0, 2) for m in masks)
+    mcs = tuple(jnp.moveaxis(m.reshape(b, n, chunk_size, *heads), 1, 0) for m in masks)
 
     @jax.checkpoint
     def one_chunk(args):
         h_c, t_c, m_cs = args
         logits = unembed(params, h_c, model_config, compute_dtype=compute_dtype, mesh=mesh)
+        if heads:
+            logits = logits.reshape(*logits.shape[:-1], *heads, model_config.vocab_size)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, t_c)
         return jnp.stack([(ce * m).sum() for m in m_cs])
 
@@ -64,6 +73,17 @@ def chunked_ce_sum(params, hidden, targets, mask, model_config: ModelConfig, chu
     if extra_mask is None:
         return sums[0]
     return sums[0], sums[1]
+
+
+def heads_ahead(x, heads: int):
+    """What the next-token heads of a row ``x [batch, seq]`` (ids, or a mask) are held to, for the positions 0 ..
+    seq - 2 that predict: ``x[:, 1:]`` for the usual one head; for P heads ``[batch, seq - 1, P]`` with head i's
+    entry at position t the row's ``t + 1 + i``, and 0 (no target, masked out) where that lies past the row's end."""
+    if heads == 1:
+        return x[:, 1:]
+    s = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (0, heads - 1)))
+    return jnp.stack([padded[:, 1 + i: s + i] for i in range(heads)], axis=-1)
 
 
 def vocab_chunked_ce_sum(params, hidden, targets, mask, model_config: ModelConfig,
@@ -212,6 +232,14 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
     # from the freeze mask; forward() runs those leading layers w8a8 with a
     # boundary stop_gradient when frozen_compute="int8". The default "bf16"
     # (or boundary 0 — lora/qlora/full fine-tune) leaves forward untouched.
+    # A model of P next-token heads: head i at position t answers token t + 1 + i, and the loss is the mean of the
+    # heads' cross-entropies, each the mean over the positions whose target lies inside the row and under the loss
+    # mask. ``weigh`` makes a head's masked SUM that mean over P; one head keeps the sum and the division below.
+    heads = model_config.num_pred_heads
+    if heads > 1 and vocab_chunk is not None:
+        raise ValueError(f"model {model_config.name!r} has {heads} next-token heads: loss_vocab_chunk streams one "
+                         "head's vocabulary; use loss_chunk_size (or neither)")
+    weigh = (lambda m: m) if heads == 1 else (lambda m: m / (heads * jnp.maximum(m.sum(axis=(0, 1)), 1.0)))
     frozen_compute = getattr(train_config, "frozen_compute", "bf16")
     if frozen_compute not in ("bf16", "int8"):
         raise ValueError(
@@ -254,12 +282,12 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             frozen_layers=frozen_layers,
             frozen_compute=frozen_compute,
         )
-        targets = batch["input_ids"][:, 1:]
-        mask = batch["loss_mask"][:, 1:].astype(jnp.float32)
-        tokens = jnp.maximum(mask.sum(), 1.0)
+        targets = heads_ahead(batch["input_ids"], heads)
+        mask = heads_ahead(batch["loss_mask"], heads).astype(jnp.float32)
+        tokens = jnp.maximum((mask if heads == 1 else mask[..., 0]).sum(), 1.0)
         amask = None
         if "completion_mask" in batch:
-            amask = batch["completion_mask"][:, 1:].astype(jnp.float32)
+            amask = heads_ahead(batch["completion_mask"], heads).astype(jnp.float32)
         # ce_fn(mask) -> sum; ce_fn(mask, extra) -> (sum, extra_sum) from a
         # SINGLE unembed on every path. One scope for the three of them (on
         # the full-logits path the unembed itself ran inside forward, under
@@ -276,15 +304,19 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
                     compute_dtype, mesh=_mesh, extra_mask=e,
                 )
             else:
-                ce = optax.softmax_cross_entropy_with_integer_labels(out[:, :-1], targets)
+                logits = out[:, :-1]
+                if heads > 1:
+                    logits = logits.reshape(*logits.shape[:-1], heads, model_config.vocab_size)
+                ce = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
                 ce_fn = lambda m, e=None: (
                     (ce * m).sum() if e is None else ((ce * m).sum(), (ce * e).sum())
                 )
             if amask is not None:
-                ce_sum, ans_sum = ce_fn(mask, amask)
+                ce_sum, ans_sum = ce_fn(weigh(mask), weigh(amask))
             else:
-                ce_sum = ce_fn(mask)
-        loss = ce_sum / tokens
+                ce_sum = ce_fn(weigh(mask))
+        # several heads: the weights have made each head's sum its mean over its own targets, an eighth of it
+        loss = ce_sum / tokens if heads == 1 else ce_sum
         if include_router_aux and "router_aux" in report:
             # The load-balancing loss joins the TRAIN objective only (eval
             # loss stays pure CE so perplexity/best-model tracking is
@@ -297,7 +329,8 @@ def make_loss_fn(model_config: ModelConfig, train_config: TrainConfig, activatio
             loss = loss + model_config.router_aux_coef * aux
         stats = {"tokens": tokens}
         if amask is not None:
-            stats.update(answer_ce_sum=ans_sum, answer_tokens=amask.sum())
+            answer_tokens = (amask if heads == 1 else amask[..., 0]).sum()
+            stats.update(answer_ce_sum=ans_sum if heads == 1 else ans_sum * answer_tokens, answer_tokens=answer_tokens)
         if "expert_load" in report:
             stats["expert_load"] = report["expert_load"]
         return loss, stats

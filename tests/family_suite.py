@@ -120,7 +120,9 @@ class CellStep:
 
 
 @dataclasses.dataclass(frozen=True)
-class Family:
+class Model:
+    """What any model family states, with routed experts or without (``ModelSuite``)."""
+
     mc: ModelConfig             # the configuration under test
     bench_cfg: Callable         # ModelConfig -> the benchmark's configuration dict (the published names)
     weights: ModuleType         # benchmarks/chipbench/weights_*.py
@@ -131,12 +133,18 @@ class Family:
     accum: int
     rtol: float                 # logits, loss, every gradient (the family's docstring says why)
     delta_tol: float            # the worst leaf's change over two steps at bfloat16 masters
-    pairs_per_token: tuple      # (above, below): the step's ``expert_pairs_per_token`` at this seed
     buffers: tuple              # the leaves ``trainable_mask`` holds back though nothing is frozen
     checkpoint_names: tuple     # HF names the stored state has to hold
-    shares: Shares
     refusals: Refusals
     published: Published
+
+
+@dataclasses.dataclass(frozen=True)
+class Family(Model):
+    """A family of routed experts: what ``FamilySuite`` asks besides."""
+
+    pairs_per_token: tuple      # (above, below): the step's ``expert_pairs_per_token`` at this seed
+    shares: Shares
     rules: Rules
     cell: CellStep
 
@@ -254,10 +262,12 @@ def mixer_passes(layers):
 # -- the suite ----------------------------------------------------------------
 
 
-class FamilySuite:
-    """The shared tests; a subclass (``Test...``) sets ``family`` and overrides the hooks it has something for."""
+class ModelSuite:
+    """The shared tests of any model against its plain reference, with routed experts or without; a subclass
+    (``Test...``) sets ``family`` (a ``Model``) and overrides the hooks it has something for. ``FamilySuite`` adds
+    what only a family of routed experts is asked."""
 
-    family: Family
+    family: Model
 
     def pytest_generate_tests(self, metafunc):
         if "refused" in metafunc.fixturenames:  # one case a refused key, each counted
@@ -267,17 +277,10 @@ class FamilySuite:
     # hooks: what only one family asserts, beside the shared assertions of the test that calls each
     def check_leaves(self, own): pass
     def check_gradients(self, got): pass
-    def check_counters(self, two_steps, ids): pass
-    def check_shares(self, parts, lp, h, items, shared_once): pass
+    def check_report(self, report, flat, ids): assert report == {}
     def check_published(self, mc, config): pass
     def check_refusal_base(self, mc): pass
     def check_checkpoint(self, state, params, flat): pass
-    def check_rules(self, monkeypatch): pass
-    def before_the_cells_step(self, monkeypatch): pass
-
-    def check_the_cells_step(self, step):
-        """What the compiled text of the family's cell must hold: every family has something."""
-        raise NotImplementedError(f"{type(self).__name__} does not say what its cell's compiled step holds")
 
     @pytest.fixture(scope="class")
     def flat(self):
@@ -328,17 +331,8 @@ class FamilySuite:
     def test_forward_logits_agree_with_the_reference(self, flat, ids):
         f = self.family
         got, report = _logits(_params(flat), ids[0, 0], f.mc)
-        assert set(report) == {"expert_load"}
         assert _rel(got, f.ref.logits(flat, f.bench_cfg(f.mc), ids[0, 0])) < f.rtol
-        # the program's counter against the reference's selection, expert layer by expert layer
-        chosen = f.ref.selections(flat, f.bench_cfg(f.mc), ids[0, 0])
-        assert sorted(chosen) == [i for i in range(f.mc.num_layers) if f.mc.layer(i).feed_forward == "grouped_experts"]
-        held = list(f.mc.held_expert_ids)
-        want_load = np.stack([np.asarray(chosen[i]).sum((0, 1))[held] for i in sorted(chosen)])
-        differ = float(np.abs(np.asarray(report["expert_load"]) - want_load).sum()) / (ids[0, 0].size * f.mc.num_experts_per_tok * len(chosen))
-        print(f"share of (token, expert) choices on which program and reference differ: {differ:.2e}")
-        np.testing.assert_array_equal(np.asarray(report["expert_load"]), want_load)
-
+        self.check_report(report, flat, ids)
     def test_loss_and_gradient_norm_agree_with_the_reference(self, two_steps):
         assert abs(float(two_steps["metrics"]["loss"]) - two_steps["want"]["losses"][0]) < self.family.rtol
         assert abs(float(two_steps["metrics"]["grad_norm"]) / two_steps["want"]["grad_norm"] - 1) < self.family.rtol
@@ -364,6 +358,65 @@ class FamilySuite:
         frozen, came = two_steps["new"].frozen, two_steps["state"].frozen
         assert tuple(frozen) == self.family.buffers
         assert all(np.array_equal(np.asarray(frozen[k]), np.asarray(came[k])) for k in came)
+
+    def test_published_config_builds_and_round_trips(self):
+        p = self.family.published
+        if not os.path.exists(CATALOG):
+            pytest.skip("the driver's catalog is not installed here")
+        with open(CATALOG) as f:
+            row = [json.loads(line) for line in f if f'"{p.catalog_name}"' in line][0]
+        mc = from_hf_config(SimpleNamespace(**row["config"]))  # verbatim
+        assert dataclasses.replace(mc, name=p.preset) == get_preset(p.preset)
+        assert p.params[0] < mc.num_params < p.params[1]
+        assert mc.replace(**p.cut).num_params == p.cut_params  # the cell's count
+        for preset in (p.preset, p.tiny):
+            assert from_hf_config(SimpleNamespace(**to_hf_dict(get_preset(preset)))) == get_preset(preset)
+        self.check_published(mc, row["config"])
+
+    def test_what_is_not_implemented_is_refused_by_name(self, refused):
+        r, (key, value) = self.family.refusals, refused
+        self.check_refusal_base(from_hf_config(SimpleNamespace(**r.base)))
+        with pytest.raises(ValueError, match=r.match(key)):
+            from_hf_config(SimpleNamespace(**dict(r.base, **{key: value})))
+
+    def test_checkpoint_names_round_trip(self, flat):
+        f = self.family
+        params = _params(flat)
+        state = hf_io.pytree_to_hf_state_dict(params, f.mc)
+        for name in f.checkpoint_names:
+            assert name in state, name
+        back = flatten_dict(hf_io.hf_state_dict_to_pytree(state, f.mc))
+        for k, v in flatten_dict(params).items():
+            np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(v), err_msg=k)
+        self.check_checkpoint(state, params, flat)
+
+
+class FamilySuite(ModelSuite):
+    """``ModelSuite`` for a family of routed experts (``family`` a ``Family``): the step's expert counters, the shares
+    that add up, the sharding, freeze and pipeline rules, the router's precision, the cell's compiled step."""
+
+    family: Family
+
+    def check_counters(self, two_steps, ids): pass
+    def check_shares(self, parts, lp, h, items, shared_once): pass
+    def check_rules(self, monkeypatch): pass
+    def before_the_cells_step(self, monkeypatch): pass
+
+    def check_the_cells_step(self, step):
+        """What the compiled text of the family's cell must hold: every family has something."""
+        raise NotImplementedError(f"{type(self).__name__} does not say what its cell's compiled step holds")
+
+    def check_report(self, report, flat, ids):
+        """The program's counter against the reference's selection, expert layer by expert layer."""
+        f = self.family
+        assert set(report) == {"expert_load"}
+        chosen = f.ref.selections(flat, f.bench_cfg(f.mc), ids[0, 0])
+        assert sorted(chosen) == [i for i in range(f.mc.num_layers) if f.mc.layer(i).feed_forward == "grouped_experts"]
+        held = list(f.mc.held_expert_ids)
+        want_load = np.stack([np.asarray(chosen[i]).sum((0, 1))[held] for i in sorted(chosen)])
+        differ = float(np.abs(np.asarray(report["expert_load"]) - want_load).sum()) / (ids[0, 0].size * f.mc.num_experts_per_tok * len(chosen))
+        print(f"share of (token, expert) choices on which program and reference differ: {differ:.2e}")
+        np.testing.assert_array_equal(np.asarray(report["expert_load"]), want_load)
 
     def test_the_step_reports_its_expert_counters(self, two_steps, ids):
         f, m = self.family, two_steps["metrics"]
@@ -401,37 +454,6 @@ class FamilySuite:
         assert _rel(total, want) < f.rtol
         assert pairs == 2 * s.tokens * mc.num_experts_per_tok
         self.check_shares(parts, lp, h, items, shared_once)
-
-    def test_published_config_builds_and_round_trips(self):
-        p = self.family.published
-        if not os.path.exists(CATALOG):
-            pytest.skip("the driver's catalog is not installed here")
-        with open(CATALOG) as f:
-            row = [json.loads(line) for line in f if f'"{p.catalog_name}"' in line][0]
-        mc = from_hf_config(SimpleNamespace(**row["config"]))  # verbatim
-        assert dataclasses.replace(mc, name=p.preset) == get_preset(p.preset)
-        assert p.params[0] < mc.num_params < p.params[1]
-        assert mc.replace(**p.cut).num_params == p.cut_params  # the cell's count
-        for preset in (p.preset, p.tiny):
-            assert from_hf_config(SimpleNamespace(**to_hf_dict(get_preset(preset)))) == get_preset(preset)
-        self.check_published(mc, row["config"])
-
-    def test_what_is_not_implemented_is_refused_by_name(self, refused):
-        r, (key, value) = self.family.refusals, refused
-        self.check_refusal_base(from_hf_config(SimpleNamespace(**r.base)))
-        with pytest.raises(ValueError, match=r.match(key)):
-            from_hf_config(SimpleNamespace(**dict(r.base, **{key: value})))
-
-    def test_checkpoint_names_round_trip(self, flat):
-        f = self.family
-        params = _params(flat)
-        state = hf_io.pytree_to_hf_state_dict(params, f.mc)
-        for name in f.checkpoint_names:
-            assert name in state, name
-        back = flatten_dict(hf_io.hf_state_dict_to_pytree(state, f.mc))
-        for k, v in flatten_dict(params).items():
-            np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(v), err_msg=k)
-        self.check_checkpoint(state, params, flat)
 
     def test_sharding_freeze_and_pipeline_rules(self, monkeypatch):
         r = self.family.rules

@@ -144,6 +144,7 @@ PLAN_OF_PRESET = {
     "qwen3_next_80b_a3b": _QWEN3_NEXT, "tiny_qwen3_next": _QWEN3_NEXT,
     "trinity_mini": _TRINITY(2048, 2), "tiny_trinity": _TRINITY(32, 1),
     "kimi_linear_48b_a3b": _KIMI((3, 7, 11, 15, 19, 23, 26)), "tiny_kimi_linear": _KIMI((3,)),
+    "evabyte_6_5b": dict(attention="eva"), "tiny_evabyte": dict(attention="eva"),  # every layer, with rope
 }
 
 
@@ -418,14 +419,14 @@ def test_every_family_states_the_whole_description_and_keeps_no_helper_of_its_ow
     import os
 
     import family_suite
-    from family_suite import Family, FamilySuite
+    from family_suite import Family, FamilySuite, ModelSuite
 
     described = [c for c in vars(family_suite).values() if dataclasses.is_dataclass(c) and c.__module__ == "family_suite"]
-    assert Family in described and len(described) == 6
+    assert Family in described and len(described) == 7  # (Model: what a family without experts states, tests/test_eva.py)
     assert all(f.default is dataclasses.MISSING and f.default_factory is dataclasses.MISSING
                for c in described for f in dataclasses.fields(c))
     may_be_none = {"redraw", "shared_once", "mc"}  # each read by the suite where it is None: "as drawn", "nothing", "its own"
-    shared = {name for name in vars(FamilySuite) if name.startswith("test_")}
+    shared = {name for suite in (ModelSuite, FamilySuite) for name in vars(suite) if name.startswith("test_")}
     assert len(shared) == 13
     theirs = {"_params", "_rel", "_state", "_train_config", "_batch", "_logits", "_logit_gap", "_bfloat16_gaps",
               "flat", "ids", "two_steps", "one_step"}

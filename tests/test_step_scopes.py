@@ -98,10 +98,12 @@ LINEAR_LAYER_SCOPES = ("linear_attn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "
 GATED_BLOCK_SCOPES = ("qk_norm", "out_norm")
 # what a Kimi Delta Attention mixer has and the other linear mixer has not: ``test_the_kda_mixers_scopes`` below
 KDA_SCOPES = ("kda_gates",)
+# the two halves of EVA attention between the IN pass and ``o_proj``: ``test_the_eva_mixers_scopes`` below
+EVA_SCOPES = ("eva_pool", "eva_agg")
 
 
 @pytest.mark.parametrize(
-    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES + KDA_SCOPES])
+    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES + KDA_SCOPES + EVA_SCOPES])
 def test_every_scope_of_the_vocabulary_is_named(paths, name):
     want = re.compile(r"^layer\d+$") if name == "layer" else re.compile(f"^{name}$")
     assert any(want.match(c) for p in paths for c in _components(p)), name
@@ -226,3 +228,15 @@ def test_the_kda_mixers_scopes():
     assert any(p.startswith("layer1/mlp/router") for p in paths) and any(p.startswith("layer1/mlp/shared_expert") for p in paths)
     assert not any(p.startswith("layer0/mlp/router") for p in paths)  # the leading dense layer
     assert not any("kda_gates" in p for p in _scoped_forward("tiny_qwen3_next"))
+
+
+def test_the_eva_mixers_scopes():
+    """An EVA layer (``tiny_evabyte``) keeps ``attn`` and ``attn_in`` as any layer of heads and names, between the IN
+    pass and ``o_proj``, the pooling (``eva_pool``) and everything else (``eva_agg``); the heads' product stays under
+    ``loss_head``; no other model carries the two names."""
+    paths = _scoped_forward("tiny_evabyte")
+    for layer in range(4):
+        for inside in ("attn/attn_in", "attn/eva_pool", "attn/eva_agg", "mlp"):
+            assert any(p.startswith(f"layer{layer}/{inside}") for p in paths), (layer, inside)
+    assert any(p.startswith("loss_head") for p in paths)
+    assert not any("eva_" in p for p in _scoped_forward("tiny"))

@@ -217,6 +217,71 @@ def test_in_pass_compiles_for_v5e_and_its_text_is_held(one_chip, cell):
     assert sum(x["bytes"] for x in programs.values()) <= 1.2 * landed, programs
 
 
+# EVA attention at the EvaByte cell's shapes (one row of 32,768 bytes, 32 heads of 128, windows of 2048 in chunks of 16):
+# the Mosaic programs of one layer's aggregate, forward and backward, and the bytes of text they landed at (my deviceless
+# lowering, PR 46; what every start of a process traces and lowers again, warm cache or not)
+EVA_PROGRAMS = {"flash_attention_fwd": 1, "flash_attention_dq": 1, "flash_attention_dkv": 1,
+                "eva_remote_fwd": 1, "eva_remote_dq": 1, "eva_remote_dkv": 1}
+EVA_LANDED = 97_908
+
+
+def test_eva_attention_compiles_for_v5e_and_its_text_is_held(one_chip):
+    """The operator's kernels at the cell's shapes through the ``custom_vjp``: the resident flash kernels take the row
+    cut into windows (512 rows of one block a head and window) under the room the operator asks for them, the remote
+    kernels the summaries resident in VMEM beside a query block, the dynamic trip counts, the aliased outputs; one
+    program each, their text inside its budget; and nothing of ``[T, T]`` or ``[T, T / 16]`` in the compiled program."""
+    from llm_fine_tune_distributed_tpu.ops import eva_attention as eva
+
+    b, h, t, d, window, chunk = 1, 32, 32768, D, 2048, 16
+    scale = d ** -0.5
+
+    def grads(q, k, v, phi, mu):
+        def loss(q, k, v, phi, mu):
+            ks, vs = eva.pool(k, v, phi, mu, chunk=chunk, scale=scale)
+            return jnp.sum(eva._make_aggregate(window, chunk, scale, False)(q, k, v, ks, vs).astype(jnp.float32) ** 2)
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, phi, mu)
+
+    shapes = [((b, h, t, d), jnp.bfloat16)] * 3 + [((h, d), jnp.float32)] * 2
+    with _without_call_stacks():
+        lowered = jax.jit(grads).lower(*(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in shapes))
+    text = lowered.compile().as_text()
+    assert sum("tpu_custom_call" in line for line in text.splitlines()) == 6
+    produced = [ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in text.splitlines()]
+    assert not [x for x in produced if f",{t},{t}]" in x or f",{t},{t // chunk}]" in x]
+    programs = mosaic_programs(lowered.as_text())
+    assert {name: x["programs"] for name, x in programs.items()} == EVA_PROGRAMS, programs
+    assert sum(x["bytes"] for x in programs.values()) <= 1.2 * EVA_LANDED, programs
+
+
+def test_the_evabyte_cells_step_compiles_for_v5e(topo, monkeypatch):
+    """The EvaByte cell's step as its traffic file states it (``benchmarks/step_memory.STEPS``: 8 frozen and 2 trained
+    layers, one row of 32,768, remat full, loss in chunks of 1024): what the chip's compiler accepts, under the 15.0
+    GiB line the cells are sized by, each layer's two forward kernels once (and once more recomputed in the two
+    trained layers: ``keeps_flash_outputs`` says recompute there), the backward kernels in the two trained layers
+    alone, the IN pass in every layer, and no buffer of ``[T, T]`` or ``[T, T / 16]``."""
+    import dataclasses
+
+    from benchmarks.step_memory import STEPS
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    preset, overrides, rows, accum, seq, recipe = STEPS["evabyte-6.5b-d10.sft-32k-eva-last2"]
+    setup = abstract_train_setup(
+        {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, preset, devices=topo.devices[:1], accum=accum, seq=seq,
+        per_dp_batch=rows, param_dtype="bfloat16", train_kwargs=recipe, model_overrides=overrides)
+    float32 = lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x  # noqa: E731
+    compiled = dataclasses.replace(setup, state=setup.state.replace(opt_state=jax.tree.map(float32, setup.state.opt_state))).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.0 * 2**30
+    lines = [ln for ln in compiled.as_text().splitlines()]
+    calls = lambda kernel: sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in lines)  # noqa: E731
+    assert {k: calls(k) for k in ("flash_attention_fwd", "eva_remote_fwd", "attn_in_fwd")} == {
+        "flash_attention_fwd": 12, "eva_remote_fwd": 12, "attn_in_fwd": 12}
+    assert [calls(k) for k in ("flash_attention_dq", "flash_attention_dkv", "eva_remote_dq", "eva_remote_dkv", "attn_in_bwd")] == [2] * 5
+    produced = [ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in lines]
+    assert not [x for x in produced if f",{seq},{seq}]" in x or f",{seq},{seq // 16}]" in x]
+
+
 def test_flash_on_a_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
     """Mosaic kernels cannot be partitioned by GSPMD: on the default fsdp=4
     mesh the dispatcher must run the kernel per shard under a shard_map
