@@ -27,14 +27,16 @@ source is ``ops/flash_attention``'s resident causal kernels as they stand, on
 the row cut into windows (``[b, h, T, d]`` read as ``[b, h * T / W, W, d]``: a
 free reshape, each window a row of one block, so the grid visits a window's own
 diagonal tile and nothing else); the REMOTE source is three small mask-free
-kernels here. The remote forward CONTINUES the local one's online softmax: it
-starts from ``m = lse_local``, ``l = 1``, ``acc = o_local`` (the same state the
-local kernel ended in, normalised), walks the ``w`` whole ``[rows, W / C]``
-tiles of the earlier windows' summaries (a head's summaries, ``T / C`` rows,
-are resident in VMEM; the count is the query block's window index, a dynamic
-trip count, no mask anywhere) and writes the merged ``o`` and ``lse`` over the
-local ones (aliased). So nothing is merged in XLA and no second ``lse`` (512
-MiB a layer at 32 heads of 32,768 in its padded layout) is ever held. The
+kernels here. The remote forward CONTINUES the local one's online softmax from
+the state the local kernel ended in (``m = lse_local``, ``acc = o_local``; its
+``l = 1`` joins at the end as ``exp(lse_local - m)``) over the ``w`` earlier
+windows' summaries, four windows (``4 W / C`` keys) behind each new maximum
+and what is left a window a trip, ``m`` and ``l`` carried ``[rows, 128]``
+lane-dense; a head's summaries, ``T / C`` rows, are resident in VMEM, ``w`` is
+the query block's window index, a dynamic trip count, no mask anywhere; the
+merged ``o`` and ``lse`` are written over the local ones (aliased). So nothing
+is merged in XLA and no second ``lse`` (512 MiB a layer at 32 heads of 32,768
+in its padded layout) is ever held. The
 backward is the flash backward of each source under the MERGED ``lse`` and
 ``delta = rowsum(do * o)``: the resident ``dq`` / ``dk, dv`` kernels on the
 windows, then a remote ``dq`` kernel that adds to the local ``dq`` and a remote
@@ -78,6 +80,8 @@ CALLS: Counter = Counter()
 # {(kernel name, (T, window, chunk)): (tiles its grid and loops visit in one head's row, tiles L and R need there)}
 # of every kernel call built in this process, as ``ops/flash_attention.GRID_TILES`` keeps the streamed kernels': a
 # local tile is a window's own [W, W] diagonal block, a remote tile one query block against one window's summaries.
+# DISTINCT tiles, each counted once however a kernel's trips group them (the remote forward takes four windows'
+# summaries a trip): 100% says "none outside the masks"; what a tile costs is the benchmark's ``eva_agg_fwd_roofline_pct``.
 GRID_TILES: dict = {}
 
 
@@ -161,28 +165,54 @@ def _rows(window: int) -> int:
     return 1024 if window % 1024 == 0 else window
 
 
+# Earlier windows whose summaries one trip of the remote forward brings behind ONE new maximum (what is left, under
+# that many, goes a window a trip). On a v5e at the EvaByte cell's shape: 4 reads 7.55 ms a call, 2 reads 8.28, 8 reads
+# 8.54 (half of a row's query blocks see fewer than 8 windows and run the single trips alone), 1 throughout 10.55.
+_WINDOWS_A_TRIP = 4
+_LANES = 128
+
+
 def _remote_fwd_kernel(q_ref, ks_ref, vs_ref, o_in_ref, lse_in_ref, o_ref, lse_ref, *, scale, per, blocks_a_window):
     """Grid (batch, head, query block). Continues the local source's online softmax over the ``w`` tiles of the
-    earlier windows' summaries: no mask, ``w`` the block's window index."""
+    earlier windows' summaries: no mask, ``w`` the block's window index. The row statistics are carried lane-dense
+    (``m`` ``[rows, 128]`` with a row's maximum in every lane, ``l`` ``[rows, 128]`` sums by lane: a ``[rows, 1]``
+    column fills as many registers, one lane in 128 used), and a trip pays ONE lane reduction, for its new maximum."""
     w = _windows_seen(pl.program_id(2) // blocks_a_window)
     q = _operand(q_ref[0, 0])
+    rows, d = q.shape
 
-    def tile(c, carry):
-        m, l, acc = carry
-        keys = pl.ds(pl.multiple_of(c * per, per), per)
-        v_blk = _operand(vs_ref[0, 0, keys, :])
-        s = jax.lax.dot_general(
-            q, _operand(ks_ref[0, 0, keys, :]), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha, p = jnp.exp(m - m_new), jnp.exp(s - m_new)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return m_new, l * alpha + jnp.sum(p, axis=1, keepdims=True), acc
+    def trips(n, first, count, carry):
+        """``count`` trips of ``n`` windows' summaries each, from window ``first`` on."""
+        def tile(c, carry):
+            m, l, acc = carry
+            keys = pl.ds(pl.multiple_of((first + c * n) * per, per), n * per)
+            v_blk = _operand(vs_ref[0, 0, keys, :])
+            s = jax.lax.dot_general(
+                q, _operand(ks_ref[0, 0, keys, :]), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            by_lanes = [s[:, at:at + _LANES] for at in range(0, n * per, _LANES)]
+            highest = functools.reduce(jnp.maximum, by_lanes)
+            m_new = jnp.maximum(m, jnp.broadcast_to(jnp.max(highest, axis=1, keepdims=True), m.shape))
+            alpha = jnp.exp(m - m_new)
+            p = [jnp.exp(x - m_new) for x in by_lanes]
+            acc = acc * jnp.concatenate([alpha] * (d // _LANES), axis=1) + jax.lax.dot_general(
+                jnp.concatenate(p, axis=1).astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, functools.reduce(jnp.add, p, l * alpha), acc
 
-    # the local kernel's final state, normalised: m = lse, l = 1, acc = o
-    start = (lse_in_ref[0, 0], jnp.ones(lse_in_ref.shape[2:], jnp.float32), o_in_ref[0, 0].astype(jnp.float32))
-    m, l, acc = jax.lax.fori_loop(0, w, tile, start)
+        return jax.lax.fori_loop(0, count, tile, carry)
+
+    # the local kernel's final state, normalised: m = lse, acc = o; its l = 1 is rescaled with the rest, so it joins
+    # at the end as exp(lse - m) and ``l`` starts empty
+    lse_local = lse_in_ref[0, 0]
+    carry = (jnp.broadcast_to(lse_local, (rows, _LANES)), jnp.zeros((rows, _LANES), jnp.float32), o_in_ref[0, 0].astype(jnp.float32))
+    wide = 0
+    if ks_ref.shape[2] >= _WINDOWS_A_TRIP * per:  # (a row of fewer windows has no block that sees that many)
+        wide = w // _WINDOWS_A_TRIP
+        carry = trips(_WINDOWS_A_TRIP, 0, wide, carry)
+    m, l, acc = trips(1, wide * _WINDOWS_A_TRIP, w - wide * _WINDOWS_A_TRIP, carry)
+    m = m[:, :1]
+    l = jnp.exp(lse_local - m) + jnp.sum(l, axis=1, keepdims=True)
     o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l)
 

@@ -213,31 +213,104 @@ def test_the_pooling_parameters_move_nothing_in_the_first_window(leaf):
     assert change[:32].max() == 0.0 and change[32:].min() > 0
 
 
-def test_the_kernels_under_the_interpreter_are_the_xla_form():
-    """The resident flash kernels on the row cut into windows, continued by the remote kernels over the summaries, and
-    the backward of both sources under the merged lse: against the XLA form, output and every cotangent. Windows of
-    256 in chunks of 2 (a window's 128 summaries are one tile), four windows, heads of whole lanes."""
-    window, chunk, scale = 256, 2, 128 ** -0.5
-    (q, k, v, phi, mu), w = operands(1, 1024, 2, 128, seed=4)
-    q, k, v, w = (x.transpose(0, 2, 1, 3) for x in (q, k, v, w))
+KERNELS = dict(window=256, chunk=2, scale=128 ** -0.5)  # a window's 128 summaries are one tile; heads of whole lanes
 
+
+def _kernels_and_xla(q, k, v, phi, mu, w):
+    """Head-major operands through both forms of the aggregate, the kernels under the Pallas interpreter: ``(o, the
+    five cotangents)`` of each."""
     def run(aggregate):
         def loss(q, k, v, phi, mu):
-            ks, vs = eva.pool(k, v, phi, mu, chunk=chunk, scale=scale)
+            ks, vs = eva.pool(k, v, phi, mu, chunk=KERNELS["chunk"], scale=KERNELS["scale"])
             o = aggregate(q, k, v, ks, vs)
             return jnp.sum(o * w), o
         (_, o), grads = jax.value_and_grad(loss, argnums=range(5), has_aux=True)(q, k, v, phi, mu)
         return o, grads
 
-    o, grads = run(eva._make_aggregate(window, chunk, scale, True))
-    want_o, wants = run(lambda *a: eva._aggregate_xla(*a, window=window, chunk=chunk, scale=scale))
+    return run(eva._make_aggregate(KERNELS["window"], KERNELS["chunk"], KERNELS["scale"], True)), run(
+        lambda *a: eva._aggregate_xla(*a, **KERNELS))
+
+
+def _head_major(args, w):
+    q, k, v, phi, mu = args
+    return (*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), phi, mu, w.transpose(0, 2, 1, 3))
+
+
+@pytest.mark.parametrize("windows", [4, 5, 7])
+def test_the_kernels_under_the_interpreter_are_the_xla_form(windows):
+    """The resident flash kernels on the row cut into windows, joined by the remote kernels over the summaries, and
+    the backward of both sources under the merged lse: against the XLA form, output and every cotangent. Windows of
+    256 in chunks of 2, heads of whole lanes; four, five and seven windows, so the remote forward's blocks see 0 to 6
+    windows: no trip, single trips alone, a wide trip of four alone, and a wide trip with one and two single ones."""
+    rows = windows * KERNELS["window"]
+    (o, grads), (want_o, wants) = _kernels_and_xla(*_head_major(*operands(1, rows, 2, 128, seed=4)))
     np.testing.assert_allclose(o, want_o, atol=5e-6)
     for name, g, want in zip(("q", "k", "v", "phi", "mu"), grads, wants):
         assert _rel(g, want) < RTOL, name
     # the grids visit a window's own diagonal block, and a query block against each EARLIER window's summaries: no more
-    tiles = {name: v for (name, shape), v in eva.GRID_TILES.items() if shape == (1024, 256, 2)}
-    assert tiles == {"flash_attention_fwd": (4, 4), "flash_attention_dq": (4, 4), "flash_attention_dkv": (4, 4),
-                     "eva_remote_fwd": (6, 6), "eva_remote_dq": (6, 6), "eva_remote_dkv": (6, 6)}
+    tiles = {name: v for (name, shape), v in eva.GRID_TILES.items() if shape == (rows, 256, 2)}
+    seen = windows * (windows - 1) // 2
+    assert tiles == {"flash_attention_fwd": (windows,) * 2, "flash_attention_dq": (windows,) * 2, "flash_attention_dkv": (windows,) * 2,
+                     "eva_remote_fwd": (seen,) * 2, "eva_remote_dq": (seen,) * 2, "eva_remote_dkv": (seen,) * 2}
+
+
+@pytest.mark.parametrize("lead", [90.0, -90.0, 40.0, -40.0])
+def test_one_source_far_above_the_other_neither_overflows_nor_loses_the_smaller(lead):
+    """The remote forward starts from the local source's state and its first trip's ``alpha`` takes the whole gap
+    between the sources at once (six windows: blocks that make wide trips, single ones and both). Every query
+    gets the same component along ``u`` and ``mu`` moves every summary's key along ``u``, so the summaries' scores
+    stand ``lead`` above the own window's lse, give or take 10 (below it where ``lead`` is negative). At 90 the gap
+    passes 80 in every row and 88.7 in some, where ``exp`` without the joint maximum overflows; at 40 the SMALLER
+    source, 1e-14 to 1e-21 of the sum and far under the output's tolerance, is still told apart in the leaves that
+    only it reaches (the own window where the summaries lead: the last window's v, which no later window pools; the
+    summaries where the own window leads: phi and mu. At 90 float32 flushes those in either form)."""
+    window, scale = KERNELS["window"], KERNELS["scale"]
+    q, k, v, phi, mu, w = _head_major(*operands(1, 6 * window, 2, 128, seed=5))
+    u, along = jnp.ones((128,)) / 128 ** 0.5, 8.0
+    q = q - (q @ u)[..., None] * u + along * u
+    mu = mu + lead / (scale * along) * u
+    ks, _ = eva.pool(k, v, phi, mu, chunk=KERNELS["chunk"], scale=scale)
+    by_window = lambda x: x.reshape(1, 2, 6, window, 128)  # noqa: E731
+    own = jnp.where(jnp.tril(jnp.ones((window, window), bool)), jnp.einsum("bhwqd,bhwkd->bhwqk", by_window(q), by_window(k)) * scale, -jnp.inf)
+    gap = (jnp.einsum("bhwqd,bhcd->bhwqc", by_window(q), ks[:, :, :128]) * scale).max(-1) - jax.nn.logsumexp(own, axis=-1)
+    gap = np.sign(lead) * np.asarray(gap[:, :, 1:])
+    assert abs(lead) - 10 < gap.min() and gap.max() < abs(lead) + 10
+
+    (o, grads), (want_o, wants) = _kernels_and_xla(q, k, v, phi, mu, w)
+    assert all(bool(jnp.isfinite(x).all()) for x in (o, *grads))
+    np.testing.assert_allclose(o, want_o, atol=5e-6)
+    for name, g, want in zip(("q", "k", "v", "phi", "mu"), grads, wants):
+        if name == "mu" and lead > 0:
+            # (every summary moves alike under mu and the summaries hold all the weight: its cotangent cancels to rounding,
+            # 4e-6 beside k's norm of 70)
+            np.testing.assert_allclose(g, want, atol=2e-5)
+        else:
+            assert _rel(g, want) < RTOL, name
+    if abs(lead) < 80:
+        smaller = {"v of the last window": (grads[2][:, :, 5 * window:], wants[2][:, :, 5 * window:])} if lead > 0 else {
+            "phi": (grads[3], wants[3]), "mu": (grads[4], wants[4])}
+        for name, (g, want) in smaller.items():
+            assert 0 < float(jnp.linalg.norm(want)) < 1e-10 and _rel(g, want) < 1e-4, name
+
+
+def test_the_first_windows_rows_leave_the_remote_forward_as_the_local_kernels_wrote_them():
+    """A block of the first window sees no summaries (``w = 0``): no trip runs, ``m = lse``, ``l = exp(0)``, and ``o``
+    and ``lse`` pass through bit for bit. Every later window's rows change."""
+    from llm_fine_tune_distributed_tpu.ops import flash_attention as flash
+
+    window, chunk, scale = KERNELS["window"], KERNELS["chunk"], KERNELS["scale"]
+    q, k, v, phi, mu, _ = _head_major(*operands(1, 3 * window, 2, 128, seed=6))
+    ks, vs = eva.pool(k, v, phi, mu, chunk=chunk, scale=scale)
+    o, residuals = eva._make_aggregate(window, chunk, scale, True).fwd(q, k, v, ks, vs)
+    lse = residuals[-1]
+    as_windows = lambda x: x.reshape(1, 2 * 3, window, 128)  # noqa: E731
+    local_o, local_lse = flash._fwd(as_windows(q), as_windows(k), as_windows(v), jnp.ones((1, window), jnp.int32),
+                                    scale=scale, block=window, groups=1, interpret=True)
+    local_o, local_lse = local_o.reshape(o.shape), local_lse.reshape(lse.shape)
+    np.testing.assert_array_equal(o[:, :, :window], local_o[:, :, :window])
+    np.testing.assert_array_equal(lse[:, :, :window], local_lse[:, :, :window])
+    assert float(jnp.abs(o[:, :, window:] - local_o[:, :, window:]).max()) > 1e-2
+    assert float((lse[:, :, window:] - local_lse[:, :, window:]).min()) > 0  # a softmax over more keys
 
 
 @pytest.mark.parametrize("shape, window, chunk, backend, mesh, said", [
