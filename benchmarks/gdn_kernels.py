@@ -28,12 +28,16 @@ the backend gives it).
 
 ``--only kda``: the rule with a decay a CHANNEL (Kimi Delta Attention) at the Kimi cell's shapes (2 rows of 8192, 32
 heads of 128), the XLA form (``_rule_xla_by_channel``) beside the two Pallas sweeps (``kda_rule_fwd``,
-``kda_rule_bwd``) and the choices their builder weighed (``kda_variants``).
+``kda_rule_bwd``) and the choices their builder weighed (``kda_variants``); then (``--only sweeps``: that alone) the two sweeps ALONE, each called
+by itself in the layouts ``_rule_kernels`` hands it (``kda_sweeps``: ms a call, and what the forward sweep keeps for the
+backward one in MiB), and with ``--parent DIR`` the same of another checkout's ``ops/gated_delta.py`` beside the
+largest difference of the output and of each cotangent from that checkout's (0.0: equal bit for bit).
 
-    chiprun -- python benchmarks/gdn_kernels.py [--only rule|kda|mixer|inverse] [--inverse solve kernels]
+    chiprun -- python benchmarks/gdn_kernels.py [--only rule|kda|sweeps|mixer|inverse] [--inverse solve kernels] [--parent _parent]
 """
 import argparse
 import functools
+import importlib.util
 import json
 import os
 import sys
@@ -203,6 +207,39 @@ def kda_variants(args, small):
     gd._from_sub_block_start, gd.GROUP = inside, group
 
 
+def kda_sweeps(args, small):
+    """``kda_rule_fwd`` and ``kda_rule_bwd`` each ALONE at the Kimi cell's shapes, on the operands ``_rule_kernels`` hands
+    them and a drawn ``do``: ms a call, the MiB the forward sweep returns beyond ``o`` (what is held from a microbatch's
+    forward pass to its backward pass). With ``--parent DIR`` the sweeps of that checkout's ``ops/gated_delta.py`` too,
+    whatever they keep (a sweep's residuals are whatever its forward returns beyond ``o``), and the largest absolute
+    difference of ``o`` and of each cotangent between the two."""
+    rows, seq, h, d = (1, 512, 2, 128) if small else (args.rows, args.seq, 32, 128)
+    q, k, v, g, beta = kda_inputs(rows, seq, h, d, jnp.bfloat16)
+    do = jax.random.normal(jax.random.key(11), (rows, seq, h * d)).astype(jnp.bfloat16)
+    operands = gd._laid_out(q, k, v, g, beta)[2]  # seq is whole steps: nothing is padded, and the parent lays them out the same
+
+    def sweeps(module, tree):
+        fwd = functools.partial(module.kda_rule_fwd, hk=h, state_dtype=jnp.float32, interpret=small)
+        bwd = functools.partial(module.kda_rule_bwd, hk=h, interpret=small)
+        o, *kept = fwd(*operands)
+        back = operands + (do,) + tuple(kept)
+        line = {"device": jax.devices()[0].device_kind, "rows": rows, "seq": seq, "heads": h, "rule": "a decay a channel", "form": "the sweeps alone",
+                "tree": tree, "fwd_ms": round(timed(fwd, operands, args.iters), 3), "bwd_ms": round(timed(bwd, back, args.iters), 3),
+                "kept_mib": {f"{list(x.shape)} {x.dtype}": round(x.nbytes / 2**20, 1) for x in kept}}
+        return line, (o, *bwd(*back))
+
+    line, got = sweeps(gd, "this")
+    if args.parent:
+        spec = importlib.util.spec_from_file_location("parent_gated_delta", os.path.join(args.parent, "llm_fine_tune_distributed_tpu/ops/gated_delta.py"))
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        theirs, want = sweeps(parent, args.parent)
+        print(json.dumps(theirs), flush=True)
+        line["max_abs_diff_from_parent"] = {n: float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
+                                            for n, a, b in zip("o dq dk dv dg dbeta".split(), got, want)}
+    print(json.dumps(line), flush=True)
+
+
 def rel(got, want):
     got, want = got.astype(jnp.float32), want.astype(jnp.float32)
     return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
@@ -313,8 +350,9 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--rows", type=int, default=2)
     ap.add_argument("--seq", type=int, default=8192)
-    ap.add_argument("--only", choices=("rule", "kda", "mixer", "inverse"))
+    ap.add_argument("--only", choices=("rule", "kda", "sweeps", "mixer", "inverse"))
     ap.add_argument("--inverse", nargs="*", help="of the rule's variants, only these (solve HIGHEST HIGH DEFAULT kernels)")
+    ap.add_argument("--parent", help="--only kda: another checkout of this repo whose two sweeps are priced and compared too")
     args = ap.parse_args(argv)
     small = not on_accelerator(jax.devices()[0].platform)  # a CPU rehearsal of the control flow: its numbers are not rates
     if args.only == "inverse":
@@ -323,6 +361,8 @@ def main(argv=None) -> int:
         rule_variants(args, small)
     if args.only in (None, "kda"):
         kda_variants(args, small)
+    if args.only in (None, "kda", "sweeps"):
+        kda_sweeps(args, small)
     if args.only in (None, "mixer"):
         mixer_variants(args, small)
     return 0

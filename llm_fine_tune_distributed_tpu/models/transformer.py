@@ -883,7 +883,9 @@ REMAT_KEEPS: dict = {}
 
 def remat_summary() -> str:
     """One line for entry points to print beside ``dispatch_summary()``: e.g. ``a rematerialized block keeps, besides
-    what its policy does: linear (full): gdn_o, gdn_states, moe_*; heads (full): flash_o, flash_lse, moe_*``."""
+    what its policy does: kda (full): gdn_o, gdn_states, kda_t_kk, kda_p, moe_*; latent (full): flash_o, flash_lse,
+    moe_*`` (a Kimi Delta Attention block on a TPU; a Gated DeltaNet block reads ``linear (full): gdn_o, gdn_states,
+    moe_*``, and either ``... (full): moe_*`` where the rule is XLA's scan)."""
     from llm_fine_tune_distributed_tpu.ops.moe import KEPT_ACROSS_REMAT as routed
 
     def said(names):
@@ -920,7 +922,7 @@ def keeps_flash_outputs(config: ModelConfig, seq: int, window: Optional[int] = N
 
 
 def keeps_scan_output(config: ModelConfig) -> tuple:
-    """Which of the gated delta rule's names (``ops/gated_delta.KEPT_ACROSS_REMAT``) a rematerialized block with a
+    """Which of the gated delta rule's names (``ops/gated_delta.KEPT_ACROSS_REMAT``, ``KEPT_BY_CHANNEL``) a rematerialized block with a
     linear-attention mixer keeps, from what the code can observe: the program the rule runs as
     (``gated_delta._program`` at this model's linear heads: the backend and the widths) and, for the XLA form, shapes.
 
@@ -937,6 +939,15 @@ def keeps_scan_output(config: ModelConfig) -> tuple:
     What comes back is the one ``reduce_precision`` pass ``jax.checkpoint`` puts on a saved residual's producer, 0.4
     ms a call over ``o``.
 
+    **The kernels of the rule with a decay a channel** (a Kimi Delta Attention mixer: ``linear_decay_rank``): those two
+    and what ``kda_rule_fwd`` writes for its backward sweep to read instead of making it again, ``kda_t_kk`` (each
+    chunk's ``T`` beside its decayed ``k k^T``, float32 ``[b, value heads, s, 128]``, 256 MiB at that microbatch) and
+    ``kda_p`` (two chunks' ``P`` side by side, ``[b, value heads, s / 2, 128]``, 64 MiB): residuals of the sweep like the
+    states, so a policy that misses one of them runs the sweep twice. By the chip (PERF.md, PR 48): the backward sweep
+    24.5 -> 17.8 ms a call and the forward one 9.0 -> 9.15, 52 ms of the Kimi cell's step for 1,280 MiB held from a
+    microbatch's forward pass to its backward pass (0.041 ms a MiB), no ``reduce_precision`` (the forward pass reads
+    neither), and the expert layers unmoved. ``W`` (one one-pass product a chunk, 128 MiB a layer) is made again.
+
     **The XLA form** (a CPU, heads that are no whole lanes): ``gdn_o`` alone, where the rule of
     ``worth_keeping_across_remat`` says so from shapes; that form carries its state through its own scan, and with
     ``o`` kept the recomputed scan only carries the state forward and the output half of each step (``Q S`` and
@@ -947,7 +958,7 @@ def keeps_scan_output(config: ModelConfig) -> tuple:
     2048, Kimi Linear 128 + 64 x 2 = 256 against 2304: recompute (and on the chip: PERF.md, PR 32)."""
     d_k, d_v = config.linear_key_head_dim, config.linear_value_head_dim
     if gated_delta._program(d_k=d_k, d_v=d_v) == "kernels":
-        return gated_delta.KEPT_ACROSS_REMAT
+        return gated_delta.KEPT_BY_CHANNEL if config.linear_decay_rank else gated_delta.KEPT_ACROSS_REMAT
     r = config.linear_num_value_heads // config.linear_num_key_heads
     return gated_delta.KEPT_ACROSS_REMAT[:1] if d_k + gated_delta.CHUNK * (1 + d_k / (r * d_v)) > config.hidden_size else ()
 
@@ -978,8 +989,9 @@ def _remat_policy(
     the block (XLA attention) the two names are in no program and the policy
     is the plain one. A block whose mixer is the linear recurrence keeps what
     ``keeps_scan_output`` names of the rule: where the rule runs as the Pallas
-    sweeps their two outputs, so that the forward sweep runs once a layer too;
-    where it is XLA's scan its output, by a rule of the mixer's widths.
+    sweeps every output of the forward one (two, or four with a decay a
+    channel), so that the forward sweep runs once a layer too; where it is
+    XLA's scan its output, by a rule of the mixer's widths.
 
     And a model with a ``grouped_experts`` layer (``keeps_routing``: the
     layer's kind, no switch) keeps what that layer names

@@ -99,7 +99,11 @@ sweep always writes it, 67 MB a call of the Qwen3-Next cell, so that the sweep
 is ONE program whether a gradient follows or not); the backward sweep walks a
 row's steps last to first, makes a step's ``T``, ``U``, ``W``, ``D`` and
 states again in VMEM, then the chunks against time with the state's cotangent
-carried in VMEM.
+carried in VMEM. With a decay a channel ``T`` and the two decayed products are
+the dear half of that (three float32 sub-block products at HIGHEST, the
+pairwise ``exp`` blocks, the inverse), so ``kda_rule_fwd`` writes them beside
+the states, 40 KiB a chunk and head, and ``kda_rule_bwd`` reads them and makes
+only ``U``, ``W``, ``D`` and the states again (PERF.md, PR 48).
 
 Rows whose length is no multiple of the chunk are padded with tokens that
 change nothing (k = 0, beta = 0, g = 0) and the padding's outputs dropped.
@@ -129,10 +133,16 @@ STATE_DTYPE = jnp.float32
 
 # What the rule names (``checkpoint_name``) for a rematerialized block to keep (``models/transformer._remat_policy``, as
 # the flash kernels' ``o`` and ``lse`` and the expert layer's routing are): its output ``o`` and, where the kernels run,
-# the state each step of the forward sweep starts from, which the backward sweep reads. Under any policy that does not
-# save them the names are inert. The two go together: with ``o`` alone kept, the recomputed pass still runs the whole
-# forward sweep for the states (``_flat_rule``). The XLA forms carry their state through their own scan and name ``o`` only.
+# what the backward sweep reads of the forward one: the state each step starts from and, of the rule with a decay a
+# channel, each chunk's ``T`` beside its decayed ``k k^T`` and its ``P`` (``KEPT_BY_CHANNEL``, the names of
+# ``kda_rule_fwd``'s four outputs in order). Under any policy that does not save them the names are inert. A sweep's
+# names go together: with one of them missing, the recomputed pass still runs the whole forward sweep for it
+# (``_flat_rule``). The XLA forms carry their state through their own scan and name ``o`` only. By the chip (PERF.md,
+# PRs 44 and 48; a microbatch of 2 rows of 8192 at 32 value heads of 128): ``o`` 128 MiB and the states 64 MiB a layer
+# buy the forward sweep's second run (4.78 ms a call of the scalar rule, 9.0 with a decay a channel: 0.050 and 0.094 ms
+# a MiB), the three matrices' 320 MiB a layer 6.7 ms of the backward sweep less 0.15 of the forward (0.041 ms a MiB).
 KEPT_ACROSS_REMAT = ("gdn_o", "gdn_states")
+KEPT_BY_CHANNEL = KEPT_ACROSS_REMAT + ("kda_t_kk", "kda_p")
 
 # {(rows, seq, key heads, value heads, d_k, d_v): [calls traced, form]} of every
 # ``gated_delta_rule`` traced in this process (as ``flash_attention.GRID_TILES``
@@ -397,6 +407,17 @@ def _decayed_operands(q, k, beta, local):
             (k32 * jnp.exp(last - cum)).astype(k.dtype), jnp.exp(last[..., 0, :]))
 
 
+def _chunk_matrices(q, k, beta, local):
+    """``(T, the decayed k k^T, P)`` of chunks, float32 ``[..., C, C]`` each: ``T = (I + A)^-1`` with ``A`` beta times the
+    decayed ``k k^T`` below the diagonal, ``P`` the decayed ``q k^T`` from the diagonal down; of ``q``, ``k`` and
+    ``local`` as ``_decayed_products`` takes them and ``beta [..., C, 1]`` float32. What the kernels' forward sweep
+    keeps of a chunk for its backward sweep (``kda_rule_fwd``)."""
+    c = q.shape[-3] * q.shape[-2]
+    rows, cols = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    kk, qk = _decayed_products(q, k, local)
+    return unit_lower_inverse(jnp.where(rows > cols, beta * kk, 0.0)), kk, jnp.where(rows >= cols, qk, 0.0)
+
+
 def _rule_xla_by_channel(q, k, v, g, beta, *, chunk: int = CHUNK):
     """``_rule_xla`` for ``g [b, s, value heads, d_k]`` (the section's comment): the same arguments otherwise, the
     same dtypes (products of bfloat16 operands added up in float32; decays, the two decayed products, the triangular
@@ -412,7 +433,6 @@ def _rule_xla_by_channel(q, k, v, g, beta, *, chunk: int = CHUNK):
 
     (q, k, v, g, beta), s = _padded_rows((q, k, v, g, beta), chunk)
     n, m = q.shape[1] // chunk, chunk // sub
-    rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
 
     @jax.checkpoint
     def before_the_walk(q, k, v, g, beta):
@@ -426,10 +446,9 @@ def _rule_xla_by_channel(q, k, v, g, beta, *, chunk: int = CHUNK):
         bc = by_head(beta.astype(f32))                                       # [n, h, C, 1]
         blocks = lambda x: x.reshape(n, hv, m, sub, x.shape[-1])             # noqa: E731
         local = jnp.cumsum(blocks(gc), axis=-2)                              # from each sub-block's start
-        kk, qk = _decayed_products(blocks(qc), blocks(kc), local)
+        t, _, p = _chunk_matrices(blocks(qc), blocks(kc), bc, local)         # [n, h, C, C]; P's diagonal included
+        t, p = t.astype(cd), p.astype(cd)
         k_beta, q_e, k_rest, e_all = _decayed_operands(qc, kc, bc, local)
-        t = unit_lower_inverse(jnp.where(rows > cols, bc * kk, 0.0)).astype(cd)  # [n, h, C, C]
-        p = jnp.where(rows >= cols, qk, 0.0).astype(cd)                      # diagonal included
         u = jnp.einsum("nhij,nhjv->nhiv", t, vc * bc.astype(cd), preferred_element_type=f32).astype(cd)
         w = jnp.einsum("nhij,nhjd->nhid", t, k_beta, preferred_element_type=f32).astype(cd)
         return u, w, p, q_e, k_rest, e_all
@@ -902,7 +921,9 @@ def gdn_rule_bwd(q, k, v, cum, beta, do, states, *, hk, rs, interpret):
 # two heads stacked into ``[2 C, 2 C]`` matrices of diagonal blocks, as the scalar kernels stack them, would double the
 # float32 sub-block products for blocks that are zero. q, k, v, o and g (float32, ``[b, s, value heads x d_k]``, as
 # ``kda_gates`` makes it) are read and written where they lie; g is summed from each sub-block's start in VMEM (four
-# shifted adds along sublanes), beta comes as rows like the scalar kernels'.
+# shifted adds along sublanes), beta comes as rows like the scalar kernels'. The forward sweep also WRITES each chunk's
+# ``T``, decayed ``k k^T`` and ``P``, which the backward sweep reads where the scalar rule's makes its own again
+# (``_kda_fwd_kernel``: the layout; ``_kda_alone_again``: what is left to make).
 
 _SUBS = CHUNK // SUB
 
@@ -1017,7 +1038,14 @@ def _kda_products_back(x, dkk, dqk):
     return jnp.concatenate(dq, axis=0), jnp.concatenate(dk, axis=0) + dk_earlier, jnp.concatenate(dlocal, axis=0), dcum, dbefore
 
 
-def _kda_alone(q, k, v, g, b_ref, c, with_output=True):
+def _kda_solved(x, v, bc, t):
+    """``U = T (beta V)`` and ``W = T (beta exp(G) K)`` of a chunk in the compute dtype, two one-pass products."""
+    cd = v.dtype
+    tc = t.astype(cd)
+    return _dot(tc, v * bc.astype(cd)).astype(cd), _dot(tc, (x["k32"] * (bc * x["e"])).astype(cd)).astype(cd)
+
+
+def _kda_alone(q, k, v, g, b_ref, c):
     """What a chunk of ONE head is before any state reaches it (a generator, ``_in_step``): ``T``, ``U``, ``W``, the
     decayed ``k k^T`` and ``q k^T``, ``(K exp(G_C - G))^T``, ``Q exp(G)`` and ``exp(G_C)`` as a column. Line by line
     what ``_rule_xla_by_channel`` computes for a row's chunks at once, in its dtypes."""
@@ -1029,9 +1057,17 @@ def _kda_alone(q, k, v, g, b_ref, c, with_output=True):
     k_t = _transposed((x["k32"] * x["rest"]).astype(cd))
     yield
     t = yield from _inverse_in_vmem(jnp.where(ri > ci, bc * kk, 0.0), ri, ci)
-    tc = t.astype(cd)
-    return dict(x, t=t, kk=kk, u=_dot(tc, v * bc.astype(cd)).astype(cd), w=_dot(tc, (x["k32"] * (bc * x["e"])).astype(cd)).astype(cd),
-                p=jnp.where(ri >= ci, qk, 0.0).astype(cd), k_t=k_t, q_e=(x["q32"] * x["e"]).astype(cd) if with_output else None)
+    u, w = _kda_solved(x, v, bc, t)
+    return dict(x, t=t, kk=kk, u=u, w=w, p=jnp.where(ri >= ci, qk, 0.0).astype(cd), k_t=k_t, q_e=(x["q32"] * x["e"]).astype(cd))
+
+
+def _kda_alone_again(q, k, v, g, b_ref, c, t):
+    """What the walk needs of ``_kda_alone`` for the backward sweep, from the ``T`` the forward sweep kept: the decays,
+    ``U``, ``W`` and ``(K exp(G_C - G))^T``, three products that wait for nothing. No decayed product and no inverse."""
+    ri, ci = _iotas(CHUNK)
+    x = _kda_decays(g, q, k)
+    u, w = _kda_solved(x, v, _col(b_ref[0, 0, pl.ds(c, 1), :], ri == ci), t)
+    return dict(x, u=u, w=w, k_t=_transposed((x["k32"] * x["rest"]).astype(k.dtype)))
 
 
 def _kda_through(alone, s, state_dtype):
@@ -1041,8 +1077,11 @@ def _kda_through(alone, s, state_dtype):
     return (alone["whole"] * s.astype(_F32) + _dot(alone["k_t"], d.astype(cd))).astype(state_dtype), d
 
 
-def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, state):
-    """A grid step of the forward sweep, as ``_fwd_kernel``: one value head's ``STEP_CHUNKS`` chunks."""
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, tk_ref, p_ref, state):
+    """A grid step of the forward sweep, as ``_fwd_kernel``: one value head's ``STEP_CHUNKS`` chunks. Beside ``o`` and
+    the step's starting state it writes what the backward sweep would otherwise make a second time, lane-dense (a
+    float32 array 64 wide would hold twice its count): each chunk's ``T`` and decayed ``k k^T`` side by side
+    (``tk_ref``, float32 ``[C, 2 C]`` a chunk) and two chunks' ``P`` side by side (``p_ref``, the compute dtype)."""
     cd = v_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
@@ -1055,6 +1094,10 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, state):
         chunks = [first + j for j in range(GROUP)]
         alone = _in_step(_kda_alone(q_ref[0, _rows(c), :], k_ref[0, _rows(c), :], v_ref[0, _rows(c), :], g_ref[0, _rows(c), :], b_ref, c)
                          for c in chunks)
+        for c, x in zip(chunks, alone):
+            tk_ref[0, 0, _rows(c), :] = jnp.concatenate([x["t"], x["kk"]], axis=1)
+        for j in range(0, GROUP, 2):
+            p_ref[0, 0, _rows(chunks[j] // 2), :] = jnp.concatenate([alone[j]["p"], alone[j + 1]["p"]], axis=1)
         walked = []
         for x in alone:
             of_state = _dot(x["q_e"], s.astype(cd))                   # (Q exp(G)) S, beside the walk
@@ -1133,11 +1176,12 @@ def _kda_backward_rest(x, y, v, t, kk):
     return dq, dk, bc * d_vb, _from_sub_block_start(dlocal, against_time=True), _row(db, x["eye"])
 
 
-def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
-                    ds_ref, s_at, d_at, t_at, w_at, kk_at, p_at):
-    """A grid step of the backward sweep, as ``_bwd_kernel``: the step's chunks forward once more from the kept state
-    (each chunk's starting state, ``D``, ``T``, ``W`` and its two decayed products left in scratch), then the groups
-    against time with the state's cotangent carried."""
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, tk_ref, p_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                    ds_ref, s_at, d_at, w_at):
+    """A grid step of the backward sweep, as ``_bwd_kernel``: the step's chunks walked forward once more from the kept
+    state (each chunk's starting state, ``D`` and ``W`` left in scratch), then the groups against time with the
+    state's cotangent carried. ``T``, the decayed ``k k^T`` and ``P`` are READ as the forward sweep left them
+    (``_kda_fwd_kernel``): no decayed product and no inverse is made here."""
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -1149,11 +1193,11 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, dq_ref, d
     def forward(first, s):
         chunks = [first + j for j in range(GROUP)]
         q, k, v, g = inputs(chunks)
-        alone = _in_step(_kda_alone(q[j], k[j], v[j], g[j], b_ref, c, with_output=False) for j, c in enumerate(chunks))
-        for c, x in zip(chunks, alone):
+        for j, c in enumerate(chunks):
+            x = _kda_alone_again(q[j], k[j], v[j], g[j], b_ref, c, tk_ref[0, 0, _rows(c), :][:, :CHUNK])
             s_at[c] = s
-            s, d = _kda_through(x, s, s.dtype)
-            d_at[c], t_at[c], w_at[c], kk_at[c], p_at[c] = d, x["t"], x["w"], x["kk"], x["p"]
+            s, d_at[c] = _kda_through(x, s, s.dtype)
+            w_at[c] = x["w"]
         return s
 
     _over_groups(forward, s0_ref[0, 0, 0])
@@ -1161,12 +1205,14 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, dq_ref, d
     def backward(first, ds):
         chunks = [first + j for j in range(GROUP)]
         q, k, v, g = inputs(chunks)
-        back = _in_step(_kda_backward_alone(q[j], k[j], v[j], do_ref[0, _rows(c), :], g[j], b_ref, c, s_at[c], d_at[c], w_at[c], p_at[c])
+        tk = [tk_ref[0, 0, _rows(c), :] for c in chunks]                                                         # T beside the decayed k k^T
+        p = [p_ref[0, 0, _rows(c // 2), :][:, j % 2 * CHUNK:(j % 2 + 1) * CHUNK] for j, c in enumerate(chunks)]  # a pair's left or right half
+        back = _in_step(_kda_backward_alone(q[j], k[j], v[j], do_ref[0, _rows(c), :], g[j], b_ref, c, s_at[c], d_at[c], w_at[c], p[j])
                         for j, c in enumerate(chunks))
         through = [None] * GROUP
         for j in reversed(range(GROUP)):
             ds, through[j] = _kda_backward_through(back[j], s_at[chunks[j]], ds)
-        out = _in_step(_kda_backward_rest(back[j], through[j], v[j], t_at[c], kk_at[c]) for j, c in enumerate(chunks))
+        out = _in_step(_kda_backward_rest(back[j], through[j], v[j], tk[j][:, :CHUNK], tk[j][:, CHUNK:]) for j in range(GROUP))
         for c, (dq, dk, dv, dg, db) in zip(chunks, out):
             dq_ref[0, _rows(c), :] = dq.astype(dq_ref.dtype)
             dk_ref[0, _rows(c), :] = dk.astype(dk_ref.dtype)
@@ -1180,50 +1226,59 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s0_ref, dq_ref, d
 
 def _kda_specs(r, dk, dv, at):
     """Block specs over ``(row, value head, step)``, the step's block ``at(t)``: q or k (its key head's columns), v or
-    o, g or a cotangent a value head ``[b, s, value heads x d_k]``, beta's rows, the kept state."""
+    o, g or a cotangent a value head ``[b, s, value heads x d_k]``, beta's rows, the kept state, and what the forward
+    sweep keeps of each chunk for the backward one (``T`` beside the decayed ``k k^T``; two chunks' ``P``)."""
     tokens = STEP_CHUNKS * CHUNK
     return (pl.BlockSpec((1, tokens, dk), lambda i, j, t: (i, at(t), j // r)),
             pl.BlockSpec((1, tokens, dv), lambda i, j, t: (i, at(t), j)),
             pl.BlockSpec((1, tokens, dk), lambda i, j, t: (i, at(t), j)),
             pl.BlockSpec((1, 1, STEP_CHUNKS, CHUNK), lambda i, j, t: (i, j, at(t), 0)),
-            pl.BlockSpec((1, 1, 1, dk, dv), lambda i, j, t: (i, j, at(t), 0, 0)))
+            pl.BlockSpec((1, 1, 1, dk, dv), lambda i, j, t: (i, j, at(t), 0, 0)),
+            pl.BlockSpec((1, 1, tokens, 2 * CHUNK), lambda i, j, t: (i, j, at(t), 0)),
+            pl.BlockSpec((1, 1, tokens // 2, 2 * CHUNK), lambda i, j, t: (i, j, at(t), 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("hk", "state_dtype", "interpret"))
 def kda_rule_fwd(q, k, v, g, beta, *, hk, state_dtype, interpret):
     """The forward sweep with a decay a channel. ``q``, ``k`` ``[b, s, hk d_k]``, ``v`` ``[b, s, hv d_v]``, ``g [b, s,
-    hv d_k]`` float32, ``beta [b, hv, s / C, C]`` -> ``o`` like v and the state each step starts from ``[b, hv,
-    steps, d_k, d_v]``."""
+    hv d_k]`` float32, ``beta [b, hv, s / C, C]`` -> ``o`` like v, the state each step starts from ``[b, hv, steps,
+    d_k, d_v]``, and what the backward sweep reads of each chunk instead of making it again: ``T`` and the decayed ``k
+    k^T`` side by side, float32 ``[b, hv, s, 2 C]`` (a chunk's ``[C, 2 C]`` at its tokens' rows), and the decayed ``q
+    k^T`` from the diagonal down, two chunks side by side in k's dtype, ``[b, hv, s / 2, 2 C]``: 40 KiB a chunk and
+    head in bfloat16, 320 MiB a call of the Kimi cell (2 rows of 8192, 32 heads), every lane used."""
     b, s, _ = q.shape
     hv, dk = beta.shape[1], q.shape[2] // hk
     dv, steps = v.shape[2] // hv, s // (STEP_CHUNKS * CHUNK)
-    qk, vo, gs, bs, ss = _kda_specs(hv // hk, dk, dv, lambda t: t)
+    qk, vo, gs, bs, ss, tk, ps = _kda_specs(hv // hk, dk, dv, lambda t: t)
+    like = jax.ShapeDtypeStruct
     return pl.pallas_call(
-        _kda_fwd_kernel, grid=(b, hv, steps), in_specs=[qk, qk, vo, gs, bs], out_specs=[vo, ss],
-        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype), jax.ShapeDtypeStruct((b, hv, steps, dk, dv), state_dtype)],
+        _kda_fwd_kernel, grid=(b, hv, steps), in_specs=[qk, qk, vo, gs, bs], out_specs=[vo, ss, tk, ps],
+        out_shape=[like(v.shape, v.dtype), like((b, hv, steps, dk, dv), state_dtype), like((b, hv, s, 2 * CHUNK), _F32),
+                   like((b, hv, s // 2, 2 * CHUNK), k.dtype)],
         scratch_shapes=[pltpu.VMEM((dk, dv), state_dtype)], name="kda_rule_fwd", interpret=interpret, **_params(interpret),
     )(q, k, v, g, beta)
 
 
 @functools.partial(jax.jit, static_argnames=("hk", "interpret"))
-def kda_rule_bwd(q, k, v, g, beta, do, states, *, hk, interpret):
-    """The backward sweep: ``kda_rule_fwd``'s inputs, ``do`` like v and the kept states -> cotangents of q and k ``[b,
-    s, hv d_k]`` (a value head each: the caller adds a key head's), of v, of g (by channel, like g) and of beta in its
-    row form."""
+def kda_rule_bwd(q, k, v, g, beta, do, states, t_kk, p, *, hk, interpret):
+    """The backward sweep: ``kda_rule_fwd``'s inputs, ``do`` like v and what the forward sweep kept (the states, ``T``
+    beside the decayed ``k k^T``, ``P``) -> cotangents of q and k ``[b, s, hv d_k]`` (a value head each: the caller
+    adds a key head's), of v, of g (by channel, like g) and of beta in its row form. By the chip (PERF.md, PR 48, a
+    call of the Kimi cell: 2 rows of 8192, 32 heads): 17.8 ms where the sweep that made the three matrices again took
+    24.5, every cotangent equal to that sweep's bit for bit, for the 320 MiB held from a microbatch's forward pass to
+    its backward pass (the forward sweep 9.0 -> 9.15 ms for writing them)."""
     b, s, _ = q.shape
     hv, dk = beta.shape[1], q.shape[2] // hk
     dv, steps = v.shape[2] // hv, s // (STEP_CHUNKS * CHUNK)
-    qk, vo, gs, bs, ss = _kda_specs(hv // hk, dk, dv, lambda t: steps - 1 - t)
+    qk, vo, gs, bs, ss, tk, ps = _kda_specs(hv // hk, dk, dv, lambda t: steps - 1 - t)
     like = jax.ShapeDtypeStruct
     return pl.pallas_call(
-        _kda_bwd_kernel, grid=(b, hv, steps), in_specs=[qk, qk, vo, gs, bs, vo, ss], out_specs=[gs, gs, vo, gs, bs],
+        _kda_bwd_kernel, grid=(b, hv, steps), in_specs=[qk, qk, vo, gs, bs, vo, ss, tk, ps], out_specs=[gs, gs, vo, gs, bs],
         out_shape=[like(g.shape, q.dtype), like(g.shape, k.dtype), like(v.shape, v.dtype), like(g.shape, _F32), like(beta.shape, _F32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), _F32), pltpu.VMEM((STEP_CHUNKS, dk, dv), states.dtype),
-                        pltpu.VMEM((STEP_CHUNKS, CHUNK, dv), _F32), pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32),
-                        pltpu.VMEM((STEP_CHUNKS, CHUNK, dk), k.dtype), pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32),
-                        pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), k.dtype)],
+                        pltpu.VMEM((STEP_CHUNKS, CHUNK, dv), _F32), pltpu.VMEM((STEP_CHUNKS, CHUNK, dk), k.dtype)],
         name="kda_rule_bwd", interpret=interpret, **_params(interpret),
-    )(q, k, v, g, beta, do, states)
+    )(q, k, v, g, beta, do, states, t_kk, p)
 
 
 def _rows_of(x, b, n, groups, rs):
@@ -1234,27 +1289,30 @@ def _rows_of(x, b, n, groups, rs):
 @functools.lru_cache(maxsize=None)
 def _flat_rule(forward, backward, hk, state_dtype, interpret, **static):
     """Two sweeps (the scalar decay's or the decay by channel's) as one differentiable function of the kernels' own
-    layouts. What the backward sweep keeps besides the inputs is the state each step starts from (``rows x seq / (8 C)
-    x value heads x d_k x d_v``, an eighth of what the scan's autodiff held), which the forward sweep always writes.
-    Both of the sweep's outputs are named (``KEPT_ACROSS_REMAT``), and the NAMED values are the primal output and the
-    residual (autodiff would read an unnamed one past the name): a ``jax.checkpoint`` whose policy saves the two names
-    has no forward sweep left in its recomputed pass (q, k, v, the decay and beta are rebuilt from the block's input,
-    the sweep itself is dead code and dropped); under a policy that saves neither, or ``o`` alone, the sweep runs a
-    second time there."""
+    layouts. What the backward sweep keeps besides the inputs is whatever the forward sweep returns beyond ``o``: the
+    state each step starts from (``rows x seq / (8 C) x value heads x d_k x d_v``, an eighth of what the scan's
+    autodiff held), which the forward sweep always writes, and of the rule with a decay a channel each chunk's ``T`` and
+    its two decayed products too (``kda_rule_fwd``: 40 KiB a chunk and head). Every output is named, in the sweep's
+    order (``KEPT_BY_CHANNEL``, of which the scalar rule's two are the first), and the NAMED values are the primal
+    output and the residuals (autodiff would read an unnamed one past the name): a ``jax.checkpoint`` whose policy
+    saves all of a sweep's names has no forward sweep left in its recomputed pass (q, k, v, the decay and beta are
+    rebuilt from the block's input, the sweep itself is dead code and dropped); under a policy that misses ONE of them
+    the sweep runs a second time there, and without ``jax.checkpoint`` the residuals are simply held (320 MiB a layer
+    and microbatch more than before PR 48 at the Kimi cell's shapes). ``o`` is the only one the forward pass also reads,
+    so the only one ``jax.checkpoint`` passes through a ``reduce_precision`` (0.4 ms a call)."""
     static = dict(static, hk=hk, interpret=interpret)
 
     def fwd(q, k, v, decay, beta):
-        o, states = forward(q, k, v, decay, beta, state_dtype=state_dtype, **static)
-        o = checkpoint_name(o, KEPT_ACROSS_REMAT[0])
-        states = checkpoint_name(states, KEPT_ACROSS_REMAT[1])
-        return o, (q, k, v, decay, beta, states)
+        swept = forward(q, k, v, decay, beta, state_dtype=state_dtype, **static)
+        o, *kept = (checkpoint_name(x, name) for x, name in zip(swept, KEPT_BY_CHANNEL))
+        return o, (q, k, v, decay, beta, *kept)
 
     @jax.custom_vjp
     def rule(q, k, v, decay, beta):
         return fwd(q, k, v, decay, beta)[0]
 
     def bwd(kept, do):
-        dq, dk, dv, ddecay, dbeta = backward(*kept[:5], do, kept[5], **static)
+        dq, dk, dv, ddecay, dbeta = backward(*kept[:5], do, *kept[5:], **static)
         b, s, _ = dq.shape
         of_key_head = lambda x: x.reshape(b, s, hk, -1, kept[0].shape[2] // hk).sum(axis=3).reshape(kept[0].shape)  # noqa: E731
         return of_key_head(dq), of_key_head(dk), dv, ddecay, dbeta
@@ -1263,13 +1321,12 @@ def _flat_rule(forward, backward, hk, state_dtype, interpret, **static):
     return rule
 
 
-def _rule_kernels(q, k, v, g, beta, *, interpret=False):
-    """The chunked rule through the kernels (``_rule_xla``'s and ``_rule_xla_by_channel``'s signature at
-    ``chunk=CHUNK``): rows padded to whole steps with tokens that change nothing, heads flattened into lanes, beta laid
-    out as rows; a decay a head summed from each chunk's start and laid out like beta, a decay a channel (``g`` of rank
-    4) handed over flat as it is; JAX differentiates these layouts, the kernels' ``custom_vjp`` the rule."""
-    b, s, hk, _ = q.shape
-    hv = v.shape[2]
+def _laid_out(q, k, v, g, beta):
+    """The kernels' own layouts of the rule's arguments: ``(the two sweeps, their static arguments besides hk, their five
+    operands, the rows' unpadded length)``: rows padded to whole steps with tokens that change nothing, heads flattened
+    into lanes, beta laid out as rows; a decay a head summed from each chunk's start and laid out like beta, a decay a
+    channel (``g`` of rank 4) handed over flat as it is."""
+    b, hk, hv = q.shape[0], q.shape[2], v.shape[2]
     (q, k, v, g, beta), s = _padded_rows((q, k, v, g, beta), STEP_CHUNKS * CHUNK)
     n = q.shape[1] // CHUNK
     flat = lambda x: x.reshape(b, n * CHUNK, -1)  # noqa: E731
@@ -1279,9 +1336,16 @@ def _rule_kernels(q, k, v, g, beta, *, interpret=False):
         rs = 2 if (hv // hk) % 2 == 0 else 1
         cum = jnp.cumsum(g.astype(_F32).reshape(b, n, CHUNK, hv), axis=2).reshape(b, n * CHUNK, hv)
         sweeps, more, decay = (gdn_rule_fwd, gdn_rule_bwd), dict(rs=rs), _rows_of(cum, b, n, hv // rs, rs)
-    o = _flat_rule(*sweeps, hk, jnp.dtype(STATE_DTYPE), interpret, **more)(
-        flat(q), flat(k), flat(v), decay, _rows_of(beta.astype(_F32), b, n, hv // rs, rs))
-    return o.reshape(b, n * CHUNK, hv, -1)[:, :s]
+    return sweeps, more, (flat(q), flat(k), flat(v), decay, _rows_of(beta.astype(_F32), b, n, hv // rs, rs)), s
+
+
+def _rule_kernels(q, k, v, g, beta, *, interpret=False):
+    """The chunked rule through the kernels (``_rule_xla``'s and ``_rule_xla_by_channel``'s signature at
+    ``chunk=CHUNK``) on their own layouts (``_laid_out``); JAX differentiates these layouts, the kernels'
+    ``custom_vjp`` the rule."""
+    sweeps, more, operands, s = _laid_out(q, k, v, g, beta)
+    o = _flat_rule(*sweeps, q.shape[2], jnp.dtype(STATE_DTYPE), interpret, **more)(*operands)
+    return o.reshape(o.shape[:2] + (v.shape[2], -1))[:, :s]
 
 
 def _program(chunk=CHUNK, **head_sizes):

@@ -484,26 +484,32 @@ class FamilySuite(ModelSuite):
         keeps its routing and gathered rows (the backward pass holds no second
         router product, selection or sort: tests/test_moe_remat.py), and
         ``check_the_cells_step`` holds what the family's kernels must show."""
-        from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
-
-        cell = self.family.cell
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         self.before_the_cells_step(monkeypatch)
-        with jax.default_matmul_precision("default"):  # conftest.py sets ``highest`` for CPU numerics; the entry points run at the default
-            setup = abstract_train_setup(
-                {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, cell.preset, devices=topo.devices[:1], accum=2,
-                seq=cell.seq, per_dp_batch=cell.rows, param_dtype="bfloat16", model_overrides=cell.overrides,
-                train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024))
-            if cell.float32_moments:
-                setup = dataclasses.replace(setup, state=setup.state.replace(opt_state=jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
-                    setup.state.opt_state)))
-            lowered = setup.lower()
-            compiled = lowered.compile()
-        text = compiled.as_text()
-        assert "jit(gmm)" in text, "no grouped product kernel in the step"
-        again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', text)
+        step = compiled_cells_step(self.family.cell, topo, monkeypatch)
+        assert "jit(gmm)" in step.text, "no grouped product kernel in the step"
+        again = re.findall(r'op_name="[^"]*rematted_computation[^"]*/router/(dot_general|top_k|jit\(argsort\))', step.text)
         assert not again, again
-        self.check_the_cells_step(SimpleNamespace(
-            setup=setup, lowered=lowered, compiled=compiled, text=text, layers=setup.model_config.num_layers,
-            names=re.findall(r'op_name="([^"]+)"', text), calls=lambda kernel: mosaic_calls(text, kernel)))
+        self.check_the_cells_step(step)
+
+
+def compiled_cells_step(cell, topo, monkeypatch):
+    """``cell``'s train step (a ``CellStep``) lowered and compiled for ONE described v5e, with what the families'
+    checks read of it: the optimized program's text, its ``op_name``s, the Mosaic calls under a kernel's name."""
+    from llm_fine_tune_distributed_tpu.observe.scaling import abstract_train_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision("default"):  # conftest.py sets ``highest`` for CPU numerics; the entry points run at the default
+        setup = abstract_train_setup(
+            {"data": 1, "fsdp": 1, "tensor": 1, "seq": 1}, cell.preset, devices=topo.devices[:1], accum=2,
+            seq=cell.seq, per_dp_batch=cell.rows, param_dtype="bfloat16", model_overrides=cell.overrides,
+            train_kwargs=dict(freeze_strategy="none", remat_policy="full", attention_impl="flash", loss_chunk_size=1024))
+        if cell.float32_moments:
+            setup = dataclasses.replace(setup, state=setup.state.replace(opt_state=jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=x.sharding) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+                setup.state.opt_state)))
+        lowered = setup.lower()
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    return SimpleNamespace(
+        setup=setup, lowered=lowered, compiled=compiled, text=text, layers=setup.model_config.num_layers,
+        names=re.findall(r'op_name="([^"]+)"', text), calls=lambda kernel: mosaic_calls(text, kernel))

@@ -184,25 +184,27 @@ def test_rule_is_the_cost_of_a_kept_byte():
 @pytest.mark.parametrize("preset, attention, sweep", [
     ("qwen3_next_80b_a3b", "linear", "gdn_rule_fwd"), ("kimi_linear_48b_a3b", "kda", "kda_rule_fwd"),
 ], ids=["a-decay-a-head", "a-decay-a-channel"])
-def test_the_delta_rules_forward_sweep_runs_once_where_the_block_keeps_its_two_outputs(monkeypatch, preset, attention, sweep):
+def test_the_delta_rules_forward_sweep_runs_once_where_the_block_keeps_all_its_outputs(monkeypatch, preset, attention, sweep):
     """The rule as its two Pallas sweeps (under the interpreter, one key head of 128 serving two value heads, a row of
     100 tokens padded to a step) inside ``jax.checkpoint`` with the policy a block of that model gets. Where the
-    kernels run (a TPU at these heads) the policy saves the sweep's output AND the states its backward sweep reads:
-    one forward sweep in the gradient's program. With ``o`` alone saved nothing is removed (the states still need the
-    whole sweep), as under the policy of a backend that runs the XLA form, which keeps nothing of the rule at these
-    widths: two. Same kernels on the same operands either way: every cotangent equal bit for bit."""
+    kernels run (a TPU at these heads) the policy saves the sweep's output AND everything its backward sweep reads of
+    it (the states; with a decay a channel each chunk's ``T``, decayed ``k k^T`` and ``P`` too, PR 48): one forward sweep
+    in the gradient's program. With one of a sweep's names missing nothing is removed (that one still needs the whole
+    sweep), as under the policy of a backend that runs the XLA form, which keeps nothing of the rule at these widths:
+    two. Same kernels on the same operands either way: every cotangent equal bit for bit."""
     config, policies = get_preset(preset), jax.checkpoint_policies
     by_channel = attention == "kda"
+    names = ("gdn_o", "gdn_states") + ("kda_t_kk", "kda_p") * by_channel
     assert keeps_scan_output(config) == ()  # this CPU: the XLA form's count, 224 against 2048 and 256 against 2304
     monkeypatch.setattr(transformer, "REMAT_KEEPS", {})
     recomputes = _remat_policy("full", config, 8192, None, attention)
     assert f"{attention} (full): moe_*" in transformer.remat_summary()
     with monkeypatch.context() as on_a_tpu:
         on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
-        assert keeps_scan_output(config) == gated_delta.KEPT_ACROSS_REMAT == ("gdn_o", "gdn_states")
+        assert keeps_scan_output(config) == (gated_delta.KEPT_BY_CHANNEL if by_channel else gated_delta.KEPT_ACROSS_REMAT) == names
         keeps = _remat_policy("full", config, 8192, None, attention)
         _remat_policy("mlp", config, 8192, None, "latent" if by_channel else "heads")  # the model's one softmax layer
-    kinds = sorted([f"{'latent' if by_channel else 'heads'} (mlp): flash_o, flash_lse, moe_*", f"{attention} (full): gdn_o, gdn_states, moe_*"])
+    kinds = sorted([f"{'latent' if by_channel else 'heads'} (mlp): flash_o, flash_lse, moe_*", f"{attention} (full): {', '.join(names)}, moe_*"])
     # the line a run prints: whether the mechanism engaged
     assert transformer.remat_summary() == f"a rematerialized block keeps, besides what its policy does: {'; '.join(kinds)}"
     keys = jax.random.split(jax.random.PRNGKey(44), 5)
@@ -216,8 +218,9 @@ def test_the_delta_rules_forward_sweep_runs_once_where_the_block_keeps_its_two_o
         return jax.grad(lambda *a: jnp.sum(jnp.sin(rule(*a))), argnums=(0, 1, 2, 3, 4))
 
     calls = {name: _kernel_calls(jax.make_jaxpr(gradient(policy))(q, k, v, g, beta).jaxpr, sweep) for name, policy in (
-        ("both kept", keeps), ("o alone", policies.save_only_these_names("gdn_o")), ("neither", recomputes))}
-    assert calls == {"both kept": 1, "o alone": 2, "neither": 2}
+        ("all kept", keeps), ("o alone", policies.save_only_these_names("gdn_o")), ("none", recomputes),
+        *((f"all but {name}", policies.save_only_these_names(*(n for n in names if n != name))) for name in names[1:]))}
+    assert calls == {"all kept": 1, "o alone": 2, "none": 2, **{f"all but {name}": 2 for name in names[1:]}}
     kept, recomputed = (jax.jit(gradient(policy))(q, k, v, g, beta) for policy in (keeps, recomputes))
     for name, a, b in zip("q k v g beta".split(), kept, recomputed):
         assert float(jnp.abs(a).max()) > 0, name

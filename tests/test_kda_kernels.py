@@ -171,6 +171,55 @@ def test_by_channel_kernels_on_bfloat16_operands_stand_where_the_xla_form_stands
         assert bool(jnp.isfinite(a).all()) and _rel(a, c) < max(1.25 * _rel(b, c), 2.0 ** -7), (name, _rel(a, c), _rel(b, c))
 
 
+def _kept_by_the_forward_sweep(q, k, v, g, beta):
+    """``(T, the decayed k k^T, P)`` ``[b, value heads, chunks, C, C]`` as ``kda_rule_fwd`` (interpreted) writes them for
+    its backward sweep, taken out of their lane-dense layouts (``T`` beside ``k k^T``; two chunks' ``P`` side by side),
+    from the layouts ``_rule_kernels`` hands the sweep."""
+    (b, _, hk, _), hv, c = q.shape, v.shape[2], gated_delta.CHUNK
+    (fwd, _), _, operands, _ = gated_delta._laid_out(q, k, v, g, beta)
+    n = operands[0].shape[1] // c
+    o, states, t_kk, p = fwd(*operands, hk=hk, state_dtype=jnp.float32, interpret=True)
+    assert t_kk.shape == (b, hv, n * c, 2 * c) and t_kk.dtype == jnp.float32 and p.shape == (b, hv, n * c // 2, 2 * c) and p.dtype == k.dtype
+    t_kk = t_kk.reshape(b, hv, n, c, 2 * c)
+    return t_kk[..., :c], t_kk[..., c:], jnp.swapaxes(p.reshape(b, hv, n // 2, c, 2, c), 3, 4).reshape(b, hv, n, c, c)
+
+
+def _chunk_matrices_of_the_xla_form(q, k, v, g, beta):
+    """The same three from ``_rule_xla_by_channel``'s own ``_chunk_matrices``, float32, on chunks laid out as it lays
+    them (a key head's value heads each get a copy of its q and k), rows padded as the kernels pad them."""
+    (b, _, hk, d), hv, c, sub = q.shape, v.shape[2], gated_delta.CHUNK, gated_delta.SUB
+    (q, k, g, beta), _ = gated_delta._padded_rows((q, k, g, beta), gated_delta.STEP_CHUNKS * c)
+    by_head = lambda x: jnp.moveaxis(x.reshape(b, -1, c, hv, x.shape[-1]), 3, 1)  # noqa: E731  [b, h, n, C, .]
+    blocks = lambda x: x.reshape(x.shape[:3] + (c // sub, sub, d))  # noqa: E731
+    qc, kc = (by_head(jnp.repeat(x, hv // hk, axis=2)) for x in (q, k))
+    local = jnp.cumsum(blocks(by_head(g.astype(jnp.float32))), axis=-2)
+    return gated_delta._chunk_matrices(blocks(qc), blocks(kc), by_head(beta.astype(jnp.float32)[..., None]), local)
+
+
+@pytest.mark.parametrize("seq, hk, hv, dtype", [
+    (1024, 1, 1, jnp.float32), (100, 1, 1, jnp.float32), (600, 1, 2, jnp.bfloat16), (200, 2, 6, jnp.float32),
+], ids=["rows-of-whole-steps", "a-row-padded-to-a-step", "bfloat16-operands", "key-heads-that-serve-three-value-heads"])
+def test_the_forward_sweep_keeps_each_chunks_inverse_and_decayed_products_for_the_backward_sweep(seq, hk, hv, dtype):
+    """What the backward sweep READS since PR 48 and made again before: each chunk's ``T = (I + A)^-1`` and decayed ``k
+    k^T`` in float32 and its ``P`` (the decayed ``q k^T`` from the diagonal down) in the operands' dtype, written by the
+    forward kernel, equal ``_rule_xla_by_channel``'s own for the same chunk: the float32 ones to summation order
+    (the inverse by levels and Newton steps against a triangular solve), ``P`` to one rounding of its dtype; the
+    padding's chunks hold the identity and nothing (k = 0, beta = 0 there)."""
+    args = _rule_inputs(11, 2, seq, hk, hv, "drawn", dtype)
+    got = jax.jit(_kept_by_the_forward_sweep)(*args)
+    want = jax.jit(_chunk_matrices_of_the_xla_form)(*args)
+    for name, a, b in zip(("T", "kk", "P"), got, want):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
+        assert _gap(a, b) < (2e-5 if a.dtype == jnp.float32 else 2.0 ** -8), (name, _gap(a, b))
+        assert float(jnp.abs(jnp.triu(a, 1)).max()) == 0.0, name                       # nothing above the diagonal
+    t, kk, p = got
+    padding = -(-seq // gated_delta.CHUNK)                                             # the first chunk that is padding alone
+    assert (padding == t.shape[2]) == (seq == 1024)
+    np.testing.assert_array_equal(t[:, :, padding:], jnp.broadcast_to(jnp.eye(gated_delta.CHUNK), t[:, :, padding:].shape))
+    assert not bool(kk[:, :, padding:].any()) and not bool(p[:, :, padding:].any())
+    assert float(jnp.abs(kk[:, :, 0]).max()) > 0.1 and float(jnp.abs(p[:, :, 0].astype(jnp.float32)).max()) > 1e-3
+
+
 def test_the_sums_from_each_sub_blocks_start_and_their_cotangent():
     """``_from_sub_block_start``: four shifted adds give g's running sum inside each sub-block of 16 (and nothing of
     the sub-block before), and against time the sum's cotangent."""

@@ -36,7 +36,7 @@ import pytest
 
 from family_suite import (
     BIAS, CellStep, Family, FamilySuite, Published, Refusals, Rules, Shares, _bfloat16_gaps, _logit_gap, _params, _rel,
-    kernel_passes, mixer_passes, xla_remats,
+    compiled_cells_step, kernel_passes, mixer_passes, xla_remats,
 )
 from llm_fine_tune_distributed_tpu.config import ModelConfig
 from llm_fine_tune_distributed_tpu.models import transformer
@@ -55,9 +55,11 @@ SEQ = 72  # rows of a chunk and a half: the rule pads, and its second chunk star
 RTOL, BF16_RTOL = 1e-4, 4e-2
 # The rule with a decay a CHANNEL in the Kimi cell's step as its two sweeps landed (PR 43): distinct Mosaic programs by
 # kernel and their serialized modules' bytes together: a warm start of that cell pays for this text (and no longer for
-# the XLA form's Python loops over sub-blocks).
+# the XLA form's Python loops over sub-blocks). Since PR 48 the backward sweep reads each chunk's ``T`` and decayed
+# products and holds neither ``_kda_products`` nor the inverse: 78,092 -> 60,412 bytes, the forward sweep's 35,620 ->
+# 37,420 for the two writes (113,712 -> 97,832 together).
 KDA_RULE_PROGRAMS = {"kda_rule_fwd": 1, "kda_rule_bwd": 1}
-KDA_RULE_MODULE_BYTES = 112_700
+KDA_RULE_MODULE_BYTES = 97_832
 
 
 def bench_cfg(mc=MC) -> dict:
@@ -193,13 +195,14 @@ class TestKimiLinear(FamilySuite):
     def check_rules(self, monkeypatch):
         """...and what a block keeps: a KDA block whose rule is XLA's scan (this CPU) recomputes it (128 + 64 x 2 = 256
         operations a kept byte against the hidden 2304); where the rule is the Pallas sweeps (a TPU at the model's
-        heads of 128) it keeps both of the forward sweep's outputs; the latent layer at 8192 keeps the flash kernel's o
-        and lse (Moonlight's widths)."""
+        heads of 128) it keeps ALL FOUR of the forward sweep's outputs (``o``, the states and, since PR 48, what the
+        backward sweep made again before: ``T`` beside the decayed ``k k^T``, and ``P``); the latent layer at 8192 keeps
+        the flash kernel's o and lse (Moonlight's widths)."""
         big = get_preset("kimi_linear_48b_a3b")
         assert keeps_scan_output(big) == () and keeps_scan_output(big.replace(hidden_size=128)) == ("gdn_o",)
         with monkeypatch.context() as on_a_tpu:
             on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
-            assert keeps_scan_output(big) == ("gdn_o", "gdn_states")
+            assert keeps_scan_output(big) == ("gdn_o", "gdn_states", "kda_t_kk", "kda_p")
             assert keeps_scan_output(MC) == ("gdn_o",)  # heads of 16: the XLA form there too, 16 + 64 x 2 = 144 against 64
         assert keeps_flash_outputs(big, 8192, None) and not keeps_flash_outputs(big, 1024, None)
         assert moe.pairs_a_chunk(big.replace(held_experts=tuple(range(8)))) >= 1
@@ -209,18 +212,20 @@ class TestKimiLinear(FamilySuite):
 
     def check_the_cells_step(self, step):
         """The compiler's own count stays under the cell's memory line (15.0 GiB for the five layers: these four hold
-        0.1 G of state less) and at its landed value (11.79 GiB since the rule's kernels, PR 43; 14.576 while the XLA
+        0.1 G of state less) and at its landed value (11.91 GiB since PR 48 keeps each chunk's ``T`` and decayed
+        products, 320 MiB a KDA layer and microbatch; 11.79 GiB with the rule's kernels, PR 43; 14.576 while the XLA
         form held a row's ``U``, ``W``, ``P`` and decayed operands of all chunks); the latent layer takes the RESIDENT
         flash kernels at q/k 192 against v 128, one query a kv head, on a row of 8192 (``dispatch_summary()`` says
         which set), its forward kernel once (``o`` and ``lse`` kept); each KDA layer's rule is the two Pallas sweeps
-        for a decay a channel (``kda_rule_fwd`` ONCE, its ``o`` and per-step states kept across the block's remat
-        since PR 44, ``kda_rule_bwd`` once; XLA's triangular solve is out of the step and it rematerializes nothing of
+        for a decay a channel (``kda_rule_fwd`` ONCE and never under a ``rematted_computation`` path, every one of
+        its four outputs kept across the block's remat (PRs 44 and 48; the case below shows the count can fail),
+        ``kda_rule_bwd`` once; XLA's triangular solve is out of the step and it rematerializes nothing of
         its own) between the two fused passes' kernels, the out pass with its sigmoid gate; ``kda_gates`` is on the
         step's operations; ``CALLS`` names the kernel form; and the sweeps' Mosaic programs and serialized bytes are
         held where they landed (``KDA_RULE_PROGRAMS``, ``KDA_RULE_MODULE_BYTES``), as the scalar rule's are in the
         Qwen3-Next step."""
         text = step.text
-        assert step.compiled.memory_analysis().peak_memory_in_bytes <= 11.9 * 2**30 < 15.0 * 2**30
+        assert step.compiled.memory_analysis().peak_memory_in_bytes <= 12.0 * 2**30 < 15.0 * 2**30
         for kernel in ("fwd", "dq", "dkv"):
             assert step.calls(f"flash_attention_{kernel}") == 1, kernel  # the resident set, the forward kernel kept
             assert step.calls(f"flash_attention_causal_{kernel}") == 0, kernel
@@ -239,6 +244,17 @@ class TestKimiLinear(FamilySuite):
         found = {name: x for name, x in mosaic_programs(step.lowered.as_text()).items() if name.endswith(("_rule_fwd", "_rule_bwd"))}
         assert {name: x["programs"] for name, x in found.items()} == KDA_RULE_PROGRAMS, found
         assert sum(x["bytes"] for x in found.values()) <= 1.2 * KDA_RULE_MODULE_BYTES, found
+
+    def test_a_block_that_keeps_none_of_the_rules_names_runs_the_forward_sweep_twice(self, topo, monkeypatch):
+        """What ``check_the_cells_step``'s count of sweeps can see: ONE KDA layer of the cell's step under a policy that
+        saves none of the rule's names (``keeps_scan_output`` made to answer as it does for the XLA form at these
+        widths) holds ``kda_rule_fwd`` a second time, under a ``rematted_computation`` path; with the names kept
+        (above) that call is not in the program."""
+        monkeypatch.setattr(transformer, "keeps_scan_output", lambda config: ())
+        cell = dataclasses.replace(FAMILY.cell, overrides=dict(FAMILY.cell.overrides, num_layers=1, layer_types=("linear_attention",)))
+        sweeps = kernel_passes(compiled_cells_step(cell, topo, monkeypatch).text, "linear_attn/gdn_scan", r"\w+_rule_\w+")
+        assert sweeps == [("jvp(layer0)", "", "kda_rule_fwd"), ("transpose(jvp(layer0))", "", "kda_rule_bwd"),
+                          ("transpose(jvp(layer0))", "rematted_computation/", "kda_rule_fwd")], sweeps
 
     def test_bfloat16_forward_stands_by_the_float32_reference(self, flat, ids):
         """The cell's compute dtype. Every product's output is rounded to 8 bits of mantissa (2^-9 relative a rounding,
