@@ -68,6 +68,12 @@ STEPS = {
     # its neighbours: two more frozen layers, and the same tokens as two rows of 16,384 (ISSUE 46)
     "evabyte-6.5b-d12.32k-1row": ("evabyte_6_5b", dict(num_layers=12), 1, 1, 32768, _EVA_RECIPE),
     "evabyte-6.5b-d10.16k-2rows": ("evabyte_6_5b", dict(num_layers=10), 2, 1, 16384, _EVA_RECIPE),
+    # Granite 4.0-H Micro whole (36 Mamba-2 layers and 4 GQA layers at heads of 64), the last 2 layers and the tied table
+    # trained: one row of 8192 x 2 (the backward pass crosses all 40 layers on its way to the table's lookup)
+    "granite-4.0-h-micro.sft-8k-ssd-tied-last2": ("granite_4_0_h_micro", {}, 1, 2, 8192, _EVA_RECIPE),
+    # its neighbours: the same tokens a step as two rows a microbatch and as one row of 16,384 (ISSUE 49)
+    "granite-4.0-h-micro.8k-2rows": ("granite_4_0_h_micro", {}, 2, 1, 8192, _EVA_RECIPE),
+    "granite-4.0-h-micro.16k-1row": ("granite_4_0_h_micro", {}, 1, 1, 16384, _EVA_RECIPE),
     # the long-row neighbour no cell measures: benchmarks/long_context.py at 4096
     "smollm3-3b.4k-mlp-ce512": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp", loss_chunk_size=512)),
     "smollm3-3b.4k-mlp": ("smollm3_3b", {}, 1, 8, 4096, dict(_DENSE, remat_policy="mlp")),
@@ -128,6 +134,12 @@ def main(names) -> int:
                 eva={
                     kernel: n
                     for kernel in ("eva_remote_fwd", "eva_remote_dq", "eva_remote_dkv")
+                    if (n := sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text))
+                },
+                # the state-space scan's two sweeps (ops/ssd.py)
+                ssd={
+                    kernel: n
+                    for kernel in ("ssd_scan_fwd", "ssd_scan_bwd")
                     if (n := sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text))
                 },
                 # the expert layer's sum of rows into tokens (ops/moe._sum_held_rows): 4 an expert layer, 2 of them behind the overflow cond

@@ -26,7 +26,8 @@ class LayerPlan:
     # "heads" (q/k/v projections, GQA) | "latent" (MLA, one low-rank kv latent) |
     # "linear" (Gated DeltaNet: a recurrence over time, no softmax, no rope, no window) |
     # "kda" (Kimi Delta Attention: that recurrence with a decay a channel behind low-rank gates) |
-    # "eva" (EvaByte: softmax over the query's own aligned window and one learned summary a chunk of every earlier window)
+    # "eva" (EvaByte: softmax over the query's own aligned window and one learned summary a chunk of every earlier window) |
+    # "ssd" (a Mamba-2 mixer: a state-space scan with a scalar decay a head, one B and C a group of heads; no softmax, no rope)
     attention: str
     rope: bool  # rotary embedding on this layer's q and k (SmolLM3's NoPE layers: False)
     rope_kind: str  # which of the forward's cos/sin tables: "plain" | "scaled" (the config's context extension)
@@ -49,8 +50,11 @@ class ModelConfig:
     Qwen3-Next, ``afmoe`` (Trinity: gated window layers with rope beside
     gated global layers without, four norms a block around routed experts),
     ``kimi_linear`` (Kimi Delta Attention layers beside latent attention
-    without rope), and ``evabyte`` (EVA attention in every layer, a float32
-    residual stream, several next-token heads).
+    without rope), ``evabyte`` (EVA attention in every layer, a float32
+    residual stream, several next-token heads), and ``granitemoehybrid``
+    (Granite 4.0-H: Mamba-2 state-space layers beside a few GQA layers without
+    rope, the embedding, both residual adds, the attention scores and the
+    logits each under a constant multiplier).
     """
 
     name: str = "unnamed"
@@ -122,8 +126,9 @@ class ModelConfig:
     sliding_window: Optional[int] = None  # Mistral-style local attention
     # HF ``layer_types``, one entry a layer: "sliding_attention" (the window
     # applies) | "full_attention" (global) | "linear_attention" (a Gated
-    # DeltaNet mixer, the ``linear_*`` fields below). Empty = the window, if
-    # any, on every layer (or on even ones, ``alternating_sliding_window``).
+    # DeltaNet mixer, the ``linear_*`` fields below) | "mamba" (a Mamba-2
+    # mixer, the ``mamba_*`` fields below). Empty = the window, if any, on
+    # every layer (or on even ones, ``alternating_sliding_window``).
     layer_types: tuple = ()
     # --- Qwen3-Next (HF Qwen3NextConfig) ---
     # A "linear_attention" layer's mixer (ops/gated_delta.py): key heads and
@@ -142,6 +147,23 @@ class ModelConfig:
     # ``sigmoid((x W_ga) W_gb)`` through another pair (as many key heads as value heads).
     linear_decay_rank: int = 0
     linear_gate_rank: int = 0
+    # --- Granite 4.0-H (``granitemoehybrid``) ---
+    # A "mamba" layer's mixer (ops/ssd.py; HF ``GraniteMoeHybridMambaLayer``): ``mamba_n_heads`` heads of
+    # ``mamba_d_head`` channels (together the inner width), each with a state ``[mamba_d_head, mamba_d_state]`` under
+    # ONE decay a head and token; B and C (``mamba_d_state`` wide) shared by the heads of a group; a causal depthwise
+    # convolution of ``mamba_d_conv`` taps WITH a bias over ``[x | B | C]``.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    # The family's four constants; at 1 (None) no operation is emitted. The embedding's output times
+    # ``embedding_multiplier``; both residual adds of a block ``x + residual_multiplier * f(norm(x))``; the logits
+    # DIVIDED by ``logits_scaling``; the attention scores times ``attention_multiplier`` in place of head_dim ** -0.5.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
     # --- EvaByte (``evabyte``, ``attention_class`` eva) ---
     # eva_window > 0 makes every layer's mixer EVA attention (ops/eva_attention.py): token n sees the tokens of its
     # own ALIGNED window ``[eva_window * (n // eva_window), n]`` exactly, and every EARLIER window through one pooled
@@ -226,11 +248,11 @@ class ModelConfig:
             if self.router_scoring not in ("sigmoid", "softmax"):
                 raise ValueError(f"router_scoring {self.router_scoring!r}: expected 'sigmoid' or 'softmax'")
         if self.layer_types:
-            kinds = set(self.layer_types) - {"sliding_attention", "full_attention", "linear_attention"}
+            kinds = set(self.layer_types) - {"sliding_attention", "full_attention", "linear_attention", "mamba"}
             if kinds or len(self.layer_types) < self.num_layers:
                 raise ValueError(
                     f"layer_types must name each of the {self.num_layers} layers 'sliding_attention', "
-                    f"'full_attention' or 'linear_attention' (got {len(self.layer_types)} entries, unknown "
+                    f"'full_attention' or 'linear_attention' (or 'mamba': a Mamba-2 mixer) (got {len(self.layer_types)} entries, unknown "
                     f"kinds {sorted(kinds)})"
                 )
             if "sliding_attention" in self.layer_types[: self.num_layers] and self.sliding_window is None:
@@ -247,6 +269,12 @@ class ModelConfig:
                         f"as many key heads as value heads (got ranks {self.linear_decay_rank}, "
                         f"{self.linear_gate_rank}, heads {hk} and {hv})"
                     )
+            if "mamba" in self.layer_types[: self.num_layers]:
+                sizes = (self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state, self.mamba_n_groups, self.mamba_d_conv)
+                if min(sizes) < 1:
+                    raise ValueError("layer_types has mamba layers: set the mamba_* fields")
+                if self.mamba_n_heads % self.mamba_n_groups:
+                    raise ValueError(f"mamba_n_groups={self.mamba_n_groups} must divide mamba_n_heads={self.mamba_n_heads}")
         if self.eva_window:
             if self.eva_chunk < 1 or self.eva_window % self.eva_chunk:
                 raise ValueError(f"eva_window={self.eva_window} must be a multiple of eva_chunk={self.eva_chunk}")
@@ -317,6 +345,7 @@ class ModelConfig:
         kd = self.linear_num_key_heads * self.linear_key_head_dim
         vd = self.linear_num_value_heads * self.linear_value_head_dim
         taps, hv = self.linear_conv_kernel_dim, self.linear_num_value_heads
+        inner, bc = self.mamba_n_heads * self.mamba_d_head, 2 * self.mamba_n_groups * self.mamba_d_state
         mixers = {
             "heads": softmax,
             "latent": softmax,
@@ -329,6 +358,12 @@ class ModelConfig:
                 h * (2 * kd + vd) + (2 * kd + vd) * taps + h * hv
                 + self.linear_decay_rank * (h + kd) + hv + kd
                 + self.linear_gate_rank * (h + vd) + self.linear_value_head_dim + vd * h
+            ),
+            # in_proj [z | x B C | dt], the convolution over [x | B | C] with its bias, A_log, D and dt_bias a head,
+            # the gated norm over the inner width, out_proj
+            "ssd": (
+                h * (2 * inner + bc + self.mamba_n_heads) + (inner + bc) * (self.mamba_d_conv + 1)
+                + 3 * self.mamba_n_heads + inner + inner * h
             ),
         }
         if self.num_experts:
@@ -369,8 +404,8 @@ class ModelConfig:
         # (odd) and Mistral applies the window everywhere
         window = self.sliding_window
         kind = self.layer_types[i] if self.layer_types else None
-        if kind == "linear_attention":
-            mixer = "kda" if self.linear_decay_rank else "linear"
+        if kind in ("linear_attention", "mamba"):
+            mixer = "ssd" if kind == "mamba" else "kda" if self.linear_decay_rank else "linear"
             return LayerPlan(attention=mixer, rope=False, rope_kind="plain", window=None, feed_forward=feed_forward)
         if self.layer_types:
             if kind == "full_attention":

@@ -124,6 +124,9 @@ class LatentAttentionNotServed(NotImplementedError):
     _LACKS["eva"] = ("EVA attention: it is supported on the training path only; serving it needs a cache of the "
                      "current window's keys and values beside a growing list of pooled summaries, and a decode path "
                      "for its several next-token heads, which infer/ lacks")
+    _LACKS["ssd"] = ("state-space (Mamba-2) layers: it is supported on the training path only; serving it needs each "
+                     "layer's state and the convolution's last inputs as a cache entry (continuous batching of states), "
+                     "which infer/ lacks")
 
     def __init__(self, name: str, kind: str = "latent"):
         super().__init__(f"model {name!r} has {self._LACKS[kind]}")
@@ -132,7 +135,7 @@ class LatentAttentionNotServed(NotImplementedError):
 def unserved_layer_kind(config: ModelConfig):
     """The first kind of layer of ``config`` that has no cache here, or None."""
     kinds = {config.layer(i).attention for i in range(config.num_layers)}
-    return next((kind for kind in ("latent", "linear", "kda", "eva") if kind in kinds), None)
+    return next((kind for kind in ("latent", "linear", "kda", "eva", "ssd") if kind in kinds), None)
 
 
 class Generator:

@@ -649,6 +649,65 @@ PRESETS = {
         num_pred_heads=8,
         fp32_residual=True,
     ),
+    "granite_4_0_h_micro": ModelConfig(
+        # HF ibm-granite/granite-4.0-h-micro (model_type granitemoehybrid): 40 pre-norm blocks, nine Mamba-2 layers
+        # (ops/ssd.py: 64 heads of 64 with a state of 128 under one scalar decay a head, one B and C for all heads, a
+        # convolution of 4 taps with a bias over [x | B | C], the gate before ONE norm over the 4096 inner channels)
+        # to one GQA layer (32 / 8 heads of 64, NO rope, scores times attention_multiplier 1/64) at 5, 15, 25, 35; the
+        # feed-forward a SwiGLU MLP of 8192 (HF's shared_mlp; num_local_experts 0: no routed part); the embedding
+        # times 12, both residual adds times 0.22, the logits divided by 8; vocabulary 100,352, tied. 36 x 76,182,976
+        # (mixer 25,847,232: in_proj 2048 x 8512, the convolution 4352 x 4 + 4352, A_log, D, dt_bias 64 each, the
+        # gated norm 4096, out_proj 4096 x 2048; MLP 50,331,648; two norms 4096) + 4 x 60,821,504 (q, o 2048 x 2048;
+        # k, v 2048 x 512) + 205,520,896 (the table) + 2048 (final norm) = 3,191,396,096 parameters. Training path only.
+        name="granite_4_0_h_micro",
+        vocab_size=100352,
+        hidden_size=2048,
+        intermediate_size=8192,
+        num_layers=40,
+        num_heads=32,
+        num_kv_heads=8,
+        rope_theta=10_000.0,
+        max_position_embeddings=131072,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=True,
+        layer_types=tuple("full_attention" if i % 10 == 5 else "mamba" for i in range(40)),
+        no_rope_layers=(0,) * 40,
+        mamba_n_heads=64,
+        mamba_d_head=64,
+        mamba_d_state=128,
+        mamba_n_groups=1,
+        mamba_d_conv=4,
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_scaling=8.0,
+        attention_multiplier=0.015625,
+    ),
+    "tiny_granite_h": ModelConfig(
+        # Granite 4.0-H's structure at toy widths (tests, the benchmark's CPU rehearsal): ten layers in the published
+        # pattern (attention at 5), Mamba heads of 16 with a state of 32, attention heads of 16
+        name="tiny_granite_h",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=176,
+        num_layers=10,
+        num_heads=4,
+        num_kv_heads=2,
+        rope_theta=10_000.0,
+        max_position_embeddings=2048,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=True,
+        layer_types=tuple("full_attention" if i % 10 == 5 else "mamba" for i in range(10)),
+        no_rope_layers=(0,) * 10,
+        mamba_n_heads=8,
+        mamba_d_head=16,
+        mamba_d_state=32,
+        mamba_n_groups=1,
+        mamba_d_conv=4,
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_scaling=8.0,
+        attention_multiplier=0.015625,
+    ),
     "mistral_7b": ModelConfig(
         name="mistral_7b",
         vocab_size=32000,
@@ -738,6 +797,17 @@ def to_hf_dict(mc: ModelConfig) -> dict:
         "eva_chunk": mc.eva_chunk,
         "num_pred_heads": mc.num_pred_heads,
         "fp32_residual": mc.fp32_residual,
+        "embedding_multiplier": mc.embedding_multiplier,
+        "residual_multiplier": mc.residual_multiplier,
+        "logits_scaling": mc.logits_scaling,
+        "attention_multiplier": mc.attention_multiplier,
+        **({
+            "mamba_n_heads": mc.mamba_n_heads,
+            "mamba_d_head": mc.mamba_d_head,
+            "mamba_d_state": mc.mamba_d_state,
+            "mamba_n_groups": mc.mamba_n_groups,
+            "mamba_d_conv": mc.mamba_d_conv,
+        } if "mamba" in mc.layer_types else {}),
         **({
             "linear_num_key_heads": mc.linear_num_key_heads,
             "linear_num_value_heads": mc.linear_num_value_heads,
@@ -1058,6 +1128,45 @@ def _evabyte_fields(g) -> dict:
     )
 
 
+def _granite_hybrid_fields(g) -> dict:
+    """ModelConfig fields of a ``granitemoehybrid`` config (IBM Granite 4.0-H): ``layer_types`` of ``mamba`` and
+    ``attention``, the Mamba-2 mixer's sizes, the SwiGLU MLP HF calls ``shared_mlp``, the family's four multipliers.
+    Whatever of it this framework does not implement is refused by name, before any weight loads."""
+    problems = []
+    if g("num_local_experts"):
+        problems.append(f"num_local_experts {g('num_local_experts')} (implemented: the shared MLP alone; the family's "
+                        "larger models route experts beside it)")
+    if g("mamba_proj_bias"):
+        problems.append("mamba_proj_bias (implemented: no bias on in_proj and out_proj)")
+    if not g("mamba_conv_bias", True):
+        problems.append("mamba_conv_bias false (implemented: the convolution with its bias)")
+    heads, groups = g("mamba_n_heads") or 0, g("mamba_n_groups") or 1
+    if heads % groups:
+        problems.append(f"mamba_n_groups {groups} that does not divide mamba_n_heads {heads}")
+    if (g("mamba_expand") or 0) * (g("hidden_size") or 0) != heads * (g("mamba_d_head") or 0):
+        problems.append(f"mamba_expand {g('mamba_expand')} x hidden_size that is not mamba_n_heads x mamba_d_head")
+    if g("normalization_function", "rmsnorm") != "rmsnorm":
+        problems.append(f"normalization_function {g('normalization_function')!r} (implemented: 'rmsnorm')")
+    position = g("position_embedding_type", "nope")
+    if position not in ("nope", "rope"):
+        problems.append(f"position_embedding_type {position!r} (implemented: 'nope', 'rope')")
+    if g("rope_scaling"):
+        problems.append(f"rope_scaling {g('rope_scaling')!r} (implemented: none)")
+    kinds = set(g("layer_types") or ()) - {"mamba", "attention"}
+    if kinds or not g("layer_types"):
+        problems.append(f"layer_types with {sorted(kinds) or 'no entry'} (implemented: 'mamba', 'attention')")
+    if problems:
+        raise ValueError("granitemoehybrid config has " + "; ".join(problems))
+    # (the mixer's sizes and the four multipliers go by HF's own names: ``from_hf_config`` reads them as it reads this
+    # framework's own save)
+    return dict(
+        layer_types=tuple("mamba" if kind == "mamba" else "full_attention" for kind in g("layer_types")),
+        no_rope_layers=(int(position == "rope"),) * g("num_hidden_layers"),
+        intermediate_size=g("shared_intermediate_size") or g("intermediate_size"),
+        sliding_window=None,
+    )
+
+
 def load_model_config(path: str) -> ModelConfig:
     """Read ``path/config.json`` (HF layout) into a ModelConfig — the ONE
     place train-time (trainer._resolve_model_config) and inference-time
@@ -1152,6 +1261,7 @@ def from_hf_config(hf_config) -> ModelConfig:
         )
     # (an evabyte config is read, and refused by name, before the dataclass's own checks see its keys)
     evabyte = _evabyte_fields(g) if mt == "evabyte" and not framework_save else {}
+    granite = _granite_hybrid_fields(g) if mt == "granitemoehybrid" and not framework_save else {}
     mc = ModelConfig(
         name=g("model_type", "hf_model"),
         vocab_size=g("vocab_size"),
@@ -1236,7 +1346,17 @@ def from_hf_config(hf_config) -> ModelConfig:
         mlp_bias=bool(g("mlp_bias", False)),
         no_rope_layers=tuple(no_rope),
         sliding_window=g("sliding_window") if g("use_sliding_window", True) else None,
-        layer_types=tuple(g("layer_types") or ()),
+        layer_types=granite.get("layer_types") or tuple(g("layer_types") or ()),
+        # (explicit keys of this framework's own save; a granitemoehybrid config's come from _granite_hybrid_fields)
+        mamba_n_heads=g("mamba_n_heads") or 0,
+        mamba_d_head=g("mamba_d_head") or 0,
+        mamba_d_state=g("mamba_d_state") or 0,
+        mamba_n_groups=g("mamba_n_groups") or 1,
+        mamba_d_conv=g("mamba_d_conv") or 4,
+        embedding_multiplier=float(g("embedding_multiplier") or 1.0),
+        residual_multiplier=float(g("residual_multiplier") or 1.0),
+        logits_scaling=float(g("logits_scaling") or 1.0),
+        attention_multiplier=g("attention_multiplier"),
         # (explicit keys of this framework's own save; a qwen3_next config's come from _qwen3_next_fields)
         partial_rotary_factor=float(g("partial_rotary_factor") or 1.0),
         attention_output_gate=bool(g("attention_output_gate", False)),
@@ -1255,7 +1375,7 @@ def from_hf_config(hf_config) -> ModelConfig:
         fp32_residual=bool(g("fp32_residual", False)),
         # MoE (HF MixtralConfig naming). router_aux_loss_coef=0.0 is a
         # legitimate explicit choice (aux disabled) — only None falls back.
-        num_experts=g("num_local_experts", 0) or 0,
+        num_experts=0 if granite else g("num_local_experts", 0) or 0,
         num_experts_per_tok=g("num_experts_per_tok", 2) or 2,
         router_aux_coef=(
             0.01 if g("router_aux_loss_coef") is None else g("router_aux_loss_coef")
@@ -1271,4 +1391,6 @@ def from_hf_config(hf_config) -> ModelConfig:
         return dataclasses.replace(mc, **_kimi_linear_fields(g))
     if evabyte:
         return dataclasses.replace(mc, **evabyte)
+    if granite:
+        return dataclasses.replace(mc, **granite)
     return dataclasses.replace(mc, **deepseek) if deepseek else mc

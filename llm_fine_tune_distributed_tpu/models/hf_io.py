@@ -203,6 +203,46 @@ def _kimi_from_stored(state: Dict[str, np.ndarray], config: ModelConfig) -> Dict
     return out
 
 
+# HF granitemoehybrid (IBM Granite 4.0-H) names that differ from the tree's (as remembered: no network here). A Mamba-2
+# layer's mixer is the layer's ``mamba`` (``in_proj``, ``conv1d`` with its bias, ``dt_bias``, ``A_log``, ``D``, ``norm``,
+# ``out_proj``: the tree's names). The feed-forward is ``shared_mlp`` with ONE ``input_linear.weight [2 f, hidden]``, the
+# gate's rows then the up projection's, and ``output_linear``; the tree keeps ``mlp`` with the three leaves
+# ``_dense_mlp`` reads, so the leaf is cut in two here.
+_GRANITE_MLP = re.compile(r"^(model\.layers\.\d+)\.(mlp|shared_mlp)\.(.*)$")
+
+
+def _granite(config: Optional[ModelConfig]) -> bool:
+    return config is not None and "mamba" in config.layer_types
+
+
+def _granite_to_stored(state: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """A state dict under the tree's dotted names (torch layout) -> under HF granitemoehybrid's."""
+    out = {}
+    for name, arr in state.items():
+        m = _GRANITE_MLP.match(name)
+        if m and m.group(3) == "gate_proj.weight":
+            out[f"{m.group(1)}.shared_mlp.input_linear.weight"] = np.concatenate([arr, state[f"{m.group(1)}.mlp.up_proj.weight"]], axis=0)
+        elif m and m.group(3) == "down_proj.weight":
+            out[f"{m.group(1)}.shared_mlp.output_linear.weight"] = arr
+        elif not (m and m.group(3) == "up_proj.weight"):
+            out[name] = arr
+    return out
+
+
+def _granite_from_stored(state: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    out = {}
+    for name, arr in state.items():
+        m = _GRANITE_MLP.match(name)
+        if m and m.group(3) == "input_linear.weight":
+            half = arr.shape[0] // 2
+            out[f"{m.group(1)}.mlp.gate_proj.weight"], out[f"{m.group(1)}.mlp.up_proj.weight"] = arr[:half], arr[half:]
+        elif m and m.group(3) == "output_linear.weight":
+            out[f"{m.group(1)}.mlp.down_proj.weight"] = arr
+        else:
+            out[name] = arr
+    return out
+
+
 # HF evabyte (EvaByte/EvaByte) stores EVA attention's two leaves a head as ``[1, heads, 1, 1, d]`` (as remembered: they
 # broadcast against ``[batch, heads, windows, chunks, d]``); the tree keeps them ``[heads, d]``. Every other name of
 # the checkpoint is a Llama block's, and ``lm_head.weight`` is ``[num_pred_heads x vocab, hidden]``, head by head.
@@ -268,6 +308,8 @@ def pytree_to_hf_state_dict(params, config: Optional[ModelConfig] = None) -> Dic
         else:
             hf_name = ".".join(path)
         state[hf_name] = np.ascontiguousarray(arr)
+    if _granite(config):
+        return _granite_to_stored(state)
     return _kimi_to_stored(state, config) if _kimi(config) else state
 
 
@@ -289,11 +331,14 @@ def hf_state_dict_to_pytree(state: Dict[str, np.ndarray], config: ModelConfig, d
                 "block_sparse_moe.gate", "kv_a_proj_with_mqa", "kv_b_proj", "mlp.gate.",
                 "in_proj_qkvz", "in_proj_ba", "linear_attn.out_proj", "shared_expert_gate",
                 "linear_attn.b_proj", "f_a_proj", "f_b_proj", "g_a_proj", "g_b_proj",
+                "mamba.in_proj", "mamba.out_proj",
             )
         )
 
     if _kimi(config):
         state = _kimi_from_stored(state, config)
+    if _granite(config):
+        state = _granite_from_stored(state)
     deepseek_re = re.compile(r"^(.*\.mlp\.experts)\.(\d+)\.(gate_proj|up_proj|down_proj)\.weight$")
     stacked_name = {v: k for k, v in _DEEPSEEK_EXPERT.items()}
     held_row = {expert: row for row, expert in enumerate(config.held_expert_ids)}

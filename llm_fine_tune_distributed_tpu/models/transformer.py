@@ -32,7 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from llm_fine_tune_distributed_tpu.config import LayerPlan, ModelConfig
 from llm_fine_tune_distributed_tpu.observe.xla import scope
-from llm_fine_tune_distributed_tpu.ops import eva_attention, gated_delta, moe
+from llm_fine_tune_distributed_tpu.ops import eva_attention, gated_delta, moe, ssd
 from llm_fine_tune_distributed_tpu.ops import rope as rope_ops
 from llm_fine_tune_distributed_tpu.ops.attention import attention, head_major_reason, softcap, xla_attention
 from llm_fine_tune_distributed_tpu.ops.int8 import (
@@ -146,6 +146,16 @@ def _init_heads_attention(keys, config: ModelConfig, dense, dtype):
     return attn
 
 
+def _scaled_queries(xq, config: ModelConfig):
+    """``q_proj``'s output under the config's attention scale (Granite's ``attention_multiplier``: the scores are ``m q
+    k^T`` where every other model here has ``d ** -0.5 q k^T``), folded into q as ``m d ** 0.5`` so that every attention
+    path, the flash kernels among them, keeps its one scale; the product's epilogue, and exact where the factor is a
+    power of two (Granite 4.0-H: 0.015625 x 8). None: the projection's output as it came, no operation."""
+    if config.attention_multiplier is None:
+        return xq
+    return xq * jnp.asarray(config.attention_multiplier * config.resolved_head_dim ** 0.5, xq.dtype)
+
+
 def _heads_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
     """q ``[b, s, heads, d]``, k and v ``[b, s, kv_heads, d]`` from their own
     projections, and the output gate ``[b, s, heads * d]`` (None without
@@ -167,6 +177,7 @@ def _heads_qkv(attn_p, hid, cos, sin, config: ModelConfig, lin, rope):
             q, gate = q[..., :d], q[..., d:].reshape(b, s, config.num_heads * d)
         else:
             q = xq.reshape(b, s, config.num_heads, d)
+        q = _scaled_queries(q, config)
         k = xk.reshape(b, s, config.num_kv_heads, d)
         v = xv.reshape(b, s, config.num_kv_heads, d)
         if config.qk_norm:
@@ -201,7 +212,7 @@ def _heads_qkv_head_major(attn_p, hid, cos, sin, config: ModelConfig, lin, rope)
         xq, gate = _by_columns(hid, attn_p["q_proj"], (0, d, 2 * d), lin, heads=config.num_heads)
     else:
         xq, gate = lin(hid, attn_p["q_proj"]), None
-    xk, xv = lin(hid, attn_p["k_proj"]), lin(hid, attn_p["v_proj"])
+    xq, xk, xv = _scaled_queries(xq, config), lin(hid, attn_p["k_proj"]), lin(hid, attn_p["v_proj"])
     weights = {}
     if config.qk_norm:  # the pass takes a norm's MULTIPLIER: 1 + w where the model's norms are zero-centred
         one = 1.0 if config.zero_centered_norm else 0.0
@@ -371,17 +382,17 @@ def _by_columns(hid, p, cuts, lin, heads: int = 1):
     return [lin(hid, {name: run_of(x, lo, hi) if name in _CUT_BY_COLUMN else x for name, x in p.items()}) for lo, hi in runs]
 
 
-def _whole_rows_only(config: ModelConfig, segment_ids, cache_entry):
+def _whole_rows_only(config: ModelConfig, segment_ids, cache_entry, kind="linear-attention", module="ops/gated_delta.py"):
     """What a mixer that is a recurrence over time refuses, and why."""
     if segment_ids is not None:
         raise NotImplementedError(
-            f"model {config.name!r} has linear-attention layers and the batch is packed (segment_ids): the "
+            f"model {config.name!r} has {kind} layers and the batch is packed (segment_ids): the "
             "recurrent state and the convolution would have to restart at every segment boundary, which "
-            "ops/gated_delta.py does not do yet (ROADMAP.md, Reach D); train it with packing off"
+            f"{module} does not do yet (ROADMAP.md, Reach D); train it with packing off"
         )
     if cache_entry is not None:
         raise NotImplementedError(
-            "a linear-attention layer has the training form only; its cache is a state, not keys and values"
+            f"a {kind} layer has the training form only; its cache is a state, not keys and values"
         )
 
 
@@ -515,6 +526,49 @@ def _eva_mixer(attn_p, hid, cos, sin, *, config, plan, lin, rope, attention_impl
     return lin(out.reshape(b, s, config.num_heads * d), attn_p["o_proj"]), None
 
 
+def _init_ssd_attention(keys, config: ModelConfig, dense, dtype):
+    """HF ``GraniteMoeHybridMambaLayer``'s leaves (the Bamba mixer) under the subtree ``mamba``. The columns of
+    ``in_proj`` lie ``[z | x | B | C | dt]`` (inner, inner, groups x N, groups x N, heads); ``conv1d/weight`` is
+    ``[taps, x | B | C channels]`` (torch's ``[channels, 1, taps]`` transposed) beside its ``bias``. Drawn as the family
+    draws them: ``A_log = log(1 .. heads)``, ``D = 1``, ``dt_bias = softplus^-1`` of a log-uniform draw in [0.001, 0.1],
+    the gated norm's weight 1 over the whole inner width, no bias on either projection."""
+    h, heads = config.hidden_size, config.mamba_n_heads
+    inner, bc = heads * config.mamba_d_head, 2 * config.mamba_n_groups * config.mamba_d_state
+    dt = jnp.exp(jax.random.uniform(next(keys), (heads,), jnp.float32, math.log(1e-3), math.log(0.1)))
+    return {
+        "in_proj": {"kernel": dense(next(keys), (h, 2 * inner + bc + heads))},
+        "conv1d": {"weight": dense(next(keys), (config.mamba_d_conv, inner + bc)), "bias": jnp.zeros((inner + bc,), dtype)},
+        "A_log": jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)).astype(dtype),
+        "D": jnp.ones((heads,), dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),  # softplus^-1
+        "norm": {"weight": jnp.ones((inner,), dtype)},
+        "out_proj": {"kernel": dense(next(keys), (inner, h))},
+    }
+
+
+def _ssd_mixer(attn_p, hid, cos, sin, *, config, lin, mesh, segment_ids, cache_entry, **_):
+    """A Mamba-2 mixer (``ops/ssd.py``): ``in_proj``'s LEAF cut by column into z, x, ``[B | C]`` and dt (four products,
+    no ``[b, s, 8512]`` activation sliced: ``_by_columns``); x and ``[B | C]`` through the causal convolution with its
+    bias and silu, ``dt = softplus(dt + dt_bias)`` (``ssd_in``); the state-space scan a head with the decay ``exp(dt
+    A)``, ``A = -exp(A_log)``, one B and C a group of heads, the skip ``D x`` (``ssd_scan``); the gate ``silu(z)`` and
+    THEN one norm over a group's channels (``ssd_gate_norm``); ``out_proj``. No rope (``cos``/``sin`` unused), no
+    mask: the scan is causal, and what a right-padded row computes at its pads reaches no real token."""
+    _whole_rows_only(config, segment_ids, cache_entry, kind="state-space", module="ops/ssd.py")
+    b, s, _ = hid.shape
+    heads, p, n, groups = config.mamba_n_heads, config.mamba_d_head, config.mamba_d_state, config.mamba_n_groups
+    inner, gn = heads * p, groups * n
+    z, x, bc, dt = _by_columns(hid, attn_p["in_proj"], (0, inner, 2 * inner, 2 * inner + 2 * gn, 2 * inner + 2 * gn + heads), lin)
+    with scope("ssd_in"):
+        x, bc, dt = ssd.mixer_in(x, bc, dt, attn_p["conv1d"]["weight"], attn_p["conv1d"]["bias"], attn_p["dt_bias"])
+    with scope("ssd_scan"):
+        y = ssd.ssd_scan(
+            x.reshape(b, s, heads, p), dt, -jnp.exp(attn_p["A_log"].astype(jnp.float32)),
+            bc[..., :gn].reshape(b, s, groups, n), bc[..., gn:].reshape(b, s, groups, n), attn_p["D"].astype(jnp.float32), mesh=mesh)
+    with scope("ssd_gate_norm"):
+        y = ssd.gated_norm(y.reshape(b, s, inner), z, attn_p["norm"]["weight"], config.rms_norm_eps, groups=groups)
+    return lin(y.astype(hid.dtype), attn_p["out_proj"]), None
+
+
 # LayerPlan.attention -> (the layer's subtree of that kind, the scope its device
 # time is read under, its half of init_params, the mixer: normed input ->
 # (output [b, s, hidden], new cache entry))
@@ -524,6 +578,7 @@ _ATTENTION = {
     "linear": ("linear_attn", "linear_attn", _init_linear_attention, _linear_mixer),
     "kda": ("linear_attn", "linear_attn", _init_kda_attention, _kda_mixer),
     "eva": ("self_attn", "attn", _init_eva_attention, _eva_mixer),
+    "ssd": ("mamba", "linear_attn", _init_ssd_attention, _ssd_mixer),
 }
 
 
@@ -648,7 +703,7 @@ def init_params(rng, config: ModelConfig, dtype=jnp.float32) -> Params:
     """Random init (normal 0.02, HF convention). Returns the params pytree."""
     h, v = config.hidden_size, config.vocab_size
     kda = any(config.layer(i).attention == "kda" for i in range(config.num_layers))
-    keys = iter(jax.random.split(rng, 2 + config.num_layers * (17 if kda else 9)))  # (a layer draws at most 9, a KDA mixer's 14)
+    keys = iter(jax.random.split(rng, 2 + config.num_layers * (17 if kda else 9)))  # (a layer draws at most 9, a KDA mixer's 14, a Mamba-2 layer 7)
 
     def dense(key, shape):
         return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
@@ -854,7 +909,7 @@ def _block(
             # Gemma2, afmoe: post_attention_layernorm norms the mixer's OUTPUT
             with scope("out_norm"):
                 attn_out = rms_norm(attn_out, lp["post_attention_layernorm"]["weight"], eps, zero_centered=zc)
-        x = x + attn_out
+        x = x + _residual(attn_out, config)
 
     with scope("mlp"):
         pre_ffn = "pre_feedforward_layernorm" if config.sandwich_norms else "post_attention_layernorm"
@@ -871,8 +926,14 @@ def _block(
             # and the shared experts' outputs (afmoe's post_mlp_layernorm), of a share of them the partial sum
             with scope("out_norm"):
                 y = rms_norm(y, lp["post_feedforward_layernorm"]["weight"], eps, zero_centered=zc)
-        x = x + y
+        x = x + _residual(y, config)
     return x, new_entry, counted
+
+
+def _residual(y, config: ModelConfig):
+    """What a half of a block adds to the stream: its output, times ``residual_multiplier`` where the model has one
+    (Granite; in the output's dtype, as HF multiplies). At 1 no operation is emitted."""
+    return y if config.residual_multiplier == 1.0 else y * jnp.asarray(config.residual_multiplier, y.dtype)
 
 
 # {kind of block (its mixer, and its window where it has one): (remat policy, the names kept besides)} of every
@@ -991,7 +1052,12 @@ def _remat_policy(
     ``keeps_scan_output`` names of the rule: where the rule runs as the Pallas
     sweeps every output of the forward one (two, or four with a decay a
     channel), so that the forward sweep runs once a layer too; where it is
-    XLA's scan its output, by a rule of the mixer's widths.
+    XLA's scan its output, by a rule of the mixer's widths. A block whose mixer
+    is the state-space scan keeps nothing of it (``ops/ssd.KEPT_ACROSS_REMAT``
+    names what a later rule may keep: ``y`` and the sweeps' states are 80 MiB
+    a layer at Granite's row of 8192, 2.8 GiB for its 36 scans where the step
+    has 1.8 GiB of room, so such a rule has to choose by layer and be
+    measured: PERF.md section 7).
 
     And a model with a ``grouped_experts`` layer (``keeps_routing``: the
     layer's kind, no switch) keeps what that layer names
@@ -1021,7 +1087,9 @@ def _remat_policy(
             f"unknown remat_policy {remat_policy!r}; expected one of {sorted(policies)}"
         )
     policy = policies[remat_policy]
-    if attention in ("linear", "kda"):
+    if attention == "ssd":
+        names = ()
+    elif attention in ("linear", "kda"):
         names = keeps_scan_output(config)
     else:
         names = flash_attention.KEPT_ACROSS_REMAT if keeps_flash_outputs(config, seq, window) else ()
@@ -1164,6 +1232,8 @@ def forward_with_report(
             # Gemma normalizer: HF multiplies by a sqrt(hidden) scalar cast to
             # the activation dtype first — mirror the cast for bf16 bit-parity
             x = x * jnp.asarray(config.hidden_size**0.5, dtype=x.dtype)
+        if config.embedding_multiplier != 1.0:  # Granite: a constant (12), in the activation's dtype as HF multiplies
+            x = x * jnp.asarray(config.embedding_multiplier, dtype=x.dtype)
         if config.fp32_residual:
             x = x.astype(jnp.float32)
     tables = rope_tables(config, positions)
@@ -1352,6 +1422,8 @@ def unembed(params: Params, hidden, config: ModelConfig, *, compute_dtype=jnp.bf
             kernel = _lookup_table_constraint(kernel, mesh, vocab_dim=1)
         logits = h @ kernel
     logits = logits.astype(logits_dtype)
+    if config.logits_scaling != 1.0:  # Granite: the logits DIVIDED by a constant, elementwise, so each chunk of a chunked loss scales its own
+        logits = logits / jnp.asarray(config.logits_scaling, logits.dtype)
     if config.final_logit_softcap is not None:
         # Gemma2 final_logit_softcapping — elementwise, so it composes with
         # both CE chunking schemes (each slice caps its own logits)

@@ -842,6 +842,9 @@ STEP_SCOPES = (
     # inside "attn" of an EVA layer (ops/eva_attention.py), between the IN pass and o_proj: the pooling of keys and
     # values into one summary a chunk (both passes), and everything else (the kernels of both key sources)
     "eva_pool", "eva_agg",
+    # inside "linear_attn" of a Mamba-2 layer (ops/ssd.py), between in_proj's products and out_proj: the convolution
+    # with its bias, silu and the softplus of dt; the state-space scan; the gate and the norm after it
+    "ssd_in", "ssd_scan", "ssd_gate_norm",
 )
 
 

@@ -1076,10 +1076,13 @@ def flash_unsupported_reason(
     block = _pick_block(sq)
     if block == 0:
         return f"seq {sq} is not a multiple of 128"
-    if d_v % 128 != 0 or (d % 128 != 0 and d == d_v):
+    if (d_v % 128 != 0 or (d % 128 != 0 and d == d_v)) and (d, d_v) != (64, 64):
         # v heads fill whole lane registers; q/k heads wider than them (latent
         # attention: 192 against 128) are taken as they lie; one head size for
-        # all three has to be aligned
+        # all three has to be aligned, or HALF a register (Granite 4.0-H: 64),
+        # which is taken as it lies too: a block's last dimension is the whole
+        # head, it pads to 128 lanes in VMEM (``tiled_bytes`` counts that) and
+        # the MXU contracts 64 in one pass; nothing is padded in HBM
         return f"head dim {d_v} is not a multiple of the 128 lanes"
     if hq % hkv:
         return f"q heads {hq} not a multiple of kv heads {hkv}"

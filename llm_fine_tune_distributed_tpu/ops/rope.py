@@ -249,7 +249,11 @@ def calls_summary() -> str:
 
 def why_not_fused(rows: int, seq: int, head_dim: int, cos) -> "str | None":
     """Why the IN pass cannot take a call that the flash kernels take on a TPU (``ops/attention.head_major_reason`` has
-    been asked, and with it whether the head is whole 128-lane registers; None: it can). From the tables' shape."""
+    been asked; None: it can). From the head's width and the tables' shape: the pass reads a head's lanes of the
+    projections' FLAT output, a block ``[tokens, d]`` at lane ``d j``, which Mosaic takes at whole 128-lane registers
+    only (heads of 64, which the flash kernels take head-major as they lie, keep the XLA form's transposes)."""
+    if head_dim % 128:
+        return f"head dim {head_dim} is not whole 128-lane registers: the pass reads a head's lanes of the flat projections"
     if cos.ndim != 3 or cos.shape[0] not in (1, rows) or cos.shape[1] != seq or cos.shape[2] % 2 or cos.shape[2] > head_dim:
         return f"tables {tuple(cos.shape)} are not [{rows} or 1, {seq}, even width <= {head_dim}]"
     return None

@@ -57,6 +57,10 @@ _MATRIX_RULES = [
     # decay and of the output gate, the input dim of the first of a pair and the output dim of the second
     (re.compile(r".*linear_attn/(q_proj|k_proj|v_proj|b_proj|f_a_proj|g_a_proj)/" + _QK), ("fsdp", None)),
     (re.compile(r".*linear_attn/(f_b_proj|g_b_proj)/" + _QK), (None, "fsdp")),
+    # a Mamba-2 mixer: in_proj's columns hold [z | x | B | C | dt] side by side, so only its input dim shards;
+    # out_proj like the linear mixers'; the convolution's [taps, channels] and the per-head vectors stay whole
+    (re.compile(r".*mamba/in_proj/" + _QK), ("fsdp", None)),
+    (re.compile(r".*mamba/out_proj/" + _QK), (None, "fsdp")),
     (re.compile(r".*mlp/shared_expert_gate/" + _QK), ("fsdp", None)),
     # MLP (and the shared experts beside routed ones: the same SwiGLU)
     (re.compile(r".*mlp/(shared_experts/)?(gate_proj|up_proj)/" + _QK), ("fsdp", "tensor")),

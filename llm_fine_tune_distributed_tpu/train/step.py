@@ -140,6 +140,8 @@ def vocab_chunked_ce_sum(params, hidden, targets, mask, model_config: ModelConfi
                 table, (0, i * vocab_chunk), (h, vocab_chunk)
             ).astype(compute_dtype)
             lg = (x @ wc).astype(jnp.float32)
+        if model_config.logits_scaling != 1.0:  # as unembed(): Granite divides its logits by a constant
+            lg = lg / jnp.float32(model_config.logits_scaling)
         if model_config.final_logit_softcap is not None:
             from llm_fine_tune_distributed_tpu.ops.attention import softcap
 

@@ -1211,7 +1211,7 @@ class SFTTrainer:
                             # path it holds (a flash request that took XLA
                             # attention names its reason) and on what it runs
                             from llm_fine_tune_distributed_tpu.models import transformer
-                            from llm_fine_tune_distributed_tpu.ops import gated_delta, moe
+                            from llm_fine_tune_distributed_tpu.ops import gated_delta, moe, ssd
                             from llm_fine_tune_distributed_tpu.ops import eva_attention
                             from llm_fine_tune_distributed_tpu.ops import rope as rope_ops
                             from llm_fine_tune_distributed_tpu.ops.attention import (
@@ -1227,6 +1227,8 @@ class SFTTrainer:
                                 print(f"[train] {moe.sum_programs_summary()}", flush=True)
                             if gated_delta.CALLS:  # linear-attention layers: the form their rule took
                                 print(f"[train] {gated_delta.calls_summary()}", flush=True)
+                            if ssd.CALLS:  # state-space layers: the sweeps or the XLA form, and why
+                                print(f"[train] {ssd.calls_summary()}", flush=True)
                             if eva_attention.CALLS:  # EVA layers: the kernels or the XLA form, and why
                                 print(f"[train] {eva_attention.calls_summary()}", flush=True)
                             if rope_ops.CALLS:  # layers of heads: the fused IN pass or the XLA form, and why

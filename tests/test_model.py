@@ -145,6 +145,9 @@ PLAN_OF_PRESET = {
     "trinity_mini": _TRINITY(2048, 2), "tiny_trinity": _TRINITY(32, 1),
     "kimi_linear_48b_a3b": _KIMI((3, 7, 11, 15, 19, 23, 26)), "tiny_kimi_linear": _KIMI((3,)),
     "evabyte_6_5b": dict(attention="eva"), "tiny_evabyte": dict(attention="eva"),  # every layer, with rope
+    # Granite 4.0-H: nine Mamba-2 layers to one softmax layer (at 5, 15, ...), no rope anywhere
+    "granite_4_0_h_micro": dict(attention=lambda i: "heads" if i % 10 == 5 else "ssd", nope=lambda i: True),
+    "tiny_granite_h": dict(attention=lambda i: "heads" if i % 10 == 5 else "ssd", nope=lambda i: True),
 }
 
 

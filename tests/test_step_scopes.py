@@ -100,10 +100,12 @@ GATED_BLOCK_SCOPES = ("qk_norm", "out_norm")
 KDA_SCOPES = ("kda_gates",)
 # the two halves of EVA attention between the IN pass and ``o_proj``: ``test_the_eva_mixers_scopes`` below
 EVA_SCOPES = ("eva_pool", "eva_agg")
+# the three parts of a Mamba-2 mixer between ``in_proj`` and ``out_proj``: ``test_the_ssd_mixers_scopes`` below
+SSD_SCOPES = ("ssd_in", "ssd_scan", "ssd_gate_norm")
 
 
 @pytest.mark.parametrize(
-    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES + KDA_SCOPES + EVA_SCOPES])
+    "name", [s for s in STEP_SCOPES if s not in EXPERT_LAYER_SCOPES + LINEAR_LAYER_SCOPES + GATED_BLOCK_SCOPES + KDA_SCOPES + EVA_SCOPES + SSD_SCOPES])
 def test_every_scope_of_the_vocabulary_is_named(paths, name):
     want = re.compile(r"^layer\d+$") if name == "layer" else re.compile(f"^{name}$")
     assert any(want.match(c) for p in paths for c in _components(p)), name
@@ -240,3 +242,15 @@ def test_the_eva_mixers_scopes():
             assert any(p.startswith(f"layer{layer}/{inside}") for p in paths), (layer, inside)
     assert any(p.startswith("loss_head") for p in paths)
     assert not any("eva_" in p for p in _scoped_forward("tiny"))
+
+
+def test_the_ssd_mixers_scopes():
+    """A Mamba-2 layer (``tiny_granite_h``) stands under ``linear_attn`` (so ``linear_attn_time_pct.train`` reads it) with
+    the convolution and dt's softplus (``ssd_in``), the scan (``ssd_scan``) and the gate and norm (``ssd_gate_norm``)
+    inside; its one attention layer keeps ``attn`` and ``attn_in``; no other model carries the three names."""
+    paths = _scoped_forward("tiny_granite_h")
+    for layer in (0, 4, 9):
+        for inside in ("linear_attn/ssd_in", "linear_attn/ssd_scan", "linear_attn/ssd_gate_norm", "mlp"):
+            assert any(p.startswith(f"layer{layer}/{inside}") for p in paths), (layer, inside)
+    assert any(p.startswith("layer5/attn/attn_in") for p in paths) and not any(p.startswith("layer5/linear_attn") for p in paths)
+    assert not any("ssd_" in p for p in _scoped_forward("tiny"))

@@ -385,3 +385,50 @@ def test_int8_trunk_matmul_compiles_for_v5e(one_chip, m, k, n):
         ((m, k), jnp.int8), ((m,), jnp.float32),
         ((k, n), jnp.int8), ((n,), jnp.float32),
     )
+
+
+# Granite 4.0-H Micro's microbatch: one row of 8192; 64 Mamba-2 heads of 64 with a state of 128; 32 / 8 attention heads of 64
+SSD_PROGRAMS = {"ssd_scan_fwd": 1, "ssd_scan_bwd": 1}
+SSD_LANDED = 28_740
+
+
+def test_the_state_space_sweeps_compile_for_v5e_and_their_text_is_held(one_chip):
+    """The scan's two sweeps at the cell's shapes through the ``custom_vjp``: two heads of 64 a 128-lane block, the
+    chunk's ``C B^T`` in VMEM scratch shared over the heads' grid axis, dB and dC resident over it, the transposed
+    products; one program each, their text inside its budget (the landed count and a fifth, stacks left out as for
+    the rule's kernels); nothing of ``[T, T]`` or ``[T, chunk]`` a head in the compiled program."""
+    from llm_fine_tune_distributed_tpu.ops import ssd
+
+    b, t, heads, p, n = 1, 8192, 64, 64, 128
+
+    def grads(x, dt, a, bm, cm, d):
+        loss = lambda *z: jnp.sum(ssd.ssd_scan(*z, impl="kernels").astype(jnp.float32) ** 2)  # noqa: E731
+        return jax.grad(loss, argnums=range(6))(x, dt, a, bm, cm, d)
+
+    shapes = [((b, t, heads, p), jnp.bfloat16), ((b, t, heads), jnp.float32), ((heads,), jnp.float32),
+              ((b, t, 1, n), jnp.bfloat16), ((b, t, 1, n), jnp.bfloat16), ((heads,), jnp.float32)]
+    with _without_call_stacks():
+        lowered = jax.jit(grads).lower(*(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in shapes))
+    text = lowered.compile().as_text()
+    assert sum("tpu_custom_call" in line for line in text.splitlines()) == 2
+    produced = [ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in text.splitlines()]
+    assert not [x for x in produced if f",{t},{t}]" in x or f"{heads},{t},{ssd.CHUNK}]" in x]
+    programs = mosaic_programs(lowered.as_text())
+    assert {name: x["programs"] for name, x in programs.items()} == SSD_PROGRAMS, programs
+    assert sum(x["bytes"] for x in programs.values()) <= 1.2 * SSD_LANDED, programs
+
+
+def test_flash_at_heads_of_64_compiles_for_v5e(one_chip):
+    """Granite's attention layers at a row of 8192: 4 queries a kv head at heads of 64 take the STREAMED kernels (the
+    resident dk/dv kernel's query group pads to 128 lanes in VMEM and passes the cap, as at heads of 128), blocks of 64
+    lanes as they lie; forward, dq and dk/dv."""
+    def loss(q, k, v):
+        return fa.pallas_flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    shapes = (((1, 8192, 32, 64), jnp.bfloat16), ((1, 8192, 8, 64), jnp.bfloat16), ((1, 8192, 8, 64), jnp.bfloat16))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *shapes).as_text()
+    calls = lambda kernel: sum("tpu_custom_call" in ln and f"/{kernel}/" in ln for ln in text.splitlines())  # noqa: E731
+    assert [calls(f"flash_attention_causal_{k}") for k in ("fwd", "dq", "dkv")] == [1, 1, 1]
+    resident = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, ((2, 1024, 32, 64), jnp.bfloat16),
+                        ((2, 1024, 8, 64), jnp.bfloat16), ((2, 1024, 8, 64), jnp.bfloat16)).as_text()
+    assert sum("tpu_custom_call" in ln and "/flash_attention_fwd/" in ln for ln in resident.splitlines()) == 1
