@@ -25,7 +25,8 @@ Which program runs a call is read from the input, the backend and the mesh (``_p
 
 - on a TPU, one device's program, one B/C group, heads that divide a 128-lane register (Granite: 64, two heads a
   register) and a state of whole registers: two Pallas sweeps, ``ssd_scan_fwd`` and ``ssd_scan_bwd`` behind one
-  ``custom_vjp`` (the section "kernels" below: the layout and why);
+  ``custom_vjp`` (the section "kernels" below: the layout and why). They take x, B, C and dt where the projections
+  and the IN pass leave them and make ``G`` and the row forms of the per-token scalars themselves, in VMEM;
 - everywhere else (a CPU, other head sizes, several groups, a mesh of several devices): XLA operations, a
   ``lax.scan`` over chunks (``_scan_xla``), which is also what the tests hold the kernels to.
 
@@ -145,16 +146,32 @@ def _scan_xla(x, dt, a, b, c, d, *, chunk: int = CHUNK):
 # keep their block index over the heads, so they are fetched once a step, and ``C B^T`` is made at the step's first
 # block of heads into VMEM scratch ``[STEP_CHUNKS, C, C]`` and read by the other blocks: once a chunk, not once a head.
 # The state of EVERY block of heads lives in scratch ``[blocks, N, 128]`` float32 (2 MiB at 64 heads of 64 x 128) along
-# the sequential step axis. The per-token scalars (``dt``, and ``G`` summed from each chunk's start in XLA, 2 MiB)
-# come as ROWS, a chunk's tokens along lanes, ``[b, blocks, chunks, heads a block, C]`` (the chunk a LEADING index: Mosaic
-# loads no single sublane at a dynamic offset): the row form is what the
-# ``[C, C]`` matrices broadcast over sublanes, and the column form is the diagonal of its broadcast (``_col``).
+# the sequential step axis.
+#
+# The per-token scalars: a head's work wants ``dt`` and ``G`` (``dt A`` summed from its chunk's start) as ROWS, a
+# chunk's tokens along lanes (the row form is what the ``[C, C]`` matrices broadcast over sublanes; the column form is
+# the diagonal of its broadcast, ``_col``). The sweeps read ``dt [b, s, heads]`` float32 AS ``mixer_in`` LEAVES IT, a
+# step's tokens of all heads a block whose index is constant over the heads' axis (fetched once a step, as B and C),
+# and ``a [1, heads]``; no XLA operation stands between the IN pass and a sweep but free reshapes. At the step's first
+# block of heads (where ``C B^T`` is made) ``_make_rows`` makes, for ALL heads and the step's chunks, both row forms
+# into scratch ``[STEP_CHUNKS, blocks, heads a block, C]``, which a later block of the same row and step reads by
+# LEADING indices only (Mosaic loads no single sublane at a dynamic offset): the token-major block ``[C, heads]``
+# times a 0/1 ``[C, C]`` matrix, contracted over the tokens, lands already turned, the identity for dt and ``U_ji =
+# [j <= i]`` for the running sum. Those products run at ``Precision.HIGHEST``: a decay is ``exp(G_i - G_j)`` and
+# ``|G|`` reaches the thousands for a fast head, the 0/1 matrix is exact in bfloat16 and ``dt A`` is not. (The other
+# way, a log-step shifted add over the tokens and a ``[C, C]`` transpose, was timed alone and not in the cell, where all
+# of this costs 0.01 to 0.02 ms a call: PERF.md, PR 50.)
 #
 # The forward sweep always writes the state each step starts from (``[b, steps, blocks, N, 128]`` float32, 16 MiB a
 # row of 8192: the backward sweep's only residual besides the inputs). The backward sweep walks a row's steps last to
 # first: inside a step the states forward once more into scratch, then the chunks against time with the state's
 # cotangent carried in scratch; dB and dC, which every head adds to, are output blocks that stay resident over the
 # heads' axis, and ``d(C B^T)`` adds up over the heads in scratch and becomes its two products at the last block.
+# The cotangents of a head's dt and G are rows too: they wait in scratch of the rows' layout for the step's LAST block
+# of heads, which turns them back with the same two 0/1 matrices (``U`` contracted over ``i`` is the running sum's
+# transpose: ``dt_j`` takes ``A`` times G's cotangents from ``j`` on) and writes dt's whole cotangent as dt lies,
+# ``[b, s, heads]``, an output block resident over the heads' axis as dB and dC are, and the step's part of ``a``'s
+# cotangent ``[b, steps, 1, heads]`` (XLA adds the parts up: a reduction to ``[heads]``).
 
 def _dot(x, y):
     return jnp.dot(x, y, preferred_element_type=_F32)
@@ -168,6 +185,17 @@ def _dot_nt(x, y):
 def _dot_tn(x, y):
     """``x^T y``."""
     return jax.lax.dot_general(x, y, (((0,), (0,)), ((), ())), preferred_element_type=_F32)
+
+
+def _turned(x, y):
+    """``x^T y`` in float32: tokens down ``[C, heads]`` times a 0/1 ``[C, C]`` matrix -> rows ``[heads, C]``, summed
+    (or merely turned) in float32. At the default precision the MXU would round ``dt A`` to bfloat16 first."""
+    return jax.lax.dot_general(x, y, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST, preferred_element_type=_F32)
+
+
+def _turned_back(x, y):
+    """``x y^T`` in float32: a 0/1 ``[C, C]`` matrix times rows ``[heads, C]`` -> ``[C, heads]``, as dt lies."""
+    return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST, preferred_element_type=_F32)
 
 
 def _col(row, eye):
@@ -184,16 +212,41 @@ def _rows(c):
     return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
 
 
+def _iotas():
+    return jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0), jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+
+
 def _masks():
-    ri = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    ri, ci = _iotas()
     return ri >= ci, ri == ci, ci[:1] == CHUNK - 1
 
 
-def _head_scalars(dt_ref, g_ref, c, j, lower, eye, at_last):
+def _zero_ones():
+    """The 0/1 matrices of the products that turn, float32: ``U_ji = [j <= i]`` (contracted over ``j`` a sum up to
+    ``i``, the running sum; contracted over ``i`` a sum from ``j`` on, its transpose) and the identity."""
+    ri, ci = _iotas()
+    return jnp.where(ri <= ci, 1.0, 0.0).astype(_F32), jnp.where(ri == ci, 1.0, 0.0).astype(_F32)
+
+
+def _make_rows(dt_ref, a_ref, dtr_ref, g_ref):
+    """At a step's first block of heads, for ALL heads and the step's chunks: dt and ``G``, the running sum of ``dt A``
+    from each chunk's start, as rows ``[heads, C]`` into scratch ``[STEP_CHUNKS, blocks, heads a block, C]``. One product
+    each with a 0/1 matrix, which lands turned: the identity for dt, ``U`` for the sum, float32 throughout."""
+    upper, same = _zero_ones()
+
+    def one(c, _):
+        dt = dt_ref[0, _rows(c), :]                                  # [C, heads], tokens down: the column form as it lies
+        dtr_ref[c] = _turned(dt, same).reshape(dtr_ref.shape[1:])
+        g_ref[c] = _turned(dt * a_ref[...], upper).reshape(g_ref.shape[1:])
+        return 0
+
+    jax.lax.fori_loop(0, STEP_CHUNKS, one, 0)
+
+
+def _head_scalars(dtr_ref, g_ref, c, block, j, lower, eye, at_last):
     """Head ``j`` of the block in chunk ``c``: dt and G as rows ``[1, C]``, G as a column, the decay mask ``L``, ``G_end``
     ``[1, 1]`` and ``exp(G_end - G_j)`` as a row."""
-    dt_row, g_row = dt_ref[0, 0, c, j:j + 1, :], g_ref[0, 0, c, j:j + 1, :]
+    dt_row, g_row = dtr_ref[c, block, j:j + 1, :], g_ref[c, block, j:j + 1, :]
     g_col = _col(g_row, eye)
     last = jnp.sum(jnp.where(at_last, g_row, 0.0), axis=1, keepdims=True)
     return dt_row, g_row, g_col, jnp.exp(jnp.where(lower, g_col - g_row, -jnp.inf)), last, jnp.exp(last - g_row)
@@ -207,7 +260,7 @@ def _make_cb(b_ref, c_ref, cb_ref):
     jax.lax.fori_loop(0, STEP_CHUNKS, one, 0)
 
 
-def _fwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, y_ref, s0_ref, cb_ref, state_ref, *, heads, p):
+def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, s0_ref, cb_ref, state_ref, dtr_ref, g_ref, *, heads, p):
     """A grid step of the forward sweep: ``heads`` heads of ``p`` lanes side by side, ``STEP_CHUNKS`` chunks."""
     step, block = pl.program_id(1), pl.program_id(2)
     cd = x_ref.dtype
@@ -217,6 +270,7 @@ def _fwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, y_ref, s0_ref, cb_ref
     @pl.when(block == 0)
     def _():
         _make_cb(b_ref, c_ref, cb_ref)
+        _make_rows(dt_ref, a_ref, dtr_ref, g_ref)
 
     @pl.when(step == 0)
     def _():
@@ -231,7 +285,7 @@ def _fwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, y_ref, s0_ref, cb_ref
         y = d_ref[0] * x32
         e_l, w_l, whole = jnp.zeros_like(x32), jnp.zeros_like(x32), jnp.zeros((1, heads * p), _F32)
         for j in range(heads):
-            dt_row, _, g_col, decay, last, rest = _head_scalars(dt_ref, g_ref, c, j, lower, eye, at_last)
+            dt_row, _, g_col, decay, last, rest = _head_scalars(dtr_ref, g_ref, c, block, j, lower, eye, at_last)
             mine = head_of_lane == j
             y = y + _dot((cb * decay * dt_row).astype(cd), jnp.where(mine, x, jnp.zeros_like(x)))
             e_l = jnp.where(mine, jnp.exp(g_col), e_l)
@@ -244,10 +298,12 @@ def _fwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, y_ref, s0_ref, cb_ref
     state_ref[block] = jax.lax.fori_loop(0, STEP_CHUNKS, chunk, state_ref[block])
 
 
-def _bwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_ref, ddt_ref, dg_ref, db_ref, dc_ref,
-                cb_ref, dcb_ref, s_at, ds_ref, *, heads, p):
+def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_ref, ddt_ref, da_ref, db_ref, dc_ref,
+                cb_ref, dcb_ref, s_at, ds_ref, dtr_ref, g_ref, ddtr_ref, dgr_ref, *, heads, p):
     """A grid step of the backward sweep (a row's steps come last first): the step's states forward once more from the
-    state the forward sweep kept, then its chunks against time with the state's cotangent carried (``ds_ref``)."""
+    state the forward sweep kept, then its chunks against time with the state's cotangent carried (``ds_ref``). The
+    cotangents of a head's dt and G are rows; they wait in scratch for the step's last block of heads, which writes
+    dt's cotangent as dt lies, G's folded in through the running sum's transpose, and the step's part of ``a``'s."""
     step, block = pl.program_id(1), pl.program_id(2)
     cd = x_ref.dtype
     lower, eye, at_last = _masks()
@@ -256,9 +312,11 @@ def _bwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_re
     @pl.when(block == 0)
     def _():
         _make_cb(b_ref, c_ref, cb_ref)
+        _make_rows(dt_ref, a_ref, dtr_ref, g_ref)
         dcb_ref[...] = jnp.zeros_like(dcb_ref)
         db_ref[...] = jnp.zeros_like(db_ref)
         dc_ref[...] = jnp.zeros_like(dc_ref)
+        da_ref[...] = jnp.zeros_like(da_ref)
 
     @pl.when(step == 0)
     def _():
@@ -269,7 +327,7 @@ def _bwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_re
         e_l, w_l = jnp.zeros((CHUNK, heads * p), _F32), jnp.zeros((CHUNK, heads * p), _F32)
         whole = jnp.zeros((1, heads * p), _F32)
         for j in range(heads):
-            dt_row, _, g_col, _, last, rest = _head_scalars(dt_ref, g_ref, c, j, lower, eye, at_last)
+            dt_row, _, g_col, _, last, rest = _head_scalars(dtr_ref, g_ref, c, block, j, lower, eye, at_last)
             mine = head_of_lane == j
             e_l = jnp.where(mine, jnp.exp(g_col), e_l)
             w_l = jnp.where(mine, _col(rest * dt_row, eye), w_l)
@@ -298,7 +356,7 @@ def _bwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_re
         dcb = dcb_ref[c]
         e_l, w_l, whole = jnp.zeros_like(x32), jnp.zeros_like(x32), jnp.zeros((1, heads * p), _F32)
         for j in range(heads):
-            dt_row, g_row, g_col, decay, last, rest = _head_scalars(dt_ref, g_ref, c, j, lower, eye, at_last)
+            dt_row, g_row, g_col, decay, last, rest = _head_scalars(dtr_ref, g_ref, c, block, j, lower, eye, at_last)
             mine = head_of_lane == j
             x_j, dy_j = jnp.where(mine, x, jnp.zeros_like(x)), jnp.where(mine, dy, jnp.zeros_like(dy))
             masked = cb * decay                                      # (C B^T) * L
@@ -312,9 +370,9 @@ def _bwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_re
             dg_col = jnp.sum(through, axis=1, keepdims=True) + e_col * jnp.sum(jnp.where(mine, dy32 * of_state, 0.0), axis=1, keepdims=True)
             at_end = jnp.sum(dw_row * w_row, axis=1, keepdims=True) + jnp.exp(last) * jnp.sum(
                 jnp.where(mine, ds * s.astype(_F32), 0.0), axis=(0, 1), keepdims=True)
-            ddt_ref[0, 0, c, j:j + 1, :] = jnp.sum(k, axis=0, keepdims=True) + dw_row * rest
-            dg_ref[0, 0, c, j:j + 1, :] = (_row(dg_col, eye) - jnp.sum(through, axis=0, keepdims=True) - dw_row * w_row
-                                           + jnp.where(at_last, at_end, 0.0))
+            ddtr_ref[c, block, j:j + 1, :] = jnp.sum(k, axis=0, keepdims=True) + dw_row * rest
+            dgr_ref[c, block, j:j + 1, :] = (_row(dg_col, eye) - jnp.sum(through, axis=0, keepdims=True) - dw_row * w_row
+                                             + jnp.where(at_last, at_end, 0.0))
             e_l = jnp.where(mine, e_col, e_l)
             w_l = jnp.where(mine, _col(w_row, eye), w_l)
             whole = jnp.where(mine, jnp.exp(last), whole)
@@ -329,6 +387,12 @@ def _bwd_kernel(x_ref, dt_ref, g_ref, b_ref, c_ref, d_ref, dy_ref, s0_ref, dx_re
             dcbc = dcb.astype(cd)
             dc_ref[0, rows, :] += _dot(dcbc, b_c)
             db_ref[0, rows, :] += _dot_tn(dcbc, c_c)
+            # every head's rows are in: G_i holds dt_j A for j <= i, so dt_j takes A times G's cotangents from j on
+            upper, same = _zero_ones()
+            flat = (ddtr_ref.shape[1] * ddtr_ref.shape[2], CHUNK)
+            from_here = _turned_back(upper, dgr_ref[c].reshape(flat))                # [C, heads]
+            ddt_ref[0, rows, :] = _turned_back(same, ddtr_ref[c].reshape(flat)) + a_ref[...] * from_here
+            da_ref[0, 0] += jnp.sum(dt_ref[0, rows, :] * from_here, axis=0, keepdims=True)
 
         return whole * ds + _dot_tn(c_c, dy_e)
 
@@ -340,57 +404,68 @@ def _params(interpret):
         dimension_semantics=("parallel", "arbitrary", "arbitrary"), vmem_limit_bytes=64 * 2**20))
 
 
-def _block_specs(p, heads, n, at):
+def _block_specs(p, per_block, all_heads, n, at):
     """Block specs over ``(row, step, block of heads)``, a row's steps at ``at(t)``: (x, y and their cotangents) a
-    block's lanes of ``[b, s, heads x P]``; (dt, G) the block's rows ``[b, blocks, chunks, heads a block, C]``; (B, C)
-    ``[b, s, N]``, the same for every block of heads; the skip's lanes ``[blocks, 1, lanes]``; a step's state
-    ``[b, steps, blocks, N, lanes]``."""
-    tokens, lanes = STEP_CHUNKS * CHUNK, heads * p
+    block's lanes of ``[b, s, heads x P]``; (dt and its cotangent) a step's tokens of ``[b, s, heads]`` as they lie,
+    ALL heads, the same for every block of heads, as (B, C) ``[b, s, N]`` are; ``a [1, heads]`` whole; the skip's lanes
+    ``[blocks, 1, lanes]``; a step's state ``[b, steps, blocks, N, lanes]``; a step's part of ``a``'s cotangent
+    ``[b, steps, 1, heads]``."""
+    tokens, lanes = STEP_CHUNKS * CHUNK, per_block * p
     return (
         pl.BlockSpec((1, tokens, lanes), lambda i, t, j: (i, at(t), j)),
-        pl.BlockSpec((1, 1, STEP_CHUNKS, heads, CHUNK), lambda i, t, j: (i, j, at(t), 0, 0)),
+        pl.BlockSpec((1, tokens, all_heads), lambda i, t, j: (i, at(t), 0)),
+        pl.BlockSpec((1, all_heads), lambda i, t, j: (0, 0)),
         pl.BlockSpec((1, tokens, n), lambda i, t, j: (i, at(t), 0)),
         pl.BlockSpec((1, 1, lanes), lambda i, t, j: (j, 0, 0)),
         pl.BlockSpec((1, 1, 1, n, lanes), lambda i, t, j: (i, at(t), j, 0, 0)),
+        pl.BlockSpec((1, 1, 1, all_heads), lambda i, t, j: (i, at(t), 0, 0)),
     )
 
 
+def _rows_scratch(blocks, per_block):
+    """A step's per-token scalars of every head as rows: a later block reads its heads' by LEADING indices."""
+    return pltpu.VMEM((STEP_CHUNKS, blocks, per_block, CHUNK), _F32)
+
+
 @functools.partial(jax.jit, static_argnames=("p", "state_dtype", "interpret"))
-def ssd_scan_fwd(x, dt, g, b, c, d, *, p, state_dtype, interpret):
-    """The forward sweep. ``x [b, s, heads x P]`` (``s`` whole steps), ``dt`` and ``g`` (``dt A`` summed from each
-    chunk's start) ``[b, blocks, s / C, heads a block, C]`` float32, ``b`` and ``c`` ``[b, s, N]``, ``d [blocks, 1,
-    lanes]`` float32 -> ``y`` like x and the state each step starts from ``[b, steps, blocks, N, lanes]``."""
+def ssd_scan_fwd(x, dt, a, b, c, d, *, p, state_dtype, interpret):
+    """The forward sweep. ``x [b, s, heads x P]`` (``s`` whole steps), ``dt [b, s, heads]`` float32 as ``mixer_in``
+    leaves it, ``a [1, heads]`` float32, ``b`` and ``c`` ``[b, s, N]``, ``d [blocks, 1, lanes]`` float32 -> ``y`` like x
+    and the state each step starts from ``[b, steps, blocks, N, lanes]``."""
     rows, s, _ = x.shape
-    blocks, n, heads = dt.shape[1], b.shape[2], dt.shape[3]
-    steps = s // (STEP_CHUNKS * CHUNK)
-    xs, scalars, bc, skip, state = _block_specs(p, heads, n, lambda t: t)
+    all_heads, n, per_block = dt.shape[2], b.shape[2], 128 // p
+    blocks, steps = all_heads // per_block, s // (STEP_CHUNKS * CHUNK)
+    xs, dts, whole, bc, skip, state, _ = _block_specs(p, per_block, all_heads, n, lambda t: t)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, p=p),
-        grid=(rows, steps, blocks), in_specs=[xs, scalars, scalars, bc, bc, skip], out_specs=[xs, state],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), jax.ShapeDtypeStruct((rows, steps, blocks, n, heads * p), state_dtype)],
-        scratch_shapes=[pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32), pltpu.VMEM((blocks, n, heads * p), state_dtype)],
+        functools.partial(_fwd_kernel, heads=per_block, p=p),
+        grid=(rows, steps, blocks), in_specs=[xs, dts, whole, bc, bc, skip], out_specs=[xs, state],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), jax.ShapeDtypeStruct((rows, steps, blocks, n, 128), state_dtype)],
+        scratch_shapes=[pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32), pltpu.VMEM((blocks, n, 128), state_dtype),
+                        _rows_scratch(blocks, per_block), _rows_scratch(blocks, per_block)],
         name="ssd_scan_fwd", interpret=interpret, **_params(interpret),
-    )(x, dt, g, b, c, d)
+    )(x, dt, a, b, c, d)
 
 
 @functools.partial(jax.jit, static_argnames=("p", "interpret"))
-def ssd_scan_bwd(x, dt, g, b, c, d, dy, states, *, p, interpret):
+def ssd_scan_bwd(x, dt, a, b, c, d, dy, states, *, p, interpret):
     """The backward sweep: ``ssd_scan_fwd``'s inputs, ``dy`` like x and the kept states -> the cotangents of x (like
-    it), of ``dt`` and ``g`` in their row form, and of ``b`` and ``c`` (float32, summed over the heads)."""
+    it), of ``dt`` as it lies (G's folded in), of ``a`` a step ``[b, steps, 1, heads]``, and of ``b`` and ``c`` (float32,
+    summed over the heads)."""
     rows, s, _ = x.shape
-    blocks, n, heads = dt.shape[1], b.shape[2], dt.shape[3]
-    steps = s // (STEP_CHUNKS * CHUNK)
-    xs, scalars, bc, skip, state = _block_specs(p, heads, n, lambda t: steps - 1 - t)
+    all_heads, n, per_block = dt.shape[2], b.shape[2], 128 // p
+    blocks, steps = all_heads // per_block, s // (STEP_CHUNKS * CHUNK)
+    xs, dts, whole, bc, skip, state, da = _block_specs(p, per_block, all_heads, n, lambda t: steps - 1 - t)
     like = jax.ShapeDtypeStruct
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, p=p),
-        grid=(rows, steps, blocks), in_specs=[xs, scalars, scalars, bc, bc, skip, xs, state],
-        out_specs=[xs, scalars, scalars, bc, bc],
-        out_shape=[like(x.shape, x.dtype), like(dt.shape, _F32), like(g.shape, _F32), like(b.shape, _F32), like(c.shape, _F32)],
+        functools.partial(_bwd_kernel, heads=per_block, p=p),
+        grid=(rows, steps, blocks), in_specs=[xs, dts, whole, bc, bc, skip, xs, state],
+        out_specs=[xs, dts, da, bc, bc],
+        out_shape=[like(x.shape, x.dtype), like(dt.shape, _F32), like((rows, steps, 1, all_heads), _F32), like(b.shape, _F32), like(c.shape, _F32)],
         scratch_shapes=[pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32), pltpu.VMEM((STEP_CHUNKS, CHUNK, CHUNK), _F32),
-                        pltpu.VMEM((STEP_CHUNKS, n, heads * p), states.dtype), pltpu.VMEM((blocks, n, heads * p), _F32)],
+                        pltpu.VMEM((STEP_CHUNKS, n, 128), states.dtype), pltpu.VMEM((blocks, n, 128), _F32)]
+                       + [_rows_scratch(blocks, per_block)] * 4,
         name="ssd_scan_bwd", interpret=interpret, **_params(interpret),
-    )(x, dt, g, b, c, d, dy, states)
+    )(x, dt, a, b, c, d, dy, states)
 
 
 @functools.lru_cache(maxsize=None)
@@ -398,44 +473,41 @@ def _flat_scan(p, state_dtype, interpret):
     """The two sweeps as one differentiable function of the kernels' own layouts. It keeps its inputs and the states
     the forward sweep wrote; both outputs are named (``KEPT_ACROSS_REMAT``), and the NAMED values are the primal output
     and the residual, so a ``jax.checkpoint`` whose policy saves both names has no forward sweep in its recomputed
-    pass. The skip's cotangent is one reduction in XLA, dead (and dropped) where ``D`` is frozen."""
+    pass. The skip's cotangent is one reduction in XLA, dead (and dropped) where ``D`` is frozen; ``a``'s the sum of
+    the steps' parts."""
 
-    def fwd(x, dt, g, b, c, d):
-        y, states = ssd_scan_fwd(x, dt, g, b, c, d, p=p, state_dtype=state_dtype, interpret=interpret)
+    def fwd(x, dt, a, b, c, d):
+        y, states = ssd_scan_fwd(x, dt, a, b, c, d, p=p, state_dtype=state_dtype, interpret=interpret)
         y, states = checkpoint_name(y, KEPT_ACROSS_REMAT[0]), checkpoint_name(states, KEPT_ACROSS_REMAT[1])
-        return y, (x, dt, g, b, c, d, states)
+        return y, (x, dt, a, b, c, d, states)
 
     @jax.custom_vjp
-    def scan(x, dt, g, b, c, d):
-        return fwd(x, dt, g, b, c, d)[0]
+    def scan(x, dt, a, b, c, d):
+        return fwd(x, dt, a, b, c, d)[0]
 
     def bwd(kept, dy):
-        x, dt, g, b, c, d, states = kept
-        dx, ddt, dg, db, dc = ssd_scan_bwd(x, dt, g, b, c, d, dy, states, p=p, interpret=interpret)
+        x, dt, a, b, c, d, states = kept
+        dx, ddt, da, db, dc = ssd_scan_bwd(x, dt, a, b, c, d, dy, states, p=p, interpret=interpret)
         lanes = d.shape[2]
         dd = jnp.sum((dy.astype(_F32) * x.astype(_F32)).reshape(-1, d.shape[0], lanes), axis=0)[:, None]
-        return dx, ddt, dg, db.astype(b.dtype), dc.astype(c.dtype), dd
+        return dx, ddt, jnp.sum(da, axis=(0, 1)), db.astype(b.dtype), dc.astype(c.dtype), dd
 
     scan.defvjp(fwd, bwd)
     return scan
 
 
 def _scan_kernels(x, dt, a, b, c, d, *, interpret=False):
-    """The scan through the kernels (``_scan_xla``'s signature at ``chunk=CHUNK``, one group) on their own layouts: rows
-    padded to whole steps with tokens that change nothing, heads flat in lanes, dt and the decay's running sum as rows.
-    JAX differentiates these layouts, the kernels' ``custom_vjp`` the scan."""
+    """The scan through the kernels (``_scan_xla``'s signature at ``chunk=CHUNK``, one group): rows padded to whole steps
+    with tokens that change nothing, heads flat in lanes, dt as ``mixer_in`` leaves it; nothing is summed or turned
+    here (the sweeps make each chunk's running sum and the row forms in VMEM). JAX differentiates the reshapes, the
+    kernels' ``custom_vjp`` the scan."""
     rows, _, heads, p = x.shape
     per_block = 128 // p
     (x, dt, b, c), s = _padded_rows((x, dt.astype(_F32), b, c), STEP_CHUNKS * CHUNK)
-    chunks, blocks = x.shape[1] // CHUNK, heads // per_block
-
-    def as_rows(z):  # [rows, s, heads] -> [rows, blocks, chunks, heads a block, C]: a chunk's tokens along lanes
-        return jnp.transpose(z.reshape(rows, chunks, CHUNK, blocks, per_block), (0, 3, 1, 4, 2))
-
-    cum = jnp.cumsum((dt * a.astype(_F32)).reshape(rows, chunks, CHUNK, heads), axis=2).reshape(rows, chunks * CHUNK, heads)
+    blocks = heads // per_block
     skip = jnp.broadcast_to(d.astype(_F32).reshape(blocks, per_block, 1), (blocks, per_block, p)).reshape(blocks, 1, 128)
-    flat = lambda z: z.reshape(rows, chunks * CHUNK, -1)  # noqa: E731
-    y = _flat_scan(p, jnp.dtype(STATE_DTYPE), interpret)(flat(x), as_rows(dt), as_rows(cum), flat(b), flat(c), skip)
+    flat = lambda z: z.reshape(rows, x.shape[1], -1)  # noqa: E731
+    y = _flat_scan(p, jnp.dtype(STATE_DTYPE), interpret)(flat(x), dt, a.astype(_F32).reshape(1, heads), flat(b), flat(c), skip)
     return y.reshape(rows, -1, heads, p)[:, :s]
 
 

@@ -12,13 +12,14 @@ order (the program's scan in chunks of 128 with a carried state, the reference's
 a time; the worst leaf observed is 6e-6, at a ``dt_bias`` whose gradient's norm is 7e-6). The scan against the walk
 token by token: 2e-4 absolute on outputs of order 10 and 5e-4 of each cotangent's norm (a chunk adds up 128 terms
 where the walk adds one). The kernels under the Pallas interpreter against the XLA form: 1e-5 of the output's norm,
-1e-4 of each cotangent's. The flash kernels at heads of 64 under the interpreter against XLA attention: 2e-5. bfloat16
+1e-4 of each cotangent's (dt's and ``a``'s too, which the backward sweep writes as dt lies and a step at a time). The flash kernels at heads of 64 under the interpreter against XLA attention: 2e-5. bfloat16
 against the float32 reference: the logits within 2e-2 of their norm, the loss within 2e-3 (the other dense models')."""
 
 from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import sys
 from types import SimpleNamespace
 
@@ -372,15 +373,59 @@ def test_the_passes_are_the_equations():
 # -- the kernels under the interpreter ----------------------------------------------------------------
 
 
-@pytest.mark.parametrize("rows, seq, heads, p", [(1, 300, 4, 64), (2, 1100, 2, 64), (1, 1024, 2, 128)])
+def _interpreted(*z):
+    return ssd.ssd_scan(*z, impl="kernels_interpret")
+
+
+@pytest.mark.parametrize("rows, seq, heads, p", [(1, 300, 4, 64), (2, 1100, 2, 64), (1, 1024, 2, 128), (1, 1025, 4, 64)])
 def test_the_sweeps_under_the_interpreter_are_the_xla_form(rows, seq, heads, p):
-    """Heads of 64 two a lane block (and of 128 one), a row shorter than a step and one of two steps (the carried state
-    and its cotangent cross the step's boundary): output and every cotangent against the XLA form."""
+    """Heads of 64 two a lane block (and of 128 one), a row shorter than a step, one of two steps (the carried state
+    and its cotangent cross the step's boundary) and one a single token over a step (the scratch that holds a step's
+    running sums and row forms is made again at the boundary, and the second step's is all padding but a token):
+    output and every cotangent, dt's and ``a``'s as the sweeps now write them, against the XLA form."""
     args, w = operands(rows, seq, heads, p, 128, seed=5)
-    interpreted = lambda *z: ssd.ssd_scan(*z, impl="kernels_interpret")  # noqa: E731
-    assert _rel(interpreted(*args), ssd.ssd_scan(*args)) < 1e-5
-    for name, g, want in zip(NAMES, _grads(interpreted, args, w), _grads(ssd.ssd_scan, args, w)):
-        assert _rel(g, want) < 1e-4, name
+    assert _rel(_interpreted(*args), ssd.ssd_scan(*args)) < 1e-5
+    for name, g, want in zip(NAMES, _grads(_interpreted, args, w), _grads(ssd.ssd_scan, args, w)):
+        assert g.shape == want.shape and _rel(g, want) < 1e-4, name
+
+
+def _fast_beside_slow():
+    """``a = -64`` and ``-1`` in ONE 128-lane block at dt near 1: ``|G|`` reaches 8,000 inside a chunk."""
+    (x, _, _, b, c, d), w = operands(1, 300, 2, 64, 128, seed=8)
+    dt = 1.0 + 0.1 * jax.random.uniform(jax.random.PRNGKey(9), (1, 300, 2), jnp.float32, -1.0, 1.0)
+    return (x, dt, jnp.asarray([-64.0, -1.0]), b, c, d), w
+
+
+def test_a_fast_head_beside_a_slow_one_in_one_lane_block():
+    """A decay taken before the mask (``exp(G_i - G_j)`` for ``i < j``) would overflow at such a ``G`` and a running
+    sum rounded to bfloat16 on its way through the MXU would be off by tens. Finite, and the XLA form's, output and
+    every cotangent."""
+    args, w = _fast_beside_slow()
+    got, want = _interpreted(*args), ssd.ssd_scan(*args)
+    assert np.isfinite(np.asarray(got)).all() and _rel(got, want) < 1e-5
+    grads = _grads(_interpreted, args, w)
+    for name, g, wanted in zip(NAMES, grads, _grads(ssd.ssd_scan, args, w)):
+        assert np.isfinite(np.asarray(g)).all() and _rel(g, wanted) < 1e-4, name
+    assert np.abs(np.asarray(grads[1])[0, :, 1]).min() > 0  # (the slow head's dt reaches later tokens through G: its cotangent is the folded one)
+
+
+def test_the_sweeps_running_sum_is_float32_and_the_tests_see_one_that_is_not(monkeypatch):
+    """The products that sum and turn the per-token scalars say ``Precision.HIGHEST`` (the interpreter on a CPU would
+    not show a bfloat16 pass, the chip would); and with ``dt A`` rounded to bfloat16 before the sum, as the MXU's
+    default pass would leave it, the comparison above fails by far."""
+    for product in (ssd._turned, ssd._turned_back):
+        (eqn,) = jax.make_jaxpr(product)(jnp.zeros((128, 128)), jnp.zeros((128, 128))).eqns
+        assert eqn.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2 and eqn.params["preferred_element_type"] == jnp.float32
+    args, _ = _fast_beside_slow()
+    want = ssd.ssd_scan(*args)
+    turned = ssd._turned
+    monkeypatch.setattr(ssd, "_turned", lambda u, v: turned(u.astype(jnp.bfloat16).astype(jnp.float32), v))
+    ssd._flat_scan.cache_clear(), ssd.ssd_scan_fwd.clear_cache()
+    try:
+        assert _rel(_interpreted(*args), want) > 1e-3
+    finally:
+        monkeypatch.undo()
+        ssd._flat_scan.cache_clear(), ssd.ssd_scan_fwd.clear_cache()
 
 
 def test_which_program_runs_the_scan_is_read_from_the_call(monkeypatch):
@@ -469,3 +514,8 @@ def test_the_cells_step_compiles_for_v5e(topo, monkeypatch):
         assert not calls("attn_in_fwd")  # heads of 64: the XLA hand-over (ops/rope.why_not_fused)
         produced = [ln.split(" = ", 1)[-1].split("(", 1)[0] for ln in lines]
         assert not [x for x in produced if f",{seq},{seq}]" in x]
+        # between the IN pass and the sweeps nothing but free reshapes: no XLA operation under the scan's scope turns (the
+        # compiler writes a transpose as a ``copy`` between layouts: the parent's step had 360), gathers or sums over a
+        # window (108 there): the running sum of dt A and the row forms are made inside the sweeps
+        under_scan = [ln.split(" = ", 1)[-1].split(", metadata", 1)[0] for ln in lines if "/ssd_scan/" in ln and "tpu_custom_call" not in ln]
+        assert under_scan and not [ln for ln in under_scan if re.search(r"\b(transpose|copy|reduce-window|gather)\(", ln)]
