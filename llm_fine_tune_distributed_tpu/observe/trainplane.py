@@ -309,9 +309,10 @@ class TrainTelemetry:
             "epochs": 0,
             "preempted": False,
             # where start-up went, once the first step has finished: seconds
-            # by phase (observe/xla.py spans: startup/data, startup/weights,
-            # startup/optimizer, startup/restore, startup/first_step with the
-            # step program's train_step/load inside it), the seconds since the
+            # by phase (observe/xla.py spans: process/before_recorder, import,
+            # startup/data, startup/weights, startup/optimizer with
+            # startup/opt_state inside it, startup/restore, startup/first_step
+            # with the step program's train_step/load inside it), the seconds since the
             # process started, the persistent compile cache's hits and misses
             # (CompileLedger.setup_phases(); a restarted job pays all of it)
             "startup": None,

@@ -18,7 +18,9 @@ This module makes that contract observable:
   monitoring events feed it too (``install_compile_listeners``): every
   jitted function's trace, lowering and backend compile as ``jit/trace``,
   ``jit/lower``, ``jit/compile`` with its ``fun_name``, and the
-  persistent cache's hits and misses as counters.
+  persistent cache's hits and misses as counters. ``observe/startup.py``
+  brings what no call site can span: ``process/before_recorder`` (process
+  start to this module's import); ``importing()`` is ``import`` / ``import/nested``.
 - ``CompileLedger`` records every compilation (program name, abstract
   arg shapes, wall compile seconds split into trace, lowering and
   backend compile, cache hit or miss), deduplicates by (program,
@@ -69,18 +71,11 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import jax
 
+from llm_fine_tune_distributed_tpu.observe import startup
+
 __all__ = [
-    "CaptureBusyError",
-    "CompileLedger",
-    "ProfilerCapture",
-    "STEP_SCOPES",
-    "SpanRecorder",
-    "annotate",
-    "device_peak_specs",
-    "install_compile_listeners",
-    "instrument",
-    "mosaic_programs",
-    "scope",
+    "CaptureBusyError", "CompileLedger", "ProfilerCapture", "STEP_SCOPES", "SpanRecorder", "annotate",
+    "device_peak_specs", "importing", "install_compile_listeners", "instrument", "mosaic_programs", "scope",
     "utilization_from_cost",
 ]
 
@@ -94,6 +89,7 @@ MAX_SPANS = 8192  # kept spans
 # run, PR 38), and it is the list's first spans that would crowd out the last.
 BRIEF_NS = 1_000_000
 _IMPORTED_NS = time.time_ns()
+_AT_IMPORT = startup.facts_at_import()  # of process/before_recorder, which ends here
 
 # JAX's monitoring events (jax/_src/dispatch.py, compiler.py, compilation_cache.py)
 _COMPILE_SPANS = {
@@ -172,13 +168,13 @@ class SpanRecorder:
     lines need no conversion beyond the capture's own start, which an xplane
     counts from), ``parent`` (the id of the span that was open on the same
     thread when it started, else the root span ``setup``, id 0, which began
-    with the process), ``thread``, and small attributes (``program``,
-    ``fun_name``, ``cache``). Spans are kept until the list holds
-    ``max_spans`` (JAX's own stages only from a millisecond up); the
-    counters go on. ``freeze()`` ends ``setup``: what was recorded until
-    then is the set-up section, and nothing is recorded after it (the
-    engine's tick phases and a late compile are ``TraceAnnotation``s and
-    ledger entries, which have their own readers)."""
+    with the process), ``thread``, and small attributes (``program``, ``fun_name``,
+    ``cache``, ``module``). The first is ``process/before_recorder``. Spans are kept
+    until the list holds ``max_spans`` (JAX's own stages only from a millisecond up:
+    ``spans_brief`` counts the briefer ones); the counters go on. ``freeze()``
+    ends ``setup``: what was recorded until then is the set-up section, and nothing
+    is recorded after it (the engine's tick phases and a late compile are
+    ``TraceAnnotation``s and ledger entries, which have their own readers)."""
 
     def __init__(self, max_spans: int = MAX_SPANS, start_ns: Optional[int] = None):
         self._lock = threading.Lock()
@@ -189,10 +185,11 @@ class SpanRecorder:
         self._max_spans = max_spans
         self._root = {"id": 0, "name": "setup", "start_ns": _process_start_ns() if start_ns is None else start_ns,
                       "end_ns": None, "parent": None, "thread": None}
-        self._spans: List[Dict[str, Any]] = []
+        self._spans = [startup.before_recorder(next(self._ids), self._root["start_ns"], _IMPORTED_NS, _AT_IMPORT)]
         self._by_function: Dict[str, List[float]] = {}  # fun_name -> [seconds, spans]
         self.frozen = False
-        self.counters: Dict[str, float] = {"spans": 0, "spans_brief": 0, "spans_dropped": 0, "jit_seconds": 0.0}
+        # process/before_recorder is counted as the span it is
+        self.counters: Dict[str, float] = {"spans": 1, "spans_brief": 0, "spans_dropped": 0, "jit_seconds": 0.0}
         self.counters.update(dict.fromkeys(_CACHE_COUNTS.values(), 0))
         self.counters.update(dict.fromkeys(_CACHE_SECONDS.values(), 0.0))
 
@@ -335,7 +332,8 @@ def install_compile_listeners() -> None:
     """Feed the recorder from JAX's monitoring events: once a process,
     however often and from whichever thread it is called (the first
     ``CompileLedger()``; ``runtime/compile_cache.enable_compile_cache()``,
-    which every entry point calls before anything compiles)."""
+    which every entry point calls before anything compiles). Imports are
+    spans where the package makes them (``importing``), not from a hook."""
     global _listeners_installed
     with _LISTENING:
         if _listeners_installed:
@@ -343,6 +341,7 @@ def install_compile_listeners() -> None:
         jax.monitoring.register_event_time_span_listener(_on_compile_span)
         jax.monitoring.register_event_listener(_on_cache_event)
         jax.monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+        # no import hook here: a sys.meta_path finder cost the benchmark's cells 6 to 16 s of set-up (startup.py)
         _listeners_installed = True
 
 
@@ -499,13 +498,14 @@ class CompileLedger:
     @staticmethod
     def setup_phases() -> Dict[str, Any]:
         """The operator's reading of set-up so far: seconds by span name in
-        order of first start (``startup/weights``, ``train_step/load``; JAX's
-        own ``jit/*`` spans are their children and stay out), the seconds
+        order of first start (``process/before_recorder``, ``import``,
+        ``startup/weights``, ``train_step/load``; JAX's own ``jit/*`` spans and
+        ``import/nested`` are their children and stay out), the seconds
         since the process started, and the persistent cache's counters."""
         section = _RECORDER.section()
         root, phases = section["spans"][0], {}
         for span in sorted(section["spans"][1:], key=lambda sp: sp["start_ns"]):
-            if "fun_name" not in span:
+            if "fun_name" not in span and span["name"] != "import/nested":
                 phases[span["name"]] = phases.get(span["name"], 0.0) + (span["end_ns"] - span["start_ns"]) / 1e9
         return {
             "phases_s": {name: round(secs, 3) for name, secs in phases.items()},
@@ -852,3 +852,36 @@ def scope(name: str, index: Optional[int] = None):
     """``jax.named_scope`` for one name of ``STEP_SCOPES``."""
     assert name in STEP_SCOPES, name
     return jax.named_scope(name if index is None else f"{name}{index}")
+
+
+_IMPORTING = threading.local()  # .depth: the import spans open on this thread
+
+
+class importing:
+    """``with importing("orbax.checkpoint"): import orbax.checkpoint as ocp``:
+    the span ``import`` where no import span is open on the thread,
+    ``import/nested`` below one (so the spans of one name never overlap on a
+    thread and add up), with ``module`` and ``cpu_s`` (the PROCESS's CPU
+    seconds over the span: wall far above it is waiting on the disk), and
+    ``error`` where the import raised. Put round the few imports of the
+    package that pull a third-party tree in (``train/__init__.py``,
+    ``train/checkpoints.py``, ``parallel/optimizer.py``); while set-up lasts
+    only: afterwards it reads no clock and is the ``TraceAnnotation`` alone."""
+
+    __slots__ = ("_span", "_cpu_0", "_depth")
+
+    def __init__(self, module: str):
+        self._depth = getattr(_IMPORTING, "depth", 0)
+        self._span = annotate("import/nested" if self._depth else "import", module=module)
+
+    def __enter__(self) -> "importing":
+        _IMPORTING.depth = self._depth + 1
+        self._cpu_0 = None if self._span.record is None else time.process_time()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        _IMPORTING.depth = self._depth
+        if self._cpu_0 is not None:
+            self._span.set(cpu_s=round(time.process_time() - self._cpu_0, 6))
+        self._span.__exit__(exc_type, exc, tb)

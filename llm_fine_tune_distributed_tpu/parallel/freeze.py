@@ -18,6 +18,7 @@ import re
 from typing import Callable
 
 from llm_fine_tune_distributed_tpu.config import ModelConfig, TrainConfig
+from llm_fine_tune_distributed_tpu.observe.xla import annotate
 from llm_fine_tune_distributed_tpu.utils.tree import (
     count_params,
     count_params_where,
@@ -113,27 +114,31 @@ def quantize_trunk_int8(frozen: dict, boundary: int):
 
     Returns ``(new_flat, n_quantized)``. Shared by the trainer
     (_prepare_state) and bench.py so the two can never disagree on which
-    leaves the w8a8 fast path covers.
+    leaves the w8a8 fast path covers. While set-up lasts it is the span
+    ``startup/quantize_trunk`` (one small program a kernel shape, dispatched
+    leaf by leaf; the device's time is not waited for).
     """
     from llm_fine_tune_distributed_tpu.ops.int8 import INT8_SUFFIXES, quantize_int8
 
     quantized = {}
     n_quant = 0
-    for k, v in frozen.items():
-        m = _LAYER_RE.search(k)
-        if (
-            m is not None
-            and int(m.group(1)) < boundary
-            and k.endswith("/kernel")
-            and not k.endswith("block_sparse_moe/gate/kernel")
-            and getattr(v, "ndim", 0) == 2
-        ):
-            q = quantize_int8(v)
-            for suffix in INT8_SUFFIXES:
-                quantized[f"{k}_{suffix}"] = q[suffix]
-            n_quant += 1
-        else:
-            quantized[k] = v
+    with annotate("startup/quantize_trunk", boundary=boundary) as span:
+        for k, v in frozen.items():
+            m = _LAYER_RE.search(k)
+            if (
+                m is not None
+                and int(m.group(1)) < boundary
+                and k.endswith("/kernel")
+                and not k.endswith("block_sparse_moe/gate/kernel")
+                and getattr(v, "ndim", 0) == 2
+            ):
+                q = quantize_int8(v)
+                for suffix in INT8_SUFFIXES:
+                    quantized[f"{k}_{suffix}"] = q[suffix]
+                n_quant += 1
+            else:
+                quantized[k] = v
+        span.set(quantized=n_quant)
     return quantized, n_quant
 
 

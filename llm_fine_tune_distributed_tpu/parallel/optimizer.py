@@ -17,10 +17,13 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from llm_fine_tune_distributed_tpu.config import TrainConfig
+from llm_fine_tune_distributed_tpu.observe.xla import annotate, importing
+
+with importing("optax"):  # chex and absl behind it: most of a second where this module is the first to ask
+    import optax
 
 
 def build_lr_schedule(config: TrainConfig, total_steps: int, data_parallel_size: int):
@@ -141,7 +144,10 @@ def opt_state_shardings(optimizer: optax.GradientTransformation, trainable, mesh
 def init_opt_state(optimizer: optax.GradientTransformation, trainable, mesh):
     """``optimizer.init`` with the state laid out by ``opt_state_shardings``:
     the whole state lives on the full mesh (restore-from-checkpoint builds
-    its target shardings from it)."""
-    return jax.jit(
-        optimizer.init, out_shardings=opt_state_shardings(optimizer, trainable, mesh)
-    )(trainable)
+    its target shardings from it). While set-up lasts it is the span
+    ``startup/opt_state`` (the program's trace, compile and dispatch; the
+    device's time is not waited for)."""
+    with annotate("startup/opt_state"):
+        return jax.jit(
+            optimizer.init, out_shardings=opt_state_shardings(optimizer, trainable, mesh)
+        )(trainable)

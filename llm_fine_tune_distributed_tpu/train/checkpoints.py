@@ -44,9 +44,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-import orbax.checkpoint as ocp
 
+from llm_fine_tune_distributed_tpu.observe.xla import importing
 from llm_fine_tune_distributed_tpu.train.state import TrainState
+
+with importing("orbax.checkpoint"):  # its logging imports google.cloud.logging: seconds, named while set-up lasts
+    import orbax.checkpoint as ocp
 
 
 class FingerprintMismatch(RuntimeError):

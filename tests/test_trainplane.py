@@ -506,12 +506,30 @@ def _wait_plane(trainer, timeout=120.0):
 def test_startup_phases_ride_status_and_the_log(qa_parquet, tmp_path, capsys, monkeypatch):  # noqa: F811
     """Where the time from process start to the first optimizer step went:
     the trainer's start-up under spans (observe/xla.py), printed once the
-    first step has finished and served as ``startup`` of /v1/train/status."""
+    first step has finished and served as ``startup`` of /v1/train/status.
+    The time before the recorder and the package's dear imports ride the
+    same line (observe/startup.py, xla.importing): a trainer's process
+    starts with both."""
+    import importlib
+    import sys
+
     from llm_fine_tune_distributed_tpu.observe import xla
     from llm_fine_tune_distributed_tpu.train.trainer import SFTTrainer
 
     # a recorder of this test's own: the test run's first mark_warm() froze the process's
     monkeypatch.setattr(xla, "_RECORDER", xla.SpanRecorder())
+    # an import that lasts, with one below it, wrapped as train/__init__.py and train/checkpoints.py wrap theirs
+    # (this process made those long ago)
+    slow = tmp_path / "slow_to_import"
+    slow.mkdir()
+    (slow / "__init__.py").write_text(
+        "import time\ntime.sleep(0.06)\nfrom llm_fine_tune_distributed_tpu.observe.xla import importing\n"
+        "with importing('slow_to_import.below'):\n    from slow_to_import import below\n")
+    (slow / "below.py").write_text("import time\ntime.sleep(0.06)\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    with xla.importing("slow_to_import"):
+        importlib.import_module("slow_to_import")
+    del sys.modules["slow_to_import"], sys.modules["slow_to_import.below"]
     data_dir, dataset_file = qa_parquet
     config = make_config(
         tmp_path / "out", data_dir, dataset_file, epochs=1, eval_steps=2, save_steps=100,
@@ -522,14 +540,25 @@ def test_startup_phases_ride_status_and_the_log(qa_parquet, tmp_path, capsys, mo
     trainer.train()
     startup = trainer.telemetry.status()["startup"]
     phases = startup["phases_s"]
-    assert list(phases) == ["startup/data", "startup/weights", "startup/optimizer", "startup/first_step",
+    assert list(phases) == ["process/before_recorder", "import", "startup/data", "startup/weights", "startup/optimizer",
+                            "startup/opt_state", "startup/first_step",
                             "train_step/load"]  # in order of start; no restore: nothing was resumed
     assert all(v >= 0.0 for v in phases.values())
     assert phases["train_step/load"] <= phases["startup/first_step"]  # the step's load is inside its first step
-    assert startup["since_process_start_s"] >= sum(phases.values()) - phases["train_step/load"]
+    assert phases["startup/opt_state"] <= phases["startup/optimizer"]  # the state's program is inside its builder's span
+    assert phases["import"] >= 0.12  # the nested import's seconds once, inside its parent's
+    nested = phases["startup/opt_state"] + phases["train_step/load"]
+    assert startup["since_process_start_s"] >= sum(phases.values()) - nested
     assert {"cache_hits", "cache_misses", "compile_requests_use_cache"} <= set(startup)
     line = [ln for ln in capsys.readouterr().out.splitlines() if ln.startswith("[train] start-up: ")]
     assert len(line) == 1 and "startup/weights" in line[0] and "train_step/load" in line[0]
+    assert line[0].startswith("[train] start-up: process/before_recorder ") and "import/nested" not in line[0]
+    assert line[0].index("startup/optimizer") < line[0].index("startup/opt_state") < line[0].index("startup/first_step")
+    spans = trainer.compile_ledger.setup()["spans"]
+    by_name = {s["name"]: s for s in spans}
+    nested, = [s for s in spans if s.get("module") == "slow_to_import.below"]
+    assert nested["name"] == "import/nested"  # recorded, and left out of the line
+    assert by_name["startup/opt_state"]["parent"] == by_name["startup/optimizer"]["id"]
     # set-up ended at the warm boundary, with the eval programs' loads in it; the step's load says it was no AOT compile
     snap = trainer.compile_ledger.snapshot()
     assert snap["warmed"] and "setup" not in snap  # a scrape's snapshot stays at its totals

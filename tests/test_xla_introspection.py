@@ -392,15 +392,16 @@ def test_the_list_is_bounded_and_the_counters_go_on(monkeypatch):
     small.add_jit_stage("jit/compile", 1.0, 3.5, "jit(late)")  # JAX's seconds; its name for a compile
     small.add_jit_stage("jit/trace", 1.0, 1.0005, "late")  # under a millisecond: counted, never kept
     section = small.section()
-    assert len(section["spans"]) == 1 + 3  # the root and what the list holds
-    assert section["counters"]["spans"] == 7 and section["counters"]["spans_dropped"] == 3
+    assert len(section["spans"]) == 1 + 3  # the root and what the list holds: process/before_recorder, two ticks
+    assert [s["name"] for s in section["spans"]] == ["setup", "process/before_recorder", "tick", "tick"]
+    assert section["counters"]["spans"] == 8 and section["counters"]["spans_dropped"] == 4
     assert section["counters"]["spans_brief"] == 1
     assert section["counters"]["jit_seconds"] == pytest.approx(2.5005)
     assert section["by_function"] == [{"fun_name": "late", "seconds": 2.5005, "spans": 2}]
     roomy = SpanRecorder()
     roomy.add_jit_stage("jit/trace", 1.0, 1.0005, "brief")
     roomy.add_jit_stage("jit/trace", 1.0, 1.002, "long_enough")
-    assert [s["fun_name"] for s in roomy.section()["spans"][1:]] == ["long_enough"]
+    assert [s["fun_name"] for s in roomy.section()["spans"][2:]] == ["long_enough"]
 
 
 def test_mark_warm_freezes_the_setup_section_and_later_compiles_still_count(recorder):
@@ -427,7 +428,8 @@ def test_mark_warm_freezes_the_setup_section_and_later_compiles_still_count(reco
     CompileLedger().mark_warm()  # a second ledger's warm-up does not move the end of set-up
     assert led.setup()["spans"][0]["end_ns"] == root["end_ns"]
     phases = led.setup_phases()
-    assert list(phases["phases_s"])[:2] == ["double/load", "double/compile"] and "startup/weights" in phases["phases_s"]
+    assert list(phases["phases_s"])[:3] == ["process/before_recorder", "double/load", "double/compile"]
+    assert "startup/weights" in phases["phases_s"]
     assert "jit/trace" not in phases["phases_s"]
     assert phases["since_process_start_s"] == pytest.approx((root["end_ns"] - root["start_ns"]) / 1e9, abs=1e-3)
 
